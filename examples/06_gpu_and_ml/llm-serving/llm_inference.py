@@ -47,25 +47,21 @@ MINUTES = 60
 
 app = mtpu.App("example-llm-inference")
 
-# HF weights + XLA compile cache live on Volumes, like the reference's
-# huggingface-cache + vllm-cache volumes (vllm_inference.py:77-81)
+# HF weights live on a Volume, like the reference's huggingface-cache volume
+# (vllm_inference.py:77-81). The XLA compile cache (the vllm-cache analog, and
+# the biggest cold-start lever on TPU) is placed by the entry point: `tpurun`
+# exports JAX_COMPILATION_CACHE_DIR (the fixed in-checkout .xla_cache/ unless
+# the environment already names a directory) and the container inherits it.
 hf_cache_vol = mtpu.Volume.from_name("huggingface-cache", create_if_missing=True)
-compile_cache_vol = mtpu.Volume.from_name("xla-compile-cache", create_if_missing=True)
 
-image = (
-    mtpu.Image.tpu_base()
-    .env({"JAX_COMPILATION_CACHE_DIR": "/root/.cache/xla"})
-)
+image = mtpu.Image.tpu_base()
 
 
 @app.server(
     port=PORT,
     tpu=TPU,
     image=image,
-    volumes={
-        "/root/.cache/huggingface": hf_cache_vol,
-        "/root/.cache/xla": compile_cache_vol,
-    },
+    volumes={"/root/.cache/huggingface": hf_cache_vol},
     startup_timeout=20 * MINUTES,
     scaledown_window=15 * MINUTES,
     target_concurrency=100,
@@ -74,21 +70,12 @@ image = (
 class LLMServer:
     @mtpu.enter()
     def start(self):
-        import jax
-
-        # persistent compile cache: the single biggest cold-start lever on
-        # TPU (the trtllm "engine build" / vllm-cache analog)
-        try:
-            jax.config.update(
-                "jax_compilation_cache_dir",
-                os.environ.get("JAX_COMPILATION_CACHE_DIR", "/tmp/xla-cache"),
-            )
-        except Exception:
-            pass
         from modal_examples_tpu.serving import OpenAIServer, build_engine
 
         engine_kw = {}
         if TP > 1:
+            import jax
+
             from modal_examples_tpu.parallel import make_mesh
 
             engine_kw["mesh"] = make_mesh(

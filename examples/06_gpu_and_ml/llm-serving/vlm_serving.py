@@ -36,22 +36,15 @@ MINUTES = 60
 app = mtpu.App("example-vlm-serving")
 
 hf_cache_vol = mtpu.Volume.from_name("huggingface-cache", create_if_missing=True)
-compile_cache_vol = mtpu.Volume.from_name("xla-compile-cache", create_if_missing=True)
 
-image = (
-    mtpu.Image.tpu_base()
-    .env({"JAX_COMPILATION_CACHE_DIR": "/root/.cache/xla"})
-)
+image = mtpu.Image.tpu_base()
 
 
 @app.server(
     port=PORT,
     tpu=TPU,
     image=image,
-    volumes={
-        "/root/.cache/huggingface": hf_cache_vol,
-        "/root/.cache/xla": compile_cache_vol,
-    },
+    volumes={"/root/.cache/huggingface": hf_cache_vol},
     startup_timeout=20 * MINUTES,
     scaledown_window=15 * MINUTES,
     target_concurrency=100,

@@ -30,7 +30,7 @@ def main():
         )
         assert wait_for_port("127.0.0.1", PORT, timeout=20), "server never bound"
         with mtpu.forward(PORT) as tunnel:
-            print(f"server tunneled at {tunnel.url}")
+            print(f"server published at {tunnel.url}")
             with urllib.request.urlopen(f"{tunnel.url}/notebook.txt", timeout=5) as r:
                 content = r.read().decode()
         assert "pretend" in content

@@ -29,9 +29,6 @@ STATE_DIR_ENV = "MTPU_STATE_DIR"
 #: (reference analog: ``MODAL_TASK_ID``, simple_torch_cluster.py:111).
 TASK_ID_ENV = "MTPU_TASK_ID"
 
-#: Comma-separated ``key=value`` telling a container which TPU chips it owns.
-TPU_VISIBLE_ENV = "TPU_VISIBLE_CHIPS"
-
 
 def backend() -> str:
     return os.environ.get(BACKEND_ENV, "process")

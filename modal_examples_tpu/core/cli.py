@@ -187,10 +187,13 @@ def cmd_serve(argv: list[str]) -> int:
             web = fn.spec.web or {}
             if web.get("type") == "web_server":
                 fn.raw_f()  # user code binds the port (thread/subprocess)
-                if wait_for_port("127.0.0.1", web["port"], web.get("startup_timeout", 30)):
-                    urls.append(f"http://127.0.0.1:{web['port']}")
-                else:
-                    print(f"warning: {name} never opened port {web['port']}")
+                if not wait_for_port(
+                    "127.0.0.1", web["port"], web.get("startup_timeout", 30)
+                ):
+                    raise SystemExit(
+                        f"{name} never opened port {web['port']}"
+                    )
+                urls.append(f"http://127.0.0.1:{web['port']}")
         if not urls:
             raise SystemExit("no web endpoints or servers registered")
         for u in urls:
@@ -1226,8 +1229,8 @@ def cmd_incidents(argv: list[str]) -> int:
                                    file raw); a unique id prefix resolves
     incidents capture [--reason TEXT] [--trigger T]
                                  — capture a bundle right now (trigger
-                                   ``manual``; ``revalidate_chip.sh``'s
-                                   stage wrapper passes ``stage_failure``)
+                                   ``manual``; a script's stage wrapper
+                                   passes ``stage_failure``)
     ``--dir PATH`` overrides the state-dir root.
     """
     from ..observability import incident as _incident
@@ -2067,6 +2070,9 @@ def main(argv: list[str] | None = None) -> int:
     handler = COMMANDS.get(cmd)
     if handler is None:
         raise SystemExit(f"unknown command {cmd!r}; one of {sorted(COMMANDS)}")
+    from ..utils.compile_cache import place_compile_cache
+
+    place_compile_cache()  # before any command imports JAX
     try:
         return handler(rest)
     except BrokenPipeError:

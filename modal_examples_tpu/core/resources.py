@@ -15,23 +15,35 @@ import dataclasses
 import re
 
 # generation -> (chips per host, HBM GiB per chip, bf16 peak TFLOP/s per chip)
-# Used for host-count derivation and for back-of-envelope perf accounting in
-# the profiler/bench tooling.
+# Peaks are the published per-chip figures (Google Cloud TPU documentation,
+# "TPU v4" / "TPU v5e" / "TPU v5p" / "TPU v6e" system architecture pages).
 TPU_GENERATIONS: dict[str, tuple[int, int, float]] = {
-    "v4": (4, 32, 137.5),
-    "v5e": (8, 16, 98.5),  # v5 lite
-    "v5p": (4, 95, 229.5),
-    "v6e": (8, 32, 459.0),
+    "v4": (4, 32, 275.0),
+    "v5e": (8, 16, 197.0),  # v5 lite
+    "v5p": (4, 95, 459.0),
+    "v6e": (8, 32, 918.0),
 }
 
-# generation -> HBM bandwidth GB/s per chip: the MBU denominator the
-# roofline meter (observability/usage.py) normalizes decode byte traffic
-# against. v5e matches bench.py's V5E_HBM_GBPS ceiling.
+# generation -> HBM bandwidth GB/s per chip (same source): the MBU
+# denominator the roofline meter (observability/usage.py) normalizes decode
+# byte traffic against.
 TPU_HBM_GBPS: dict[str, float] = {
     "v4": 1228.0,
     "v5e": 819.0,
     "v5p": 2765.0,
-    "v6e": 1638.0,
+    "v6e": 1640.0,
+}
+
+# jax ``device_kind`` -> generation, so a process on a TPU reads its peaks
+# from the device it is on (observability.usage.resolve_peaks)
+DEVICE_KIND_GENERATION: dict[str, str] = {
+    "TPU v4": "v4",
+    "TPU v5 lite": "v5e",
+    "TPU v5e": "v5e",
+    "TPU v5": "v5p",
+    "TPU v5p": "v5p",
+    "TPU v6 lite": "v6e",
+    "TPU v6e": "v6e",
 }
 
 _SPEC_RE = re.compile(r"^(?P<gen>v\d+[a-z]*)(?:-(?P<chips>\d+))?$", re.IGNORECASE)

@@ -756,10 +756,10 @@ def decode_step(
     impl="pallas" (round 4) keeps this same read-only structure but swaps
     the attention for the v3 ragged kernel (ops.paged_decode_attention_ragged)
     — it reads exactly ceil(ctx/page_size) pages per sequence where the XLA
-    gather reads and materializes ALL pages_per_seq pages (measured as the
-    dominant, superlinear-in-slots step cost: benchmarks/decode_ablate.py).
-    ``impl="xla-writeback"`` keeps the round-2 write-then-attend structure
-    as the A/B lever for benchmarks/decode_micro.py.
+    gather reads and materializes ALL pages_per_seq pages (a builder's
+    round-4 knock-out ablation put that at the dominant, superlinear-in-slots
+    step cost; not a driver record). ``impl="xla-writeback"`` keeps the
+    round-2 write-then-attend structure as an A/B lever.
     """
     if impl in ("xla-writeback", "pallas-writeback"):
         return _decode_step_writeback(
@@ -840,14 +840,12 @@ def decode_step(
         layer_fn, x, (_layer_stack(params), jnp.arange(L))
     )
     # k_all: [L, B, Hkv, D] -> one scatter for every layer's token.
-    # The pallas scatter (in-place strided DMAs; XLA's scatter for this
-    # update measured 4.8 ms/step at 7B/32 slots, decode_ablate.py) is
-    # opt-in (scatter_impl="pallas", resolved above — callers that jit must
-    # pass it explicitly, same trap as impl=) until it is revalidated on a
-    # healthy chip: its first on-chip run this round wedged the device
-    # mid-compile, and a wedged chip poisons every later bench config.
-    # Independent of the attention impl — both structures end in the same
-    # post-scan scatter; only the (Hkv, D) minor-dim tile legality gates it.
+    # The pallas scatter (in-place strided DMAs) is opt-in
+    # (scatter_impl="pallas", resolved above — callers that jit must pass
+    # it explicitly, same trap as impl=); choosing between the two is the
+    # benchmark's job (ROADMAP S2/D3). Independent of the attention impl —
+    # both structures end in the same post-scan scatter; only the (Hkv, D)
+    # minor-dim tile legality gates it.
     if plan["scatter"] == "pallas":
         k_pages, v_pages = sharded_scatter_kv_pages(
             mesh, k_pages, v_pages, k_all, v_all, page_idx, slot

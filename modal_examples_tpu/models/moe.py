@@ -24,8 +24,6 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from ..parallel.mesh import shard_map_compat
-
 
 @dataclasses.dataclass(frozen=True)
 class MoEConfig:
@@ -270,7 +268,7 @@ def moe_mlp_ep(
         out = jnp.einsum("tec,ecd->td", combine, expert_out)
         return out, aux[None]  # rank-1 so shards concatenate over the axis
 
-    out, aux = shard_map_compat(
+    out, aux = jax.shard_map(
         shard_fn,
         mesh=mesh,
         in_specs=(P(), P(axis), P(axis), P(axis)),

@@ -1,9 +1,10 @@
 """Native host runtime: ctypes bridge to mtpu_host.cpp.
 
-Builds the shared library on first import (g++ is in the image; no
-pybind11 — plain C ABI via ctypes) and caches it next to the source.
-Every consumer has a pure-Python fallback, so the framework degrades
-gracefully where no compiler exists.
+Builds the shared library on first use (g++ is in the image; no pybind11 —
+plain C ABI via ctypes) next to the source, where git ignores it: a fresh
+checkout builds its own. Every consumer has a pure-Python fallback and
+:func:`load_error` says why it was taken; the KV cache reports which page
+allocator it ended up with (``PagedKVCache.allocator_impl``).
 """
 
 from __future__ import annotations
@@ -21,9 +22,11 @@ _LIB = _HERE / "libmtpu_host.so"
 _lock = threading.Lock()
 _lib = None
 _tried = False
+_load_error: str | None = None
 
 
-def _build() -> bool:
+def _build() -> str | None:
+    """Compile the library; returns why it could not, or None."""
     try:
         subprocess.run(
             [
@@ -34,24 +37,33 @@ def _build() -> bool:
             capture_output=True,
             timeout=120,
         )
-        return True
-    except Exception:
-        return False
+        return None
+    except subprocess.CalledProcessError as e:
+        return f"g++ failed: {e.stderr.decode(errors='replace')[-400:]}"
+    except (OSError, subprocess.TimeoutExpired) as e:
+        return f"g++ did not run: {e}"
+
+
+def load_error() -> str | None:
+    """Why :func:`load` returned None (None while it has not, or did not)."""
+    return _load_error
 
 
 def load():
     """The loaded library, or None when native isn't available."""
-    global _lib, _tried
+    global _lib, _tried, _load_error
     with _lock:
         if _lib is not None or _tried:
             return _lib
         _tried = True
         if not _LIB.exists() or _LIB.stat().st_mtime < _SRC.stat().st_mtime:
-            if not _build():
+            _load_error = _build()
+            if _load_error is not None:
                 return None
         try:
             lib = ctypes.CDLL(str(_LIB))
-        except OSError:
+        except OSError as e:
+            _load_error = f"cannot load {_LIB.name}: {e}"
             return None
         lib.mtpu_alloc_create.restype = ctypes.c_void_p
         lib.mtpu_alloc_create.argtypes = [ctypes.c_int32]
@@ -87,7 +99,7 @@ class NativePageAllocator:
     def __init__(self, n_pages: int):
         lib = load()
         if lib is None:
-            raise RuntimeError("native library unavailable")
+            raise RuntimeError(f"native library unavailable: {load_error()}")
         self._lib = lib
         self.n_pages = n_pages
         self._h = lib.mtpu_alloc_create(n_pages)

@@ -85,6 +85,10 @@ KV_PAGE_OCCUPANCY = "mtpu_kv_page_occupancy"
 #: gauge {dtype}: total HBM bytes of the paged KV cache arrays (dtype-aware:
 #: int8 caches report ~half the bf16 footprint — docs/kv_cache.md)
 KV_CACHE_BYTES = "mtpu_kv_cache_bytes"
+#: gauge {device, kind}: ``device.memory_stats()`` of each local device, read
+#: when /metrics is scraped (kind = in_use | peak | limit) — how weights and
+#: KV are spread over a host's chips, visible to a process that is not JAX's
+DEVICE_MEMORY_BYTES = "mtpu_device_memory_bytes"
 #: counter: zero-ref prefix-cache pages reclaimed under allocator pressure
 PREFIX_CACHE_EVICTIONS_TOTAL = "mtpu_prefix_cache_evictions_total"
 #: gauge: total payload bytes resident in the memory-snapshot store
@@ -381,7 +385,7 @@ ROOFLINE_PHASES = ("prefill", "decode", "total")
 
 #: gauge {phase}: model FLOPs utilization — analytic FLOPs accounted to
 #: the phase over (device seconds x peak TFLOP/s x chips), against the
-#: core/resources.py bf16 peak for the resolved generation (MTPU_TPU_GEN)
+#: core/resources.py bf16 peak for the device's generation (usage.resolve_peaks)
 MFU = "mtpu_mfu"
 #: gauge {phase}: HBM bandwidth utilization (MBU) — analytic bytes moved
 #: (weight stream + kv_dtype-aware KV reads) over (device seconds x peak
@@ -555,6 +559,11 @@ CATALOG: dict[str, dict] = {
     KV_CACHE_BYTES: {
         "type": "gauge", "labels": ["dtype"],
         "help": "total HBM bytes of the paged KV cache (dtype-aware)",
+    },
+    DEVICE_MEMORY_BYTES: {
+        "type": "gauge", "labels": ["device", "kind"],
+        "help": "device.memory_stats() per local device at scrape time "
+                "(kind=in_use|peak|limit)",
     },
     PREFIX_CACHE_EVICTIONS_TOTAL: {
         "type": "counter", "labels": [],
@@ -830,11 +839,16 @@ CATALOG: dict[str, dict] = {
     },
     DECODE_IMPL: {
         "type": "gauge",
-        "labels": ["attention", "scatter", "kv_dtype", "tp", "variant"],
+        "labels": [
+            "attention", "scatter", "kv_dtype", "tp", "variant",
+            "downgraded", "allocator",
+        ],
         "help": (
             "resolved decode implementation plan (info metric, value 1); "
             "tp = tensor-parallel degree, variant = the PER-SHARD ragged "
-            "kernel formulation actually run"
+            "kernel formulation actually run, downgraded = requested Pallas "
+            "impls that fell back to XLA, allocator = native|python page "
+            "allocator"
         ),
     },
     SPEC_PROPOSED_TOTAL: {

@@ -26,8 +26,8 @@ Triggers (the ``mtpu_incidents_captured_total{trigger}`` label set):
   caught a replica generating tokens that diverge bit-exact from its
   golden transcript; the bundle's reason names the mismatching probe
   request so its trace is findable in the open-trace section.
-- ``stage_failure`` — ``benchmarks/revalidate_chip.sh``'s stage wrapper on
-  any nonzero exit (the next chip wedge ships a bundle, not a shrug).
+- ``stage_failure`` — a script's stage wrapper, on any nonzero exit
+  (``tpurun incidents capture --trigger stage_failure``).
 - ``manual`` — ``tpurun incidents capture``.
 
 Bundles are LRU-bounded like the TraceStore (:data:`MAX_INCIDENTS`,
@@ -369,7 +369,7 @@ def _capture_locked(
 
 
 #: a tmp dir younger than this is a CONCURRENT capture mid-write (two
-#: triggers firing together, or revalidate_chip.sh capturing from another
+#: triggers firing together, or a script capturing from another
 #: process), not an orphan — sweeping it would silently lose that bundle
 _TMP_GRACE_S = 120.0
 

@@ -133,9 +133,11 @@ def set_engine_gauges(
 
 def set_decode_impl(plan: dict, *, registry: Registry | None = None) -> None:
     """Info gauge for the engine's resolved decode plan: the attention /
-    scatter impls, cache dtype, tensor-parallel degree, and the PER-SHARD
-    ragged variant (``paged_impl_plan(mesh=...)``) — so dashboards and
-    benches report the sharded plan actually run, not the requested one."""
+    scatter impls, cache dtype, tensor-parallel degree, the PER-SHARD
+    ragged variant (``paged_impl_plan(mesh=...)``), how many requested
+    Pallas impls were downgraded and which page allocator loaded — so
+    dashboards, benches and ``chip_smoke.py`` report the plan actually run,
+    not the requested one."""
     _reg(registry).gauge_set(
         C.DECODE_IMPL,
         1.0,
@@ -145,6 +147,8 @@ def set_decode_impl(plan: dict, *, registry: Registry | None = None) -> None:
             "kv_dtype": str(plan["kv_dtype"]),
             "tp": str(plan.get("tp", 1)),
             "variant": str(plan.get("ragged_variant") or "-"),
+            "downgraded": str(len(plan.get("downgraded") or ())),
+            "allocator": str(plan.get("allocator") or "-"),
         },
         help=C.CATALOG[C.DECODE_IMPL]["help"],
     )

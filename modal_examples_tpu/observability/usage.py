@@ -22,8 +22,8 @@ gaps with three cooperating pieces:
   reads on the engine's injectable clock), lazily joined with the work
   model into cataloged MFU / MBU / achieved-TFLOP/s gauges per phase and
   a compute-vs-bandwidth bound classification against the
-  ``core/resources.py`` peaks (generation resolved from ``MTPU_TPU_GEN``,
-  default v5e).
+  ``core/resources.py`` peaks (generation from the device; off a TPU from
+  ``MTPU_TPU_GEN``, default v5e).
 - the **usage meter** — per-(tenant, class) buckets (prompt + generated
   tokens, slot device-seconds, KV page-seconds, sheds) updated at the
   SAME sites that update ``EngineStats``, so conservation (Σ tenants ==
@@ -47,11 +47,11 @@ from . import metrics as _obs
 from .canary import CANARY_TENANT
 from .journal import JOURNALS, DecisionJournal, named_journal
 
-#: generation override for peak resolution (one env, read once per engine
-#: at meter construction — the MTPU_KV_DTYPE rule)
+#: off a TPU (CPU tests, dev runs) no device names the chip: this env, read
+#: once per engine at meter construction, picks the denominator
 GENERATION_ENV = "MTPU_TPU_GEN"
-#: the fleet's deploy target; also the honest CPU-run denominator — a CPU
-#: bench reports MFU against the chip it is standing in for
+#: ... and this stands in when it is unset: the fleet's deploy target. A CPU
+#: run's MFU/MBU is a count over a nominal peak, never a device measurement.
 DEFAULT_GENERATION = "v5e"
 
 #: the journal file name under ``<state_dir>`` — owned by the JOURNALS
@@ -60,20 +60,40 @@ USAGE_JOURNAL_NAME = JOURNALS["usage"]
 
 
 def resolve_peaks(generation: str | None = None, chips: int = 1) -> dict:
-    """Peak FLOP/s and HBM bandwidth for the accounting denominator:
-    explicit arg beats :data:`GENERATION_ENV` beats :data:`DEFAULT_GENERATION`;
-    an unknown generation falls back to the default instead of refusing to
-    meter. ``chips`` scales both peaks (tensor parallelism spreads one
-    model's work over the mesh)."""
+    """Peak FLOP/s and HBM bandwidth for the accounting denominator.
+
+    On a TPU backend the generation is the one ``device_kind`` names — the
+    meter never assumes a peak for a device it did not ask about — and a TPU
+    the table does not know is an error. An explicit ``generation`` (tests,
+    offline tools) overrides; off a TPU :data:`GENERATION_ENV` then
+    :data:`DEFAULT_GENERATION` stand in. An unknown name raises. ``chips``
+    scales both peaks (tensor parallelism spreads one model's work over the
+    mesh)."""
     import os
 
-    from ..core.resources import TPU_GENERATIONS, TPU_HBM_GBPS
+    import jax
 
-    gen = (
-        generation or os.environ.get(GENERATION_ENV) or DEFAULT_GENERATION
-    ).lower()
+    from ..core.resources import (
+        DEVICE_KIND_GENERATION,
+        TPU_GENERATIONS,
+        TPU_HBM_GBPS,
+    )
+
+    gen = generation
+    if gen is None and jax.default_backend() == "tpu":
+        kind = jax.devices()[0].device_kind
+        gen = DEVICE_KIND_GENERATION.get(kind)
+        if gen is None:
+            raise ValueError(
+                f"no peaks known for TPU device_kind {kind!r}; add it to "
+                "core/resources.py (DEVICE_KIND_GENERATION, TPU_GENERATIONS, "
+                "TPU_HBM_GBPS) with its published figures"
+            )
+    gen = (gen or os.environ.get(GENERATION_ENV) or DEFAULT_GENERATION).lower()
     if gen not in TPU_GENERATIONS:
-        gen = DEFAULT_GENERATION
+        raise ValueError(
+            f"unknown TPU generation {gen!r}; known: {sorted(TPU_GENERATIONS)}"
+        )
     return {
         "generation": gen,
         "chips": max(1, int(chips)),

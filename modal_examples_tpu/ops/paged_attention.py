@@ -49,25 +49,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 from .kv_quant import QuantizedKV, is_quantized, kv_gather, quantize_kv
 
-# jax renamed TPUCompilerParams -> CompilerParams across releases; accept
-# whichever this jax ships so the ragged kernels work on both
-_CompilerParamsCls = getattr(
-    pltpu, "CompilerParams", getattr(pltpu, "TPUCompilerParams", None)
-)
-
-
-def _CompilerParams(**kw):
-    if _CompilerParamsCls is None:
-        # lazy so a further-renamed class breaks the kernel call with an
-        # actionable message, not package import (the XLA decode path
-        # doesn't need pallas at all)
-        raise RuntimeError(
-            "this jax exposes neither pltpu.CompilerParams nor "
-            "pltpu.TPUCompilerParams; the pallas paged-attention kernels "
-            "cannot compile — use the XLA impls (MTPU_PAGED_IMPL=xla)"
-        )
-    return _CompilerParamsCls(**kw)
-
 
 def _decode_kernel(
     # scalar prefetch
@@ -196,9 +177,10 @@ def _paged_decode_xla(
     qg = q.reshape(B, Hkv, G, D)
     # operands stay in cache dtype INTO the MXU (f32 accumulation via
     # preferred_element_type): an `.astype(f32)` on the gathered pages
-    # materializes an f32 copy of the whole gathered cache in HBM —
-    # measured round 4 (benchmarks/decode_ablate.py) as the dominant,
-    # superlinear-in-slots decode cost (44 of 57 ms/step at 7B, 32 slots)
+    # materializes an f32 copy of the whole gathered cache in HBM — a
+    # builder's round-4 knock-out ablation (not a driver record) put it at
+    # the dominant, superlinear-in-slots decode cost (44 of 57 ms/step at
+    # 7B, 32 slots)
     s = jnp.einsum(
         "bhgd,bpthd->bhgpt", qg, ks, preferred_element_type=jnp.float32
     ) * sm_scale  # [B, Hkv, G, pp, ps] f32
@@ -250,7 +232,7 @@ def paged_decode_attention_inflight(
     qg = q.reshape(B, Hkv, G, D)
     # cache-dtype operands into the MXU, f32 accumulation — an astype(f32)
     # on the gathered pages materializes an f32 cache copy per layer per
-    # step; measured as the dominant decode cost (benchmarks/decode_ablate)
+    # step (the dominant decode cost in the round-4 ablation, see above)
     s = jnp.einsum(
         "bhgd,bpthd->bhgpt", qg, ks, preferred_element_type=jnp.float32
     ) * sm_scale
@@ -296,11 +278,10 @@ def _decode_kernel_ragged(
     v_new_ref,  # (B, Hkv, D) VMEM
     k_hbm,  # (L, n_pages, page_size, Hkv, D) ANY/HBM
     v_hbm,
-    # quantized=True adds ks_hbm/vs_hbm (L, n_pages, page_size, Hkv) f32
-    # scale inputs and ks_scr/vs_scr (depth, page_size, Hkv) scratch rings;
-    # sems widen to (depth, 4). `*rest` keeps ONE kernel for both layouts.
-    *rest,  # [ks_hbm, vs_hbm,] o_ref, k_scr, v_scr, [ks_scr, vs_scr,]
-    # acc_scr, sems
+    # quantized=True adds ks_ref/vs_ref: this sequence's per-page scale rows,
+    # (1, pages_per_seq, page_size*Hkv) f32 VMEM blocks (see
+    # _gathered_scale_rows). `*rest` keeps ONE kernel for both layouts.
+    *rest,  # [ks_ref, vs_ref,] o_ref, k_scr, v_scr, acc_scr, sems
     page_size: int,
     pages_per_seq: int,
     group: int,  # Hq // Hkv
@@ -320,26 +301,25 @@ def _decode_kernel_ragged(
     never slices (= copies) a per-layer cache view. Reads exactly
     ceil(prefix/page_size) pages per sequence — the XLA gather formulation
     reads (and materializes) all pages_per_seq pages regardless of context,
-    measured round 4 as the dominant, superlinear-in-slots decode cost
-    (benchmarks/decode_ablate.py: 44 of 57 ms/step at 7B int8, 32 slots).
+    the dominant, superlinear-in-slots decode cost in a builder's round-4
+    knock-out ablation (44 of 57 ms/step at 7B int8, 32 slots; not a driver
+    record).
 
-    With ``quantized=True`` the pages stream as int8 plus a per-token-head
-    f32 scale row, and the dequant (one bf16 multiply) happens on the VMEM
-    copy right before the MXU — KV HBM traffic is halved, the online
+    With ``quantized=True`` the pages stream as int8 and the per-token-head
+    scales arrive as lane-major rows (one f32 per logit column), so the
+    dequant is a multiply on the (Hq, W) scores and probabilities instead of
+    on every (W, D) page element — KV HBM traffic is halved, the online
     softmax math is unchanged.
     """
     if quantized:
-        (ks_hbm, vs_hbm, o_ref, k_scr, v_scr, ks_scr, vs_scr, acc_scr,
-         sems) = rest
+        ks_ref, vs_ref, o_ref, k_scr, v_scr, acc_scr, sems = rest
     else:
         o_ref, k_scr, v_scr, acc_scr, sems = rest
-        ks_hbm = vs_hbm = ks_scr = vs_scr = None
     b = pl.program_id(0)
     li = layer_ref[0]
     prefix, n_pages, depth, k_dma, v_dma = _ragged_ring_setup(
         li, page_tables_ref, prefix_lens_ref, b, k_hbm, v_hbm, k_scr, v_scr,
-        sems, pages_per_seq, ks_hbm=ks_hbm, vs_hbm=vs_hbm, ks_scr=ks_scr,
-        vs_scr=vs_scr,
+        sems, pages_per_seq,
     )
 
     acc_scr[:] = jnp.zeros_like(acc_scr)
@@ -373,31 +353,25 @@ def _decode_kernel_ragged(
         @pl.when(i + depth - 1 < n_pages)
         def _prefetch():
             nxt = jax.lax.rem(i + depth - 1, depth)
-            for c in k_dma(nxt, i + depth - 1) + v_dma(nxt, i + depth - 1):
-                c.start()
+            k_dma(nxt, i + depth - 1).start()
+            v_dma(nxt, i + depth - 1).start()
 
-        for c in k_dma(slot, i) + v_dma(slot, i):
-            c.wait()
+        k_dma(slot, i).wait()
+        v_dma(slot, i).wait()
+        k = k_scr[slot].reshape(W, D)  # cache dtype, no retile
+        v = v_scr[slot].reshape(W, D)
         if quantized:
-            # dequant at the VMEM load: int8 page * its f32 scale row, one
-            # multiply per element at the query's compute dtype (bf16 on
-            # the serving path — matches the XLA gather fallback)
-            k = (
-                k_scr[slot].astype(q.dtype)
-                * ks_scr[slot][..., None].astype(q.dtype)
-            ).reshape(W, D)
-            v = (
-                v_scr[slot].astype(q.dtype)
-                * vs_scr[slot][..., None].astype(q.dtype)
-            ).reshape(W, D)
-        else:
-            k = k_scr[slot].reshape(W, D)  # cache dtype, no retile
-            v = v_scr[slot].reshape(W, D)
+            # int8 values are exact at the query's compute dtype; their
+            # scales multiply the scores (K) and probabilities (V) below
+            k = k.astype(q.dtype)
+            v = v.astype(q.dtype)
 
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         ) * sm_scale  # (Hq, W) f32
+        if quantized:
+            s = s * ks_ref[0, pl.ds(i, 1), :]  # (1, W): column c's k scale
         valid = head_ok & (i * page_size + col_tok < prefix)
         s = jnp.where(valid, s, -jnp.inf)
 
@@ -409,6 +383,8 @@ def _decode_kernel_ragged(
             jnp.isfinite(m_prev), jnp.exp(m_prev - m_safe), 0.0
         )
         l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        if quantized:
+            p = p * vs_ref[0, pl.ds(i, 1), :]
         pv = jax.lax.dot_general(
             p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -462,13 +438,10 @@ def scatter_shapes_ok(head_dim: int) -> bool:
 
 def _ragged_ring_setup(
     li, page_tables_ref, prefix_lens_ref, b, k_hbm, v_hbm, k_scr, v_scr,
-    sems, pages_per_seq, *, ks_hbm=None, vs_hbm=None, ks_scr=None,
-    vs_scr=None,
+    sems, pages_per_seq,
 ):
     """v3 (flat) DMA-ring prologue: page-id lookup, K/V copy factories,
-    and the warm-up that puts depth-1 page transfers in flight. The copy
-    factories return a LIST of copies: just the page for plain caches, the
-    page plus its f32 scale row for int8 caches (sems columns 2/3). The
+    and the warm-up that puts depth-1 page transfers in flight. The
     grouped kernel streams at CHUNK granularity with clamped page ids and
     owns its own inlined version."""
     prefix = prefix_lens_ref[b]
@@ -479,41 +452,21 @@ def _ragged_ring_setup(
         return page_tables_ref[b * pages_per_seq + i]
 
     def k_dma(slot, i):
-        copies = [
-            pltpu.make_async_copy(
-                k_hbm.at[li, page_id(i)], k_scr.at[slot], sems.at[slot, 0]
-            )
-        ]
-        if ks_hbm is not None:
-            copies.append(
-                pltpu.make_async_copy(
-                    ks_hbm.at[li, page_id(i)], ks_scr.at[slot],
-                    sems.at[slot, 2],
-                )
-            )
-        return copies
+        return pltpu.make_async_copy(
+            k_hbm.at[li, page_id(i)], k_scr.at[slot], sems.at[slot, 0]
+        )
 
     def v_dma(slot, i):
-        copies = [
-            pltpu.make_async_copy(
-                v_hbm.at[li, page_id(i)], v_scr.at[slot], sems.at[slot, 1]
-            )
-        ]
-        if vs_hbm is not None:
-            copies.append(
-                pltpu.make_async_copy(
-                    vs_hbm.at[li, page_id(i)], vs_scr.at[slot],
-                    sems.at[slot, 3],
-                )
-            )
-        return copies
+        return pltpu.make_async_copy(
+            v_hbm.at[li, page_id(i)], v_scr.at[slot], sems.at[slot, 1]
+        )
 
     depth = k_scr.shape[0]
     for j in range(depth - 1):
         @pl.when(j < n_pages)
         def _(j=j):
-            for c in k_dma(j, j) + v_dma(j, j):
-                c.start()
+            k_dma(j, j).start()
+            v_dma(j, j).start()
 
     return prefix, n_pages, depth, k_dma, v_dma
 
@@ -564,10 +517,10 @@ def _decode_kernel_ragged_grouped(
     v_new_ref,  # (B, Hkv, D) VMEM
     k_hbm,  # (L, n_pages, page_size, Hkv, D) ANY/HBM
     v_hbm,
-    # quantized=True adds ks_hbm/vs_hbm scale inputs and ks_scr/vs_scr
-    # scratch (see _decode_kernel_ragged); sems widen to (depth, 4)
-    *rest,  # [ks_hbm, vs_hbm,] o_ref, k_scr, v_scr, [ks_scr, vs_scr,]
-    # acc_scr, sems
+    # quantized=True adds ks_ref/vs_ref: this sequence's per-head scale rows,
+    # (1, Hkv, n_chunks, chunk*page_size) f32 VMEM blocks (see
+    # _gathered_scale_rows)
+    *rest,  # [ks_ref, vs_ref,] o_ref, k_scr, v_scr, acc_scr, sems
     page_size: int,
     pages_per_seq: int,
     group: int,
@@ -597,18 +550,15 @@ def _decode_kernel_ragged_grouped(
       is two half-buffers of `chunk` pages (scratch depth = 2*chunk):
       the next chunk streams while the current one computes.
     The trade: Hkv small matmuls per chunk at G-row MXU utilization.
-    On-chip A/B vs flat: benchmarks/decode_micro.py --variant.
 
-    ``quantized=True`` streams int8 pages + f32 scale rows and dequantizes
-    per-head slices at the VMEM load (one bf16 multiply) — same online
-    softmax, half the KV HBM traffic.
+    ``quantized=True`` streams int8 pages and multiplies each head's scores
+    and probabilities by its lane-major scale row — same online softmax,
+    half the KV HBM traffic.
     """
     if quantized:
-        (ks_hbm, vs_hbm, o_ref, k_scr, v_scr, ks_scr, vs_scr, acc_scr,
-         sems) = rest
+        ks_ref, vs_ref, o_ref, k_scr, v_scr, acc_scr, sems = rest
     else:
         o_ref, k_scr, v_scr, acc_scr, sems = rest
-        ks_hbm = vs_hbm = ks_scr = vs_scr = None
     b = pl.program_id(0)
     li = layer_ref[0]
     prefix = prefix_lens_ref[b]
@@ -631,42 +581,22 @@ def _decode_kernel_ragged_grouped(
         ]
 
     def k_dma(slot, i):
-        copies = [
-            pltpu.make_async_copy(
-                k_hbm.at[li, page_id(i)], k_scr.at[slot], sems.at[slot, 0]
-            )
-        ]
-        if quantized:
-            copies.append(
-                pltpu.make_async_copy(
-                    ks_hbm.at[li, page_id(i)], ks_scr.at[slot],
-                    sems.at[slot, 2],
-                )
-            )
-        return copies
+        return pltpu.make_async_copy(
+            k_hbm.at[li, page_id(i)], k_scr.at[slot], sems.at[slot, 0]
+        )
 
     def v_dma(slot, i):
-        copies = [
-            pltpu.make_async_copy(
-                v_hbm.at[li, page_id(i)], v_scr.at[slot], sems.at[slot, 1]
-            )
-        ]
-        if quantized:
-            copies.append(
-                pltpu.make_async_copy(
-                    vs_hbm.at[li, page_id(i)], vs_scr.at[slot],
-                    sems.at[slot, 3],
-                )
-            )
-        return copies
+        return pltpu.make_async_copy(
+            v_hbm.at[li, page_id(i)], v_scr.at[slot], sems.at[slot, 1]
+        )
 
     # warm-up: chunk 0 into half 0 (every chunk's start has exactly one
     # matching wait in the body: warmup pairs with iteration 0)
     @pl.when(n_chunks > 0)
     def _():
         for j in range(C):
-            for c in k_dma(j, j) + v_dma(j, j):
-                c.start()
+            k_dma(j, j).start()
+            v_dma(j, j).start()
 
     acc_scr[:] = jnp.zeros_like(acc_scr)
     q = q_ref[b]  # (Hq, D) model dtype into the MXU, f32 accumulate
@@ -686,39 +616,47 @@ def _decode_kernel_ragged_grouped(
         @pl.when(i + 1 < n_chunks)
         def _():
             for j in range(C):
-                for c in (
-                    k_dma(nxt_base + j, (i + 1) * C + j)
-                    + v_dma(nxt_base + j, (i + 1) * C + j)
-                ):
-                    c.start()
+                k_dma(nxt_base + j, (i + 1) * C + j).start()
+                v_dma(nxt_base + j, (i + 1) * C + j).start()
         # wait this chunk's pages (all C were started: warmup or prefetch)
         for j in range(C):
-            for c in k_dma(base + j, i * C + j) + v_dma(base + j, i * C + j):
-                c.wait()
+            k_dma(base + j, i * C + j).wait()
+            v_dma(base + j, i * C + j).wait()
 
-        def head_slice(scr, scale_scr, h):
-            """The head's (chunk*ps, D) keys/values, dequantized for int8
-            caches (int8 slice * its (C, ps) scale slice, one multiply at
-            the query's compute dtype)."""
+        def head_slice(scr, h):
+            """The head's (chunk*ps, D) keys/values; int8 values are exact
+            at the query's compute dtype (their scales multiply the scores
+            and probabilities instead)."""
             x = scr[pl.ds(base, C), :, h, :]
             if quantized:
-                x = x.astype(q.dtype) * (
-                    scale_scr[pl.ds(base, C), :, h][..., None]
-                ).astype(q.dtype)
+                x = x.astype(q.dtype)
             return x.reshape(W, D)
+
+        def head_rows(scale_ref):
+            """(Hq, W) scale rows for this chunk: row h*G+g carries kv head
+            h's per-column scales."""
+            return jnp.concatenate(
+                [
+                    jnp.broadcast_to(scale_ref[0, h, pl.ds(i, 1), :], (G, W))
+                    for h in range(Hkv)
+                ],
+                axis=0,
+            )
 
         # per-kv-head: query rows h*G:(h+1)*G against the head's
         # (chunk*ps, D) keys — static head slices, unrolled over Hkv
         s_parts = []
         for h in range(Hkv):
-            k_h = head_slice(k_scr, ks_scr, h)
             s_parts.append(
                 jax.lax.dot_general(
-                    q[h * G : (h + 1) * G], k_h, (((1,), (1,)), ((), ())),
+                    q[h * G : (h + 1) * G], head_slice(k_scr, h),
+                    (((1,), (1,)), ((), ())),
                     preferred_element_type=jnp.float32,
                 )
             )
         s = jnp.concatenate(s_parts, axis=0) * sm_scale  # (Hq, W) f32
+        if quantized:
+            s = s * head_rows(ks_ref)
         s = jnp.where(i * W + col_tok < prefix, s, -jnp.inf)
 
         m_cur = jnp.max(s, axis=-1, keepdims=True)
@@ -729,9 +667,11 @@ def _decode_kernel_ragged_grouped(
             jnp.isfinite(m_prev), jnp.exp(m_prev - m_safe), 0.0
         )
         l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        if quantized:
+            p = p * head_rows(vs_ref)
         pv_parts = []
         for h in range(Hkv):
-            v_h = head_slice(v_scr, vs_scr, h)
+            v_h = head_slice(v_scr, h)
             pv_parts.append(
                 jax.lax.dot_general(
                     p[h * G : (h + 1) * G].astype(v_h.dtype), v_h,
@@ -751,6 +691,33 @@ def _decode_kernel_ragged_grouped(
         q, k_new_ref, v_new_ref, b, o_ref, acc_scr, m_prev, l_prev, group,
         sm_scale,
     )
+
+
+def _gathered_scale_rows(scale, layer, page_tables, variant, chunk):
+    """Each sequence's int8-KV scales as lane-major rows, one row per
+    softmax update of the kernel, gathered by XLA outside it.
+
+    The cache keeps scales as ``[L, P, page_size, Hkv]`` f32. Mosaic cannot
+    DMA a page's ``(page_size, Hkv)`` slab out of that (an HBM slice must
+    cover whole 128-lane tiles of the minor dim, and Hkv is 8..32), and the
+    kernels want the scales along the LOGIT axis anyway — one f32 per score
+    column — so they arrive as an ordinary VMEM-blocked input:
+
+    - ``flat``: ``[B, pages_per_seq, page_size*Hkv]`` — row i is page i's
+      scales in the kernel's column order c = tok*Hkv + head;
+    - ``grouped``: ``[B, Hkv, n_chunks, chunk*page_size]`` — row (h, i) is
+      kv head h's scales for chunk i's columns (page_in_chunk, tok).
+
+    Unlike the pages this reads all ``pages_per_seq`` rows whatever the
+    context; scales are 1/32 of the int8 page bytes at D=128."""
+    B, pp = page_tables.shape
+    ps, Hkv = scale.shape[2:]
+    rows = scale[layer, page_tables]  # [B, pp, ps, Hkv]
+    if variant == "flat":
+        return rows.reshape(B, pp, ps * Hkv)
+    n_chunks = -(-pp // chunk)
+    rows = jnp.pad(rows, ((0, 0), (0, n_chunks * chunk - pp), (0, 0), (0, 0)))
+    return rows.transpose(0, 3, 1, 2).reshape(B, Hkv, n_chunks, chunk * ps)
 
 
 def paged_decode_attention_ragged(
@@ -780,9 +747,10 @@ def paged_decode_attention_ragged(
     and grouped otherwise; pass ``variant=`` explicitly to A/B.
 
     ``k_pages``/``v_pages`` may be int8 :class:`~.kv_quant.QuantizedKV`
-    caches: both variants then DMA the int8 page plus its f32 scale row and
-    dequantize in VMEM — tolerance-accurate vs the f32 cache (the accuracy
-    contract in docs/kv_cache.md), half the KV HBM traffic.
+    caches: both variants then DMA the int8 pages and apply the scales
+    (:func:`_gathered_scale_rows`) to the scores and probabilities —
+    tolerance-accurate vs the f32 cache (the accuracy contract in
+    docs/kv_cache.md), half the KV HBM traffic.
     """
     B, Hq, D = q.shape
     quantized = is_quantized(k_pages)
@@ -816,9 +784,9 @@ def paged_decode_attention_ragged(
             "shape)"
         )
 
-    # int8 caches dequantize to (and fold the in-flight token at) the
-    # query's compute dtype; plain caches keep their own dtype into the
-    # MXU exactly as before (no retile, bit-identical default path)
+    # int8 caches compute at (and fold the in-flight token at) the query's
+    # dtype; plain caches keep their own dtype into the MXU exactly as
+    # before (no retile, bit-identical default path)
     compute_dtype = q.dtype if quantized else k_pages.dtype
     # DMA ring depth: enough in-flight pages to hide issue latency (measured
     # ~2.3 us/page at depth 2), capped so K+V scratch stays ~<=4 MB of VMEM.
@@ -843,12 +811,8 @@ def paged_decode_attention_ragged(
         _const3((B, Hq, D)),
         _const3((B, Hkv, D)),
         _const3((B, Hkv, D)),
-        pl.BlockSpec(memory_space=pltpu.ANY),
-        pl.BlockSpec(memory_space=pltpu.ANY),
-    ]
-    scratch = [
-        pltpu.VMEM((depth, page_size, Hkv, D), k_pages.dtype),
-        pltpu.VMEM((depth, page_size, Hkv, D), v_pages.dtype),
+        pl.BlockSpec(memory_space=pl.ANY),
+        pl.BlockSpec(memory_space=pl.ANY),
     ]
     operands = [
         q,
@@ -856,24 +820,27 @@ def paged_decode_attention_ragged(
         v_new.astype(compute_dtype),
     ]
     if quantized:
-        # int8 data + f32 scale-row inputs; scale scratch rides the same
-        # ring (sems columns 2/3)
+        scale_rows = [
+            _gathered_scale_rows(
+                pages.scale, layer, page_tables, variant, chunk
+            )
+            for pages in (k_pages, v_pages)
+        ]
+        block = (1,) + scale_rows[0].shape[1:]
         in_specs += [
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-        ]
-        scratch += [
-            pltpu.VMEM((depth, page_size, Hkv), jnp.float32),
-            pltpu.VMEM((depth, page_size, Hkv), jnp.float32),
-        ]
-        operands += [
-            k_pages.data, v_pages.data, k_pages.scale, v_pages.scale,
-        ]
+            pl.BlockSpec(
+                block, lambda b, *_refs: (b,) + (0,) * (len(block) - 1),
+                memory_space=pltpu.VMEM,
+            )
+        ] * 2
+        operands += [k_pages.data, v_pages.data, *scale_rows]
     else:
         operands += [k_pages, v_pages]
-    scratch += [
+    scratch = [
+        pltpu.VMEM((depth, page_size, Hkv, D), k_pages.dtype),
+        pltpu.VMEM((depth, page_size, Hkv, D), v_pages.dtype),
         pltpu.VMEM((Hq, D), jnp.float32),
-        pltpu.SemaphoreType.DMA((depth, 4 if quantized else 2)),
+        pltpu.SemaphoreType.DMA((depth, 2)),
     ]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
@@ -903,7 +870,7 @@ def paged_decode_attention_ragged(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hq, D), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
         cost_estimate=pl.CostEstimate(
@@ -928,26 +895,23 @@ def _kv_scatter_kernel(
     # scalar prefetch
     page_idx_ref,  # (B,) int32
     slot_ref,  # (B,) int32
-    # `*refs` (n_arrays is static): n_arrays sources (L, B, ...) ANY, then
-    # n_arrays aliased page inputs, then n_arrays outputs, then DMA sems
-    # (2, n_arrays). n_arrays=2 is the plain k/v cache; int8 caches run
-    # n_arrays=4 with the f32 scale rows as arrays 2/3 ((L, B, Hkv) ->
-    # (L, Hkv) at (page, slot) — the scale travels with its page).
-    *refs,
-    n_arrays: int,
+    k_src,  # (L, B, Hkv, D) ANY/HBM — new K per layer per slot
+    v_src,
+    _k_pages_in,  # aliased to the outputs: the update is in place
+    _v_pages_in,
+    k_out,  # (L, P, page_size, Hkv, D) ANY/HBM
+    v_out,
+    sems,  # DMA sems (2, 2)
 ):
     """One strided HBM->HBM DMA per (slot, array): copies the [L, Hkv, D]
-    column of new KV (and, for int8 caches, its [L, Hkv] scale column) into
-    (page_idx[b], slot[b]) of every layer's pages.
+    column of new KV into (page_idx[b], slot[b]) of every layer's pages.
 
     XLA's scatter for the same update measured 4.8 ms/step at 7B/32 slots
-    (benchmarks/decode_ablate.py) — it rewrites far more than the 33 MB it
-    touches. Dead slots all target trash page 0 slot 0; those writes race
-    harmlessly (the trash page's content is never attended).
+    (a builder's round-4 knock-out ablation, not a driver record) — it
+    rewrites far more than the 33 MB it touches. Dead slots all target
+    trash page 0 slot 0; those writes race harmlessly (the trash page's
+    content is never attended).
     """
-    srcs = refs[:n_arrays]
-    outs = refs[2 * n_arrays : 3 * n_arrays]
-    sems = refs[3 * n_arrays]
     b = pl.program_id(0)
     nb = pl.num_programs(0)
 
@@ -957,9 +921,9 @@ def _kv_scatter_kernel(
         buf = jax.lax.rem(bb, 2)
         return [
             pltpu.make_async_copy(
-                srcs[a].at[:, bb], outs[a].at[:, pid, sl], sems.at[buf, a]
+                src.at[:, bb], out.at[:, pid, sl], sems.at[buf, a]
             )
-            for a in range(n_arrays)
+            for a, (src, out) in enumerate(((k_src, k_out), (v_src, v_out)))
         ]
 
     # two-deep pipeline: start this program's copies, wait the previous
@@ -995,8 +959,10 @@ def scatter_kv_pages(
     slots (all pointed at trash page 0) may race, which is harmless.
 
     int8 caches quantize HERE (per token-head amax/127, fused by XLA into
-    the producing program) and scatter four arrays — int8 K/V columns plus
-    their f32 scale columns — through the same DMA pipeline."""
+    the producing program). The int8 K/V columns go through the DMA
+    pipeline; their f32 scale columns ([L, Hkv] per slot — a minor dim far
+    below the 128 lanes a Mosaic DMA slice must cover) take the XLA scatter,
+    which for an array 1/32 the size of the pages is the cheap part."""
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     quantized = is_quantized(k_pages)
@@ -1010,8 +976,8 @@ def scatter_kv_pages(
         )
     if quantized:
         qk, qv = quantize_kv(k_all), quantize_kv(v_all)
-        srcs = [qk.data, qv.data, qk.scale, qv.scale]
-        pages = [k_pages.data, v_pages.data, k_pages.scale, v_pages.scale]
+        srcs = [qk.data, qv.data]
+        pages = [k_pages.data, v_pages.data]
     else:
         srcs = [k_all.astype(k_pages.dtype), v_all.astype(v_pages.dtype)]
         pages = [k_pages, v_pages]
@@ -1022,24 +988,23 @@ def scatter_kv_pages(
         # Hkv, D] lines up with k_all directly.
         outs = [p.at[:, page_idx, slot].set(s) for p, s in zip(pages, srcs)]
     else:
-        n = len(pages)
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(B,),
-            in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)] * (2 * n),
-            out_specs=[pl.BlockSpec(memory_space=pltpu.ANY)] * n,
-            scratch_shapes=[pltpu.SemaphoreType.DMA((2, n))],
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * 4,
+            out_specs=[pl.BlockSpec(memory_space=pl.ANY)] * 2,
+            scratch_shapes=[pltpu.SemaphoreType.DMA((2, 2))],
         )
         outs = pl.pallas_call(
-            functools.partial(_kv_scatter_kernel, n_arrays=n),
+            _kv_scatter_kernel,
             grid_spec=grid_spec,
             out_shape=[
                 jax.ShapeDtypeStruct(p.shape, p.dtype) for p in pages
             ],
-            # +2 for the two scalar-prefetch operands, +n for the sources:
-            # alias the page arrays through so the update is in place
-            input_output_aliases={2 + n + a: a for a in range(n)},
-            compiler_params=_CompilerParams(
+            # operands: 2 scalar-prefetch, 2 sources, then the page arrays —
+            # aliased through so the update is in place
+            input_output_aliases={4: 0, 5: 1},
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("arbitrary",),
             ),
             interpret=interpret,
@@ -1051,8 +1016,14 @@ def scatter_kv_pages(
         )
     if quantized:
         return (
-            QuantizedKV(data=outs[0], scale=outs[2]),
-            QuantizedKV(data=outs[1], scale=outs[3]),
+            QuantizedKV(
+                data=outs[0],
+                scale=k_pages.scale.at[:, page_idx, slot].set(qk.scale),
+            ),
+            QuantizedKV(
+                data=outs[1],
+                scale=v_pages.scale.at[:, page_idx, slot].set(qv.scale),
+            ),
         )
     return outs[0], outs[1]
 
@@ -1098,8 +1069,8 @@ def paged_decode_attention(
     # merging padded tiles relayouts). Sub-tile shapes (tiny/test models,
     # GQA) take the XLA path regardless of impl. int8 (QuantizedKV) caches
     # also take the XLA path here — _paged_decode_xla dequantizes in its
-    # gather; only the v3/v4 ragged kernels have the int8 Mosaic bring-up
-    # (this legacy write-then-attend kernel is the decode_micro A/B lever).
+    # gather; only the v3/v4 ragged kernels take int8 caches (this legacy
+    # write-then-attend kernel is an A/B lever, ROADMAP D3).
     if (
         impl != "pallas"
         or is_quantized(k_pages)
@@ -1117,8 +1088,8 @@ def paged_decode_attention(
                 (1, Hq, D), lambda b, *_refs: (b, 0, 0),
                 memory_space=pltpu.VMEM,
             ),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=pl.BlockSpec(
             (1, Hq, D), lambda b, *_refs: (b, 0, 0),
@@ -1142,7 +1113,7 @@ def paged_decode_attention(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hq, D), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             # each sequence reads shared pages but writes a distinct output
             # block: the grid is safely parallel
             dimension_semantics=("parallel",),

@@ -32,8 +32,6 @@ from jax import lax
 
 from .flash_attention import flash_attention_with_lse
 from ..parallel.collectives import axis_size as _axis_size
-from ..parallel.mesh import shard_map_compat
-
 
 
 def _merge(o1, lse1, o2, lse2):
@@ -123,7 +121,7 @@ def ring_attention_sharded(
     fn = functools.partial(
         ring_attention, axis_name=seq_axis, causal=causal, sm_scale=sm_scale
     )
-    return shard_map_compat(
+    return jax.shard_map(
         fn, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
         check_vma=False,
     )(q, k, v)
@@ -183,7 +181,7 @@ def ulysses_attention_sharded(
     fn = functools.partial(
         ulysses_attention, axis_name=seq_axis, causal=causal, sm_scale=sm_scale
     )
-    return shard_map_compat(
+    return jax.shard_map(
         fn, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
         check_vma=False,
     )(q, k, v)

@@ -45,9 +45,10 @@ instead.
 
 from __future__ import annotations
 
+import jax
 from jax.sharding import PartitionSpec as P
 
-from ..parallel.mesh import TENSOR, shard_map_compat
+from ..parallel.mesh import TENSOR
 from .flash_attention import flash_attention, flash_attention_chunked
 from .kv_quant import QuantizedKV, is_quantized
 from .paged_attention import (
@@ -164,7 +165,7 @@ def sharded_ragged_decode(
             sm_scale=sm_scale, variant=variant, interpret=interpret,
         )
 
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(
@@ -172,6 +173,7 @@ def sharded_ragged_decode(
             heads, heads,
         ),
         out_specs=heads,
+        check_vma=False,
     )
     return fn(
         q, *flatten(k_pages), *flatten(v_pages), layer, page_tables,
@@ -217,13 +219,14 @@ def sharded_scatter_kv_pages(
         )
         return tuple(flatten(ok)) + tuple(flatten(ov))
 
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(
             *pg_specs, *pg_specs, new_kv, new_kv, P(None), P(None),
         ),
         out_specs=tuple(pg_specs) + tuple(pg_specs),
+        check_vma=False,
     )
     out = fn(
         *flatten(k_pages), *flatten(v_pages), k_all, v_all, page_idx, slot
@@ -250,11 +253,12 @@ def sharded_flash_attention(
         return flash_attention(q, k, v, causal)
     _check_heads(tp, [("n_heads", q.shape[1]), ("n_kv_heads", k.shape[1])])
     heads = P(None, axis, None, None)
-    return shard_map_compat(
+    return jax.shard_map(
         lambda q, k, v: flash_attention(q, k, v, causal),
         mesh=mesh,
         in_specs=(heads, heads, heads),
         out_specs=heads,
+        check_vma=False,
     )(q, k, v)
 
 
@@ -275,11 +279,12 @@ def sharded_flash_attention_chunked(
         return flash_attention_chunked(q, k, v, q_offset=q_offset)
     _check_heads(tp, [("n_heads", q.shape[1]), ("n_kv_heads", k.shape[1])])
     heads = P(None, axis, None, None)
-    return shard_map_compat(
+    return jax.shard_map(
         lambda q, k, v: flash_attention_chunked(q, k, v, q_offset=q_offset),
         mesh=mesh,
         in_specs=(heads, heads, heads),
         out_specs=heads,
+        check_vma=False,
     )(q, k, v)
 
 
@@ -319,9 +324,10 @@ def sharded_paged_decode_attention(
         tables, lens = rest[2 * n_pg :]
         return paged_decode_attention(q, kp, vp, tables, lens, impl=impl)
 
-    return shard_map_compat(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(heads, *pg_specs, *pg_specs, P(None, None), P(None)),
         out_specs=heads,
+        check_vma=False,
     )(q, *flatten(k_pages), *flatten(v_pages), page_tables, context_lens)

@@ -34,23 +34,6 @@ EXPERT = "expert"
 AXIS_ORDER = (DATA, FSDP, EXPERT, SEQ, TENSOR)
 
 
-def shard_map_compat(fn, *, mesh, in_specs, out_specs, check_vma=False):
-    """``jax.shard_map`` across the rename: new jax exposes it top-level
-    with ``check_vma``; 0.4.x ships ``jax.experimental.shard_map`` with the
-    same knob spelled ``check_rep``. One wrapper so kernels never branch."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check_vma,
-        )
-    from jax.experimental.shard_map import shard_map as _sm
-
-    return _sm(
-        fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=bool(check_vma),
-    )
-
-
 def resolve_axes(
     axes: dict[str, int] | None, n_devices: int
 ) -> dict[str, int]:

@@ -30,6 +30,9 @@ import jax.numpy as jnp
 
 from ..observability import metrics as _obs
 from ..ops.kv_quant import is_quantized, kv_dtype_name, kv_empty
+from ..utils.log import get_logger
+
+_log = get_logger("kv_cache")
 
 
 class OutOfPages(RuntimeError):
@@ -129,8 +132,8 @@ class PagedKVCache:
                 from ..native import NativePageAllocator
 
                 allocator = NativePageAllocator(n_pages)
-            except Exception:
-                allocator = None
+            except Exception as e:
+                _log.warning("page allocator: python fallback (%s)", e)
         return cls(
             k_pages=kv_empty(shape, kv_dtype),
             v_pages=kv_empty(shape, kv_dtype),
@@ -141,6 +144,14 @@ class PagedKVCache:
     @property
     def n_pages(self) -> int:
         return self.k_pages.shape[1]
+
+    @property
+    def allocator_impl(self) -> str:
+        """Which page allocator this cache loaded: "native" (the C++ free
+        list) or "python"."""
+        if isinstance(self.allocator, PageAllocator):
+            return "python"
+        return "native"
 
     @property
     def kv_dtype(self) -> str:
@@ -185,7 +196,7 @@ class PagedKVCache:
 
 # a jax pytree (device leaves: k/v pages — 2 for bf16, 4 for int8 with the
 # scale arrays riding alongside) so tree utilities (jax.tree.leaves,
-# utils.sync.force, snapshot codecs) see the device state. The leaf set is
+# jax.block_until_ready, snapshot codecs) see the device state. The leaf set is
 # also the WIRE CONTRACT of disaggregated serving: the KV-page transport
 # (serving/disagg/transport.wire_leaves) enumerates these leaves by tree
 # flattening and ships every one per migrated page, with every leaf's page

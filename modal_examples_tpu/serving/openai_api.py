@@ -221,8 +221,24 @@ class _Handler(BaseHTTPRequestHandler):
                     f'"{eng.impl_plan["scatter"]}",kv_dtype='
                     f'"{eng.impl_plan["kv_dtype"]}",tp='
                     f'"{eng.impl_plan.get("tp", 1)}",variant='
-                    f'"{eng.impl_plan.get("ragged_variant") or "-"}"}} 1'
+                    f'"{eng.impl_plan.get("ragged_variant") or "-"}",'
+                    f'downgraded="{len(eng.impl_plan["downgraded"])}",'
+                    f'allocator="{eng.impl_plan["allocator"]}"}} 1'
                 )
+            import jax
+
+            for dev in jax.local_devices():
+                stats = dev.memory_stats() or {}  # the CPU reports none
+                for kind, key in (
+                    ("in_use", "bytes_in_use"),
+                    ("peak", "peak_bytes_in_use"),
+                    ("limit", "bytes_limit"),
+                ):
+                    if key in stats:
+                        lines.append(
+                            f'{_C.DEVICE_MEMORY_BYTES}{{device="{dev.id}",'
+                            f'kind="{kind}"}} {stats[key]}'
+                        )
             body = ("\n".join(lines) + "\n" + reg_text).encode()
             self.send_response(200)
             self.send_header("content-type", "text/plain; version=0.0.4")

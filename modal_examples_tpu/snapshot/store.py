@@ -10,9 +10,8 @@ change what that state looks like:
 - the **env fingerprint** (the container env the spec resolves: image env +
   secrets + TPU spec),
 - the **cls-params hash** (``modal.parameter`` overrides), and
-- the host **CPU machine tag** (utils/compile_cache.py ``_machine_tag``) —
-  captured arrays and the compile-cache entries they pair with are only valid
-  on the microarch that produced them.
+- the host **architecture** (``platform.machine()``) — a pickled payload is
+  not carried between instruction sets.
 
 Layout: one directory per key under the store root (default
 ``<state_dir>/snapshots``, override with ``MTPU_SNAPSHOT_DIR`` — point it at a
@@ -30,6 +29,7 @@ from __future__ import annotations
 import hashlib
 import json
 import os
+import platform
 import shutil
 import time
 import uuid
@@ -37,7 +37,6 @@ from pathlib import Path
 
 from .._internal import config as _config
 from ..observability import metrics as _obs
-from ..utils.compile_cache import _machine_tag
 
 _DISABLED = ("0", "off", "none")
 
@@ -79,14 +78,14 @@ def compute_snapshot_key(
     source_hash: str,
     env: dict[str, str] | None = None,
     cls_params: bytes | None = None,
-    machine_tag: str | None = None,
+    host_arch: str | None = None,
 ) -> str:
     env_fp = hashlib.sha256(
         json.dumps(sorted((env or {}).items())).encode()
     ).hexdigest()
     params_fp = hashlib.sha256(cls_params or b"").hexdigest()
     blob = "|".join([image_digest, source_hash, env_fp, params_fp])
-    tag = machine_tag or _machine_tag()
+    tag = host_arch or platform.machine()
     return f"{tag}-{hashlib.sha256(blob.encode()).hexdigest()[:24]}"
 
 

@@ -100,13 +100,13 @@ METRICS: list[tuple[str, bool, str]] = [
 #: diffed against a TPU run (or two different chips) produces nonsense
 #: verdicts for every hardware-relative metric, so the diff refuses
 #: instead of printing a table that looks authoritative.
-IDENTITY_KEYS = ("backend", "chip_note")
+IDENTITY_KEYS = ("backend",)
 
 
 def identity_mismatches(old: dict, new: dict) -> list[str]:
     """Human-readable identity disagreements between two bench jsons.
     Keys absent from either side are not mismatches (older files predate
-    ``chip_note``); only a present-and-different value disqualifies."""
+    them); only a present-and-different value disqualifies."""
     out = []
     for key in IDENTITY_KEYS:
         ov, nv = old.get(key), new.get(key)

@@ -1,20 +1,18 @@
-"""Persistent XLA compile cache — the framework-level cold-start lever.
+"""Where the persistent XLA compile cache lives: one rule, one place.
 
-The reference's serving example leans on engine AOT caches and FAST_BOOT
-(vllm_inference.py:79-101: cached torch.compile / CUDA graphs on volumes);
-the TPU analog is XLA's persistent compilation cache. Round-2 measurement:
-llama2-7b engine boot paid 41.5 s build + 62.6 s compile on every start.
-With this cache warm, recompiles become disk hits.
+Cold start is weights-to-HBM plus XLA compilation, and a warm persistent
+cache turns the second into disk reads. The cache directory is part of every
+entry's key, so it must not move between runs:
 
-Wired in by default at the three places compiles happen:
-- ``LLMEngine.__init__`` (serving),
-- the executor's containers (via ``JAX_COMPILATION_CACHE_DIR`` in the child
-  env — jax reads it natively, and ``core`` stays jax-free),
-- ``bench.py`` children.
+- ``JAX_COMPILATION_CACHE_DIR`` set: JAX reads it natively and nothing in
+  this repo sets or overrides a directory.
+- unset: the entry points (``tpurun``, ``bench.py``, ``chip_smoke.py``) call
+  :func:`place_compile_cache` before anything imports JAX. It exports the
+  one fixed, git-ignored directory inside the checkout through that same
+  variable, so this process and every container it spawns agree on it.
 
-Opt out with ``MTPU_COMPILE_CACHE=0``; point somewhere else (e.g. a Volume
-mount, as examples/06_gpu_and_ml/tpu_snapshot.py does) with
-``MTPU_COMPILE_CACHE=/path``.
+Library code (``LLMEngine``, the executor, examples, tests) never enables or
+places the cache.
 """
 
 from __future__ import annotations
@@ -22,77 +20,21 @@ from __future__ import annotations
 import os
 from pathlib import Path
 
-_DISABLED = ("0", "off", "none")
+CACHE_DIR_ENV = "JAX_COMPILATION_CACHE_DIR"
+
+#: the fixed fallback: no host fingerprint, pid, time or temporary name
+DEFAULT_CACHE_DIR = Path(__file__).resolve().parents[2] / ".xla_cache"
 
 
-import functools
-
-
-@functools.lru_cache(maxsize=1)
-def _machine_tag() -> str:
-    """Short fingerprint of the host CPU. XLA:CPU AOT entries bake in the
-    compile machine's feature set; loading them on a different microarch
-    logs 'could lead to execution errors such as SIGILL' per entry (seen
-    when this image migrated hosts between rounds). Segmenting the default
-    cache dir by CPU features keeps foreign AOT results out. Covers x86
-    ('flags', 'model name') and arm ('Features', 'CPU part') cpuinfo keys."""
-    import hashlib
-    import platform
-
-    parts = set()
-    try:
-        with open("/proc/cpuinfo") as f:
-            for line in f:
-                key = line.split(":", 1)[0].strip()
-                if key in ("flags", "Features", "model name", "CPU part"):
-                    parts.add(" ".join(line.split(":", 1)[1].split()))
-    except OSError:
-        pass
-    return hashlib.md5(
-        (platform.machine() + ":" + "|".join(sorted(parts))).encode()
-    ).hexdigest()[:8]
-
-
-def cache_dir() -> str | None:
-    """The resolved cache directory, or None when disabled."""
-    env = os.environ.get("MTPU_COMPILE_CACHE", "")
-    if env.lower() in _DISABLED:
-        return None
-    if env:
-        return env
-    return str(
-        Path.home() / ".cache" / "modal_examples_tpu"
-        / f"xla-cache-{_machine_tag()}"
-    )
-
-
-def enable_compile_cache(path: str | None = None) -> str | None:
-    """Idempotently enable the persistent XLA compile cache.
-
-    Returns the cache dir in use, or None when disabled. Safe to call
-    before or after backend init; entries are keyed by HLO + compile flags,
-    so CPU and TPU runs coexist in one directory.
-
-    A cache dir the user already configured via ``jax.config`` directly is
-    respected (ADVICE r3): only an explicit ``path=`` argument or
-    ``MTPU_COMPILE_CACHE`` env overrides it; the built-in default never does.
-    """
-    import jax
-
-    explicit = path is not None or bool(os.environ.get("MTPU_COMPILE_CACHE"))
-    path = path or cache_dir()
-    if path is None:
-        return None
-    current = getattr(jax.config, "jax_compilation_cache_dir", None)
-    if current and not explicit:
-        return current
-    try:
-        Path(path).mkdir(parents=True, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", path)
-        # default thresholds skip small-but-hot entries; the engine's decode
-        # block alone is worth caching regardless of its compile seconds
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-    except Exception:
-        return None
+def place_compile_cache() -> str:
+    """Return the compile-cache directory, exporting the in-checkout default
+    when the environment names none. Jax-free; call before importing JAX
+    (JAX reads the variables once, at import)."""
+    path = os.environ.get(CACHE_DIR_ENV)
+    if not path:
+        path = os.environ[CACHE_DIR_ENV] = str(DEFAULT_CACHE_DIR)
+    # keep every program, however fast it compiled: under JAX's default
+    # (keep what took >= 1 s) a program near the threshold is written on
+    # some runs and not others, and a warm run still reports misses
+    os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0")
     return path

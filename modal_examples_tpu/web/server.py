@@ -35,11 +35,14 @@ class ServerHandle:
         return self.cfg["port"]
 
     def serve(self, wait_ready: bool = True) -> str:
-        """Boot one replica (runs @enter hooks, which start the server)."""
+        """Boot one replica (runs @enter hooks, which start the server).
+
+        Raises the container's own boot error as soon as it happens (a
+        failed @enter, a refused TPU lease, a crash) instead of waiting out
+        ``startup_timeout``."""
         if self._obj is None:
             self._obj = self._cls()
-            # Booting = creating the pool with a warm container. Submitting a
-            # no-op readiness method forces container boot + enter hooks.
+            # Booting = creating the pool with a warm container.
             pool = self._obj._pool()
             if hasattr(pool, "_ensure_target"):  # inline backend
                 pool._ensure_target()
@@ -48,14 +51,18 @@ class ServerHandle:
                 pool._autoscale(time.monotonic())
         url = f"http://127.0.0.1:{self.port}"
         if wait_ready:
-            ok = wait_for_port(
-                "127.0.0.1", self.port, self.cfg.get("startup_timeout", 60.0)
-            )
-            if not ok:
-                raise TimeoutError(
-                    f"server on port {self.port} not ready after "
-                    f"{self.cfg.get('startup_timeout', 60.0)}s"
-                )
+            pool = self._obj._pool()
+            timeout = self.cfg.get("startup_timeout", 60.0)
+            deadline = time.monotonic() + timeout
+            while not wait_for_port("127.0.0.1", self.port, 0.5):
+                boot_error = getattr(pool, "boot_error", None)
+                if boot_error is not None:
+                    raise boot_error
+                if time.monotonic() > deadline:
+                    raise TimeoutError(
+                        f"server on port {self.port} not ready after "
+                        f"{timeout}s"
+                    )
         registry.publish(self._cls._spec.tag, url)
         return url
 

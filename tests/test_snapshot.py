@@ -145,8 +145,8 @@ class TestKey:
     )
 
     def test_deterministic(self):
-        k1 = compute_snapshot_key(machine_tag="mt", **self.BASE)
-        k2 = compute_snapshot_key(machine_tag="mt", **self.BASE)
+        k1 = compute_snapshot_key(host_arch="mt", **self.BASE)
+        k2 = compute_snapshot_key(host_arch="mt", **self.BASE)
         assert k1 == k2
 
     @pytest.mark.parametrize(
@@ -159,14 +159,14 @@ class TestKey:
         ],
     )
     def test_every_component_changes_key(self, field, value):
-        base = compute_snapshot_key(machine_tag="mt", **self.BASE)
+        base = compute_snapshot_key(host_arch="mt", **self.BASE)
         changed = compute_snapshot_key(
-            machine_tag="mt", **{**self.BASE, field: value}
+            host_arch="mt", **{**self.BASE, field: value}
         )
         assert base != changed
 
-    def test_machine_tag_prefix(self):
-        key = compute_snapshot_key(machine_tag="cafe1234", **self.BASE)
+    def test_host_arch_prefix(self):
+        key = compute_snapshot_key(host_arch="cafe1234", **self.BASE)
         assert key.startswith("cafe1234-")
 
     def test_source_hash_tracks_code(self):

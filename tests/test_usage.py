@@ -109,12 +109,32 @@ class TestResolvePeaks:
         monkeypatch.delenv(us.GENERATION_ENV)
         assert us.resolve_peaks()["generation"] == us.DEFAULT_GENERATION
 
-    def test_unknown_generation_falls_back_and_chips_scale(self):
-        p = us.resolve_peaks("tpu9000", chips=4)
-        assert p["generation"] == us.DEFAULT_GENERATION
+    def test_unknown_generation_raises_and_chips_scale(self, monkeypatch):
+        with pytest.raises(ValueError, match="tpu9000"):
+            us.resolve_peaks("tpu9000")
+        monkeypatch.setenv(us.GENERATION_ENV, "tpu9000")
+        with pytest.raises(ValueError, match="tpu9000"):
+            us.resolve_peaks()
+        p = us.resolve_peaks("v5e", chips=4)
         assert p["chips"] == 4
-        assert p["tflops_per_chip"] > 0
-        assert p["hbm_gbps_per_chip"] > 0
+        # published per-chip peaks (Google Cloud "TPU v5e" documentation)
+        assert p["tflops_per_chip"] == 197.0
+        assert p["hbm_gbps_per_chip"] == 819.0
+
+    def test_on_a_tpu_the_device_kind_decides(self, monkeypatch):
+        import jax
+
+        class _Dev:
+            def __init__(self, kind):
+                self.device_kind = kind
+
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        monkeypatch.setenv(us.GENERATION_ENV, "v4")  # ignored on a TPU
+        monkeypatch.setattr(jax, "devices", lambda: [_Dev("TPU v6 lite")])
+        assert us.resolve_peaks()["generation"] == "v6e"
+        monkeypatch.setattr(jax, "devices", lambda: [_Dev("TPU v9 mega")])
+        with pytest.raises(ValueError, match="TPU v9 mega"):
+            us.resolve_peaks()
 
 
 # ---------------------------------------------------------------------------
@@ -520,11 +540,10 @@ class TestBenchDiffIdentity:
         assert bd.identity_mismatches(
             {"backend": "cpu"}, {"backend": "tpu"}
         ) == ["backend: 'cpu' != 'tpu'"]
-        # absent keys never disqualify: older files predate chip_note
+        # absent keys never disqualify: older files predate them
         assert bd.identity_mismatches({}, {"backend": "tpu"}) == []
         assert bd.identity_mismatches(
-            {"backend": "tpu", "chip_note": "wedged"},
-            {"backend": "tpu", "chip_note": "wedged"},
+            {"backend": "tpu"}, {"backend": "tpu"}
         ) == []
 
     def test_run_diff_refuses_cross_hardware_compare(self, tmp_path, capsys):
