@@ -80,7 +80,7 @@ CONFIGS = {
         # the >=40-slot compile ceiling repro (ROADMAP S7): the round-4
         # sweep saw compiles fail somewhere past ~40 slots. NOT in the
         # supervisor's default order — run it by name (BENCH_MODEL) with
-        # MTPU_PROFILE=1 and a local MTPU_STATE_DIR: the profiler
+        # a local MTPU_STATE_DIR: the profiler
         # writes a `begin` ledger event BEFORE each program build, so even
         # when this run dies mid-compile the ledger's begin-without-end
         # row names exactly which program/shape hit the ceiling —
@@ -1310,13 +1310,12 @@ def _child(model: str) -> None:
     # bench-with-tracing deliberately; `tpurun benchdiff` then shows what
     # the instrumentation costs.
     os.environ.setdefault("MTPU_TRACE_SAMPLE", "0")
-    # bench configs OPT IN to the hot-path profiler (the one explicit env,
-    # resolved once in LLMEngine.__init__ — docs/observability.md): every
-    # BENCH json carries an `overhead` section (host fraction, per-phase
-    # tick p50/p95, compile totals), and the compile ledger captures every
-    # program build. MTPU_PROFILE=0 in the environment still wins, so the
-    # instrumentation cost itself stays A/B-able via `tpurun benchdiff`.
-    os.environ.setdefault("MTPU_PROFILE", "1")
+    # the hot-path profiler is on unless MTPU_PROFILE=0 (resolved once in
+    # LLMEngine.__init__ — docs/observability.md): every BENCH json carries
+    # an `overhead` section (host fraction, per-phase tick p50/p95, compile
+    # totals), and the compile ledger captures every program build. With
+    # MTPU_PROFILE=0 the instrumentation cost itself is A/B-able via
+    # `tpurun benchdiff`.
     # ... and to the flight recorder (docs/observability.md#metrics-history):
     # the engine starts the tsdb sampler once, so every bench run leaves a
     # replayable metrics history under <state_dir>/tsdb/ and the `overhead`
@@ -1501,8 +1500,8 @@ def _child(model: str) -> None:
         }
 
     phase_latency = {}
-    for phase in ("prefill", "prefill_chunked", "decode_wait"):
-        q = _q(C.ENGINE_PHASE_SECONDS, {"phase": phase})
+    for phase in (*C.TICK_PHASES, C.TICK_TOTAL_PHASE):
+        q = _q(C.TICK_PHASE_SECONDS, {"phase": phase})
         if q:
             phase_latency[phase] = q
     for key, name in (
@@ -1548,7 +1547,7 @@ def _child(model: str) -> None:
     # snapshotted HERE, with the other latency sections and before the
     # interference/fleet/failover A/Bs, so the headline attribution
     # reflects the measured traffic rather than the deliberately-degraded
-    # A/B arms. Children run MTPU_PROFILE=1 by default, so every config's
+    # A/B arms. The profiler is on by default, so every config's
     # json carries the section; benchdiff gates overhead.host_fraction and
     # overhead.tick_p95 round over round.
     overhead = None

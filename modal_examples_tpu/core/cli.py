@@ -561,21 +561,123 @@ def cmd_benchdiff(argv: list[str]) -> int:
     return run_diff(argv)
 
 
+def _profile_xplane(argv: list[str]) -> int:
+    """``tpurun profile --xplane PATH [--json]``: the operator's reader of
+    what the program writes into a device trace: the hot-path profiler's
+    spans (idle time by scheduler phase) and the ``jax.named_scope``s of
+    the model programs (device time by part of the model)."""
+    import glob
+    import os
+
+    from ..observability import xplane as _xp
+
+    i = argv.index("--xplane")
+    if i + 1 >= len(argv):
+        print("usage: tpurun profile --xplane <trace dir | file.xplane.pb> [--json]")
+        return 2
+    path = argv[i + 1]
+    if os.path.isdir(path):
+        found = sorted(
+            glob.glob(os.path.join(path, "**", "*.xplane.pb"), recursive=True),
+            key=os.path.getmtime,
+        )
+        if not found:
+            print(f"no .xplane.pb under {path}")
+            return 1
+        path = found[-1]
+    from jax.profiler import ProfileData  # the one JAX import of this CLI
+
+    data = ProfileData.from_file(path)
+    with open(path, "rb") as f:
+        op_scopes = _xp.op_scopes(f.read())
+    scopes: dict[str, dict] = {}
+    chips: dict[str, list] = {}
+    spans: list[tuple[str, float, float]] = []
+    dispatches: dict[str, int] = {}
+    for plane in data.planes:
+        if plane.name.startswith("/device:TPU:"):
+            lines = {line.name: line for line in plane.lines}
+            # as the benchmark's reduction: operations, else whole programs
+            line = lines.get("XLA Ops") or lines.get("XLA Modules")
+            if line is None:
+                continue
+            events = [
+                ev for ev in line.events
+                # a loop or a branch only contains others: not time of its own
+                if not _xplane_container(ev.name)
+            ]
+            if events:
+                chips[plane.name] = [
+                    (ev.start_ns * 1e-9, (ev.start_ns + ev.duration_ns) * 1e-9)
+                    for ev in events
+                ]
+            if line.name == "XLA Ops":
+                _xp.time_by_scope(
+                    [(ev.name, ev.duration_ns * 1e-9) for ev in events],
+                    op_scopes.get(plane.name, {}), scopes,
+                )
+        elif plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for ev in line.events:
+                    name = ev.name
+                    if name.startswith(_xp.TICK_PREFIX):
+                        t0 = ev.start_ns * 1e-9
+                        spans.append((
+                            name[len(_xp.TICK_PREFIX):], t0,
+                            t0 + ev.duration_ns * 1e-9,
+                        ))
+                    elif name.startswith(_xp.DISPATCH_PREFIX):
+                        key = name[len(_xp.DISPATCH_PREFIX):]
+                        dispatches[key] = dispatches.get(key, 0) + 1
+    report = _xp.reduce_chips(chips, spans)
+    report["dispatches"] = dispatches
+    if set(scopes) - {_xp.UNSCOPED}:  # a program with no named scope: no table
+        report["scopes"] = scopes
+    report["file"] = path
+    if "--json" in argv:
+        print(json.dumps(report))
+    else:
+        print("\n".join(_xp.render(report)))
+    return 0
+
+
+def _xplane_container(op_name: str) -> bool:
+    """An HLO operation that only contains others (``while``,
+    ``conditional``, ``call``): the rule of the benchmark's
+    ``trace_reduce.describe_op``, so both count the same busy time."""
+    import re
+
+    m = re.match(
+        r"^%?([\w.\-]+) = \(?([a-z0-9]+\[[0-9,]*\])?.*?\)? ?([a-z\-]+)\(",
+        op_name,
+    )
+    return bool(m) and m.group(3) in ("while", "conditional", "call")
+
+
 def cmd_profile(argv: list[str]) -> int:
     """Hot-path time attribution (docs/observability.md#hot-path-profiling):
     the scheduler-tick phase table (p50/p95 per catalog.TICK_PHASES entry),
     the host-vs-device overhead fraction, and the compile ledger's biggest
     builds — from the pushed metrics files plus
-    ``<state_dir>/compiles.jsonl``. Engines emit these series only under
-    ``MTPU_PROFILE`` (bench configs opt in), so an empty table means no
-    profiled engine has pushed yet. jax-free by construction.
+    ``<state_dir>/compiles.jsonl``. Engines emit these series unless
+    ``MTPU_PROFILE=0``, so an empty table means no engine has pushed yet.
+    jax-free, but for ``--xplane``.
 
     profile [N]        — phase table + top N ledger compiles (default 10)
     profile --json     — the machine-readable payload
+    profile --xplane P — device busy/idle of a profiler trace (a directory
+                         ``jax.profiler.start_trace`` wrote, or one
+                         ``.xplane.pb``), the idle time by scheduler
+                         phase, from the ``mtpu.tick/*`` events in it,
+                         and the device time by ``mtpu.*`` named scope;
+                         imports JAX to read the file
     ``--dir PATH`` overrides the state-dir root (``metrics/`` +
     ``compiles.jsonl`` live under it).
     """
     from pathlib import Path
+
+    if "--xplane" in argv:
+        return _profile_xplane(argv)
 
     from ..observability import catalog as C
     from ..observability import profiler as _prof
@@ -707,7 +809,7 @@ def cmd_profile(argv: list[str]) -> int:
     else:
         print(
             "no tick-phase series in pushed metrics "
-            "(run a bench or an engine with MTPU_PROFILE=1 first)"
+            "(no engine has pushed yet, or it ran under MTPU_PROFILE=0)"
         )
     if lookups:
         print("\ncompile-cache lookups per program (miss=fresh build):")

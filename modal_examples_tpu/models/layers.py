@@ -14,6 +14,7 @@ import jax
 import jax.numpy as jnp
 
 from ..ops import flash_attention
+from ..ops.scopes import DENSE_MLP
 
 
 def rms_norm(x: jax.Array, weight: jax.Array, eps: float = 1e-5) -> jax.Array:
@@ -123,6 +124,7 @@ def _proj(x, w, name, lora, lora_scale):
     return _proj_f32(x, w, name, lora, lora_scale).astype(x.dtype)
 
 
+@jax.named_scope(DENSE_MLP)
 def swiglu_mlp(
     params: dict, x: jax.Array, lora: dict | None = None, lora_scale: float = 1.0
 ) -> jax.Array:

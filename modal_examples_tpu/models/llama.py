@@ -44,6 +44,7 @@ from ..ops import (
     sharded_ragged_decode,
     sharded_scatter_kv_pages,
 )
+from ..ops import scopes as _scopes
 from . import layers
 
 
@@ -458,7 +459,8 @@ def prefill(
         else:
             from ..ops import reference as _ref
 
-            o = _ref.attention(q, k, v, causal=True)
+            with jax.named_scope(_scopes.ATTENTION):
+                o = _ref.attention(q, k, v, causal=True)
         o = o.transpose(0, 2, 1, 3).reshape(B, S, cfg.n_heads * D)
         x = x + layers.mm(o, layer["wo"]).astype(x.dtype)
         h = layers.rms_norm(x, layer["mlp_norm"], cfg.norm_eps)
@@ -572,7 +574,10 @@ def prefill_chunk(
         else:
             from ..ops import reference as _ref
 
-            o = _ref.attention_chunked(q, k_full, v_full, q_offset=q_offset)
+            with jax.named_scope(_scopes.ATTENTION):
+                o = _ref.attention_chunked(
+                    q, k_full, v_full, q_offset=q_offset
+                )
         o = o.transpose(0, 2, 1, 3).reshape(B, C, cfg.n_heads * D)
         x = x + layers.mm(o, layer["wo"]).astype(x.dtype)
         h = layers.rms_norm(x, layer["mlp_norm"], cfg.norm_eps)

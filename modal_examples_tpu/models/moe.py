@@ -24,6 +24,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from ..ops.scopes import EXPERT_SCAN, ROUTER
+
 
 @dataclasses.dataclass(frozen=True)
 class MoEConfig:
@@ -168,29 +170,33 @@ def moe_swiglu_nodrop(
     """
     E = w_gate.shape[0]
     xf = x.astype(jnp.float32)
-    logits = jnp.einsum("td,de->te", xf, router.astype(jnp.float32))
-    probs = jax.nn.softmax(logits, axis=-1)  # [T, E]
+    with jax.named_scope(ROUTER):
+        logits = jnp.einsum("td,de->te", xf, router.astype(jnp.float32))
+        probs = jax.nn.softmax(logits, axis=-1)  # [T, E]
 
-    top1 = jnp.argmax(probs, axis=-1)
-    aux = E * jnp.sum(
-        jnp.mean(jax.nn.one_hot(top1, E), axis=0) * jnp.mean(probs, axis=0)
-    )
+        top1 = jnp.argmax(probs, axis=-1)
+        aux = E * jnp.sum(
+            jnp.mean(jax.nn.one_hot(top1, E), axis=0) * jnp.mean(probs, axis=0)
+        )
 
-    topk_p, topk_idx = jax.lax.top_k(probs, top_k)  # [T, k]
-    topk_p = topk_p / jnp.sum(topk_p, axis=-1, keepdims=True)
-    # [T, E] combine weights, zero off the top-k
-    w_full = jnp.zeros_like(probs)
-    w_full = jax.vmap(lambda w, p, i: w.at[i].add(p))(w_full, topk_p, topk_idx)
+        topk_p, topk_idx = jax.lax.top_k(probs, top_k)  # [T, k]
+        topk_p = topk_p / jnp.sum(topk_p, axis=-1, keepdims=True)
+        # [T, E] combine weights, zero off the top-k
+        w_full = jnp.zeros_like(probs)
+        w_full = jax.vmap(lambda w, p, i: w.at[i].add(p))(
+            w_full, topk_p, topk_idx
+        )
 
     def body(acc, ew):
         wg, wu, wd, we = ew  # we: [T] this expert's combine weight per token
         return acc + we[:, None] * _swiglu_expert(wg, wu, wd, xf), None
 
-    out, _ = jax.lax.scan(
-        body,
-        jnp.zeros_like(xf),
-        (w_gate, w_up, w_down, w_full.T),
-    )
+    with jax.named_scope(EXPERT_SCAN):
+        out, _ = jax.lax.scan(
+            body,
+            jnp.zeros_like(xf),
+            (w_gate, w_up, w_down, w_full.T),
+        )
     return out, aux
 
 
@@ -224,13 +230,17 @@ def moe_swiglu_capacity(
     )
     xf = x.astype(jnp.float32)
     cap = cfg.capacity(x.shape[0])
-    dispatch, combine, aux = _route(xf, router.astype(jnp.float32), cfg, cap)
-    expert_in = jnp.einsum("tec,td->ecd", dispatch, xf)  # [E, C, D]
-    h = jax.nn.silu(jnp.einsum("ecd,edf->ecf", expert_in, w_gate)) * jnp.einsum(
-        "ecd,edf->ecf", expert_in, w_up
-    )
-    expert_out = jnp.einsum("ecf,efd->ecd", h, w_down)
-    out = jnp.einsum("tec,ecd->td", combine, expert_out)
+    with jax.named_scope(ROUTER):
+        dispatch, combine, aux = _route(
+            xf, router.astype(jnp.float32), cfg, cap
+        )
+    with jax.named_scope(EXPERT_SCAN):
+        expert_in = jnp.einsum("tec,td->ecd", dispatch, xf)  # [E, C, D]
+        h = jax.nn.silu(
+            jnp.einsum("ecd,edf->ecf", expert_in, w_gate)
+        ) * jnp.einsum("ecd,edf->ecf", expert_in, w_up)
+        expert_out = jnp.einsum("ecf,efd->ecd", h, w_down)
+        out = jnp.einsum("tec,ecd->td", combine, expert_out)
     return out, aux
 
 

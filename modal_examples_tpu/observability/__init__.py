@@ -57,7 +57,6 @@ from .timeseries import TsdbSampler, ensure_sampler, read_window
 from .metrics import (
     record_container_kill,
     record_engine_batch,
-    record_engine_phase,
     record_engine_queue_wait,
     record_phase,
     record_prefix_evictions,
@@ -130,7 +129,6 @@ __all__ = [
     "read_pushed_metrics",
     "record_container_kill",
     "record_engine_batch",
-    "record_engine_phase",
     "record_engine_queue_wait",
     "record_phase",
     "record_prefix_evictions",

@@ -39,13 +39,20 @@ SNAPSHOT_CAPTURES_METRIC = "mtpu_snapshot_captures_total"
 
 # -- serving engine (serving/engine.py batch loop) --------------------------
 
-#: histogram {phase}: engine hot-loop phase latency;
-#: phase = prefill | prefill_chunked | decode_wait
-ENGINE_PHASE_SECONDS = "mtpu_engine_phase_seconds"
 #: histogram: slots active per dispatched decode block (batch composition)
 ENGINE_BATCH_SIZE = "mtpu_engine_batch_size"
 #: histogram: request submit -> prefill admission wait
 ENGINE_QUEUE_WAIT_SECONDS = "mtpu_engine_queue_wait_seconds"
+#: histogram: admission (slot and pages claimed, prefill about to be
+#: dispatched) -> first generated token accepted: the part of TTFT that
+#: lies behind the queue wait
+ENGINE_FIRST_TOKEN_WAIT_SECONDS = "mtpu_engine_first_token_wait_seconds"
+#: counter {kind}: token positions at the prefill boundary, counted at each
+#: prefill dispatch; kind = computed (rows x padded length of a bucket
+#: call, or a chunk call's length: padding rows and columns included) |
+#: needed (prompt tokens of the requests in the call not served from
+#: cached pages). needed / computed is what padding and recomputation cost
+PREFILL_POSITIONS_TOTAL = "mtpu_prefill_positions_total"
 #: gauge: requests waiting for admission (engine queue depth)
 WAITING_REQUESTS = "mtpu_waiting_requests"
 #: gauge: slots currently decoding
@@ -274,15 +281,21 @@ TICK_TOTAL_PHASE = "total"
 
 #: histogram {phase}: per-tick host time attributed to one scheduler phase
 #: (phase = TICK_PHASES, plus "total" for the whole-tick duration).
-#: Emitted ONLY under MTPU_PROFILE — the disabled hot path takes zero new
+#: Emitted unless MTPU_PROFILE=0 — the disabled hot path takes zero
 #: timestamps (the faults-gate zero-cost contract)
 TICK_PHASE_SECONDS = "mtpu_tick_phase_seconds"
+#: counter {phase}: scheduler-thread seconds during which nothing was
+#: dispatched and unharvested, while a request was queued or running,
+#: under the tick phase the thread was in — a lower bound on device
+#: idleness that needs no profiler session (profiler.note_harvest)
+DEVICE_STARVED_SECONDS_TOTAL = "mtpu_device_starved_seconds_total"
 #: gauge: host share of busy-tick time over the profiler ring —
 #: 1 - (device-blocked seconds / total tick seconds); the per-token host
 #: overhead ROADMAP #3's multi-step decode loop exists to amortize
 HOST_OVERHEAD_RATIO = "mtpu_host_overhead_ratio"
-#: histogram {program}: seconds spent building one jitted program at its
-#: first dispatch of a (program, shape_key); program = block | prefill |
+#: histogram {program}: seconds of a dispatch that built its jitted
+#: program (first of a (program, shape_key), or the jitted function's
+#: cache grew during the call); program = block | prefill |
 #: prefill_mm | prefill_chunk | draft_prefill | spec_verify | ngram_verify
 #: | sample (the ops-level first-token helper) | multistep (the N-step
 #: macro-dispatch scan, serving/multistep/)
@@ -484,12 +497,6 @@ CATALOG: dict[str, dict] = {
         "labels": ["function"],
         "help": "memory snapshots captured and published to the store",
     },
-    ENGINE_PHASE_SECONDS: {
-        "type": "histogram",
-        "labels": ["phase"],
-        "help": "engine hot-loop phase latency "
-                "(prefill|prefill_chunked|decode_wait)",
-    },
     ENGINE_BATCH_SIZE: {
         "type": "histogram",
         "labels": [],
@@ -499,6 +506,18 @@ CATALOG: dict[str, dict] = {
         "type": "histogram",
         "labels": [],
         "help": "request submit-to-admission wait",
+    },
+    ENGINE_FIRST_TOKEN_WAIT_SECONDS: {
+        "type": "histogram",
+        "labels": [],
+        "help": "admission to first generated token accepted",
+    },
+    PREFILL_POSITIONS_TOTAL: {
+        "type": "counter",
+        "labels": ["kind"],
+        "help": "token positions at prefill dispatch (kind=computed: "
+                "rows x padded length | needed: prompt tokens not on "
+                "cached pages)",
     },
     WAITING_REQUESTS: {
         "type": "gauge",
@@ -767,8 +786,14 @@ CATALOG: dict[str, dict] = {
         "type": "histogram", "labels": ["phase"],
         "help": "scheduler-tick host time per phase (phase=ctrl|policy|"
                 "admit|prefill_resume|prefill_dispatch|decode_dispatch|"
-                "harvest|detokenize|accept, plus total); emitted only "
-                "under MTPU_PROFILE",
+                "harvest|detokenize|accept, plus total); off under "
+                "MTPU_PROFILE=0",
+    },
+    DEVICE_STARVED_SECONDS_TOTAL: {
+        "type": "counter", "labels": ["phase"],
+        "help": "scheduler-thread seconds with nothing dispatched and "
+                "unharvested while a request was queued or running, by "
+                "tick phase",
     },
     HOST_OVERHEAD_RATIO: {
         "type": "gauge", "labels": [],

@@ -79,17 +79,6 @@ def record_container_kill(
 # -- serving engine ---------------------------------------------------------
 
 
-def record_engine_phase(
-    phase: str, seconds: float, *, registry: Registry | None = None
-) -> None:
-    _reg(registry).histogram_observe(
-        C.ENGINE_PHASE_SECONDS,
-        seconds,
-        labels={"phase": phase},
-        help=C.CATALOG[C.ENGINE_PHASE_SECONDS]["help"],
-    )
-
-
 def record_engine_batch(n: int, *, registry: Registry | None = None) -> None:
     _reg(registry).histogram_observe(
         C.ENGINE_BATCH_SIZE,
@@ -107,6 +96,30 @@ def record_engine_queue_wait(
         seconds,
         help=C.CATALOG[C.ENGINE_QUEUE_WAIT_SECONDS]["help"],
     )
+
+
+def record_first_token_wait(
+    seconds: float, *, registry: Registry | None = None
+) -> None:
+    _reg(registry).histogram_observe(
+        C.ENGINE_FIRST_TOKEN_WAIT_SECONDS,
+        seconds,
+        buckets=C.TOKEN_TIME_BUCKETS,
+        help=C.CATALOG[C.ENGINE_FIRST_TOKEN_WAIT_SECONDS]["help"],
+    )
+
+
+def record_prefill_positions(
+    computed: int, needed: int, *, registry: Registry | None = None
+) -> None:
+    """One prefill dispatch: the positions the program computes (padding
+    included) and the prompt tokens that needed computing."""
+    reg = _reg(registry)
+    for kind, n in (("computed", computed), ("needed", needed)):
+        reg.counter_inc(
+            C.PREFILL_POSITIONS_TOTAL, float(n), labels={"kind": kind},
+            help=C.CATALOG[C.PREFILL_POSITIONS_TOTAL]["help"],
+        )
 
 
 def set_engine_gauges(
@@ -546,7 +559,7 @@ def record_tick_phase(
 ) -> None:
     """One scheduler tick's host time attributed to ``phase`` (a
     ``catalog.TICK_PHASES`` member, or ``"total"`` for the whole tick).
-    Called only by the hot-path profiler — with MTPU_PROFILE unset nothing
+    Called only by the hot-path profiler — under MTPU_PROFILE=0 nothing
     reaches here (the zero-cost gate)."""
     _reg(registry).histogram_observe(
         C.TICK_PHASE_SECONDS,
@@ -554,6 +567,16 @@ def record_tick_phase(
         labels={"phase": phase},
         buckets=C.TICK_PHASE_BUCKETS,
         help=C.CATALOG[C.TICK_PHASE_SECONDS]["help"],
+    )
+
+
+def record_device_starved(
+    phase: str, seconds: float, *, registry: Registry | None = None
+) -> None:
+    _reg(registry).counter_inc(
+        C.DEVICE_STARVED_SECONDS_TOTAL, float(seconds),
+        labels={"phase": phase},
+        help=C.CATALOG[C.DEVICE_STARVED_SECONDS_TOTAL]["help"],
     )
 
 

@@ -14,21 +14,40 @@ adaptivity) is judged against.
 Three legs:
 
 - **Tick anatomy** — the scheduler thread accounts each ``step()`` into
-  named phases (:data:`~.catalog.TICK_PHASES`) via monotonic deltas on the
+  named phases (:data:`~.catalog.TICK_PHASES`) as SCOPED spans on the
   engine's injectable clock: :meth:`HotPathProfiler.begin_tick` hands the
   tick a :class:`TickProfile`, the engine's ``_tm(tick, "phase")`` helper
-  closes intervals into phases, and :meth:`~HotPathProfiler.end_tick`
-  aggregates busy ticks into a ring buffer plus the
-  ``mtpu_tick_phase_seconds{phase}`` histograms. Blocking device reads
-  mark with ``device=True``, so the ring carries a host-vs-device split
+  ENTERS a phase (closing the one before it — one thread, so the spans
+  partition the tick), and :meth:`~HotPathProfiler.end_tick` aggregates
+  busy ticks into a ring buffer plus the
+  ``mtpu_tick_phase_seconds{phase}`` histograms. Because the phase is
+  known when its span opens, each span also opens the engine-supplied
+  trace annotation ``mtpu.tick/<phase>`` (and each program dispatch
+  ``mtpu.dispatch/<program>``): a no-op with no profiler session, an
+  event on the host plane of the device trace under one — the program's
+  spans and the device's operations on ONE clock. Blocking device reads
+  enter with ``device=True``, so the ring carries a host-vs-device split
   and the ``mtpu_host_overhead_ratio`` gauge falls out: 1 - device-blocked
   over total — the number the multi-step decode loop must shrink.
-- **Compile telemetry** — every jitted-program build site reports through
-  ONE chokepoint, :meth:`~HotPathProfiler.note_compile`: first dispatch of
-  a (program, shape_key) is timed (``mtpu_compile_seconds{program}``,
+- **Device starvation** — the profiler numbers every dispatch and the
+  engine reports which number each blocking read harvested; the device
+  runs one program after another, so everything up to a harvested number
+  is done. Scheduler-thread time with nothing dispatched and unharvested,
+  while a request is queued or running, accrues to
+  ``mtpu_device_starved_seconds_total{phase}`` under the phase the thread
+  was in: a lower bound on device idleness that needs no profiler session
+  (a program that finished before the host read it is still counted as
+  running; the trace annotations see those gaps, ``tpurun profile
+  --xplane``).
+- **Compile telemetry** — every jitted-program dispatch goes through ONE
+  chokepoint, :meth:`~HotPathProfiler.dispatch`: a dispatch that BUILT
+  its program — the first of a (program, shape_key), or any call during
+  which the jitted function's own cache grew (XLA traced and built again
+  because a shape or dtype changed under the same key) — is timed
+  (``mtpu_compile_seconds{program}``,
   ``mtpu_compiles_total{program,cache="miss"}``) and appended to the
-  ``<state_dir>/compiles.jsonl`` ledger (the journal pattern); later
-  dispatches count as cache hits. The ledger writes a ``begin`` event
+  ``<state_dir>/compiles.jsonl`` ledger (the journal pattern); every other
+  dispatch counts as a cache hit. The ledger writes a ``begin`` event
   BEFORE the build and an ``end`` event after — so a compile helper that
   crashes or hangs mid-build (the ≥40-slot ceiling) leaves a
   begin-without-end row naming exactly which program/shape killed it,
@@ -38,12 +57,16 @@ Three legs:
   compile slices merged into the replica-aware trace export, and the
   BENCH ``overhead`` section via :meth:`~HotPathProfiler.overhead_summary`.
 
-**Zero-cost when disabled** (the ``faults/inject.py`` gate pattern):
-``LLMEngine.__init__`` resolves ``MTPU_PROFILE`` ONCE (explicit arg beats
-env beats off) and keeps ``self.profiler = None`` when off — every hot-path
-touch point is then a ``tick is None`` branch with no timestamp, no
-allocation, no dict write. ``tests/test_profiler.py`` pins the no-op shape
-at the AST level like the faults gate.
+**On by default, zero-cost when disabled** (the ``faults/inject.py`` gate
+pattern): ``LLMEngine.__init__`` resolves ``MTPU_PROFILE`` ONCE (explicit
+arg beats env; unset means on, ``0`` off) and keeps ``self.profiler =
+None`` when off — every hot-path touch point is then a ``tick is None``
+branch with no timestamp, no allocation, no dict write.
+``tests/test_profiler.py`` pins the no-op shape at the AST level like the
+faults gate. On, a busy tick costs a clock read per phase entered, one
+histogram observation per phase at its end, and two clock reads per
+accepted token (the ``detokenize`` split, which writes nothing else); an
+idle engine's ticks get no :class:`TickProfile` at all.
 
 jax-free and import-light: ``observability/`` is imported by the jax-free
 ``core/`` layer, and ``tpurun profile`` must not attach a chip to render a
@@ -63,8 +86,14 @@ from . import metrics as _obs
 from .journal import JOURNALS, DecisionJournal, named_journal
 
 #: the one env switch (resolved once in ``LLMEngine.__init__``, the
-#: MTPU_KV_DTYPE rule): unset/0 = off — bench configs opt in explicitly
+#: MTPU_KV_DTYPE rule): the OFF switch — ``0`` = off, unset = on
 PROFILE_ENV = "MTPU_PROFILE"
+
+#: trace-annotation names, built once (an annotation per phase entered
+#: must not format a string on the scheduler thread)
+TICK_ANNOTATION_PREFIX = "mtpu.tick/"
+DISPATCH_ANNOTATION_PREFIX = "mtpu.dispatch/"
+TICK_ANNOTATION = {p: TICK_ANNOTATION_PREFIX + p for p in C.TICK_PHASES}
 
 #: busy ticks retained in the in-memory ring (per profiler)
 RING_TICKS = 512
@@ -83,42 +112,77 @@ LEDGER_NAME = JOURNALS["compiles"]
 
 def profiling_enabled(explicit=None) -> bool:
     """Resolve the profile switch ONCE: explicit arg beats
-    :data:`PROFILE_ENV` beats off (the MTPU_KV_DTYPE rule — the env is
-    never re-read on the hot path)."""
+    :data:`PROFILE_ENV`; unset means ON and ``0`` off (the MTPU_KV_DTYPE
+    rule — the env is never re-read on the hot path)."""
     import os
 
     if explicit is not None:
         return bool(explicit)
-    return os.environ.get(PROFILE_ENV, "") not in ("", "0")
+    return os.environ.get(PROFILE_ENV, "") != "0"
 
 
 class TickProfile:
-    """One scheduler tick's phase accumulator.
+    """One scheduler tick as a sequence of scoped phase spans.
 
-    Interval semantics: :meth:`mark` closes the time since the PREVIOUS
-    mark (or the tick's start) into the named phase — the scheduler runs
-    one thread, so sequential marks partition the tick exactly and the
-    per-phase sums can never exceed the tick total. ``device=True``
-    additionally counts the interval as device-blocked time (the host
-    waiting on a device array), feeding the host-vs-device split.
+    :meth:`enter` opens a phase and closes the one before it — the
+    scheduler runs one thread, so the spans partition the tick exactly and
+    the per-phase sums can never exceed the tick total. ``device=True``
+    additionally counts the span as device-blocked time (the host waiting
+    on a device array), feeding the host-vs-device split. Each span closed
+    while the device had nothing dispatched adds to the tick's
+    ``starved`` seconds under its phase (see
+    :meth:`HotPathProfiler.note_harvest`).
     """
 
-    __slots__ = ("_clock", "t0", "_last", "phases", "device_s")
+    __slots__ = (
+        "_prof", "_clock", "t0", "_last", "_phase", "_device", "_ann",
+        "phases", "device_s", "starved",
+    )
 
-    def __init__(self, clock):
-        self._clock = clock
-        self.t0 = self._last = clock()
+    def __init__(self, prof: "HotPathProfiler"):
+        self._prof = prof
+        self._clock = prof._clock
+        self.t0 = self._last = self._clock()
+        self._phase: str | None = None
+        self._device = False
+        self._ann = None
         self.phases: dict[str, float] = {}
         self.device_s = 0.0
+        self.starved: dict[str, float] = {}
 
-    def mark(self, phase: str, device: bool = False) -> None:
+    def enter(
+        self, phase: str | None, device: bool = False, annotate: bool = True
+    ) -> float:
+        """Close the open span into its phase and open ``phase`` (None:
+        only close — the tick's end). Returns the closed span's seconds.
+        ``annotate=False`` switches the accounting alone and leaves the
+        open trace annotation as it is: the per-token ``detokenize`` split
+        costs two clock reads and writes nothing into a trace."""
         now = self._clock()
         dt = now - self._last
         self._last = now
-        if dt > 0:
-            self.phases[phase] = self.phases.get(phase, 0.0) + dt
-            if device:
-                self.device_s += dt
+        old = self._phase
+        if old is not None:
+            if dt > 0:
+                self.phases[old] = self.phases.get(old, 0.0) + dt
+                if self._device:
+                    self.device_s += dt
+            since = self._prof._starved_since
+            if since is not None:
+                if now > since:
+                    self.starved[old] = self.starved.get(old, 0.0) + now - since
+                self._prof._starved_since = now
+        self._phase = phase
+        self._device = device
+        if annotate:
+            if self._ann is not None:
+                self._ann.__exit__(None, None, None)
+                self._ann = None
+            make = self._prof._annotate
+            if make is not None and phase is not None:
+                self._ann = make(TICK_ANNOTATION[phase])
+                self._ann.__enter__()
+        return dt
 
 
 class HotPathProfiler:
@@ -127,8 +191,11 @@ class HotPathProfiler:
     ``clock`` is the engine's injectable monotonic clock (fake-clock tests
     see real phase deltas); ``name`` is the replica name, or a zero-arg
     callable resolving it lazily (the engine's ``trace_name`` is assigned
-    by the fleet AFTER construction). All methods are safe from the
-    scheduler thread plus concurrent ``prefill_sync`` server threads.
+    by the fleet AFTER construction); ``annotate`` is the engine's trace-
+    annotation factory (``jax.profiler.TraceAnnotation`` — this package
+    stays free of JAX), called as ``annotate(name, **attrs)`` for a context
+    manager. Ticks and starvation belong to the scheduler thread; :meth:`dispatch`
+    is also safe from ``prefill_sync`` server threads.
     """
 
     def __init__(
@@ -139,10 +206,20 @@ class HotPathProfiler:
         registry=None,
         ledger_path=None,
         ring: int = RING_TICKS,
+        annotate=None,
     ):
         self._clock = clock or time.monotonic
         self._name = name
         self._registry = registry
+        self._annotate = annotate
+        #: programs dispatched / the highest dispatch number a blocking
+        #: read has harvested (the device runs them in order, so all up to
+        #: it are done); equal = nothing on the device that the host knows of
+        self.dispatched = 0
+        self._harvested = 0
+        #: start of the open starvation interval on ``clock``, or None
+        self._starved_since: float | None = None
+        self._tick: TickProfile | None = None  # the open tick, if profiled
         self._lock = threading.Lock()
         self._ring: deque[dict] = deque(maxlen=ring)
         self._busy_ticks = 0
@@ -164,17 +241,40 @@ class HotPathProfiler:
 
     # -- tick anatomy --------------------------------------------------------
 
-    def begin_tick(self) -> TickProfile:
-        return TickProfile(self._clock)
+    def begin_tick(self, demand: bool = True) -> TickProfile | None:
+        """The tick's profile, or None for a tick of an idle engine
+        (``demand`` false — no request queued or running — and nothing on
+        the device): idle ticks take no timestamp at all. A tick that
+        starts with demand and an empty device opens a starvation
+        interval (a request reached an idle engine)."""
+        empty = self.dispatched <= self._harvested
+        if empty and not demand:
+            self._starved_since = None
+            return None
+        tick = self._tick = TickProfile(self)
+        if empty and self._starved_since is None:
+            self._starved_since = tick.t0
+        return tick
 
-    def end_tick(self, tick: TickProfile, worked: bool) -> None:
-        """Close one tick. Idle ticks (``worked=False``, or nothing marked)
-        record NOTHING — the ring and histograms carry only ticks that did
-        work, so an idle engine's profile stays empty instead of drowning
-        the signal in sub-millisecond no-op loops."""
+    def end_tick(
+        self, tick: TickProfile, worked: bool, demand: bool = True
+    ) -> None:
+        """Close one tick. Idle ticks (``worked=False``, or no phase
+        entered) record NOTHING in the ring and the phase histograms — an
+        idle engine's profile stays empty instead of drowning the signal
+        in sub-millisecond no-op loops. Starved seconds are counted either
+        way; with no ``demand`` left the open starvation interval ends."""
+        tick.enter(None)
+        self._tick = None
+        if not demand:
+            self._starved_since = None
+        for phase, seconds in tick.starved.items():
+            _obs.record_device_starved(
+                phase, seconds, registry=self._registry
+            )
         if not worked or not tick.phases:
             return
-        total = max(0.0, self._clock() - tick.t0)
+        total = max(0.0, tick._last - tick.t0)
         entry = {
             "at": time.time(),  # wall clock: aligns with trace span starts
             "total": total,
@@ -192,6 +292,26 @@ class HotPathProfiler:
         )
         if refresh:
             self._refresh_ratio()
+
+    def note_harvest(self, seq: int, tick: TickProfile | None = None) -> None:
+        """A blocking read returned the output of dispatch number ``seq``:
+        that program and every one before it are done. With nothing later
+        dispatched the device is empty from the moment the read returned —
+        the start of ``tick``'s open span, which the engine entered right
+        after the read — until the next dispatch."""
+        if seq > self._harvested:
+            self._harvested = seq
+        if self.dispatched <= self._harvested and self._starved_since is None:
+            self._starved_since = (
+                tick._last if tick is not None else self._clock()
+            )
+
+    def note_drained(self) -> None:
+        """Everything dispatched is done or dropped (a ``block_until_ready``
+        outside the scheduler loop, or the release sweep of a stopping
+        engine): nothing outstanding, no starvation interval open."""
+        self._harvested = self.dispatched
+        self._starved_since = None
 
     def note_dispatch_tokens(self, n: int, steps: int | None = None) -> None:
         """One harvested decode dispatch accepted ``n`` tokens (both the
@@ -222,44 +342,67 @@ class HotPathProfiler:
 
     # -- compile telemetry ---------------------------------------------------
 
-    def compile_begin(self, program: str, shape_key) -> float | None:
-        """First half of the build-site chokepoint: None when this
-        (program, shape_key) was already built in this process (the caller
-        records a cache hit via :meth:`compile_end`); otherwise the start
-        timestamp — and a ``begin`` ledger event, written BEFORE the build
-        so a crash/hang mid-compile still names its program/shape."""
+    def dispatch(self, program: str, shape_key, fn, args, kwargs):
+        """THE dispatch chokepoint: call ``fn`` (a jitted program, async)
+        under the ``mtpu.dispatch/<program>`` annotation, number it for
+        the starvation account, and tell a build from a cache hit.
+
+        A build is the first dispatch of a (program, shape_key) in this
+        process, or ANY call during which the jitted function's own cache
+        grew (``fn._cache_size()`` before and after): XLA traced and built
+        the program again because an argument's shape or dtype changed
+        under a key the engine thought it had seen. The first kind writes
+        its ``begin`` ledger event BEFORE the call, so a crash or hang
+        mid-compile still names its program/shape; a build that raises is
+        forgotten, so the retry is timed as a fresh miss."""
         key = (program, str(shape_key))
         with self._lock:
-            if key in self._seen:
-                return None
+            first = key not in self._seen
             self._seen.add(key)
-        self._ledger_record({
-            "at": time.time(),
-            "event": "begin",
-            "replica": self.replica,
-            "program": program,
-            "shape_key": str(shape_key),
-        })
-        return self._clock()
-
-    def compile_abort(self, program: str, shape_key) -> None:
-        """A build that raised: forget the (program, shape_key) so the
-        next dispatch is timed as a fresh miss again — without this, the
-        successful retry would be misreported as a cache hit and its
-        ``begin`` row would read as a crash forever. The open ``begin``
-        stays in the ledger; the retry's own begin/end pair supersedes it
-        in :func:`unfinished_builds`, and a never-retried failure keeps
-        reading as unfinished — which it is."""
-        with self._lock:
-            self._seen.discard((program, str(shape_key)))
-
-    def compile_end(self, program: str, shape_key, t0: float | None) -> None:
-        if t0 is None:
-            self.note_compile(program, shape_key, 0.0, cache_hit=True)
-        else:
-            self.note_compile(
-                program, shape_key, self._clock() - t0, cache_hit=False
+        cache_size = getattr(fn, "_cache_size", None)
+        n0 = cache_size() if cache_size is not None else 0
+        if first:
+            self._ledger_record({
+                "at": time.time(),
+                "event": "begin",
+                "replica": self.replica,
+                "program": program,
+                "shape_key": key[1],
+            })
+        ann = None
+        if self._annotate is not None:
+            ann = self._annotate(
+                DISPATCH_ANNOTATION_PREFIX + program, shape=key[1]
             )
+            ann.__enter__()
+        t0 = self._clock()
+        try:
+            out = fn(*args, **kwargs)
+        except BaseException:
+            if first:
+                with self._lock:
+                    self._seen.discard(key)
+            raise
+        finally:
+            if ann is not None:
+                ann.__exit__(None, None, None)
+        built = first or (cache_size is not None and cache_size() > n0)
+        self.note_compile(
+            program, shape_key,
+            self._clock() - t0 if built else 0.0, cache_hit=not built,
+        )
+        # the program is on the device's queue: a starvation interval ends
+        # here, charged to the phase the scheduler thread is in
+        self.dispatched += 1
+        since = self._starved_since
+        if since is not None:
+            self._starved_since = None
+            tick = self._tick
+            if tick is not None and tick._phase is not None and t0 > since:
+                tick.starved[tick._phase] = (
+                    tick.starved.get(tick._phase, 0.0) + t0 - since
+                )
+        return out
 
     def note_compile(
         self, program: str, shape_key, seconds: float, cache_hit: bool

@@ -61,6 +61,18 @@ ROOT_SPAN = "request"
 #: inflating the effective sample rate and splitting attribution.
 UNSET = object()
 
+#: span timestamps are wall-clock (spans of one request land from several
+#: processes); the engine stamps a request on the monotonic clock. One
+#: offset, taken once, moves a stamp onto the spans' timeline.
+_WALL_MINUS_MONOTONIC = time.time() - time.monotonic()
+
+
+def wall(t_monotonic: float | None) -> float:
+    """The wall-clock time of a ``time.monotonic()`` stamp (None: now)."""
+    if t_monotonic is None:
+        return time.time()
+    return t_monotonic + _WALL_MINUS_MONOTONIC
+
 
 def resolve_entry_trace(trace, entry: str, store=None):
     """The one rule every submit layer applies to its ``trace=`` kwarg:
@@ -128,7 +140,7 @@ class RequestTraceContext:
     """
 
     __slots__ = ("trace_id", "root", "store", "owns_root", "_lock", "_open",
-                 "_done")
+                 "_done", "_stores")
 
     def __init__(
         self,
@@ -147,6 +159,9 @@ class RequestTraceContext:
         self._lock = threading.Lock()
         self._open: dict[str, Span] = {}
         self._done = False
+        #: stores holding deferred spans of this trace (each replica writes
+        #: to its own): :func:`finish_root` settles them all
+        self._stores: set = set()
 
     @property
     def done(self) -> bool:
@@ -197,10 +212,12 @@ def begin(
     name: str,
     *,
     parent: str | None = None,
+    start: float | None = None,
     **attrs,
 ) -> Span | None:
     """Open a span (recorded only when :func:`finish` closes it). The span
-    registers as OPEN on the context so a crash path's sweep can close it."""
+    registers as OPEN on the context so a crash path's sweep can close it.
+    ``start`` (wall-clock) places its beginning at a stamp taken earlier."""
     if ctx is None:
         return None
     sp = Span(
@@ -209,6 +226,8 @@ def begin(
         parent_id=parent or ctx.root.span_id,
         attrs=attrs,
     )
+    if start is not None:
+        sp.start = start
     with ctx._lock:
         # _done re-checked UNDER the lock: a span registered after the
         # terminal sweep cleared _open would dangle forever (the race is
@@ -226,18 +245,32 @@ def finish(
     status: str = "ok",
     *,
     store: TraceStore | None = None,
+    end: float | None = None,
     **attrs,
 ) -> None:
     """Close + record a :func:`begin`-opened span. Idempotent: a span that
     was already closed (e.g. by the terminal sweep) is left alone, so
-    failure paths may finish defensively."""
+    failure paths may finish defensively. ``end`` (wall-clock) closes it
+    at a stamp taken earlier instead of now."""
     if ctx is None or span is None:
         return
     with ctx._lock:
         if ctx._open.pop(span.span_id, None) is None:
             return
+    if end is not None:
+        span.end = end
     span.finish(status, **attrs)
-    (store or ctx.store).record(span)
+    _defer(ctx, store, span)
+
+
+def _defer(ctx: RequestTraceContext, store: TraceStore | None, span: Span) -> None:
+    """A finished span of a request: kept in memory by its store and
+    written off-thread when the request finishes (:func:`finish_root`) —
+    spans are recorded from the engine's scheduler thread, which must not
+    open files."""
+    st = store or ctx.store
+    ctx._stores.add(st)
+    st.defer(span)
 
 
 def record_span(
@@ -264,7 +297,7 @@ def record_span(
     )
     sp.end = end if end is not None else time.time()
     sp.status = status
-    (store or ctx.store).record(sp)
+    _defer(ctx, store, sp)
     return sp
 
 
@@ -305,13 +338,14 @@ def finish_root(
         ctx._done = True
         leftovers = list(ctx._open.values())
         ctx._open.clear()
-    st = store or ctx.store
     for sp in leftovers:
         sp.finish(status)
-        st.record(sp)
+        _defer(ctx, store, sp)
     if ctx.owns_root:
         ctx.root.finish(status, **attrs)
-        st.record(ctx.root)
+        _defer(ctx, store, ctx.root)
+    for st in ctx._stores:
+        st.settle(ctx.trace_id)
 
 
 def finish_request(req, reason: str, *, store: TraceStore | None = None) -> None:

@@ -19,11 +19,13 @@ and the executor skips every span call site).
 
 from __future__ import annotations
 
+import atexit
 import contextlib
 import contextvars
 import dataclasses
 import json
 import os
+import queue
 import re
 import threading
 import time
@@ -49,6 +51,56 @@ def _env_int(name: str, default: int) -> int:
 #: oldest-first past either cap
 _MAX_TRACE_FILES = _env_int("MTPU_TRACE_MAX_FILES", 2000)
 _MAX_TRACE_BYTES = _env_int("MTPU_TRACE_MAX_BYTES", 256 * 1024 * 1024)
+#: deferred spans a store holds in memory before it hands them all to the
+#: writer thread, finished trace or not (a trace nobody finishes or reads
+#: must not grow without bound)
+_MAX_DEFERRED_SPANS = 4096
+
+
+class _SpanWriter:
+    """The one thread per process that appends deferred spans to their
+    files, so that the threads recording them (the serving engine's
+    scheduler first of all) never open a file. Started on first use."""
+
+    def __init__(self):
+        self._q: queue.SimpleQueue = queue.SimpleQueue()
+        self._lock = threading.Lock()
+        self._thread: threading.Thread | None = None
+
+    def submit(self, store: "TraceStore", batches: dict) -> None:
+        if not batches:
+            return
+        with self._lock:
+            if self._thread is None or not self._thread.is_alive():
+                self._thread = threading.Thread(
+                    target=self._run, name="mtpu-span-writer", daemon=True
+                )
+                self._thread.start()
+                atexit.register(self.drain)
+        self._q.put((store, batches))
+
+    def drain(self, timeout: float = 10.0) -> None:
+        """Return once everything submitted before this call is on disk."""
+        with self._lock:
+            alive = self._thread is not None and self._thread.is_alive()
+        if not alive or threading.current_thread() is self._thread:
+            return
+        done = threading.Event()
+        self._q.put(done)
+        done.wait(timeout)
+
+    def _run(self) -> None:
+        while True:
+            item = self._q.get()
+            if isinstance(item, threading.Event):
+                item.set()
+                continue
+            store, batches = item
+            for trace_id, lines in batches.items():
+                store._append(trace_id, "".join(lines))
+
+
+_writer = _SpanWriter()
 
 
 def tracing_enabled() -> bool:
@@ -113,6 +165,10 @@ class TraceStore:
         self._resolved: Path | None = None  # root after its one-time mkdir
         self._lock = threading.Lock()
         self._last_gc = 0.0
+        #: trace id -> JSON lines recorded with :meth:`defer`, not yet
+        #: handed to the writer thread
+        self._deferred: dict[str, list[str]] = {}
+        self._n_deferred = 0
 
     @property
     def root(self) -> Path:
@@ -126,24 +182,61 @@ class TraceStore:
         d = span.to_dict() if isinstance(span, Span) else dict(span)
         if d.get("end") is None:
             d["end"] = time.time()
-        path = self.root / f"{d['trace_id']}.jsonl"
-        line = json.dumps(d) + "\n"
+        self._append(d["trace_id"], json.dumps(d) + "\n")
+
+    def _append(self, trace_id: str, text: str) -> None:
+        path = self.root / f"{trace_id}.jsonl"
         with self._lock:
             try:
                 with open(path, "a") as f:
-                    f.write(line)
+                    f.write(text)
             except FileNotFoundError:
                 # traces dir deleted out from under us: re-create and retry
                 # (record runs in the result-delivery path — never raise)
                 self._resolved = None
                 try:
                     with open(self.root / path.name, "a") as f:
-                        f.write(line)
+                        f.write(text)
                 except OSError:
                     pass
         self._maybe_gc()
 
+    def defer(self, span: "Span | dict") -> None:
+        """Record a finished span in memory only: no file is opened on the
+        caller's thread. :meth:`settle` (when the trace finishes) hands
+        the trace's spans to the process's writer thread; a reader of this
+        store settles first, so it sees what :meth:`record` would have
+        shown it."""
+        d = span.to_dict() if isinstance(span, Span) else dict(span)
+        if d.get("end") is None:
+            d["end"] = time.time()
+        line = json.dumps(d) + "\n"
+        with self._lock:
+            self._deferred.setdefault(d["trace_id"], []).append(line)
+            self._n_deferred += 1
+            over = self._n_deferred > _MAX_DEFERRED_SPANS
+        if over:
+            self.settle()
+
+    def settle(self, trace_id: str | None = None) -> None:
+        """Hand the deferred spans of ``trace_id`` (None: of every trace)
+        to the writer thread."""
+        with self._lock:
+            if trace_id is None:
+                batches, self._deferred = self._deferred, {}
+            else:
+                lines = self._deferred.pop(trace_id, None)
+                batches = {trace_id: lines} if lines else {}
+            self._n_deferred -= sum(len(v) for v in batches.values())
+        _writer.submit(self, batches)
+
+    def _settled(self, trace_id: str | None = None) -> None:
+        """Reader side: everything deferred so far is on disk on return."""
+        self.settle(trace_id)
+        _writer.drain()
+
     def read(self, trace_id: str) -> list[dict]:
+        self._settled(trace_id)
         path = self.root / f"{trace_id}.jsonl"
         if not path.exists():
             return []
@@ -170,12 +263,14 @@ class TraceStore:
         trace``/``explain`` take either kind, abbreviated."""
         if not token or not self._ID_TOKEN_RE.match(token):
             return None
+        self._settled()
         if (self.root / f"{token}.jsonl").exists():
             return token
         matches = sorted(p.stem for p in self.root.glob(f"{token}*.jsonl"))
         return matches[0] if len(matches) == 1 else None
 
     def list_traces(self, limit: int = 50) -> list[str]:
+        self._settled()
         files = sorted(
             self.root.glob("*.jsonl"),
             key=lambda p: p.stat().st_mtime,

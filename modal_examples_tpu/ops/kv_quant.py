@@ -34,6 +34,8 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 
+from .scopes import KV_SCATTER, PAGE_GATHER
+
 #: scale granularity: one f32 per (token, kv-head) over the D axis
 _QMAX = 127.0
 
@@ -145,6 +147,7 @@ def kv_empty(shape: tuple, kv_dtype) -> jax.Array | QuantizedKV:
     return jnp.zeros(shape, kv_dtype)
 
 
+@jax.named_scope(PAGE_GATHER)
 def kv_gather(pages, tables, layer=None, *, dtype=jnp.bfloat16):
     """``pages[(layer,) tables]`` with the dequant multiply fused into the
     gather (XLA fuses gather -> convert -> multiply into one bandwidth-bound
@@ -173,6 +176,7 @@ def shard_kv(pages, data_sharding, scale_sharding):
     return jax.device_put(pages, data_sharding)
 
 
+@jax.named_scope(KV_SCATTER)
 def kv_scatter(pages, update, page_idx, slot, *, leading_layer: bool = True):
     """``pages.at[(:,) page_idx, slot].set(update)`` with quantize-at-write
     fused in for int8 caches (per token-head amax/127 computed on the

@@ -48,6 +48,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .kv_quant import QuantizedKV, is_quantized, kv_gather, quantize_kv
+from .scopes import ATTENTION
 
 
 def _decode_kernel(
@@ -201,6 +202,7 @@ def _paged_decode_xla(
     return o.reshape(B, Hq, D).astype(q.dtype)
 
 
+@jax.named_scope(ATTENTION)
 def paged_decode_attention_inflight(
     q: jax.Array,  # [B, Hq, D]
     ks: jax.Array,  # [B, pages_per_seq, page_size, Hkv, D] — gathered pages

@@ -51,6 +51,7 @@ from jax.sharding import PartitionSpec as P
 from ..parallel.mesh import TENSOR
 from .flash_attention import flash_attention, flash_attention_chunked
 from .kv_quant import QuantizedKV, is_quantized
+from .scopes import ATTENTION, KV_SCATTER
 from .paged_attention import (
     paged_decode_attention,
     paged_decode_attention_ragged,
@@ -115,6 +116,7 @@ def _pages_specs(quantized: bool, axis: str, head_dim: int = 3):
     )
 
 
+@jax.named_scope(ATTENTION)
 def sharded_ragged_decode(
     mesh,
     q,  # [B, Hq, D]
@@ -181,6 +183,7 @@ def sharded_ragged_decode(
     )
 
 
+@jax.named_scope(KV_SCATTER)
 def sharded_scatter_kv_pages(
     mesh,
     k_pages,  # [L, P, ps, Hkv, D] array or QuantizedKV
@@ -234,6 +237,7 @@ def sharded_scatter_kv_pages(
     return rebuild(list(out[:n_pg])), rebuild(list(out[n_pg:]))
 
 
+@jax.named_scope(ATTENTION)
 def sharded_flash_attention(
     mesh,
     q,  # [B, Hq, S, D]
@@ -262,6 +266,7 @@ def sharded_flash_attention(
     )(q, k, v)
 
 
+@jax.named_scope(ATTENTION)
 def sharded_flash_attention_chunked(
     mesh,
     q,  # [B, Hq, C, D]

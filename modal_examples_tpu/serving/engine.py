@@ -67,23 +67,34 @@ from ..utils.tokenizer import load_tokenizer
 _log = get_logger("engine")
 
 
-def _tm(tick, phase: str) -> None:
-    """Close the interval since the tick's last mark into ``phase`` — THE
-    way scheduler code feeds the hot-path profiler (docs/observability.md).
-    ``tick`` is None whenever profiling is off, so the disabled hot path is
-    one branch: no timestamp, no allocation (the faults-gate zero-cost
-    contract; tests/test_profiler.py pins this shape at the AST level, and
+def _tm(tick, phase: str):
+    """Enter ``phase``: the scheduler thread's time from here to the next
+    ``_tm`` belongs to it, as a span of the hot-path profiler and, under a
+    profiler session, a ``mtpu.tick/<phase>`` event in the device trace —
+    THE way scheduler code feeds the profiler (docs/observability.md).
+    Returns the seconds of the span this closes. ``tick`` is None whenever
+    profiling is off or the engine is idle, so that path is one branch: no
+    timestamp, no allocation (the faults-gate zero-cost contract;
+    tests/test_profiler.py pins this shape at the AST level, and
     tests/test_static.py pins the phase names to catalog.TICK_PHASES)."""
     if tick is not None:
-        tick.mark(phase)
+        return tick.enter(phase)
 
 
-def _tm_device(tick, phase: str) -> None:
-    """`_tm`, additionally counting the interval as DEVICE-blocked time (a
+def _tm_device(tick, phase: str):
+    """`_tm`, additionally counting the span as DEVICE-blocked time (a
     blocking read of a device array) — the device half of the profiler's
     host-vs-device split behind ``mtpu_host_overhead_ratio``."""
     if tick is not None:
-        tick.mark(phase, device=True)
+        return tick.enter(phase, device=True)
+
+
+def _tm_inner(tick, phase: str):
+    """`_tm` for a split made once per TOKEN: the accounting switches to
+    ``phase``, the open trace annotation stays — two clock reads, nothing
+    written into a trace."""
+    if tick is not None:
+        return tick.enter(phase, annotate=False)
 
 
 @dataclasses.dataclass
@@ -101,6 +112,12 @@ class Request:
     # token-level telemetry (monotonic clock): TTFT = first_token_at -
     # created; inter-token gaps feed the TPOT histogram. n_generated is the
     # request's own generated-token count (streaming usage reporting).
+    # admitted_at: slot and pages claimed — created..admitted_at is the
+    # request trace's queue span, admitted_at..first_token_at the
+    # first-token wait histogram; request-trace spans take their ends from
+    # these. (mtpu_engine_queue_wait_seconds ends a little later, at the
+    # start of the tick's prefill call.)
+    admitted_at: float | None = None
     first_token_at: float | None = None
     last_token_at: float | None = None
     n_generated: int = 0
@@ -210,8 +227,6 @@ class _PendingPrefill:
     ticks: int = 0  # scheduler ticks that dispatched at least one chunk
     suspensions: int = 0  # times the budget paused this prefill mid-prompt
     logits: object | None = None  # last dispatched chunk's logits (device)
-    t_start: float = 0.0  # monotonic, for the phase histogram
-    t_wall: float = 0.0  # wall-clock, for trace spans
 
 
 class _NgramIndex:
@@ -447,10 +462,9 @@ class LLMEngine:
         admission: AdmissionController | None = None,  # shed/deadline gate
         clock=None,  # injectable monotonic clock (fake-clock scheduling tests)
         # hot-path profiler (observability/profiler.py): None resolves
-        # MTPU_PROFILE once (the MTPU_KV_DTYPE rule); True/False override.
-        # Off = self.profiler stays None and the scheduler tick takes ZERO
-        # new timestamps, so chaos/loadgen runs can't silently pay
-        # profiling cost; bench configs opt in explicitly.
+        # MTPU_PROFILE once (the MTPU_KV_DTYPE rule: unset = on, 0 = off);
+        # True/False override. Off = self.profiler stays None and the
+        # scheduler tick takes ZERO timestamps of its own.
         profile=None,
         # tiered prefix cache (docs/disagg.md): True for env-default sizing,
         # or a dict of TieredPrefixCache kwargs (host_bytes=, volume=);
@@ -665,11 +679,15 @@ class LLMEngine:
         # the engine's injectable clock so fake-clock tests see real ages.
         self.watermarks = EngineWatermarks(clock=self._clock)
         # hot-path profiler (docs/observability.md#hot-path-profiling):
-        # resolved ONCE — explicit arg beats MTPU_PROFILE beats off. The
-        # lazy name callable picks up the fleet's trace_name assignment.
+        # resolved ONCE — explicit arg beats MTPU_PROFILE, unset is on. The
+        # lazy name callable picks up the fleet's trace_name assignment;
+        # the annotation factory puts the profiler's spans into the device
+        # trace of whoever has a profiler session open (observability/
+        # itself never imports JAX).
         self.profiler = (
             _profiler.HotPathProfiler(
-                clock=self._clock, name=lambda: self.trace_name
+                clock=self._clock, name=lambda: self.trace_name,
+                annotate=jax.profiler.TraceAnnotation,
             )
             if _profiler.profiling_enabled(profile)
             else None
@@ -702,8 +720,9 @@ class LLMEngine:
         # config, true weight HBM bytes from the loaded tree, dtype-aware
         # KV bytes/token from the cache's own accounting — and the meter
         # shares the engine's injectable clock, so fake-clock runs meter
-        # bit-reproducible MFU/MBU. Always on: the per-token cost is a few
-        # integer adds (no extra timestamps), unlike the profiler.
+        # bit-reproducible MFU/MBU. The per-token cost is a few integer
+        # adds; the device seconds under MFU/MBU are the profiler's harvest
+        # spans (none under MTPU_PROFILE=0).
         from ..models.quantize import param_bytes
 
         self.usage = _usage.EngineUsage(
@@ -765,7 +784,8 @@ class LLMEngine:
         self._override_mask = np.zeros((max_slots,), bool)
         import collections
 
-        self._inflight = collections.deque()  # (tokens [K, B] device, snapshot)
+        # (tokens [K, B] device, valid, snapshot, spec_meta, dispatch number)
+        self._inflight = collections.deque()
         # stall-free admission state: finished prefills whose sampled first
         # token is still a device array — the blocking read is deferred
         # until AFTER the decode block for already-running slots has been
@@ -778,7 +798,7 @@ class LLMEngine:
         # reference — enqueue (fn, result_queue) here and step() services
         # them at the top of each tick (_run_on_scheduler)
         self._ctrl = collections.deque()
-        # last decode-block dispatch (monotonic); None while no decodable
+        # last decode-block dispatch (engine clock); None while no decodable
         # slot exists — feeds mtpu_decode_stall_seconds
         self._last_dispatch_at: float | None = None
 
@@ -932,33 +952,66 @@ class LLMEngine:
     # -- jitted programs ----------------------------------------------------
 
     def _profiled(self, program: str, shape_key, fn):
-        """THE compile-telemetry chokepoint (docs/observability.md): every
-        jitted-program dispatch site wraps its callable here. Profiling
-        off: returns ``fn`` untouched — no wrapper, no allocation (the
-        zero-cost gate, AST-pinned in tests/test_profiler.py). On: the
-        first dispatch of each (program, shape_key) is timed into
-        ``mtpu_compile_seconds{program}`` and the compiles.jsonl ledger
-        (begin event BEFORE the build, so a mid-compile crash/hang still
-        names its program — the ≥40-slot ceiling diagnosis); later
-        dispatches count as ``mtpu_compiles_total{cache="hit"}``."""
+        """THE dispatch chokepoint (docs/observability.md): every jitted-
+        program dispatch site wraps its callable here. Profiling off:
+        returns ``fn`` untouched — no wrapper, no allocation (the zero-cost
+        gate, AST-pinned in tests/test_profiler.py). On:
+        ``HotPathProfiler.dispatch`` runs it under the
+        ``mtpu.dispatch/<program>`` trace annotation, numbers it for the
+        device-starvation account, and times a dispatch that BUILT its
+        program into ``mtpu_compile_seconds{program}`` and the
+        compiles.jsonl ledger (begin event BEFORE a first build, so a
+        mid-compile crash/hang still names its program — the ≥40-slot
+        ceiling diagnosis); the others count as
+        ``mtpu_compiles_total{cache="hit"}``."""
         prof = self.profiler
         if prof is None:
             return fn
 
         def run(*args, **kwargs):
-            t0 = prof.compile_begin(program, shape_key)
-            try:
-                out = fn(*args, **kwargs)
-            except BaseException:
-                if t0 is not None:
-                    # the build raised: forget the key so a retry is timed
-                    # as a fresh miss, not misreported as a cache hit
-                    prof.compile_abort(program, shape_key)
-                raise
-            prof.compile_end(program, shape_key, t0)
-            return out
+            return prof.dispatch(program, shape_key, fn, args, kwargs)
 
         return run
+
+    def _harvest_begin(self) -> float | None:
+        """Enter the ``harvest`` span: a blocking read of a device array
+        follows. With no tick open (profiling off, or a stopped engine's
+        ``prefill_sync``) returns the engine clock instead, for
+        ``_harvested``: the roofline meter's device seconds do not depend
+        on the profiler's switch."""
+        tick = self._tick
+        _tm_device(tick, "harvest")
+        return self._clock() if tick is None else None
+
+    def _harvested(self, seq: int, kind: str, t0: float | None) -> None:
+        """A blocking read of dispatch number ``seq``'s output just
+        returned (``t0``: what ``_harvest_begin`` gave): close the
+        ``harvest`` span into ``accept``, tell the starvation account how
+        far the device has come, and hand the span's seconds to the
+        roofline meter as ``kind`` ("prefill" | "decode") device time."""
+        tick = self._tick
+        waited = _tm(tick, "accept")
+        if t0 is not None:
+            waited = self._clock() - t0
+        prof = self.profiler
+        if prof is not None:
+            prof.note_harvest(seq, tick)
+        if waited:
+            self.usage.note_phase_seconds(kind, waited)
+
+    def _dispatch_seq(self) -> int:
+        """The number of the program dispatched last (0 unprofiled)."""
+        prof = self.profiler
+        return 0 if prof is None else prof.dispatched
+
+    def _has_demand(self) -> bool:
+        """A request is queued or holds a slot, or a control command
+        waits: the engine is not idle."""
+        return (
+            bool(self._ctrl)
+            or self.policy.total_depth() > 0
+            or any(s.request is not None for s in self.slots)
+        )
 
     def _decode_block_fn(
         self, params, k_pages, v_pages, prev_tokens, override, override_mask,
@@ -1051,6 +1104,27 @@ class LLMEngine:
             self._prefill_jits[bucket] = fn
         return fn
 
+    def _chunk_jit(self, offset: int):
+        """The chunked-prefill program for chunks that start at ``offset``
+        (static: it sizes the gather of the cached prefix)."""
+        fn = self._chunk_jits.get(offset)
+        if fn is None:
+            attn_impl, mesh = self._attn_impl, self.mesh
+
+            def prefill_chunk(params, toks, k_pages, v_pages, tables, lens, *, cfg):
+                return llama.prefill_chunk(
+                    params, toks, k_pages, v_pages, tables, lens, cfg=cfg,
+                    q_offset=offset, attn_impl=attn_impl, mesh=mesh,
+                )
+
+            # the compiled program's name in a device trace: one per offset
+            prefill_chunk.__name__ = f"prefill_chunk_off{offset}"
+            fn = jax.jit(
+                prefill_chunk, static_argnames=("cfg",), donate_argnums=(2, 3)
+            )
+            self._chunk_jits[offset] = fn
+        return fn
+
     def _prefill_and_sample_mm(
         self, params, vparams, k_pages, v_pages, images, tokens, page_tables,
         seq_lens, key, temps, top_ps, top_ks, seeds,
@@ -1082,13 +1156,13 @@ class LLMEngine:
         if fn is None:
             dcfg = self.draft_cfg
 
-            def run(params, k_pages, v_pages, tokens, tables, seq_lens):
+            def draft_prefill(params, k_pages, v_pages, tokens, tables, seq_lens):
                 return llama.prefill(
                     params, tokens, k_pages, v_pages, tables, seq_lens, dcfg,
                     attn_impl=self._attn_impl, mesh=self.mesh,
                 )
 
-            fn = jax.jit(run, donate_argnums=(1, 2))
+            fn = jax.jit(draft_prefill, donate_argnums=(1, 2))
             self._draft_prefill_jits[key] = fn
         return fn
 
@@ -1500,6 +1574,8 @@ class LLMEngine:
                 jnp.full((B,), -1, jnp.int32),
             )
         jax.block_until_ready(self.cache.k_pages)
+        if self.profiler is not None:
+            self.profiler.note_drained()
         return time.monotonic() - t0
 
     def _finish_stream(self, req: Request, marker: "_Finish") -> None:
@@ -1518,6 +1594,9 @@ class LLMEngine:
         """THE terminal delivery: close the request's trace (sweeping any
         still-open spans — queue, decode — so no failure path can leak a
         dangling span) and only then release the caller's stream."""
+        sp = getattr(req, "_decode_span", None)
+        if sp is not None and req.last_token_at is not None:
+            sp.end = _rt.wall(req.last_token_at)  # the sweep keeps a set end
         _rt.finish_request(req, marker.reason, store=self._trace_store)
         # per-request usage record (usage.jsonl): journaled at the SAME
         # terminal point that releases the stream, with the ACCOUNTED
@@ -1535,9 +1614,10 @@ class LLMEngine:
         sp = getattr(req, "_queue_span", None)
         if sp is not None:
             req._queue_span = None
+            end = _rt.wall(req.admitted_at)  # now, for one never admitted
             _rt.finish(
-                req.trace, sp, store=self._trace_store,
-                wait_s=round(max(0.0, time.time() - sp.start), 6),
+                req.trace, sp, store=self._trace_store, end=end,
+                wait_s=round(max(0.0, end - sp.start), 6),
             )
 
     def abort(self, request: Request) -> None:
@@ -1592,9 +1672,13 @@ class LLMEngine:
                 raise OutOfPages(
                     f"prefill replica out of KV pages for {req.request_id}"
                 )
-            t_start = time.monotonic()
-            t_wall = time.time()
-            u_start = self._clock()  # usage meter: engine-clock domain
+            req.admitted_at = time.monotonic()
+            # no scheduler loop here: the call is one tick of its own
+            # (prefill_dispatch, then the blocking read as harvest)
+            prof = self.profiler
+            tick = None if prof is None else prof.begin_tick()
+            self._tick = tick
+            _tm(tick, "prefill_dispatch")
             try:
                 first = self._prefill_pages(req, claim)
             except Exception:
@@ -1602,12 +1686,14 @@ class LLMEngine:
                 # leak the claim or poison the trie with unwritten pages
                 self.release_claim(claim, valid=False)
                 raise
+            finally:
+                if tick is not None:
+                    self._tick = None
+                    prof.end_tick(tick, worked=True, demand=False)
             self.stats.prompt_tokens += claim["n_prompt"]
             self.usage.note_prompt(req, claim["n_prompt"])
-            self.usage.note_phase_seconds("prefill", self._clock() - u_start)
-            _obs.record_engine_phase("prefill", time.monotonic() - t_start)
             _rt.record_span(
-                req.trace, "prefill", start=t_wall,
+                req.trace, "prefill", start=_rt.wall(req.admitted_at),
                 parent=getattr(req, "_trace_parent", None),
                 store=self._trace_store, replica=self.trace_name,
                 n_prompt=claim["n_prompt"],
@@ -2063,6 +2149,8 @@ class LLMEngine:
             out_q.put(("err", RuntimeError("engine released all requests")))
         self._inflight.clear()
         self._pending_harvest.clear()
+        if self.profiler is not None:
+            self.profiler.note_drained()
         self._device_tokens = None
         self._last_dispatch_at = None
         # queue BEFORE slots: delivering an in-flight marker wakes that
@@ -2091,14 +2179,11 @@ class LLMEngine:
 
         Tick anatomy (docs/observability.md#hot-path-profiling): with the
         profiler on, the tick's host time is partitioned into the
-        catalog.TICK_PHASES via sequential ``_tm`` marks here and in the
-        helpers this calls; idle ticks record nothing."""
+        catalog.TICK_PHASES by the ``_tm`` phase entries here and in the
+        helpers this calls; an idle engine's ticks are not profiled."""
         # fault point (docs/faults.md): a scheduler-thread crash. _loop
         # catches the FaultError, fails every caller loudly, and survives.
         _inject.check("engine.scheduler_crash")
-        prof = self.profiler
-        tick = None if prof is None else prof.begin_tick()
-        self._tick = tick
         # fault point (docs/health.md): a SILENT scheduler freeze — the
         # thread stays alive, healthy() stays true, but no tick, dispatch,
         # or accept ever lands again. Nothing inside the engine ends it;
@@ -2117,19 +2202,28 @@ class LLMEngine:
             while self._running:
                 time.sleep(0.005)
             return False
-        self.watermarks.note_tick()
-        self._drain_ctrl()
-        _tm(tick, "ctrl")
-        self._expire_deadlines()
-        _tm(tick, "policy")
-        admitted = self._admit()
-        decoded = self._decode_tick()
-        self._refresh_gauges()
-        _tm(tick, "policy")
-        if tick is not None:
-            self._tick = None
-            prof.end_tick(tick, worked=admitted or decoded)
-        return admitted or decoded
+        prof = self.profiler
+        tick = None if prof is None else prof.begin_tick(self._has_demand())
+        self._tick = tick
+        worked = False
+        try:
+            _tm(tick, "ctrl")
+            self.watermarks.note_tick()
+            self._drain_ctrl()
+            _tm(tick, "policy")
+            self._expire_deadlines()
+            admitted = self._admit()
+            decoded = self._decode_tick()
+            _tm(tick, "policy")
+            self._refresh_gauges()
+            worked = admitted or decoded
+        finally:
+            # also on a scheduler error: the open span and its trace
+            # annotation close with the tick they belong to
+            if tick is not None:
+                self._tick = None
+                prof.end_tick(tick, worked, demand=self._has_demand())
+        return worked
 
     def _expire_deadlines(self) -> None:
         """Deadline enforcement, both stages: queued work past its deadline
@@ -2288,8 +2382,9 @@ class LLMEngine:
         block, so in-flight streams never wait on a prefill round trip."""
         tick = self._tick
         budget = self.prefill_budget or None  # None/0 = unlimited
-        spent = self._advance_pending_prefills(budget, 0)
         _tm(tick, "prefill_resume")
+        spent = self._advance_pending_prefills(budget, 0)
+        _tm(tick, "admit")
         assignments: list[tuple[int, "Request", dict]] = []  # (slot, req, claim)
         free_slots = [i for i, s in enumerate(self.slots) if s.free]
         entries = (
@@ -2356,6 +2451,7 @@ class LLMEngine:
             _obs.record_sched_queue_wait(
                 entry.priority, max(0.0, now - entry.enqueued_at)
             )
+            req.admitted_at = time.monotonic()
             self._close_queue_span(req)
             assignments.append((free_slots[taken], req, claim))
             taken += 1
@@ -2368,7 +2464,7 @@ class LLMEngine:
                 # as their state machine advances below
                 spent += claim["n_prompt"]
 
-        _tm(tick, "admit")
+        _tm(tick, "prefill_dispatch")
         long_ones: list[tuple] = []
         grouped: list[tuple] = []
         for a in assignments:
@@ -2411,13 +2507,12 @@ class LLMEngine:
 
                 traceback.print_exc()
                 self._fail_claims([a])
-        _tm(tick, "prefill_dispatch")
         if long_ones:
             # newly admitted long prompts advance with what remains of this
             # tick's budget (at least one chunk fires when nothing else
             # did: the progress guarantee)
-            spent = self._advance_pending_prefills(budget, spent)
             _tm(tick, "prefill_resume")
+            spent = self._advance_pending_prefills(budget, spent)
         return bool(assignments) or adopted_any or spent > 0
 
     def _admit_adopted(
@@ -2450,7 +2545,8 @@ class LLMEngine:
             req.trace = _rt.from_wire(
                 block.meta.get("trace"), store=self._trace_store
             )
-        t_wall = time.time()
+        req.admitted_at = time.monotonic()
+        t_wall = _rt.wall(req.admitted_at)
         try:
             adopt_pages(self.cache, block, pages[: block.n_pages])
         except TransportError as e:
@@ -2532,6 +2628,7 @@ class LLMEngine:
             pass
         else:
             self._accept_token(slot_idx, state["first_token"])
+            _tm(self._tick, "admit")  # _accept_token left the accounting in accept
         return "ok"
 
     def _fail_claims(self, chunk: list) -> None:
@@ -2663,31 +2760,25 @@ class LLMEngine:
             self._spec_ctrl.forget(slot.request.request_id)
 
     def _dispatch_prefill_chunk(
-        self, prompt_tokens: list, table, offset: int
+        self, prompt_tokens: list, table, offset: int, cached: int = 0
     ) -> "jax.Array":
         """Dispatch ONE bucket-sized prefill chunk (async — the logits come
         back as a device future, nothing blocks the host): the unit both
         the atomic loop (``_run_prefill_chunks``) and the budgeted state
         machine (``_advance_pending_prefills``) advance by, so the two
-        paths can never drift."""
-        import functools
-
+        paths can never drift. ``cached`` is how many leading prompt tokens
+        sit on cached pages (computed again all the same: the count at the
+        prefill boundary says so)."""
         C = self.prefill_buckets[-1]
         pad_tok = self.tokenizer.pad_id % self.cfg.vocab_size
         chunk = prompt_tokens[offset : offset + C]
         toks = np.full((1, C), pad_tok, np.int32)
         toks[0, : len(chunk)] = chunk
-        fn = self._chunk_jits.get(offset)
-        if fn is None:
-            fn = jax.jit(
-                functools.partial(
-                    llama.prefill_chunk, q_offset=offset,
-                    attn_impl=self._attn_impl, mesh=self.mesh,
-                ),
-                static_argnames=("cfg",),
-                donate_argnums=(2, 3),
-            )
-            self._chunk_jits[offset] = fn
+        _obs.record_prefill_positions(
+            computed=C,
+            needed=max(0, offset + len(chunk) - max(offset, cached)),
+        )
+        fn = self._chunk_jit(offset)
         logits, self.cache.k_pages, self.cache.v_pages = self._profiled(
             "prefill_chunk", f"off{offset}", fn
         )(
@@ -2716,7 +2807,9 @@ class LLMEngine:
             )
         return logits
 
-    def _run_prefill_chunks(self, prompt_tokens: list, table) -> "jax.Array":
+    def _run_prefill_chunks(
+        self, prompt_tokens: list, table, cached: int = 0
+    ) -> "jax.Array":
         """The atomic chunked-prefill loop (every chunk in one call), used
         by the slot-free disagg path (``_prefill_pages``) — the slot path
         runs the same chunks through the resumable state machine instead.
@@ -2725,7 +2818,9 @@ class LLMEngine:
         C = self.prefill_buckets[-1]
         logits = None
         for offset in range(0, n_prompt, C):
-            logits = self._dispatch_prefill_chunk(prompt_tokens, table, offset)
+            logits = self._dispatch_prefill_chunk(
+                prompt_tokens, table, offset, cached
+            )
         return logits
 
     def _prefill_pages(self, req: Request, claim: dict) -> int:
@@ -2739,7 +2834,9 @@ class LLMEngine:
         table[: len(pages)] = pages
         p = req.params
         if n_prompt > self.prefill_buckets[-1]:
-            logits = self._run_prefill_chunks(req.prompt_tokens, table)
+            logits = self._run_prefill_chunks(
+                req.prompt_tokens, table, req.cached_prompt_tokens
+            )
             # the ops-level first-token helper: eager sample() builds its
             # own small compiled programs — report them through the same
             # chokepoint as the big jits
@@ -2752,7 +2849,10 @@ class LLMEngine:
                 seeds=jnp.asarray([_req_seed(req)], np.int32),
                 step_ids=jnp.asarray([n_prompt], np.int32),
             )
-            return int(np.asarray(first)[0])
+            t0 = self._harvest_begin()
+            first = int(np.asarray(first)[0])
+            self._harvested(self._dispatch_seq(), "prefill", t0)
+            return first
         bucket = self._bucket_for(n_prompt)
         B = self.prefill_batch
         pad_tok = self.tokenizer.pad_id % self.cfg.vocab_size
@@ -2768,6 +2868,9 @@ class LLMEngine:
         seeds = np.full((B,), -1, np.int32)
         temps[0], top_ps[0], top_ks[0] = p.temperature, p.top_p, p.top_k
         seeds[0] = _req_seed(req)
+        _obs.record_prefill_positions(
+            computed=B * bucket, needed=n_prompt - req.cached_prompt_tokens
+        )
         next_tok, self.cache.k_pages, self.cache.v_pages = self._profiled(
             "prefill", f"b{bucket}x{B}", self._prefill_jit((bucket, B))
         )(
@@ -2783,7 +2886,10 @@ class LLMEngine:
             jnp.asarray(top_ks),
             jnp.asarray(seeds),
         )
-        return int(np.asarray(next_tok)[0])
+        t0 = self._harvest_begin()
+        first = int(np.asarray(next_tok)[0])
+        self._harvested(self._dispatch_seq(), "prefill", t0)
+        return first
 
     def _prefill_long(self, slot_idx: int, req: Request, claim: dict) -> None:
         """Begin a chunked prefill (prompts beyond the largest bucket) as a
@@ -2796,8 +2902,10 @@ class LLMEngine:
         engines dispatch every chunk in one tick — but the first-token
         read still defers to the harvest queue, behind the decode
         dispatch."""
-        t_start = time.monotonic()
-        _obs.record_engine_queue_wait(t_start - req.created)
+        # mtpu_engine_queue_wait_seconds ends at the start of the prefill
+        # call, after the tick's whole admission loop, not at admitted_at:
+        # the benchmark's queue_wait_p50_ms reads this series
+        _obs.record_engine_queue_wait(time.monotonic() - req.created)
         pages = claim["pages"]
         slot = self.slots[slot_idx]
         slot.request = req
@@ -2820,9 +2928,7 @@ class LLMEngine:
         table = np.zeros((self.pages_per_slot,), np.int32)
         table[: len(pages)] = pages
         self._page_tables[slot_idx] = table
-        slot.prefill = _PendingPrefill(
-            req=req, table=table, t_start=t_start, t_wall=time.time()
-        )
+        slot.prefill = _PendingPrefill(req=req, table=table)
 
     def _advance_pending_prefills(self, budget: int | None, spent: int) -> int:
         """Advance every mid-flight sliced prefill chunk by chunk until
@@ -2843,7 +2949,8 @@ class LLMEngine:
                     budget is None or spent == 0 or spent < budget
                 ):
                     pp.logits = self._dispatch_prefill_chunk(
-                        pp.req.prompt_tokens, pp.table, pp.offset
+                        pp.req.prompt_tokens, pp.table, pp.offset,
+                        pp.req.cached_prompt_tokens,
                     )
                     step = min(C, n_prompt - pp.offset)
                     pp.offset += step
@@ -2894,9 +3001,7 @@ class LLMEngine:
             first,
             [(slot_idx, req, 0, n_prompt, slot.tenancy)],
             {
-                "phase": "prefill_chunked",
-                "t_start": pp.t_start,
-                "t_wall": pp.t_wall,
+                "seq": self._dispatch_seq(),
                 "chunks": -(-n_prompt // self.prefill_buckets[-1]),
                 "ticks": pp.ticks,
             },
@@ -2913,10 +3018,9 @@ class LLMEngine:
         worked = False
         while self._pending_harvest:
             next_tok, rows, meta = self._pending_harvest.popleft()
-            u_start = self._clock()  # usage meter: engine-clock domain
+            t0 = self._harvest_begin()
             try:
                 next_np = np.asarray(next_tok)
-                _tm_device(tick, "harvest")
             except Exception:
                 # a prefill that failed ON DEVICE (materialization error):
                 # unwind every still-owned slot and release the callers —
@@ -2924,18 +3028,17 @@ class LLMEngine:
                 import traceback
 
                 traceback.print_exc()
+                _tm(tick, "accept")
                 for slot_idx, req, _row, _n, tenancy in rows:
                     s = self.slots[slot_idx]
                     if s.request is req and s.tenancy == tenancy:
                         self._fail_slot(slot_idx, req)
                 continue
-            _obs.record_engine_phase(
-                meta["phase"], time.monotonic() - meta["t_start"]
-            )
-            # roofline prefill seconds: the blocking-read interval on the
-            # injectable clock (the dispatch itself is async; this is
-            # where the host actually waits on prefill device work)
-            self.usage.note_phase_seconds("prefill", self._clock() - u_start)
+            # roofline prefill seconds: the harvest span (the dispatch
+            # itself is async; this is where the host actually waits on
+            # prefill device work)
+            self._harvested(meta["seq"], "prefill", t0)
+            t_first = _rt.wall(time.monotonic())  # where the prefill spans end
             u_calls = 1  # one dispatched program per harvest entry
             for slot_idx, req, row, n_prompt, tenancy in rows:
                 s = self.slots[slot_idx]
@@ -2975,10 +3078,11 @@ class LLMEngine:
                     s.last_token = int(next_np[row])
                 s.fresh = True
                 worked = True
-                if meta["phase"] == "prefill_chunked":
+                t_admitted = _rt.wall(req.admitted_at)
+                if "chunks" in meta:
                     sliced = meta["ticks"] > 1
                     _rt.record_span(
-                        req.trace, "prefill", start=meta["t_wall"],
+                        req.trace, "prefill", start=t_admitted, end=t_first,
                         store=self._trace_store, replica=self.trace_name,
                         n_prompt=n_prompt, chunked=True,
                         chunks=meta["chunks"], sliced=sliced,
@@ -2986,19 +3090,20 @@ class LLMEngine:
                     )
                     if sliced:
                         _rt.record_span(
-                            req.trace, "prefill_wait", start=meta["t_wall"],
+                            req.trace, "prefill_wait", start=t_admitted,
+                            end=t_first,
                             store=self._trace_store, replica=self.trace_name,
                             ticks=meta["ticks"], chunks=meta["chunks"],
                         )
                 else:
                     _rt.record_span(
-                        req.trace, "prefill", start=meta["t_wall"],
+                        req.trace, "prefill", start=t_admitted, end=t_first,
                         store=self._trace_store, replica=self.trace_name,
                         n_prompt=n_prompt, bucket=meta["bucket"],
                     )
                 req._decode_span = _rt.begin(
-                    req.trace, "decode", replica=self.trace_name,
-                    spec_mode=self.spec_mode or "-",
+                    req.trace, "decode", start=t_first,
+                    replica=self.trace_name, spec_mode=self.spec_mode or "-",
                 )
                 if rs is not None:
                     # resumed: the fed token was already accepted and its
@@ -3007,7 +3112,6 @@ class LLMEngine:
                     req._resume_state = None
                 else:
                     self._accept_token(slot_idx, s.last_token)
-            _tm(tick, "accept")
         return worked
 
     def _replay_decode_prefix(self, slot_idx: int, replay: list) -> None:
@@ -3101,9 +3205,8 @@ class LLMEngine:
 
     def _prefill_group(self, bucket: int, group: list, is_mm: bool = False) -> None:
         t_start = time.monotonic()
-        t_wall = time.time()  # span timestamps are wall-clock
         for _slot_idx, req, _claim in group:
-            _obs.record_engine_queue_wait(t_start - req.created)
+            _obs.record_engine_queue_wait(t_start - req.created)  # as _prefill_long
         B = self.prefill_batch  # fixed compile shape; short groups pad
         pad_tok = self.tokenizer.pad_id % self.cfg.vocab_size
         tokens = np.full((B, bucket), pad_tok, np.int32)
@@ -3151,6 +3254,13 @@ class LLMEngine:
             seeds[i] = _req_seed(req)
             if is_mm:
                 images[i] = req.image
+        _obs.record_prefill_positions(
+            computed=B * bucket,
+            needed=sum(
+                claim["n_prompt"] - req.cached_prompt_tokens
+                for _slot_idx, req, claim in group
+            ),
+        )
 
         if is_mm:
             next_tok, self.cache.k_pages, self.cache.v_pages = (
@@ -3220,16 +3330,12 @@ class LLMEngine:
         self._pending_harvest.append((
             next_tok,
             rows,
-            {
-                "phase": "prefill",
-                "t_start": t_start,
-                "t_wall": t_wall,
-                "bucket": bucket,
-            },
+            {"seq": self._dispatch_seq(), "bucket": bucket},
         ))
 
     def _decode_tick(self) -> bool:
         tick = self._tick
+        _tm(tick, "policy")
         # fault point (docs/faults.md): one stalled decode tick — a slow
         # collective, a preempted host thread. Latency only; the tick then
         # proceeds normally and requests still terminate.
@@ -3260,7 +3366,6 @@ class LLMEngine:
                     self._release_slot_pages(s)
                 s.request = None
                 self._active[i] = False
-        _tm(tick, "policy")
         live = [i for i, s in enumerate(self.slots) if s.decodable]
 
         if self.spec_gamma:
@@ -3270,6 +3375,7 @@ class LLMEngine:
             live = [i for i, s in enumerate(self.slots) if s.decodable]
             if not live:
                 return worked
+            _tm(tick, "admit")  # spec batch staging: slot-state bookkeeping
             self._active[:] = False
             # reset dead-slot sampling params (same rationale as
             # _dispatch_block: stale top_p/top_k keeps sample()'s runtime
@@ -3314,7 +3420,6 @@ class LLMEngine:
                 gammas = np.minimum(
                     gammas, ngram_props[1].astype(np.int32)
                 )
-            _tm(tick, "admit")  # spec batch staging: slot-state bookkeeping
             if not any(gammas[i] for i in live):
                 # whole-round fallback: nobody speculates this round
                 # (pressure, collapse, or sampling lanes only) — the
@@ -3360,8 +3465,8 @@ class LLMEngine:
         per-block snapshot pins request identity so the host drops output
         rows whose slot was recycled.
         """
-        tick = self._tick
-        now = time.monotonic()
+        _tm(self._tick, "decode_dispatch")
+        now = self._clock()
         if self._last_dispatch_at is not None:
             # dispatch-to-dispatch gap while decodable slots existed the
             # whole time: the stall the prefill budget bounds to ~one chunk
@@ -3471,25 +3576,22 @@ class LLMEngine:
                 for i in live
             ],
             None,  # spec_meta: classic/macro-step blocks carry none
+            self._dispatch_seq(),
         ))
         for i in live:
             self._opt_positions[i] += n
-        _tm(tick, "decode_dispatch")
 
     def _process_block(self) -> bool:
         tick = self._tick
-        toks, valid, snapshot, spec_meta = self._inflight.popleft()
-        t_wait = time.monotonic()
-        u_start = self._clock()  # usage meter: engine-clock domain
+        toks, valid, snapshot, spec_meta, seq = self._inflight.popleft()
+        t0 = self._harvest_begin()
         toks_np = np.asarray(toks)  # [K, B] — the ONE blocking read per block
         # the macro-step harvest plane (docs/multistep.md): the validity
         # mask rides the SAME round trip as the tokens — per-slot accept
         # stops at the first invalid row (the lane died at its stop token
         # or length budget on-device; in a spec round, at its accept cut)
         valid_np = None if valid is None else np.asarray(valid)
-        _obs.record_engine_phase("decode_wait", time.monotonic() - t_wait)
-        self.usage.note_phase_seconds("decode", self._clock() - u_start)
-        _tm_device(tick, "harvest")
+        self._harvested(seq, "decode", t0)
         n_steps = int(toks_np.shape[0])
         # only steps with a live lane executed (masked_scan's cond skips
         # the rest once every lane died): count the truth, not the
@@ -3579,7 +3681,6 @@ class LLMEngine:
             prof = self.profiler
             if prof is not None:
                 prof.note_dispatch_tokens(accepted, steps=1)
-        _tm(tick, "accept")
         return worked
 
     def _slot_gamma(
@@ -3621,8 +3722,8 @@ class LLMEngine:
         validity plane). Spec rounds never pipeline — the next round's
         positions depend on this round's acceptance — so the block is
         processed immediately after dispatch."""
-        tick = self._tick
-        now = time.monotonic()
+        _tm(self._tick, "decode_dispatch")
+        now = self._clock()
         if self._last_dispatch_at is not None:
             _obs.record_decode_stall(now - self._last_dispatch_at)
         self._last_dispatch_at = now
@@ -3699,8 +3800,8 @@ class LLMEngine:
                 for i in live
             ],
             {"gammas": gammas, "proposed": proposed},
+            self._dispatch_seq(),
         ))
-        _tm(tick, "decode_dispatch")
         return self._process_block()
 
     def _accept_token(self, slot_idx: int, token: int) -> None:
@@ -3733,6 +3834,8 @@ class LLMEngine:
             req.first_token_at = now
             if req.tenant != _CANARY_TENANT:
                 _obs.record_ttft(now - req.created)
+                if req.admitted_at is not None:
+                    _obs.record_first_token_wait(now - req.admitted_at)
             if req.trace is not None:
                 req.trace.root.attrs["ttft_s"] = round(now - req.created, 6)
         elif req.tenant != _CANARY_TENANT:
@@ -3769,7 +3872,7 @@ class LLMEngine:
                 w = self._ensure_detok()
             if w.alive:
                 tick = self._tick
-                _tm(tick, "accept")
+                _tm_inner(tick, "detokenize")
                 if not w.owns(req):
                     prior = (
                         slot.generated[:-1] if appended
@@ -3782,7 +3885,7 @@ class LLMEngine:
                 if appended:
                     w.feed(req, token)
                 # enqueue cost only: the decode itself runs off-thread
-                _tm(tick, "detokenize")
+                _tm_inner(tick, "accept")
                 if finished:
                     # release BEFORE the finish marker is enqueued: the
                     # worker thread can deliver it (and wake the client)
@@ -3797,12 +3900,11 @@ class LLMEngine:
         # incremental detokenization: emit the stable new suffix. Profiled
         # as its own phase (the ROADMAP #3 "move detokenization off the
         # scheduler thread" candidate needs its cost attributed first):
-        # everything since the last mark is accept bookkeeping, the decode
-        # call itself is detokenize.
+        # the decode call itself is detokenize, all around it accept.
         tick = self._tick
-        _tm(tick, "accept")
+        _tm_inner(tick, "detokenize")
         text = self.tokenizer.decode(slot.generated)
-        _tm(tick, "detokenize")
+        _tm_inner(tick, "accept")
         if req.params.stop:
             for stop_s in req.params.stop:
                 idx = text.find(stop_s)

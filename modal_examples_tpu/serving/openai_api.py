@@ -173,7 +173,7 @@ class _Handler(BaseHTTPRequestHandler):
             active = sum(1 for sl in eng.slots if not sl.free)
             pc = eng.prefix_cache
             # the process registry carries the engine's histogram/gauge series
-            # (mtpu_engine_phase_seconds etc., recorded by the batch loop) —
+            # (mtpu_tick_phase_seconds etc., recorded by the batch loop) —
             # without it a scraper could never see the latency distributions
             reg_text = default_registry.expose()
             reg_names = set(re.findall(r"^# TYPE (\S+)", reg_text, re.M))

@@ -11,6 +11,8 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 
+from ..ops.scopes import SAMPLING
+
 
 @dataclasses.dataclass(frozen=True)
 class SamplingParams:
@@ -55,6 +57,7 @@ def seeded_row_keys(
     return jax.vmap(row_key)(jnp.arange(B))
 
 
+@jax.named_scope(SAMPLING)
 def sample(
     logits: jax.Array,  # [B, V] f32
     key: jax.Array,

@@ -261,13 +261,17 @@ def _profile_snapshot(last: int = 20) -> dict:
     ``<state_dir>/compiles.jsonl`` — the ``/profile`` route's payload
     (``tpurun profile`` renders the same data from pushed metrics + the
     ledger; docs/observability.md#hot-path-profiling). Empty ``replicas``
-    means no engine in this process runs with MTPU_PROFILE on."""
+    means this process holds no engine, or runs under MTPU_PROFILE=0."""
     from ..observability import profiler as _prof
 
     replicas = {}
     for p in _prof.active_profilers():
+        summary = p.overhead_summary()
+        seen = replicas.get(p.replica)
+        if seen is not None and seen["summary"]["ticks"] >= summary["ticks"]:
+            continue  # two engines under one name: the busier one speaks
         replicas[p.replica] = {
-            "summary": p.overhead_summary(),
+            "summary": summary,
             "perfetto": p.perfetto_snapshot(),
         }
     # the unfinished scan reads a DEEP tail regardless of the display size

@@ -31,10 +31,11 @@ def test_bench_emits_schema_json():
     assert payload["value"] > 0
     assert payload["unit"] == "tok/s"
     # phase-attributed latency: every BENCH_*.json carries p50/p95/p99 per
-    # engine phase from the observability histograms (docs/observability.md)
+    # scheduler-tick phase from the observability histograms
+    # (docs/observability.md)
     pl = payload.get("phase_latency")
     assert pl, payload
-    some = pl.get("prefill") or pl.get("decode_wait")
+    some = pl.get("harvest") or pl.get("decode_dispatch")
     assert some and {"p50", "p95", "p99", "count"} <= set(some)
     # token-level serving latency (ISSUE-3): TTFT/TPOT p50/p95 + tokens/s
     # ride alongside phase_latency in every BENCH json
@@ -65,7 +66,7 @@ def test_bench_emits_schema_json():
     assert payload["tokens_per_second"] == payload["value"]
     # hot-path overhead attribution (docs/observability.md#hot-path-
     # profiling): EVERY bench config's json carries the `overhead` section
-    # — bench children run MTPU_PROFILE=1 — with per-phase attribution
+    # — the profiler is on by default — with per-phase attribution
     # summing to ~the tick duration (cover ≤ 1 structurally: sequential
     # marks partition the tick) and a nonzero compile ledger. Structure
     # only — wall-clock DIRECTION lives behind the on-chip benchdiff gate.

@@ -1,0 +1,138 @@
+"""The names a device trace shows: every program the engine jits has a
+stable name (none is ``jit__unknown``), and the operations inside carry the
+``jax.named_scope`` of the layer's part they belong to (ops/scopes.py).
+Lowered on the CPU at a tiny size; nothing runs."""
+
+import re
+
+import pytest
+
+from modal_examples_tpu.ops import scopes
+
+
+@pytest.fixture(scope="module", params=["dense", "moe"])
+def engine(request):
+    from modal_examples_tpu.models import llama
+    from modal_examples_tpu.serving import LLMEngine
+
+    cfg = (
+        llama.LlamaConfig.tiny() if request.param == "dense"
+        else llama.LlamaConfig.tiny_moe()
+    )
+    return LLMEngine(
+        cfg, max_slots=4, max_model_len=128, prefill_buckets=(32, 64),
+    )
+
+
+def _lowered(jitted, *args, **kwargs):
+    text = jitted.lower(*args, **kwargs).as_text(debug_info=True)
+    return re.search(r"module @(\S+)", text).group(1), text
+
+
+def _block_args(eng):
+    import jax.numpy as jnp
+
+    B = eng.max_slots
+    return (
+        eng.params, eng.cache.k_pages, eng.cache.v_pages,
+        jnp.zeros((B,), jnp.int32), jnp.zeros((B,), jnp.int32),
+        jnp.zeros((B,), bool), jnp.zeros((B,), jnp.int32),
+        jnp.zeros((B, eng.pages_per_slot), jnp.int32), jnp.zeros((B,), bool),
+        eng._next_key(), jnp.ones((B,), jnp.float32),
+        jnp.ones((B,), jnp.float32), jnp.zeros((B,), jnp.int32),
+        jnp.full((B,), -1, jnp.int32),
+    )
+
+
+def _mlp_scopes(eng):
+    if eng.cfg.n_experts > 0:
+        return {scopes.ROUTER, scopes.EXPERT_SCAN}
+    return {scopes.DENSE_MLP}
+
+
+def test_decode_block_program_is_named_and_scoped(engine):
+    name, text = _lowered(engine._block_jit, *_block_args(engine))
+    assert name == "jit__decode_block_fn"  # what programs.json's pattern finds
+    wanted = {
+        scopes.PAGE_GATHER, scopes.ATTENTION, scopes.KV_SCATTER,
+        scopes.SAMPLING, *_mlp_scopes(engine),
+    }
+    found = set(re.findall(r"mtpu\.[a-z_]+", text))
+    assert wanted <= found, wanted - found
+
+
+def test_chunk_program_is_named_and_scoped(engine):
+    import jax.numpy as jnp
+
+    C = engine.prefill_buckets[-1]
+    name, text = _lowered(
+        engine._chunk_jit(C),
+        engine.params, jnp.zeros((1, C), jnp.int32),
+        engine.cache.k_pages, engine.cache.v_pages,
+        jnp.zeros((1, engine.pages_per_slot), jnp.int32),
+        jnp.asarray([C], jnp.int32), cfg=engine.cfg,
+    )
+    # one program per chunk offset, and "prefill" leads: the benchmark's
+    # pattern for prefill programs (^jit_+prefill) finds it
+    assert name == f"jit_prefill_chunk_off{C}"
+    wanted = {
+        scopes.PAGE_GATHER, scopes.ATTENTION, scopes.KV_SCATTER,
+        *_mlp_scopes(engine),
+    }
+    found = set(re.findall(r"mtpu\.[a-z_]+", text))
+    assert wanted <= found, wanted - found
+
+
+def test_bucket_prefill_program_is_named_and_scoped(engine):
+    import jax.numpy as jnp
+
+    B, bucket = engine.prefill_batch, engine.prefill_buckets[0]
+    name, text = _lowered(
+        engine._prefill_jit((bucket, B)),
+        engine.params, engine.cache.k_pages, engine.cache.v_pages,
+        jnp.zeros((B, bucket), jnp.int32),
+        jnp.zeros((B, engine.pages_per_slot), jnp.int32),
+        jnp.ones((B,), jnp.int32), engine._next_key(),
+        jnp.ones((B,), jnp.float32), jnp.ones((B,), jnp.float32),
+        jnp.zeros((B,), jnp.int32), jnp.full((B,), -1, jnp.int32),
+    )
+    assert name == "jit__prefill_and_sample"
+    wanted = {
+        scopes.ATTENTION, scopes.KV_SCATTER, scopes.SAMPLING,
+        *_mlp_scopes(engine),
+    }
+    found = set(re.findall(r"mtpu\.[a-z_]+", text))
+    assert wanted <= found, wanted - found
+
+
+def test_every_scope_is_one_the_list_names():
+    assert len(set(scopes.ALL)) == len(scopes.ALL) == 7
+    assert all(s.startswith("mtpu.") for s in scopes.ALL)
+
+
+def test_no_engine_program_is_left_unnamed():
+    """``jax.jit`` of a ``functools.partial`` or a lambda is ``jit__unknown``
+    / ``jit__lambda_`` in a trace: the engine jits named functions only."""
+    import ast
+    from pathlib import Path
+
+    import modal_examples_tpu.serving.engine as engine_mod
+
+    tree = ast.parse(Path(engine_mod.__file__).read_text())
+    bad = []
+    for node in ast.walk(tree):
+        if (
+            isinstance(node, ast.Call)
+            and isinstance(node.func, ast.Attribute)
+            and node.func.attr == "jit"
+            and isinstance(node.func.value, ast.Name)
+            and node.func.value.id == "jax"
+            and node.args
+        ):
+            target = node.args[0]
+            if isinstance(target, ast.Lambda) or (
+                isinstance(target, ast.Call)
+                and "partial" in ast.unparse(target.func)
+            ):
+                bad.append(f"line {node.lineno}: jax.jit({ast.unparse(target)[:40]})")
+    assert not bad, bad

@@ -1,10 +1,13 @@
 """Hot-path profiler (observability/profiler.py, docs/observability.md):
-fake-clock phase-attribution matrix, the zero-cost disabled gate (behavioral
-AND AST-pinned, like the faults gate), compile-ledger schema + cache-hit
+fake-clock phase-attribution matrix over scoped spans, the trace-annotation
+order, device starvation under scripted dispatch/harvest sequences, the
+on-by-default switch and the zero-cost disabled gate (behavioral AND
+AST-pinned, like the faults gate), compile-ledger schema + miss/hit
 accounting, and the CLI/gateway surfaces."""
 
 import ast
 import json
+import os
 import shutil
 from pathlib import Path
 
@@ -52,8 +55,8 @@ class TestTickAttribution:
         }
         tick = prof.begin_tick()
         for phase, dt in deltas.items():
+            tick.enter(phase, device=(phase == "harvest"))
             clk.advance(dt)
-            tick.mark(phase, device=(phase == "harvest"))
         prof.end_tick(tick, worked=True)
 
         [entry] = prof.perfetto_snapshot()["ticks"]
@@ -92,32 +95,275 @@ class TestTickAttribution:
             clock=clk, name="t-idle", registry=reg,
             ledger_path=tmp_path / "compiles.jsonl",
         )
-        # worked=False: even a marked tick is discarded
+        # worked=False: even a tick that entered phases is discarded
         tick = prof.begin_tick()
+        tick.enter("ctrl")
         clk.advance(0.5)
-        tick.mark("ctrl")
         prof.end_tick(tick, worked=False)
-        # worked=True but nothing marked (no phases): also discarded
+        # worked=True but no phase entered: also discarded
         prof.end_tick(prof.begin_tick(), worked=True)
+        # no request queued or running, nothing on the device: the tick
+        # is not profiled at all (no timestamp is taken)
+        assert prof.begin_tick(demand=False) is None
         assert prof.perfetto_snapshot()["ticks"] == []
         assert prof.overhead_summary()["ticks"] == 0
         assert reg.histogram_quantiles(
             C.TICK_PHASE_SECONDS, labels={"phase": "ctrl"}
         ) is None
 
-    def test_mark_partitions_are_cumulative(self):
-        """Two marks of one phase in a tick accumulate (the _admit path
-        marks prefill_resume twice)."""
+    def test_spans_of_one_phase_accumulate(self):
+        """Two spans of one phase in a tick accumulate (the _admit path
+        enters prefill_resume twice), and enter() returns the seconds of
+        the span it closed (the harvest span's, for the roofline meter)."""
         clk = ManualClock()
         prof = P.HotPathProfiler(clock=clk, registry=Registry())
         tick = prof.begin_tick()
+        tick.enter("prefill_resume")
         clk.advance(0.002)
-        tick.mark("prefill_resume")
+        assert tick.enter("admit") == pytest.approx(0.002)
+        clk.advance(0.001)
+        tick.enter("prefill_resume")
         clk.advance(0.003)
-        tick.mark("prefill_resume")
         prof.end_tick(tick, worked=True)
         [entry] = prof.perfetto_snapshot()["ticks"]
         assert entry["phases"]["prefill_resume"] == pytest.approx(0.005)
+        assert entry["phases"]["admit"] == pytest.approx(0.001)
+
+    def test_scoped_phases_partition_a_tick(self):
+        """Entered one after the other, the phases cover the tick: what is
+        not attributed is only what passed before the first was entered."""
+        clk = ManualClock()
+        prof = P.HotPathProfiler(clock=clk, registry=Registry())
+        tick = prof.begin_tick()
+        clk.advance(0.0001)  # before the first phase: unattributed
+        for phase in C.TICK_PHASES:
+            tick.enter(phase)
+            clk.advance(0.004)
+            tick.enter("detokenize", annotate=False)  # a per-token split
+            clk.advance(0.001)
+            tick.enter(phase, annotate=False)
+            clk.advance(0.001)
+        prof.end_tick(tick, worked=True)
+        summary = prof.overhead_summary()
+        assert 0.95 <= summary["attribution_cover"] <= 1.0
+        [entry] = prof.perfetto_snapshot()["ticks"]
+        assert sum(entry["phases"].values()) == pytest.approx(
+            entry["total"] - 0.0001
+        )
+
+
+class FakeAnnotations:
+    """The engine hands the profiler ``jax.profiler.TraceAnnotation``; the
+    tests hand it this: every enter and exit, in order."""
+
+    def __init__(self):
+        self.log: list[tuple] = []
+
+    def __call__(self, name, **attrs):
+        outer = self
+
+        class _Ann:
+            def __enter__(self):
+                outer.log.append(("enter", name, attrs))
+
+            def __exit__(self, *exc):
+                outer.log.append(("exit", name))
+
+        return _Ann()
+
+
+class TestAnnotations:
+    def test_every_phase_and_dispatch_opens_and_closes_in_order(self, tmp_path):
+        clk = ManualClock()
+        ann = FakeAnnotations()
+        prof = P.HotPathProfiler(
+            clock=clk, registry=Registry(), annotate=ann,
+            ledger_path=tmp_path / "compiles.jsonl",
+        )
+        tick = prof.begin_tick()
+        tick.enter("admit")
+        tick.enter("prefill_dispatch")
+        assert prof.dispatch("prefill", "b32x4", lambda x: x + 1, (1,), {}) == 2
+        tick.enter("accept")
+        tick.enter("detokenize", annotate=False)  # accounting only
+        tick.enter("accept", annotate=False)
+        prof.end_tick(tick, worked=True)
+        assert ann.log == [
+            ("enter", "mtpu.tick/admit", {}),
+            ("exit", "mtpu.tick/admit"),
+            ("enter", "mtpu.tick/prefill_dispatch", {}),
+            ("enter", "mtpu.dispatch/prefill", {"shape": "b32x4"}),
+            ("exit", "mtpu.dispatch/prefill"),
+            ("exit", "mtpu.tick/prefill_dispatch"),
+            ("enter", "mtpu.tick/accept", {}),
+            ("exit", "mtpu.tick/accept"),
+        ]
+
+    def test_a_dispatch_that_raises_still_closes_its_annotation(self, tmp_path):
+        ann = FakeAnnotations()
+        prof = P.HotPathProfiler(
+            clock=ManualClock(), registry=Registry(), annotate=ann,
+            ledger_path=tmp_path / "compiles.jsonl",
+        )
+
+        def boom():
+            raise ValueError("no")
+
+        with pytest.raises(ValueError):
+            prof.dispatch("block", "s4k8", boom, (), {})
+        assert [e[:2] for e in ann.log] == [
+            ("enter", "mtpu.dispatch/block"), ("exit", "mtpu.dispatch/block"),
+        ]
+        assert prof.dispatched == 0  # nothing reached the device
+
+
+class TestStarvation:
+    """mtpu_device_starved_seconds_total: scheduler-thread time with
+    nothing dispatched and unharvested while a request is queued or
+    running, under the phase the thread was in."""
+
+    def _prof(self, tmp_path):
+        clk = ManualClock()
+        reg = Registry()
+        prof = P.HotPathProfiler(
+            clock=clk, registry=reg, ledger_path=tmp_path / "compiles.jsonl"
+        )
+        return clk, reg, prof
+
+    @staticmethod
+    def _starved(reg):
+        return {
+            phase: reg.value(
+                C.DEVICE_STARVED_SECONDS_TOTAL, labels={"phase": phase}
+            )
+            for phase in C.TICK_PHASES
+            if reg.value(
+                C.DEVICE_STARVED_SECONDS_TOTAL, labels={"phase": phase}
+            )
+        }
+
+    def test_admission_wait_with_nothing_in_flight_is_all_starved(self, tmp_path):
+        """A request reaches an idle engine: until the prefill is
+        dispatched the device has nothing, and all of it is `admit`'s and
+        `prefill_dispatch`'s."""
+        clk, reg, prof = self._prof(tmp_path)
+        tick = prof.begin_tick(demand=True)
+        tick.enter("admit")
+        clk.advance(0.030)
+        tick.enter("prefill_dispatch")
+        clk.advance(0.004)  # host work before the program is queued
+        prof.dispatch("prefill", "b32x4", lambda: None, (), {})
+        clk.advance(0.010)  # after the dispatch: the device has work
+        tick.enter("harvest", device=True)
+        clk.advance(0.100)
+        tick.enter("accept")
+        prof.note_harvest(prof.dispatched, tick)
+        prof.end_tick(tick, worked=True, demand=False)
+        got = self._starved(reg)
+        assert got == {
+            "admit": pytest.approx(0.030),
+            "prefill_dispatch": pytest.approx(0.004),
+        }
+
+    def test_a_pipelined_second_block_leaves_no_starvation(self, tmp_path):
+        """Block 2 is dispatched before block 1 is harvested: the device
+        always has one queued, whatever the host does in between."""
+        clk, reg, prof = self._prof(tmp_path)
+        tick = prof.begin_tick(demand=True)
+        tick.enter("decode_dispatch")
+        prof.dispatch("block", "s4k8", lambda: None, (), {})
+        first = prof.dispatched
+        prof.end_tick(tick, worked=True, demand=True)
+        for _ in range(3):
+            tick = prof.begin_tick(demand=True)
+            tick.enter("decode_dispatch")
+            clk.advance(0.002)
+            prof.dispatch("block", "s4k8", lambda: None, (), {})
+            second = prof.dispatched
+            tick.enter("harvest", device=True)
+            clk.advance(0.050)
+            tick.enter("accept")
+            prof.note_harvest(first, tick)  # the OLDER block: one still runs
+            clk.advance(0.005)
+            prof.end_tick(tick, worked=True, demand=True)
+            first = second
+        assert self._starved(reg) == {}
+
+    def test_harvest_of_the_last_block_starts_the_account(self, tmp_path):
+        """Nothing queued behind the harvested block: accept, the next
+        tick's bookkeeping and its dispatch preparation all starve the
+        device, each under its own phase, until the next dispatch."""
+        clk, reg, prof = self._prof(tmp_path)
+        tick = prof.begin_tick(demand=True)
+        tick.enter("decode_dispatch")
+        prof.dispatch("block", "s4k8", lambda: None, (), {})
+        tick.enter("harvest", device=True)
+        clk.advance(0.050)
+        tick.enter("accept")
+        prof.note_harvest(prof.dispatched, tick)
+        clk.advance(0.003)
+        prof.end_tick(tick, worked=True, demand=True)
+        clk.advance(0.001)  # between ticks: goes to the next one's first phase
+        tick = prof.begin_tick(demand=True)
+        tick.enter("ctrl")
+        clk.advance(0.002)
+        tick.enter("decode_dispatch")
+        clk.advance(0.016)
+        prof.dispatch("block", "s4k8", lambda: None, (), {})
+        clk.advance(0.004)
+        prof.end_tick(tick, worked=True, demand=True)
+        assert self._starved(reg) == {
+            "accept": pytest.approx(0.003),
+            "ctrl": pytest.approx(0.003),
+            "decode_dispatch": pytest.approx(0.016),
+        }
+
+    def test_a_block_finished_but_unread_counts_as_running(self, tmp_path):
+        """The bound is a LOWER one. The closed cells' stall: a decode
+        block and a first token are dispatched and unread, the eager
+        sampler has drained the device, and admission then takes 0.85 s of
+        host time. By dispatch and harvest numbers the device still has
+        work, so none of it is counted (the trace annotations see it:
+        ``tpurun profile --xplane``); the account opens at the harvest
+        that finds nothing queued behind it."""
+        clk, reg, prof = self._prof(tmp_path)
+        tick = prof.begin_tick(demand=True)
+        tick.enter("decode_dispatch")
+        prof.dispatch("block", "s4k8", lambda: None, (), {})
+        tick.enter("prefill_resume")
+        prof.dispatch("sample", "first_token", lambda: None, (), {})
+        clk.advance(0.400)
+        tick.enter("admit")
+        clk.advance(0.850)  # the device ran dry in here; nothing was read
+        tick.enter("harvest", device=True)
+        clk.advance(0.001)
+        tick.enter("accept")
+        prof.note_harvest(prof.dispatched, tick)
+        assert self._starved(reg) == {}
+        clk.advance(0.005)
+        prof.end_tick(tick, worked=True, demand=True)
+        assert self._starved(reg) == {"accept": pytest.approx(0.005)}
+
+    def test_an_idle_engine_accrues_none(self, tmp_path):
+        clk, reg, prof = self._prof(tmp_path)
+        # a finished engine: the last block was harvested, no request left
+        tick = prof.begin_tick(demand=True)
+        tick.enter("decode_dispatch")
+        prof.dispatch("block", "s4k8", lambda: None, (), {})
+        tick.enter("harvest", device=True)
+        tick.enter("accept")
+        prof.note_harvest(prof.dispatched, tick)
+        prof.end_tick(tick, worked=True, demand=False)
+        for _ in range(100):
+            clk.advance(0.002)
+            assert prof.begin_tick(demand=False) is None
+        assert self._starved(reg) == {}
+        # after a warm-up outside the loop nothing is outstanding either
+        prof.dispatch("block", "s4k8", lambda: None, (), {})
+        prof.note_drained()
+        clk.advance(5.0)
+        assert prof.begin_tick(demand=False) is None
+        assert self._starved(reg) == {}
 
 
 # ---------------------------------------------------------------------------
@@ -134,14 +380,11 @@ class TestCompileTelemetry:
             clock=clk, name="t-cc", registry=reg, ledger_path=ledger
         )
         # first dispatch: a miss — timed, ledgered (begin THEN end)
-        t0 = prof.compile_begin("block", "s4k8")
-        assert t0 is not None
-        clk.advance(1.5)
-        prof.compile_end("block", "s4k8", t0)
+        assert prof.dispatch(
+            "block", "s4k8", lambda: clk.advance(1.5) or "out", (), {}
+        ) == "out"
         # second dispatch of the same key: a hit — counted, not ledgered
-        t1 = prof.compile_begin("block", "s4k8")
-        assert t1 is None
-        prof.compile_end("block", "s4k8", t1)
+        prof.dispatch("block", "s4k8", lambda: None, (), {})
 
         rows = [json.loads(l) for l in ledger.read_text().splitlines()]
         assert [r["event"] for r in rows] == ["begin", "end"]
@@ -178,14 +421,54 @@ class TestCompileTelemetry:
             clock=clk, name="t-dead", registry=Registry(),
             ledger_path=tmp_path / "compiles.jsonl",
         )
-        done = prof.compile_begin("prefill", "b256x4")
-        prof.compile_end("prefill", "b256x4", done)
-        prof.compile_begin("block", "s44k8")  # never ends: the crash
+        prof.dispatch("prefill", "b256x4", lambda: None, (), {})
+
+        class Died(BaseException):
+            """The process dying mid-build, as far as a test can."""
+
+        def build_and_die():
+            raise Died
+
+        with pytest.raises(Died):
+            prof.dispatch("block", "s44k8", build_and_die, (), {})
         rows = P.read_ledger(tmp_path / "compiles.jsonl")
         open_builds = P.unfinished_builds(rows)
         assert [(r["program"], r["shape_key"]) for r in open_builds] == [
             ("block", "s44k8")
         ]
+        # the failed build is forgotten: the retry is a fresh miss
+        prof.dispatch("block", "s44k8", lambda: None, (), {})
+        assert not P.unfinished_builds(
+            P.read_ledger(tmp_path / "compiles.jsonl")
+        )
+
+    def test_a_rebuild_under_a_seen_key_is_a_miss(self, tmp_path):
+        """XLA builds a program again when an argument's shape changes,
+        whatever (program, shape_key) the engine calls it by: the jitted
+        function's own cache grows during the call, and that is a miss."""
+        import jax
+        import jax.numpy as jnp
+
+        reg = Registry()
+        prof = P.HotPathProfiler(
+            clock=ManualClock(), registry=reg,
+            ledger_path=tmp_path / "compiles.jsonl",
+        )
+        fn = jax.jit(lambda x: x + 1)
+        for n in (4, 4, 8, 8, 4):
+            prof.dispatch("block", "s4k8", fn, (jnp.zeros((n,)),), {})
+        miss = reg.value(
+            C.COMPILES_TOTAL, labels={"program": "block", "cache": "miss"}
+        )
+        hit = reg.value(
+            C.COMPILES_TOTAL, labels={"program": "block", "cache": "hit"}
+        )
+        assert (miss, hit) == (2.0, 3.0)
+        ends = [
+            r for r in P.read_ledger(tmp_path / "compiles.jsonl")
+            if r["event"] == "end"
+        ]
+        assert len(ends) == 2
 
 
 # ---------------------------------------------------------------------------
@@ -205,6 +488,10 @@ def profiled_engine(tmp_path_factory):
         prefill_buckets=(32, 64),
         profile=True,  # explicit arg beats env: no monkeypatching needed
     )
+    # every engine of every xdist worker writes the one session ledger now
+    # that the profiler is on by default: a name of its own tells this
+    # engine's builds from one still compiling in another worker
+    eng.trace_name = f"profiled-{os.getpid()}"
     eng.start()
     reqs = [
         eng.submit(
@@ -270,23 +557,48 @@ class TestEngineIntegration:
         assert eng.profiler is None
         assert eng._tick is None
 
-    def test_env_resolves_once_like_kv_dtype(self, monkeypatch):
+    def test_the_roofline_meter_keeps_its_seconds_with_the_profiler_off(self):
+        """`MTPU_PROFILE=0` switches the spans off, not the device seconds
+        under the MFU/MBU gauges: unprofiled, the blocking reads are timed
+        on the engine's clock directly."""
         from modal_examples_tpu.models import llama
-        from modal_examples_tpu.serving import LLMEngine
+        from modal_examples_tpu.serving import LLMEngine, SamplingParams
 
-        monkeypatch.setenv("MTPU_PROFILE", "1")
         eng = LLMEngine(
-            llama.LlamaConfig.tiny(), max_slots=2, max_model_len=64,
-            prefill_buckets=(32,),
-        )
-        assert eng.profiler is not None
-        # explicit arg beats env
-        monkeypatch.setenv("MTPU_PROFILE", "1")
-        eng2 = LLMEngine(
             llama.LlamaConfig.tiny(), max_slots=2, max_model_len=64,
             prefill_buckets=(32,), profile=False,
         )
-        assert eng2.profiler is None
+        eng.start()
+        try:
+            params = SamplingParams(max_tokens=6, temperature=0.0)
+            "".join(eng.stream(eng.submit("the quick brown fox", params)))
+        finally:
+            eng.stop()
+        seconds = eng.usage._phase_seconds
+        assert seconds["prefill"] > 0 and seconds["decode"] > 0
+
+    def test_on_by_default_and_the_env_is_the_off_switch(self, monkeypatch):
+        from modal_examples_tpu.models import llama
+        from modal_examples_tpu.serving import LLMEngine
+
+        def build(**kw):
+            return LLMEngine(
+                llama.LlamaConfig.tiny(), max_slots=2, max_model_len=64,
+                prefill_buckets=(32,), **kw,
+            )
+
+        monkeypatch.delenv("MTPU_PROFILE", raising=False)
+        assert P.profiling_enabled() is True
+        assert build().profiler is not None  # unset means on
+        monkeypatch.setenv("MTPU_PROFILE", "1")
+        assert build().profiler is not None
+        monkeypatch.setenv("MTPU_PROFILE", "0")
+        eng = build()
+        assert eng.profiler is None and eng._tick is None
+        # explicit arg beats env, both ways
+        assert build(profile=True).profiler is not None
+        monkeypatch.setenv("MTPU_PROFILE", "1")
+        assert build(profile=False).profiler is None
 
 
 class TestDisabledGateShape:
@@ -315,7 +627,7 @@ class TestDisabledGateShape:
 
     def test_tm_helpers_are_one_branch(self):
         tree = self._engine_tree()
-        for name in ("_tm", "_tm_device"):
+        for name in ("_tm", "_tm_device", "_tm_inner"):
             body = self._body(self._fn(tree, name))
             assert len(body) == 1, f"{name} must be ONE statement"
             guard = body[0]
@@ -364,7 +676,7 @@ class TestDisabledGateShape:
         ]
         assert ifexps, (
             "step() must create the tick via `None if prof is None else "
-            "prof.begin_tick()` — the disabled tick path takes no timestamp"
+            "prof.begin_tick(...)` — the disabled tick path takes no timestamp"
         )
 
 
@@ -398,7 +710,10 @@ class TestSurfaces:
         payload = json.loads(capsys.readouterr().out)
         assert payload["compiles_n"] >= 2
         assert payload["phases"]["total"]["count"] >= 1
-        assert payload["unfinished_builds"] == []
+        name = profiled_engine.profiler.replica
+        assert not [
+            r for r in payload["unfinished_builds"] if r["replica"] == name
+        ]
 
     def test_cli_profile_empty_state_says_so(self, tmp_path, capsys):
         from modal_examples_tpu.core.cli import main as cli_main
@@ -421,4 +736,307 @@ class TestSurfaces:
             node["perfetto"]["ticks"][0]
         )
         assert isinstance(snap["ledger"], list)
-        assert snap["unfinished_builds"] == []
+        assert not [
+            r for r in snap["unfinished_builds"] if r["replica"] == name
+        ]
+
+
+# ---------------------------------------------------------------------------
+# the spans in a device trace: `tpurun profile --xplane`
+# ---------------------------------------------------------------------------
+
+
+class TestXplane:
+    def test_idle_seconds_go_to_the_phase_that_covered_them(self):
+        from modal_examples_tpu.observability import xplane as X
+
+        # device: busy 0-1, idle 1-1.5, busy 1.5-2 (two overlapping ops),
+        # idle 2-2.1, busy 2.1-3
+        ops = [(0.0, 1.0), (1.5, 1.8), (1.7, 2.0), (2.1, 3.0)]
+        spans = [
+            ("harvest", 0.2, 1.1),           # covers 1.0-1.1 of the first gap
+            ("accept", 1.1, 1.3),            # 1.1-1.3
+            ("decode_dispatch", 1.35, 1.6),  # 1.35-1.5; 1.3-1.35 uncovered
+            ("admit", 1.95, 2.5),            # all of the second gap
+        ]
+        got = X.idle_by_phase(ops, spans)
+        assert got["window_s"] == pytest.approx(3.0)
+        assert got["busy_s"] == pytest.approx(2.4)
+        assert got["idle_s"] == pytest.approx(0.6)
+        idle = {p: row["idle_s"] for p, row in got["phases"].items()}
+        assert idle == {
+            "harvest": pytest.approx(0.1), "accept": pytest.approx(0.2),
+            "decode_dispatch": pytest.approx(0.15),
+            X.UNCOVERED: pytest.approx(0.05), "admit": pytest.approx(0.1),
+        }
+        assert sum(idle.values()) == pytest.approx(got["idle_s"])
+        assert got["phases"]["accept"]["longest_s"] == pytest.approx(0.2)
+        # whose the longest gap is: every phase that covered part of it
+        first, second = got["longest_gaps"]
+        assert first["seconds"] == pytest.approx(0.5)
+        assert first["at_s"] == pytest.approx(1.0)
+        assert first["phases"] == {
+            "harvest": pytest.approx(0.1), "accept": pytest.approx(0.2),
+            "decode_dispatch": pytest.approx(0.15),
+            X.UNCOVERED: pytest.approx(0.05),
+        }
+        assert second["phases"] == {"admit": pytest.approx(0.1)}
+
+    def test_idle_total_agrees_with_the_benchmarks_reduction(self):
+        """On the benchmark's own recorded trace: the same busy union, the
+        same window, so the same idle time (the issue allows 1%)."""
+        import sys
+
+        from modal_examples_tpu.observability import xplane as X
+
+        bench = PKG_ROOT.parent / "benchmarks" / "serving"
+        recorded = json.loads(
+            (PKG_ROOT.parent / "tests" / "bench_serving"
+             / "recorded_trace.json").read_text()
+        )
+        sys.path.insert(0, str(bench))
+        try:
+            import trace_reduce
+        finally:
+            sys.path.remove(str(bench))
+        theirs = trace_reduce.reduce_events(recorded)
+        chips = {
+            name: [(s, s + d) for _n, s, d in chip["ops"]]
+            for name, chip in recorded["chips"].items()
+        }
+        mine = X.reduce_chips(chips, spans=[])
+        their_idle = theirs["window_s"] - theirs["busy_s"]
+        assert mine["idle_s"] == pytest.approx(their_idle, rel=0.01)
+        assert mine["busy_s"] == pytest.approx(theirs["busy_s"], rel=1e-6)
+        # no spans in a trace from before the annotations: all uncovered
+        assert set(mine["phases"]) == {X.UNCOVERED}
+        assert "no mtpu.tick/* events" in "\n".join(X.render(mine))
+
+    def test_render_ranks_phases_by_idle_time(self):
+        from modal_examples_tpu.observability import xplane as X
+
+        report = X.reduce_chips(
+            {"/device:TPU:0": [(0.0, 1.0), (1.5, 2.0), (2.1, 3.0)]},
+            [("prefill_dispatch", 0.9, 1.6), ("admit", 1.9, 2.2)],
+        )
+        report["dispatches"] = {"block": 3}
+        lines = X.render(report)
+        assert lines[0].startswith("device: busy 2.400s  idle 0.600s  (20.00%)")
+        assert lines[2].split()[0] == "prefill_dispatch"
+        assert lines[3].split()[0] == "admit"
+        assert lines[-1] == "dispatches in the trace: block x3"
+
+    @staticmethod
+    def _xspace(planes) -> bytes:
+        """A serialized XSpace, hand-encoded (xplane.proto's field numbers):
+        ``planes`` = [(name, {stat id: stat name}, [(op name, [stat])])],
+        a stat ``(stat id, "str" | "ref", value)``."""
+
+        def varint(n):
+            out = bytearray()
+            while True:
+                out.append((n & 0x7F) | (0x80 if n > 0x7F else 0))
+                n >>= 7
+                if not n:
+                    return bytes(out)
+
+        def field(number, value):
+            if isinstance(value, int):
+                return varint(number << 3) + varint(value)
+            value = value.encode() if isinstance(value, str) else value
+            return varint(number << 3 | 2) + varint(len(value)) + value
+
+        def entry(key, message):
+            return field(1, key) + field(2, message)
+
+        space = b""
+        for name, stat_names, ops in planes:
+            plane = field(1, 7) + field(2, name)
+            plane += field(3, field(2, "XLA Ops"))  # a line: skipped whole
+            for sid, sname in stat_names.items():
+                plane += field(5, entry(sid, field(1, sid) + field(2, sname)))
+            for mid, (op_name, stats) in enumerate(ops, start=1):
+                meta = field(1, mid) + field(2, op_name)
+                for sid, kind, value in stats:
+                    meta += field(5, field(1, sid) + field(
+                        5 if kind == "str" else 7, value
+                    ))
+                plane += field(4, entry(mid, meta))
+            space += field(1, plane)
+        return space
+
+    def test_an_operations_scope_is_read_from_its_metadata(self):
+        from modal_examples_tpu.observability import xplane as X
+
+        stat_names = {
+            1: "flops", 2: "tf_op",
+            300: "jit(decode)/while/body/mtpu.dense_mlp/dot_general:",
+        }
+        data = self._xspace([
+            ("/device:TPU:0", stat_names, [
+                ("%fusion.1 = bf16[8] fusion()", [
+                    (1, "ref", 300),  # not the tf_op stat: ignored
+                    (2, "str", "jit(decode)/mtpu.attention/mtpu.page_gather/gather:"),
+                ]),
+                ("%fusion.2 = bf16[8] fusion()", [(2, "ref", 300)]),
+                ("%copy.3 = bf16[8] copy()", []),
+            ]),
+            ("/host:CPU", {2: "tf_op"}, [("python", [(2, "str", "mtpu.x")])]),
+        ])
+        got = X.op_scopes(data)
+        assert got == {"/device:TPU:0": {
+            "%fusion.1 = bf16[8] fusion()":
+                "jit(decode)/mtpu.attention/mtpu.page_gather/gather:",
+            "%fusion.2 = bf16[8] fusion()":
+                "jit(decode)/while/body/mtpu.dense_mlp/dot_general:",
+        }}
+        scopes = got["/device:TPU:0"]
+        # the innermost scope names the part; what has none is kept apart
+        assert X.scope_of(scopes["%fusion.1 = bf16[8] fusion()"]) == "mtpu.page_gather"
+        assert X.scope_of("jit(f)/transpose") == X.UNSCOPED
+        rows = X.time_by_scope(
+            [("%fusion.1 = bf16[8] fusion()", 0.002),
+             ("%fusion.2 = bf16[8] fusion()", 0.005),
+             ("%fusion.2 = bf16[8] fusion()", 0.005),
+             ("%copy.3 = bf16[8] copy()", 0.001)],
+            scopes,
+        )
+        assert rows == {
+            "mtpu.page_gather": {"busy_s": pytest.approx(0.002), "ops": 1},
+            "mtpu.dense_mlp": {"busy_s": pytest.approx(0.010), "ops": 2},
+            X.UNSCOPED: {"busy_s": pytest.approx(0.001), "ops": 1},
+        }
+
+    def test_every_scope_the_program_writes_is_one_the_reader_finds(self):
+        from modal_examples_tpu.observability import xplane as X
+        from modal_examples_tpu.ops import scopes
+
+        for name in scopes.ALL:
+            assert X.scope_of(f"jit(step)/while/body/{name}/dot_general:") == name
+
+    def test_render_ranks_scopes_by_device_time(self):
+        from modal_examples_tpu.observability import xplane as X
+
+        report = X.reduce_chips({"/device:TPU:0": [(0.0, 1.0), (1.5, 2.0)]}, [])
+        report["scopes"] = {
+            "mtpu.attention": {"busy_s": 0.3, "ops": 40},
+            "mtpu.page_gather": {"busy_s": 0.9, "ops": 64},
+            X.UNSCOPED: {"busy_s": 0.3, "ops": 7},
+        }
+        lines = X.render(report)
+        at = next(i for i, l in enumerate(lines) if l.startswith("DEVICE TIME BY SCOPE"))
+        assert [l.split()[0] for l in lines[at + 1 : at + 4]] == [
+            "mtpu.page_gather", "mtpu.attention", "(no",
+        ]
+        assert "60.0%" in lines[at + 1] and lines[at + 1].split()[-1] == "64"
+
+    def test_a_trace_holds_the_tick_and_dispatch_events(
+        self, tmp_path, capsys
+    ):
+        """Under a profiler session the engine's spans are events on the
+        host plane of the .xplane.pb, and `tpurun profile --xplane` reads
+        the file (here a CPU's: no device plane, so no table)."""
+        import glob
+
+        import jax
+        from jax.profiler import ProfileData
+
+        from modal_examples_tpu.core.cli import main as cli_main
+        from modal_examples_tpu.models import llama
+        from modal_examples_tpu.serving import LLMEngine, SamplingParams
+
+        eng = LLMEngine(
+            llama.LlamaConfig.tiny(), max_slots=4, max_model_len=128,
+            prefill_buckets=(32, 64),
+        )
+        eng.start()
+        try:
+            params = SamplingParams(max_tokens=10, temperature=0.0)
+            "".join(eng.stream(eng.submit("warm up " * 3, params)))
+            jax.profiler.start_trace(str(tmp_path))
+            try:
+                "".join(eng.stream(eng.submit("the quick fox " * 3, params)))
+            finally:
+                jax.profiler.stop_trace()
+        finally:
+            eng.stop()
+        [path] = glob.glob(str(tmp_path / "plugins/profile/*/*.xplane.pb"))
+        names = {
+            ev.name
+            for plane in ProfileData.from_file(path).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines
+            for ev in line.events
+            if ev.name.startswith("mtpu.")
+        }
+        assert {
+            "mtpu.tick/admit", "mtpu.tick/prefill_dispatch",
+            "mtpu.tick/decode_dispatch", "mtpu.tick/harvest",
+            "mtpu.tick/accept", "mtpu.dispatch/prefill",
+            "mtpu.dispatch/block",
+        } <= names, names
+        assert "mtpu.tick/detokenize" not in names  # accounted, not traced
+        assert cli_main(["profile", "--xplane", str(tmp_path)]) == 0
+        assert "no device operations" in capsys.readouterr().out
+
+
+# ---------------------------------------------------------------------------
+# the host_overhead alert: now fed on every replica (the profiler is on by
+# default), so its threshold has to mean what its description says
+# ---------------------------------------------------------------------------
+
+
+class TestHostOverheadAlert:
+    def _ratio(self, harvest_s: float, host_s: float) -> float:
+        """What the profiler's gauge reads after ticks of this shape."""
+        clk = ManualClock()
+        reg = Registry()
+        prof = P.HotPathProfiler(clock=clk, registry=reg)
+        for _ in range(8):
+            tick = prof.begin_tick()
+            tick.enter("decode_dispatch")
+            clk.advance(host_s)
+            tick.enter("harvest", device=True)
+            clk.advance(harvest_s)
+            prof.end_tick(tick, worked=True)
+        prof.flush()
+        return reg.value(C.HOST_OVERHEAD_RATIO)
+
+    def _fires(self, ratio: float, tmp_path) -> list[str]:
+        from modal_examples_tpu.observability import alerts as al
+
+        [rule] = [r for r in al.DEFAULT_RULES if r.name == "host_overhead"]
+
+        class Src:
+            records: list = []
+
+            def recent(self, window_s=None):
+                return list(self.records)
+
+        src = Src()
+        src.records = []
+        ev = al.AlertEvaluator(
+            (rule,), source=src, registry=Registry(),
+            journal_path=tmp_path / "alerts.jsonl",
+        )
+        events = []
+        for at in (10.0, 25.0, 41.0, 60.0):
+            src.records.append({"at": at, "series": [
+                [C.HOST_OVERHEAD_RATIO, {}, "gauge", ratio, 0.0],
+            ]})
+            events += [t["event"] for t in ev.evaluate_once(now=at)]
+        return events
+
+    def test_a_device_bound_replica_never_fires(self, tmp_path):
+        """The paced cell's shape on the chip: the scheduler thread waits
+        on the device for ~96% of a busy tick (PERF.md section 5)."""
+        ratio = self._ratio(harvest_s=0.345, host_s=0.012)
+        assert ratio == pytest.approx(0.012 / 0.357, rel=1e-3)
+        assert self._fires(ratio, tmp_path) == []
+
+    def test_a_host_bound_replica_fires_after_its_hold(self, tmp_path):
+        """Ticks that are ~all host work: the device is starved, which is
+        what the rule's description says it reports."""
+        ratio = self._ratio(harvest_s=0.001, host_s=0.099)
+        assert ratio > 0.97
+        assert self._fires(ratio, tmp_path) == ["fire"]

@@ -452,7 +452,7 @@ class TestOpenAIServer:
         # the process registry's engine series (latency histograms) are part
         # of the exposition, and no metric name appears in both the
         # hand-built block and the registry block
-        assert "mtpu_engine_phase_seconds_bucket" in text
+        assert 'mtpu_tick_phase_seconds_bucket{phase="harvest"' in text
         names = [
             l.split("{")[0].split(" ")[0]
             for l in text.splitlines()

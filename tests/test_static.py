@@ -763,7 +763,8 @@ def test_profiler_series_declared_and_emitted():
         for attr, val in vars(catalog).items()
         if isinstance(val, str)
         and val.startswith(
-            ("mtpu_tick_phase", "mtpu_host_overhead", "mtpu_compile")
+            ("mtpu_tick_phase", "mtpu_host_overhead", "mtpu_compile",
+             "mtpu_device_starved")
         )
     }
     assert len(consts) >= 4, consts
@@ -786,6 +787,7 @@ def test_profiler_series_declared_and_emitted():
     metrics_path = PKG_ROOT / "observability" / "metrics.py"
     recorders = (
         "record_tick_phase", "set_host_overhead_ratio", "record_compile",
+        "record_device_starved",
     )
     orphans = [
         fn for fn in recorders
@@ -800,10 +802,10 @@ def test_profiler_series_declared_and_emitted():
     )
 
 
-#: the engine's profiler mark helpers — THE call-site convention for tick
-#: phase attribution (serving/engine.py `_tm`/`_tm_device`): a string-
-#: literal phase name from catalog.TICK_PHASES at positional index 1
-_TICK_MARK_FUNCS = {"_tm", "_tm_device"}
+#: the engine's phase-entry helpers — THE call-site convention for tick
+#: phase attribution (serving/engine.py `_tm`/`_tm_device`/`_tm_inner`): a
+#: string-literal phase name from catalog.TICK_PHASES at positional index 1
+_TICK_MARK_FUNCS = {"_tm", "_tm_device", "_tm_inner"}
 
 
 def test_tick_phase_names_declared_and_wired():
@@ -815,7 +817,7 @@ def test_tick_phase_names_declared_and_wired():
     (b) every declared phase has at least one live mark site (a phase the
     scheduler stopped marking fails here instead of rotting in dashboards
     and the BENCH overhead schema), and (c) serving code never calls a raw
-    ``tick.mark(...)`` outside the two helpers — the PR-13 watermark-guard
+    ``tick.enter(...)`` outside the helpers — the PR-13 watermark-guard
     lesson applied to timing."""
     from modal_examples_tpu.observability.catalog import TICK_PHASES
 
@@ -823,8 +825,8 @@ def test_tick_phase_names_declared_and_wired():
     violations: list[str] = []
     for path in sorted((PKG_ROOT / "serving").rglob("*.py")):
         tree = ast.parse(path.read_text())
-        # line ranges of the _tm/_tm_device helper bodies (their internal
-        # tick.mark(phase) is the gate itself, not a bypass)
+        # line ranges of the _tm* helper bodies (their internal
+        # tick.enter(phase) is the gate itself, not a bypass)
         helper_ranges = [
             (n.lineno, n.end_lineno)
             for n in ast.walk(tree)
@@ -843,13 +845,18 @@ def test_tick_phase_names_declared_and_wired():
                     violations.append(f"{where}: non-literal phase name")
                 else:
                     sites.setdefault(phase, []).append(where)
-            elif isinstance(fn, ast.Attribute) and fn.attr == "mark":
+            elif (
+                isinstance(fn, ast.Attribute)
+                and fn.attr == "enter"
+                and isinstance(fn.value, ast.Name)
+                and fn.value.id == "tick"
+            ):
                 inside_helper = any(
                     lo <= node.lineno <= hi for lo, hi in helper_ranges
                 )
                 if not inside_helper:
                     violations.append(
-                        f"{where}: raw .mark() outside the _tm gate"
+                        f"{where}: raw tick.enter() outside the _tm gate"
                     )
     assert not violations, violations
     undeclared = sorted(set(sites) - set(TICK_PHASES))
@@ -879,16 +886,18 @@ _SERVING_MONOTONIC_ALLOWLIST = frozenset({
     ("serving/disagg/roles.py", "Migration.__init__"),
     ("serving/engine.py", "EngineStats.tokens_per_second"),
     ("serving/engine.py", "LLMEngine._accept_token"),
-    ("serving/engine.py", "LLMEngine._dispatch_block"),
+    # the request's stage stamps (created / admitted_at / first_token_at /
+    # last_token_at) are one raw monotonic clock, the client's: admission
+    # stamps admitted_at, the prefill harvest ends the prefill span there
+    ("serving/engine.py", "LLMEngine._admit"),
+    ("serving/engine.py", "LLMEngine._admit_adopted"),
     ("serving/engine.py", "LLMEngine._harvest_prefills"),
+    # mtpu_engine_queue_wait_seconds ends at the start of the prefill call,
+    # against the request's `created` (the same raw clock)
     ("serving/engine.py", "LLMEngine._prefill_group"),
     ("serving/engine.py", "LLMEngine._prefill_long"),
     ("serving/engine.py", "LLMEngine._prefill_sync_locked"),
-    ("serving/engine.py", "LLMEngine._process_block"),
     ("serving/engine.py", "LLMEngine._refresh_gauges"),
-    # the fused speculative round is a dispatch site like _dispatch_block:
-    # same decode-stall watermark accounting, same raw-clock rationale
-    ("serving/engine.py", "LLMEngine._spec_round"),
     ("serving/engine.py", "LLMEngine.submit_resumed"),
     ("serving/engine.py", "LLMEngine.warmup"),
     ("serving/failover.py", "migrate_request"),
