@@ -37,7 +37,7 @@ from ..ops import (
     kv_gather,
     kv_scatter,
     mesh_tp_degree,
-    paged_decode_attention_inflight,
+    paged_decode_attention_chunked,
     sharded_flash_attention,
     sharded_flash_attention_chunked,
     sharded_paged_decode_attention,
@@ -749,9 +749,10 @@ def decode_step(
     ``paged_impl_plan`` to see what will actually run for given shapes.
 
     Structure (round-3 rework): the page arrays are READ-ONLY inside the
-    layer scan — attention sees the cached prefix via a fused gather plus
-    the current token's K/V still in registers
-    (ops.paged_decode_attention_inflight) — and every layer's new KV is
+    layer scan — attention walks the cached prefix in chunks of the page
+    table, as far as the step's longest live context reaches, with the
+    current token's K/V still in registers
+    (ops.paged_decode_attention_chunked) — and every layer's new KV is
     scattered into the pages in ONE update after the scan (the same shape
     ``prefill`` uses). Round 2 threaded the full caches through the scan as
     stacked ys, which XLA materialized as cache-slice copies every layer of
@@ -761,9 +762,8 @@ def decode_step(
     impl="pallas" (round 4) keeps this same read-only structure but swaps
     the attention for the v3 ragged kernel (ops.paged_decode_attention_ragged)
     — it reads exactly ceil(ctx/page_size) pages per sequence where the XLA
-    gather reads and materializes ALL pages_per_seq pages (a builder's
-    round-4 knock-out ablation put that at the dominant, superlinear-in-slots
-    step cost; not a driver record). ``impl="xla-writeback"`` keeps the
+    loop reads every slot as far as the batch's longest context, rounded up
+    to a chunk. ``impl="xla-writeback"`` keeps the
     round-2 write-then-attend structure as an A/B lever.
     """
     if impl in ("xla-writeback", "pallas-writeback"):
@@ -824,16 +824,15 @@ def decode_step(
                 prefix_lens, k_tok, v_tok, variant=ragged_variant,
             )  # [B, H, D]
         else:
-            # one gather from the full [L, P, ...] arrays (layer scalar +
-            # table array fuse into a single XLA gather — no per-layer slice
-            # copy); int8 caches dequantize in the gather (one multiply at
-            # the model dtype, fused into the same bandwidth-bound loop)
-            ks = kv_gather(
-                k_pages, page_tables, layer=li, dtype=x.dtype
-            )  # [B, pp, ps, Hkv, D]
-            vs = kv_gather(v_pages, page_tables, layer=li, dtype=x.dtype)
-            o = paged_decode_attention_inflight(
-                q[:, :, 0], ks, vs, prefix_lens, k_tok, v_tok
+            # the table walked in chunks, as far as the longest live prefix
+            # of THIS step reaches (the trip count is read from prefix_lens,
+            # so inside a decode block it grows as pos + 1 crosses a chunk
+            # edge); each chunk one gather from the full [L, P, ...] arrays
+            # (layer scalar + table columns — no per-layer slice copy), an
+            # int8 cache dequantizing in it
+            o = paged_decode_attention_chunked(
+                q[:, :, 0], k_pages, v_pages, li, page_tables, prefix_lens,
+                k_tok, v_tok,
             )  # [B, H, D]
         o = o.reshape(B, cfg.n_heads * D)
         x = x + layers.mm(o, layer["wo"]).astype(x.dtype)

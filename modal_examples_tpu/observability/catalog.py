@@ -53,6 +53,13 @@ ENGINE_FIRST_TOKEN_WAIT_SECONDS = "mtpu_engine_first_token_wait_seconds"
 #: needed (prompt tokens of the requests in the call not served from
 #: cached pages). needed / computed is what padding and recomputation cost
 PREFILL_POSITIONS_TOTAL = "mtpu_prefill_positions_total"
+#: counter {kind}: KV positions of the decode attention, counted at each
+#: decode-block dispatch from the host-known positions, per step of the
+#: block; kind = read (trips of the chunked loop x positions a trip x
+#: slots: what the device gathers and scores) | live (the live contexts,
+#: summed) | table (slots x pages_per_slot x page_size: what a full-table
+#: gather would read). read / table is how far the loop runs
+DECODE_KV_POSITIONS_TOTAL = "mtpu_decode_kv_positions_total"
 #: gauge: requests waiting for admission (engine queue depth)
 WAITING_REQUESTS = "mtpu_waiting_requests"
 #: gauge: slots currently decoding
@@ -518,6 +525,13 @@ CATALOG: dict[str, dict] = {
         "help": "token positions at prefill dispatch (kind=computed: "
                 "rows x padded length | needed: prompt tokens not on "
                 "cached pages)",
+    },
+    DECODE_KV_POSITIONS_TOTAL: {
+        "type": "counter",
+        "labels": ["kind"],
+        "help": "KV positions per decode step at block dispatch (kind="
+                "read: chunk trips x chunk positions x slots | live: live "
+                "contexts | table: slots x table positions)",
     },
     WAITING_REQUESTS: {
         "type": "gauge",

@@ -122,6 +122,19 @@ def record_prefill_positions(
         )
 
 
+def record_decode_kv_positions(
+    read: int, live: int, table: int, *, registry: Registry | None = None
+) -> None:
+    """One decode-block dispatch: the KV positions its steps' attention
+    reads, those that are live, and what the whole table holds."""
+    reg = _reg(registry)
+    for kind, n in (("read", read), ("live", live), ("table", table)):
+        reg.counter_inc(
+            C.DECODE_KV_POSITIONS_TOTAL, float(n), labels={"kind": kind},
+            help=C.CATALOG[C.DECODE_KV_POSITIONS_TOTAL]["help"],
+        )
+
+
 def set_engine_gauges(
     *,
     waiting: int,

@@ -21,7 +21,10 @@ from .kv_quant import (
     quantize_kv,
 )
 from .paged_attention import (
+    decode_chunk_pages,
+    decode_chunk_trips,
     paged_decode_attention,
+    paged_decode_attention_chunked,
     paged_decode_attention_inflight,
     paged_decode_attention_ragged,
     scatter_kv_pages,
@@ -47,12 +50,15 @@ from . import reference
 
 __all__ = [
     "QuantizedKV",
+    "decode_chunk_pages",
+    "decode_chunk_trips",
     "dequantize_int8",
     "dequantize_kv",
     "flash_attention",
     "flash_attention_chunked",
     "flash_attention_with_lse",
     "paged_decode_attention",
+    "paged_decode_attention_chunked",
     "paged_decode_attention_inflight",
     "paged_decode_attention_ragged",
     "is_quantized",

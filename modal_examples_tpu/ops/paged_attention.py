@@ -44,6 +44,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -225,6 +226,11 @@ def paged_decode_attention_inflight(
     gathered pages, the current token contributes one extra logit column,
     and both share one softmax. Exact same math as write-then-attend with
     ``ctx_lens = prefix_lens + 1``.
+
+    Since PR 25 ``decode_step`` runs ``paged_decode_attention_chunked``
+    (the same softmax over the live part of the table only); this
+    full-width form stays as what the ragged kernels and the chunked loop
+    are tested against (tests, ops/probes.py).
     """
     B, Hq, D = q.shape
     _, pages_per_seq, page_size, Hkv, _ = ks.shape
@@ -263,6 +269,122 @@ def paged_decode_attention_inflight(
         v_new.astype(vs.dtype).astype(jnp.float32)[:, :, None, :]
     )
     return o.reshape(B, Hq, D).astype(q.dtype)
+
+
+#: positions (page_size x pages) one trip of the chunked decode loop reads
+#: per slot: the loop's only size. Tuned once on the v5e at Mistral-7B's
+#: cache shapes (16 slots x 256 pages of 16, bf16, 8 KV heads of 128; PERF.md
+#: section 6, PR 25): 256 read the full table fastest (21.5 ms a step of 32
+#: layers against 24.2 at 128, 31.3 at 512 and 45.4 for the single gather)
+#: and a 1200-token context too; the compiler keeps a gathered chunk of
+#: this size in VMEM.
+_CHUNK_POSITIONS = 256
+
+
+def decode_chunk_pages(page_size: int, pages_per_seq: int) -> int:
+    """Table columns (pages) a trip of ``paged_decode_attention_chunked``
+    gathers: ``_CHUNK_POSITIONS`` worth, at most the whole table."""
+    return max(1, min(pages_per_seq, _CHUNK_POSITIONS // page_size))
+
+
+def decode_chunk_trips(longest, page_size: int, pages_per_seq: int):
+    """Trips the loop makes when the longest live prefix is ``longest``
+    positions: the chunks up to and including the one that holds its last
+    token, 0 when nothing is live. Whole-number arithmetic on whatever
+    ``longest`` is (a traced scalar in the op, a numpy array of the steps of
+    a block where the engine counts what the device will read)."""
+    w = decode_chunk_pages(page_size, pages_per_seq)
+    span = w * page_size
+    xp = jnp if isinstance(longest, jax.Array) else np  # the host stays off JAX
+    return xp.minimum((longest + span - 1) // span, -(-pages_per_seq // w))
+
+
+@jax.named_scope(ATTENTION)
+def paged_decode_attention_chunked(
+    q: jax.Array,  # [B, Hq, D]
+    k_pages,  # [L, n_pages, page_size, Hkv, D] — the cache, in place
+    v_pages,
+    layer: jax.Array,  # scalar int32
+    page_tables: jax.Array,  # [B, pages_per_seq] int32
+    prefix_lens: jax.Array,  # [B] int32 — tokens already IN the cache
+    k_new: jax.Array,  # [B, Hkv, D] — current token's K (not yet written)
+    v_new: jax.Array,
+    *,
+    sm_scale: float | None = None,
+) -> jax.Array:  # [B, Hq, D]
+    """``paged_decode_attention_inflight`` over the live context only.
+
+    The same contract and mathematics, but the page table is walked in
+    chunks of ``decode_chunk_pages`` columns by a loop whose trip count is
+    read from ``prefix_lens``: it stops after the chunk that holds the
+    batch's longest live prefix, so a step reads what its contexts need,
+    not every slot's whole ``max_model_len`` (at 16 slots x 256 pages that
+    was 134 MB gathered, written and read again for K and for V in every
+    layer, whatever the contexts: PERF.md section 6, PR 25). Each trip
+    gathers its chunk with ``kv_gather`` (an int8 cache dequantises there),
+    scores it with the layout-preserving einsums (cache-dtype operands, f32
+    accumulation), masks ``pos < prefix_lens`` and folds it into a running
+    (max, sum, accumulator) in f32: an online softmax, exact up to f32
+    rounding. The running state starts from the in-flight token's column,
+    which is always there, so the maximum is finite from the first trip on
+    and a chunk a slot has nothing in changes nothing (alpha = 1, p = 0):
+    what a slot gets does not depend on how far a longer neighbour makes the
+    loop run. A ``while`` of gathers and einsums, so still auto-partitionable
+    over the KV-head axis under a sharded jit.
+    """
+    B, Hq, D = q.shape
+    _, _, page_size, Hkv, _ = k_pages.shape
+    G = Hq // Hkv
+    pages_per_seq = page_tables.shape[1]
+    if sm_scale is None:
+        sm_scale = D**-0.5
+    W = decode_chunk_pages(page_size, pages_per_seq)
+    if pages_per_seq % W:
+        # pad the table with the trash page: those columns lie past every
+        # prefix, so the mask drops them
+        page_tables = jnp.pad(page_tables, ((0, 0), (0, -pages_per_seq % W)))
+    trips = decode_chunk_trips(jnp.max(prefix_lens), page_size, pages_per_seq)
+    # what the gather hands the einsums: the cache's dtype, or the query's
+    # for an int8 cache (dequantised at it)
+    kv_dtype = q.dtype if is_quantized(k_pages) else k_pages.dtype
+    qg = q.reshape(B, Hkv, G, D)
+    in_chunk = (
+        jnp.arange(W)[:, None] * page_size + jnp.arange(page_size)[None, :]
+    )  # [W, ps]
+
+    def chunk(c, carry):
+        m, l, acc = carry  # [B, Hkv, G], [B, Hkv, G], [B, Hkv, G, D] f32
+        cols = jax.lax.dynamic_slice_in_dim(page_tables, c * W, W, axis=1)
+        ks = kv_gather(k_pages, cols, layer=layer, dtype=q.dtype)
+        vs = kv_gather(v_pages, cols, layer=layer, dtype=q.dtype)
+        s = jnp.einsum(
+            "bhgd,bpthd->bhgpt", qg, ks, preferred_element_type=jnp.float32
+        ) * sm_scale  # [B, Hkv, G, W, ps]
+        valid = (c * W * page_size + in_chunk)[None] < prefix_lens[
+            :, None, None
+        ]  # [B, W, ps]
+        s = jnp.where(valid[:, None, None], s, -jnp.inf)
+        m_new = jnp.maximum(m, jnp.max(s, axis=(-2, -1)))
+        p = jnp.exp(s - m_new[..., None, None])
+        alpha = jnp.exp(m - m_new)
+        l = l * alpha + jnp.sum(p, axis=(-2, -1))
+        acc = acc * alpha[..., None] + jnp.einsum(
+            "bhgpt,bpthd->bhgd", p.astype(vs.dtype), vs,
+            preferred_element_type=jnp.float32,
+        )
+        return m_new, l, acc
+
+    # the in-flight token at cache dtype, as if read back from the cache
+    s_new = jnp.einsum(
+        "bhgd,bhd->bhg", qg, k_new.astype(kv_dtype),
+        preferred_element_type=jnp.float32,
+    ) * sm_scale
+    v_tok = v_new.astype(kv_dtype).astype(jnp.float32)[:, :, None, :]
+    _, l, acc = jax.lax.fori_loop(
+        0, trips, chunk,
+        (s_new, jnp.ones_like(s_new), jnp.broadcast_to(v_tok, (B, Hkv, G, D))),
+    )
+    return (acc / l[..., None]).reshape(B, Hq, D).astype(q.dtype)
 
 
 def _decode_kernel_ragged(

@@ -57,6 +57,7 @@ from ..scheduling.policy import (
 from ..faults import inject as _inject
 from ..faults.inject import FaultError as _FaultError
 from ..observability.canary import CANARY_TENANT as _CANARY_TENANT
+from ..ops.paged_attention import decode_chunk_pages, decode_chunk_trips
 from ..utils.log import get_logger
 from .health import EngineWatermarks
 from .kv_cache import OutOfPages, PagedKVCache
@@ -1044,6 +1045,31 @@ class LLMEngine:
             jax.random.split(key, self.decode_block),
         )
         return toks, last, k_pages, v_pages
+
+    def _count_decode_kv(self, positions, active, steps: int) -> None:
+        """What the ``steps`` decode steps of one dispatch read of the KV
+        cache, from the positions the host hands the program: step j sees
+        every live slot j tokens further on, and the chunked loop
+        (ops.paged_decode_attention_chunked) makes as many trips as that
+        step's longest context needs, each over every slot. The macro-step
+        program can kill a lane before its last step; it is counted as
+        running them all."""
+        if self.impl_plan["attention"] != "xla-gather":
+            return  # the ragged kernels and the write-back path do not loop
+        live = positions[active].astype(np.int64)
+        ps, pp = self.cache.page_size, self.pages_per_slot
+        read = 0
+        if live.size:
+            trips = decode_chunk_trips(live.max() + np.arange(steps), ps, pp)
+            read = (
+                int(trips.sum()) * decode_chunk_pages(ps, pp) * ps
+                * self.max_slots
+            )
+        _obs.record_decode_kv_positions(
+            read=read,
+            live=int(live.sum()) * steps + live.size * steps * (steps - 1) // 2,
+            table=self.max_slots * pp * ps * steps,
+        )
 
     def _multistep_jit(self, n: int):
         """The N-step macro decode program (serving/multistep/runtime.py),
@@ -3150,6 +3176,7 @@ class LLMEngine:
         for i, tok in enumerate(replay[:-1]):
             override[slot_idx] = int(tok)
             positions[slot_idx] = base_pos + i
+            self._count_decode_kv(positions, active, self.decode_block)
             _toks, _last, self.cache.k_pages, self.cache.v_pages = (
                 self._profiled(
                     "block", f"s{self.max_slots}k{self.decode_block}",
@@ -3564,6 +3591,7 @@ class LLMEngine:
                 jnp.asarray(self._seeds.copy()),
                 jnp.asarray(budgets),
             )
+        self._count_decode_kv(self._positions, self._active, n)
         self._device_tokens = last
         # snapshot pins (slot, request, tenancy): request identity alone is
         # not enough — a failover-resumed request is the same object back

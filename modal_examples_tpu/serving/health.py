@@ -279,6 +279,11 @@ def replica_snapshot(replica, now: float | None = None) -> dict:
     snap["outstanding"] = int(replica.outstanding())
     decodable = 0
     slots = []
+    # the longest a decodable slot has gone without an accepted token
+    # (before its first: since it was admitted — a slot counts as decodable
+    # while its synchronous prefill is still running); None while a slot
+    # carries neither stamp — see progress_age
+    waits: list = []
     clock = getattr(eng, "_clock", time.monotonic)
     t = clock() if now is None else now
     for i, s in enumerate(getattr(eng, "slots", ())):
@@ -287,6 +292,10 @@ def replica_snapshot(replica, now: float | None = None) -> dict:
             continue
         if s.decodable:
             decodable += 1
+            since = req.last_token_at
+            if since is None:
+                since = getattr(req, "admitted_at", None)
+            waits.append(None if since is None else max(0.0, t - since))
         slots.append({
             "slot": i,
             "request_id": req.request_id,
@@ -298,6 +307,9 @@ def replica_snapshot(replica, now: float | None = None) -> dict:
             "generated": len(req.generated_tokens),
         })
     snap["decodable"] = decodable
+    snap["decodable_wait_age"] = (
+        max(waits) if waits and None not in waits else None
+    )
     snap["slots"] = slots
     oldest = None
     policy = getattr(eng, "policy", None)
@@ -317,9 +329,19 @@ def progress_age(snap: dict) -> float | None:
         return None
     ages = [snap.get("tick_age", 0.0)]
     if snap.get("decodable", 0) > 0:
+        # the engine-wide dispatch and accept watermarks date from the
+        # last time there was anything to decode: after an idle spell they
+        # are as old as the spell. They are stale only for as long as a
+        # decodable slot has been kept waiting, so that wait bounds them
+        # (an engine idle for ``wedged_after_s`` whose first tick with a
+        # new request ran past one watchdog poll read as wedged: the chaos
+        # run's canary episode, PR 25)
+        waited = snap.get("decodable_wait_age")
         for key in ("dispatch_age", "accept_age"):
             if snap.get(key) is not None:
-                ages.append(snap[key])
+                ages.append(
+                    snap[key] if waited is None else min(snap[key], waited)
+                )
     return max(ages)
 
 

@@ -17,8 +17,10 @@ import pytest
 def chaos_report(jax_cpu):
     from modal_examples_tpu.faults.chaos import run_chaos
 
-    # strict=True: any invariant violation raises here, failing every test
-    return run_chaos(seed=0, strict=True)
+    # strict=False: a violated invariant is in the report, and fails the tests
+    # that read it (test_all_invariants_hold_after_every_episode names the
+    # episode), not every test of the module
+    return run_chaos(seed=0, strict=False)
 
 
 class TestChaosAcceptance:

@@ -246,6 +246,45 @@ class TestClassification:
         )
         assert classify(snap, policy) == "healthy"
 
+    @pytest.mark.parametrize("waited, state", [
+        (0.2, "healthy"),  # idle 12 s, then a request 0.2 s into decode
+        (3.0, "degraded"),  # the slot itself has been kept waiting
+        (11.0, "wedged"),
+        (None, "wedged"),  # unknown (no token accepted here yet): no bound
+    ])
+    def test_idle_spell_does_not_age_the_decode_watermarks(self, waited, state):
+        """The dispatch and accept watermarks of an engine that sat idle
+        are as old as the idle spell; they count against a decodable slot
+        only for as long as that slot has waited."""
+        policy = WatchdogPolicy(degraded_after_s=2.0, wedged_after_s=10.0)
+        snap = self._snap(
+            tick_age=0.15, dispatch_age=12.0, accept_age=12.0,
+            decodable_wait_age=waited,
+        )
+        assert classify(snap, policy) == state
+
+    def test_snapshot_reports_the_longest_decodable_wait(self):
+        clock = FakeClock()
+        rep = _FakeReplica("dw-0", clock, outstanding=3)
+        eng = rep.engine
+        t0 = clock()
+        eng.slots = [
+            _FakeSlot(_FakeRequest("a", last_token_at=t0), decodable=True),
+            _FakeSlot(_FakeRequest("b", last_token_at=t0 + 1.0), decodable=True),
+            _FakeSlot(_FakeRequest("c"), decodable=False),  # mid-prefill
+            _FakeSlot(),
+        ]
+        clock.advance(3.0)
+        assert replica_snapshot(rep)["decodable_wait_age"] == pytest.approx(3.0)
+        # admitted 4 s ago, its synchronous prefill still running: no
+        # token yet, and the slot already counts as decodable
+        fresh = _FakeRequest("d")
+        fresh.admitted_at = t0 - 1.0
+        eng.slots.append(_FakeSlot(fresh, decodable=True))
+        assert replica_snapshot(rep)["decodable_wait_age"] == pytest.approx(4.0)
+        eng.slots.append(_FakeSlot(_FakeRequest("e"), decodable=True))
+        assert replica_snapshot(rep)["decodable_wait_age"] is None
+
     def test_queue_head_age_is_degraded_only(self):
         policy = WatchdogPolicy(
             degraded_after_s=2.0, wedged_after_s=10.0,

@@ -317,6 +317,177 @@ class TestPagedAttention:
             )
 
 
+class TestChunkedDecodeAttention:
+    """ops.paged_decode_attention_chunked (the default decode path since PR
+    25: the page table walked in chunks as far as the longest live prefix)
+    against ops.reference at full width."""
+
+    PS, PP = 16, 72  # 4.5 chunks of 16 pages: the last one is padded
+
+    @staticmethod
+    def _edges(ps, pp):
+        from modal_examples_tpu.ops import decode_chunk_pages
+
+        span = decode_chunk_pages(ps, pp) * ps
+        assert pp * ps > 2 * span, "the table must hold several chunks"
+        return span
+
+    def _case(self, jax, jnp, kv, heads, prefix, L=2):
+        """Inputs, and per layer the reference's answer: the in-flight token
+        written behind each prefix (the table gets one more column for a
+        prefix that fills it) and ops.reference over context = prefix + 1.
+        An int8 cache goes quantized into the op and dequantized into the
+        reference, which isolates the loop from quantization noise."""
+        from modal_examples_tpu import ops
+        from modal_examples_tpu.ops import reference
+
+        ps, pp = self.PS, self.PP
+        Hq, Hkv = heads
+        D = 32
+        B = len(prefix)
+        dt = jnp.float32 if kv == "f32" else jnp.bfloat16
+        ks = jax.random.split(jax.random.PRNGKey(len(prefix) + Hkv), 5)
+        n_pages = 1 + B * (pp + 1)
+        q = jax.random.normal(ks[0], (B, Hq, D), dt)
+        kp = jax.random.normal(ks[1], (L, n_pages, ps, Hkv, D), dt)
+        vp = jax.random.normal(ks[2], kp.shape, dt)
+        k_new = jax.random.normal(ks[3], (B, Hkv, D), dt)
+        v_new = jax.random.normal(ks[4], (B, Hkv, D), dt)
+        wide = 1 + np.arange(B * (pp + 1), dtype=np.int32).reshape(B, pp + 1)
+        prefix = np.asarray(prefix, np.int32)
+        dead = prefix < 0
+        prefix = np.where(dead, 0, prefix)
+        tables = np.where(dead[:, None], 0, wide[:, :pp])  # dead: trash page
+        if kv == "int8":
+            kp, vp = ops.quantize_kv(kp), ops.quantize_kv(vp)
+        page = wide[np.arange(B), prefix // ps]
+        want = []
+        for li in range(L):
+            kd = ops.dequantize_kv(kp[li], dt).at[page, prefix % ps].set(k_new)
+            vd = ops.dequantize_kv(vp[li], dt).at[page, prefix % ps].set(v_new)
+            want.append(reference.paged_decode_attention(
+                q, kd, vd, jnp.asarray(wide), jnp.asarray(prefix + 1)
+            ))
+        args = (q, kp, vp, jnp.asarray(tables), jnp.asarray(prefix), k_new, v_new)
+        return args, want, 2e-5 if kv == "f32" else 3e-2
+
+    @pytest.mark.parametrize("how", ["jit", "scan"])
+    @pytest.mark.parametrize("batch", ["edges", "short-and-dead"])
+    @pytest.mark.parametrize("heads", [(32, 8), (32, 32)], ids=["gqa", "mha"])
+    @pytest.mark.parametrize("kv", ["f32", "bf16", "int8"])
+    def test_matches_reference(self, jax, jnp, kv, heads, batch, how):
+        from modal_examples_tpu.ops import paged_decode_attention_chunked
+
+        span = self._edges(self.PS, self.PP)
+        if batch == "edges":
+            # every edge of a chunk and of the table in one batch, with a
+            # fresh slot (prefix 0) and a dead one (-1)
+            prefix = [0, 1, span - 1, span, span + 1, self.PS * self.PP, -1]
+        else:
+            # one trip: nobody reaches the second chunk; two dead slots
+            prefix = [-1, 5, span - 1, -1]
+        (q, kp, vp, pt, pl_, kn, vn), want, tol = self._case(
+            jax, jnp, kv, heads, prefix
+        )
+        if how == "jit":
+            got = [
+                jax.jit(paged_decode_attention_chunked)(
+                    q, kp, vp, jnp.int32(li), pt, pl_, kn, vn
+                )
+                for li in range(len(want))
+            ]
+        else:
+            # as decode_step runs it: inside the scan over layers
+            def layer(carry, li):
+                o = paged_decode_attention_chunked(
+                    q, kp, vp, li, pt, pl_, kn, vn
+                )
+                return carry, o
+
+            got = jax.jit(
+                lambda: jax.lax.scan(layer, 0, jnp.arange(len(want)))[1]
+            )()
+        for li, w in enumerate(want):
+            np.testing.assert_allclose(
+                np.asarray(got[li], np.float32), np.asarray(w, np.float32),
+                atol=tol, err_msg=f"layer {li}",
+            )
+
+    @pytest.mark.parametrize("chunks, plus, trips", [
+        (0, 0, 0), (0, 1, 1), (1, -1, 1), (1, 0, 1), (1, 1, 2), (2, 0, 2),
+        (2, 1, 3), (4, 1, 5), (4.5, 0, 5), (20, 0, 5),
+    ])
+    def test_trip_count(self, jax, jnp, chunks, plus, trips):
+        """ceil(longest / chunk positions), never past the table (4.5
+        chunks: 5 trips); the same for the host's numpy as for a traced
+        scalar."""
+        from modal_examples_tpu.ops import decode_chunk_trips
+
+        longest = int(chunks * self._edges(self.PS, self.PP)) + plus
+        assert decode_chunk_trips(np.int64(longest), self.PS, self.PP) == trips
+        on_device = jax.jit(
+            lambda n: decode_chunk_trips(n, self.PS, self.PP)
+        )(jnp.int32(longest))
+        assert int(on_device) == trips
+
+    def test_a_longer_neighbour_changes_nothing(self, jax, jnp):
+        """A chunk a slot has nothing in leaves its running state as it
+        was (alpha 1, p 0): the slot's output is bit-identical however far
+        a longer neighbour makes the loop run, which is what lets a
+        failover replay (one slot alone) rebuild the KV decode wrote."""
+        from modal_examples_tpu.ops import paged_decode_attention_chunked
+
+        span = self._edges(self.PS, self.PP)
+        (q, kp, vp, pt, pl_, kn, vn), _, _ = self._case(
+            jax, jnp, "bf16", (32, 8), [span // 2, self.PS * self.PP - 1], L=1
+        )
+        fn = jax.jit(paged_decode_attention_chunked)
+        both = fn(q, kp, vp, jnp.int32(0), pt, pl_, kn, vn)
+        alone = fn(q, kp, vp, jnp.int32(0), pt, pl_.at[1].set(0), kn, vn)
+        np.testing.assert_array_equal(
+            np.asarray(both[0], np.float32), np.asarray(alone[0], np.float32)
+        )
+
+    def test_decode_step_compiles_to_a_loop_not_a_table_gather(self, jax, jnp):
+        """The default decode step holds the chunk loop (a ``while`` nested
+        in the layer scan's) and no array of a whole table's gathered pages
+        ``[B, pages_per_seq, page_size, Hkv, D]``."""
+        import re
+
+        from modal_examples_tpu.models import llama
+        from modal_examples_tpu.ops import decode_chunk_pages
+
+        cfg = llama.LlamaConfig(
+            vocab_size=256, dim=64, n_layers=2, n_heads=8, n_kv_heads=2,
+            ffn_dim=128, max_seq_len=2048, dtype="float32",
+        )
+        B, ps, pp, n_pages = 3, 16, 96, 8
+        W = decode_chunk_pages(ps, pp)
+        assert W < pp
+        shape = (cfg.n_layers, n_pages, ps, cfg.n_kv_heads, cfg.head_dim)
+        S = jax.ShapeDtypeStruct
+        params = jax.eval_shape(
+            lambda: llama.init_params(jax.random.PRNGKey(0), cfg)
+        )
+        text = jax.jit(
+            lambda *a: llama.decode_step(*a, cfg)
+        ).lower(
+            params, S((B,), jnp.int32), S((B,), jnp.int32),
+            S(shape, jnp.float32), S(shape, jnp.float32),
+            S((B, pp), jnp.int32), S((B,), bool),
+        ).compile().as_text()
+        whiles = re.findall(r"= \([^\n]*\) while\(", text)
+        assert len(whiles) >= 2, len(whiles)
+        dims = f"[{B},{{n}},{ps},{cfg.n_kv_heads},{cfg.head_dim}]"
+        assert dims.format(n=pp) not in text
+        assert f"[{B * pp},{ps},{cfg.n_kv_heads},{cfg.head_dim}]" not in text
+        # the chunk's gather is there instead
+        assert (
+            dims.format(n=W) in text
+            or f"[{B * W},{ps},{cfg.n_kv_heads},{cfg.head_dim}]" in text
+        )
+
+
 class TestQuantizedMatmul:
     def test_quantize_roundtrip(self, jax, jnp):
         from modal_examples_tpu.ops import dequantize_int8, quantize_int8

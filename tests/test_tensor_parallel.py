@@ -198,6 +198,67 @@ class TestEngineTP:
         assert float(np.max(np.abs(lo_t - lo_s))) < 1e-3
         assert float(np.max(np.abs(l2_t - l2_s))) < 1e-3
 
+    @pytest.mark.parametrize("tp, kv", [(2, "float32"), (4, "int8")])
+    def test_chunked_decode_loop_under_mesh_matches_single(self, jax, tp, kv):
+        """The default decode step's chunk loop (a ``while`` of gathers and
+        einsums whose trip count is read from the positions) stays
+        auto-partitionable over the KV-head axis: contexts that end in the
+        first, second and third chunk of the table, the page cache sharded
+        by head, the logits and the cache writes of one device."""
+        import functools
+
+        import jax.numpy as jnp
+        from jax.sharding import NamedSharding
+        from jax.sharding import PartitionSpec as P
+
+        from modal_examples_tpu import ops
+        from modal_examples_tpu.models import llama
+        from modal_examples_tpu.ops.kv_quant import shard_kv
+        from modal_examples_tpu.parallel import make_mesh
+        from modal_examples_tpu.serving.engine import _shard_params
+
+        cfg = llama.LlamaConfig(
+            vocab_size=128, dim=64, n_layers=2, n_heads=8, n_kv_heads=4,
+            ffn_dim=128, max_seq_len=2048, dtype="float32",
+        )
+        params = llama.init_params(jax.random.PRNGKey(0), cfg)
+        mesh = make_mesh({"tensor": tp}, devices=jax.devices()[:tp])
+        B, ps, pp = 4, 16, 96
+        span = ops.decode_chunk_pages(ps, pp) * ps
+        assert pp * ps >= 3 * span
+        positions = jnp.asarray([7, span + 3, 2 * span + 40, 0], jnp.int32)
+        active = jnp.asarray([True, True, True, False])
+        n_pages = 1 + B * pp
+        shape = (cfg.n_layers, n_pages, ps, cfg.n_kv_heads, cfg.head_dim)
+        kp = 0.3 * jax.random.normal(jax.random.PRNGKey(1), shape, jnp.float32)
+        vp = 0.3 * jax.random.normal(jax.random.PRNGKey(2), shape, jnp.float32)
+        if kv == "int8":
+            kp, vp = ops.quantize_kv(kp), ops.quantize_kv(vp)
+        tables = jnp.asarray(1 + np.arange(B * pp).reshape(B, pp), jnp.int32)
+        toks = jnp.asarray([5, 9, 11, 0], jnp.int32)
+
+        def run(p, mesh_arg, kp, vp):
+            if mesh_arg is not None:
+                kp, vp = (
+                    shard_kv(
+                        pages,
+                        NamedSharding(mesh_arg, P(None, None, None, "tensor", None)),
+                        NamedSharding(mesh_arg, P(None, None, None, "tensor")),
+                    )
+                    for pages in (kp, vp)
+                )
+            lg, k2, _ = jax.jit(
+                functools.partial(
+                    llama.decode_step, cfg=cfg, impl="xla", mesh=mesh_arg
+                )
+            )(p, toks, positions, kp, vp, tables, active)
+            return np.asarray(lg), np.asarray(ops.dequantize_kv(k2, jnp.float32))
+
+        lg_s, k_s = run(params, None, kp, vp)
+        lg_t, k_t = run(_shard_params(params, cfg, mesh), mesh, kp, vp)
+        assert float(np.max(np.abs(lg_t[:3] - lg_s[:3]))) < 1e-3
+        assert float(np.max(np.abs(k_t - k_s))) < 1e-3
+
     def test_int8_kv_engine_tp2(self, jax):
         """int8 KV composes with tensor parallelism: the 4-leaf cache's
         scale arrays shard on the same kv-head axis as their int8 data
