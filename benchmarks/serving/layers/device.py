@@ -3,31 +3,16 @@
 A compiled program is found by the name of its XLA module (patterns in
 ``programs.json``); its time is the device time of its module events in the
 traced part of the window. Roofline shares hold that time against the least
-the chip could take for the work the algorithm needs (``work_model.py``).
+the chip could take (``work_model.py``) for the work the algorithm needs, as
+the configuration's family counts it (``families/<family>.py``).
 """
 
-import json
-import re
-from pathlib import Path
-
+import manifest
 import work_model
-
-PATTERNS = json.loads((Path(__file__).parent / "programs.json").read_text())
-
-
-def _program(run, kind):
-    """(device seconds, calls) of the programs of one kind in the trace."""
-    if not run.trace:
-        return None
-    pat = re.compile(PATTERNS[kind])
-    hits = [v for k, v in run.trace["programs"].items() if pat.search(k)]
-    if not hits:
-        return None
-    return sum(v["time_s"] for v in hits), sum(v["count"] for v in hits)
 
 
 def decode_dev_ms(run):
-    got = _program(run, "decode")
+    got = run.program("decode")
     if not got or not got[1]:
         return None
     steps = got[1] * int(run.device["decode_block"])
@@ -35,7 +20,7 @@ def decode_dev_ms(run):
 
 
 def prefill_dev_pct(run):
-    got = _program(run, "prefill")
+    got = run.program("prefill")
     if not got:
         return None
     return 100.0 * got[0] / run.trace["window_s"]
@@ -53,25 +38,21 @@ def decode_roofline(run):
     step_ms, batch, context = decode_dev_ms(run), run.decode_batch_mean(), _mean_context(run)
     if step_ms is None or batch is None or context is None:
         return None
-    work = work_model.decode_step(run.config, batch, batch * context)
+    work = manifest.load_family(run.config).decode_step(run.config, batch, batch * context)
     return work_model.roofline_pct(work, step_ms / 1000.0, work_model.peaks_for(run.device["kind"]))
 
 
 def prefill_roofline(run):
     """The window's prefilled prompts against the device time the prefill
     programs took: their share of the traced part, over the whole window."""
-    got = _program(run, "prefill")
+    got = run.program("prefill")
     window = run.times["window_close"] - run.times["window_open"]
-    t0, t1 = run.times["window_open"], run.times["window_close"]
-    prompts = [
-        e["n_prompt"] for e in run.engine_log.values()
-        if e.get("first_token_at") is not None and t0 <= e["first_token_at"] < t1
-    ]
+    prompts = run.prefilled_prompts()
     if not got or not got[0] or not prompts:
         return None
     share = got[0] / run.trace["window_s"]
     calls = got[1] * window / run.trace["window_s"]
-    work = work_model.prefill(run.config, prompts, calls)
+    work = manifest.load_family(run.config).prefill(run.config, prompts, calls)
     return work_model.roofline_pct(work, share * window, work_model.peaks_for(run.device["kind"]))
 
 

@@ -7,21 +7,42 @@ metric sits in a file of its own, found by name:
   ``assumed``, the deployment, the engine's settings;
 - ``mixes/<traffic>.json``: the mix's parameters, read by ``traffic.py``;
 - ``cells/<workload>.json`` (optional): what belongs to the pair, such as the
-  paced rate that a sweep found for this configuration under this mix; its
-  keys overlay the mix's;
+  paced rate that a sweep found for this configuration under this mix or its
+  number of closed-loop clients; its keys overlay the mix's;
+- ``families/<family>.py``: everything that knows a layer's shape (below);
 - ``layers/*.py``: readers, each a ``METRICS`` table from a quantity's name
   to a function of the run's data. A metric's name in ``BENCHMARK.json`` is
   ``[<variant>.]<quantity>``: one quantity read in cells that report
-  different end-to-end metrics has an entry for each (``paced.decode_dev_ms``
-  moves ``tpot_p50_ms``, ``closed.decode_dev_ms`` moves ``out_tok_s``), and
-  both find the reader ``decode_dev_ms``.
+  different end-to-end metrics has an entry for each (``reason.decode_dev_ms``
+  moves ``out_tok_s``, ``closed.decode_dev_ms`` moves ``req_s``), and both
+  find the reader ``decode_dev_ms``.
 
-So a later PR adds cells, mixes, configurations and metrics by adding files
-and manifest entries, and edits none.
+A configuration's file names its ``family`` (absent: ``llama``); nothing
+dispatches on a model's or a cell's name. To add a family, add one file
+``families/<family>.py`` with (:data:`FAMILY_INTERFACE`): ``dims_of(config)``,
+the sizes its generator and reference need; ``make_tree(seed, dims)``, the
+whole seeded tree for the engine in one jitted call (from ``weights.py``'s
+primitives; what its reference needs to make one layer again alone is its
+own affair); ``program_config(config_file)``, what ``LLMEngine`` is given
+(``server_app.py`` builds the engine itself: the normal path is one path);
+``logits_at(seed, dims, sequences, rows, bits) -> (logits, margins, clock)``,
+its plain float32 ``highest`` forward pass over padded token ids, layers
+outermost, ``bits=4`` the control (``reference.py`` pads, takes the gaps and
+decides); ``decode_step(config, batch, context_tokens)`` and ``prefill(config,
+prompt_lengths, calls)`` returning ``{"flops", "bytes"}`` for the rooflines;
+and ``SCOPE_WORK``, a table from ``mtpu.*`` scope to ``fn(config, tokens,
+calls)`` returning the same for the tokens of one kind of program call, the
+window's prefill calls or its decode steps (``layers/scopes.py``). The
+file imports JAX only inside the functions that need it: the load
+generator's process reads the work functions and may not hold JAX.
+
+So a later PR adds cells, mixes, configurations, families and metrics by
+adding files and manifest entries, and edits none.
 """
 
 from __future__ import annotations
 
+import ast
 import importlib.util
 import json
 import re
@@ -31,6 +52,14 @@ HERE = Path(__file__).resolve().parent
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 SOURCES = ("device_trace", "program_span", "program_counter", "host_clock")
+FAMILY_INTERFACE = ("dims_of", "make_tree", "program_config", "logits_at",
+                    "decode_step", "prefill", "SCOPE_WORK")
+#: ``reduced`` may name a key that counts rows, experts, heads or layers
+#: (``model-configs`` section 4: the chip's share; ``vocab_size`` counts
+#: rows), never a width: a hidden, intermediate, latent, state, projection or
+#: head size, an expansion factor, the experts a token is routed to
+WIDTHS = re.compile(r"(_dim|_rank|_size|_width|_factor|_per_tok)$|^d_\w+$")
+ROW_COUNTS = ("vocab_size",)
 
 
 def load_manifest(root: Path) -> dict:
@@ -67,14 +96,68 @@ def quantity(metric_name: str) -> str:
     return metric_name.rpartition(".")[2]
 
 
+def family_name(config: dict) -> str:
+    return config.get("family", "llama")
+
+
+def _load(path: Path, name: str):
+    spec = importlib.util.spec_from_file_location(name, path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+_families: dict = {}
+
+
+def _family_file(name: str) -> Path | None:
+    path = HERE / "families" / f"{name}.py"
+    return path if NAME.match(name) and path.is_file() else None
+
+
+def load_family(config: dict):
+    """The module ``families/<family>.py`` of a configuration, loaded once."""
+    name = family_name(config)
+    if name not in _families:
+        path = _family_file(name)
+        if path is None:
+            raise KeyError(f"no family file families/{name}.py")
+        _families[name] = _load(path, f"bench_family_{name}")
+    return _families[name]
+
+
+def family_problems(name: str) -> list[str]:
+    """A family file that is missing, or short of the interface. Read, not
+    run: the file may import the program."""
+    path = _family_file(name)
+    if path is None:
+        return [f"family {name}: no file families/{name}.py"]
+    defined: set = set()
+    for node in ast.parse(path.read_text()).body:
+        if isinstance(node, (ast.FunctionDef, ast.ClassDef)):
+            defined.add(node.name)
+        elif isinstance(node, (ast.Assign, ast.AnnAssign)):
+            targets = node.targets if isinstance(node, ast.Assign) else [node.target]
+            defined.update(
+                n.id for t in targets for n in ast.walk(t) if isinstance(n, ast.Name)
+            )
+        elif isinstance(node, (ast.Import, ast.ImportFrom)):
+            defined.update((a.asname or a.name).split(".")[0] for a in node.names)
+    missing = [n for n in FAMILY_INTERFACE if n not in defined]
+    return [f"family {name}: families/{name}.py lacks {', '.join(missing)}"] if missing else []
+
+
+def reduced_problem(key: str) -> bool:
+    """Whether ``reduced`` may not name this key: a width, or no name."""
+    return not NAME.match(key) or (key not in ROW_COUNTS and bool(WIDTHS.search(key)))
+
+
 def load_readers() -> dict:
     """quantity -> reader, from every file under ``layers/``. Two files that
     claim one name are an error."""
     readers: dict = {}
     for path in sorted((HERE / "layers").glob("*.py")):
-        spec = importlib.util.spec_from_file_location(f"bench_layer_{path.stem}", path)
-        module = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(module)
+        module = _load(path, f"bench_layer_{path.stem}")
         for name, fn in module.METRICS.items():
             if name in readers:
                 raise ValueError(f"metric {name!r} has two readers ({path.name})")
@@ -112,8 +195,11 @@ def problems(manifest: dict, root: Path) -> list[str]:
             out.append(f"config {c['name']}: file outside paths")
         if not (Path(root) / c["file"]).is_file():
             out.append(f"config {c['name']}: {c['file']} missing")
+        else:
+            family = family_name(json.loads((Path(root) / c["file"]).read_text()))
+            out.extend(f"config {c['name']}: {p}" for p in family_problems(family))
         for key in c["reduced"]:
-            if not NAME.match(key) or key.endswith(("_dim", "_rank")) or "size" in key:
+            if reduced_problem(key):
                 out.append(f"config {c['name']}: reduced names a width: {key}")
         if not any(w["config"] == c["name"] for w in cells.values()):
             out.append(f"config {c['name']} is used by no cell")

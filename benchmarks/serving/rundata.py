@@ -3,9 +3,15 @@
 from __future__ import annotations
 
 import dataclasses
+import json
 import math
+import re
+from pathlib import Path
 
 import stats
+
+#: the XLA module names of the compiled programs, by kind
+PROGRAMS = json.loads((Path(__file__).parent / "layers" / "programs.json").read_text())
 
 
 @dataclasses.dataclass
@@ -46,6 +52,25 @@ class RunData:
             if t is not None:
                 out.append(t)
         return out
+
+    def program(self, kind: str) -> tuple[float, float] | None:
+        """(device seconds, calls) of the programs of one kind (``decode``,
+        ``prefill``: patterns in ``layers/programs.json``) in the trace."""
+        if not self.trace:
+            return None
+        pat = re.compile(PROGRAMS[kind])
+        hits = [v for k, v in self.trace["programs"].items() if pat.search(k)]
+        if not hits:
+            return None
+        return sum(v["time_s"] for v in hits), sum(v["count"] for v in hits)
+
+    def prefilled_prompts(self) -> list[int]:
+        """Lengths of the prompts whose first token fell inside the window."""
+        t0, t1 = self.times["window_open"], self.times["window_close"]
+        return [
+            e["n_prompt"] for e in self.engine_log.values()
+            if e.get("first_token_at") is not None and t0 <= e["first_token_at"] < t1
+        ]
 
     def decode_batch_mean(self) -> float | None:
         """Decode tokens over decode steps in the window: generated tokens

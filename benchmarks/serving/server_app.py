@@ -3,11 +3,12 @@
 ``serving/openai_api.py``, with the benchmark's seeded weights.
 
 It differs from examples/06_gpu_and_ml/llm-serving/llm_inference.py in what a
-benchmark has to own: the configuration comes from a file of published sizes
-(``LlamaConfig.from_hf_config``), the weights from ``weights.py`` (so the
-reference can have the same ones without taking anything the program made),
-and the tokenizer is vocabulary-complete (``tokenizer.py``). Every engine
-setting the configuration file does not name stays at the program's default.
+benchmark has to own: the configuration comes from a file of published sizes,
+and what the engine is given for it, the seeded weights and the reference
+that sees the same ones without taking anything the program made all come
+from the file of the configuration's family (``families/<family>.py``); the
+tokenizer is vocabulary-complete (``tokenizer.py``). Every engine setting
+the configuration file does not name stays at the program's default.
 
 Beside the OpenAI port the container opens a control port for what only the
 process that owns the chip can do: start and stop the profiler and reduce
@@ -103,18 +104,18 @@ class BenchServer:
     def start(self):
         import jax
 
-        import weights as W
+        import manifest
         from tokenizer import IdTokenizer
 
-        from modal_examples_tpu.models.llama import LlamaConfig
         from modal_examples_tpu.models.quantize import QuantizedWeight
         from modal_examples_tpu.serving import OpenAIServer
         from modal_examples_tpu.serving.engine import LLMEngine
 
         self.config = json.loads(Path(CONFIG_FILE).read_text())
-        self.dims = W.dims_of(self.config)
-        cfg = LlamaConfig.from_hf_config(CONFIG_FILE)
-        tree = W.make_tree(SEED, self.dims)
+        self.family = manifest.load_family(self.config)
+        self.dims = self.family.dims_of(self.config)
+        cfg = self.family.program_config(CONFIG_FILE)
+        tree = self.family.make_tree(SEED, self.dims)
         params = jax.tree.map(
             lambda leaf: QuantizedWeight(q=leaf["q"], scale=leaf["scale"])
             if isinstance(leaf, dict) else leaf,
@@ -126,7 +127,8 @@ class BenchServer:
             cfg, params, seed=SEED % (2**31 - 1), **self.config["engine"]
         )
         del params
-        self.engine.tokenizer = IdTokenizer(cfg.vocab_size)
+        vocab = int(self.config["vocab_size"])
+        self.engine.tokenizer = IdTokenizer(vocab)
         self.server = OpenAIServer(
             self.engine, model_name=self.config["name"], port=PORT
         )
@@ -144,13 +146,12 @@ class BenchServer:
         self.server.submit = logged_submit
         if BREAK == "alter-token":
             accept = self.engine._accept_token
-            vocab = cfg.vocab_size
             self.engine._accept_token = lambda slot, token: accept(
                 slot, 3 + (int(token) + 7) % (vocab - 3)
             )
         self.static = {
             "impl_plan": {k: str(v) for k, v in self.engine.impl_plan.items()},
-            "kv_pages": int(self.engine.cache.k_pages.shape[1]),
+            "kv_pages": int(self.engine.cache.allocator.n_pages),
             "prefill_buckets": list(self.engine.prefill_buckets),
             "decode_block": int(self.engine.decode_block),
         }
@@ -199,7 +200,16 @@ class BenchServer:
     def trace_start(self) -> dict:
         import jax
 
-        jax.profiler.start_trace(TRACE_DIR)
+        # the reduction reads the device planes only. The profiler's Python
+        # tracer, on by default, hooks every Python call of the process: in a
+        # decode-bound cell (128 tokens a tick through the detokenizer and
+        # the streams) it held the scheduler 0.6 s a tick and the traced
+        # device idled 73% where the untraced run's idles ~13% (PERF.md
+        # section 6). The host's TraceMe events (the program's
+        # ``mtpu.tick/<phase>`` and ``mtpu.dispatch/<program>``) stay.
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        jax.profiler.start_trace(TRACE_DIR, profiler_options=options)
         return {"ok": True}
 
     def trace_stop(self, body: dict) -> dict:
@@ -223,15 +233,14 @@ class BenchServer:
 
         t0 = time.monotonic()
         self.server.stop()
-        engine, self.engine = self.engine, None
-        for leaf in jax.tree.leaves(engine.params):
-            leaf.delete()
-        for pages in jax.tree.leaves((engine.cache.k_pages, engine.cache.v_pages)):
-            pages.delete()
-        del engine
+        self.engine = None
         gc.collect()
+        # weights and cache, whatever their leaves are called: every device
+        # array the process still holds
+        for array in jax.live_arrays():
+            array.delete()
         out = reference.served_gaps(
-            SEED, self.dims, body["samples"], control=bool(body.get("control")),
+            self.family, SEED, self.dims, body["samples"], control=bool(body.get("control")),
             detail=bool(body.get("detail")),
         )
         out["check_s"] = time.monotonic() - t0

@@ -223,8 +223,7 @@ def prompt_lengths(mix: dict, seconds: float) -> list[int]:
             for p in quantile_grid(mix["prompt"], int(round(rate * length)))
         ]
     sess = mix["session"]
-    docs = quantile_grid(sess["document"], int(sess["pool"]))
-    return [1 + d + q for d in (min(docs), max(docs))
-            for q in (int(sess["question"]["min"]), int(sess["question"]["max"]))] + [
-        1 + d + int(sess["question"]["max"]) for d in docs
-    ]
+    docs = set(quantile_grid(sess["document"], int(sess["pool"])))
+    n_req = int(sess["sessions"]) * int(sess["turns"])
+    questions = set(quantile_grid(sess["question"], n_req))
+    return [1 + d + q for d in docs for q in questions]

@@ -1,15 +1,18 @@
 """The serving benchmark end to end on the CPU, at a tiny size.
 
-A made-up configuration (dense and routed), two made-up mixes and a made-up
-per-layer metric are added to a temporary copy of the benchmark as new files,
-with manifest entries of their own and no edit to any file that was there:
-the harness has to take them as data. Each run is a process of its own, as
-the command's are (the load generator's process may not hold JAX).
+A made-up configuration (dense and routed), two made-up mixes, a made-up
+per-layer metric and a made-up *family* (a family file, a configuration that
+names it, a cell) are added to a temporary copy of the benchmark as new
+files, with manifest entries of their own and no edit to any file that was
+there: the harness has to take them as data. Each run is a process of its
+own, as the command's are (the load generator's process may not hold JAX).
 
 What the runs show: the result line's keys; that the command refuses to
 report without a TPU; that the int4 control comes out as not correct while
-the program passes; and that a token altered where the timed path produces
-it turns ``correct`` false.
+the program passes; that a token altered where the timed path produces it
+turns ``correct`` false; and that the harness dispatches on the family a
+configuration names: the made-up family's cell is ``correct``, and the same
+served tokens held against the other family's reference are not.
 """
 
 import json
@@ -35,6 +38,80 @@ TINY = {
     # CPU: sound runs 0.0 to 0.02, the int4 control 0.5 and more at its widest
     "check": {"served_gap_max": 0.15, "served_gap_mean": 0.03},
 }
+# The made-up family: the program's one layer under keys of its own, its
+# weights and its reference from another stream of the seed than Llama's. The
+# harness may read none of its keys but ``vocab_size``, ``engine``, ``check``.
+ODD = {
+    "name": "tiny-odd", "family": "made-up", "width": 128, "ffn_width": 256, "depth": 2,
+    "q_heads": 4, "kv_groups": 2, "vocab_size": 512, "positions": 256, "eps": 1e-5,
+    "theta": 10000.0, "quantization": "int8", "kv_dtype": "bfloat16", "reduced": [],
+    "engine": TINY["engine"], "check": TINY["check"],
+}
+MADE_UP_FAMILY = '''
+"""A made-up family: the Llama layer under other keys, another weight stream."""
+import json
+from pathlib import Path
+
+import manifest
+
+_llama = manifest.load_family({})
+_STREAM = 1_000_003
+
+
+def _as_llama(c):
+    return {
+        "hidden_size": c["width"], "intermediate_size": c["ffn_width"],
+        "num_hidden_layers": c["depth"], "num_attention_heads": c["q_heads"],
+        "num_key_value_heads": c["kv_groups"], "vocab_size": c["vocab_size"],
+        "rms_norm_eps": c["eps"], "rope_theta": c["theta"],
+        "quantization": c.get("quantization"), "kv_dtype": c.get("kv_dtype", "bfloat16"),
+    }
+
+
+def dims_of(config):
+    return _llama.dims_of(_as_llama(config))
+
+
+def make_tree(seed, dims):
+    return _llama.make_tree(seed + _STREAM, dims)
+
+
+def program_config(config_file):
+    from modal_examples_tpu.models.llama import LlamaConfig
+
+    c = json.loads(Path(config_file).read_text())
+    return LlamaConfig(
+        vocab_size=c["vocab_size"], dim=c["width"], n_layers=c["depth"], n_heads=c["q_heads"],
+        n_kv_heads=c["kv_groups"], ffn_dim=c["ffn_width"], rope_theta=c["theta"],
+        norm_eps=c["eps"], max_seq_len=c["positions"],
+    )
+
+
+def logits_at(seed, dims, sequences, rows, bits=8):
+    return _llama.logits_at(seed + _STREAM, dims, sequences, rows, bits)
+
+
+def decode_step(config, batch, context_tokens):
+    return _llama.decode_step(_as_llama(config), batch, context_tokens)
+
+
+def prefill(config, prompt_lengths, calls):
+    return _llama.prefill(_as_llama(config), prompt_lengths, calls)
+
+
+SCOPE_WORK = {}
+'''
+# The same engine, the same weights, the same served tokens: held against the
+# other family's reference (Llama's stream of the seed).
+CROSSED_FAMILY = '''
+"""The made-up family's program and weights, the Llama family's reference."""
+import manifest
+
+_made_up = manifest.load_family({"family": "made-up"})
+dims_of, make_tree, program_config = _made_up.dims_of, _made_up.make_tree, _made_up.program_config
+decode_step, prefill, SCOPE_WORK = _made_up.decode_step, _made_up.prefill, _made_up.SCOPE_WORK
+logits_at = manifest.load_family({}).logits_at
+'''
 PACED = {
     "loop": "open", "rate_rps": 4,
     "prompt": {"dist": "lognormal", "median": 40, "sigma": 0.6, "min": 8, "max": 200},
@@ -74,27 +151,39 @@ def copy(tmp_path_factory):
     (bench / "configs/tiny-moe.json").write_text(json.dumps(
         dict(TINY, name="tiny-moe", num_local_experts=4, num_experts_per_tok=2)
     ))
+    (bench / "configs/tiny-odd.json").write_text(json.dumps(ODD))
+    (bench / "configs/tiny-crossed.json").write_text(json.dumps(
+        dict(ODD, name="tiny-crossed", family="made-up-crossed")
+    ))
+    (bench / "families/made-up.py").write_text(MADE_UP_FAMILY)
+    (bench / "families/made-up-crossed.py").write_text(CROSSED_FAMILY)
     (bench / "mixes/tiny-paced.json").write_text(json.dumps(PACED))
     (bench / "mixes/tiny-closed.json").write_text(json.dumps(CLOSED))
     (bench / "layers/made_up.py").write_text(
         "METRICS = {'requests_scored': lambda run: float(len(run.scored))}\n"
     )
     manifest = json.loads((ROOT / "BENCHMARK.json").read_text())
-    paced_cells = {w["name"] for w in manifest["workloads"] if w["traffic"] == "chat-paced"}
+    reason_cells = {w["name"] for w in manifest["workloads"] if w["traffic"] == "reason-closed"}
     manifest["configs"] = [
         {"name": n, "source": "made up for the test", "reduced": [], "why": "test",
-         "file": f"benchmarks/serving/configs/{n}.json"} for n in ("tiny-dense", "tiny-moe")
+         "file": f"benchmarks/serving/configs/{n}.json"}
+        for n in ("tiny-dense", "tiny-moe", "tiny-odd", "tiny-crossed")
     ]
     manifest["workloads"] = [
         {"name": "tiny-dense.tiny-paced", "config": "tiny-dense", "traffic": "tiny-paced",
          "chips": 1, "why": "test"},
         {"name": "tiny-moe.tiny-closed", "config": "tiny-moe", "traffic": "tiny-closed",
          "chips": 1, "why": "test"},
+        {"name": "tiny-odd.tiny-paced", "config": "tiny-odd", "traffic": "tiny-paced",
+         "chips": 1, "why": "test"},
+        {"name": "tiny-crossed.tiny-paced", "config": "tiny-crossed", "traffic": "tiny-paced",
+         "chips": 1, "why": "test"},
     ]
+    tiny_paced = [w["name"] for w in manifest["workloads"] if w["traffic"] == "tiny-paced"]
     for metric in manifest["end_to_end"] + manifest["per_layer"]:
         if "workloads" in metric:
             metric["workloads"] = (
-                ["tiny-dense.tiny-paced"] if set(metric["workloads"]) <= paced_cells
+                tiny_paced if set(metric["workloads"]) <= reason_cells
                 else ["tiny-moe.tiny-closed"]
             )
     manifest["per_layer"].append({
@@ -144,7 +233,7 @@ def test_result_line_of_an_untraced_run(paced):
     assert {"correct", "attempted", "failed", "metrics", "device"} <= set(result)
     assert result["correct"] is True and result["failed"] == 0
     assert result["attempted"] == round(PACED["rate_rps"] * 3.0)
-    assert set(result["metrics"]) == {"tpot_p50_ms", "setup_s"}
+    assert set(result["metrics"]) == {"out_tok_s", "setup_s"}
     for metric in result["metrics"].values():
         assert metric["value"] > 0 and metric["unit"]
     assert {"platform", "kind", "count", "memory_peak_bytes"} <= set(result["device"])
@@ -157,9 +246,10 @@ def test_traced_run_reports_the_layers_and_the_made_up_metric(closed_traced):
     names = set(result["metrics"])
     assert {"boot_s", "warmup_s", "closed.decode_batch_mean", "prefix_hit_pct",
             "kv_pages_peak_pct", "tpot_p50_obs_ms", "closed.ttft_p50_obs_ms",
-            "closed.ttft_p90_obs_ms", "closed.tpot_p90_obs_ms", "requests_scored"} <= names
+            "closed.ttft_p90_obs_ms", "closed.tpot_p90_obs_ms", "closed.out_tok_obs_s",
+            "requests_scored"} <= names
     # a traced line carries the per-layer metrics, and only this cell's variants
-    assert "out_tok_s" not in names and not any(n.startswith("paced.") for n in names)
+    assert "req_s" not in names and not any(n.startswith("reason.") for n in names)
     assert result["metrics"]["requests_scored"]["value"] == result["attempted"]
     assert 50 < result["metrics"]["prefix_hit_pct"]["value"] <= 100
     assert {"busy_s", "window_s"} <= set(result["device"])
@@ -187,6 +277,29 @@ def test_a_token_altered_in_the_timed_path_is_not_correct(copy):
     )
     assert result["correct"] is False
     assert result["compared"]["served_gap_max"] > TINY["check"]["served_gap_max"]
+
+
+def test_a_family_added_as_files_is_served_and_checked_by_its_own_reference(copy, paced):
+    result, stdout = _run(copy, workload="tiny-odd.tiny-paced", trace=False)
+    assert result["correct"] is True and result["failed"] == 0
+    assert result["attempted"] == round(PACED["rate_rps"] * 3.0)
+    assert set(result["metrics"]) == {"out_tok_s", "setup_s"}
+    assert result["compared"]["served_gap_max"] <= TINY["check"]["served_gap_max"]
+    assert "compared served_gap_max:" in stdout
+    # another stream of the seed: not the Llama family's run over again
+    assert result["compared"]["served_gap_mean"] != paced[0]["compared"]["served_gap_mean"]
+
+
+def test_the_other_familys_reference_finds_the_same_served_tokens_not_correct(copy):
+    """The harness dispatches on the family the configuration names: the
+    crossed family serves what the made-up family serves (its engine, its
+    weights, the same seed and traffic) and checks it against Llama's
+    reference."""
+    result, _ = _run(copy, workload="tiny-crossed.tiny-paced", trace=False)
+    assert result["failed"] == 0 and result["attempted"] == round(PACED["rate_rps"] * 3.0)
+    assert result["correct"] is False
+    assert result["compared"]["served_gap_max"] > TINY["check"]["served_gap_max"]
+    assert result["compared"]["served_gap_mean"] > TINY["check"]["served_gap_mean"]
 
 
 def test_the_command_refuses_to_report_without_a_tpu(copy):

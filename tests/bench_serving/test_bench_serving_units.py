@@ -24,9 +24,11 @@ import work_model  # noqa: E402
 
 CHAT = {**json.loads((BENCH / "mixes/chat-paced.json").read_text()), "rate_rps": 1.5}
 DOCQA = json.loads((BENCH / "mixes/docqa-closed.json").read_text())
+CHAT_CLOSED = json.loads((BENCH / "mixes/chat-closed.json").read_text())
 MISTRAL = json.loads((BENCH / "configs/mistral-7b-int8.json").read_text())
 MIXTRAL = json.loads((BENCH / "configs/mixtral-8x7b-int8-1chip.json").read_text())
 MANIFEST = json.loads((ROOT / "BENCHMARK.json").read_text())
+LLAMA = M.load_family(MISTRAL)  # both configurations are of the one family
 
 
 # -- traffic ------------------------------------------------------------------
@@ -190,6 +192,56 @@ def test_prompt_lengths_cover_what_the_mix_sends():
     assert max(closed) >= longest and min(closed) > 2048  # every prompt takes the chunked path
 
 
+@pytest.mark.parametrize("seed", [5, 2**31 + 77])
+def test_closed_chat_sends_one_multiset_and_warms_every_bucket_it_uses(seed):
+    """A closed-loop mix whose prompts spread over the prefill buckets: the
+    warm-up has one request for each bucket a prompt can fall into, so that
+    nothing compiles in the window."""
+    import run
+
+    sessions = traffic.closed_loop(CHAT_CLOSED, seed, 32000)
+    base = traffic.closed_loop(CHAT_CLOSED, 1, 32000)
+    assert all(len(s) == 1 for s in sessions) and len(sessions) == 720
+    sent = [1 + len(s[0].prompt_ids) for s in sessions]
+    assert sorted(sent) == sorted(1 + len(s[0].prompt_ids) for s in base)
+    assert sorted(s[0].max_tokens for s in sessions) == sorted(s[0].max_tokens for s in base)
+    assert len({tuple(s[0].prompt_ids[:64]) for s in sessions}) == 4  # the system prompts
+    assert 80 <= min(sent) and max(sent) <= 1024 and 180 < np.median(sent) < 205
+    assert set(sent) <= set(traffic.prompt_lengths(CHAT_CLOSED, 51))
+    buckets = [128, 256, 512, 1024, 2048]
+    warm = [1 + len(r.prompt_ids)
+            for r in run.warmup_requests(CHAT_CLOSED, 51, {"prefill_buckets": buckets}, 32000)]
+    bucket_of = lambda n: min(b for b in buckets if n <= b)  # noqa: E731
+    assert {bucket_of(n) for n in sent} == {bucket_of(n) for n in warm} == {128, 256, 512, 1024}
+    assert len(warm) == 4
+
+
+def test_req_s_counts_a_request_by_the_share_of_its_flight_in_the_window():
+    from types import SimpleNamespace as NS
+
+    sys.path.insert(0, str(BENCH / "layers"))
+    import end_to_end
+
+    def outcome(pieces, sent, done, ok=True):
+        return NS(pieces=pieces, sent_t=sent, done_t=done, ok=ok)
+
+    run = NS(
+        times={"window_open": 10.0, "window_close": 20.0},
+        outcomes=[
+            outcome([(11.0, 4), (12.0, 4)], 10.5, 12.0),  # whole: 1
+            outcome([(9.0, 5), (10.0, 5)], 8.0, 10.0),  # over as the window opens: 0
+            outcome([(19.5, 1), (20.0, 1), (21.0, 2)], 19.0, 21.0),  # half its flight: 0.5
+            outcome([(9.0, 1), (25.0, 1)], 5.0, 25.0),  # the window is half its flight: 0.5
+            outcome([(15.0, 3)], 14.0, 16.0, ok=False),  # failed: 0, though tokens came
+            outcome([], 19.9, None, ok=False),  # never answered
+        ],
+    )
+    assert end_to_end.req_s(run) == pytest.approx(2.0 / 10.0)
+    assert end_to_end.out_tok_s(run) == pytest.approx((8 + 5 + 1 + 3) / 10.0)
+    assert end_to_end.METRICS["req_obs_s"] is end_to_end.req_s
+    assert end_to_end.METRICS["out_tok_obs_s"] is end_to_end.out_tok_s
+
+
 def test_tokenizer_round_trip_over_the_vocabulary():
     tok = tokenizer.IdTokenizer(32000)
     ids = [3, 31, 32, 1023, 1024, 31999, 17]
@@ -264,7 +316,7 @@ def test_reduction_of_the_recorded_chip_trace():
 
 def test_busy_beyond_the_window_is_a_bug_not_a_result():
     peaks = work_model.peaks_for("TPU v5 lite")
-    work = work_model.decode_step(MISTRAL, 16, 16 * 1000)
+    work = LLAMA.decode_step(MISTRAL, 16, 16 * 1000)
     with pytest.raises(AssertionError):
         work_model.roofline_pct(work, 1e-3, peaks)  # faster than the HBM allows
     assert 0 < work_model.roofline_pct(work, 54e-3, peaks) < 100
@@ -276,16 +328,16 @@ def test_busy_beyond_the_window_is_a_bug_not_a_result():
 
 
 def test_mistral_decode_step_by_hand():
-    s = work_model.sizes(MISTRAL)
+    s = LLAMA.sizes(MISTRAL)
     attn = 4096 * 128 * (32 + 16) + 32 * 128 * 4096
     mlp = 3 * 4096 * 14336
-    assert work_model.attn_params(s) == attn == 41_943_040
-    assert work_model.expert_params(s) == mlp == 176_160_768
+    assert LLAMA.attn_params(s) == attn == 41_943_040
+    assert LLAMA.expert_params(s) == mlp == 176_160_768
     active = 32 * (attn + mlp) + 4096 * 32000
-    assert work_model.active_params_per_token(s) == active == 7_110_393_856
-    work = work_model.decode_step(MISTRAL, 8, 8 * 500)
+    assert LLAMA.active_params_per_token(s) == active == 7_110_393_856
+    work = LLAMA.decode_step(MISTRAL, 8, 8 * 500)
     kv_token = 2 * 32 * 8 * 128 * 2  # K and V, 32 layers, 8 heads of 128, bf16
-    assert work_model.kv_bytes_per_token(s) == kv_token == 131_072
+    assert LLAMA.kv_bytes_per_token(s) == kv_token == 131_072
     assert work["flops"] == 2 * active * 8 + 4 * 32 * 32 * 128 * 4000
     assert work["bytes"] == active * 1.0 + kv_token * 4008 + 8 * 4096 * 2
     least, bound = work_model.least_seconds(work, work_model.peaks_for("TPU v5 lite"))
@@ -293,29 +345,29 @@ def test_mistral_decode_step_by_hand():
 
 
 def test_mixtral_decode_step_streams_only_the_experts_reached():
-    s = work_model.sizes(MIXTRAL)
+    s = LLAMA.sizes(MIXTRAL)
     L = s["L"]
     assert L == 7 and s["E"] == 8 and s["k"] == 2
-    assert work_model.experts_reached(s, 1) == pytest.approx(2.0)
-    assert work_model.experts_reached(s, 16) == pytest.approx(8 * (1 - 0.75**16))
+    assert LLAMA.experts_reached(s, 1) == pytest.approx(2.0)
+    assert LLAMA.experts_reached(s, 16) == pytest.approx(8 * (1 - 0.75**16))
     attn, expert = 41_943_040, 176_160_768
     active = L * (attn + 2 * expert + 4096 * 8) + 4096 * 32000
-    assert work_model.active_params_per_token(s) == active
-    one = work_model.decode_step(MIXTRAL, 1, 100)
+    assert LLAMA.active_params_per_token(s) == active
+    one = LLAMA.decode_step(MIXTRAL, 1, 100)
     want = L * (attn + 2 * expert + 4096 * 8 * 2.0) + 4096 * 32000
     want += 2 * L * 8 * 128 * 2 * 101 + 4096 * 2
     assert one["bytes"] == pytest.approx(want)
 
 
 def test_prefill_counts_causal_attention_and_reads_weights_once_a_call():
-    work = work_model.prefill(MISTRAL, [2048], 1)
-    s = work_model.sizes(MISTRAL)
-    body = work_model.active_params_per_token(s) - 4096 * 32000
+    work = LLAMA.prefill(MISTRAL, [2048], 1)
+    s = LLAMA.sizes(MISTRAL)
+    body = LLAMA.active_params_per_token(s) - 4096 * 32000
     assert work["flops"] == pytest.approx(
         2 * body * 2048 + 2 * 4096 * 32000 + 4 * 32 * 32 * 128 * 2048 * 2049 / 2
     )
-    two = work_model.prefill(MISTRAL, [2048], 2)
-    assert two["bytes"] - work["bytes"] == pytest.approx(work_model.weight_bytes(s, 1024))
+    two = LLAMA.prefill(MISTRAL, [2048], 2)
+    assert two["bytes"] - work["bytes"] == pytest.approx(LLAMA.weight_bytes(s, 1024))
 
 
 # -- the numbers `correct` is decided on --------------------------------------
@@ -356,10 +408,11 @@ def test_manifest_meets_the_contract_rules():
     for cell in MANIFEST["workloads"]:
         info = M.resolve(MANIFEST, cell["name"], ROOT)
         reported = {m["name"] for m in info["end_to_end"]}
-        paced = info["mix"]["loop"] == "open"
-        assert reported == {"setup_s", "tpot_p50_ms" if paced else "out_tok_s"}
-        if paced:
-            assert info["mix"]["rate_rps"] > 0
+        assert info["mix"]["loop"] == "closed" and info["mix"]["clients"] > 0
+        reason = cell["traffic"] == "reason-closed"
+        assert reported == {"setup_s", "out_tok_s" if reason else "req_s"}
+        variants = {m["name"].rpartition(".")[0] for m in info["per_layer"]}
+        assert variants == {"", "reason" if reason else "closed"}
         # a quantity read in both kinds of cell has a variant for each, and a
         # cell reports each quantity once
         quantities = [M.quantity(m["name"]) for m in info["per_layer"]]
@@ -370,7 +423,7 @@ def test_manifest_meets_the_contract_rules():
 def test_a_metric_finds_its_reader_by_its_quantity():
     assert M.quantity("closed.decode_roofline") == "decode_roofline"
     assert M.quantity("decode_roofline") == "decode_roofline"
-    assert M.quantity("paced.ttft_p50_obs_ms") == "ttft_p50_obs_ms"
+    assert M.quantity("reason.ttft_p50_obs_ms") == "ttft_p50_obs_ms"
 
 
 @pytest.mark.parametrize("mutate,needle", [

@@ -143,7 +143,7 @@ def test_manifest_with_the_new_entries_has_no_problems():
     assert M.problems(MANIFEST, ROOT) == []
     names = {m["name"] for m in MANIFEST["per_layer"]}
     for quantity in (*COUNTER_SOURCED, "helper_programs_per_block"):
-        assert {f"paced.{quantity}", f"closed.{quantity}"} <= names
+        assert {f"reason.{quantity}", f"closed.{quantity}"} <= names
         assert quantity in READERS
     compile_s = next(m for m in MANIFEST["per_layer"] if m["name"] == "compile_s")
     assert compile_s["moves"] == "setup_s" and "workloads" not in compile_s
@@ -236,7 +236,7 @@ def test_traced_cpu_run_prints_every_counter_sourced_metric(traced):
     for quantity in COUNTER_SOURCED:
         assert f"closed.{quantity}" in metrics, (quantity, sorted(metrics))
     assert "compile_s" in metrics
-    assert not any(name.startswith("paced.") for name in metrics)
+    assert not any(name.startswith("reason.") for name in metrics)
     assert traced["correct"] is True and traced["failed"] == 0
 
 
