@@ -718,6 +718,15 @@ class _Container:
                     exc, _tb = ser.deserialize_exception(msg[1])
                     self.boot_error = exc
                     self.ready.set()
+                    # the pool hears of the failure from _on_death and drops
+                    # this container; whoever waits on the boot may then exit.
+                    # Not before the process is gone: one that had opened the
+                    # chips takes seconds to hand them back, and would be left
+                    # behind still holding them
+                    try:
+                        self.proc.wait(60.0 if self.pool.spec.tpu else 5.0)
+                    except subprocess.TimeoutExpired:
+                        self.kill()
                     break
                 elif kind == "yield":
                     _, input_id, payload = msg

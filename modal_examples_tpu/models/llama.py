@@ -79,6 +79,28 @@ class LlamaConfig:
     def jnp_dtype(self):
         return jnp.dtype(self.dtype)
 
+    # -- the seam LLMEngine reads (docs/mla.md): a configuration names the
+    # module that holds its programs (init_params, prefill, prefill_chunk,
+    # decode_step, paged_impl_plan, partition_specs, load_hf_weights), the
+    # per-token shape of the two paged cache leaves, and its int8 targets
+
+    @property
+    def model(self):
+        import sys
+
+        return sys.modules[__name__]
+
+    @property
+    def cache_leaf_shapes(self) -> tuple[tuple[int, int], tuple[int, int]]:
+        kv = (self.n_kv_heads, self.head_dim)
+        return (kv, kv)
+
+    @property
+    def quant_targets(self) -> tuple[str, ...]:
+        from .quantize import LLAMA_TARGETS
+
+        return LLAMA_TARGETS
+
     @property
     def param_count(self) -> int:
         emb = self.vocab_size * self.dim * (1 if self.tie_embeddings else 2)

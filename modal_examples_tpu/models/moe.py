@@ -24,7 +24,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from ..ops.scopes import EXPERT_SCAN, ROUTER
+from ..ops.scopes import EXPERT_DISPATCH, EXPERT_SCAN, ROUTER
 
 
 @dataclasses.dataclass(frozen=True)
@@ -198,6 +198,153 @@ def moe_swiglu_nodrop(
             (w_gate, w_up, w_down, w_full.T),
         )
     return out, aux
+
+
+
+def route_group_limited(
+    scores: jax.Array,  # [T, E] f32 — the router's softmax over all E experts
+    top_k: int,
+    *,
+    n_group: int = 1,
+    topk_group: int = 1,
+    scale: float = 1.0,
+    renormalize: bool = False,
+) -> tuple[jax.Array, jax.Array]:
+    """Group-limited greedy top-k (DeepSeek-V2's ``group_limited_greedy``):
+    a group's score is its largest expert score, the ``topk_group`` best of
+    ``n_group`` groups stay and the other groups' scores read zero, then the
+    ``top_k`` largest of what is left. The weights are those scores, not
+    renormalised unless asked, times ``scale``. ``n_group=1`` is the plain
+    top-k. Returns (weights [T, k] f32, expert ids [T, k] int32)."""
+    T, E = scores.shape
+    if n_group > 1:
+        group_scores = scores.reshape(T, n_group, E // n_group).max(axis=-1)
+        _, group_ids = jax.lax.top_k(group_scores, topk_group)  # [T, kg]
+        keep = jax.nn.one_hot(group_ids, n_group, dtype=jnp.int32).sum(axis=1) > 0
+        scores = jnp.where(jnp.repeat(keep, E // n_group, axis=1), scores, 0.0)
+    weights, ids = jax.lax.top_k(scores, top_k)
+    if renormalize:
+        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-20)
+    return weights * scale, ids.astype(jnp.int32)
+
+
+def _round_up(n: int, to: int) -> int:
+    return -(-n // to) * to
+
+
+def moe_swiglu_sparse(
+    w_gate,  # [E_held, D, F] — plain or QuantizedWeight
+    w_up,
+    w_down,  # [E_held, F, D]
+    x: jax.Array,  # [T, D]
+    ids: jax.Array,  # [T, k] int32 — expert ids out of the router's full width
+    weights: jax.Array,  # [T, k] f32 — their combine weights
+    *,
+    expert_offset: int = 0,  # id of the first expert held here
+    token_mask: jax.Array | None = None,  # [T] bool — tokens that count
+    tile: int | None = None,
+    layer: jax.Array | None = None,  # weights are [L, E_held, ...]: this layer
+) -> tuple[jax.Array, jax.Array]:
+    """The held experts' part of a routed SwiGLU layer, computing only the
+    (token, expert) pairs that land on them: exact, no capacity, no dropped
+    pair, work proportional to the pairs up to a tile's padding.
+
+    "Experts held here": the weights hold experts ``expert_offset ..
+    expert_offset + E_held - 1`` of a router that is wider; pairs routed
+    elsewhere add nothing (another chip's share would). The pairs are sorted
+    by expert, each expert's run padded to whole tiles of ``tile`` rows, and
+    a loop whose trip count is the number of tiles *in use* runs one tile a
+    trip: gather the tile's token rows, the three matmuls against that one
+    expert's weights (indexed out of the stack, so an expert no pair reaches
+    is never read), write the tile's rows. The combine gathers each token's
+    ``k`` rows back (a pair not computed here points at a row of zeros) and
+    sums them under the weights in f32. Static shapes throughout: the row
+    buffer holds the worst case, all ``T * k`` pairs held plus a partial tile
+    an expert; the loop touches only the rows in use.
+
+    With ``layer`` (a traced scalar) the weights keep a leading layer axis
+    and a tile indexes ``[layer, expert]`` out of the whole stack: a layer
+    scan that sliced the stack per layer instead would copy every expert of
+    the layer, reached or not, before the loop ran.
+
+    ``tile`` defaults to 128 rows (where an int8 expert matrix's read and a
+    tile's matmul take a v5e about as long), or all ``T`` tokens rounded up
+    to 16 when that is less: then no expert has more than one tile.
+
+    Returns (out [T, D] f32, counts [2] int32: the pairs of counted tokens
+    that landed on held experts, and all their pairs).
+    """
+    from .layers import mm
+
+    T, D = x.shape
+    k = ids.shape[1]
+    E = w_gate.shape[0 if layer is None else 1]
+    TM = tile or min(128, _round_up(T, 16))
+    M = T * k
+    M_pad = _round_up(M + E * (TM - 1), TM)  # rows; row M_pad stays zero
+    with jax.named_scope(EXPERT_DISPATCH):
+        local = ids - expert_offset
+        held = (local >= 0) & (local < E)
+        if token_mask is not None:
+            held = held & token_mask[:, None]
+            n_all = jnp.sum(token_mask.astype(jnp.int32)) * k
+        else:
+            n_all = jnp.asarray(M, jnp.int32)
+        counts_out = jnp.stack([jnp.sum(held.astype(jnp.int32)), n_all])
+        eid = jnp.where(held, local, E).reshape(M)  # E: not computed here
+        order = jnp.argsort(eid, stable=True)
+        eid_sorted = eid[order]
+        counts = jnp.bincount(eid, length=E + 1)[:E]
+        padded = (counts + TM - 1) // TM * TM
+        ends = jnp.cumsum(padded)  # [E] — row where each expert's tiles end
+        n_tiles = ends[-1] // TM
+        e_of = jnp.minimum(eid_sorted, E - 1)
+        rank = jnp.arange(M) - (jnp.cumsum(counts) - counts)[e_of]
+        row_sorted = jnp.where(
+            eid_sorted < E, (ends - padded)[e_of] + rank, M_pad
+        )
+        # which token each row of the buffer computes; T: a row of zeros
+        row_token = jnp.full((M_pad,), T, jnp.int32).at[row_sorted].set(
+            (order // k).astype(jnp.int32), mode="drop"
+        )
+        row_of_pair = jnp.zeros((M,), jnp.int32).at[order].set(
+            row_sorted.astype(jnp.int32)
+        ).reshape(T, k)
+        tile_expert = jnp.minimum(
+            jnp.searchsorted(ends, jnp.arange(M_pad // TM) * TM, side="right"),
+            E - 1,
+        ).astype(jnp.int32)
+        x_rows = jnp.concatenate([x, jnp.zeros((1, D), x.dtype)], axis=0)
+
+    def one(w, e):
+        """Expert ``e``'s matrix, sliced out of the stack where it is used."""
+        lead = (e,) if layer is None else (layer, e)
+
+        def pick(a):
+            rest = a.shape[len(lead):]
+            start = (*lead, *(0 for _ in rest))
+            return jax.lax.dynamic_slice(a, start, (1,) * len(lead) + rest).reshape(rest)
+
+        return jax.tree.map(pick, w)
+
+    def body(i, buf):
+        with jax.named_scope(EXPERT_DISPATCH):
+            rows = jax.lax.dynamic_slice_in_dim(row_token, i * TM, TM)
+            xt = x_rows[rows]  # [TM, D]
+        with jax.named_scope(EXPERT_SCAN):
+            e = tile_expert[i]
+            a = mm(xt, one(w_gate, e))
+            b = mm(xt, one(w_up, e))
+            y = mm((jax.nn.silu(a) * b).astype(x.dtype), one(w_down, e))
+        with jax.named_scope(EXPERT_DISPATCH):
+            return jax.lax.dynamic_update_slice_in_dim(buf, y, i * TM, 0)
+
+    buf = jax.lax.fori_loop(
+        0, n_tiles, body, jnp.zeros((M_pad + 1, D), jnp.float32)
+    )
+    with jax.named_scope(EXPERT_DISPATCH):
+        out = jnp.einsum("tk,tkd->td", weights, buf[row_of_pair])
+    return out, counts_out
 
 
 def moe_swiglu_capacity(

@@ -79,6 +79,15 @@ LLAMA_TARGETS = (
 )
 
 
+#: ... and in a DeepSeek-V2 tree (models/deepseek_v2.py): the two low-rank
+#: query and key/value projections, the output projection, the dense, shared
+#: and routed SwiGLUs; the router, like the norms, stays high precision
+DEEPSEEK_V2_TARGETS = (
+    "wq_a", "wq_b", "wkv_a", "wkv_b", "wo", "gate", "up", "down",
+    "moe_gate", "moe_up", "moe_down", "shared_gate", "shared_up", "shared_down",
+)
+
+
 def bits_of(quantization: str) -> int:
     if quantization not in ("int8", "int4"):
         raise ValueError(f"unknown quantization {quantization!r}")
@@ -88,7 +97,10 @@ def bits_of(quantization: str) -> int:
 def quantize_llama(
     params: dict, targets=LLAMA_TARGETS, *, bits: int = 8
 ) -> dict:
-    """Quantize the layer matmuls (and lm_head) of a llama param tree.
+    """Quantize the layer matmuls (and lm_head) of a llama param tree, or
+    of any tree whose layer stacks sit under keys that end in ``layers``
+    (DeepSeek-V2's ``dense_layers`` / ``moe_layers`` with
+    ``DEEPSEEK_V2_TARGETS``).
 
     Device-side path for caller-provided trees. Peak HBM is bf16 + int
     together; callers that own the tree outright should random-init via
@@ -96,10 +108,12 @@ def quantize_llama(
     ``llama.load_hf_weights(quantization=...)`` (host-side quantize).
     """
     out = dict(params)
-    out["layers"] = {
-        name: quantize_weight(w, bits) if name in targets else w
-        for name, w in params["layers"].items()
-    }
+    for key, stack in params.items():
+        if key.endswith("layers"):
+            out[key] = {
+                name: quantize_weight(w, bits) if name in targets else w
+                for name, w in stack.items()
+            }
     if "lm_head" in params:
         out["lm_head"] = quantize_weight(params["lm_head"], bits)
     return out
@@ -114,10 +128,10 @@ def init_quantized_llama(key, cfg, *, bits: int = 8) -> dict:
     compiler frees it inside the program, so peak HBM is the quantized tree
     plus one transient leaf.
     """
-    from . import llama
-
     return jax.jit(
-        lambda k: quantize_llama(llama.init_params(k, cfg), bits=bits)
+        lambda k: quantize_llama(
+            cfg.model.init_params(k, cfg), cfg.quant_targets, bits=bits
+        )
     )(key)
 
 

@@ -60,6 +60,18 @@ PREFILL_POSITIONS_TOTAL = "mtpu_prefill_positions_total"
 #: summed) | table (slots x pages_per_slot x page_size: what a full-table
 #: gather would read). read / table is how far the loop runs
 DECODE_KV_POSITIONS_TOTAL = "mtpu_decode_kv_positions_total"
+#: counter: cached prefix positions a prefill call attends to, counted at
+#: the dispatch of a chunk at an offset (host-known: the offset): the
+#: positions whose cached state the program reads back, and, for a latent
+#: cache, expands again (models/deepseek_v2.py)
+PREFILL_PREFIX_POSITIONS_TOTAL = "mtpu_prefill_prefix_positions_total"
+#: counter {where}: (token, expert) pairs the decode blocks' routed layers
+#: chose, over their live slots, steps and layers; where = held (the expert
+#: is one this chip holds: its part of the sum is computed) | elsewhere
+#: (another chip's share: left out). Counted on the device, read with the
+#: block's tokens at its harvest. Only a model that holds a share of its
+#: experts reports it
+ROUTED_PAIRS_TOTAL = "mtpu_routed_pairs_total"
 #: gauge: requests waiting for admission (engine queue depth)
 WAITING_REQUESTS = "mtpu_waiting_requests"
 #: gauge: slots currently decoding
@@ -532,6 +544,18 @@ CATALOG: dict[str, dict] = {
         "help": "KV positions per decode step at block dispatch (kind="
                 "read: chunk trips x chunk positions x slots | live: live "
                 "contexts | table: slots x table positions)",
+    },
+    PREFILL_PREFIX_POSITIONS_TOTAL: {
+        "type": "counter",
+        "labels": [],
+        "help": "cached prefix positions that prefill chunk calls at an "
+                "offset attended to (read back from the cache)",
+    },
+    ROUTED_PAIRS_TOTAL: {
+        "type": "counter",
+        "labels": ["where"],
+        "help": "(token, expert) pairs routed in decode blocks (where=held: "
+                "on an expert this chip holds | elsewhere: another share's)",
     },
     WAITING_REQUESTS: {
         "type": "gauge",

@@ -135,6 +135,30 @@ def record_decode_kv_positions(
         )
 
 
+def record_prefill_prefix_positions(
+    positions: int, *, registry: Registry | None = None
+) -> None:
+    """One prefill chunk call at an offset: the cached prefix positions it
+    attends to."""
+    _reg(registry).counter_inc(
+        C.PREFILL_PREFIX_POSITIONS_TOTAL, float(positions),
+        help=C.CATALOG[C.PREFILL_PREFIX_POSITIONS_TOTAL]["help"],
+    )
+
+
+def record_routed_pairs(
+    held: int, elsewhere: int, *, registry: Registry | None = None
+) -> None:
+    """One harvested decode block of a model that holds a share of its
+    experts: the routed pairs that landed on held experts, and the rest."""
+    reg = _reg(registry)
+    for where, n in (("held", held), ("elsewhere", elsewhere)):
+        reg.counter_inc(
+            C.ROUTED_PAIRS_TOTAL, float(n), labels={"where": where},
+            help=C.CATALOG[C.ROUTED_PAIRS_TOTAL]["help"],
+        )
+
+
 def set_engine_gauges(
     *,
     waiting: int,

@@ -25,6 +25,7 @@ from .paged_attention import (
     decode_chunk_trips,
     paged_decode_attention,
     paged_decode_attention_chunked,
+    paged_latent_decode_attention_chunked,
     paged_decode_attention_inflight,
     paged_decode_attention_ragged,
     scatter_kv_pages,
@@ -59,6 +60,7 @@ __all__ = [
     "flash_attention_with_lse",
     "paged_decode_attention",
     "paged_decode_attention_chunked",
+    "paged_latent_decode_attention_chunked",
     "paged_decode_attention_inflight",
     "paged_decode_attention_ragged",
     "is_quantized",

@@ -40,15 +40,15 @@ _LANES = 128  # f32 scratch tile: (8, 128); m/l are broadcast across lanes
 def _fwd_kernel(
     q_ref,  # (1, block_q, D)
     k_ref,  # (1, block_k, D)
-    v_ref,  # (1, block_k, D)
-    o_ref,  # (1, block_q, D)
+    v_ref,  # (1, block_k, Dv): values may be narrower than q/k
+    o_ref,  # (1, block_q, Dv)
     lse_ref,  # (1, block_q, LANES) — row stats ride a 128-lane dim: Mosaic
     #           requires output tiles shaped (8k, 128m); a bare (1, block_q)
     #           block fails lowering (the official TPU flash kernel pads the
     #           same way)
     m_scr,  # (block_q, LANES) f32
     l_scr,  # (block_q, LANES) f32
-    acc_scr,  # (block_q, D) f32
+    acc_scr,  # (block_q, Dv) f32
     *,
     sm_scale: float,
     causal: bool,
@@ -126,6 +126,7 @@ def _flash_forward(
 ):
     B, Hq, S, D = q.shape  # S = query length
     Hkv, Skv = k.shape[1], k.shape[2]
+    Dv = v.shape[-1]  # values may be narrower than q/k (latent attention)
     if S % block_q or Skv % block_k:
         raise ValueError(
             f"lengths (q={S}, kv={Skv}) must be multiples of block sizes "
@@ -141,7 +142,7 @@ def _flash_forward(
     # fold (B, Hkv, group) into one leading grid axis; kv index drops `group`
     qf = q.reshape(B * Hkv * group, S, D)
     kf = k.reshape(B * Hkv, Skv, D)
-    vf = v.reshape(B * Hkv, Skv, D)
+    vf = v.reshape(B * Hkv, Skv, Dv)
 
     grid = (B * Hkv * group, pl.cdiv(S, block_q), pl.cdiv(Skv, block_k))
     kernel = functools.partial(
@@ -166,14 +167,14 @@ def _flash_forward(
                 memory_space=pltpu.VMEM,
             ),
             pl.BlockSpec(
-                (1, block_k, D),
+                (1, block_k, Dv),
                 lambda bh, qi, ki, g=group: (bh // g, ki, 0),
                 memory_space=pltpu.VMEM,
             ),
         ],
         out_specs=[
             pl.BlockSpec(
-                (1, block_q, D), lambda bh, qi, ki: (bh, qi, 0),
+                (1, block_q, Dv), lambda bh, qi, ki: (bh, qi, 0),
                 memory_space=pltpu.VMEM,
             ),
             pl.BlockSpec(
@@ -182,22 +183,24 @@ def _flash_forward(
             ),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct(qf.shape, q.dtype),
+            jax.ShapeDtypeStruct((B * Hkv * group, S, Dv), q.dtype),
             jax.ShapeDtypeStruct((B * Hkv * group, S, _LANES), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, _LANES), jnp.float32),
             pltpu.VMEM((block_q, _LANES), jnp.float32),
-            pltpu.VMEM((block_q, D), jnp.float32),
+            pltpu.VMEM((block_q, Dv), jnp.float32),
         ],
         cost_estimate=pl.CostEstimate(
-            flops=int(4 * B * Hq * S * S * D * (0.5 if causal else 1.0)),
-            bytes_accessed=(qf.size + kf.size + vf.size + qf.size) * q.dtype.itemsize,
+            flops=int(2 * B * Hq * S * S * (D + Dv) * (0.5 if causal else 1.0)),
+            bytes_accessed=(
+                qf.size + kf.size + vf.size + B * Hq * S * Dv
+            ) * q.dtype.itemsize,
             transcendentals=B * Hq * S * S,
         ),
         interpret=interpret,
     )(qf, kf, vf)
-    return o.reshape(B, Hq, S, D), lse[:, :, 0].reshape(B, Hq, S)
+    return o.reshape(B, Hq, S, Dv), lse[:, :, 0].reshape(B, Hq, S)
 
 
 def _use_interpret() -> bool:

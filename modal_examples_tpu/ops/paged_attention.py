@@ -387,6 +387,92 @@ def paged_decode_attention_chunked(
     return (acc / l[..., None]).reshape(B, Hq, D).astype(q.dtype)
 
 
+
+@jax.named_scope(ATTENTION)
+def paged_latent_decode_attention_chunked(
+    q_lat: jax.Array,  # [B, Hq, C] — queries absorbed into the latent space
+    q_pe: jax.Array,  # [B, Hq, R] — their rotated slice
+    c_pages,  # [L, n_pages, page_size, 1, C] — normalised latents, in place
+    r_pages,  # [n_pages, page_size, 1, R] — this layer's rotated keys, shared
+    #           by all heads (or [L, ...]: indexed by ``layer`` like c_pages)
+    layer: jax.Array,  # scalar int32
+    page_tables: jax.Array,  # [B, pages_per_seq] int32
+    prefix_lens: jax.Array,  # [B] int32 — tokens already IN the cache
+    c_new: jax.Array,  # [B, C] — current token's latent (not yet written)
+    r_new: jax.Array,  # [B, R]
+    *,
+    sm_scale: float,
+) -> jax.Array:  # [B, Hq, C] — attention-weighted latents
+    """Absorbed latent (MLA) decode attention over the live context only.
+
+    ``paged_decode_attention_chunked``'s loop (the same chunk size, the same
+    trip count read from ``prefix_lens``, the same online softmax in f32
+    started from the in-flight token's column) over a cache whose leaves are
+    one latent and one rotated key a token, shared by every head: scores are
+    ``q_lat . c + q_pe . r``, values are the latents themselves. A gathered
+    chunk is read once for all ``Hq`` heads. The caller absorbs ``W_kvb``'s
+    key half into ``q_lat`` and applies its value half to the result.
+    """
+    B, Hq, C = q_lat.shape
+    page_size = c_pages.shape[2]
+    pages_per_seq = page_tables.shape[1]
+    W = decode_chunk_pages(page_size, pages_per_seq)
+    if pages_per_seq % W:
+        page_tables = jnp.pad(page_tables, ((0, 0), (0, -pages_per_seq % W)))
+    trips = decode_chunk_trips(jnp.max(prefix_lens), page_size, pages_per_seq)
+    kv_dtype = c_pages.dtype
+    q_lat, q_pe = q_lat.astype(kv_dtype), q_pe.astype(kv_dtype)
+    # a page's rotated keys as one row of page_size * R lanes: gathered as
+    # [page_size, 1, R] slabs the R = 64 minor dim is half a lane tile. The
+    # caller hands this layer's slice (a layer scan's sliced input): over
+    # the whole [L, ...] leaf the TPU compiler keeps a pages-minor layout
+    # and relays all of it out (192 MiB at the benchmark's size) for every
+    # gather; the slice it relays out is an eighth of that, once a layer
+    if r_pages.ndim == 5:
+        r_pages = r_pages[layer]
+    r_rows = r_pages.reshape(r_pages.shape[0], -1)  # [n_pages, ps * R]
+    in_chunk = (
+        jnp.arange(W)[:, None] * page_size + jnp.arange(page_size)[None, :]
+    )  # [W, ps]
+
+    def chunk(c, carry):
+        m, l, acc = carry  # [B, Hq], [B, Hq], [B, Hq, C] f32
+        cols = jax.lax.dynamic_slice_in_dim(page_tables, c * W, W, axis=1)
+        cs = kv_gather(c_pages, cols, layer=layer)[:, :, :, 0]  # [B, W, ps, C]
+        rs = r_rows[cols].reshape(B, W, page_size, -1)  # [B, W, ps, R]
+        s = (
+            jnp.einsum("bhc,bptc->bhpt", q_lat, cs,
+                       preferred_element_type=jnp.float32)
+            + jnp.einsum("bhr,bptr->bhpt", q_pe, rs,
+                         preferred_element_type=jnp.float32)
+        ) * sm_scale  # [B, Hq, W, ps]
+        valid = (c * W * page_size + in_chunk)[None] < prefix_lens[
+            :, None, None
+        ]  # [B, W, ps]
+        s = jnp.where(valid[:, None], s, -jnp.inf)
+        m_new = jnp.maximum(m, jnp.max(s, axis=(-2, -1)))
+        p = jnp.exp(s - m_new[..., None, None])
+        alpha = jnp.exp(m - m_new)
+        l = l * alpha + jnp.sum(p, axis=(-2, -1))
+        acc = acc * alpha[..., None] + jnp.einsum(
+            "bhpt,bptc->bhc", p.astype(cs.dtype), cs,
+            preferred_element_type=jnp.float32,
+        )
+        return m_new, l, acc
+
+    # the in-flight token at cache dtype, as if read back from the cache
+    c_tok, r_tok = c_new.astype(kv_dtype), r_new.astype(kv_dtype)
+    s_new = (
+        jnp.einsum("bhc,bc->bh", q_lat, c_tok, preferred_element_type=jnp.float32)
+        + jnp.einsum("bhr,br->bh", q_pe, r_tok, preferred_element_type=jnp.float32)
+    ) * sm_scale
+    acc0 = jnp.broadcast_to(c_tok.astype(jnp.float32)[:, None, :], (B, Hq, C))
+    _, l, acc = jax.lax.fori_loop(
+        0, trips, chunk, (s_new, jnp.ones_like(s_new), acc0)
+    )
+    return acc / l[..., None]
+
+
 def _decode_kernel_ragged(
     # scalar prefetch
     layer_ref,  # (1,) int32, SMEM — which layer of the [L, P, ...] cache

@@ -100,7 +100,7 @@ def attention_chunked(
     s = jnp.where(rows >= cols, s, -jnp.inf)
     p = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("bhgqk,bhkd->bhgqd", p.astype(v.dtype), v)
-    return o.reshape(B, Hq, Sq, D)
+    return o.reshape(B, Hq, Sq, v.shape[-1])  # values may be narrower than q/k
 
 
 def paged_decode_attention(

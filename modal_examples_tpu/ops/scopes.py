@@ -19,9 +19,11 @@ ROUTER = "mtpu.router"  # MoE router logits, top-k, combine weights
 EXPERT_SCAN = "mtpu.expert_scan"  # the experts' SwiGLU over the token block
 KV_SCATTER = "mtpu.kv_scatter"  # new K/V rows -> cache pages
 SAMPLING = "mtpu.sampling"  # logits -> next token
+LATENT_EXPAND = "mtpu.latent_expand"  # MLA latents -> per-head keys and values
+EXPERT_DISPATCH = "mtpu.expert_dispatch"  # routed pairs sorted to tiles and back
 
 ALL = (
     PAGE_GATHER, ATTENTION, DENSE_MLP, ROUTER, EXPERT_SCAN, KV_SCATTER,
-    SAMPLING,
+    SAMPLING, LATENT_EXPAND, EXPERT_DISPATCH,
 )
 

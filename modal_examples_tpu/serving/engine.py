@@ -39,7 +39,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..models import llama
+from ..models import deepseek_v2, llama
+from ..models.deepseek_v2 import refuse as _refuse
 from ..observability import incident as _incident
 from ..observability import metrics as _obs
 from ..observability import profiler as _profiler
@@ -363,7 +364,7 @@ def _shard_params(params, cfg, mesh):
 
     from ..models.quantize import QuantizedWeight
 
-    specs = llama.partition_specs(cfg)
+    specs = cfg.model.partition_specs(cfg)
 
     def place(p, s):
         if isinstance(p, QuantizedWeight):
@@ -395,6 +396,7 @@ MODEL_PRESETS = {
     "mixtral-8x7b": llama.LlamaConfig.mixtral_8x7b,
     "tiny": llama.LlamaConfig.tiny,
     "tiny-moe": llama.LlamaConfig.tiny_moe,
+    "tiny-deepseek-v2": deepseek_v2.DeepseekV2Config.tiny,
 }
 
 
@@ -407,7 +409,7 @@ class LLMEngine:
 
     def __init__(
         self,
-        cfg: llama.LlamaConfig,
+        cfg,  # a model's configuration object: llama.LlamaConfig, deepseek_v2.DeepseekV2Config
         params=None,
         *,
         model_dir: str | None = None,
@@ -512,6 +514,25 @@ class LLMEngine:
         kv_dtype = resolve_kv_dtype(kv_dtype)
         self.kv_dtype = "int8" if kv_dtype == "int8" else str(kv_dtype)
         self.cfg = cfg
+        # the model seam (docs/mla.md): the configuration object names the
+        # module that holds its programs and declares its cache leaves;
+        # what a model's programs do not implement yet is refused here, by
+        # name, never silently
+        self._model = cfg.model
+        self._counts_routed = bool(getattr(cfg, "counts_routed_pairs", False))
+        for asked, feature in (
+            (self.kv_dtype == "int8", "int8 KV cache"),
+            (speculative is not None, "speculative decoding"),
+            (mesh is not None, "tensor parallelism"),
+            (vision is not None, "vision"),
+            (bool(tiered_prefix), "disaggregated transfer"),
+            (
+                self.paged_impl != "xla" or self.scatter_impl != "xla",
+                "a Pallas paged_impl or scatter_impl",
+            ),
+        ):
+            if asked:
+                _refuse(cfg, feature)
         self.tokenizer = load_tokenizer(model_dir)
         from ..models.quantize import SUPPORTED as _QUANT_MODES
 
@@ -525,7 +546,7 @@ class LLMEngine:
                 # checkpoint loads quantize on the HOST (the bf16 tensors
                 # never reach the device: ~7 GB HBM for a 7B int8 model,
                 # ~3.5 GB int4)
-                params = llama.load_hf_weights(
+                params = self._model.load_hf_weights(
                     model_dir, cfg, quantization=quantization
                 )
             elif quantization is not None:
@@ -537,11 +558,13 @@ class LLMEngine:
                     jax.random.PRNGKey(seed), cfg, bits=bits_of(quantization)
                 )
             else:
-                params = llama.init_params(jax.random.PRNGKey(seed), cfg)
+                params = self._model.init_params(jax.random.PRNGKey(seed), cfg)
         elif quantization is not None:
             from ..models.quantize import bits_of, quantize_llama
 
-            params = quantize_llama(params, bits=bits_of(quantization))
+            params = quantize_llama(
+                params, cfg.quant_targets, bits=bits_of(quantization)
+            )
 
         # tensor parallelism is ONE ENGINE FLAG, not a separate code path
         # (matching vllm_inference.py:180's --tensor-parallel-size): weights
@@ -579,8 +602,7 @@ class LLMEngine:
             n_pages = 1 + max_slots * self.pages_per_slot
         self.cache = PagedKVCache.create(
             n_layers=cfg.n_layers,
-            n_kv_heads=cfg.n_kv_heads,
-            head_dim=cfg.head_dim,
+            leaf_shapes=cfg.cache_leaf_shapes,
             n_pages=n_pages,
             page_size=page_size,
             kv_dtype=kv_dtype,
@@ -594,7 +616,7 @@ class LLMEngine:
         # record it so benches/metrics report the real path instead of the
         # requested one (ADVICE r4)
         self.impl_plan = {
-            **llama.paged_impl_plan(
+            **self._model.paged_impl_plan(
                 cfg, page_size, self.paged_impl, self.scatter_impl,
                 kv_dtype=self.kv_dtype, mesh=mesh,
             ),
@@ -808,6 +830,8 @@ class LLMEngine:
         from .multistep.runtime import resolve_decode_steps
 
         self.decode_steps = resolve_decode_steps(decode_steps)
+        if self.decode_steps > 1:
+            _refuse(cfg, "multistep decode")
         self._multistep_jits: dict[int, object] = {}  # keyed by N
         self._detok = None  # lazy DetokWorker (first routed token)
         # tokens-per-dispatch accounting (harvest-side; feeds the
@@ -1025,25 +1049,33 @@ class LLMEngine:
         (override, override_mask). Returns (tokens [K, B], last [B], caches).
         """
         tok0 = jnp.where(override_mask, override, prev_tokens)
+        # a model that counts its routed pairs hands them back beside the
+        # logits; summed over the block's steps they leave it as two more
+        # rows of the token matrix ([held], [all]: _process_block splits
+        # them off), so the harvest's one read of the tokens brings them
+        counted = {"return_counts": True} if self._counts_routed else {}
 
         def body(carry, k_i):
             tok, pos, kp, vp = carry
-            logits, kp, vp = llama.decode_step(
+            logits, kp, vp, *counts = self._model.decode_step(
                 params, tok, pos, kp, vp, page_tables, active, self.cfg,
                 impl=self.paged_impl, scatter_impl=self.scatter_impl,
-                mesh=self.mesh,
+                mesh=self.mesh, **counted,
             )
             nxt = sample(
                 logits, k_i, temps, top_ps, top_ks, seeds=seeds, step_ids=pos
             )
             nxt = jnp.where(active, nxt, tok)  # dead slots hold steady
-            return (nxt, pos + 1, kp, vp), nxt
+            return (nxt, pos + 1, kp, vp), (nxt, *counts)
 
-        (last, _, k_pages, v_pages), toks = jax.lax.scan(
+        (last, _, k_pages, v_pages), (toks, *counts) = jax.lax.scan(
             body,
             (tok0, positions, k_pages, v_pages),
             jax.random.split(key, self.decode_block),
         )
+        for c in counts:
+            rows = jnp.broadcast_to(c.sum(axis=0)[:, None], (2, toks.shape[1]))
+            toks = jnp.concatenate([toks, rows.astype(toks.dtype)], axis=0)
         return toks, last, k_pages, v_pages
 
     def _count_decode_kv(self, positions, active, steps: int) -> None:
@@ -1114,7 +1146,7 @@ class LLMEngine:
         self, params, k_pages, v_pages, tokens, page_tables, seq_lens, key,
         temps, top_ps, top_ks, seeds,
     ):
-        logits, k_pages, v_pages = llama.prefill(
+        logits, k_pages, v_pages = self._model.prefill(
             params, tokens, k_pages, v_pages, page_tables, seq_lens, self.cfg,
             attn_impl=self._attn_impl, mesh=self.mesh,
         )
@@ -1138,7 +1170,8 @@ class LLMEngine:
             attn_impl, mesh = self._attn_impl, self.mesh
 
             def prefill_chunk(params, toks, k_pages, v_pages, tables, lens, *, cfg):
-                return llama.prefill_chunk(
+                # cfg is the target's or the draft's: each names its module
+                return cfg.model.prefill_chunk(
                     params, toks, k_pages, v_pages, tables, lens, cfg=cfg,
                     q_offset=offset, attn_impl=attn_impl, mesh=mesh,
                 )
@@ -1161,7 +1194,7 @@ class LLMEngine:
         from ..models import vlm
 
         embeds = vlm.encode_image(vparams, images, self.vision_cfg)
-        logits, k_pages, v_pages = llama.prefill(
+        logits, k_pages, v_pages = self._model.prefill(
             params, tokens, k_pages, v_pages, page_tables, seq_lens, self.cfg,
             attn_impl=self._attn_impl, input_embeds=embeds, mesh=self.mesh,
         )
@@ -1675,6 +1708,7 @@ class LLMEngine:
         racing that donation would pass deleted arrays. Prefill-role
         replicas never ``start()`` their engine; concurrent server threads
         serialize on an internal lock."""
+        _refuse(self.cfg, "disaggregated transfer")
         if req.image is not None:
             raise ValueError(
                 "multimodal requests do not take the disagg prefill path "
@@ -1769,6 +1803,7 @@ class LLMEngine:
         """Pull the prefilled pages of a :meth:`prefill_sync` result off the
         device as a wire-ready :class:`~.disagg.transport.PageBlock` (page
         data + every other cache leaf, block hashes, sampler meta)."""
+        _refuse(self.cfg, "disaggregated transfer")
         from .disagg.transport import chain_hashes, extract_pages
 
         claim = state["claim"]
@@ -1806,6 +1841,7 @@ class LLMEngine:
         first sample. ``entry`` is the migration's admission reservation,
         taken by the coordinator BEFORE any byte moved so decode-side KV
         headroom was guaranteed while the transfer was in flight."""
+        _refuse(self.cfg, "disaggregated transfer")
         if block.kv_dtype != self.cache.kv_dtype:
             raise ValueError(
                 f"migrated block is {block.kv_dtype}, this cache is "
@@ -1856,6 +1892,7 @@ class LLMEngine:
         ``generated`` degrades to a plain resubmission. The same ``req``
         object (same id, same out_queue, same trace id) rides through, so
         a blocked ``stream()`` consumer continues without reconnecting."""
+        _refuse(self.cfg, "disaggregated transfer")
         if req.image is not None:
             raise ValueError(
                 "multimodal requests do not take the failover resume path"
@@ -1916,6 +1953,7 @@ class LLMEngine:
 
         Raises when the scheduler loop is stopped or unresponsive — the
         caller falls back to the reactive (checkpoint-only) resume."""
+        _refuse(self.cfg, "disaggregated transfer")
         return self._run_on_scheduler(
             lambda: self._migrate_out_on_sched(req), timeout
         )
@@ -2804,6 +2842,8 @@ class LLMEngine:
             computed=C,
             needed=max(0, offset + len(chunk) - max(offset, cached)),
         )
+        if offset:
+            _obs.record_prefill_prefix_positions(offset)
         fn = self._chunk_jit(offset)
         logits, self.cache.k_pages, self.cache.v_pages = self._profiled(
             "prefill_chunk", f"off{offset}", fn
@@ -3614,6 +3654,12 @@ class LLMEngine:
         toks, valid, snapshot, spec_meta, seq = self._inflight.popleft()
         t0 = self._harvest_begin()
         toks_np = np.asarray(toks)  # [K, B] — the ONE blocking read per block
+        if self._counts_routed and valid is None and spec_meta is None:
+            # the classic block of a model that counts its routed pairs
+            # carries them as its last two rows: the same read brought them
+            held, pairs = int(toks_np[-2, 0]), int(toks_np[-1, 0])
+            toks_np = toks_np[:-2]
+            _obs.record_routed_pairs(held=held, elsewhere=pairs - held)
         # the macro-step harvest plane (docs/multistep.md): the validity
         # mask rides the SAME round trip as the tokens — per-slot accept
         # stops at the first invalid row (the lane died at its stop token

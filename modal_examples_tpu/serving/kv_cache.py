@@ -101,7 +101,9 @@ class PageAllocator:
 class PagedKVCache:
     # plain [L, P, page_size, Hkv, hd] arrays, or QuantizedKV (int8 data +
     # [L, P, page_size, Hkv] f32 scales) — two device leaves each way, so
-    # the whole cache is a 2- (bf16) or 4-leaf (int8) pytree
+    # the whole cache is a 2- (bf16) or 4-leaf (int8) pytree. The two need
+    # not be alike: a model declares each leaf's per-token shape
+    # (``create(leaf_shapes=)``), the page axis stays at 1 in both
     k_pages: object
     v_pages: object
     page_size: int
@@ -112,10 +114,15 @@ class PagedKVCache:
         cls,
         *,
         n_layers: int,
-        n_kv_heads: int,
-        head_dim: int,
+        n_kv_heads: int | None = None,
+        head_dim: int | None = None,
         n_pages: int,
         page_size: int = 16,
+        # per-token shape of the two leaves, for a model whose cache is not
+        # a symmetric pair of per-head K and V (a configuration's
+        # ``cache_leaf_shapes``: DeepSeek-V2 keeps ``(1, 512)`` latents and
+        # ``(1, 64)`` rotated keys). Default: ``(n_kv_heads, head_dim)`` twice
+        leaf_shapes: tuple | None = None,
         kv_dtype=None,  # "int8" | jnp dtype; the canonical spelling
         dtype=None,  # legacy alias for kv_dtype (kept for callers)
         prefer_native: bool = True,
@@ -125,7 +132,13 @@ class PagedKVCache:
         kv_dtype = kv_dtype if kv_dtype is not None else dtype
         if kv_dtype is None:
             kv_dtype = jnp.bfloat16
-        shape = (n_layers, n_pages, page_size, n_kv_heads, head_dim)
+        if leaf_shapes is None:
+            if n_kv_heads is None or head_dim is None:
+                raise ValueError("pass n_kv_heads= and head_dim=, or leaf_shapes=")
+            leaf_shapes = ((n_kv_heads, head_dim),) * 2
+        k_shape, v_shape = (
+            (n_layers, n_pages, page_size, *leaf) for leaf in leaf_shapes
+        )
         allocator = None
         if prefer_native:
             try:  # C++ free list (native/mtpu_host.cpp); same semantics
@@ -135,8 +148,8 @@ class PagedKVCache:
             except Exception as e:
                 _log.warning("page allocator: python fallback (%s)", e)
         return cls(
-            k_pages=kv_empty(shape, kv_dtype),
-            v_pages=kv_empty(shape, kv_dtype),
+            k_pages=kv_empty(k_shape, kv_dtype),
+            v_pages=kv_empty(v_shape, kv_dtype),
             page_size=page_size,
             allocator=allocator or PageAllocator(n_pages),
         )

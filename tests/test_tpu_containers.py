@@ -113,6 +113,33 @@ class TestServeSurfacesBootFailures:
         assert "serving:" not in proc.stdout
         assert elapsed < 60  # the startup_timeout is 600
 
+    def test_failed_boot_is_reported_once_its_process_is_gone(self, tmp_path):
+        """A container that fails at boot may take seconds to leave (one
+        that opened the chips hands them back at exit). ``serve()`` raises
+        its error only then: a caller that exits on the error leaves no
+        process behind, still holding what the next run needs."""
+        import os
+
+        pid_file = tmp_path / "pid"
+        slow = mtpu.App("slow-to-leave")
+
+        @slow.server(port=18973, startup_timeout=600)
+        class SlowToLeave:
+            @mtpu.enter()
+            def start(self):
+                import atexit
+
+                pid_file.write_text(str(os.getpid()))
+                atexit.register(time.sleep, 1.5)
+                raise RuntimeError("boot failed on purpose")
+
+        with slow.run():
+            with pytest.raises(RuntimeError, match="boot failed on purpose"):
+                SlowToLeave.serve()
+            with pytest.raises(ProcessLookupError):  # gone, and reaped
+                os.kill(int(pid_file.read_text()), 0)
+            SlowToLeave.stop()
+
     def test_web_server_that_never_opens_its_port_exits_nonzero(self, tmp_path):
         proc, _ = self._serve(tmp_path, """
             import modal_examples_tpu as mtpu
