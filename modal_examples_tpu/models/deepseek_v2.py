@@ -57,10 +57,6 @@ from . import deepseek_v2_reference as _ref
 from . import layers
 from . import moe as _moe
 
-#: the routed experts' stacks stay out of the layer scans' sliced inputs: a
-#: tile of the dispatch indexes [layer, expert] out of the whole stack
-_EXPERTS = ("moe_gate", "moe_up", "moe_down")
-
 
 @dataclasses.dataclass(frozen=True)
 class DeepseekV2Config:
@@ -443,19 +439,10 @@ def _mlp(layer, h, cfg, dense: bool, token_mask):
         out = layers.swiglu_mlp({k: layer[k] for k in ("gate", "up", "down")}, h)
         return out, jnp.zeros((2,), jnp.int32)
     flat = h.reshape(-1, cfg.dim)
-    with jax.named_scope(_scopes.ROUTER):
-        scores = jax.nn.softmax(
-            jnp.einsum(
-                "td,de->te", flat.astype(jnp.float32), layer["router"].astype(jnp.float32)
-            ),
-            axis=-1,
-        )
-        weights, ids = _moe.route_group_limited(
-            scores, cfg.top_k_experts, n_group=cfg.n_group, topk_group=cfg.topk_group,
-            scale=cfg.routed_scaling_factor, renormalize=cfg.norm_topk_prob,
-        )
-    out, counts = _moe.moe_swiglu_sparse(
-        *(layer[n] for n in _EXPERTS), flat, ids, weights,
+    out, counts = _moe.moe_swiglu_routed(
+        layer["router"], *(layer[n] for n in _moe.EXPERT_LEAVES), flat, cfg.top_k_experts,
+        n_group=cfg.n_group, topk_group=cfg.topk_group,
+        scale=cfg.routed_scaling_factor, renormalize=cfg.norm_topk_prob,
         expert_offset=cfg.expert_offset,
         token_mask=None if token_mask is None else token_mask.reshape(-1),
         layer=layer.get("expert_layer"),
@@ -469,9 +456,9 @@ def _mlp(layer, h, cfg, dense: bool, token_mask):
 
 def _scan_layers(params, cfg, layer_fn, x, per_layer=None):
     """Run ``layer_fn(x, layer, cache layer index, dense) -> (x, ys)`` over
-    the dense layers, then the routed ones; ys concatenated on axis 0. A
-    routed layer's dict holds the experts' whole stacks and its own index
-    into them (``expert_layer``), not its slice of them. ``per_layer``
+    the dense layers, then the routed ones (``moe.scan_layers``: a routed
+    layer's dict holds the experts' whole stacks and its own index into
+    them, not its slice of them); ys concatenated on axis 0. ``per_layer``
     [L, ...], if given, reaches the layer sliced, as ``layer["per_layer"]``."""
     ys = []
     first = 0
@@ -479,20 +466,14 @@ def _scan_layers(params, cfg, layer_fn, x, per_layer=None):
         ("dense_layers", True, cfg.n_dense_layers), ("moe_layers", False, cfg.n_moe_layers),
     ):
         if n:
-            stack = params[name]
-            whole = {k: stack[k] for k in _EXPERTS if k in stack}
-            sliced = {k: v for k, v in stack.items() if k not in whole}
-
+            stack = dict(params[name])
             if per_layer is not None:
-                sliced["per_layer"] = per_layer[first:first + n]
+                stack["per_layer"] = per_layer[first:first + n]
 
-            def body(x, s, dense=dense, whole=whole):
-                layer = dict(s[0], **whole, expert_layer=s[2]) if whole else s[0]
-                return layer_fn(x, layer, s[1], dense)
+            def body(x, layer, i, dense=dense, first=first):
+                return layer_fn(x, layer, first + i, dense)
 
-            x, y = jax.lax.scan(
-                body, x, (sliced, first + jnp.arange(n), jnp.arange(n))
-            )
+            x, y = _moe.scan_layers(stack, body, x)
             ys.append(y)
             first += n
     return x, jax.tree.map(lambda *a: jnp.concatenate(a, axis=0), *ys)

@@ -46,6 +46,7 @@ from ..ops import (
 )
 from ..ops import scopes as _scopes
 from . import layers
+from . import moe as _moe
 
 
 @dataclasses.dataclass(frozen=True)
@@ -62,8 +63,8 @@ class LlamaConfig:
     dtype: str = "bfloat16"
     tie_embeddings: bool = False
     # MoE (Mixtral-style): n_experts > 0 replaces the dense SwiGLU MLP with
-    # a routed expert MLP (models.moe); serving decode for MoE is a
-    # round-2 item — training/forward support here.
+    # a routed expert MLP (models.moe): every expert on every token in
+    # ``forward``, only the pairs the router chose in the serving programs
     n_experts: int = 0
     top_k_experts: int = 2
     expert_capacity_factor: float = 1.5
@@ -100,6 +101,11 @@ class LlamaConfig:
         from .quantize import LLAMA_TARGETS
 
         return LLAMA_TARGETS
+
+    @property
+    def counts_routed_pairs(self) -> bool:
+        """A routed model's decode block hands back the pairs it counted."""
+        return self.n_experts > 0
 
     @property
     def param_count(self) -> int:
@@ -310,13 +316,13 @@ def _mlp_block(
     layer: dict, h: jax.Array, cfg: LlamaConfig, *, lora=None, lora_scale=1.0,
     moe_impl: str = "nodrop",
 ) -> tuple[jax.Array, jax.Array]:
-    """Post-norm MLP for one layer: dense SwiGLU, or — when cfg.n_experts > 0
-    — top-k routed SwiGLU experts (the reference's served MoE lives inside
-    vLLM/SGLang: vllm_inference.py:54-58). ``moe_impl="nodrop"`` (serving
-    default) runs every expert so incremental decode reproduces the dense
-    forward token-for-token; ``"capacity"`` is the GShard-dispatched
+    """Post-norm MLP for one layer of ``forward`` (training, and the tests'
+    ground truth): dense SwiGLU, or — when cfg.n_experts > 0 — top-k routed
+    SwiGLU experts. ``moe_impl="nodrop"`` runs every expert on every token
+    in float32, exact per token; ``"capacity"`` is the GShard-dispatched
     formulation at ~top_k/E the FLOPs for compute-bound training forward.
-    Returns (out, aux_load_balance_loss)."""
+    The serving programs run ``_serving_mlp``. Returns (out,
+    aux_load_balance_loss)."""
     if cfg.n_experts > 0:
         if lora is not None and any(
             f"{n}_a" in lora for n in ("gate", "up", "down")
@@ -329,8 +335,6 @@ def _mlp_block(
                 "expert MLPs; restrict LoRAConfig.targets to attention "
                 "projections (wq/wk/wv/wo) for n_experts > 0"
             )
-        from . import moe as _moe
-
         shape = h.shape
         if moe_impl == "capacity":
             flat, aux = _moe.moe_swiglu_capacity(
@@ -349,6 +353,28 @@ def _mlp_block(
         lora=lora, lora_scale=lora_scale,
     )
     return out, jnp.zeros((), jnp.float32)
+
+
+def _serving_mlp(
+    layer: dict, h: jax.Array, cfg: LlamaConfig, token_mask=None
+) -> tuple[jax.Array, jax.Array]:
+    """Post-norm MLP for one layer of a serving program (``layer`` as
+    ``moe.scan_layers`` hands it over): dense SwiGLU, or the routed layer as
+    Mixtral defines it: softmax over all experts, the top k renormalised,
+    and only those (token, expert) pairs multiplied, out of the experts'
+    whole stacks at ``layer["expert_layer"]``. Returns (out, [2] int32: the
+    routed pairs of the tokens ``token_mask`` counts, held here and all;
+    zeros for a dense layer)."""
+    if cfg.n_experts == 0:
+        out = layers.swiglu_mlp({k: layer[k] for k in ("gate", "up", "down")}, h)
+        return out, jnp.zeros((2,), jnp.int32)
+    flat, counts = _moe.moe_swiglu_routed(
+        layer["router"], *(layer[n] for n in _moe.EXPERT_LEAVES),
+        h.reshape(-1, cfg.dim), cfg.top_k_experts, renormalize=True,
+        layer=layer["expert_layer"],
+        token_mask=None if token_mask is None else token_mask.reshape(-1),
+    )
+    return flat.astype(h.dtype).reshape(h.shape), counts
 
 
 # -- forward (training / prefill) ------------------------------------------
@@ -464,8 +490,7 @@ def prefill(
     page_idx = jnp.where(valid, page_idx, 0)
     slot = jnp.where(valid, positions % page_size, 0)
 
-    def layer_fn(carry, layer):
-        x = carry
+    def layer_fn(x, layer, _li):
         h = layers.rms_norm(x, layer["attn_norm"], cfg.norm_eps)
         D = cfg.head_dim
         q = layers.mm(h, layer["wq"]).astype(x.dtype)
@@ -486,12 +511,12 @@ def prefill(
         o = o.transpose(0, 2, 1, 3).reshape(B, S, cfg.n_heads * D)
         x = x + layers.mm(o, layer["wo"]).astype(x.dtype)
         h = layers.rms_norm(x, layer["mlp_norm"], cfg.norm_eps)
-        h, _ = _mlp_block(layer, h, cfg)
+        h, _ = _serving_mlp(layer, h, cfg)
         x = x + h
         # stack KV for a single scatter outside the scan: [Hkv, B, S, D]
         return x, (k.transpose(1, 0, 2, 3), v.transpose(1, 0, 2, 3))
 
-    x, (k_all, v_all) = jax.lax.scan(layer_fn, x, _layer_stack(params))
+    x, (k_all, v_all) = _moe.scan_layers(_layer_stack(params), layer_fn, x)
     # k_all: [L, Hkv, B, S, D] -> pages at (page_idx[b,s], slot[b,s])
     k_pages, v_pages = _scatter_pages(k_pages, v_pages, k_all, v_all, page_idx, slot)
 
@@ -558,9 +583,7 @@ def prefill_chunk(
     n_prefix_pages = q_offset // page_size
     prefix_tables = page_tables[:, :n_prefix_pages] if n_prefix_pages else None
 
-    def layer_fn(carry, layer_with_pages):
-        x = carry
-        layer, k_pg, v_pg = layer_with_pages  # [P, ps, Hkv, D]
+    def layer_fn(x, layer, _li, k_pg, v_pg):  # pages: [P, ps, Hkv, D]
         D = cfg.head_dim
         h = layers.rms_norm(x, layer["attn_norm"], cfg.norm_eps)
         q = layers.mm(h, layer["wq"]).astype(x.dtype)
@@ -603,12 +626,12 @@ def prefill_chunk(
         o = o.transpose(0, 2, 1, 3).reshape(B, C, cfg.n_heads * D)
         x = x + layers.mm(o, layer["wo"]).astype(x.dtype)
         h = layers.rms_norm(x, layer["mlp_norm"], cfg.norm_eps)
-        h, _ = _mlp_block(layer, h, cfg)
+        h, _ = _serving_mlp(layer, h, cfg)
         x = x + h
         return x, (k.transpose(1, 0, 2, 3), v.transpose(1, 0, 2, 3))
 
-    x, (k_all, v_all) = jax.lax.scan(
-        layer_fn, x, (_layer_stack(params), k_pages, v_pages)
+    x, (k_all, v_all) = _moe.scan_layers(
+        _layer_stack(params), layer_fn, x, k_pages, v_pages
     )
     k_pages, v_pages = _scatter_pages(k_pages, v_pages, k_all, v_all, page_idx, slot)
 
@@ -755,11 +778,15 @@ def decode_step(
     scatter_impl: str = "xla",
     ragged_variant: str | None = None,  # None: auto (flat | grouped by Hkv)
     mesh=None,  # jax Mesh with a "tensor" axis: kernels run per head shard
+    return_counts: bool = False,
 ):
     """One token of batched decode against the paged cache.
 
-    Returns (logits [B, vocab], k_pages, v_pages). Pass donated pages for
-    in-place updates under jit.
+    Returns (logits [B, vocab], k_pages, v_pages) and, with
+    ``return_counts``, [2] int32: the live slots' routed pairs over the
+    layers, those on experts held here and all of them (the same: a llama
+    model holds every expert its router names; zeros for a dense model).
+    Pass donated pages for in-place updates under jit.
 
     ``impl`` selects the decode structure ("xla" default, "pallas",
     "xla-writeback"). There is deliberately NO env-var fallback here: this
@@ -791,7 +818,7 @@ def decode_step(
     if impl in ("xla-writeback", "pallas-writeback"):
         return _decode_step_writeback(
             params, tokens, positions, k_pages, v_pages, page_tables, active,
-            cfg, impl=impl, mesh=mesh,
+            cfg, impl=impl, mesh=mesh, return_counts=return_counts,
         )
     B = tokens.shape[0]
     page_size = k_pages.shape[2]
@@ -818,11 +845,8 @@ def decode_step(
     page_idx = jnp.where(active, page_idx, 0)
     slot = jnp.where(active, positions % page_size, 0)
     prefix_lens = jnp.where(active, positions, 0).astype(jnp.int32)
-    L = cfg.n_layers
 
-    def layer_fn(carry, scanned):
-        x = carry
-        layer, li = scanned
+    def layer_fn(x, layer, li):
         D = cfg.head_dim
         h = layers.rms_norm(x, layer["attn_norm"], cfg.norm_eps)
         q = layers.mm(h, layer["wq"]).astype(x.dtype)
@@ -859,11 +883,11 @@ def decode_step(
         o = o.reshape(B, cfg.n_heads * D)
         x = x + layers.mm(o, layer["wo"]).astype(x.dtype)
         h = layers.rms_norm(x, layer["mlp_norm"], cfg.norm_eps)
-        h, _ = _mlp_block(layer, h, cfg)
-        return x + h, (k_tok, v_tok)
+        h, counts = _serving_mlp(layer, h, cfg, active)
+        return x + h, (k_tok, v_tok, counts)
 
-    x, (k_all, v_all) = jax.lax.scan(
-        layer_fn, x, (_layer_stack(params), jnp.arange(L))
+    x, (k_all, v_all, counts) = _moe.scan_layers(
+        _layer_stack(params), layer_fn, x
     )
     # k_all: [L, B, Hkv, D] -> one scatter for every layer's token.
     # The pallas scatter (in-place strided DMAs) is opt-in
@@ -887,12 +911,14 @@ def decode_step(
     x = layers.rms_norm(x, params["final_norm"], cfg.norm_eps)
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
     logits = layers.mm(x, head)
+    if return_counts:
+        return logits, k_pages, v_pages, counts.sum(axis=0)
     return logits, k_pages, v_pages
 
 
 def _decode_step_writeback(
     params, tokens, positions, k_pages, v_pages, page_tables, active, cfg,
-    impl: str = "xla-writeback", mesh=None,
+    impl: str = "xla-writeback", mesh=None, return_counts: bool = False,
 ):
     """Write-then-attend decode (Pallas paged kernel path): each layer lands
     its KV in the pages before calling the kernel, which reads the current
@@ -919,9 +945,7 @@ def _decode_step_writeback(
     slot = jnp.where(active, positions % page_size, 0)
     ctx_lens = jnp.where(active, positions + 1, 1).astype(jnp.int32)
 
-    def layer_fn(carry, layer_with_pages):
-        x = carry
-        layer, k_pg, v_pg = layer_with_pages
+    def layer_fn(x, layer, _li, k_pg, v_pg):
         D = cfg.head_dim
         h = layers.rms_norm(x, layer["attn_norm"], cfg.norm_eps)
         q = layers.mm(h, layer["wq"]).astype(x.dtype)
@@ -949,15 +973,17 @@ def _decode_step_writeback(
         o = o.reshape(B, cfg.n_heads * D)
         x = x + layers.mm(o, layer["wo"]).astype(x.dtype)
         h = layers.rms_norm(x, layer["mlp_norm"], cfg.norm_eps)
-        h, _ = _mlp_block(layer, h, cfg)
-        return x + h, (k_pg, v_pg)
+        h, counts = _serving_mlp(layer, h, cfg, active)
+        return x + h, (k_pg, v_pg, counts)
 
-    x, (k_pages, v_pages) = jax.lax.scan(
-        layer_fn, x, (_layer_stack(params), k_pages, v_pages)
+    x, (k_pages, v_pages, counts) = _moe.scan_layers(
+        _layer_stack(params), layer_fn, x, k_pages, v_pages
     )
     x = layers.rms_norm(x, params["final_norm"], cfg.norm_eps)
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
     logits = layers.mm(x, head)
+    if return_counts:
+        return logits, k_pages, v_pages, counts.sum(axis=0)
     return logits, k_pages, v_pages
 
 
@@ -1001,9 +1027,7 @@ def verify_step(
     page_idx = jnp.where(valid, page_idx, 0)
     slot = jnp.where(valid, pos_c % page_size, 0)
 
-    def layer_fn(carry, layer_with_pages):
-        x = carry
-        layer, k_pg, v_pg = layer_with_pages  # [P, ps, Hkv, D]
+    def layer_fn(x, layer, _li, k_pg, v_pg):  # pages: [P, ps, Hkv, D]
         D = cfg.head_dim
         h = layers.rms_norm(x, layer["attn_norm"], cfg.norm_eps)
         q = layers.mm(h, layer["wq"]).astype(x.dtype)
@@ -1029,11 +1053,11 @@ def verify_step(
         o = o.reshape(B, T, cfg.n_heads * D)
         x = x + layers.mm(o, layer["wo"]).astype(x.dtype)
         h = layers.rms_norm(x, layer["mlp_norm"], cfg.norm_eps)
-        h, _ = _mlp_block(layer, h, cfg)
+        h, _ = _serving_mlp(layer, h, cfg)
         return x + h, (k_pg, v_pg)
 
-    x, (k_pages, v_pages) = jax.lax.scan(
-        layer_fn, x, (_layer_stack(params), k_pages, v_pages)
+    x, (k_pages, v_pages) = _moe.scan_layers(
+        _layer_stack(params), layer_fn, x, k_pages, v_pages
     )
     x = layers.rms_norm(x, params["final_norm"], cfg.norm_eps)
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]

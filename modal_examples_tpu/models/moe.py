@@ -13,7 +13,15 @@ dispatch TPU-natively:
 - ``moe_mlp_ep``: the same math under shard_map with experts sharded over an
   ``expert`` mesh axis — dispatch/return ride two ``all_to_all``s (ICI on a
   real slice);
-- the standard load-balancing auxiliary loss.
+- the standard load-balancing auxiliary loss;
+- the serving form of a routed SwiGLU layer (``moe_swiglu_routed`` ->
+  ``moe_swiglu_sparse``, shared by ``models/llama.py`` and
+  ``models/deepseek_v2.py``): only the (token, expert) pairs the router
+  chose, sorted into tiles of one expert each, the expert's weights indexed
+  ``[layer, expert]`` out of the whole stack (``scan_layers`` keeps them
+  out of the layer scan's sliced inputs). ``moe_swiglu_nodrop``, every
+  expert on every token in float32, is the ground truth of the tests and
+  the training forward; no serving program calls it.
 """
 
 from __future__ import annotations
@@ -128,11 +136,10 @@ def moe_mlp(
 
 
 def _mm(h, w):
-    """h @ w where w may be an int8 QuantizedWeight (serving decode streams
-    every expert's weights; int8 halves that HBM traffic exactly like the
-    dense matmuls — models.quantize.LLAMA_TARGETS includes moe_gate/up/down).
-    Delegates to layers.mm (the one quantized-matmul dispatch) and rounds
-    back to h's dtype."""
+    """h @ w where w may be an int8 QuantizedWeight (a quantized tree goes
+    through ``forward`` too: models.quantize.LLAMA_TARGETS includes
+    moe_gate/up/down). Delegates to layers.mm (the one quantized-matmul
+    dispatch) and rounds back to h's dtype."""
     from .layers import mm
 
     return mm(h, w).astype(h.dtype)
@@ -153,19 +160,18 @@ def moe_swiglu_nodrop(
     x: jax.Array,  # [T, D]
     top_k: int,
 ) -> tuple[jax.Array, jax.Array]:
-    """Top-k routed SwiGLU experts with NO capacity drops — the serving
-    formulation (and the per-token ground truth the capacity-routed training
-    path approximates).
+    """Top-k routed SwiGLU experts with NO capacity drops, every expert run
+    on every token in float32: the per-token ground truth (what the
+    capacity-routed training path approximates and ``moe_swiglu_routed``,
+    the serving form, is tested against) and ``llama.forward``'s default.
 
-    Routing is per-token, so incremental decode reproduces full-sequence
-    results token-for-token — the property the engine's exact-vs-dense MoE
-    test relies on. Every expert runs on every token (a grouped-matmul over
-    the full expert set); at decode batch sizes all experts' weights are the
-    HBM-bandwidth floor anyway, and the [T, F] intermediate stays bounded by
-    scanning over experts rather than materializing [T, E, F].
+    Routing is per-token, so a full-sequence pass through it and the serving
+    programs' incremental passes agree position by position up to the
+    order of float summation. The [T, F] intermediate stays bounded by
+    scanning over experts rather than materializing [T, E, F]; the work is
+    E / top_k times what the chosen pairs need, which is why nothing that
+    serves runs it.
 
-    Replaces the engine-internal MoE the reference serves via vLLM/SGLang
-    (vllm_inference.py:54-58 Gemma MoE, sglang_low_latency.py:67 Qwen MoE).
     Returns (out [T, D] float32, aux load-balance loss).
     """
     E = w_gate.shape[0]
@@ -232,6 +238,79 @@ def _round_up(n: int, to: int) -> int:
     return -(-n // to) * to
 
 
+#: the expert leaves of a layer stack, [L, E, ...] each
+EXPERT_LEAVES = ("moe_gate", "moe_up", "moe_down")
+
+
+def scan_layers(stack: dict, layer_fn, x, *per_layer):
+    """``lax.scan`` of ``layer_fn(x, layer, i, *per_layer[i]) -> (x, ys)``
+    over a stack of layers (leaves [n, ...]) for the serving programs. The
+    expert leaves stay out of the scanned inputs: a routed layer's dict holds
+    the experts' whole stacks and ``expert_layer``, its own index into them,
+    and ``moe_swiglu_sparse`` picks ``[layer, expert]`` where it multiplies.
+    Scanned like the other leaves, a layer's slice of them is a copy of
+    every expert of the layer (1.4 GB of a Mixtral layer in int8, 64% of its
+    decode step) before anything reads it."""
+    whole = {k: stack[k] for k in EXPERT_LEAVES if k in stack}
+    sliced = {k: v for k, v in stack.items() if k not in whole}
+    n = jax.tree.leaves(sliced)[0].shape[0]
+
+    def body(x, scanned):
+        layer, i, *rest = scanned
+        if whole:
+            layer = dict(layer, **whole, expert_layer=i)
+        return layer_fn(x, layer, i, *rest)
+
+    return jax.lax.scan(body, x, (sliced, jnp.arange(n), *per_layer))
+
+
+def expert_tile(n_tokens: int) -> int:
+    """Rows of one tile of ``moe_swiglu_sparse``, from the shape of the call:
+    128, or every token (rounded up to 16 rows) where that is less, so that
+    a decode step has one tile an expert. 128 rows are where an int8 expert
+    matrix's read and a tile's matmul take a v5e about as long, so the
+    weights an expert's next tile reads again stream under its matmuls, and
+    what a larger tile adds is padding, half a tile an expert. Measured on
+    the chip, one Mixtral layer (8 experts of 4096 x 14336 int8, 2 a token,
+    PR 28): 2048 tokens 12.5 / 13.4 / 15.1 / 18.1 ms at 128 / 256 / 512 /
+    1024 rows, 8192 tokens 39.6 / 40.2 / 43.7 / 53.7 ms at 256 / 512 / 1024 /
+    2048, 128 also ahead at 512 and 1024 tokens; DeepSeek-V2's 77 pairs an
+    expert a chunk sit in one such tile."""
+    return min(128, _round_up(n_tokens, 16))
+
+
+def moe_swiglu_routed(
+    router: jax.Array,  # [D, E_router]
+    w_gate,  # [E_held, D, F] or, with ``layer``, [L, E_held, D, F]
+    w_up,
+    w_down,
+    x: jax.Array,  # [T, D]
+    top_k: int,
+    *,
+    layer: jax.Array | None = None,
+    expert_offset: int = 0,
+    token_mask: jax.Array | None = None,
+    **routing,  # route_group_limited's: n_group, topk_group, scale, renormalize
+) -> tuple[jax.Array, jax.Array]:
+    """A routed SwiGLU layer as the serving programs run it: the router's
+    softmax over its whole width in f32, ``route_group_limited`` (plain
+    top-k renormalised is Mixtral's, group-limited and scaled DeepSeek-V2's),
+    then only the chosen (token, expert) pairs through
+    ``moe_swiglu_sparse``: activations in ``x``'s dtype into the tile
+    matmuls, f32 accumulation, the combine in f32. Nothing is dropped.
+    Returns (out [T, D] f32, counts [2] int32) as ``moe_swiglu_sparse``."""
+    with jax.named_scope(ROUTER):
+        scores = jax.nn.softmax(
+            jnp.einsum("td,de->te", x.astype(jnp.float32), router.astype(jnp.float32)),
+            axis=-1,
+        )
+        weights, ids = route_group_limited(scores, top_k, **routing)
+    return moe_swiglu_sparse(
+        w_gate, w_up, w_down, x, ids, weights,
+        expert_offset=expert_offset, token_mask=token_mask, layer=layer,
+    )
+
+
 def moe_swiglu_sparse(
     w_gate,  # [E_held, D, F] — plain or QuantizedWeight
     w_up,
@@ -265,11 +344,9 @@ def moe_swiglu_sparse(
     With ``layer`` (a traced scalar) the weights keep a leading layer axis
     and a tile indexes ``[layer, expert]`` out of the whole stack: a layer
     scan that sliced the stack per layer instead would copy every expert of
-    the layer, reached or not, before the loop ran.
+    the layer, reached or not, before the loop ran (``scan_layers``).
 
-    ``tile`` defaults to 128 rows (where an int8 expert matrix's read and a
-    tile's matmul take a v5e about as long), or all ``T`` tokens rounded up
-    to 16 when that is less: then no expert has more than one tile.
+    ``tile`` defaults to ``expert_tile(T)``.
 
     Returns (out [T, D] f32, counts [2] int32: the pairs of counted tokens
     that landed on held experts, and all their pairs).
@@ -279,7 +356,7 @@ def moe_swiglu_sparse(
     T, D = x.shape
     k = ids.shape[1]
     E = w_gate.shape[0 if layer is None else 1]
-    TM = tile or min(128, _round_up(T, 16))
+    TM = tile or expert_tile(T)
     M = T * k
     M_pad = _round_up(M + E * (TM - 1), TM)  # rows; row M_pad stays zero
     with jax.named_scope(EXPERT_DISPATCH):

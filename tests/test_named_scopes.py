@@ -46,7 +46,9 @@ def _block_args(eng):
 
 def _mlp_scopes(eng):
     if eng.cfg.n_experts > 0:
-        return {scopes.ROUTER, scopes.EXPERT_SCAN}
+        # the routed layer as the serving programs run it (moe_swiglu_routed):
+        # the router, the sort into tiles and the combine, the tiles' matmuls
+        return {scopes.ROUTER, scopes.EXPERT_DISPATCH, scopes.EXPERT_SCAN}
     return {scopes.DENSE_MLP}
 
 
