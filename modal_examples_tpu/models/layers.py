@@ -5,7 +5,7 @@ vLLM / unsloth internals) with TPU-first primitives: parameters are plain
 pytrees (nested dicts of jax arrays) so sharding is a PartitionSpec tree and
 checkpointing is orbax-native; compute is bf16 on the MXU with f32 for norms
 and softmax; attention goes through ops.flash_attention (training/prefill)
-or ops.paged_decode_attention (serving decode).
+or ops.paged_decode_attention_chunked / _ragged (serving decode).
 """
 
 from __future__ import annotations

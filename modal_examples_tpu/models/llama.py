@@ -40,7 +40,6 @@ from ..ops import (
     paged_decode_attention_chunked,
     sharded_flash_attention,
     sharded_flash_attention_chunked,
-    sharded_paged_decode_attention,
     sharded_ragged_decode,
     sharded_scatter_kv_pages,
 )
@@ -650,9 +649,8 @@ _impl_downgrades_warned: set = set()
 
 def tp_shard_ok(cfg: LlamaConfig, tp: int) -> bool:
     """Whether this model's heads divide the tensor-parallel degree — the
-    ONE predicate behind every head-sharding legality decision
-    (``paged_impl_plan`` and the writeback dispatch share it, so the plan
-    and the runtime path cannot drift)."""
+    ONE predicate behind every head-sharding legality decision of
+    ``paged_impl_plan``."""
     return cfg.n_kv_heads % tp == 0 and cfg.n_heads % tp == 0
 
 
@@ -683,7 +681,7 @@ def paged_impl_plan(
     the degree. Head counts not divisible by tp downgrade loudly to the
     auto-partitioned XLA paths (the only genuinely illegal sharding).
 
-    Returns ``{"attention": "ragged"|"xla-gather"|"writeback",
+    Returns ``{"attention": "ragged"|"xla-gather",
     "ragged_variant": "flat"|"grouped"|None, "scatter": "pallas"|"xla",
     "kv_dtype": str, "tp": int, "downgraded": [...]}``.
     """
@@ -697,15 +695,7 @@ def paged_impl_plan(
     hkv_shard = cfg.n_kv_heads // tp if shard_ok else cfg.n_kv_heads
     downgraded = []
     ragged_variant = None
-    if impl in ("xla-writeback", "pallas-writeback"):
-        attention = "writeback"
-        if impl == "pallas-writeback" and not shard_ok:
-            downgraded.append(
-                f"pallas-writeback -> xla-writeback (n_kv_heads="
-                f"{cfg.n_kv_heads}/n_heads={cfg.n_heads} not divisible by "
-                f"tp={tp})"
-            )
-    elif impl == "pallas":
+    if impl == "pallas":
         # legality predicates live with the kernels (ops.paged_attention)
         # so the plan and the wrappers cannot drift. Hkv no longer gates
         # the kernel (round 5): Hkv%16 shapes take the "flat" all-heads
@@ -788,14 +778,13 @@ def decode_step(
     model holds every expert its router names; zeros for a dense model).
     Pass donated pages for in-place updates under jit.
 
-    ``impl`` selects the decode structure ("xla" default, "pallas",
-    "xla-writeback"). There is deliberately NO env-var fallback here: this
-    function is jitted by its callers, an env read would happen at trace
-    time and not be part of any jit cache key, so toggling the env after a
-    trace would silently keep the previously compiled implementation
-    (ADVICE r3/r4). The engine resolves MTPU_PAGED_IMPL once in
-    ``LLMEngine.__init__`` and passes it explicitly; use
-    ``paged_impl_plan`` to see what will actually run for given shapes.
+    ``impl`` selects the attention ("xla" default, "pallas"). There is
+    deliberately NO env-var fallback here: this function is jitted by its
+    callers, an env read would happen at trace time and not be part of any
+    jit cache key, so toggling the env after a trace would silently keep the
+    previously compiled implementation (ADVICE r3/r4). The engine resolves
+    MTPU_PAGED_IMPL once in ``LLMEngine.__init__`` and passes it explicitly;
+    use ``paged_impl_plan`` to see what will actually run for given shapes.
 
     Structure (round-3 rework): the page arrays are READ-ONLY inside the
     layer scan — attention walks the cached prefix in chunks of the page
@@ -812,14 +801,8 @@ def decode_step(
     the attention for the v3 ragged kernel (ops.paged_decode_attention_ragged)
     — it reads exactly ceil(ctx/page_size) pages per sequence where the XLA
     loop reads every slot as far as the batch's longest context, rounded up
-    to a chunk. ``impl="xla-writeback"`` keeps the
-    round-2 write-then-attend structure as an A/B lever.
+    to a chunk.
     """
-    if impl in ("xla-writeback", "pallas-writeback"):
-        return _decode_step_writeback(
-            params, tokens, positions, k_pages, v_pages, page_tables, active,
-            cfg, impl=impl, mesh=mesh, return_counts=return_counts,
-        )
     B = tokens.shape[0]
     page_size = k_pages.shape[2]
     # "pallas" = the v3 ragged kernel in the SAME read-only-pages structure
@@ -908,77 +891,6 @@ def decode_step(
         # decode program and scatters data + scale rows).
         k_pages = kv_scatter(k_pages, k_all, page_idx, slot)
         v_pages = kv_scatter(v_pages, v_all, page_idx, slot)
-    x = layers.rms_norm(x, params["final_norm"], cfg.norm_eps)
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = layers.mm(x, head)
-    if return_counts:
-        return logits, k_pages, v_pages, counts.sum(axis=0)
-    return logits, k_pages, v_pages
-
-
-def _decode_step_writeback(
-    params, tokens, positions, k_pages, v_pages, page_tables, active, cfg,
-    impl: str = "xla-writeback", mesh=None, return_counts: bool = False,
-):
-    """Write-then-attend decode (Pallas paged kernel path): each layer lands
-    its KV in the pages before calling the kernel, which reads the current
-    token back from the cache. See ``decode_step`` for why the default path
-    avoids threading the caches through the scan."""
-    B = tokens.shape[0]
-    page_size = k_pages.shape[2]
-    # the plan's downgrade contract via the SHARED predicate: heads not
-    # divisible by tp fall back to the auto-partitioned xla-writeback
-    # (exactly what paged_impl_plan reports), never a trace error
-    pallas_wb = impl == "pallas-writeback" and tp_shard_ok(
-        cfg, mesh_tp_degree(mesh)
-    )
-    x = params["embed"][tokens]  # [B, D]
-    cos, sin = layers.rotary_embedding(
-        positions[:, None], cfg.head_dim, cfg.rope_theta, dtype=jnp.float32,
-        rope_scaling=dict(cfg.rope_scaling) if cfg.rope_scaling else None,
-    )  # [B, 1, hd/2]
-
-    page_idx = jnp.take_along_axis(
-        page_tables, (positions // page_size)[:, None], axis=1
-    )[:, 0]
-    page_idx = jnp.where(active, page_idx, 0)
-    slot = jnp.where(active, positions % page_size, 0)
-    ctx_lens = jnp.where(active, positions + 1, 1).astype(jnp.int32)
-
-    def layer_fn(x, layer, _li, k_pg, v_pg):
-        D = cfg.head_dim
-        h = layers.rms_norm(x, layer["attn_norm"], cfg.norm_eps)
-        q = layers.mm(h, layer["wq"]).astype(x.dtype)
-        k = layers.mm(h, layer["wk"]).astype(x.dtype)
-        v = layers.mm(h, layer["wv"]).astype(x.dtype)
-        q = q.reshape(B, 1, cfg.n_heads, D).transpose(0, 2, 1, 3)  # [B,H,1,D]
-        k = k.reshape(B, 1, cfg.n_kv_heads, D).transpose(0, 2, 1, 3)
-        v = v.reshape(B, 1, cfg.n_kv_heads, D).transpose(0, 2, 1, 3)
-        q = layers.apply_rope(q, cos, sin)
-        k = layers.apply_rope(k, cos, sin)
-        # write this token's KV into the page cache ([P, ps, Hkv, D] layout:
-        # adjacent advanced indices at dims 0, 1 land the [B, Hkv, D]
-        # update); int8 caches quantize at the write
-        k_pg = kv_scatter(k_pg, k[:, :, 0], page_idx, slot,
-                          leading_layer=False)
-        v_pg = kv_scatter(v_pg, v[:, :, 0], page_idx, slot,
-                          leading_layer=False)
-        # xla-writeback stays auto-partitioned (the gather needs no manual
-        # sharding); pallas-writeback goes through the shard_map dispatch
-        o = sharded_paged_decode_attention(
-            mesh if pallas_wb else None,
-            q[:, :, 0], k_pg, v_pg, page_tables, ctx_lens,
-            impl="pallas" if pallas_wb else "xla",
-        )  # [B, H, D]
-        o = o.reshape(B, cfg.n_heads * D)
-        x = x + layers.mm(o, layer["wo"]).astype(x.dtype)
-        h = layers.rms_norm(x, layer["mlp_norm"], cfg.norm_eps)
-        h, counts = _serving_mlp(layer, h, cfg, active)
-        return x + h, (k_pg, v_pg, counts)
-
-    x, (k_pages, v_pages, counts) = _moe.scan_layers(
-        _layer_stack(params), layer_fn, x, k_pages, v_pages
-    )
     x = layers.rms_norm(x, params["final_norm"], cfg.norm_eps)
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
     logits = layers.mm(x, head)

@@ -23,7 +23,6 @@ from .kv_quant import (
 from .paged_attention import (
     decode_chunk_pages,
     decode_chunk_trips,
-    paged_decode_attention,
     paged_decode_attention_chunked,
     paged_latent_decode_attention_chunked,
     paged_decode_attention_inflight,
@@ -37,7 +36,6 @@ from .sharded import (
     shard_cache_pages,
     sharded_flash_attention,
     sharded_flash_attention_chunked,
-    sharded_paged_decode_attention,
     sharded_ragged_decode,
     sharded_scatter_kv_pages,
 )
@@ -58,7 +56,6 @@ __all__ = [
     "flash_attention",
     "flash_attention_chunked",
     "flash_attention_with_lse",
-    "paged_decode_attention",
     "paged_decode_attention_chunked",
     "paged_latent_decode_attention_chunked",
     "paged_decode_attention_inflight",
@@ -73,7 +70,6 @@ __all__ = [
     "shard_cache_pages",
     "sharded_flash_attention",
     "sharded_flash_attention_chunked",
-    "sharded_paged_decode_attention",
     "sharded_ragged_decode",
     "sharded_scatter_kv_pages",
     "quantize_int8",

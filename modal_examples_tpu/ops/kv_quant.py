@@ -166,8 +166,7 @@ def shard_kv(pages, data_sharding, scale_sharding):
     """Place cache pages on a mesh: plain arrays take ``data_sharding``;
     QuantizedKV shards its f32 scale array WITH the int8 data on the same
     kv-head axis (``scale_sharding`` = the data spec minus the D axis), so
-    dequant never crosses chips. The one helper behind both the paged
-    (engine._shard_cache) and dense (DenseKVCache.create) TP caches."""
+    dequant never crosses chips (engine._shard_cache's placement)."""
     if is_quantized(pages):
         return QuantizedKV(
             data=jax.device_put(pages.data, data_sharding),

@@ -52,157 +52,6 @@ from .kv_quant import QuantizedKV, is_quantized, kv_gather, quantize_kv
 from .scopes import ATTENTION
 
 
-def _decode_kernel(
-    # scalar prefetch
-    page_tables_ref,  # (B * pages_per_seq,) int32, SMEM
-    ctx_lens_ref,  # (B,) int32, SMEM
-    # inputs
-    q_ref,  # (1, Hq, D) VMEM
-    k_hbm,  # (n_pages, page_size, Hkv, D) ANY/HBM
-    v_hbm,  # (n_pages, page_size, Hkv, D) ANY/HBM
-    # outputs
-    o_ref,  # (1, Hq, D) VMEM
-    # scratch
-    k_scr,  # (2, page_size, Hkv, D) VMEM
-    v_scr,  # (2, page_size, Hkv, D) VMEM
-    acc_scr,  # (Hq, D) f32
-    sems,  # DMA sems (2, 2)
-    *,
-    page_size: int,
-    pages_per_seq: int,
-    group: int,  # Hq // Hkv
-    sm_scale: float,
-):
-    b = pl.program_id(0)
-    ctx = ctx_lens_ref[b]
-    n_pages = pl.cdiv(ctx, page_size)
-
-    def page_id(i):
-        return page_tables_ref[b * pages_per_seq + i]
-
-    def k_dma(slot, i):
-        return pltpu.make_async_copy(
-            k_hbm.at[page_id(i)], k_scr.at[slot], sems.at[slot, 0]
-        )
-
-    def v_dma(slot, i):
-        return pltpu.make_async_copy(
-            v_hbm.at[page_id(i)], v_scr.at[slot], sems.at[slot, 1]
-        )
-
-    @pl.when(n_pages > 0)
-    def _():
-        k_dma(0, 0).start()
-        v_dma(0, 0).start()
-
-    acc_scr[:] = jnp.zeros_like(acc_scr)
-    q = q_ref[0].astype(jnp.float32) * sm_scale  # (Hq, D)
-    Hq, D = q.shape
-    Hkv = k_scr.shape[2]
-    W = page_size * Hkv  # page width, token-major flatten (tok, head)
-
-    # static (Hq, W) head-alignment mask: query row r (kv head r // group)
-    # may only see columns of its own kv head (column c % Hkv)
-    row_head = jax.lax.broadcasted_iota(jnp.int32, (Hq, W), 0) // group
-    col_head = jax.lax.broadcasted_iota(jnp.int32, (Hq, W), 1) % Hkv
-    head_ok = row_head == col_head
-    col_tok = jax.lax.broadcasted_iota(jnp.int32, (Hq, W), 1) // Hkv
-
-    def body(i, carry):
-        m_prev, l_prev = carry  # (Hq, 1) each
-        slot = jax.lax.rem(i, 2)
-
-        @pl.when(i + 1 < n_pages)
-        def _prefetch():
-            nxt = jax.lax.rem(i + 1, 2)
-            k_dma(nxt, i + 1).start()
-            v_dma(nxt, i + 1).start()
-
-        k_dma(slot, i).wait()
-        v_dma(slot, i).wait()
-        k = k_scr[slot].reshape(W, D).astype(jnp.float32)
-        v = v_scr[slot].reshape(W, D).astype(jnp.float32)
-
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )  # (Hq, W)
-        valid = head_ok & (i * page_size + col_tok < ctx)
-        s = jnp.where(valid, s, -jnp.inf)
-
-        m_cur = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        m_safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
-        p = jnp.where(jnp.isfinite(m_new), jnp.exp(s - m_safe), 0.0)
-        alpha = jnp.where(jnp.isfinite(m_prev), jnp.exp(m_prev - m_safe), 0.0)
-        l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        pv = jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )  # (Hq, D) — off-head columns of p are 0, so per-head rows are exact
-        acc_scr[:] = acc_scr[:] * alpha + pv
-        return m_new, l_new
-
-    init = (
-        jnp.full((Hq, 1), -jnp.inf, jnp.float32),
-        jnp.zeros((Hq, 1), jnp.float32),
-    )
-    _, l_final = jax.lax.fori_loop(0, n_pages, body, init)
-    l_safe = jnp.where(l_final > 0, l_final, 1.0)
-    o_ref[0] = (acc_scr[:] / l_safe).astype(o_ref.dtype)
-
-
-def _paged_decode_xla(
-    q, k_pages, v_pages, page_tables, context_lens, sm_scale
-):
-    """Gather + layout-preserving einsums — the default decode path.
-
-    Measured on a v5e chip at 7B decode shapes (B=8, 32 heads, D=128,
-    ctx 256): ~0.05 ms vs 1.5 ms for the hand-written Pallas kernel and
-    1.7 ms for a transpose-then-einsum formulation. The trick is that no
-    operand is ever relaid out: the einsums contract directly over the
-    gathered ``[B, pages, page_size, Hkv, D]`` page layout, so XLA fuses
-    gather → QK → softmax → PV into bandwidth-bound loops. Also (unlike a
-    pallas_call) this is auto-partitionable under a sharded jit, which is
-    what lets tensor-parallel serving shard the page cache by kv head.
-
-    int8 caches (:class:`~.kv_quant.QuantizedKV`) dequantize HERE: one
-    multiply at the query's dtype fused into the gather (bf16 on the
-    serving path, matching the ragged kernels' in-VMEM dequant exactly),
-    so the HBM page reads stay int8.
-    """
-    B, Hq, D = q.shape
-    _, page_size, Hkv, _ = k_pages.shape
-    G = Hq // Hkv
-    pages_per_seq = page_tables.shape[1]
-
-    ks = kv_gather(k_pages, page_tables, dtype=q.dtype)  # [B, pp, ps, Hkv, D]
-    vs = kv_gather(v_pages, page_tables, dtype=q.dtype)
-    qg = q.reshape(B, Hkv, G, D)
-    # operands stay in cache dtype INTO the MXU (f32 accumulation via
-    # preferred_element_type): an `.astype(f32)` on the gathered pages
-    # materializes an f32 copy of the whole gathered cache in HBM — a
-    # builder's round-4 knock-out ablation (not a driver record) put it at
-    # the dominant, superlinear-in-slots decode cost (44 of 57 ms/step at
-    # 7B, 32 slots)
-    s = jnp.einsum(
-        "bhgd,bpthd->bhgpt", qg, ks, preferred_element_type=jnp.float32
-    ) * sm_scale  # [B, Hkv, G, pp, ps] f32
-    pos = (
-        jnp.arange(pages_per_seq)[:, None] * page_size
-        + jnp.arange(page_size)[None, :]
-    )  # [pp, ps]
-    valid = pos[None] < context_lens[:, None, None]  # [B, pp, ps]
-    s = jnp.where(valid[:, None, None], s, -jnp.inf)
-    flat = s.reshape(B, Hkv, G, pages_per_seq * page_size)
-    p = jax.nn.softmax(flat, axis=-1).reshape(s.shape)
-    # probabilities at cache dtype for the PV contraction (flash-attention
-    # numerics: f32 softmax, bf16 PV operands, f32 accumulation)
-    o = jnp.einsum(
-        "bhgpt,bpthd->bhgd", p.astype(vs.dtype), vs,
-        preferred_element_type=jnp.float32,
-    )
-    return o.reshape(B, Hq, D).astype(q.dtype)
-
-
 @jax.named_scope(ATTENTION)
 def paged_decode_attention_inflight(
     q: jax.Array,  # [B, Hq, D]
@@ -224,8 +73,9 @@ def paged_decode_attention_inflight(
     registers lets the model scatter ALL layers' KV once per step, outside
     the scan, so the pages are read-only here: prefix scores come from the
     gathered pages, the current token contributes one extra logit column,
-    and both share one softmax. Exact same math as write-then-attend with
-    ``ctx_lens = prefix_lens + 1``.
+    and both share one softmax. Exact same math as attention over pages
+    that already hold the token (``reference.paged_decode_attention`` with
+    ``context_lens = prefix_lens + 1``).
 
     Since PR 25 ``decode_step`` runs ``paged_decode_attention_chunked``
     (the same softmax over the live part of the table only); this
@@ -251,8 +101,8 @@ def paged_decode_attention_inflight(
     valid = pos[None] < prefix_lens[:, None, None]  # [B, pp, ps]
     s = jnp.where(valid[:, None, None], s, -jnp.inf)
     flat = s.reshape(B, Hkv, G, pages_per_seq * page_size)
-    # match the numerics of the write-then-attend path: the old path read
-    # the current token back from the cache, i.e. at cache dtype
+    # the current token at cache dtype: the numerics of reading it back
+    # from the cache, which is what the next step's attention will do
     s_new = jnp.einsum(
         "bhgd,bhd->bhg", qg, k_new.astype(ks.dtype),
         preferred_element_type=jnp.float32,
@@ -500,11 +350,11 @@ def _decode_kernel_ragged(
 ):
     """Ragged decode attention v3: prefix pages + ONE in-flight column.
 
-    v2 (write-then-attend, `_decode_kernel`) forced the model to scatter each
-    layer's KV into the cache *before* attention — the scan-threaded cache
-    structure XLA materializes as full cache copies (round-3 NOTES). v3 keeps
-    the pages READ-ONLY (the fast decode structure: one scatter per step,
-    after the layer scan) by folding the current token's K/V — still in
+    A kernel that reads the current token back from the cache forces the
+    model to scatter each layer's KV *before* attention — a scan-threaded
+    cache, which XLA materializes as full cache copies (round-3 NOTES). v3
+    keeps the pages READ-ONLY (the fast decode structure: one scatter per
+    step, after the layer scan) by folding the current token's K/V — still in
     registers — into the online softmax as one extra logit column, exactly
     like ops.paged_decode_attention_inflight does in XLA. It also indexes the
     full [L, P, ...] cache via a prefetched layer scalar, so the layer scan
@@ -1236,107 +1086,3 @@ def scatter_kv_pages(
             ),
         )
     return outs[0], outs[1]
-
-
-def paged_decode_attention(
-    q: jax.Array,  # [B, Hq, D]
-    k_pages: jax.Array,  # [n_pages, page_size, Hkv, D]
-    v_pages: jax.Array,  # [n_pages, page_size, Hkv, D]
-    page_tables: jax.Array,  # [B, pages_per_seq] int32
-    context_lens: jax.Array,  # [B] int32
-    *,
-    sm_scale: float | None = None,
-    interpret: bool | None = None,
-    impl: str | None = None,  # None/env: "xla" (default) or "pallas"
-) -> jax.Array:  # [B, Hq, D]
-    """One decode step of attention against the paged KV cache.
-
-    Default impl is the fused-gather XLA formulation (see
-    ``_paged_decode_xla`` for on-chip measurements); the Pallas kernel is
-    kept selectable (``MTPU_PAGED_IMPL=pallas``) as the base for future
-    tuning where its exact-ctx page reads matter (very long, very ragged
-    contexts where the gather's pages_per_seq padding dominates).
-    """
-    import os
-
-    B, Hq, D = q.shape
-    n_pages, page_size, Hkv, _ = k_pages.shape
-    if Hq % Hkv:
-        raise ValueError(f"Hq={Hq} must be a multiple of Hkv={Hkv}")
-    G = Hq // Hkv
-    pages_per_seq = page_tables.shape[1]
-    if sm_scale is None:
-        sm_scale = D**-0.5
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    if impl is None:
-        impl = os.environ.get("MTPU_PAGED_IMPL", "xla")
-
-    # Mosaic DMA units are (sublane, lane) tiles — a page must be a whole
-    # number of (16, 128) bf16 tiles or the HBM→VMEM copies fail to lower
-    # (observed on-chip with head_dim 32), and the kernel's (ps, Hkv, D) ->
-    # (ps*Hkv, D) flatten needs Hkv % 16 (sub-16 head counts pad sublanes;
-    # merging padded tiles relayouts). Sub-tile shapes (tiny/test models,
-    # GQA) take the XLA path regardless of impl. int8 (QuantizedKV) caches
-    # also take the XLA path here — _paged_decode_xla dequantizes in its
-    # gather; only the v3/v4 ragged kernels take int8 caches (this legacy
-    # write-then-attend kernel is an A/B lever, ROADMAP D3).
-    if (
-        impl != "pallas"
-        or is_quantized(k_pages)
-        or (not interpret and (D % 128 or page_size % 16 or Hkv % 16))
-    ):
-        return _paged_decode_xla(
-            q, k_pages, v_pages, page_tables, context_lens, sm_scale
-        )
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B,),
-        in_specs=[
-            pl.BlockSpec(
-                (1, Hq, D), lambda b, *_refs: (b, 0, 0),
-                memory_space=pltpu.VMEM,
-            ),
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ],
-        out_specs=pl.BlockSpec(
-            (1, Hq, D), lambda b, *_refs: (b, 0, 0),
-            memory_space=pltpu.VMEM,
-        ),
-        scratch_shapes=[
-            pltpu.VMEM((2, page_size, Hkv, D), k_pages.dtype),
-            pltpu.VMEM((2, page_size, Hkv, D), v_pages.dtype),
-            pltpu.VMEM((Hq, D), jnp.float32),
-            pltpu.SemaphoreType.DMA((2, 2)),
-        ],
-    )
-    kernel = functools.partial(
-        _decode_kernel,
-        page_size=page_size,
-        pages_per_seq=pages_per_seq,
-        group=G,
-        sm_scale=sm_scale,
-    )
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Hq, D), q.dtype),
-        compiler_params=pltpu.CompilerParams(
-            # each sequence reads shared pages but writes a distinct output
-            # block: the grid is safely parallel
-            dimension_semantics=("parallel",),
-        ),
-        cost_estimate=pl.CostEstimate(
-            flops=int(4 * B * Hq * pages_per_seq * page_size * Hkv * D),
-            bytes_accessed=int(
-                2 * B * pages_per_seq * Hkv * page_size * D
-                * k_pages.dtype.itemsize
-            ),
-            transcendentals=int(B * Hq * pages_per_seq * page_size * Hkv),
-        ),
-        interpret=interpret,
-    )(page_tables.reshape(-1).astype(jnp.int32), context_lens.astype(jnp.int32),
-      q, k_pages, v_pages)
-    return out

@@ -25,9 +25,9 @@ PROBED_MODULES: dict[str, list[str]] = {
         "flash_fwd", "flash_bwd", "flash_chunked",
     ],
     "modal_examples_tpu.ops.paged_attention": [
-        "paged_decode", "ragged_decode", "ragged_decode_gqa",
-        "ragged_decode_int8kv", "ragged_decode_gqa_int8kv",
-        "ragged_decode_tp_shard_int8kv", "scatter_kv", "scatter_kv_int8",
+        "ragged_decode", "ragged_decode_gqa", "ragged_decode_int8kv",
+        "ragged_decode_gqa_int8kv", "ragged_decode_tp_shard_int8kv",
+        "scatter_kv", "scatter_kv_int8",
     ],
     "modal_examples_tpu.ops.quantized_matmul": ["int8_matmul"],
 }
@@ -132,35 +132,6 @@ def probe_int8_matmul() -> dict:
     return {"rel_err": round(rel, 4)}
 
 
-def probe_paged_decode() -> dict:
-    import jax
-    import jax.numpy as jnp
-
-    from modal_examples_tpu import ops
-    from modal_examples_tpu.ops import reference
-
-    B, Hq, Hkv, D, ps, pp = 2, 16, 16, 128, 16, 4
-    n_pages = B * pp + 2
-    kp = jax.random.normal(
-        jax.random.PRNGKey(0), (n_pages, ps, Hkv, D), jnp.bfloat16
-    )
-    vp = jax.random.normal(
-        jax.random.PRNGKey(1), (n_pages, ps, Hkv, D), jnp.bfloat16
-    )
-    pt = jax.random.permutation(jax.random.PRNGKey(2), n_pages)[
-        : B * pp
-    ].reshape(B, pp).astype(jnp.int32)
-    lens = jnp.array([30, 57], jnp.int32)
-    q = jax.random.normal(jax.random.PRNGKey(3), (B, Hq, D), jnp.bfloat16)
-    o = jax.jit(functools.partial(ops.paged_decode_attention, impl="pallas"))(
-        q, kp, vp, pt, lens
-    )
-    ref = jax.jit(reference.paged_decode_attention)(q, kp, vp, pt, lens)
-    err = _err(o, ref)
-    assert err < ATTN_TOL, err
-    return {"max_err": round(err, 4)}
-
-
 def probe_ragged(
     Hq: int, Hkv: int, variant: str | None, *, int8: bool = False,
     L=2, B=2, D=128, ps=16, pp=4,
@@ -257,7 +228,6 @@ KERNEL_PROBES: dict[str, Callable[[], dict]] = {
     "flash_bwd": probe_flash_bwd,
     "flash_chunked": probe_flash_chunked,
     "int8_matmul": probe_int8_matmul,
-    "paged_decode": probe_paged_decode,
     "ragged_decode": functools.partial(probe_ragged, 16, 16, "flat"),
     # the "grouped" per-kv-head formulation at a GQA shape (Hkv=8, G=4): no
     # (ps*Hkv) flatten, so Hkv%16 doesn't apply

@@ -153,7 +153,7 @@ def paged_verify_attention(
     """Teacher-forced attention of a T-token chain against the paged cache
     (the chain's own KV must already be written). Query t attends to cache
     positions <= positions[b, t] — the multi-token generalization of
-    ``paged_decode_attention`` used by speculative-decoding verification
+    ``paged_decode_attention`` above, used by speculative-decoding verification
     (the reference ships spec decode engine-side, vllm_inference.py:196-205).
     int8 (QuantizedKV) page caches dequantize in the gather, so the verify
     pass scores proposals against exactly the KV values decode will read.

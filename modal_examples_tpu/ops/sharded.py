@@ -53,7 +53,6 @@ from .flash_attention import flash_attention, flash_attention_chunked
 from .kv_quant import QuantizedKV, is_quantized
 from .scopes import ATTENTION, KV_SCATTER
 from .paged_attention import (
-    paged_decode_attention,
     paged_decode_attention_ragged,
     scatter_kv_pages,
 )
@@ -97,18 +96,16 @@ def _check_heads(tp: int, name_shapes: list[tuple[str, int]]) -> None:
             )
 
 
-def _pages_specs(quantized: bool, axis: str, head_dim: int = 3):
-    """(in_specs, operand-flatten, rebuild) for one page operand whose
-    kv-head axis sits at ``head_dim`` ([L, P, ps, Hkv, D] → 3; the
-    writeback path's per-layer [P, ps, Hkv, D] → 2): plain arrays are one
-    head-sharded leaf; QuantizedKV flattens to (int8 data, f32 scale) with
-    the scale sharded on the SAME head axis so in-kernel dequant never
-    crosses chips — the one place that data/scale pairing rule lives."""
-    lead = (None,) * head_dim
-    data = P(*lead, axis, None)
+def _pages_specs(quantized: bool, axis: str):
+    """(in_specs, operand-flatten, rebuild) for one [L, P, ps, Hkv, D]
+    page operand: plain arrays are one head-sharded leaf; QuantizedKV
+    flattens to (int8 data, f32 scale) with the scale sharded on the SAME
+    head axis so in-kernel dequant never crosses chips — the one place
+    that data/scale pairing rule lives."""
+    data = P(None, None, None, axis, None)
     if not quantized:
         return [data], lambda pg: [pg], lambda leaves: leaves[0]
-    scale = P(*lead, axis)
+    scale = P(None, None, None, axis)
     return (
         [data, scale],
         lambda pg: [pg.data, pg.scale],
@@ -291,48 +288,3 @@ def sharded_flash_attention_chunked(
         out_specs=heads,
         check_vma=False,
     )(q, k, v)
-
-
-def sharded_paged_decode_attention(
-    mesh,
-    q,  # [B, Hq, D]
-    k_pages,  # [P, ps, Hkv, D] — per-layer pages (the writeback structure)
-    v_pages,
-    page_tables,  # [B, pages_per_seq] int32
-    context_lens,  # [B] int32
-    *,
-    impl: str | None = None,
-    axis: str = TENSOR,
-):
-    """The legacy write-then-attend decode kernel under tensor parallelism
-    (the ``pallas-writeback`` A/B lever): same head sharding, per-layer
-    [P, ps, Hkv, D] page views. Inside the shard the wrapper's own shape
-    legality applies to the LOCAL head count (an Hkv//tp below 16 silently
-    takes the XLA gather per shard, exactly like single-chip sub-16)."""
-    tp = mesh_tp_degree(mesh, axis)
-    if tp <= 1:
-        return paged_decode_attention(
-            q, k_pages, v_pages, page_tables, context_lens, impl=impl
-        )
-    _check_heads(
-        tp, [("n_heads", q.shape[1]), ("n_kv_heads", k_pages.shape[2])]
-    )
-    quantized = is_quantized(k_pages)
-    # per-layer [P, ps, Hkv, D] pages: the head axis sits one dim earlier
-    pg_specs, flatten, rebuild = _pages_specs(quantized, axis, head_dim=2)
-    heads = P(None, axis, None)
-    n_pg = len(pg_specs)
-
-    def local(q, *rest):
-        kp = rebuild(rest[:n_pg])
-        vp = rebuild(rest[n_pg : 2 * n_pg])
-        tables, lens = rest[2 * n_pg :]
-        return paged_decode_attention(q, kp, vp, tables, lens, impl=impl)
-
-    return jax.shard_map(
-        local,
-        mesh=mesh,
-        in_specs=(heads, *pg_specs, *pg_specs, P(None, None), P(None)),
-        out_specs=heads,
-        check_vma=False,
-    )(q, *flatten(k_pages), *flatten(v_pages), page_tables, context_lens)

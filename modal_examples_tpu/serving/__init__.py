@@ -4,7 +4,7 @@ The TPU-native replacement for the vLLM/SGLang/TRT-LLM engines every
 llm-serving example in the reference shells out to (SURVEY.md §2.2).
 """
 
-from . import disagg, speculative, tensor_parallel
+from . import disagg, speculative
 from .engine import LLMEngine, Request, build_engine
 from .kv_cache import OutOfPages, PagedKVCache, PageAllocator
 from .openai_api import OpenAIServer
@@ -22,5 +22,4 @@ __all__ = [
     "build_engine",
     "sample",
     "speculative",
-    "tensor_parallel",
 ]

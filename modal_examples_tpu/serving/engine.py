@@ -484,7 +484,7 @@ class LLMEngine:
         # resolved ONCE here and passed explicitly into every jitted decode:
         # the env vars are not part of any jit cache key (ADVICE r3)
         self.paged_impl = paged_impl or _os.environ.get("MTPU_PAGED_IMPL", "xla")
-        _known_impls = ("xla", "pallas", "xla-writeback", "pallas-writeback")
+        _known_impls = ("xla", "pallas")
         if self.paged_impl not in _known_impls:
             raise ValueError(
                 f"unknown paged_impl {self.paged_impl!r}; known: {_known_impls}"
@@ -1087,7 +1087,7 @@ class LLMEngine:
         program can kill a lane before its last step; it is counted as
         running them all."""
         if self.impl_plan["attention"] != "xla-gather":
-            return  # the ragged kernels and the write-back path do not loop
+            return  # the ragged kernels do not loop
         live = positions[active].astype(np.int64)
         ps, pp = self.cache.page_size, self.pages_per_slot
         read = 0

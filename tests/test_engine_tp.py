@@ -1,5 +1,6 @@
-"""Tensor-parallel serving tests: the sharded dense-cache decode must equal
-the single-device full forward, and params/cache must actually shard."""
+"""Tensor-parallel serving tests: the paged engine under ``mesh=`` serves,
+shards its params and cache, and stays within the documented logit drift of
+the single-device run."""
 
 import pytest
 
@@ -11,83 +12,6 @@ import numpy as np
 @pytest.fixture(scope="module")
 def jax(jax_cpu):
     return jax_cpu
-
-
-@pytest.fixture(scope="module")
-def setup(jax):
-    from modal_examples_tpu.models import llama
-
-    cfg = llama.LlamaConfig(
-        vocab_size=128, dim=64, n_layers=2, n_heads=4, n_kv_heads=2,
-        ffn_dim=128, max_seq_len=64, dtype="float32",
-    )
-    params = llama.init_params(jax.random.PRNGKey(0), cfg)
-    tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 16), 0, 128)
-    return cfg, params, tokens
-
-
-class TestDenseDecodeTP:
-    def test_matches_forward_single_device(self, jax, setup):
-        import jax.numpy as jnp
-
-        from modal_examples_tpu.models import llama
-        from modal_examples_tpu.serving import tensor_parallel as tp
-
-        cfg, params, tokens = setup
-        logits_full = llama.forward(params, tokens, cfg)
-        want_next = np.argmax(np.asarray(logits_full[:, -1]), -1)
-
-        out = tp.generate_tp(
-            params, cfg, tokens, jnp.full((2,), 16), max_new=1, max_len=32
-        )
-        np.testing.assert_array_equal(np.asarray(out[:, 16]), want_next)
-
-    def test_matches_forward_on_tensor_mesh(self, jax, setup):
-        import jax.numpy as jnp
-
-        from modal_examples_tpu.models import llama
-        from modal_examples_tpu.parallel import make_mesh
-        from modal_examples_tpu.serving import tensor_parallel as tp
-
-        cfg, params, tokens = setup
-        mesh = make_mesh({"tensor": 2})
-        logits_full = llama.forward(params, tokens, cfg)
-        want_next = np.argmax(np.asarray(logits_full[:, -1]), -1)
-        out = tp.generate_tp(
-            params, cfg, tokens, jnp.full((2,), 16), max_new=1,
-            mesh=mesh, max_len=32,
-        )
-        np.testing.assert_array_equal(np.asarray(out[:, 16]), want_next)
-
-    def test_params_and_cache_sharded(self, jax, setup):
-        from jax.sharding import PartitionSpec as P
-
-        from modal_examples_tpu.parallel import make_mesh
-        from modal_examples_tpu.serving import tensor_parallel as tp
-
-        cfg, params, _ = setup
-        mesh = make_mesh({"tensor": 2})
-        sharded = tp.shard_params_tp(params, cfg, mesh)
-        assert sharded["layers"]["wq"].sharding.spec == P(None, None, "tensor")
-        cache = tp.DenseKVCache.create(cfg, 2, 32, mesh)
-        assert cache.k.sharding.spec == P(None, None, "tensor", None, None)
-
-    def test_multi_token_greedy_generation(self, jax, setup):
-        import jax.numpy as jnp
-
-        from modal_examples_tpu.parallel import make_mesh
-        from modal_examples_tpu.serving import tensor_parallel as tp
-
-        cfg, params, tokens = setup
-        mesh = make_mesh({"tensor": 2})
-        single = tp.generate_tp(
-            params, cfg, tokens, jnp.full((2,), 16), max_new=6, max_len=32
-        )
-        meshed = tp.generate_tp(
-            params, cfg, tokens, jnp.full((2,), 16), max_new=6,
-            mesh=mesh, max_len=32,
-        )
-        np.testing.assert_array_equal(np.asarray(single), np.asarray(meshed))
 
 
 class TestEngineTP:

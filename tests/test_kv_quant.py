@@ -198,9 +198,6 @@ class TestInt8RaggedKernels:
         o = reference.paged_decode_attention(q, qkp[1], qvp[1], pt, lens)
         ref = reference.paged_decode_attention(q, kp[1], vp[1], pt, lens)
         assert float(jnp.max(jnp.abs(o - ref))) < 0.05
-        # the legacy dense-layer entry point (writeback A/B path) too
-        o2 = ops.paged_decode_attention(q, qkp[1], qvp[1], pt, lens)
-        assert float(jnp.max(jnp.abs(o2 - ref))) < 0.05
 
     def test_variant_auto_selection_respects_kv_dtype(self):
         from modal_examples_tpu.ops.paged_attention import ragged_variant_for
@@ -279,7 +276,7 @@ class TestModelPaths:
         bound = kp8.scale[..., None] * 0.51 + 1e-6
         assert bool(jnp.all(jnp.abs(deq - kp32) <= bound))
 
-    @pytest.mark.parametrize("impl", ["xla", "pallas", "xla-writeback"])
+    @pytest.mark.parametrize("impl", ["xla", "pallas"])
     def test_decode_step_int8_logit_drift(self, tiny_model, impl):
         cfg, params = tiny_model
         lo32, k32, v32, tables, seq_lens = self._prefilled(
@@ -504,34 +501,3 @@ class TestNgramIndex:
         for t in seq[3:]:
             inc.push(t)
         assert bulk.propose(4) == inc.propose(4) == [3, 1, 2]
-
-
-# -- dense TP cache -----------------------------------------------------------
-
-
-class TestDenseKVCacheInt8:
-    def test_decode_step_dense_int8_drift(self):
-        from modal_examples_tpu.serving import tensor_parallel as tp
-
-        cfg = llama.LlamaConfig.tiny()
-        params = llama.init_params(jax.random.PRNGKey(0), cfg)
-        B, S = 2, 32
-        toks = jax.random.randint(
-            jax.random.PRNGKey(1), (B,), 0, cfg.vocab_size
-        )
-        outs = {}
-        for kvd in (None, "int8"):
-            cache = tp.DenseKVCache.create(
-                cfg, B, S, dtype=jnp.float32, kv_dtype=kvd or jnp.float32
-            )
-            logits = None
-            for pos in range(4):  # a few steps so reads hit written KV
-                positions = jnp.full((B,), pos, jnp.int32)
-                logits, cache = tp.decode_step_dense(
-                    params, toks, cache, positions, cfg
-                )
-            outs[str(kvd)] = logits
-            if kvd == "int8":
-                assert is_quantized(cache.k)
-        drift = float(jnp.max(jnp.abs(outs["int8"] - outs["None"])))
-        assert drift < LOGIT_TOL

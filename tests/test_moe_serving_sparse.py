@@ -145,7 +145,7 @@ def _prefilled(jax, llama, cfg, params, B, S, room):
     return prompt, k, v, tables
 
 
-@pytest.mark.parametrize("impl", ["xla", "xla-writeback"])
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
 @pytest.mark.parametrize("B", [1, 16], ids=["T1", "T16"])
 def test_decode_step_matches_forward(jax, llama, model, B, impl):
     import jax.numpy as jnp
@@ -216,7 +216,7 @@ def _lower(jax, fn, *args):
     return jax.jit(fn).lower(*args).as_text(debug_info=True)
 
 
-@pytest.mark.parametrize("impl", ["xla", "xla-writeback"])
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
 def test_decode_program_slices_no_layers_expert_stack(jax, llama, mixtral_shaped, impl):
     import jax.numpy as jnp
 

@@ -280,17 +280,24 @@ class TestDetokWorkerStopStrings:
         eng8 = _mk_engine(params=eng1.params, decode_steps=8)
         try:
             free = SamplingParams(max_tokens=24, temperature=0.0)
-            ref = eng1.submit(PROMPT, free)
-            ref_text = "".join(eng1.stream(ref))
+            # a random-weight model's ids decode to whatever they happen to
+            # (ids past the byte range: to nothing): take the first prompt
+            # whose free run has the text a mid-stream stop string needs
+            for prompt in (PROMPT, "hello world", "The answer is",
+                           "once upon a time", "stop strings need text"):
+                ref = eng1.submit(prompt, free)
+                ref_text = "".join(eng1.stream(ref))
+                if len(ref_text) > 8:
+                    break
             assert len(ref_text) > 8
             # a substring from the middle of the free-running output:
             # guaranteed to match mid-stream on both engines
             stop = ref_text[len(ref_text) // 2:len(ref_text) // 2 + 3]
             sp = SamplingParams(max_tokens=24, temperature=0.0, stop=(stop,))
 
-            c = eng1.submit(PROMPT, sp)
+            c = eng1.submit(prompt, sp)
             classic_out = "".join(eng1.stream(c))
-            m = eng8.submit(PROMPT, sp)
+            m = eng8.submit(prompt, sp)
             ms_out = "".join(eng8.stream(m))
 
             assert ms_out == classic_out

@@ -124,28 +124,6 @@ class TestFlashAttention:
 
 
 class TestPagedAttention:
-    def test_matches_reference_ragged_lens(self, jax, jnp):
-        from modal_examples_tpu.ops import paged_decode_attention, reference
-
-        B, Hq, Hkv, D = 4, 8, 2, 64
-        page_size, n_pages, pages_per_seq = 16, 32, 4
-        ks = jax.random.split(jax.random.PRNGKey(1), 4)
-        q = jax.random.normal(ks[0], (B, Hq, D), jnp.float32)
-        kp = jax.random.normal(ks[1], (n_pages, page_size, Hkv, D), jnp.float32)
-        vp = jax.random.normal(ks[2], (n_pages, page_size, Hkv, D), jnp.float32)
-        pt = (
-            jax.random.permutation(ks[3], n_pages)[: B * pages_per_seq]
-            .reshape(B, pages_per_seq)
-            .astype(jnp.int32)
-        )
-        cl = jnp.array([5, 16, 33, 64], jnp.int32)  # ragged, page-unaligned
-        want = reference.paged_decode_attention(q, kp, vp, pt, cl)
-        for impl in ("xla", "pallas"):  # default fused-gather path + kernel
-            out = paged_decode_attention(q, kp, vp, pt, cl, impl=impl)
-            np.testing.assert_allclose(
-                np.asarray(out), np.asarray(want), atol=2e-5, err_msg=impl
-            )
-
     def test_ragged_kernel_matches_inflight(self, jax, jnp):
         """v3 kernel (full [L,P,...] cache + layer scalar + in-flight token)
         must exactly match the XLA inflight formulation the default decode
@@ -271,50 +249,133 @@ class TestPagedAttention:
         for a, b in zip(outs["xla"], outs["pallas"]):
             np.testing.assert_allclose(a, b, atol=3e-5)
 
-    def test_decode_step_writeback_matches_default(self, jax, jnp):
-        """The write-then-attend A/B structure (impl='xla-writeback') must
-        produce the same logits and cache as the default read-only path —
-        kept as the benchmark lever, so it must not rot (it went through
-        the round-4 layout migration too)."""
+    def test_mha_group_of_one(self, jax, jnp):
+        """Hq == Hkv (a group of one) through both decode attentions
+        ``decode_step`` chooses between, against the plain reference over
+        the same pages with the in-flight token written behind each
+        prefix."""
+        from modal_examples_tpu.ops import (
+            paged_decode_attention_chunked,
+            paged_decode_attention_ragged,
+            reference,
+        )
+
+        L, B, H, D = 2, 2, 4, 64
+        page_size, n_pages, pages_per_seq = 16, 16, 2
+        ks = jax.random.split(jax.random.PRNGKey(5), 5)
+        q = jax.random.normal(ks[0], (B, H, D), jnp.float32)
+        kp = jax.random.normal(ks[1], (L, n_pages, page_size, H, D), jnp.float32)
+        vp = jax.random.normal(ks[2], kp.shape, jnp.float32)
+        k_new = jax.random.normal(ks[3], (B, H, D), jnp.float32)
+        v_new = jax.random.normal(ks[4], (B, H, D), jnp.float32)
+        pt = jnp.arange(B * pages_per_seq, dtype=jnp.int32).reshape(B, -1)
+        prefix = jnp.array([16, 31], jnp.int32)  # contexts 17 and 32
+        page = pt[jnp.arange(B), prefix // page_size]
+        for li in range(L):
+            want = reference.paged_decode_attention(
+                q,
+                kp[li].at[page, prefix % page_size].set(k_new),
+                vp[li].at[page, prefix % page_size].set(v_new),
+                pt, prefix + 1,
+            )
+            for op in (paged_decode_attention_chunked, paged_decode_attention_ragged):
+                out = op(q, kp, vp, jnp.int32(li), pt, prefix, k_new, v_new)
+                np.testing.assert_allclose(
+                    np.asarray(out), np.asarray(want), atol=2e-5,
+                    err_msg=f"{op.__name__} layer {li}",
+                )
+
+
+class TestPagedImplOption:
+    """``paged_impl`` chooses the attention inside the ONE decode structure
+    (``llama.decode_step``): it has two values, the plan reports what will
+    run for each, and neither the model nor its kernels read the
+    environment (the engine resolves ``MTPU_PAGED_IMPL`` once)."""
+
+    @staticmethod
+    def _refusal(monkeypatch, how, value):
+        from modal_examples_tpu.models import llama
+        from modal_examples_tpu.serving import LLMEngine
+
+        kw = {}
+        if how == "arg":
+            kw["paged_impl"] = value
+        else:
+            monkeypatch.setenv("MTPU_PAGED_IMPL", value)
+        with pytest.raises(ValueError, match="unknown paged_impl") as e:
+            LLMEngine(llama.LlamaConfig.tiny(), **kw)
+        return str(e.value)
+
+    @staticmethod
+    def _known(msg):
+        """The values the engine accepts, as its own refusal names them."""
+        import re
+
+        return re.findall(r"'([\w-]+)'", msg.split("known:")[1])
+
+    @pytest.mark.parametrize(
+        "how, value",
+        [("arg", "xla-writeback"), ("env", "pallas-writeback")],
+    )
+    def test_engine_refuses_the_retired_values(self, monkeypatch, how, value):
+        msg = self._refusal(monkeypatch, how, value)
+        assert repr(value) in msg
+        assert self._known(msg) == ["xla", "pallas"], msg
+
+    @pytest.mark.parametrize("backend", ["cpu", "tpu"])
+    @pytest.mark.parametrize("shape", ["tiny", "mistral_7b", "mixtral_8x7b"])
+    def test_plan_names_what_runs_for_every_accepted_value(
+        self, monkeypatch, jax, shape, backend
+    ):
+        """Whatever the engine accepts resolves to one of the two attentions
+        ``decode_step`` has, and a request the shapes cannot honour (on the
+        chip: ``tiny``'s head_dim) is named in ``downgraded``. The plan is
+        shape arithmetic: ``backend`` only steers its legality branch."""
         from modal_examples_tpu.models import llama
 
-        cfg = llama.LlamaConfig.tiny()
-        params = llama.init_params(jax.random.PRNGKey(2), cfg)
-        B, ps, pp = 2, 16, 4
-        n_pages = 1 + B * pp
-        kp = jnp.zeros((cfg.n_layers, n_pages, ps, cfg.n_kv_heads,
-                        cfg.head_dim), jnp.float32)
-        vp = jnp.zeros_like(kp)
-        tables = jnp.asarray(1 + np.arange(B * pp).reshape(B, pp), jnp.int32)
-        toks = jnp.asarray([5, 11], jnp.int32)
-        pos = jnp.asarray([7, 30], jnp.int32)
-        active = jnp.ones((B,), bool)
-        outs = {}
-        for impl in ("xla", "xla-writeback"):
-            lg, k2, v2 = llama.decode_step(
-                params, toks, pos, kp, vp, tables, active, cfg, impl=impl
-            )
-            outs[impl] = (np.asarray(lg), np.asarray(k2), np.asarray(v2))
-        for a, b in zip(outs["xla"], outs["xla-writeback"]):
-            np.testing.assert_allclose(a, b, atol=3e-5)
+        cfg = getattr(llama.LlamaConfig, shape)()
+        accepted = self._known(self._refusal(monkeypatch, "arg", "no-such-impl"))
+        monkeypatch.setattr(jax, "default_backend", lambda: backend)
+        for impl in accepted:
+            for scatter in ("xla", "pallas"):
+                for kvd in ("bfloat16", "int8"):
+                    plan = llama.paged_impl_plan(
+                        cfg, 16, impl, scatter, kv_dtype=kvd, warn=False
+                    )
+                    assert plan["attention"] in {"ragged", "xla-gather"}, plan
+                    asked = {"pallas": "ragged", "xla": "xla-gather"}[impl]
+                    down = " ".join(plan["downgraded"])
+                    assert (plan["attention"] == asked) != (
+                        f"paged_impl={impl} ->" in down
+                    ), plan
+                    assert (plan["scatter"] == scatter) != (
+                        f"scatter_impl={scatter} ->" in down
+                    ), plan
+        if backend == "tpu" and shape != "tiny":
+            # both serving geometries keep the kernel on the chip: 8 KV
+            # heads take the per-kv-head variant
+            plan = llama.paged_impl_plan(cfg, 16, "pallas", warn=False)
+            assert (plan["attention"], plan["ragged_variant"]) == (
+                "ragged", "grouped"
+            ), plan
 
-    def test_mha_group_of_one(self, jax, jnp):
-        from modal_examples_tpu.ops import paged_decode_attention, reference
+    def test_model_and_kernels_do_not_read_the_environment(self):
+        """``decode_step`` is jitted by its callers: an environment read in
+        it or below it happens at trace time and is in no jit cache key."""
+        import ast
+        from pathlib import Path
 
-        B, H, D = 2, 4, 64
-        page_size, n_pages, pages_per_seq = 16, 16, 2
-        ks = jax.random.split(jax.random.PRNGKey(5), 4)
-        q = jax.random.normal(ks[0], (B, H, D), jnp.float32)
-        kp = jax.random.normal(ks[1], (n_pages, page_size, H, D), jnp.float32)
-        vp = jax.random.normal(ks[2], (n_pages, page_size, H, D), jnp.float32)
-        pt = jnp.arange(B * pages_per_seq, dtype=jnp.int32).reshape(B, -1)
-        cl = jnp.array([17, 32], jnp.int32)
-        want = reference.paged_decode_attention(q, kp, vp, pt, cl)
-        for impl in ("xla", "pallas"):
-            out = paged_decode_attention(q, kp, vp, pt, cl, impl=impl)
-            np.testing.assert_allclose(
-                np.asarray(out), np.asarray(want), atol=2e-5, err_msg=impl
-            )
+        import modal_examples_tpu
+
+        root = Path(modal_examples_tpu.__file__).parent
+        for rel in ("ops/paged_attention.py", "models/llama.py"):
+            tree = ast.parse((root / rel).read_text())
+            reads = [
+                f"{rel}:{n.lineno}" for n in ast.walk(tree)
+                if (isinstance(n, ast.Attribute) and n.attr in ("environ", "getenv"))
+                or (isinstance(n, ast.Name) and n.id in ("environ", "getenv"))
+            ]
+            assert not reads, reads
 
 
 class TestChunkedDecodeAttention:

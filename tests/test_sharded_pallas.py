@@ -85,18 +85,31 @@ class TestShardedKernelOps:
         out = jax.jit(
             lambda *a: sharded_ragged_decode(mesh2, *a, variant=variant)
         )(q, kp, vp, layer, tables, prefix, k_new, v_new)
-        if variant == "grouped":
-            # per-kv-head contractions are untouched by head sharding: the
-            # sharded kernel is BIT-exact vs single-device — int8 too (the
-            # scales are per token-head)
-            np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
-        else:
-            # flat's block-diagonal matmul contracts over W = ps*Hkv
-            # columns; halving Hkv per shard changes the f32 summation
-            # tree, so flat is ulp-exact (measured 7e-9), not bit-exact
-            np.testing.assert_allclose(
-                np.asarray(out), np.asarray(ref), atol=1e-6, rtol=0
-            )
+        # the dispatcher adds nothing to the kernel: BIT-exact against the
+        # same kernel run on each shard's own heads — int8 too (the scales
+        # are per token-head)
+        tp, g = 2, Hq // Hkv
+        per_shard = []
+        for s in range(tp):
+            kv = slice(s * Hkv // tp, (s + 1) * Hkv // tp)
+            own = lambda pg: jax.tree.map(lambda a: a[:, :, :, kv], pg)
+            per_shard.append(paged_decode_attention_ragged(
+                q[:, kv.start * g : kv.stop * g], own(kp), own(vp), layer,
+                tables, prefix, k_new[:, kv], v_new[:, kv], variant=variant,
+            ))
+        np.testing.assert_array_equal(
+            np.asarray(out), np.asarray(jnp.concatenate(per_shard, axis=1))
+        )
+        # against all heads in one call: ulp-exact. flat's block-diagonal
+        # matmul contracts over W = ps*Hkv columns, so halving Hkv changes
+        # its f32 summation tree; grouped's per-kv-head contractions do not
+        # change, but in interpret mode its softmax runs on XLA's CPU
+        # backend, whose f32 row sums over an (Hq, W) array depend on Hq in
+        # the last bit (one head alone differs from itself among two, on
+        # one device, by 1.5e-8)
+        np.testing.assert_allclose(
+            np.asarray(out), np.asarray(ref), atol=1e-6, rtol=0
+        )
 
     @pytest.mark.parametrize("kv_dtype", ["float32", "int8"])
     def test_sharded_scatter_matches_xla(self, jax, mesh2, kv_dtype):

@@ -216,14 +216,13 @@ def test_scheduler_policies_implement_full_abc():
     )
 
 
-#: modules that consume the paged/dense KV cache arrays; every entry point
+#: modules that consume the paged KV cache arrays; every entry point
 #: in them must handle BOTH cache forms (plain arrays and the int8 4-leaf
 #: QuantizedKV pytree — docs/kv_cache.md)
 _KV_CONSUMER_MODULES = (
     "ops/paged_attention.py",
     "ops/reference.py",
     "models/llama.py",
-    "serving/tensor_parallel.py",
 )
 
 #: referencing any of these marks a function as quantized-cache-aware
@@ -235,10 +234,10 @@ _KV_QUANT_TOKENS = {
 
 def test_kv_cache_consumers_handle_quantized_pytree():
     """Every paged-attention entry point / cache consumer — any top-level
-    function taking the page arrays (``k_pages``/``v_pages`` params, or the
-    dense ``cache`` in tensor_parallel) — must handle the int8 4-leaf
-    QuantizedKV cache: either it references a kv_quant helper directly, or
-    it delegates to another checked consumer (transitive closure). A
+    function taking the page arrays (``k_pages``/``v_pages`` params) —
+    must handle the int8 4-leaf QuantizedKV cache: either it references a
+    kv_quant helper directly, or it delegates to another checked consumer
+    (transitive closure). A
     consumer that silently indexes plain arrays would make ``kv_dtype=
     "int8"`` crash (best case) or silently read garbage through a pytree
     leaf (worst) — the same unrepresentability treatment as the decorator-
@@ -255,9 +254,7 @@ def test_kv_cache_consumers_handle_quantized_pytree():
                 a.arg for a in node.args.args + node.args.kwonlyargs
             }
             funcs[node.name] = node
-            if {"k_pages", "v_pages"} & params or (
-                rel.endswith("tensor_parallel.py") and "cache" in params
-            ):
+            if {"k_pages", "v_pages"} & params:
                 consumers.append(node.name)
 
     def refs(fn: ast.FunctionDef) -> set[str]:
@@ -424,8 +421,7 @@ def test_serving_path_reaches_pallas_only_through_sharded_dispatch():
     # the guard must actually be guarding the fast-path surface
     assert {
         "flash_attention", "flash_attention_chunked",
-        "paged_decode_attention", "paged_decode_attention_ragged",
-        "scatter_kv_pages",
+        "paged_decode_attention_ragged", "scatter_kv_pages",
     } <= entries, entries
 
     # completeness: the dispatch layer covers every serving fast-path entry
@@ -443,8 +439,7 @@ def test_serving_path_reaches_pallas_only_through_sharded_dispatch():
         e for e in entries
         if e in (
             "flash_attention", "flash_attention_chunked",
-            "paged_decode_attention", "paged_decode_attention_ragged",
-            "scatter_kv_pages",
+            "paged_decode_attention_ragged", "scatter_kv_pages",
         )
         and e not in sharded_refs
     }
