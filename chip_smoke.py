@@ -289,7 +289,11 @@ def leg_kernels(args) -> dict:
     jax = _require_backend(args.rehearse_cpu)
     from jax.experimental import pallas as pl
 
-    from modal_examples_tpu.ops.probes import KERNEL_PROBES, model_geometry_probes
+    from modal_examples_tpu.ops.probes import (
+        CELL_FLASH_PROBES,
+        KERNEL_PROBES,
+        model_geometry_probes,
+    )
     from modal_examples_tpu.serving.engine import MODEL_PRESETS
     from modal_examples_tpu.serving.kv_cache import PagedKVCache
 
@@ -322,6 +326,8 @@ def leg_kernels(args) -> dict:
     probes = dict(KERNEL_PROBES)
     if args.rehearse_cpu:  # the interpreter is slow: a flat and a DMA kernel
         probes = {k: probes[k] for k in ("ragged_decode", "scatter_kv")}
+    else:
+        probes.update(CELL_FLASH_PROBES)
     probes.update(model_geometry_probes(
         n_heads=cfg.n_heads, n_kv_heads=cfg.n_kv_heads, head_dim=cfg.head_dim,
         n_layers=cfg.n_layers, page_size=PAGE_SIZE,

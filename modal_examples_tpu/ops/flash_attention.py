@@ -8,17 +8,27 @@ Design (TPU-first, not a CUDA translation):
 - grid = (batch*kv_heads*group, q_blocks, k_blocks); the LAST grid axis is
   sequential on TPU, so the online-softmax state (m, l, acc) lives in VMEM
   scratch carried across k-block steps — no atomics, no cross-block sync.
-- blocks default to 128x128: MXU-shaped, and the f32 scratch tiles align to
-  (8, 128).
-- causal masking skips fully-masked k blocks via a zero-work early exit
-  (the index map still walks them, but no FLOPs issue), and applies an
-  elementwise triangle mask only on the one diagonal block.
+- the forward is bound by its grid steps, not its flops, so its tiles are
+  the largest divisors of the lengths that fit a VMEM budget
+  (``choose_blocks``: 1024 x 1024 at the serving shapes, a short bucket call
+  one tile of its own length); the f32 scratch tiles align to (8, 128).
+- both dots take their operands in the input's dtype (bf16 straight into
+  the MXU) and accumulate in f32; the scale multiplies the f32 scores, p is
+  cast to v's dtype for the second dot, m / l / acc stay f32. Float32 inputs
+  keep float32 dots.
+- causal masking: the k grid stops at the last key any query of the call
+  sees, and a query tile's K/V index map stays on that tile's own last block,
+  so the blocks above the diagonal are not fetched (a repeated block index
+  is no new DMA) and their steps do nothing but count; the elementwise
+  triangle mask is built only in the tiles the diagonal crosses, the ones
+  wholly below it skip it.
 - GQA folds the query-head group into the batch dimension; K/V blocks are
-  indexed by kv head so grouped queries share the same K/V traffic.
+  indexed by kv head, so the group's consecutive grid rows read the same K/V.
 - backward: dedicated Pallas kernels (dq with sequential k-blocks, dk/dv
   with sequential q-blocks) sharing per-block dS math, including the lse
   output's cotangent so ring/ulysses merges differentiate through the
   kernels; MTPU_FLASH_BWD=recompute switches to an XLA-recompute fallback.
+  They keep 128 x 128 blocks and float32 dots.
 
 Runs in interpreter mode off-TPU so CPU CI exercises the same code path.
 """
@@ -36,6 +46,66 @@ from . import reference
 
 _LANES = 128  # f32 scratch tile: (8, 128); m/l are broadcast across lanes
 
+# Masked scores take a large finite value, not -inf: exp(mask - m) is 0 for
+# any finite m and no -inf - -inf can arise, so the step needs no isfinite
+# guards (under causal masking every query row sees key 0 in its first block).
+_MASK = -0.7 * float(jnp.finfo(jnp.float32).max)
+
+# ---------------------------------------------------------------------------
+# Forward tiles. The kernel is bound by its grid steps (a 128 x 128 step took
+# 0.58 us on a v5e for work the MXU does in 0.04), so a call wants the
+# fewest, fattest steps that fit VMEM: choose_blocks() picks them from the
+# call's own shapes.
+# ---------------------------------------------------------------------------
+
+#: what one grid step may hold, by block_footprint()'s count: room for a
+#: 1024 x 1024 bf16 tile at the serving widths (18.5 MiB at D = Dv = 128, 19.0
+#: at 192 / 128), the fastest of the nine tiles swept on a v5e (PERF.md §6,
+#: PR 30; a key block of 512 rows costs 1.6-1.9x one of 1024). The call asks
+#: Mosaic for _VMEM_LIMIT of scoped VMEM (a v5e core has 128 MiB; the default
+#: of 16 refuses such a tile), which leaves the compiler's own temporaries
+#: half as much again.
+_VMEM_BUDGET = 20 * 2**20
+_VMEM_LIMIT = 32 * 2**20
+
+
+def block_footprint(block_q: int, block_k: int, D: int, Dv: int, itemsize: int) -> int:
+    """VMEM bytes of one forward grid step: the q/k/v/o blocks and the lse
+    block double-buffered by the pipeline, the m/l/acc scratch, and the score
+    tile three times in f32 (s, p, the select) and once in the input dtype
+    (p into the second dot)."""
+    blocks = 2 * (block_q + block_k) * (D + Dv) * itemsize
+    stats = (2 * _LANES + 2 * _LANES + Dv) * block_q * 4  # lse x2, m, l, acc
+    tile = block_q * block_k * (3 * 4 + itemsize)
+    return blocks + stats + tile
+
+
+def _tiles(S: int) -> list[int]:
+    """Block lengths a sequence of S may take, largest first: 128 * 2^i where
+    that divides S; else (short or odd lengths, never a serving shape) the
+    sublane-aligned divisors; else S whole."""
+    tiles = [t for t in (2048, 1024, 512, 256, 128) if S % t == 0]
+    if not tiles:
+        tiles = [d for d in range(S, 7, -1) if S % d == 0 and d % 8 == 0]
+    return tiles or [S]
+
+
+def choose_blocks(
+    Sq: int, Skv: int, D: int, Dv: int, itemsize: int,
+    budget: int = _VMEM_BUDGET,
+) -> tuple[int, int]:
+    """(block_q, block_k) for a forward call: the divisors of Sq and Skv with
+    the largest tile whose footprint fits ``budget``. Ties go to the squarer
+    tile (the diagonal wastes least of it), then to the longer key block
+    (fewer rescales of the accumulator); the smallest pair if none fits."""
+    pairs = [(bq, bk) for bq in _tiles(Sq) for bk in _tiles(Skv)]
+    fits = [
+        p for p in pairs if block_footprint(*p, D, Dv, itemsize) <= budget
+    ]
+    if not fits:
+        return pairs[-1]
+    return max(fits, key=lambda p: (p[0] * p[1], min(p), p[1]))
+
 
 def _fwd_kernel(
     q_ref,  # (1, block_q, D)
@@ -52,14 +122,11 @@ def _fwd_kernel(
     *,
     sm_scale: float,
     causal: bool,
-    block_q: int,
-    block_k: int,
     q_offset: int = 0,
 ):
-    del block_k  # derivable from refs; kept for signature symmetry
+    block_q, block_k = q_ref.shape[1], k_ref.shape[1]
     qi = pl.program_id(1)
     ki = pl.program_id(2)
-    nk = pl.num_programs(2)
 
     @pl.when(ki == 0)
     def _init():
@@ -67,66 +134,74 @@ def _fwd_kernel(
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    # causal: k blocks strictly above the diagonal contribute nothing.
     # q_offset shifts query GLOBAL positions (chunked prefill: this q chunk
     # starts at q_offset within the full sequence the K/V cover).
-    block_k = k_ref.shape[1]
     q_start = qi * block_q + q_offset
     k_start = ki * block_k
-    run = jnp.logical_or(not causal, k_start <= q_start + block_q - 1)
 
-    @pl.when(run)
-    def _step():
-        q = q_ref[0].astype(jnp.float32) * sm_scale
-        k = k_ref[0].astype(jnp.float32)
+    def step(masked: bool):
+        # operands as stored (bf16 straight into the MXU), sums in f32
         s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )  # (block_q, block_k)
-        if causal:
-            rows = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) + q_start
-            cols = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) + k_start
-            s = jnp.where(rows >= cols, s, -jnp.inf)
-
+            q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) * sm_scale  # (block_q, block_k)
+        if masked:
+            # row - col >= k_start - q_start: the iota difference is the
+            # same in every tile, only the scalar moves
+            diff = jax.lax.broadcasted_iota(
+                jnp.int32, s.shape, 0
+            ) - jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            s = jnp.where(diff >= k_start - q_start, s, _MASK)
         m_prev = m_scr[:, :1]  # (block_q, 1)
-        l_prev = l_scr[:, :1]
-        m_cur = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        # guard fully-masked rows (m_new == -inf) from producing NaNs
-        m_safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
-        p = jnp.exp(s - m_safe)
-        p = jnp.where(jnp.isfinite(m_new), p, 0.0)
-        alpha = jnp.where(jnp.isfinite(m_prev), jnp.exp(m_prev - m_safe), 0.0)
-        l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        v = v_ref[0].astype(jnp.float32)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        l_new = l_scr[:, :1] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        v = v_ref[0]
         pv = jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
         )
         acc_scr[:] = acc_scr[:] * alpha + pv
         m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
         l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
 
-    # finalize on the last k block this q block ever sees
-    last_k = (
-        jnp.minimum((q_start + block_q - 1) // block_k, nk - 1) if causal else nk - 1
-    )
+    if causal:
+        # the last key block this query tile sees; the ones after it are
+        # neither fetched (the index map stays on last_k) nor computed.
+        # Only a tile the diagonal crosses pays for the mask.
+        last_k = (q_start + block_q - 1) // block_k
+        crossed = k_start + block_k - 1 > q_start
+        pl.when(jnp.logical_and(ki <= last_k, jnp.logical_not(crossed)))(
+            functools.partial(step, False)
+        )
+        pl.when(jnp.logical_and(ki <= last_k, crossed))(
+            functools.partial(step, True)
+        )
+    else:
+        last_k = pl.num_programs(2) - 1
+        step(False)
 
     @pl.when(ki == last_k)
     def _finalize():
-        m = m_scr[:, :1]
-        l = l_scr[:, :1]
-        l_safe = jnp.where(l > 0, l, 1.0)
-        o_ref[0] = (acc_scr[:] / l_safe).astype(o_ref.dtype)
-        lse = jnp.where(l > 0, m + jnp.log(l_safe), -jnp.inf)
-        lse_ref[0] = jnp.broadcast_to(lse, lse_ref.shape[1:])
+        l = l_scr[:, :1]  # >= 1: the row's largest score adds exp(0)
+        o_ref[0] = (acc_scr[:] / l).astype(o_ref.dtype)
+        lse_ref[0] = jnp.broadcast_to(
+            m_scr[:, :1] + jnp.log(l), lse_ref.shape[1:]
+        )
 
 
 def _flash_forward(
-    q, k, v, *, causal: bool, sm_scale: float, block_q: int, block_k: int,
-    interpret: bool, q_offset: int = 0,
+    q, k, v, *, causal: bool, sm_scale: float, interpret: bool,
+    block_q: int | None = None, block_k: int | None = None, q_offset: int = 0,
 ):
+    """``block_q`` / ``block_k`` None: chosen from the shapes."""
     B, Hq, S, D = q.shape  # S = query length
     Hkv, Skv = k.shape[1], k.shape[2]
     Dv = v.shape[-1]  # values may be narrower than q/k (latent attention)
+    chosen_q, chosen_k = choose_blocks(S, Skv, D, Dv, q.dtype.itemsize)
+    block_q = min(block_q or chosen_q, S)
+    block_k = min(block_k or chosen_k, Skv)
     if S % block_q or Skv % block_k:
         raise ValueError(
             f"lengths (q={S}, kv={Skv}) must be multiples of block sizes "
@@ -144,14 +219,20 @@ def _flash_forward(
     kf = k.reshape(B * Hkv, Skv, D)
     vf = v.reshape(B * Hkv, Skv, Dv)
 
-    grid = (B * Hkv * group, pl.cdiv(S, block_q), pl.cdiv(Skv, block_k))
+    # causal: no query sees a key past q_offset + S, so the grid stops there,
+    # and a query tile's k index stops at its own last block: a block index
+    # that repeats is not fetched again
+    kv_len = min(Skv, q_offset + S) if causal else Skv
+    grid = (B * Hkv * group, S // block_q, pl.cdiv(kv_len, block_k))
+
+    def kv_index(bh, qi, ki):
+        if causal:
+            ki = jnp.minimum(ki, (qi * block_q + q_offset + block_q - 1) // block_k)
+        return (bh // group, ki, 0)
+
+    pairs = S * q_offset + S * (S + 1) // 2 if causal else S * Skv
     kernel = functools.partial(
-        _fwd_kernel,
-        sm_scale=sm_scale,
-        causal=causal,
-        block_q=block_q,
-        block_k=block_k,
-        q_offset=q_offset,
+        _fwd_kernel, sm_scale=sm_scale, causal=causal, q_offset=q_offset
     )
     o, lse = pl.pallas_call(
         kernel,
@@ -161,16 +242,8 @@ def _flash_forward(
                 (1, block_q, D), lambda bh, qi, ki: (bh, qi, 0),
                 memory_space=pltpu.VMEM,
             ),
-            pl.BlockSpec(
-                (1, block_k, D),
-                lambda bh, qi, ki, g=group: (bh // g, ki, 0),
-                memory_space=pltpu.VMEM,
-            ),
-            pl.BlockSpec(
-                (1, block_k, Dv),
-                lambda bh, qi, ki, g=group: (bh // g, ki, 0),
-                memory_space=pltpu.VMEM,
-            ),
+            pl.BlockSpec((1, block_k, D), kv_index, memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, block_k, Dv), kv_index, memory_space=pltpu.VMEM),
         ],
         out_specs=[
             pl.BlockSpec(
@@ -191,12 +264,16 @@ def _flash_forward(
             pltpu.VMEM((block_q, _LANES), jnp.float32),
             pltpu.VMEM((block_q, Dv), jnp.float32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT,
+        ),
         cost_estimate=pl.CostEstimate(
-            flops=int(2 * B * Hq * S * S * (D + Dv) * (0.5 if causal else 1.0)),
+            flops=2 * B * Hq * pairs * (D + Dv),
             bytes_accessed=(
                 qf.size + kf.size + vf.size + B * Hq * S * Dv
             ) * q.dtype.itemsize,
-            transcendentals=B * Hq * S * S,
+            transcendentals=B * Hq * pairs,
         ),
         interpret=interpret,
     )(qf, kf, vf)
@@ -415,10 +492,12 @@ def flash_attention(
     v: jax.Array,
     causal: bool = True,
     sm_scale: float | None = None,
-    block_q: int = 128,
-    block_k: int = 128,
+    block_q: int | None = None,
+    block_k: int | None = None,
 ) -> jax.Array:
-    """Fused attention: q [B,Hq,S,D], k/v [B,Hkv,S,D] (GQA when Hkv < Hq)."""
+    """Fused attention: q [B,Hq,S,D], k/v [B,Hkv,S,D] (GQA when Hkv < Hq).
+    ``block_q`` / ``block_k`` None: the forward's tiles are chosen from the
+    shapes (``choose_blocks``), the backward kernels keep their 128."""
     o, _ = _flash_fwd_rule(q, k, v, causal, sm_scale, block_q, block_k)
     return o
 
@@ -427,13 +506,24 @@ def _resolve_scale(q, sm_scale):
     return q.shape[-1] ** -0.5 if sm_scale is None else sm_scale
 
 
+def _bwd_blocks(S, block_q=None, block_k=None):
+    """The backward kernels' blocks: 128 unless given; their grids walk whole
+    blocks only, so the length has to divide."""
+    bq, bk = min(block_q or 128, S), min(block_k or 128, S)
+    if S % bq or S % bk:
+        raise ValueError(
+            f"length {S} must be a multiple of block sizes ({bq}, {bk}); "
+            "pad sequences at the model layer"
+        )
+    return bq, bk
+
+
 def _flash_fwd_rule(q, k, v, causal, sm_scale, block_q, block_k):
     scale = _resolve_scale(q, sm_scale)
-    S = q.shape[2]
-    bq, bk = min(block_q, S), min(block_k, S)
+    _bwd_blocks(q.shape[2], block_q, block_k)
     o, lse = _flash_forward(
         q, k, v, causal=causal, sm_scale=scale,
-        block_q=bq, block_k=bk, interpret=_use_interpret(),
+        block_q=block_q, block_k=block_k, interpret=_use_interpret(),
     )
     return o, (q, k, v, o, lse)
 
@@ -441,8 +531,7 @@ def _flash_fwd_rule(q, k, v, causal, sm_scale, block_q, block_k):
 def _flash_bwd_rule(causal, sm_scale, block_q, block_k, res, g):
     q, k, v, o, lse = res
     scale = _resolve_scale(q, sm_scale)
-    S = q.shape[2]
-    bq, bk = min(block_q, S), min(block_k, S)
+    bq, bk = _bwd_blocks(q.shape[2], block_q, block_k)
     import os as _os
 
     if _os.environ.get("MTPU_FLASH_BWD", "kernel") == "recompute":
@@ -463,11 +552,9 @@ flash_attention.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
 def _flash_with_lse(q, k, v, causal, sm_scale):
-    S = q.shape[2]
+    _bwd_blocks(q.shape[2])
     return _flash_forward(
-        q, k, v, causal=causal, sm_scale=sm_scale,
-        block_q=min(128, S), block_k=min(128, S),
-        interpret=_use_interpret(),
+        q, k, v, causal=causal, sm_scale=sm_scale, interpret=_use_interpret(),
     )
 
 
@@ -486,7 +573,7 @@ def _flash_with_lse_bwd(causal, sm_scale, res, cots):
     # ring/ulysses softmax merges) feeds the kernels' dS term directly
     q, k, v, o, lse = res
     g_o, g_lse = cots
-    S = q.shape[2]
+    bq, bk = _bwd_blocks(q.shape[2])
     import os as _os
 
     if _os.environ.get("MTPU_FLASH_BWD", "kernel") == "recompute":
@@ -499,21 +586,17 @@ def _flash_with_lse_bwd(causal, sm_scale, res, cots):
         return vjp(cots)
     return _flash_backward(
         q, k, v, o, lse, g_o, causal=causal, sm_scale=sm_scale,
-        block_q=min(128, S), block_k=min(128, S),
-        interpret=_use_interpret(), g_lse=g_lse,
+        block_q=bq, block_k=bk, interpret=_use_interpret(), g_lse=g_lse,
     )
 
 
 _flash_with_lse.defvjp(_flash_with_lse_fwd, _flash_with_lse_bwd)
 
 
-def flash_attention_with_lse(
-    q, k, v, *, causal=True, sm_scale=None, block_q=128, block_k=128
-):
+def flash_attention_with_lse(q, k, v, *, causal=True, sm_scale=None):
     """Variant also returning the per-row logsumexp (used by ring attention
-    to combine partial results across shards). Differentiable: backward
-    recomputes through the XLA reference (same pattern as flash_attention)."""
-    del block_q, block_k  # fixed at 128 (clamped to S) on this path
+    to combine partial results across shards). Differentiable through the
+    Pallas backward kernels, the lse output's cotangent included."""
     return _flash_with_lse(q, k, v, causal, _resolve_scale(q, sm_scale))
 
 
@@ -531,18 +614,8 @@ def flash_attention_chunked(
     longer K/V prefix (the engine processes long prompts chunk by chunk with
     bounded VMEM; also the building block for prefix-cache reuse). Forward
     only — prefill needs no gradients."""
-    scale = _resolve_scale(q, sm_scale)
-    Sq, Skv = q.shape[2], k.shape[2]
-
-    def pick_block(S: int) -> int:
-        for b in (128, 64, 32, 16, 8):
-            if S % b == 0:
-                return b
-        return S
-
     o, _ = _flash_forward(
-        q, k, v, causal=causal, sm_scale=scale,
-        block_q=pick_block(Sq), block_k=pick_block(Skv),
+        q, k, v, causal=causal, sm_scale=_resolve_scale(q, sm_scale),
         interpret=_use_interpret(), q_offset=q_offset,
     )
     return o

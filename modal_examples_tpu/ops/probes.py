@@ -46,13 +46,15 @@ def _err(a, b) -> float:
     )
 
 
-def _qkv(B, Hq, Hkv, S, D):
+def _qkv(B, Hq, Hkv, S, D, Dv=None):
     import jax
     import jax.numpy as jnp
 
     q = jax.random.normal(jax.random.PRNGKey(0), (B, Hq, S, D), jnp.bfloat16)
     k = jax.random.normal(jax.random.PRNGKey(1), (B, Hkv, S, D), jnp.bfloat16)
-    v = jax.random.normal(jax.random.PRNGKey(2), (B, Hkv, S, D), jnp.bfloat16)
+    v = jax.random.normal(
+        jax.random.PRNGKey(2), (B, Hkv, S, Dv or D), jnp.bfloat16
+    )
     return q, k, v
 
 
@@ -93,21 +95,31 @@ def probe_flash_bwd() -> dict:
     return {"max_err": round(max(errs), 4)}
 
 
-def probe_flash_chunked(B=1, Hq=8, Hkv=4, S=256, D=128, C=128) -> dict:
+def probe_flash_chunked(
+    B=1, Hq=8, Hkv=4, S=256, D=128, C=128, *, Dv=None, ref_kv_heads=None
+) -> dict:
     """The last ``C`` query positions against the whole ``S``-long K/V —
-    the engine's chunked-prefill shape."""
+    the engine's chunked-prefill shape. ``Dv``: values narrower than q/k
+    (latent attention). The kernel runs every head; ``ref_kv_heads`` holds
+    the XLA reference to the first so many KV heads and their query heads
+    (its f32 scores for 128 heads of a 2048 x 4096 call are 4 GiB)."""
     import jax
 
     from modal_examples_tpu import ops
     from modal_examples_tpu.ops import reference
 
     off = S - C
-    q, k, v = _qkv(B, Hq, Hkv, S, D)
+    q, k, v = _qkv(B, Hq, Hkv, S, D, Dv)
+    qc = q[:, :, off:, :]
     o = jax.jit(
         lambda qc, k, v: ops.flash_attention_chunked(qc, k, v, q_offset=off)
-    )(q[:, :, off:, :], k, v)
-    ref = jax.jit(reference.attention)(q, k, v)[:, :, off:, :]
-    err = _err(o, ref)
+    )(qc, k, v)
+    n_kv = ref_kv_heads or Hkv
+    n_q = n_kv * (Hq // Hkv)
+    ref = jax.jit(
+        lambda qc, k, v: reference.attention_chunked(qc, k, v, q_offset=off)
+    )(qc[:, :n_q], k[:, :n_kv], v[:, :n_kv])
+    err = _err(o[:, :n_q], ref)
     assert err < ATTN_TOL, err
     return {"max_err": round(err, 4)}
 
@@ -247,6 +259,23 @@ KERNEL_PROBES: dict[str, Callable[[], dict]] = {
     ),
     "scatter_kv": functools.partial(probe_scatter, 16),
     "scatter_kv_int8": functools.partial(probe_scatter, 32, int8=True),
+}
+
+
+#: the docqa cells' chunk call at its real size: 2048 query rows at offset 2048
+#: over Mistral's and Mixtral's heads (32 over 8, width 128) and DeepSeek-V2's
+#: (128 heads, q/k 192 wide, values 128) — does Mosaic take the tiles the
+#: forward chooses inside VMEM, and are they right? Too large for the
+#: interpreter: ``chip_smoke.py``'s kernel leg runs them on the chip, and
+#: tests/test_tpu_compile.py compiles the same calls for the v5e.
+CELL_FLASH_PROBES: dict[str, Callable[[], dict]] = {
+    "docqa_flash_chunk_gqa": functools.partial(
+        probe_flash_chunked, 1, 32, 8, 4096, 128, 2048
+    ),
+    "docqa_flash_chunk_mla": functools.partial(
+        probe_flash_chunked, 1, 128, 128, 4096, 192, 2048, Dv=128,
+        ref_kv_heads=8,
+    ),
 }
 
 

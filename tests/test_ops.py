@@ -115,6 +115,111 @@ class TestFlashAttention:
             atol=2e-5,
         )
 
+    @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+    @pytest.mark.parametrize("chunk", [384, 640])
+    @pytest.mark.parametrize("offset_chunks", [0, 1])
+    @pytest.mark.parametrize(
+        "Hq,Hkv,D,Dv", [(8, 2, 128, 128), (4, 4, 192, 128)]
+    )
+    def test_chunked_tiles_match_reference(
+        self, jax, jnp, Hq, Hkv, D, Dv, offset_chunks, chunk, dtype
+    ):
+        """The chosen tiles against ``reference.attention_chunked`` at the
+        cells' head geometries (GQA 4:1 at width 128; 192-wide q/k over
+        128-wide values) and at lengths the larger tiles do not divide: 384
+        and 640 query rows fall to tiles of 128 over key blocks of 128 or
+        256, so at an offset of one chunk the first query tile walks whole
+        key blocks, the block the diagonal crosses, and masked ones."""
+        from modal_examples_tpu.ops import flash_attention_chunked, reference
+        from modal_examples_tpu.ops.flash_attention import choose_blocks
+
+        dt = jnp.dtype(dtype)
+        q_offset = offset_chunks * chunk
+        Skv = q_offset + chunk
+        bq, bk = choose_blocks(chunk, Skv, D, Dv, dt.itemsize)
+        assert chunk // bq > 1 and Skv // bk > 1  # several tiles both ways
+        if q_offset:
+            first_tile_last = (q_offset + bq - 1) // bk
+            assert 0 < first_tile_last < Skv // bk - 1  # whole, crossed, masked
+        ks = jax.random.split(jax.random.PRNGKey(5), 3)
+        q = jax.random.normal(ks[0], (1, Hq, chunk, D), dt)
+        k = jax.random.normal(ks[1], (1, Hkv, Skv, D), dt)
+        v = jax.random.normal(ks[2], (1, Hkv, Skv, Dv), dt)
+        out = flash_attention_chunked(q, k, v, q_offset=q_offset)
+        want = reference.attention_chunked(q, k, v, q_offset=q_offset)
+        assert out.shape == want.shape and out.dtype == dt
+        want = np.asarray(want.astype(jnp.float32))
+        # float32: today's bound. bf16: one ulp at the output's scale (the
+        # kernel rounds exp(s - m) to bf16 before the second dot and divides
+        # by the row sum after it; the reference rounds the normalised p)
+        atol = 2e-5 if dtype == "float32" else float(
+            jnp.finfo(jnp.bfloat16).eps
+        ) * float(np.abs(want).max())
+        np.testing.assert_allclose(
+            np.asarray(out.astype(jnp.float32)), want, atol=atol
+        )
+
+    @pytest.mark.parametrize("causal", [True, False])
+    def test_lse_is_logsumexp_across_tiles(self, jax, jnp, causal):
+        """384 rows are three query tiles over three key blocks: the row
+        statistics carried from block to block give the whole row's lse."""
+        from modal_examples_tpu.ops import flash_attention_with_lse, reference
+        from modal_examples_tpu.ops.flash_attention import choose_blocks
+
+        assert choose_blocks(384, 384, 64, 64, 4) == (128, 128)
+        ks = jax.random.split(jax.random.PRNGKey(6), 3)
+        q = jax.random.normal(ks[0], (1, 4, 384, 64))
+        k = jax.random.normal(ks[1], (1, 2, 384, 64))
+        v = jax.random.normal(ks[2], (1, 2, 384, 64))
+        o, lse = flash_attention_with_lse(q, k, v, causal=causal)
+        want_o, want_lse = reference.attention_with_lse(q, k, v, causal=causal)
+        np.testing.assert_allclose(np.asarray(lse), np.asarray(want_lse), atol=1e-4)
+        np.testing.assert_allclose(np.asarray(o), np.asarray(want_o), atol=2e-5)
+
+    @pytest.mark.parametrize(
+        "Sq,Skv,D,Dv",
+        [
+            (2048, 4096, 128, 128),  # Mistral / Mixtral chunk at offset 2048
+            (2048, 2048, 128, 128),  # ... at offset 0
+            (2048, 4096, 192, 128),  # DeepSeek-V2 chunk at offset 2048
+            (2048, 2048, 192, 128),  # ... at offset 0
+            (256, 256, 128, 128),  # the reason cells' bucket call
+        ],
+    )
+    def test_chosen_tiles_divide_and_fit(self, Sq, Skv, D, Dv):
+        """Pure Python: at the cells' five shapes (bf16) the chooser returns
+        divisors of the lengths whose footprint is under the budget it
+        states; a bucket call keeps a tile of its own length."""
+        from modal_examples_tpu.ops.flash_attention import (
+            _VMEM_BUDGET,
+            _VMEM_LIMIT,
+            block_footprint,
+            choose_blocks,
+        )
+
+        bq, bk = choose_blocks(Sq, Skv, D, Dv, 2)
+        assert Sq % bq == 0 and Skv % bk == 0
+        assert block_footprint(bq, bk, D, Dv, 2) <= _VMEM_BUDGET
+        assert _VMEM_BUDGET < _VMEM_LIMIT <= 64 * 2**20
+        if Sq >= 2048:  # far fewer steps than 128 x 128 took
+            assert bq * bk >= 16 * 128 * 128
+        else:
+            assert (bq, bk) == (Sq, Skv)
+        # a tighter budget falls to smaller divisors, never to a non-divisor
+        sq, sk = choose_blocks(Sq, Skv, D, Dv, 2, budget=2**20)
+        assert Sq % sq == 0 and Skv % sk == 0 and sq * sk <= bq * bk
+
+    @pytest.mark.parametrize(
+        "S,want", [(384, 128), (640, 128), (768, 256), (200, 200), (48, 48), (12, 12)]
+    )
+    def test_chosen_tile_of_odd_lengths(self, S, want):
+        """Lengths no power-of-two tile divides: the 128-multiples fall to
+        the largest 128 * 2^i that divides them, short or ragged ones (tests
+        and tiny models only) keep one block."""
+        from modal_examples_tpu.ops.flash_attention import choose_blocks
+
+        assert choose_blocks(S, S, 64, 64, 4) == (want, want)
+
     def test_rejects_ragged_seq(self, jax, jnp):
         from modal_examples_tpu.ops import flash_attention
 

@@ -11,6 +11,7 @@ from pathlib import Path
 import pytest
 
 from modal_examples_tpu.ops.probes import (
+    CELL_FLASH_PROBES,
     KERNEL_PROBES,
     PROBED_MODULES,
     model_geometry_probes,
@@ -54,6 +55,23 @@ class TestProbesRun:
     def test_full_registry_green(self):
         for name, probe in KERNEL_PROBES.items():
             assert probe(), name
+
+    def test_cell_flash_probes_at_a_small_geometry(self):
+        """The docqa chunk probes' own function at the two head layouts
+        (grouped at width 128; ungrouped, 192-wide q/k over 128-wide values,
+        the reference held to some heads), small enough for the interpreter
+        and still several tiles long."""
+        from modal_examples_tpu.ops.probes import probe_flash_chunked
+
+        assert set(CELL_FLASH_PROBES) == {
+            "docqa_flash_chunk_gqa", "docqa_flash_chunk_mla",
+        }
+        for probe in CELL_FLASH_PROBES.values():
+            assert probe.func is probe_flash_chunked
+        assert probe_flash_chunked(1, 8, 2, 768, 128, 384)["max_err"] < 0.06
+        assert probe_flash_chunked(
+            1, 4, 4, 768, 192, 384, Dv=128, ref_kv_heads=2
+        )["max_err"] < 0.06
 
     def test_model_geometry_probes_at_a_small_geometry(self):
         """The cases chip_smoke adds at the smoke model's shapes, at a
