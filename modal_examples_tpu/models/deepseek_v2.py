@@ -55,6 +55,8 @@ from ..ops import scopes as _scopes
 from ..ops.flash_attention import flash_attention_chunked
 from . import deepseek_v2_reference as _ref
 from . import layers
+from .layers import refuse
+from .layers import scatter_rows as _scatter_rows
 from . import moe as _moe
 
 
@@ -238,17 +240,6 @@ class DeepseekV2Config:
             norm_eps=cfg.get("rms_norm_eps", 1e-6),
             max_seq_len=cfg.get("max_position_embeddings", 4096),
             tie_embeddings=cfg.get("tie_word_embeddings", False),
-        )
-
-
-def refuse(cfg, feature: str) -> None:
-    """Raise, by name, if ``cfg``'s programs do not implement ``feature``
-    (one of its ``unsupported``). A config with no such list refuses nothing."""
-    if feature in getattr(cfg, "unsupported", ()):
-        raise NotImplementedError(
-            f"{type(cfg).__name__} does not support {feature} yet "
-            f"(models/{cfg.model.__name__.rpartition('.')[2]}.py refuses: "
-            f"{', '.join(cfg.unsupported)})"
         )
 
 
@@ -477,18 +468,6 @@ def _scan_layers(params, cfg, layer_fn, x, per_layer=None):
             ys.append(y)
             first += n
     return x, jax.tree.map(lambda *a: jnp.concatenate(a, axis=0), *ys)
-
-
-@jax.named_scope(_scopes.KV_SCATTER)
-def _scatter_rows(pages, rows, page_idx, slot):
-    """Write ``rows`` [L, ..., 1, w] (every layer's new cache rows of the
-    tokens at ``page_idx`` / ``slot`` [...]) into ``pages`` [L, P, ps, 1, w].
-    The layer index is spelt out beside the page and the slot: with the
-    layers as a slice (``pages.at[:, page_idx, slot]``) the TPU compiler
-    lays the whole cache out layers-minor for the scatter and copies it in
-    and out of every call (1.5 GiB each way at the benchmark's size)."""
-    layer = jnp.arange(pages.shape[0]).reshape(-1, *(1,) * page_idx.ndim)
-    return pages.at[layer, page_idx[None], slot[None]].set(rows.astype(pages.dtype))
 
 
 def _logits(params, x, cfg):

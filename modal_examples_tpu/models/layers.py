@@ -14,7 +14,7 @@ import jax
 import jax.numpy as jnp
 
 from ..ops import flash_attention
-from ..ops.scopes import DENSE_MLP
+from ..ops.scopes import DENSE_MLP, KV_SCATTER
 
 
 def rms_norm(x: jax.Array, weight: jax.Array, eps: float = 1e-5) -> jax.Array:
@@ -214,3 +214,29 @@ def init_dense(key, shape, scale: float | None = None, dtype=jnp.bfloat16):
     # sample directly in the target dtype: a 7B bf16 init must never
     # materialize an f32 copy (2x HBM) on a 16GB chip
     return jax.random.normal(key, shape, dtype) * jnp.asarray(scale, dtype)
+
+
+# -- shared by the model files behind the engine's seam (docs/mla.md) -------------
+
+
+def refuse(cfg, feature: str) -> None:
+    """Raise, by name, if ``cfg``'s programs do not implement ``feature``
+    (one of its ``unsupported``). A config with no such list refuses nothing."""
+    if feature in getattr(cfg, "unsupported", ()):
+        raise NotImplementedError(
+            f"{type(cfg).__name__} does not support {feature} yet "
+            f"(models/{cfg.model.__name__.rpartition('.')[2]}.py refuses: "
+            f"{', '.join(cfg.unsupported)})"
+        )
+
+
+@jax.named_scope(KV_SCATTER)
+def scatter_rows(pages, rows, page_idx, slot):
+    """Write ``rows`` [L, ..., h, w] (every layer's new cache rows of the
+    tokens at ``page_idx`` / ``slot`` [...]) into ``pages`` [L, P, ps, h, w].
+    The layer index is spelt out beside the page and the slot: with the
+    layers as a slice (``pages.at[:, page_idx, slot]``) the TPU compiler
+    lays the whole cache out layers-minor for the scatter and copies it in
+    and out of every call (1.5 GiB each way at the benchmark's size)."""
+    layer = jnp.arange(pages.shape[0]).reshape(-1, *(1,) * page_idx.ndim)
+    return pages.at[layer, page_idx[None], slot[None]].set(rows.astype(pages.dtype))

@@ -88,6 +88,14 @@ DEEPSEEK_V2_TARGETS = (
 )
 
 
+#: ... and in a Granite hybrid tree (models/granite_hybrid.py): the Mamba-2
+#: mixer's two projections, the attention projections and the SwiGLU; the
+#: convolution, the norms and the per-head ``A_log`` / ``dt_bias`` / ``D`` stay
+GRANITE_HYBRID_TARGETS = (
+    "in_z", "in_xbc", "in_dt", "out_proj", "wq", "wk", "wv", "wo", "gate", "up", "down",
+)
+
+
 def bits_of(quantization: str) -> int:
     if quantization not in ("int8", "int4"):
         raise ValueError(f"unknown quantization {quantization!r}")

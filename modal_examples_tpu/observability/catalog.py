@@ -72,6 +72,15 @@ PREFILL_PREFIX_POSITIONS_TOTAL = "mtpu_prefill_prefix_positions_total"
 #: block's tokens at its harvest. Only a model that holds a share of its
 #: experts reports it
 ROUTED_PAIRS_TOTAL = "mtpu_routed_pairs_total"
+#: counter {kind}: rows of the cache's per-slot leaves (a recurrent layer's
+#: state, one row a slot and layer) that the decode blocks' steps read and
+#: wrote, counted at each block dispatch from what the host knows: kind =
+#: stepped (max_slots x steps: a step runs over every slot) | live (slots
+#: holding a running sequence x steps). Only a model with per-slot state
+#: reports it (docs/recurrent_state.md)
+STATE_ROWS_TOTAL = "mtpu_state_rows_total"
+#: gauge: device bytes of the cache's per-slot leaves (0: a model with none)
+STATE_BYTES = "mtpu_state_bytes"
 #: gauge: requests waiting for admission (engine queue depth)
 WAITING_REQUESTS = "mtpu_waiting_requests"
 #: gauge: slots currently decoding
@@ -556,6 +565,17 @@ CATALOG: dict[str, dict] = {
         "labels": ["where"],
         "help": "(token, expert) pairs routed in decode blocks (where=held: "
                 "on an expert this chip holds | elsewhere: another share's)",
+    },
+    STATE_ROWS_TOTAL: {
+        "type": "counter",
+        "labels": ["kind"],
+        "help": "per-slot state rows per decode step at block dispatch (kind="
+                "stepped: every slot | live: slots holding a running sequence)",
+    },
+    STATE_BYTES: {
+        "type": "gauge",
+        "labels": [],
+        "help": "device bytes of the cache's per-slot (recurrent state) leaves",
     },
     WAITING_REQUESTS: {
         "type": "gauge",

@@ -135,6 +135,26 @@ def record_decode_kv_positions(
         )
 
 
+def record_state_rows(
+    stepped: int, live: int, *, registry: Registry | None = None
+) -> None:
+    """One decode-block dispatch of a model with per-slot state: the slot
+    rows its steps read and wrote, and those of a running sequence."""
+    reg = _reg(registry)
+    for kind, n in (("stepped", stepped), ("live", live)):
+        reg.counter_inc(
+            C.STATE_ROWS_TOTAL, float(n), labels={"kind": kind},
+            help=C.CATALOG[C.STATE_ROWS_TOTAL]["help"],
+        )
+
+
+def set_state_bytes(nbytes: int, *, registry: Registry | None = None) -> None:
+    """The cache's per-slot leaves, in device bytes (0: none)."""
+    _reg(registry).gauge_set(
+        C.STATE_BYTES, float(nbytes), help=C.CATALOG[C.STATE_BYTES]["help"],
+    )
+
+
 def record_prefill_prefix_positions(
     positions: int, *, registry: Registry | None = None
 ) -> None:

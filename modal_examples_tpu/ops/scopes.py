@@ -21,9 +21,12 @@ KV_SCATTER = "mtpu.kv_scatter"  # new K/V rows -> cache pages
 SAMPLING = "mtpu.sampling"  # logits -> next token
 LATENT_EXPAND = "mtpu.latent_expand"  # MLA latents -> per-head keys and values
 EXPERT_DISPATCH = "mtpu.expert_dispatch"  # routed pairs sorted to tiles and back
+SSM_PROJ = "mtpu.ssm_proj"  # a Mamba-2 mixer's in_proj, gated norm and out_proj
+SSM_SCAN = "mtpu.ssm_scan"  # prefill: the causal convolution and the chunked scan
+SSM_STEP = "mtpu.ssm_step"  # decode: convolution shift, one state update, output
 
 ALL = (
     PAGE_GATHER, ATTENTION, DENSE_MLP, ROUTER, EXPERT_SCAN, KV_SCATTER,
-    SAMPLING, LATENT_EXPAND, EXPERT_DISPATCH,
+    SAMPLING, LATENT_EXPAND, EXPERT_DISPATCH, SSM_PROJ, SSM_SCAN, SSM_STEP,
 )
 

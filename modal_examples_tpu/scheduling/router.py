@@ -81,7 +81,7 @@ class EngineReplica:
         if role not in ROLES:
             raise ValueError(f"unknown replica role {role!r}; one of {ROLES}")
         if role != "unified" and hasattr(engine, "cfg"):
-            from ..models.deepseek_v2 import refuse
+            from ..models.layers import refuse
 
             refuse(engine.cfg, "disaggregated transfer")
         self.engine = engine

@@ -39,8 +39,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..models import deepseek_v2, llama
-from ..models.deepseek_v2 import refuse as _refuse
+from ..models import deepseek_v2, granite_hybrid, llama
+from ..models.layers import refuse as _refuse
 from ..observability import incident as _incident
 from ..observability import metrics as _obs
 from ..observability import profiler as _profiler
@@ -348,6 +348,17 @@ def _req_seed(req: "Request") -> int:
     return req.auto_seed if req.auto_seed is not None else -1
 
 
+def _program_outputs(out, stateful: bool):
+    """``(logits, k_pages, v_pages, state, counts)`` of what one of a model's
+    programs returned. The order is the seam's (docs/mla.md): logits and the
+    two paged leaves; then the per-slot state, from a program that was handed
+    ``state=``, and only from one; then whatever it counts (a list, empty for
+    most). A program with neither returns the three, as it always has."""
+    logits, k_pages, v_pages, *rest = out
+    state = rest.pop(0) if stateful else ()
+    return logits, k_pages, v_pages, state, rest
+
+
 def _shard_params(params, cfg, mesh):
     """Place a llama param tree with its Megatron partition specs — one
     implementation for target and draft so the paths can't drift.
@@ -397,6 +408,7 @@ MODEL_PRESETS = {
     "tiny": llama.LlamaConfig.tiny,
     "tiny-moe": llama.LlamaConfig.tiny_moe,
     "tiny-deepseek-v2": deepseek_v2.DeepseekV2Config.tiny,
+    "tiny-granite-hybrid": granite_hybrid.GraniteHybridConfig.tiny,
 }
 
 
@@ -526,6 +538,7 @@ class LLMEngine:
             (mesh is not None, "tensor parallelism"),
             (vision is not None, "vision"),
             (bool(tiered_prefix), "disaggregated transfer"),
+            (bool(enable_prefix_cache), "prefix caching"),
             (
                 self.paged_impl != "xla" or self.scatter_impl != "xla",
                 "a Pallas paged_impl or scatter_impl",
@@ -600,13 +613,19 @@ class LLMEngine:
         self.pages_per_slot = (max_model_len + page_size - 1) // page_size
         if n_pages is None:
             n_pages = 1 + max_slots * self.pages_per_slot
+        # the paged leaves cover the layers that keep K/V per token (all of
+        # them unless the model says otherwise); a model with per-sequence
+        # state declares per-slot leaves beside them (docs/recurrent_state.md)
         self.cache = PagedKVCache.create(
-            n_layers=cfg.n_layers,
+            n_layers=getattr(cfg, "n_cache_layers", cfg.n_layers),
             leaf_shapes=cfg.cache_leaf_shapes,
             n_pages=n_pages,
             page_size=page_size,
             kv_dtype=kv_dtype,
+            state_leaves=getattr(cfg, "state_leaves", ()),
+            max_slots=max_slots,
         )
+        _obs.set_state_bytes(self.cache.state_bytes())
         if mesh is not None:
             self._shard_cache(self.cache)
         # what will ACTUALLY run for these shapes on this backend — a
@@ -825,7 +844,9 @@ class LLMEngine:
         # slot exists — feeds mtpu_decode_stall_seconds
         self._last_dispatch_at: float | None = None
 
-        self._block_jit = jax.jit(self._decode_block_fn, donate_argnums=(1, 2))
+        self._block_jit = jax.jit(
+            self._decode_block_fn, donate_argnums=(1, 2), donate_argnames=("state",)
+        )
         # macro-step decode runtime (serving/multistep, docs/multistep.md)
         from .multistep.runtime import resolve_decode_steps
 
@@ -1041,12 +1062,16 @@ class LLMEngine:
     def _decode_block_fn(
         self, params, k_pages, v_pages, prev_tokens, override, override_mask,
         positions, page_tables, active, key, temps, top_ps, top_ks, seeds,
+        state=(),
     ):
         """`decode_block` decode+sample steps in one program: tokens feed
         forward in-graph (lax.scan), so nothing crosses the host boundary
         between steps. ``prev_tokens`` is the previous block's device-resident
         output; freshly prefilled slots merge their host-known first token via
-        (override, override_mask). Returns (tokens [K, B], last [B], caches).
+        (override, override_mask). ``state``: the cache's per-slot leaves
+        (``()`` for a model with none: no argument of the program), row b of
+        each the slot b of the batch, carried through the steps beside the
+        pages. Returns (tokens [K, B], last [B], caches, state).
         """
         tok0 = jnp.where(override_mask, override, prev_tokens)
         # a model that counts its routed pairs hands them back beside the
@@ -1056,27 +1081,45 @@ class LLMEngine:
         counted = {"return_counts": True} if self._counts_routed else {}
 
         def body(carry, k_i):
-            tok, pos, kp, vp = carry
-            logits, kp, vp, *counts = self._model.decode_step(
-                params, tok, pos, kp, vp, page_tables, active, self.cfg,
-                impl=self.paged_impl, scatter_impl=self.scatter_impl,
-                mesh=self.mesh, **counted,
+            tok, pos, kp, vp, st = carry
+            stateful = {"state": st} if st else {}
+            logits, kp, vp, st, counts = _program_outputs(
+                self._model.decode_step(
+                    params, tok, pos, kp, vp, page_tables, active, self.cfg,
+                    impl=self.paged_impl, scatter_impl=self.scatter_impl,
+                    mesh=self.mesh, **counted, **stateful,
+                ),
+                bool(st),
             )
             nxt = sample(
                 logits, k_i, temps, top_ps, top_ks, seeds=seeds, step_ids=pos
             )
             nxt = jnp.where(active, nxt, tok)  # dead slots hold steady
-            return (nxt, pos + 1, kp, vp), (nxt, *counts)
+            return (nxt, pos + 1, kp, vp, st), (nxt, *counts)
 
-        (last, _, k_pages, v_pages), (toks, *counts) = jax.lax.scan(
+        (last, _, k_pages, v_pages, state), (toks, *counts) = jax.lax.scan(
             body,
-            (tok0, positions, k_pages, v_pages),
+            (tok0, positions, k_pages, v_pages, state),
             jax.random.split(key, self.decode_block),
         )
         for c in counts:
             rows = jnp.broadcast_to(c.sum(axis=0)[:, None], (2, toks.shape[1]))
             toks = jnp.concatenate([toks, rows.astype(toks.dtype)], axis=0)
-        return toks, last, k_pages, v_pages
+        return toks, last, k_pages, v_pages, state
+
+    def _state_args(self, slots: list[int] | None = None, rows: int = 0) -> dict:
+        """The keyword arguments that hand a program the cache's per-slot
+        leaves: ``state`` and, for a prefill call of ``rows`` rows whose
+        first ones fill ``slots``, the rows' ``slot_ids`` (a row with no slot
+        gets ``max_slots``: written nowhere). None for a model with no such
+        state: its programs are called as they always were."""
+        if not self.cache.state:
+            return {}
+        if slots is None:
+            return {"state": self.cache.state}
+        ids = np.full((rows,), self.max_slots, np.int32)
+        ids[: len(slots)] = slots
+        return {"state": self.cache.state, "slot_ids": jnp.asarray(ids)}
 
     def _count_decode_kv(self, positions, active, steps: int) -> None:
         """What the ``steps`` decode steps of one dispatch read of the KV
@@ -1144,21 +1187,28 @@ class LLMEngine:
 
     def _prefill_and_sample(
         self, params, k_pages, v_pages, tokens, page_tables, seq_lens, key,
-        temps, top_ps, top_ks, seeds,
+        temps, top_ps, top_ks, seeds, state=(), slot_ids=None,
     ):
-        logits, k_pages, v_pages = self._model.prefill(
-            params, tokens, k_pages, v_pages, page_tables, seq_lens, self.cfg,
-            attn_impl=self._attn_impl, mesh=self.mesh,
+        stateful = {"state": state, "slot_ids": slot_ids} if state else {}
+        logits, k_pages, v_pages, state, _ = _program_outputs(
+            self._model.prefill(
+                params, tokens, k_pages, v_pages, page_tables, seq_lens, self.cfg,
+                attn_impl=self._attn_impl, mesh=self.mesh, **stateful,
+            ),
+            bool(state),
         )
         next_tokens = sample(
             logits, key, temps, top_ps, top_ks, seeds=seeds, step_ids=seq_lens
         )
-        return next_tokens, k_pages, v_pages
+        return next_tokens, k_pages, v_pages, state
 
     def _prefill_jit(self, bucket: int):
         fn = self._prefill_jits.get(bucket)
         if fn is None:
-            fn = jax.jit(self._prefill_and_sample, donate_argnums=(1, 2))
+            fn = jax.jit(
+                self._prefill_and_sample, donate_argnums=(1, 2),
+                donate_argnames=("state",),
+            )
             self._prefill_jits[bucket] = fn
         return fn
 
@@ -1169,17 +1219,26 @@ class LLMEngine:
         if fn is None:
             attn_impl, mesh = self._attn_impl, self.mesh
 
-            def prefill_chunk(params, toks, k_pages, v_pages, tables, lens, *, cfg):
+            def prefill_chunk(
+                params, toks, k_pages, v_pages, tables, lens, state=(),
+                slot_ids=None, *, cfg,
+            ):
                 # cfg is the target's or the draft's: each names its module
-                return cfg.model.prefill_chunk(
-                    params, toks, k_pages, v_pages, tables, lens, cfg=cfg,
-                    q_offset=offset, attn_impl=attn_impl, mesh=mesh,
+                stateful = {"state": state, "slot_ids": slot_ids} if state else {}
+                logits, k_pages, v_pages, state, _ = _program_outputs(
+                    cfg.model.prefill_chunk(
+                        params, toks, k_pages, v_pages, tables, lens, cfg=cfg,
+                        q_offset=offset, attn_impl=attn_impl, mesh=mesh, **stateful,
+                    ),
+                    bool(state),
                 )
+                return logits, k_pages, v_pages, state
 
             # the compiled program's name in a device trace: one per offset
             prefill_chunk.__name__ = f"prefill_chunk_off{offset}"
             fn = jax.jit(
-                prefill_chunk, static_argnames=("cfg",), donate_argnums=(2, 3)
+                prefill_chunk, static_argnames=("cfg",), donate_argnums=(2, 3),
+                donate_argnames=("state",),
             )
             self._chunk_jits[offset] = fn
         return fn
@@ -1472,7 +1531,9 @@ class LLMEngine:
             # warmup shares the dispatch sites' (program, shape_key) space:
             # boot-time builds land in the compile ledger once, and the
             # live path then records cache hits instead of re-timing
-            _tok, self.cache.k_pages, self.cache.v_pages = self._profiled(
+            (
+                _tok, self.cache.k_pages, self.cache.v_pages, self.cache.state,
+            ) = self._profiled(
                 "prefill", f"b{bucket}x{B}", self._prefill_jit((bucket, B))
             )(
                 self.params,
@@ -1486,6 +1547,7 @@ class LLMEngine:
                 jnp.ones((B,), jnp.float32),
                 jnp.zeros((B,), jnp.int32),
                 jnp.full((B,), -1, jnp.int32),
+                **self._state_args([], B),
             )
         if self.vision_cfg is not None:
             # one compiled multimodal prefill shape: the bucket that fits
@@ -1515,7 +1577,10 @@ class LLMEngine:
         # the block program warms for EVERY engine: spec engines run it
         # too — whole-round γ=0 fallbacks (pressure/collapse) and the
         # failover replay path both dispatch it
-        _toks, _last, self.cache.k_pages, self.cache.v_pages = self._profiled(
+        (
+            _toks, _last, self.cache.k_pages, self.cache.v_pages,
+            self.cache.state,
+        ) = self._profiled(
             "block", f"s{self.max_slots}k{self.decode_block}",
             self._block_jit,
         )(
@@ -1533,6 +1598,7 @@ class LLMEngine:
             jnp.ones((B,), jnp.float32),
             jnp.zeros((B,), jnp.int32),
             jnp.full((B,), -1, jnp.int32),
+            **self._state_args(),
         )
         n_ms = max(1, int(self.decode_steps))
         if n_ms > 1:
@@ -2824,7 +2890,8 @@ class LLMEngine:
             self._spec_ctrl.forget(slot.request.request_id)
 
     def _dispatch_prefill_chunk(
-        self, prompt_tokens: list, table, offset: int, cached: int = 0
+        self, prompt_tokens: list, table, offset: int, cached: int = 0,
+        slot_idx: int | None = None,
     ) -> "jax.Array":
         """Dispatch ONE bucket-sized prefill chunk (async — the logits come
         back as a device future, nothing blocks the host): the unit both
@@ -2832,7 +2899,9 @@ class LLMEngine:
         machine (``_advance_pending_prefills``) advance by, so the two
         paths can never drift. ``cached`` is how many leading prompt tokens
         sit on cached pages (computed again all the same: the count at the
-        prefill boundary says so)."""
+        prefill boundary says so). ``slot_idx``: the slot the prompt fills; a
+        model with per-slot state starts the chunk at offset 0 from zeros and
+        a later one from what the chunk before it left in that slot."""
         C = self.prefill_buckets[-1]
         pad_tok = self.tokenizer.pad_id % self.cfg.vocab_size
         chunk = prompt_tokens[offset : offset + C]
@@ -2845,7 +2914,9 @@ class LLMEngine:
         if offset:
             _obs.record_prefill_prefix_positions(offset)
         fn = self._chunk_jit(offset)
-        logits, self.cache.k_pages, self.cache.v_pages = self._profiled(
+        (
+            logits, self.cache.k_pages, self.cache.v_pages, self.cache.state,
+        ) = self._profiled(
             "prefill_chunk", f"off{offset}", fn
         )(
             self.params,
@@ -2854,13 +2925,16 @@ class LLMEngine:
             self.cache.v_pages,
             jnp.asarray(table[None, :]),
             jnp.asarray([len(chunk)], np.int32),
+            **self._state_args([] if slot_idx is None else [slot_idx], 1),
             cfg=self.cfg,
         )
         if self.spec_mode == "draft":
             # the same cached jit serves the draft: cfg is a static call
             # argument, so target and draft get separate compile-cache
             # entries under one callable
-            _, self.draft_cache.k_pages, self.draft_cache.v_pages = self._profiled(
+            (
+                _, self.draft_cache.k_pages, self.draft_cache.v_pages, _,
+            ) = self._profiled(
                 "draft_prefill", f"chunk-off{offset}", fn
             )(
                 self.draft_params,
@@ -2937,7 +3011,9 @@ class LLMEngine:
         _obs.record_prefill_positions(
             computed=B * bucket, needed=n_prompt - req.cached_prompt_tokens
         )
-        next_tok, self.cache.k_pages, self.cache.v_pages = self._profiled(
+        (
+            next_tok, self.cache.k_pages, self.cache.v_pages, self.cache.state,
+        ) = self._profiled(
             "prefill", f"b{bucket}x{B}", self._prefill_jit((bucket, B))
         )(
             self.params,
@@ -2951,6 +3027,7 @@ class LLMEngine:
             jnp.asarray(top_ps),
             jnp.asarray(top_ks),
             jnp.asarray(seeds),
+            **self._state_args([], B),
         )
         t0 = self._harvest_begin()
         first = int(np.asarray(next_tok)[0])
@@ -3016,7 +3093,7 @@ class LLMEngine:
                 ):
                     pp.logits = self._dispatch_prefill_chunk(
                         pp.req.prompt_tokens, pp.table, pp.offset,
-                        pp.req.cached_prompt_tokens,
+                        pp.req.cached_prompt_tokens, slot_idx=i,
                     )
                     step = min(C, n_prompt - pp.offset)
                     pp.offset += step
@@ -3217,7 +3294,10 @@ class LLMEngine:
             override[slot_idx] = int(tok)
             positions[slot_idx] = base_pos + i
             self._count_decode_kv(positions, active, self.decode_block)
-            _toks, _last, self.cache.k_pages, self.cache.v_pages = (
+            (
+                _toks, _last, self.cache.k_pages, self.cache.v_pages,
+                self.cache.state,
+            ) = (
                 self._profiled(
                     "block", f"s{self.max_slots}k{self.decode_block}",
                     self._block_jit,
@@ -3236,6 +3316,7 @@ class LLMEngine:
                     ones,
                     zeros_i,
                     no_seed,
+                    **self._state_args(),
                 )
             )
 
@@ -3351,7 +3432,10 @@ class LLMEngine:
                 )
             )
         else:
-            next_tok, self.cache.k_pages, self.cache.v_pages = self._profiled(
+            (
+                next_tok, self.cache.k_pages, self.cache.v_pages,
+                self.cache.state,
+            ) = self._profiled(
                 "prefill", f"b{bucket}x{B}", self._prefill_jit((bucket, B))
             )(
                 self.params,
@@ -3365,6 +3449,10 @@ class LLMEngine:
                 jnp.asarray(top_ps),
                 jnp.asarray(top_ks),
                 jnp.asarray(seeds),
+                # a row's recurrent state, from zeros, goes to its slot
+                **self._state_args(
+                    [slot_idx for slot_idx, _req, _claim in group], B
+                ),
             )
         if self.spec_mode == "draft":
             # fill the draft model's cache over the same pages (same tables:
@@ -3573,7 +3661,10 @@ class LLMEngine:
         n = max(1, int(self.decode_steps))  # runtime-mutable: read ONCE
         if n <= 1:
             # classic pipelined block: byte-identical fall-through
-            toks, last, self.cache.k_pages, self.cache.v_pages = self._profiled(
+            (
+                toks, last, self.cache.k_pages, self.cache.v_pages,
+                self.cache.state,
+            ) = self._profiled(
                 "block", f"s{self.max_slots}k{self.decode_block}",
                 self._block_jit,
             )(
@@ -3591,6 +3682,7 @@ class LLMEngine:
                 jnp.asarray(self._top_ps.copy()),
                 jnp.asarray(self._top_ks.copy()),
                 jnp.asarray(self._seeds.copy()),
+                **self._state_args(),
             )
             valid = None
             n = self.decode_block
@@ -3632,6 +3724,12 @@ class LLMEngine:
                 jnp.asarray(budgets),
             )
         self._count_decode_kv(self._positions, self._active, n)
+        if self.cache.state:
+            # the per-slot state a block's steps read and write: every slot's
+            # rows each step, those of a running sequence among them
+            _obs.record_state_rows(
+                stepped=self.max_slots * n, live=len(live) * n
+            )
         self._device_tokens = last
         # snapshot pins (slot, request, tenancy): request identity alone is
         # not enough — a failover-resumed request is the same object back

@@ -108,6 +108,12 @@ class PagedKVCache:
     v_pages: object
     page_size: int
     allocator: PageAllocator
+    # per-slot leaves ``[layers, max_slots, *shape]``: state a model keeps per
+    # *sequence*, not per token (a recurrent layer's: docs/recurrent_state.md).
+    # No page axis: a row is addressed by the slot's index, written by the
+    # prefill that fills the slot and by every decode step, and means nothing
+    # once the slot is free. A model that declares none has none
+    state: tuple = ()
 
     @classmethod
     def create(
@@ -126,6 +132,10 @@ class PagedKVCache:
         kv_dtype=None,  # "int8" | jnp dtype; the canonical spelling
         dtype=None,  # legacy alias for kv_dtype (kept for callers)
         prefer_native: bool = True,
+        # a configuration's ``state_leaves``: ``(layers, per-slot shape,
+        # dtype)`` for each per-slot leaf, made for ``max_slots`` slots
+        state_leaves: tuple = (),
+        max_slots: int = 0,
     ) -> "PagedKVCache":
         if kv_dtype is not None and dtype is not None:
             raise ValueError("pass kv_dtype= or dtype=, not both")
@@ -152,6 +162,10 @@ class PagedKVCache:
             v_pages=kv_empty(v_shape, kv_dtype),
             page_size=page_size,
             allocator=allocator or PageAllocator(n_pages),
+            state=tuple(
+                jnp.zeros((layers, max_slots, *shape), dtype)
+                for layers, shape, dtype in state_leaves
+            ),
         )
 
     @property
@@ -183,6 +197,10 @@ class PagedKVCache:
         occupancy gauges and bench.py's ``kv_cache`` section report.
         (``nbytes`` is a property on QuantizedKV and jax.Array alike.)"""
         return self.k_pages.nbytes + self.v_pages.nbytes
+
+    def state_bytes(self) -> int:
+        """Device bytes of the per-slot leaves (0 for a model with none)."""
+        return sum(leaf.nbytes for leaf in self.state)
 
     def pages_for(self, n_tokens: int) -> int:
         return (n_tokens + self.page_size - 1) // self.page_size
@@ -219,8 +237,10 @@ class PagedKVCache:
 # state, no __eq__) — do NOT pass a whole cache as a jit argument; every
 # distinct allocator would be a distinct static key (silent retraces).
 # Jitted programs take cache.k_pages / cache.v_pages, as the engine does.
+# The per-slot leaves (``state``) have no page axis and are not on the wire:
+# a model that declares them refuses disaggregated transfer.
 jax.tree_util.register_dataclass(
     PagedKVCache,
-    data_fields=("k_pages", "v_pages"),
+    data_fields=("k_pages", "v_pages", "state"),
     meta_fields=("page_size", "allocator"),
 )
