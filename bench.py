@@ -1113,8 +1113,8 @@ def _measure_fleet(engine, spec: dict, make_engine) -> dict:
         eng = make_engine(params=params)
         # compile-cache hits (the primary compiled the same shapes): the
         # replica joins the fleet jitted, not paying first-request
-        # compiles. warmup() skips the chunk-offset jits long prompts hit,
-        # so serve one short and one chunking prompt before placement too.
+        # compiles (warmup() starts building the chunk programs long prompts
+        # hit, too). One short and one chunking prompt before placement run them.
         eng.warmup()
         eng.start()
         for warm_prompt in ("warm " * 8, "boot warm long prompt " * 12):

@@ -6,7 +6,8 @@
 ms a call (bf16, causal) of ``ops.flash_attention._flash_forward`` for the
 chunk calls of the docqa cells (Mistral / Mixtral: 32 heads over 8, width
 128; DeepSeek-V2: 128 heads, q/k 192 over values 128; 2048 query rows at
-offset 2048 and 0) and the reason cells' 4 x 256 bucket call, with the tiles
+offset 2048 and 0; the tail chunks of 128-1024 rows over 2048 cached keys)
+and the reason cells' 4 x 256 bucket call, with the tiles
 the kernel chooses and with each of ``--tiles``. With ``--parent`` the same
 calls through that checkout's kernel, before and after, and the largest
 difference between the two outputs. Needs the chip: a time from the
@@ -33,6 +34,15 @@ SHAPES = {
     "mla_off0": ((1, 128, 2048, 192), (1, 128, 2048, 192), (1, 128, 2048, 128), 0),
     "bucket_4x256": ((4, 32, 256, 128), (4, 8, 256, 128), (4, 8, 256, 128), 0),
 }
+# the last chunk of a docqa prompt (PR 33): w query rows over 2048 cached keys
+# and its own, w the bucket that holds what is left (2048: *_off2048 above)
+for _w in (128, 256, 512, 1024):
+    SHAPES[f"gqa_tail{_w}"] = (
+        (1, 32, _w, 128), (1, 8, 2048 + _w, 128), (1, 8, 2048 + _w, 128), 2048,
+    )
+    SHAPES[f"mla_tail{_w}"] = (
+        (1, 128, _w, 192), (1, 128, 2048 + _w, 192), (1, 128, 2048 + _w, 128), 2048,
+    )
 
 
 def main() -> int:

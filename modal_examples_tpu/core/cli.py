@@ -740,7 +740,7 @@ def cmd_profile(argv: list[str]) -> int:
         spec_tpd = merged.peak(C.SPEC_TOKENS_PER_DISPATCH) or None
         for labels, v in merged.series(C.COMPILES_TOTAL):
             entry = lookups.setdefault(
-                labels.get("program", "?"), {"hit": 0, "miss": 0}
+                labels.get("program", "?"), {"hit": 0, "miss": 0, "ahead": 0}
             )
             entry[labels.get("cache", "miss")] = int(v)
 
@@ -812,10 +812,14 @@ def cmd_profile(argv: list[str]) -> int:
             "(no engine has pushed yet, or it ran under MTPU_PROFILE=0)"
         )
     if lookups:
-        print("\ncompile-cache lookups per program (miss=fresh build):")
+        print(
+            "\ncompile-cache lookups per program "
+            "(miss=fresh build, ahead=built before any dispatch asked):"
+        )
         for program, entry in sorted(lookups.items()):
             print(
-                f"  {program:<16} miss={entry['miss']:<5} hit={entry['hit']}"
+                f"  {program:<16} miss={entry['miss']:<5} "
+                f"ahead={entry['ahead']:<5} hit={entry['hit']}"
             )
     if top:
         print(f"\ntop compiles ({len(builds)} ledgered builds):")

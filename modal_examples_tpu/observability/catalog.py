@@ -330,7 +330,9 @@ HOST_OVERHEAD_RATIO = "mtpu_host_overhead_ratio"
 COMPILE_SECONDS = "mtpu_compile_seconds"
 #: counter {program, cache}: program-cache lookups at the engine's jit
 #: dispatch sites; cache = miss (a fresh build — timed and appended to the
-#: <state_dir>/compiles.jsonl ledger) | hit (served already-compiled)
+#: <state_dir>/compiles.jsonl ledger) | hit (served already-compiled) |
+#: ahead (built off the dispatch path before any dispatch asked for it:
+#: ``HotPathProfiler.build``; timed and ledgered like a miss)
 COMPILES_TOTAL = "mtpu_compiles_total"
 
 # -- macro-step decode runtime (serving/multistep/, docs/multistep.md) -------
@@ -868,7 +870,8 @@ CATALOG: dict[str, dict] = {
     COMPILES_TOTAL: {
         "type": "counter", "labels": ["program", "cache"],
         "help": "program-cache lookups at jit dispatch sites "
-                "(cache=miss fresh build, ledgered | hit served compiled)",
+                "(cache=miss fresh build, ledgered | hit served compiled | "
+                "ahead built before any dispatch asked, ledgered)",
     },
     TSDB_SAMPLES_TOTAL: {
         "type": "counter", "labels": [],

@@ -648,15 +648,17 @@ def set_host_overhead_ratio(
 
 def record_compile(
     program: str, seconds: float, cache_hit: bool, *,
-    registry: Registry | None = None,
+    ahead: bool = False, registry: Registry | None = None,
 ) -> None:
-    """One program-cache lookup at a jit dispatch site: every lookup
-    counts under its outcome label; only misses (fresh builds) carry a
-    build-seconds observation."""
+    """One program-cache lookup at a jit dispatch site, or (``ahead``) one
+    program built before any dispatch asked for it: each counts under its
+    outcome label; only builds (miss, ahead) carry a build-seconds
+    observation."""
     reg = _reg(registry)
+    outcome = "hit" if cache_hit else "ahead" if ahead else "miss"
     reg.counter_inc(
         C.COMPILES_TOTAL, 1.0,
-        labels={"program": program, "cache": "hit" if cache_hit else "miss"},
+        labels={"program": program, "cache": outcome},
         help=C.CATALOG[C.COMPILES_TOTAL]["help"],
     )
     if not cache_hit:

@@ -362,13 +362,7 @@ class HotPathProfiler:
         cache_size = getattr(fn, "_cache_size", None)
         n0 = cache_size() if cache_size is not None else 0
         if first:
-            self._ledger_record({
-                "at": time.time(),
-                "event": "begin",
-                "replica": self.replica,
-                "program": program,
-                "shape_key": key[1],
-            })
+            self._ledger_begin(key)
         ann = None
         if self._annotate is not None:
             ann = self._annotate(
@@ -404,15 +398,42 @@ class HotPathProfiler:
                 )
         return out
 
+    def build(self, program: str, shape_key, build_fn, *, ahead: bool = False):
+        """Build a program off the jitted function's own dispatch
+        (``build_fn``: lower and compile, nothing run) and book it as that
+        (program, shape_key)'s one build: ``begin`` before, seconds and
+        ``end`` after, so the dispatch that later runs the compiled program
+        counts as a hit. ``ahead``: no dispatch is waiting for it (a helper
+        thread's build), so it counts as ``cache="ahead"``, not as a miss at
+        a dispatch site. Any thread may call it; nothing is numbered for the
+        starvation account (the device was handed nothing)."""
+        key = (program, str(shape_key))
+        with self._lock:
+            self._seen.add(key)
+        self._ledger_begin(key)
+        t0 = self._clock()
+        try:
+            out = build_fn()
+        except BaseException:
+            with self._lock:
+                self._seen.discard(key)
+            raise
+        self.note_compile(
+            program, shape_key, self._clock() - t0, cache_hit=False, ahead=ahead
+        )
+        return out
+
     def note_compile(
-        self, program: str, shape_key, seconds: float, cache_hit: bool
+        self, program: str, shape_key, seconds: float, cache_hit: bool,
+        ahead: bool = False,
     ) -> None:
         """THE chokepoint every build site reports through: counts the
-        lookup (``mtpu_compiles_total{program,cache}``); a miss (fresh
-        build) also observes ``mtpu_compile_seconds{program}`` and appends
-        the ``end`` event to the ledger."""
+        lookup (``mtpu_compiles_total{program,cache}``); a build (a miss at
+        a dispatch site, or one made ``ahead`` of any) also observes
+        ``mtpu_compile_seconds{program}`` and appends the ``end`` event to
+        the ledger."""
         _obs.record_compile(
-            program, seconds, cache_hit, registry=self._registry
+            program, seconds, cache_hit, ahead=ahead, registry=self._registry
         )
         if cache_hit:
             return
@@ -423,7 +444,7 @@ class HotPathProfiler:
             "program": program,
             "shape_key": str(shape_key),
             "seconds": round(float(seconds), 6),
-            "cache": "miss",
+            "cache": "ahead" if ahead else "miss",
         }
         with self._lock:
             self._compiles += 1
@@ -431,11 +452,21 @@ class HotPathProfiler:
             self._compile_log.append(rec)
         self._ledger_record(rec)
 
+    def _ledger_begin(self, key: tuple[str, str]) -> None:
+        self._ledger_record({
+            "at": time.time(),
+            "event": "begin",
+            "replica": self.replica,
+            "program": key[0],
+            "shape_key": key[1],
+        })
+
     def _ledger_record(self, rec: dict) -> None:
-        if self._ledger is None:
-            self._ledger = named_journal(
-                "compiles", path=self._ledger_path
-            )
+        with self._lock:  # build() records from helper threads
+            if self._ledger is None:
+                self._ledger = named_journal(
+                    "compiles", path=self._ledger_path
+                )
         self._ledger.record(rec)
 
     # -- read surfaces -------------------------------------------------------

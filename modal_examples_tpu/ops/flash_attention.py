@@ -90,6 +90,19 @@ def _tiles(S: int) -> list[int]:
     return tiles or [S]
 
 
+#: the key block beyond which the kernel gains nothing (PERF.md section 6, PR 30)
+_LONG_KEY_BLOCK = 1024
+
+
+def padded_kv_len(Skv: int) -> int:
+    """The key length ``flash_attention_chunked`` runs a causal call of
+    ``Skv`` keys at: the next multiple of the long key block, once there is
+    more than one such block of keys (a shorter call is one tile or two)."""
+    if Skv <= _LONG_KEY_BLOCK:
+        return Skv
+    return -(-Skv // _LONG_KEY_BLOCK) * _LONG_KEY_BLOCK
+
+
 def choose_blocks(
     Sq: int, Skv: int, D: int, Dv: int, itemsize: int,
     budget: int = _VMEM_BUDGET,
@@ -613,7 +626,17 @@ def flash_attention_chunked(
     """Rectangular attention for chunked prefill: one query chunk against a
     longer K/V prefix (the engine processes long prompts chunk by chunk with
     bounded VMEM; also the building block for prefix-cache reuse). Forward
-    only — prefill needs no gradients."""
+    only — prefill needs no gradients.
+
+    A prefix plus a short last chunk (2048 + 128 keys) divides into no key
+    block longer than the chunk, and the key block's length decides the
+    kernel's time: past ``_LONG_KEY_BLOCK`` keys the call pads K/V with zero
+    rows to the next multiple of it. They lie after every query's position,
+    so the causal mask covers them and no step is taken for a block of them
+    alone (0.36 -> 0.21 ms a call at 128 rows over 2048, PERF.md section 6)."""
+    pad = padded_kv_len(k.shape[2]) - k.shape[2] if causal else 0
+    if pad:
+        k, v = (jnp.pad(x, ((0, 0), (0, 0), (0, pad), (0, 0))) for x in (k, v))
     o, _ = _flash_forward(
         q, k, v, causal=causal, sm_scale=_resolve_scale(q, sm_scale),
         interpret=_use_interpret(), q_offset=q_offset,

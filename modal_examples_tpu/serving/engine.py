@@ -29,11 +29,13 @@ TPU-first architecture (vs vLLM's CUDA design):
 from __future__ import annotations
 
 import dataclasses
+import functools
 import itertools
 import queue
 import threading
 import time
 import uuid
+from concurrent.futures import Future, ThreadPoolExecutor
 
 import jax
 import jax.numpy as jnp
@@ -338,6 +340,12 @@ class _Finish:
 
 
 _FINISH = _Finish("stop")
+
+#: how long the engine has to have been without a request before helper
+#: threads start building queued chunk programs: the last request's response
+#: is still on its way out then, and a lowering holds the interpreter in
+#: long stretches (see ``LLMEngine._start_chunk_builds``)
+_CHUNK_BUILD_IDLE_S = 0.1
 
 
 def _req_seed(req: "Request") -> int:
@@ -863,6 +871,15 @@ class LLMEngine:
         self._ms_tpd = 0.0
         self._prefill_jits: dict[int, object] = {}
         self._chunk_jits: dict[int, object] = {}  # keyed by chunk q_offset
+        # the compiled chunk programs, keyed (q_offset, width, is_draft): a
+        # whole offset is queued at its first chunk (_build_chunk_programs),
+        # a program is a future while a helper thread of _chunk_builder is
+        # at it (_start_chunk_builds)
+        self._chunk_programs: dict[tuple[int, int, bool], object] = {}
+        self._chunk_queued: dict[tuple[int, int, bool], object] = {}
+        self._chunk_builder = None  # ThreadPoolExecutor, at the first start
+        self._idle_since = None  # clock of the first of the idle ticks in a row
+        self._chunk_lowering = threading.Lock()  # one lowering at a time
 
         # speculative decoding (the engine-side flag the reference exposes:
         # vllm_inference.py:196-205), as a first-class scheduler decode
@@ -1213,8 +1230,9 @@ class LLMEngine:
         return fn
 
     def _chunk_jit(self, offset: int):
-        """The chunked-prefill program for chunks that start at ``offset``
-        (static: it sizes the gather of the cached prefix)."""
+        """The chunked-prefill function for chunks that start at ``offset``
+        (static: it sizes the gather of the cached prefix). It builds one
+        program a chunk width, all named ``jit_prefill_chunk_off<offset>``."""
         fn = self._chunk_jits.get(offset)
         if fn is None:
             attn_impl, mesh = self._attn_impl, self.mesh
@@ -1242,6 +1260,146 @@ class LLMEngine:
             )
             self._chunk_jits[offset] = fn
         return fn
+
+    def _chunk_widths(self, offset: int) -> list[int]:
+        """The widths a chunk that starts at ``offset`` can take: the largest
+        bucket while more than that is left of the prompt, else the smallest
+        bucket that holds what is left (``_bucket_for``). Offset 0 only ever
+        sees a whole chunk (a shorter prompt is not chunked)."""
+        buckets = self.prefill_buckets
+        if not offset:
+            return [buckets[-1]]
+        most_left = self.max_model_len - 1 - offset  # submit() admits no more
+        return [b for b, below in zip(buckets, (0, *buckets)) if below < most_left]
+
+    def _build_chunk_programs(self, offset: int, first: int | None = None) -> None:
+        """Build (lower and compile, nothing run) the programs of ``first``,
+        the chunk width the caller is about to dispatch at ``offset``, on its
+        thread, and queue every other width of ``_chunk_widths`` for
+        ``_start_chunk_builds``: a whole offset at its first chunk, the
+        draft's programs beside the target's, because a tail width first met
+        later would be built under traffic. Each program lands in the compile
+        ledger under its dispatch's own (program, shape_key)."""
+        fn = self._chunk_jit(offset)
+        prof = self.profiler
+
+        def spec(tree):
+            return jax.tree.map(
+                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=a.sharding),
+                tree,
+            )
+
+        def i32(*shape):
+            return jax.ShapeDtypeStruct(shape, jnp.int32)
+
+        models = [(
+            False, "prefill_chunk", f"off{offset}", self.cfg, self.params,
+            spec((self.cache.k_pages, self.cache.v_pages)),
+            spec(self._state_args([0], 1)),
+        )]
+        if self.spec_mode == "draft":
+            models.append((
+                True, "draft_prefill", f"chunk-off{offset}", self.draft_cfg,
+                self.draft_params,
+                spec((self.draft_cache.k_pages, self.draft_cache.v_pages)), {},
+            ))
+
+        def build(ahead, width, program, key, cfg, params, pages, state):
+            def lower_and_compile():
+                # lowering is the interpreter's work, which the scheduler
+                # thread wants too: one helper at a time; the compiles (or
+                # the compile cache's loads) run side by side
+                with self._chunk_lowering:
+                    lowered = fn.lower(
+                        params, i32(1, width), *pages,
+                        i32(1, self.pages_per_slot), i32(1), **state, cfg=cfg,
+                    )
+                return lowered.compile()
+
+            if prof is None:
+                return lower_and_compile()
+            return prof.build(
+                program, f"{key}w{width}", lower_and_compile, ahead=ahead
+            )
+
+        for width in self._chunk_widths(offset):
+            for draft, *model in models:
+                key = (offset, width, draft)
+                if width == first:
+                    self._chunk_queued.pop(key, None)
+                    self._chunk_programs[key] = build(False, width, *model)
+                elif key not in self._chunk_programs:
+                    self._chunk_queued[key] = functools.partial(
+                        build, True, width, *model
+                    )
+
+    def _start_chunk_builds_when_idle(self, worked: bool) -> None:
+        """A tick's end, with chunk programs queued (they are queued by a
+        request in flight, so the clock below starts after it): hand them to
+        the helpers once no request has been here for
+        ``_CHUNK_BUILD_IDLE_S``."""
+        if worked or self._has_demand():
+            self._idle_since = None
+        elif self._idle_since is None:
+            self._idle_since = self._clock()
+        elif self._clock() - self._idle_since >= _CHUNK_BUILD_IDLE_S:
+            self._idle_since = None
+            self._start_chunk_builds()
+
+    def _start_chunk_builds(self) -> None:
+        """Hand the queued chunk programs to helper threads: once the engine
+        has been without a request for a while
+        (``_start_chunk_builds_when_idle``), or from ``warmup()``. Their
+        lowering would otherwise take the interpreter from a request in
+        flight, or from the threads that are still writing the last one's
+        response. Nobody waits for them: until one is built,
+        ``_chunk_width`` gives its chunks the next wider program that is."""
+        if self._chunk_queued and self._chunk_builder is None:
+            self._chunk_builder = ThreadPoolExecutor(
+                thread_name_prefix="mtpu-chunk-build"
+            )
+        while self._chunk_queued:
+            key, job = self._chunk_queued.popitem()
+            self._chunk_programs[key] = self._chunk_builder.submit(job)
+
+    def _chunk_width(self, offset: int, left: int) -> int:
+        """The width of the chunk call at ``offset`` of a prompt with ``left``
+        tokens to go: the bucket that holds them (``_bucket_for``: the
+        largest while more than that is left, so offsets stay its multiples),
+        or, while a helper thread is still building that one, the narrowest
+        wider bucket whose programs are built. Where none is, the caller
+        builds its own now."""
+        want = self._bucket_for(left)
+        drafts = (False, True) if self.spec_mode == "draft" else (False,)
+
+        def built(key):
+            program = self._chunk_programs.get(key)
+            if isinstance(program, Future):
+                if not program.done():
+                    return False
+                if (error := program.exception()) is not None:
+                    # a helper's build failed: the width is unbuilt again,
+                    # and a caller that finds no wider one builds its own
+                    # and raises what that raises
+                    _log.warning(
+                        "chunk program %s failed to build ahead: %r", key, error
+                    )
+                    del self._chunk_programs[key]
+                    return False
+            return program is not None
+
+        for width in self._chunk_widths(offset):
+            if width >= want and all(built((offset, width, d)) for d in drafts):
+                return width
+        self._build_chunk_programs(offset, first=want)
+        return want
+
+    def _chunk_program(self, offset: int, width: int, draft: bool = False):
+        """The compiled program ``_chunk_width`` found built."""
+        program = self._chunk_programs[offset, width, draft]
+        if isinstance(program, Future):
+            program = self._chunk_programs[offset, width, draft] = program.result()
+        return program
 
     def _prefill_and_sample_mm(
         self, params, vparams, k_pages, v_pages, images, tokens, page_tables,
@@ -1518,9 +1676,10 @@ class LLMEngine:
 
     def warmup(self, buckets: tuple[int, ...] | None = None) -> float:
         """Pre-compile the decode step and prefill buckets against trash
-        pages (no allocator state touched) — the FAST_BOOT-style cold-start
-        control (vllm_inference.py:85-101): pay compiles at boot, not on the
-        first user request. Returns seconds spent."""
+        pages (no allocator state touched), and start building the chunk
+        programs of prompts beyond the largest bucket — the FAST_BOOT-style
+        cold-start control (vllm_inference.py:85-101): pay compiles at boot,
+        not on the first user request. Returns seconds spent."""
         if self._running:
             # the scheduler thread donates the same cache buffers; racing it
             # would pass deleted arrays. Warmup is a boot-time API.
@@ -1698,6 +1857,13 @@ class LLMEngine:
                 jnp.zeros((B,), jnp.int32),
                 jnp.full((B,), -1, jnp.int32),
             )
+        # the chunk programs of every offset a prompt can reach: built, not
+        # run, on helper threads that nobody waits for
+        longest = self.max_model_len - 1
+        if longest > self.prefill_buckets[-1]:
+            for offset in range(0, longest, self.prefill_buckets[-1]):
+                self._build_chunk_programs(offset)
+            self._start_chunk_builds()
         jax.block_until_ready(self.cache.k_pages)
         if self.profiler is not None:
             self.profiler.note_drained()
@@ -2147,6 +2313,11 @@ class LLMEngine:
         self._running = False
         if self._thread and self._thread is not threading.current_thread():
             self._thread.join(timeout=10)
+        if self._chunk_builder is not None:
+            # the helpers finish what was handed to them, unwaited, and
+            # end; a restarted engine finds those programs built
+            self._chunk_builder.shutdown(wait=False)
+            self._chunk_builder = None
         if self._detok is not None:
             # drain held text BEFORE the release sweep: its direct markers
             # must land behind every chunk the worker still owes
@@ -2347,6 +2518,8 @@ class LLMEngine:
             _tm(tick, "policy")
             self._refresh_gauges()
             worked = admitted or decoded
+            if self._chunk_queued:
+                self._start_chunk_builds_when_idle(worked)
         finally:
             # also on a scheduler error: the open span and its trace
             # annotation close with the tick they belong to
@@ -2893,31 +3066,33 @@ class LLMEngine:
         self, prompt_tokens: list, table, offset: int, cached: int = 0,
         slot_idx: int | None = None,
     ) -> "jax.Array":
-        """Dispatch ONE bucket-sized prefill chunk (async — the logits come
-        back as a device future, nothing blocks the host): the unit both
-        the atomic loop (``_run_prefill_chunks``) and the budgeted state
-        machine (``_advance_pending_prefills``) advance by, so the two
-        paths can never drift. ``cached`` is how many leading prompt tokens
+        """Dispatch ONE prefill chunk (async — the logits come back as a
+        device future, nothing blocks the host): the unit both the atomic
+        loop (``_run_prefill_chunks``) and the budgeted state machine
+        (``_advance_pending_prefills``) advance by, so the two paths can
+        never drift. The chunk is as wide as the bucket that holds what is
+        left of the prompt from ``offset`` (``_chunk_width``), the draft
+        model's beside it. ``cached`` is how many leading prompt tokens
         sit on cached pages (computed again all the same: the count at the
         prefill boundary says so). ``slot_idx``: the slot the prompt fills; a
         model with per-slot state starts the chunk at offset 0 from zeros and
         a later one from what the chunk before it left in that slot."""
-        C = self.prefill_buckets[-1]
+        width = self._chunk_width(offset, len(prompt_tokens) - offset)
         pad_tok = self.tokenizer.pad_id % self.cfg.vocab_size
-        chunk = prompt_tokens[offset : offset + C]
-        toks = np.full((1, C), pad_tok, np.int32)
+        chunk = prompt_tokens[offset : offset + width]
+        toks = np.full((1, width), pad_tok, np.int32)
         toks[0, : len(chunk)] = chunk
         _obs.record_prefill_positions(
-            computed=C,
+            computed=width,
             needed=max(0, offset + len(chunk) - max(offset, cached)),
         )
         if offset:
             _obs.record_prefill_prefix_positions(offset)
-        fn = self._chunk_jit(offset)
         (
             logits, self.cache.k_pages, self.cache.v_pages, self.cache.state,
         ) = self._profiled(
-            "prefill_chunk", f"off{offset}", fn
+            "prefill_chunk", f"off{offset}w{width}",
+            self._chunk_program(offset, width),
         )(
             self.params,
             jnp.asarray(toks),
@@ -2926,16 +3101,13 @@ class LLMEngine:
             jnp.asarray(table[None, :]),
             jnp.asarray([len(chunk)], np.int32),
             **self._state_args([] if slot_idx is None else [slot_idx], 1),
-            cfg=self.cfg,
         )
         if self.spec_mode == "draft":
-            # the same cached jit serves the draft: cfg is a static call
-            # argument, so target and draft get separate compile-cache
-            # entries under one callable
             (
                 _, self.draft_cache.k_pages, self.draft_cache.v_pages, _,
             ) = self._profiled(
-                "draft_prefill", f"chunk-off{offset}", fn
+                "draft_prefill", f"chunk-off{offset}w{width}",
+                self._chunk_program(offset, width, draft=True),
             )(
                 self.draft_params,
                 jnp.asarray(toks),
@@ -2943,7 +3115,6 @@ class LLMEngine:
                 self.draft_cache.v_pages,
                 jnp.asarray(table[None, :]),
                 jnp.asarray([len(chunk)], np.int32),
-                cfg=self.draft_cfg,
             )
         return logits
 

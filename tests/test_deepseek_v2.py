@@ -12,6 +12,7 @@ import json
 import sys
 from pathlib import Path
 
+import chunk_tail
 import numpy as np
 import pytest
 
@@ -425,6 +426,28 @@ def test_the_engines_cache_and_counters_are_the_latent_ones(jax, ds, model):
     # the chunk calls at offsets 32, 64, ... each attended to that many cached positions
     offsets = range(32, len(prompt_ids), 32)
     assert value(C.PREFILL_PREFIX_POSITIONS_TOTAL) - before["prefix"] == sum(offsets) > 0
+
+
+@pytest.fixture(scope="module")
+def tail_engine(jax, ds, model):
+    import jax.numpy as jnp
+
+    from modal_examples_tpu.serving import LLMEngine
+
+    cfg, params = model
+    eng = LLMEngine(
+        cfg, params, max_slots=2, max_model_len=chunk_tail.MAX_MODEL_LEN, page_size=16,
+        kv_dtype=jnp.float32, seed=0, prefill_buckets=chunk_tail.BUCKETS,
+    )
+    yield chunk_tail.warmed(eng)
+    eng.stop()
+
+
+@pytest.mark.parametrize("case", list(chunk_tail.CASES))
+def test_the_tail_chunk_over_cached_latents_is_as_wide_as_what_is_left(tail_engine, case, monkeypatch):
+    """The last chunk expands the cached latents again whatever its width;
+    its own rows are the bucket that holds what is left (tests/chunk_tail.py)."""
+    chunk_tail.check(tail_engine, case, monkeypatch)
 
 
 REFUSED = {

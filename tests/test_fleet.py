@@ -518,10 +518,11 @@ def fleet_run(jax_cpu, tmp_path_factory):
         built_params.append(params)
         eng = mk(params=params)
         eng.warmup()
-        # warmup() covers buckets + the decode block, NOT the chunk-offset
-        # jits long prompts hit: serve one short and one chunking prompt
-        # before joining the router, so the replica's first user request
-        # never pays a compile inside a measurement window
+        # warmup() covers buckets and the decode block and starts building
+        # the chunk programs long prompts hit; one short and one chunking
+        # prompt before joining the router run them all once, so the
+        # replica's first user request pays nothing of a first run inside a
+        # measurement window
         eng.start()
         from modal_examples_tpu.serving import SamplingParams
 

@@ -18,6 +18,7 @@ one.
 
 import json
 
+import chunk_tail
 import numpy as np
 import pytest
 
@@ -399,6 +400,30 @@ def _assert_decided_tokens_are_the_references(jax, ref, params, cfg, prompt_ids,
     assert [int(t) for t in logits.argmax(-1)[decided]] == [
         t for t, d in zip(served, decided) if d
     ]
+
+
+@pytest.fixture(scope="module")
+def tail_engine(model):
+    import jax.numpy as jnp
+
+    from modal_examples_tpu.serving import LLMEngine
+
+    cfg, params = model
+    eng = LLMEngine(
+        cfg, params, max_slots=2, max_model_len=chunk_tail.MAX_MODEL_LEN, page_size=8,
+        kv_dtype=jnp.float32, seed=0, enable_prefix_cache=False, decode_block=4,
+        prefill_buckets=chunk_tail.BUCKETS,
+    )
+    yield chunk_tail.warmed(eng)
+    eng.stop()
+
+
+@pytest.mark.parametrize("case", list(chunk_tail.CASES))
+def test_a_narrower_tail_chunk_leaves_the_state_at_the_last_real_token(tail_engine, case, monkeypatch):
+    """The decode steps after a tail chunk of the bucket that holds what is
+    left start from the state the chunk padded to the largest bucket left:
+    the same greedy tokens (tests/chunk_tail.py)."""
+    chunk_tail.check(tail_engine, case, monkeypatch)
 
 
 PROMPTS = {

@@ -12,6 +12,7 @@ quantization legitimately changes logits — vLLM's fp8 KV does too):
   cache, pass-through helpers — bit-identical to the pre-int8 code.
 """
 
+import chunk_tail
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -501,3 +502,22 @@ class TestNgramIndex:
         for t in seq[3:]:
             inc.push(t)
         assert bulk.propose(4) == inc.propose(4) == [3, 1, 2]
+
+
+@pytest.fixture(scope="module")
+def tail_engine():
+    from modal_examples_tpu.serving import LLMEngine
+
+    eng = LLMEngine(
+        llama.LlamaConfig.tiny(), max_slots=2, page_size=16, seed=0, kv_dtype="int8",
+        max_model_len=chunk_tail.MAX_MODEL_LEN, prefill_buckets=chunk_tail.BUCKETS,
+    )
+    yield chunk_tail.warmed(eng)
+    eng.stop()
+
+
+@pytest.mark.parametrize("case", list(chunk_tail.CASES))
+def test_the_tail_chunk_over_int8_pages_is_as_wide_as_what_is_left(tail_engine, case, monkeypatch):
+    """The prefix the last chunk gathers is dequantised whatever its width
+    (tests/chunk_tail.py)."""
+    chunk_tail.check(tail_engine, case, monkeypatch)

@@ -8,6 +8,7 @@ lowered decode program may hold no slice of a layer's whole expert stack."""
 
 import re
 
+import chunk_tail
 import numpy as np
 import pytest
 
@@ -334,3 +335,25 @@ def test_a_routed_llama_engine_counts_its_decode_pairs(jax, llama):
     per_step = cfg.n_layers * cfg.top_k_experts  # one live slot
     assert held >= eng.decode_block * per_step and held % per_step == 0
     assert elsewhere == 0  # the router is no wider than what the chip holds
+
+
+# -- the routed layer under a tail chunk of each width --------------------------------
+
+
+@pytest.fixture(scope="module")
+def tail_engine(jax, llama):
+    from modal_examples_tpu.serving import LLMEngine
+
+    eng = LLMEngine(
+        llama.LlamaConfig.tiny_moe(), max_slots=2, max_model_len=chunk_tail.MAX_MODEL_LEN,
+        page_size=16, prefill_buckets=chunk_tail.BUCKETS, seed=0,
+    )
+    yield chunk_tail.warmed(eng)
+    eng.stop()
+
+
+@pytest.mark.parametrize("case", list(chunk_tail.CASES))
+def test_the_routed_tail_chunk_is_as_wide_as_what_is_left(tail_engine, case, monkeypatch):
+    """Fewer rows reach the expert tiles of the last chunk call; the pairs of
+    the real tokens are the same ones (tests/chunk_tail.py)."""
+    chunk_tail.check(tail_engine, case, monkeypatch)

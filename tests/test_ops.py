@@ -116,6 +116,38 @@ class TestFlashAttention:
         )
 
     @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+    @pytest.mark.parametrize("tail", [128, 256])
+    @pytest.mark.parametrize("Hq,Hkv,D,Dv", [(8, 2, 128, 128), (4, 4, 192, 128)])
+    def test_a_short_tail_over_a_long_prefix_runs_on_padded_keys(
+        self, jax, jnp, Hq, Hkv, D, Dv, tail, dtype
+    ):
+        """A last chunk of 128 or 256 rows over 1024 cached keys: 1152 / 1280
+        keys divide into no block longer than the tail, so the call pads them
+        with zero rows to 2048 and takes one long key block a query tile; the
+        padded keys lie after every query and weigh nothing."""
+        from modal_examples_tpu.ops import flash_attention_chunked, reference
+        from modal_examples_tpu.ops.flash_attention import choose_blocks, padded_kv_len
+
+        dt = jnp.dtype(dtype)
+        q_offset, Skv = 1024, 1024 + tail
+        assert choose_blocks(tail, Skv, D, Dv, dt.itemsize)[1] <= tail
+        assert padded_kv_len(Skv) == 2048
+        assert choose_blocks(tail, 2048, D, Dv, dt.itemsize)[1] >= 1024
+        ks = jax.random.split(jax.random.PRNGKey(7), 3)
+        q = jax.random.normal(ks[0], (1, Hq, tail, D), dt)
+        k = jax.random.normal(ks[1], (1, Hkv, Skv, D), dt)
+        v = jax.random.normal(ks[2], (1, Hkv, Skv, Dv), dt)
+        out = flash_attention_chunked(q, k, v, q_offset=q_offset)
+        want = np.asarray(
+            reference.attention_chunked(q, k, v, q_offset=q_offset).astype(jnp.float32)
+        )
+        assert out.shape == want.shape and out.dtype == dt
+        atol = 2e-5 if dtype == "float32" else float(
+            jnp.finfo(jnp.bfloat16).eps
+        ) * float(np.abs(want).max())
+        np.testing.assert_allclose(np.asarray(out.astype(jnp.float32)), want, atol=atol)
+
+    @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
     @pytest.mark.parametrize("chunk", [384, 640])
     @pytest.mark.parametrize("offset_chunks", [0, 1])
     @pytest.mark.parametrize(
@@ -131,14 +163,16 @@ class TestFlashAttention:
         256, so at an offset of one chunk the first query tile walks whole
         key blocks, the block the diagonal crosses, and masked ones."""
         from modal_examples_tpu.ops import flash_attention_chunked, reference
-        from modal_examples_tpu.ops.flash_attention import choose_blocks
+        from modal_examples_tpu.ops.flash_attention import choose_blocks, padded_kv_len
 
         dt = jnp.dtype(dtype)
         q_offset = offset_chunks * chunk
         Skv = q_offset + chunk
-        bq, bk = choose_blocks(chunk, Skv, D, Dv, dt.itemsize)
-        assert chunk // bq > 1 and Skv // bk > 1  # several tiles both ways
-        if q_offset:
+        padded = padded_kv_len(Skv)  # 1280 keys run as 2048: one long key block
+        bq, bk = choose_blocks(chunk, padded, D, Dv, dt.itemsize)
+        assert chunk // bq > 1  # several query tiles
+        if q_offset and padded == Skv:
+            assert Skv // bk > 1
             first_tile_last = (q_offset + bq - 1) // bk
             assert 0 < first_tile_last < Skv // bk - 1  # whole, crossed, masked
         ks = jax.random.split(jax.random.PRNGKey(5), 3)

@@ -442,6 +442,63 @@ class TestCompileTelemetry:
             P.read_ledger(tmp_path / "compiles.jsonl")
         )
 
+    def test_a_program_built_ahead_is_its_keys_one_build(self, tmp_path):
+        """``build`` (lower and compile, nothing run) books the build under
+        the dispatch's own (program, shape_key), from whatever thread, as
+        ``ahead`` where no dispatch waits for it and as a miss where one
+        does: the compiled program's first dispatch is then a hit and
+        numbers one program for the starvation account; a build that raises
+        is forgotten."""
+        import threading
+
+        import jax
+        import jax.numpy as jnp
+
+        clk, reg = ManualClock(), Registry()
+        prof = P.HotPathProfiler(
+            clock=clk, name="t-aot", registry=reg,
+            ledger_path=tmp_path / "compiles.jsonl",
+        )
+        fn = jax.jit(lambda x: x + 1)
+        built = {}
+
+        def ahead(n):
+            built[n] = prof.build(
+                "prefill_chunk", f"off64w{n}",
+                lambda: clk.advance(2.0) or fn.lower(jnp.zeros((n,))).compile(),
+                ahead=n == 32,
+            )
+
+        threads = [threading.Thread(target=ahead, args=(n,)) for n in (16, 32)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join()
+        assert prof.dispatched == 0
+        for n in (16, 32, 16):
+            out = prof.dispatch(
+                "prefill_chunk", f"off64w{n}", built[n], (jnp.zeros((n,)),), {}
+            )
+            assert out.shape == (n,)
+        assert prof.dispatched == 3
+
+        def count(cache):
+            return reg.value(
+                C.COMPILES_TOTAL, labels={"program": "prefill_chunk", "cache": cache}
+            )
+
+        assert (count("miss"), count("ahead"), count("hit")) == (1.0, 1.0, 3.0)
+        rows = P.read_ledger(tmp_path / "compiles.jsonl")
+        assert sorted((r["event"], r["shape_key"], r.get("cache")) for r in rows) == [
+            ("begin", "off64w16", None), ("begin", "off64w32", None),
+            ("end", "off64w16", "miss"), ("end", "off64w32", "ahead"),
+        ]
+        assert not P.unfinished_builds(rows)
+        with pytest.raises(ZeroDivisionError):
+            prof.build("prefill_chunk", "off64w64", lambda: 1 / 0)
+        prof.dispatch("prefill_chunk", "off64w64", lambda: None, (), {})
+        assert count("miss") == 2.0  # the retry is a fresh miss
+
     def test_a_rebuild_under_a_seen_key_is_a_miss(self, tmp_path):
         """XLA builds a program again when an argument's shape changes,
         whatever (program, shape_key) the engine calls it by: the jitted

@@ -73,6 +73,98 @@ def test_flash_chunk_compiles_for_v5e(one_chip, Hq, Hkv, D, Dv, q_offset):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+# -- the docqa cells' tail chunk programs, by width (PR 33) ---------------------------------
+
+#: width of the last chunk -> the tiles its flash call takes over 2048 cached
+#: keys and its own. ``choose_blocks`` takes divisors, and 2048 + 128 has no
+#: key block longer than 128: ``flash_attention_chunked`` pads the keys to 3072
+TAIL_BLOCKS = {128: (128, 1024), 256: (256, 1024), 512: (512, 1024), 1024: (1024, 1024),
+               2048: (1024, 1024)}
+
+
+@pytest.mark.parametrize("D,Dv", [(128, 128), (192, 128)], ids=["gqa-128", "mla-192-128"])
+def test_the_flash_call_of_a_tail_chunk_takes_these_tiles(D, Dv):
+    from modal_examples_tpu.ops.flash_attention import choose_blocks, padded_kv_len
+
+    assert [padded_kv_len(2048 + w) for w in TAIL_BLOCKS] == [3072, 3072, 3072, 3072, 4096]
+    assert [padded_kv_len(n) for n in (80, 1024, 1025, 2048)] == [80, 1024, 2048, 2048]
+    assert {
+        w: choose_blocks(w, padded_kv_len(2048 + w), D, Dv, 2) for w in TAIL_BLOCKS
+    } == TAIL_BLOCKS
+    # unpadded, the key block is no longer than the tail: what the padding is for
+    assert choose_blocks(128, 2048 + 128, D, Dv, 2) == (128, 128)
+
+
+@pytest.fixture(scope="module")
+def chunk_program(one_chip):
+    """``compile(family, width)``: the engine's chunk program at offset 2048
+    for a docqa configuration of the benchmark (its file, its pages), as
+    shapes on the described chip."""
+    import jax
+    import jax.numpy as jnp
+
+    from modal_examples_tpu.models import deepseek_v2, llama
+    from modal_examples_tpu.models.quantize import quantize_llama
+    from modal_examples_tpu.serving.engine import LLMEngine
+
+    configs = {  # module, its configuration class, the cell's file, its n_pages
+        "mistral": (llama, llama.LlamaConfig, "mistral-7b-int8", 3072),
+        "deepseek": (deepseek_v2, deepseek_v2.DeepseekV2Config, "deepseek-v2-int8-ep4", 12288),
+    }
+    S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+    eng = object.__new__(LLMEngine)  # the program's body, without an engine's arrays
+    eng._attn_impl, eng.mesh, eng._chunk_jits = "flash", None, {}
+
+    def compile(family, width):
+        module, config_class, name, n_pages = configs[family]
+        cfg = config_class.from_hf_config(f"benchmarks/serving/configs/{name}.json")
+        params = jax.tree.map(
+            lambda a: S(a.shape, a.dtype),
+            jax.eval_shape(
+                lambda k: quantize_llama(module.init_params(k, cfg), cfg.quant_targets),
+                jax.random.PRNGKey(0),
+            ),
+        )
+        layers = getattr(cfg, "n_cache_layers", cfg.n_layers)
+        k_pages, v_pages = (
+            S((layers, n_pages, 16, *leaf), jnp.bfloat16) for leaf in cfg.cache_leaf_shapes
+        )
+        i32 = lambda *s: S(s, jnp.int32)  # noqa: E731
+        return eng._chunk_jit(2048).lower(
+            params, i32(1, width), k_pages, v_pages, i32(1, 256), i32(1), cfg=cfg
+        ).compile()
+
+    # the kernels pick interpret= from the backend at trace time
+    backend, jax.default_backend = jax.default_backend, lambda: "tpu"
+    try:
+        yield compile
+    finally:
+        jax.default_backend = backend
+
+
+def test_mistrals_five_tail_programs_compile_for_a_v5e(chunk_program):
+    """Every width the last chunk of a docqa prompt can take, at offset 2048:
+    each goes through Mosaic, is named for its offset, and a narrower one
+    needs less beside weights and pages than the 2048-wide call (0.60 GiB),
+    which every chunked prompt took before."""
+    temps = {}
+    for width in TAIL_BLOCKS:
+        compiled = chunk_program("mistral", width)
+        text = compiled.as_text()
+        assert "tpu_custom_call" in text and "jit_prefill_chunk_off2048" in text
+        temps[width] = compiled.memory_analysis().temp_size_in_bytes
+    assert sorted(temps.values()) == [temps[w] for w in sorted(temps)]
+    assert temps[1024] < temps[2048] < 0.7 * GIB
+
+
+def test_deepseeks_tail_program_compiles_at_widths_192_and_128_for_a_v5e(chunk_program):
+    """The 1 x 512 tail over 2048 cached latents expanded again (q/k 192 wide,
+    values 128): under the 2048-wide call's 1.31 GiB of temporaries."""
+    compiled = chunk_program("deepseek", 512)
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.0 * GIB  # 0.81
+
+
 # -- Granite-4.0-H-Micro at its published widths (PR 31) ------------------------------------
 
 
