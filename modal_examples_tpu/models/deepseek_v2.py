@@ -361,9 +361,11 @@ def _rope(x, cos, sin):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1).astype(x.dtype)
 
 
-def _project(layer, h, cos, sin, cfg):
+def _project(layer, h, cos, sin, cfg, *, with_c_q: bool = False):
     """h [..., S, D] (normed) -> q_nope [..., S, H, nope], q_pe [..., S, H,
-    rope] rotated, c_kv [..., S, rank] normalised, k_pe [..., S, rope] rotated."""
+    rope] rotated, c_kv [..., S, rank] normalised, k_pe [..., S, rope] rotated
+    (and, asked for, the query's normalised latent c_q [..., S, q_rank] fifth:
+    what a model with an indexer projects its index queries from)."""
     dt = h.dtype
     H, nope, rank = cfg.n_heads, cfg.qk_nope_head_dim, cfg.kv_lora_rank
     c_q = layers.rms_norm(layers.mm(h, layer["wq_a"]).astype(dt), layer["q_norm"], cfg.norm_eps)
@@ -371,7 +373,8 @@ def _project(layer, h, cos, sin, cfg):
     kv_a = layers.mm(h, layer["wkv_a"]).astype(dt)
     c_kv = layers.rms_norm(kv_a[..., :rank], layer["kv_norm"], cfg.norm_eps)
     k_pe = _rope(kv_a[..., None, rank:], cos, sin)[..., 0, :]
-    return q[..., :nope], _rope(q[..., nope:], cos, sin), c_kv, k_pe
+    out = (q[..., :nope], _rope(q[..., nope:], cos, sin), c_kv, k_pe)
+    return (*out, c_q) if with_c_q else out
 
 
 def _kvb_halves(w, cfg, dt):
@@ -430,11 +433,16 @@ def _mlp(layer, h, cfg, dense: bool, token_mask):
         out = layers.swiglu_mlp({k: layer[k] for k in ("gate", "up", "down")}, h)
         return out, jnp.zeros((2,), jnp.int32)
     flat = h.reshape(-1, cfg.dim)
+    # GLM-5.2's router (models/glm_dsa.py runs this layer too): each expert
+    # scored on its own, chosen with the layer's selection bias
+    biased = {"score": getattr(cfg, "scoring_func", "softmax")}
+    if "router_bias" in layer:
+        biased["bias"] = layer["router_bias"]
     out, counts = _moe.moe_swiglu_routed(
         layer["router"], *(layer[n] for n in _moe.EXPERT_LEAVES), flat, cfg.top_k_experts,
         n_group=cfg.n_group, topk_group=cfg.topk_group,
         scale=cfg.routed_scaling_factor, renormalize=cfg.norm_topk_prob,
-        expert_offset=cfg.expert_offset,
+        expert_offset=cfg.expert_offset, **biased,
         token_mask=None if token_mask is None else token_mask.reshape(-1),
         layer=layer.get("expert_layer"),
     )

@@ -215,14 +215,25 @@ def route_group_limited(
     topk_group: int = 1,
     scale: float = 1.0,
     renormalize: bool = False,
+    bias: jax.Array | None = None,  # [E] f32 — added for the selection only
 ) -> tuple[jax.Array, jax.Array]:
     """Group-limited greedy top-k (DeepSeek-V2's ``group_limited_greedy``):
     a group's score is its largest expert score, the ``topk_group`` best of
     ``n_group`` groups stay and the other groups' scores read zero, then the
     ``top_k`` largest of what is left. The weights are those scores, not
     renormalised unless asked, times ``scale``. ``n_group=1`` is the plain
-    top-k. Returns (weights [T, k] f32, expert ids [T, k] int32)."""
+    top-k. With ``bias`` (``noaux_tc``: GLM-5.2's, at ``n_group=1``) the
+    experts are chosen by ``scores + bias`` and weighted by their unbiased
+    scores. Returns (weights [T, k] f32, expert ids [T, k] int32)."""
     T, E = scores.shape
+    if bias is not None:
+        if n_group > 1:
+            raise NotImplementedError("a selection bias with a group limit")
+        _, ids = jax.lax.top_k(scores + bias.astype(scores.dtype), top_k)
+        weights = jnp.take_along_axis(scores, ids, axis=-1)
+        if renormalize:
+            weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-20)
+        return weights * scale, ids.astype(jnp.int32)
     if n_group > 1:
         group_scores = scores.reshape(T, n_group, E // n_group).max(axis=-1)
         _, group_ids = jax.lax.top_k(group_scores, topk_group)  # [T, kg]
@@ -290,20 +301,23 @@ def moe_swiglu_routed(
     layer: jax.Array | None = None,
     expert_offset: int = 0,
     token_mask: jax.Array | None = None,
-    **routing,  # route_group_limited's: n_group, topk_group, scale, renormalize
+    score: str = "softmax",  # or "sigmoid": each expert scored on its own
+    **routing,  # route_group_limited's: n_group, topk_group, scale, renormalize, bias
 ) -> tuple[jax.Array, jax.Array]:
     """A routed SwiGLU layer as the serving programs run it: the router's
-    softmax over its whole width in f32, ``route_group_limited`` (plain
-    top-k renormalised is Mixtral's, group-limited and scaled DeepSeek-V2's),
+    softmax (or sigmoid) over its whole width in f32, ``route_group_limited``
+    (plain top-k renormalised is Mixtral's, group-limited and scaled
+    DeepSeek-V2's, sigmoid scores chosen with a bias GLM-5.2's),
     then only the chosen (token, expert) pairs through
     ``moe_swiglu_sparse``: activations in ``x``'s dtype into the tile
     matmuls, f32 accumulation, the combine in f32. Nothing is dropped.
     Returns (out [T, D] f32, counts [2] int32) as ``moe_swiglu_sparse``."""
     with jax.named_scope(ROUTER):
-        scores = jax.nn.softmax(
-            jnp.einsum("td,de->te", x.astype(jnp.float32), router.astype(jnp.float32)),
-            axis=-1,
-        )
+        logits = jnp.einsum("td,de->te", x.astype(jnp.float32), router.astype(jnp.float32))
+        if score == "sigmoid":
+            scores = jax.nn.sigmoid(logits)
+        else:
+            scores = jax.nn.softmax(logits, axis=-1)
         weights, ids = route_group_limited(scores, top_k, **routing)
     return moe_swiglu_sparse(
         w_gate, w_up, w_down, x, ids, weights,

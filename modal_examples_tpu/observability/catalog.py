@@ -79,6 +79,16 @@ ROUTED_PAIRS_TOTAL = "mtpu_routed_pairs_total"
 #: holding a running sequence x steps). Only a model with per-slot state
 #: reports it (docs/recurrent_state.md)
 STATE_ROWS_TOTAL = "mtpu_state_rows_total"
+#: counter {kind}: (query, cached position, layer) triples of a learned
+#: sparse attention, counted at each prefill and decode-block dispatch from
+#: the positions the host hands the program: kind = scored (the indexers'
+#: layers: every position s <= t of every query) | selected (every layer:
+#: the positions in a query's selection, min(t + 1, index_topk)) | attended
+#: (every layer: what the attention program computed for: every causal
+#: position under a masked-dense form, the selected ones under a gathered
+#: one). selected / attended is how sparse the attention that ran was. Only
+#: a model with an indexer reports it (docs/sparse_attention.md)
+SPARSE_POSITIONS_TOTAL = "mtpu_sparse_positions_total"
 #: gauge: device bytes of the cache's per-slot leaves (0: a model with none)
 STATE_BYTES = "mtpu_state_bytes"
 #: gauge: requests waiting for admission (engine queue depth)
@@ -573,6 +583,13 @@ CATALOG: dict[str, dict] = {
         "labels": ["kind"],
         "help": "per-slot state rows per decode step at block dispatch (kind="
                 "stepped: every slot | live: slots holding a running sequence)",
+    },
+    SPARSE_POSITIONS_TOTAL: {
+        "type": "counter",
+        "labels": ["kind"],
+        "help": "(query, position, layer) triples of a learned sparse attention "
+                "at dispatch (kind=scored: by the indexers | selected: in a "
+                "query's top-k | attended: computed by the attention program)",
     },
     STATE_BYTES: {
         "type": "gauge",

@@ -135,6 +135,17 @@ def record_decode_kv_positions(
         )
 
 
+def record_sparse_positions(counts: dict, *, registry: Registry | None = None) -> None:
+    """One prefill or decode-block dispatch of a model with an indexer:
+    ``counts`` maps kind (scored | selected | attended) to its triples."""
+    reg = _reg(registry)
+    for kind, n in counts.items():
+        reg.counter_inc(
+            C.SPARSE_POSITIONS_TOTAL, float(n), labels={"kind": kind},
+            help=C.CATALOG[C.SPARSE_POSITIONS_TOTAL]["help"],
+        )
+
+
 def record_state_rows(
     stepped: int, live: int, *, registry: Registry | None = None
 ) -> None:

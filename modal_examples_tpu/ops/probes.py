@@ -30,6 +30,7 @@ PROBED_MODULES: dict[str, list[str]] = {
         "scatter_kv", "scatter_kv_int8",
     ],
     "modal_examples_tpu.ops.quantized_matmul": ["int8_matmul"],
+    "modal_examples_tpu.ops.sparse_attention": ["selected_attention"],
 }
 
 #: every attention probe's bound against its reference: bf16 operands with
@@ -234,6 +235,32 @@ def probe_scatter(
     return {"max_err": max(errs.values())}
 
 
+def probe_selected_attention(H=4, C=256, S=512, D=256, k=64) -> dict:
+    """The flash kernel under a selection mask (a learned sparse attention's
+    prefill: ``k`` of each query's causal positions, keys in blocks of 128)
+    against the masked softmax in XLA."""
+    import jax
+    import jax.numpy as jnp
+
+    from modal_examples_tpu.ops import sparse_attention as sp
+
+    q, keys, v = _qkv(1, H, H, S, D)
+    q = q[:, :, S - C:]
+    scores = jax.random.normal(jax.random.PRNGKey(3), (1, C, S))
+    causal = jnp.arange(S - C, S)[None, :, None] >= jnp.arange(S)[None, None, :]
+    mask = sp.select_mask(scores, causal, k)
+    blocked = lambda a: a.reshape(1, H, S // 128, 128, -1).transpose(0, 2, 1, 3, 4)  # noqa: E731
+    o, ref = (
+        jax.jit(lambda *a, impl=impl: sp.selected_attention(*a, sm_scale=D**-0.5, impl=impl))(
+            q, blocked(keys), blocked(v), mask
+        )
+        for impl in ("flash", "xla")
+    )
+    err = _err(o, ref)
+    assert err < ATTN_TOL, err
+    return {"max_err": round(err, 4)}
+
+
 #: probe name -> zero-argument callable, on the smallest legal shapes
 KERNEL_PROBES: dict[str, Callable[[], dict]] = {
     "flash_fwd": probe_flash_fwd,
@@ -259,6 +286,7 @@ KERNEL_PROBES: dict[str, Callable[[], dict]] = {
     ),
     "scatter_kv": functools.partial(probe_scatter, 16),
     "scatter_kv_int8": functools.partial(probe_scatter, 32, int8=True),
+    "selected_attention": probe_selected_attention,
 }
 
 

@@ -24,9 +24,12 @@ EXPERT_DISPATCH = "mtpu.expert_dispatch"  # routed pairs sorted to tiles and bac
 SSM_PROJ = "mtpu.ssm_proj"  # a Mamba-2 mixer's in_proj, gated norm and out_proj
 SSM_SCAN = "mtpu.ssm_scan"  # prefill: the causal convolution and the chunked scan
 SSM_STEP = "mtpu.ssm_step"  # decode: convolution shift, one state update, output
+INDEXER = "mtpu.indexer"  # sparse attention: index projections, key gather, index scores
+TOPK_SELECT = "mtpu.topk_select"  # ... and the exact top-k of the scores
 
 ALL = (
     PAGE_GATHER, ATTENTION, DENSE_MLP, ROUTER, EXPERT_SCAN, KV_SCATTER,
     SAMPLING, LATENT_EXPAND, EXPERT_DISPATCH, SSM_PROJ, SSM_SCAN, SSM_STEP,
+    INDEXER, TOPK_SELECT,
 )
 

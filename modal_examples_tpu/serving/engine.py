@@ -41,7 +41,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..models import deepseek_v2, granite_hybrid, llama
+from ..models import deepseek_v2, glm_dsa, granite_hybrid, llama
 from ..models.layers import refuse as _refuse
 from ..observability import incident as _incident
 from ..observability import metrics as _obs
@@ -347,6 +347,10 @@ _FINISH = _Finish("stop")
 #: long stretches (see ``LLMEngine._start_chunk_builds``)
 _CHUNK_BUILD_IDLE_S = 0.1
 
+#: prefix lengths a chunk program that takes its offset as an argument is
+#: built for, at most (``LLMEngine._chunk_key``)
+_PREFIX_BUCKETS = 3
+
 
 def _req_seed(req: "Request") -> int:
     """The seed sample() uses for this request's rows: the user's, else the
@@ -417,6 +421,7 @@ MODEL_PRESETS = {
     "tiny-moe": llama.LlamaConfig.tiny_moe,
     "tiny-deepseek-v2": deepseek_v2.DeepseekV2Config.tiny,
     "tiny-granite-hybrid": granite_hybrid.GraniteHybridConfig.tiny,
+    "tiny-glm-dsa": glm_dsa.GlmDsaConfig.tiny,
 }
 
 
@@ -426,6 +431,9 @@ class LLMEngine:
     #: sentinel asserts this stays empty, so a swallowed scheduler
     #: exception anywhere is a loud failure. Capped at 50.
     _error_reports: list = []
+    #: whether the chunk programs take the chunk's offset as an argument (a
+    #: configuration's ``chunk_offset_runtime``; ``__init__`` reads it)
+    _runtime_offset = False
 
     def __init__(
         self,
@@ -540,6 +548,9 @@ class LLMEngine:
         # name, never silently
         self._model = cfg.model
         self._counts_routed = bool(getattr(cfg, "counts_routed_pairs", False))
+        # a model whose chunk program takes the chunk's offset as an argument
+        # (one program a prefix bucket and width, not one an offset)
+        self._runtime_offset = bool(getattr(cfg, "chunk_offset_runtime", False))
         for asked, feature in (
             (self.kv_dtype == "int8", "int8 KV cache"),
             (speculative is not None, "speculative decoding"),
@@ -627,6 +638,7 @@ class LLMEngine:
         self.cache = PagedKVCache.create(
             n_layers=getattr(cfg, "n_cache_layers", cfg.n_layers),
             leaf_shapes=cfg.cache_leaf_shapes,
+            leaf_layers=getattr(cfg, "cache_leaf_layers", None),
             n_pages=n_pages,
             page_size=page_size,
             kv_dtype=kv_dtype,
@@ -879,7 +891,9 @@ class LLMEngine:
         self._chunk_queued: dict[tuple[int, int, bool], object] = {}
         self._chunk_builder = None  # ThreadPoolExecutor, at the first start
         self._idle_since = None  # clock of the first of the idle ticks in a row
-        self._chunk_lowering = threading.Lock()  # one lowering at a time
+        # one thread traces at a time: a helper lowering a chunk program, or
+        # the scheduler's tick (which takes it again to build its own)
+        self._chunk_lowering = threading.RLock()
 
         # speculative decoding (the engine-side flag the reference exposes:
         # vllm_inference.py:196-205), as a first-class scheduler decode
@@ -1125,18 +1139,27 @@ class LLMEngine:
         return toks, last, k_pages, v_pages, state
 
     def _state_args(self, slots: list[int] | None = None, rows: int = 0) -> dict:
-        """The keyword arguments that hand a program the cache's per-slot
-        leaves: ``state`` and, for a prefill call of ``rows`` rows whose
-        first ones fill ``slots``, the rows' ``slot_ids`` (a row with no slot
-        gets ``max_slots``: written nowhere). None for a model with no such
-        state: its programs are called as they always were."""
-        if not self.cache.state:
+        """The keyword arguments that hand a program the cache's leaves
+        beside the first two (``PagedKVCache.beside``: further paged leaves,
+        then per-slot ones): ``state`` and, for a prefill call of ``rows``
+        rows whose first ones fill ``slots``, the rows' ``slot_ids`` (a row
+        with no slot gets ``max_slots``: written nowhere). None for a model
+        with no such leaves: its programs are called as they always were."""
+        if not self.cache.beside:
             return {}
         if slots is None:
-            return {"state": self.cache.state}
+            return {"state": self.cache.beside}
         ids = np.full((rows,), self.max_slots, np.int32)
         ids[: len(slots)] = slots
-        return {"state": self.cache.state, "slot_ids": jnp.asarray(ids)}
+        return {"state": self.cache.beside, "slot_ids": jnp.asarray(ids)}
+
+    def _count_sparse(self, queries, phase: str) -> None:
+        """A dispatch of a model with an indexer (``cfg.sparse_positions``):
+        what it scores, selects and attends to, from the positions of the
+        queries ``queries()`` gives. No other model's dispatch builds them."""
+        count = getattr(self.cfg, "sparse_positions", None)
+        if count is not None:
+            _obs.record_sparse_positions(count(queries(), phase))
 
     def _count_decode_kv(self, positions, active, steps: int) -> None:
         """What the ``steps`` decode steps of one dispatch read of the KV
@@ -1146,6 +1169,9 @@ class LLMEngine:
         step's longest context needs, each over every slot. The macro-step
         program can kill a lane before its last step; it is counted as
         running them all."""
+        self._count_sparse(
+            lambda: positions[active].astype(np.int64)[:, None] + np.arange(steps), "decode"
+        )
         if self.impl_plan["attention"] != "xla-gather":
             return  # the ragged kernels do not loop
         live = positions[active].astype(np.int64)
@@ -1229,31 +1255,56 @@ class LLMEngine:
             self._prefill_jits[bucket] = fn
         return fn
 
+    def _chunk_key(self, offset: int) -> int:
+        """What the chunk programs of a chunk at ``offset`` are keyed and
+        named by. The offset itself, static in the program. Or, for a model
+        whose chunk program takes the offset as an argument
+        (``cfg.chunk_offset_runtime``: contexts long enough that a program an
+        offset is too many), the static length of the cached prefix the
+        program gathers: 0 for the first chunk, else one of at most
+        ``_PREFIX_BUCKETS`` buckets, each the largest of its offsets."""
+        if not self._runtime_offset or not offset:
+            return offset
+        C = self.prefill_buckets[-1]
+        offsets = range(C, self.max_model_len - 1, C)
+        per = -(-len(offsets) // _PREFIX_BUCKETS)
+        last = min(len(offsets), ((offset // C - 1) // per + 1) * per) - 1
+        return offsets[last]
+
     def _chunk_jit(self, offset: int):
-        """The chunked-prefill function for chunks that start at ``offset``
-        (static: it sizes the gather of the cached prefix). It builds one
-        program a chunk width, all named ``jit_prefill_chunk_off<offset>``."""
+        """The chunked-prefill function for chunks whose ``_chunk_key`` is
+        ``offset`` (static: it sizes the gather of the cached prefix). It
+        builds one program a chunk width, all named
+        ``jit_prefill_chunk_off<offset>`` (``..._pre<prefix bucket>`` where
+        the offset is an argument)."""
         fn = self._chunk_jits.get(offset)
         if fn is None:
             attn_impl, mesh = self._attn_impl, self.mesh
+            runtime = self._runtime_offset
 
             def prefill_chunk(
                 params, toks, k_pages, v_pages, tables, lens, state=(),
-                slot_ids=None, *, cfg,
+                slot_ids=None, q_offset=None, *, cfg,
             ):
                 # cfg is the target's or the draft's: each names its module
                 stateful = {"state": state, "slot_ids": slot_ids} if state else {}
+                at = (
+                    {"q_offset": q_offset, "prefix_len": offset} if runtime
+                    else {"q_offset": offset}
+                )
                 logits, k_pages, v_pages, state, _ = _program_outputs(
                     cfg.model.prefill_chunk(
                         params, toks, k_pages, v_pages, tables, lens, cfg=cfg,
-                        q_offset=offset, attn_impl=attn_impl, mesh=mesh, **stateful,
+                        **at, attn_impl=attn_impl, mesh=mesh, **stateful,
                     ),
                     bool(state),
                 )
                 return logits, k_pages, v_pages, state
 
             # the compiled program's name in a device trace: one per offset
-            prefill_chunk.__name__ = f"prefill_chunk_off{offset}"
+            prefill_chunk.__name__ = (
+                f"prefill_chunk_pre{offset}" if runtime else f"prefill_chunk_off{offset}"
+            )
             fn = jax.jit(
                 prefill_chunk, static_argnames=("cfg",), donate_argnums=(2, 3),
                 donate_argnames=("state",),
@@ -1265,9 +1316,13 @@ class LLMEngine:
         """The widths a chunk that starts at ``offset`` can take: the largest
         bucket while more than that is left of the prompt, else the smallest
         bucket that holds what is left (``_bucket_for``). Offset 0 only ever
-        sees a whole chunk (a shorter prompt is not chunked)."""
+        sees a whole chunk (a shorter prompt is not chunked), and so does a
+        model whose chunk programs take the offset as an argument."""
         buckets = self.prefill_buckets
-        if not offset:
+        if not offset or self._runtime_offset:
+            # a program over a prefix bucket is long to build (10 s cold on a
+            # v5e, and too large for the compile cache the machine keeps):
+            # one a bucket, its tail chunk padded to the whole width
             return [buckets[-1]]
         most_left = self.max_model_len - 1 - offset  # submit() admits no more
         return [b for b, below in zip(buckets, (0, *buckets)) if below < most_left]
@@ -1292,10 +1347,11 @@ class LLMEngine:
         def i32(*shape):
             return jax.ShapeDtypeStruct(shape, jnp.int32)
 
+        at = {"q_offset": i32()} if self._runtime_offset else {}
         models = [(
-            False, "prefill_chunk", f"off{offset}", self.cfg, self.params,
-            spec((self.cache.k_pages, self.cache.v_pages)),
-            spec(self._state_args([0], 1)),
+            False, "prefill_chunk", self._chunk_shape_key(offset), self.cfg,
+            self.params, spec((self.cache.k_pages, self.cache.v_pages)),
+            {**spec(self._state_args([0], 1)), **at},
         )]
         if self.spec_mode == "draft":
             models.append((
@@ -1362,6 +1418,11 @@ class LLMEngine:
             key, job = self._chunk_queued.popitem()
             self._chunk_programs[key] = self._chunk_builder.submit(job)
 
+    def _chunk_shape_key(self, key: int) -> str:
+        """The compile ledger's shape key of the chunk programs of ``key``
+        (``_chunk_key``), less the width."""
+        return f"pre{key}" if self._runtime_offset else f"off{key}"
+
     def _chunk_width(self, offset: int, left: int) -> int:
         """The width of the chunk call at ``offset`` of a prompt with ``left``
         tokens to go: the bucket that holds them (``_bucket_for``: the
@@ -1369,7 +1430,7 @@ class LLMEngine:
         or, while a helper thread is still building that one, the narrowest
         wider bucket whose programs are built. Where none is, the caller
         builds its own now."""
-        want = self._bucket_for(left)
+        want = self.prefill_buckets[-1] if self._runtime_offset else self._bucket_for(left)
         drafts = (False, True) if self.spec_mode == "draft" else (False,)
 
         def built(key):
@@ -1691,7 +1752,7 @@ class LLMEngine:
             # boot-time builds land in the compile ledger once, and the
             # live path then records cache hits instead of re-timing
             (
-                _tok, self.cache.k_pages, self.cache.v_pages, self.cache.state,
+                _tok, self.cache.k_pages, self.cache.v_pages, self.cache.beside,
             ) = self._profiled(
                 "prefill", f"b{bucket}x{B}", self._prefill_jit((bucket, B))
             )(
@@ -1738,7 +1799,7 @@ class LLMEngine:
         # failover replay path both dispatch it
         (
             _toks, _last, self.cache.k_pages, self.cache.v_pages,
-            self.cache.state,
+            self.cache.beside,
         ) = self._profiled(
             "block", f"s{self.max_slots}k{self.decode_block}",
             self._block_jit,
@@ -1861,8 +1922,9 @@ class LLMEngine:
         # run, on helper threads that nobody waits for
         longest = self.max_model_len - 1
         if longest > self.prefill_buckets[-1]:
-            for offset in range(0, longest, self.prefill_buckets[-1]):
-                self._build_chunk_programs(offset)
+            offsets = range(0, longest, self.prefill_buckets[-1])
+            for key in dict.fromkeys(map(self._chunk_key, offsets)):
+                self._build_chunk_programs(key)
             self._start_chunk_builds()
         jax.block_until_ready(self.cache.k_pages)
         if self.profiler is not None:
@@ -2508,15 +2570,21 @@ class LLMEngine:
         self._tick = tick
         worked = False
         try:
-            _tm(tick, "ctrl")
-            self.watermarks.note_tick()
-            self._drain_ctrl()
-            _tm(tick, "policy")
-            self._expire_deadlines()
-            admitted = self._admit()
-            decoded = self._decode_tick()
-            _tm(tick, "policy")
-            self._refresh_gauges()
+            # a tick traces wherever it dispatches a program or an eager
+            # operation at a new shape; the helper threads lower their chunk
+            # programs between ticks and never beside one (two threads
+            # tracing at once crashed inside jax's WeakrefLRUCache: PERF.md
+            # section 7, after PR 34)
+            with self._chunk_lowering:
+                _tm(tick, "ctrl")
+                self.watermarks.note_tick()
+                self._drain_ctrl()
+                _tm(tick, "policy")
+                self._expire_deadlines()
+                admitted = self._admit()
+                decoded = self._decode_tick()
+                _tm(tick, "policy")
+                self._refresh_gauges()
             worked = admitted or decoded
             if self._chunk_queued:
                 self._start_chunk_builds_when_idle(worked)
@@ -3077,7 +3145,8 @@ class LLMEngine:
         prefill boundary says so). ``slot_idx``: the slot the prompt fills; a
         model with per-slot state starts the chunk at offset 0 from zeros and
         a later one from what the chunk before it left in that slot."""
-        width = self._chunk_width(offset, len(prompt_tokens) - offset)
+        key = self._chunk_key(offset)
+        width = self._chunk_width(key, len(prompt_tokens) - offset)
         pad_tok = self.tokenizer.pad_id % self.cfg.vocab_size
         chunk = prompt_tokens[offset : offset + width]
         toks = np.full((1, width), pad_tok, np.int32)
@@ -3088,11 +3157,12 @@ class LLMEngine:
         )
         if offset:
             _obs.record_prefill_prefix_positions(offset)
+        self._count_sparse(lambda: offset + np.arange(len(chunk)), "prefill")
         (
-            logits, self.cache.k_pages, self.cache.v_pages, self.cache.state,
+            logits, self.cache.k_pages, self.cache.v_pages, self.cache.beside,
         ) = self._profiled(
-            "prefill_chunk", f"off{offset}w{width}",
-            self._chunk_program(offset, width),
+            "prefill_chunk", f"{self._chunk_shape_key(key)}w{width}",
+            self._chunk_program(key, width),
         )(
             self.params,
             jnp.asarray(toks),
@@ -3101,6 +3171,7 @@ class LLMEngine:
             jnp.asarray(table[None, :]),
             jnp.asarray([len(chunk)], np.int32),
             **self._state_args([] if slot_idx is None else [slot_idx], 1),
+            **({"q_offset": jnp.int32(offset)} if self._runtime_offset else {}),
         )
         if self.spec_mode == "draft":
             (
@@ -3182,8 +3253,9 @@ class LLMEngine:
         _obs.record_prefill_positions(
             computed=B * bucket, needed=n_prompt - req.cached_prompt_tokens
         )
+        self._count_sparse(lambda: np.arange(n_prompt), "prefill")
         (
-            next_tok, self.cache.k_pages, self.cache.v_pages, self.cache.state,
+            next_tok, self.cache.k_pages, self.cache.v_pages, self.cache.beside,
         ) = self._profiled(
             "prefill", f"b{bucket}x{B}", self._prefill_jit((bucket, B))
         )(
@@ -3467,7 +3539,7 @@ class LLMEngine:
             self._count_decode_kv(positions, active, self.decode_block)
             (
                 _toks, _last, self.cache.k_pages, self.cache.v_pages,
-                self.cache.state,
+                self.cache.beside,
             ) = (
                 self._profiled(
                     "block", f"s{self.max_slots}k{self.decode_block}",
@@ -3580,6 +3652,10 @@ class LLMEngine:
                 for _slot_idx, req, claim in group
             ),
         )
+        self._count_sparse(
+            lambda: np.concatenate([np.arange(c["n_prompt"]) for _i, _r, c in group]),
+            "prefill",
+        )
 
         if is_mm:
             next_tok, self.cache.k_pages, self.cache.v_pages = (
@@ -3605,7 +3681,7 @@ class LLMEngine:
         else:
             (
                 next_tok, self.cache.k_pages, self.cache.v_pages,
-                self.cache.state,
+                self.cache.beside,
             ) = self._profiled(
                 "prefill", f"b{bucket}x{B}", self._prefill_jit((bucket, B))
             )(
@@ -3834,7 +3910,7 @@ class LLMEngine:
             # classic pipelined block: byte-identical fall-through
             (
                 toks, last, self.cache.k_pages, self.cache.v_pages,
-                self.cache.state,
+                self.cache.beside,
             ) = self._profiled(
                 "block", f"s{self.max_slots}k{self.decode_block}",
                 self._block_jit,

@@ -114,6 +114,13 @@ class PagedKVCache:
     # prefill that fills the slot and by every decode step, and means nothing
     # once the slot is free. A model that declares none has none
     state: tuple = ()
+    # paged leaves beyond the first two, ``[layers_i, n_pages, page_size,
+    # *leaf]`` each with a layer count of its own (GLM-5.2 keeps its indexer's
+    # keys for the layers that have an indexer: docs/sparse_attention.md).
+    # They share the page table and the allocator: a page id names one page
+    # of every paged leaf, so what the prefix cache shares or frees, it
+    # shares or frees in all of them. A model that declares two has none
+    more_pages: tuple = ()
 
     @classmethod
     def create(
@@ -124,11 +131,15 @@ class PagedKVCache:
         head_dim: int | None = None,
         n_pages: int,
         page_size: int = 16,
-        # per-token shape of the two leaves, for a model whose cache is not
+        # per-token shape of the paged leaves, for a model whose cache is not
         # a symmetric pair of per-head K and V (a configuration's
         # ``cache_leaf_shapes``: DeepSeek-V2 keeps ``(1, 512)`` latents and
-        # ``(1, 64)`` rotated keys). Default: ``(n_kv_heads, head_dim)`` twice
+        # ``(1, 64)`` rotated keys; a third entry and on makes ``more_pages``).
+        # Default: ``(n_kv_heads, head_dim)`` twice
         leaf_shapes: tuple | None = None,
+        # layers of each paged leaf (a configuration's ``cache_leaf_layers``);
+        # default: ``n_layers`` for every one
+        leaf_layers: tuple | None = None,
         kv_dtype=None,  # "int8" | jnp dtype; the canonical spelling
         dtype=None,  # legacy alias for kv_dtype (kept for callers)
         prefer_native: bool = True,
@@ -146,8 +157,15 @@ class PagedKVCache:
             if n_kv_heads is None or head_dim is None:
                 raise ValueError("pass n_kv_heads= and head_dim=, or leaf_shapes=")
             leaf_shapes = ((n_kv_heads, head_dim),) * 2
-        k_shape, v_shape = (
-            (n_layers, n_pages, page_size, *leaf) for leaf in leaf_shapes
+        if leaf_layers is None:
+            leaf_layers = (n_layers,) * len(leaf_shapes)
+        if len(leaf_layers) != len(leaf_shapes) or len(leaf_shapes) < 2:
+            raise ValueError(
+                f"{len(leaf_shapes)} paged leaves with {len(leaf_layers)} layer counts"
+            )
+        k_shape, v_shape, *more_shapes = (
+            (layers, n_pages, page_size, *leaf)
+            for layers, leaf in zip(leaf_layers, leaf_shapes)
         )
         allocator = None
         if prefer_native:
@@ -166,6 +184,7 @@ class PagedKVCache:
                 jnp.zeros((layers, max_slots, *shape), dtype)
                 for layers, shape, dtype in state_leaves
             ),
+            more_pages=tuple(kv_empty(shape, kv_dtype) for shape in more_shapes),
         )
 
     @property
@@ -196,7 +215,22 @@ class PagedKVCache:
         about half the bf16 figure, which is exactly the headroom the
         occupancy gauges and bench.py's ``kv_cache`` section report.
         (``nbytes`` is a property on QuantizedKV and jax.Array alike.)"""
-        return self.k_pages.nbytes + self.v_pages.nbytes
+        return (
+            self.k_pages.nbytes + self.v_pages.nbytes
+            + sum(leaf.nbytes for leaf in self.more_pages)
+        )
+
+    @property
+    def beside(self) -> tuple:
+        """The leaves the programs carry beside ``k_pages`` and ``v_pages``,
+        as one tuple (the ``state=`` they take and hand back: docs/mla.md):
+        the further paged leaves first, then the per-slot ones."""
+        return self.more_pages + self.state
+
+    @beside.setter
+    def beside(self, leaves: tuple) -> None:
+        n = len(self.more_pages)
+        self.more_pages, self.state = tuple(leaves[:n]), tuple(leaves[n:])
 
     def state_bytes(self) -> int:
         """Device bytes of the per-slot leaves (0 for a model with none)."""
@@ -241,6 +275,6 @@ class PagedKVCache:
 # a model that declares them refuses disaggregated transfer.
 jax.tree_util.register_dataclass(
     PagedKVCache,
-    data_fields=("k_pages", "v_pages", "state"),
+    data_fields=("k_pages", "v_pages", "state", "more_pages"),
     meta_fields=("page_size", "allocator"),
 )

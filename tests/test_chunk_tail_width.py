@@ -124,3 +124,40 @@ def test_a_helpers_failed_build_leaves_the_wider_program_serving(jax_cpu, monkey
         assert not eng.error_log
     finally:
         eng.stop()
+
+
+def test_a_tick_never_runs_beside_a_helpers_lowering(jax_cpu):
+    """One thread traces at a time (``LLMEngine._chunk_lowering``): while a
+    helper lowers a chunk program a tick waits for it, a helper waits for a
+    tick, and the tick's own build takes the lock again without blocking."""
+    import threading
+
+    C = chunk_tail.C
+    eng = _engine(jax_cpu, "budgeted")
+    try:
+        held, release, ticked = threading.Event(), threading.Event(), threading.Event()
+
+        def helper():
+            with eng._chunk_lowering:
+                held.set()
+                release.wait(30.0)
+
+        def tick():
+            eng.step()
+            ticked.set()
+
+        threads = [threading.Thread(target=helper), threading.Thread(target=tick)]
+        threads[0].start()
+        assert held.wait(30.0)
+        threads[1].start()
+        assert not ticked.wait(0.3)  # the tick waits for the helper's lowering
+        release.set()
+        assert ticked.wait(30.0)
+        for thread in threads:
+            thread.join(30.0)
+        # a chunked request builds its programs inside ticks, under the lock they hold
+        with chunk_tail.dispatched(eng) as seen:
+            chunk_tail.serve(eng, C + 1, seed=3)
+        assert chunk_tail._chunk_keys(seen)[0] == "off0w64" and not eng.error_log
+    finally:
+        eng.stop()

@@ -108,7 +108,7 @@ def test_bucket_prefill_program_is_named_and_scoped(engine):
 
 
 def test_every_scope_is_one_the_list_names():
-    assert len(set(scopes.ALL)) == len(scopes.ALL) == 12
+    assert len(set(scopes.ALL)) == len(scopes.ALL) == 14  # PR 34: mtpu.indexer, mtpu.topk_select
     assert all(s.startswith("mtpu.") for s in scopes.ALL)
 
 
