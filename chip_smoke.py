@@ -22,12 +22,13 @@ process at a time:
                 reference: the ops/probes.py registry plus the ragged /
                 scatter / flash cases at the smoke model's own geometry.
 3. ``server-default``  what ``tpurun serve`` gives a user today (Pallas
-                flash prefill, XLA-gather decode): /health, /v1/models and
+                flash prefill, the decode attention the plan picks: the
+                ragged kernel on the chip): /health, /v1/models and
                 13 /v1/chat/completions requests — sequential, concurrent,
                 streamed, over three prompt lengths.
 4. ``server-pallas``   the same with ``paged_impl=pallas scatter_impl=pallas``
                 (what bench.py passes for every config): ``impl_plan`` must
-                read ragged / grouped / pallas with nothing downgraded.
+                read ragged / flat / pallas with nothing downgraded.
                 Once with bf16 KV and, while the time limit allows, once
                 with int8 KV.
 
@@ -581,8 +582,16 @@ def leg_server(args) -> dict:
         os.environ.pop(var, None)
         if value:
             os.environ[var] = value
+    # what llama.paged_impl_plan resolves: a head shard of 8 KV heads (the
+    # smoke model on one chip) takes the ragged kernel's flat variant, the
+    # rehearsal's 2 and a TP shard's 8 // tp the grouped one; with nothing
+    # asked for the plan picks the kernel on the chip and the loop off it
+    kv_heads = (2 if args.rehearse_cpu else 8) // args.tp
+    variant = "grouped" if kv_heads % 8 else "flat"
     if args.paged_impl == "pallas":
-        want = {"attention": "ragged", "variant": "grouped", "scatter": "pallas"}
+        want = {"attention": "ragged", "variant": variant, "scatter": "pallas"}
+    elif not args.paged_impl and not args.rehearse_cpu:
+        want = {"attention": "ragged", "variant": variant, "scatter": "xla"}
     else:
         want = {"attention": "xla-gather", "variant": "-", "scatter": "xla"}
     want.update(tp=str(args.tp), downgraded="0",

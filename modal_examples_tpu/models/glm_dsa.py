@@ -499,7 +499,7 @@ def load_hf_weights(model_dir, cfg: GlmDsaConfig, *, quantization=None, layer_of
     return params
 
 
-def paged_impl_plan(cfg: GlmDsaConfig, page_size: int, impl: str = "xla",
+def paged_impl_plan(cfg: GlmDsaConfig, page_size: int, impl: str | None = None,
                     scatter_impl: str = "xla", **kwargs) -> dict:
     """DeepSeek-V2's plan: XLA gathers over the latent pages, the XLA scatter."""
     return _mla.paged_impl_plan(cfg, page_size, impl, scatter_impl, **kwargs)
@@ -777,7 +777,7 @@ def decode_step(
     page_tables: jax.Array,  # [B, pages_per_seq]
     active: jax.Array,  # [B] bool — live slots (dead slots write trash page 0)
     cfg: GlmDsaConfig,
-    impl: str = "xla",
+    impl: str | None = None,
     scatter_impl: str = "xla",
     ragged_variant: str | None = None,
     mesh=None,

@@ -416,7 +416,7 @@ def load_hf_weights(model_dir, cfg: GraniteHybridConfig, *, quantization=None, d
 
 
 def paged_impl_plan(
-    cfg: GraniteHybridConfig, page_size: int, impl: str = "xla",
+    cfg: GraniteHybridConfig, page_size: int, impl: str | None = None,
     scatter_impl: str = "xla", *, kv_dtype="bfloat16", mesh=None, warn: bool = True,
 ) -> dict:
     """What runs for this model: the chunked XLA loop over the attention
@@ -424,7 +424,7 @@ def paged_impl_plan(
     ops.paged_attention.ragged_shapes_ok); anything else is refused here."""
     from ..ops.kv_quant import resolve_kv_dtype
 
-    if impl != "xla" or scatter_impl != "xla":
+    if impl not in (None, "xla") or scatter_impl != "xla":  # unset: as "xla"
         refuse(cfg, "a Pallas paged_impl or scatter_impl")
     if mesh is not None:
         refuse(cfg, "tensor parallelism")
@@ -881,7 +881,7 @@ def decode_step(
     page_tables: jax.Array,  # [B, pages_per_seq]
     active: jax.Array,  # [B] bool — live slots
     cfg: GraniteHybridConfig,
-    impl: str = "xla",
+    impl: str | None = None,
     scatter_impl: str = "xla",
     ragged_variant: str | None = None,
     mesh=None,

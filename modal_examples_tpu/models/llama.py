@@ -657,7 +657,7 @@ def tp_shard_ok(cfg: LlamaConfig, tp: int) -> bool:
 def paged_impl_plan(
     cfg: LlamaConfig,
     page_size: int,
-    impl: str = "xla",
+    impl: str | None = None,
     scatter_impl: str = "xla",
     *,
     kv_dtype="bfloat16",
@@ -667,11 +667,20 @@ def paged_impl_plan(
     """Resolve the decode structure that will ACTUALLY run for these shapes
     on the current backend — the single source of truth shared by
     ``decode_step`` and the engine's stats/metrics, so a requested pallas
-    impl that gets shape-downgraded (GQA Hkv<16, sub-128 head_dim) is
+    impl that gets shape-downgraded (sub-128 head_dim) is
     visible instead of silently benchmarking the XLA path (ADVICE r4).
 
-    ``kv_dtype`` ("int8" = the quantized QuantizedKV cache) affects the
-    flat-variant Hkv legality (int8 page flattens need Hkv%32, not %16).
+    ``impl`` left unset (None) means the plan decides, from what it can
+    observe: the ragged kernel (each slot's live pages DMAed once, straight
+    from the cache) where the backend is a TPU, ``ragged_shapes_ok`` holds
+    and the heads divide over the mesh; the chunked XLA loop everywhere
+    else (the CPU, where the kernel would run in the interpreter; ``tiny``'s
+    head width). One algorithm, an online softmax over a slot's pages, and
+    two ways of fetching them. ``"pallas"`` / ``"xla"`` force one, and a
+    forced kernel the shapes refuse is named in ``downgraded``.
+
+    ``kv_dtype`` ("int8" = the quantized QuantizedKV cache) is reported;
+    since PR 35 it no longer moves the variant (flat at Hkv%8 for both).
 
     ``mesh`` (a jax Mesh with a "tensor" axis) makes the plan PER-SHARD
     aware: under ``shard_map`` tensor parallelism the kernels see
@@ -686,6 +695,13 @@ def paged_impl_plan(
     "kv_dtype": str, "tp": int, "downgraded": [...]}``.
     """
     from ..ops.kv_quant import resolve_kv_dtype
+    # legality predicates live with the kernels (ops.paged_attention) so the
+    # plan and the wrappers cannot drift. Hkv does not gate the kernel:
+    # Hkv%8 shapes (GQA's 8, MHA's 32) take the "flat" all-heads
+    # formulation, smaller head shards the "grouped" per-kv-head one. Under
+    # TP the SHARD-local Hkv decides: the kernel inside shard_map sees
+    # Hkv // tp.
+    from ..ops.paged_attention import ragged_shapes_ok, ragged_variant_for
 
     kvd = resolve_kv_dtype(kv_dtype)
     kvd_name = "int8" if kvd == "int8" else str(kvd)
@@ -695,34 +711,25 @@ def paged_impl_plan(
     hkv_shard = cfg.n_kv_heads // tp if shard_ok else cfg.n_kv_heads
     downgraded = []
     ragged_variant = None
-    if impl == "pallas":
-        # legality predicates live with the kernels (ops.paged_attention)
-        # so the plan and the wrappers cannot drift. Hkv no longer gates
-        # the kernel (round 5): Hkv%16 shapes take the "flat" all-heads
-        # formulation, others (GQA Hkv=8, the llama-3-era serving targets)
-        # the "grouped" per-kv-head one. Under TP the SHARD-local Hkv
-        # decides (round 7): the kernel inside shard_map sees Hkv // tp.
-        from ..ops.paged_attention import ragged_shapes_ok, ragged_variant_for
-
-        ok = (not on_tpu or ragged_shapes_ok(cfg.head_dim, page_size)) and (
-            shard_ok
-        )
-        attention = "ragged" if ok else "xla-gather"
-        if ok:
-            ragged_variant = ragged_variant_for(hkv_shard, kvd_name)
-        elif not shard_ok:
-            downgraded.append(
-                f"paged_impl=pallas -> xla-gather (n_kv_heads="
-                f"{cfg.n_kv_heads}/n_heads={cfg.n_heads} not divisible by "
-                f"tp={tp}: head-sharded kernels need whole heads per shard)"
-            )
-        else:
-            downgraded.append(
-                f"paged_impl=pallas -> xla-gather (head_dim={cfg.head_dim}, "
-                f"page_size={page_size} fail D%128/ps%16 Mosaic tiling)"
-            )
+    shapes_ok = ragged_shapes_ok(cfg.head_dim, page_size)
+    if impl is None:
+        ok = on_tpu and shapes_ok and shard_ok
     else:
-        attention = "xla-gather"
+        ok = impl == "pallas" and (not on_tpu or shapes_ok) and shard_ok
+    attention = "ragged" if ok else "xla-gather"
+    if ok:
+        ragged_variant = ragged_variant_for(hkv_shard)
+    elif impl == "pallas" and not shard_ok:
+        downgraded.append(
+            f"paged_impl=pallas -> xla-gather (n_kv_heads="
+            f"{cfg.n_kv_heads}/n_heads={cfg.n_heads} not divisible by "
+            f"tp={tp}: head-sharded kernels need whole heads per shard)"
+        )
+    elif impl == "pallas":
+        downgraded.append(
+            f"paged_impl=pallas -> xla-gather (head_dim={cfg.head_dim}, "
+            f"page_size={page_size} fail D%128/ps%16 Mosaic tiling)"
+        )
     scatter = "xla"
     if scatter_impl == "pallas":
         from ..ops.paged_attention import scatter_shapes_ok
@@ -764,7 +771,7 @@ def decode_step(
     page_tables: jax.Array,  # [B, pages_per_seq]
     active: jax.Array,  # [B] bool — live slots (dead slots write trash page 0)
     cfg: LlamaConfig,
-    impl: str = "xla",
+    impl: str | None = None,
     scatter_impl: str = "xla",
     ragged_variant: str | None = None,  # None: auto (flat | grouped by Hkv)
     mesh=None,  # jax Mesh with a "tensor" axis: kernels run per head shard
@@ -778,7 +785,9 @@ def decode_step(
     model holds every expert its router names; zeros for a dense model).
     Pass donated pages for in-place updates under jit.
 
-    ``impl`` selects the attention ("xla" default, "pallas"). There is
+    ``impl`` selects the attention: None (the default) leaves it to
+    ``paged_impl_plan`` (the ragged kernel on a TPU where the shapes allow
+    it, the chunked loop elsewhere), "xla" / "pallas" force one. There is
     deliberately NO env-var fallback here: this function is jitted by its
     callers, an env read would happen at trace time and not be part of any
     jit cache key, so toggling the env after a trace would silently keep the
@@ -797,18 +806,19 @@ def decode_step(
     every step — the main gap between the measured 28 ms decode step and the
     16.5 ms weight-streaming floor (NOTES.md round 2).
 
-    impl="pallas" (round 4) keeps this same read-only structure but swaps
-    the attention for the v3 ragged kernel (ops.paged_decode_attention_ragged)
-    — it reads exactly ceil(ctx/page_size) pages per sequence where the XLA
+    The ragged plan keeps this same read-only structure but swaps
+    the attention for the ragged kernel (ops.paged_decode_attention_ragged)
+    — it reads exactly ceil(ctx/page_size) pages per sequence, straight
+    from the cache, where the XLA
     loop reads every slot as far as the batch's longest context, rounded up
-    to a chunk.
+    to a chunk, into a gathered copy.
     """
     B = tokens.shape[0]
     page_size = k_pages.shape[2]
-    # "pallas" = the v3 ragged kernel in the SAME read-only-pages structure
-    # as the default path (in-flight token as an extra softmax column, one
-    # scatter after the scan); shape legality + downgrade reporting live in
-    # paged_impl_plan (single source of truth with the engine's stats).
+    # the ragged kernel and the chunked loop share ONE read-only-pages
+    # structure (in-flight token as an extra softmax column, one scatter
+    # after the scan); the choice, shape legality + downgrade reporting live
+    # in paged_impl_plan (single source of truth with the engine's stats).
     # mesh= makes both per-shard aware: the pallas paths go through the
     # ops.sharded shard_map dispatchers, so TP serving keeps the kernels.
     kv_dtype = "int8" if is_quantized(k_pages) else str(k_pages.dtype)

@@ -27,6 +27,8 @@ from .paged_attention import (
     paged_latent_decode_attention_chunked,
     paged_decode_attention_inflight,
     paged_decode_attention_ragged,
+    ragged_kernel_sizes,
+    ragged_pages_read,
     scatter_kv_pages,
 )
 from .quantized_matmul import dequantize_int8, quantize_int8, quantized_matmul
@@ -60,6 +62,8 @@ __all__ = [
     "paged_latent_decode_attention_chunked",
     "paged_decode_attention_inflight",
     "paged_decode_attention_ragged",
+    "ragged_kernel_sizes",
+    "ragged_pages_read",
     "is_quantized",
     "kv_empty",
     "kv_gather",

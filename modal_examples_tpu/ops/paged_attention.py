@@ -25,14 +25,16 @@ Kernel design:
   per-DMA latency. Page tables + context lengths arrive via scalar prefetch
   (SMEM) so the kernel computes its own DMA addresses — the "ragged" part:
   each sequence reads exactly ceil(ctx/page_size) pages.
-- pages stream HBM→VMEM with double buffering, overlapped with the
-  online-softmax update of the previous page.
-- all heads in ONE MXU matmul per page: q rows (all Hq query heads) against
-  the page's (Hkv*page_size, D) keys with a block-diagonal head mask —
-  off-head logits are -inf so the p·V matmul accumulates per-head results
-  exactly. The off-diagonal FLOPs are free (the MXU is idle in a
-  bandwidth-bound kernel); what matters is that both contractions are
-  single dense (Hq, Hkv*ps, D) matmuls instead of Hkv tiny ones.
+- pages stream HBM→VMEM a chunk at a time through a ring of two halves
+  that carries over from one sequence to the next, overlapped with the
+  online-softmax updates of the chunk before (`_decode_kernel_ragged`).
+- all heads in ONE MXU matmul per update ("flat"): q rows (all Hq query
+  heads) against the pages' (Hkv*page_size, D) keys with a block-diagonal
+  head mask — off-head logits are -inf so the p·V matmul accumulates
+  per-head results exactly. The off-diagonal FLOPs are free (the MXU is
+  idle in a bandwidth-bound kernel); what matters is that both operands are
+  the pages as they lie, where a per-head slice of a token-major page is a
+  relayout of every element.
 
 Runs in interpreter mode off-TPU (CPU CI), with a dense XLA reference in
 ops.reference for ground truth.
@@ -323,147 +325,6 @@ def paged_latent_decode_attention_chunked(
     return acc / l[..., None]
 
 
-def _decode_kernel_ragged(
-    # scalar prefetch
-    layer_ref,  # (1,) int32, SMEM — which layer of the [L, P, ...] cache
-    page_tables_ref,  # (B * pages_per_seq,) int32, SMEM
-    prefix_lens_ref,  # (B,) int32, SMEM — tokens already IN the cache
-    # inputs — FULL arrays as single constant-index blocks: Mosaic skips the
-    # re-fetch when a block's index map is unchanged between grid steps, so
-    # q/k_new/v_new stream into VMEM once per pallas_call instead of paying
-    # 4 small block DMAs per program (measured ~18 us/program of pure
-    # overhead at 7B shapes with per-program (1, H, D) blocks)
-    q_ref,  # (B, Hq, D) VMEM
-    k_new_ref,  # (B, Hkv, D) VMEM — current token's K (not yet written)
-    v_new_ref,  # (B, Hkv, D) VMEM
-    k_hbm,  # (L, n_pages, page_size, Hkv, D) ANY/HBM
-    v_hbm,
-    # quantized=True adds ks_ref/vs_ref: this sequence's per-page scale rows,
-    # (1, pages_per_seq, page_size*Hkv) f32 VMEM blocks (see
-    # _gathered_scale_rows). `*rest` keeps ONE kernel for both layouts.
-    *rest,  # [ks_ref, vs_ref,] o_ref, k_scr, v_scr, acc_scr, sems
-    page_size: int,
-    pages_per_seq: int,
-    group: int,  # Hq // Hkv
-    sm_scale: float,
-    quantized: bool = False,
-):
-    """Ragged decode attention v3: prefix pages + ONE in-flight column.
-
-    A kernel that reads the current token back from the cache forces the
-    model to scatter each layer's KV *before* attention — a scan-threaded
-    cache, which XLA materializes as full cache copies (round-3 NOTES). v3
-    keeps the pages READ-ONLY (the fast decode structure: one scatter per
-    step, after the layer scan) by folding the current token's K/V — still in
-    registers — into the online softmax as one extra logit column, exactly
-    like ops.paged_decode_attention_inflight does in XLA. It also indexes the
-    full [L, P, ...] cache via a prefetched layer scalar, so the layer scan
-    never slices (= copies) a per-layer cache view. Reads exactly
-    ceil(prefix/page_size) pages per sequence — the XLA gather formulation
-    reads (and materializes) all pages_per_seq pages regardless of context,
-    the dominant, superlinear-in-slots decode cost in a builder's round-4
-    knock-out ablation (44 of 57 ms/step at 7B int8, 32 slots; not a driver
-    record).
-
-    With ``quantized=True`` the pages stream as int8 and the per-token-head
-    scales arrive as lane-major rows (one f32 per logit column), so the
-    dequant is a multiply on the (Hq, W) scores and probabilities instead of
-    on every (W, D) page element — KV HBM traffic is halved, the online
-    softmax math is unchanged.
-    """
-    if quantized:
-        ks_ref, vs_ref, o_ref, k_scr, v_scr, acc_scr, sems = rest
-    else:
-        o_ref, k_scr, v_scr, acc_scr, sems = rest
-    b = pl.program_id(0)
-    li = layer_ref[0]
-    prefix, n_pages, depth, k_dma, v_dma = _ragged_ring_setup(
-        li, page_tables_ref, prefix_lens_ref, b, k_hbm, v_hbm, k_scr, v_scr,
-        sems, pages_per_seq,
-    )
-
-    acc_scr[:] = jnp.zeros_like(acc_scr)
-    q = q_ref[b]  # (Hq, D) — stays in model dtype INTO the MXU (native
-    # mixed-precision, f32 accumulate); sm_scale is applied to the f32
-    # scores. Explicit astype(f32) on the page operands forced a Mosaic
-    # retile of every page (measured ~0.6 us of the ~2.3 us/page cost).
-    Hq, D = q.shape
-    Hkv = k_scr.shape[2]
-    W = page_size * Hkv  # token-major flatten: column c = (tok, head)
-
-    # static (Hq, W) head-alignment mask: query row r (kv head r // group)
-    # may only see columns of its own kv head (column c % Hkv). The
-    # off-head MXU FLOPs are the price of one dense matmul per page; at
-    # MHA (group=1, the 7B shape) that is Hkv x more logits than exist —
-    # the measured per-page cost is ~2 us compute-bound (a VPU
-    # mul+lane-reduce formulation measured the same, round 4).
-    row_head = jax.lax.broadcasted_iota(jnp.int32, (Hq, W), 0) // group
-    col_head = jax.lax.broadcasted_iota(jnp.int32, (Hq, W), 1) % Hkv
-    head_ok = row_head == col_head
-    col_tok = jax.lax.broadcasted_iota(jnp.int32, (Hq, W), 1) // Hkv
-
-    def body(i, carry):
-        m_prev, l_prev = carry  # (Hq, 1) each
-        slot = jax.lax.rem(i, depth)
-
-        # refill the slot consumed LAST iteration (its loads are done:
-        # sequential loop order) with the page depth-1 ahead — keeps
-        # depth-1 transfers in flight so the DMA engine streams
-        # back-to-back instead of paying issue latency per page
-        @pl.when(i + depth - 1 < n_pages)
-        def _prefetch():
-            nxt = jax.lax.rem(i + depth - 1, depth)
-            k_dma(nxt, i + depth - 1).start()
-            v_dma(nxt, i + depth - 1).start()
-
-        k_dma(slot, i).wait()
-        v_dma(slot, i).wait()
-        k = k_scr[slot].reshape(W, D)  # cache dtype, no retile
-        v = v_scr[slot].reshape(W, D)
-        if quantized:
-            # int8 values are exact at the query's compute dtype; their
-            # scales multiply the scores (K) and probabilities (V) below
-            k = k.astype(q.dtype)
-            v = v.astype(q.dtype)
-
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * sm_scale  # (Hq, W) f32
-        if quantized:
-            s = s * ks_ref[0, pl.ds(i, 1), :]  # (1, W): column c's k scale
-        valid = head_ok & (i * page_size + col_tok < prefix)
-        s = jnp.where(valid, s, -jnp.inf)
-
-        m_cur = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        m_safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
-        p = jnp.where(jnp.isfinite(m_new), jnp.exp(s - m_safe), 0.0)
-        alpha = jnp.where(
-            jnp.isfinite(m_prev), jnp.exp(m_prev - m_safe), 0.0
-        )
-        l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        if quantized:
-            p = p * vs_ref[0, pl.ds(i, 1), :]
-        pv = jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # flash-attention numerics: f32 softmax, cache-dtype PV operands
-        acc_scr[:] = acc_scr[:] * alpha + pv
-        return m_new, l_new
-
-    init = (
-        jnp.full((Hq, 1), -jnp.inf, jnp.float32),
-        jnp.zeros((Hq, 1), jnp.float32),
-    )
-    m_prev, l_prev = jax.lax.fori_loop(0, n_pages, body, init)
-
-    _inflight_epilogue(
-        q, k_new_ref, v_new_ref, b, o_ref, acc_scr, m_prev, l_prev, group,
-        sm_scale,
-    )
-
-
 def ragged_shapes_ok(head_dim: int, page_size: int) -> bool:
     """Mosaic legality for the ragged decode kernels on TPU: pages must be
     whole (16, 128) bf16 tiles for the HBM→VMEM DMAs. Single source of
@@ -472,23 +333,24 @@ def ragged_shapes_ok(head_dim: int, page_size: int) -> bool:
     return head_dim % 128 == 0 and page_size % 16 == 0
 
 
-def flat_variant_hkv_multiple(kv_dtype: str = "bfloat16") -> int:
-    """The Hkv multiple the "flat" variant's (ps, Hkv, D) -> (ps*Hkv, D)
-    page flatten needs: the sublane count of one packed Mosaic tile —
-    16 for bf16, 32 for int8 ((32, 128) tiles)."""
-    return 32 if str(kv_dtype) == "int8" else 16
+#: the KV-head multiple the "flat" variant needs: it reads a page as
+#: ``(ps*Hkv, D)`` rows, a view XLA makes of the ``[L, P, ps, Hkv, D]`` cache
+#: in HBM, and that reshape is a bitcast (no copy of the cache) when a token's
+#: ``(Hkv, D)`` slab is whole ``T(8, 128)`` tiles: 8 rows, for bf16 and for
+#: int8 pages alike (compiled for the v5e, PR 35; until then the flatten was
+#: done in VMEM and wanted 16 / 32)
+FLAT_VARIANT_HKV_MULTIPLE = 8
 
 
-def ragged_variant_for(n_kv_heads: int, kv_dtype: str = "bfloat16") -> str:
-    """Default kernel formulation: "flat" (one all-heads matmul) needs the
-    (ps, Hkv, D) -> (ps*Hkv, D) flatten, legal only at Hkv % tile-sublanes
-    (16 bf16, 32 int8); everything else (GQA) takes "grouped" (per-kv-head
-    contractions)."""
-    return (
-        "flat"
-        if n_kv_heads % flat_variant_hkv_multiple(kv_dtype) == 0
-        else "grouped"
-    )
+def ragged_variant_for(n_kv_heads: int) -> str:
+    """Default kernel formulation: "flat" (one all-heads block-diagonal
+    matmul over pages read as ``(ps*Hkv, D)`` rows) wherever that view is
+    free (``FLAT_VARIANT_HKV_MULTIPLE``): on the v5e at 8 KV heads of 128
+    it runs 1.9x faster than "grouped" (per-kv-head contractions), whose
+    head slices of a token-major page are a relayout of every K/V element
+    (PERF.md section 6, PR 35). Everything else (a head shard of 1, 2 or 4
+    KV heads under tensor parallelism) takes "grouped"."""
+    return "grouped" if n_kv_heads % FLAT_VARIANT_HKV_MULTIPLE else "flat"
 
 
 def scatter_shapes_ok(head_dim: int) -> bool:
@@ -496,46 +358,49 @@ def scatter_shapes_ok(head_dim: int) -> bool:
     return head_dim % 128 == 0
 
 
-def _ragged_ring_setup(
-    li, page_tables_ref, prefix_lens_ref, b, k_hbm, v_hbm, k_scr, v_scr,
-    sems, pages_per_seq,
-):
-    """v3 (flat) DMA-ring prologue: page-id lookup, K/V copy factories,
-    and the warm-up that puts depth-1 page transfers in flight. The
-    grouped kernel streams at CHUNK granularity with clamped page ids and
-    owns its own inlined version."""
-    prefix = prefix_lens_ref[b]
-    page_size = k_scr.shape[1]
-    n_pages = pl.cdiv(prefix, page_size)
+def ragged_pages_read(prefix, page_size: int):
+    """Pages of a slot the ragged kernel DMAs for a prefix of ``prefix``
+    positions: the live ones, none for a dead slot. The kernel's loop bounds
+    and the engine's count of what a decode step reads
+    (``mtpu_decode_kv_positions_total``) both come from here; whole-number
+    arithmetic on a traced scalar, a Python int or a numpy array."""
+    return (prefix + page_size - 1) // page_size
 
-    def page_id(i):
-        return page_tables_ref[b * pages_per_seq + i]
 
-    def k_dma(slot, i):
-        return pltpu.make_async_copy(
-            k_hbm.at[li, page_id(i)], k_scr.at[slot], sems.at[slot, 0]
-        )
+#: the ragged kernel's sizes, tuned once on the v5e at Mistral-7B's cache
+#: shapes (16 slots, 8 KV heads of 128, pages of 16, bf16; PERF.md section 6,
+#: PR 35, ``benchmarks/ragged_micro.py``). The ring: K and V, two halves of
+#: ``chunk`` pages each; 2 MiB is 16 pages a half there (a half in flight
+#: behind the one computed: 32 pages ran no faster, 8 pages 6% slower).
+_RING_BYTES = 2 * 1024 * 1024
+#: logit columns of one softmax update of the flat form (pages x ps x Hkv:
+#: 16 pages = 256 positions at 8 KV heads; 128 positions ran 8% slower,
+#: 64 positions 33% slower) and positions of one of the grouped form
+_FLAT_UPDATE_COLUMNS = 2048
+_GROUPED_UPDATE_POSITIONS = 128
 
-    def v_dma(slot, i):
-        return pltpu.make_async_copy(
-            v_hbm.at[li, page_id(i)], v_scr.at[slot], sems.at[slot, 1]
-        )
 
-    depth = k_scr.shape[0]
-    for j in range(depth - 1):
-        @pl.when(j < n_pages)
-        def _(j=j):
-            k_dma(j, j).start()
-            v_dma(j, j).start()
-
-    return prefix, n_pages, depth, k_dma, v_dma
+def ragged_kernel_sizes(
+    variant: str, page_size: int, n_kv_heads: int, head_dim: int,
+    itemsize: int, pages_per_seq: int,
+) -> tuple[int, int]:
+    """``(chunk, update)``: pages one half of the DMA ring holds, and pages
+    one online-softmax update covers (``update`` divides ``chunk``)."""
+    if variant == "flat":
+        update = _FLAT_UPDATE_COLUMNS // (page_size * n_kv_heads)
+    else:
+        update = _GROUPED_UPDATE_POSITIONS // page_size
+    update = max(1, min(update, pages_per_seq))
+    page_bytes = page_size * n_kv_heads * head_dim * itemsize
+    updates = max(1, _RING_BYTES // (4 * page_bytes * update))
+    return update * min(updates, -(-pages_per_seq // update)), update
 
 
 def _inflight_epilogue(
     q, k_new_ref, v_new_ref, b, o_ref, acc_scr, m_prev, l_prev, group,
     sm_scale,
 ):
-    """Shared v3/v4 epilogue: fold the current token's K/V (still in
+    """Fold the current token's K/V (still in
     registers, not yet written to the cache) into the online softmax as one
     extra column, normalize, and write the output row. Per q row r the only
     valid kv head is r // group — selected via a (Hq, Hkv) mask so both
@@ -566,158 +431,212 @@ def _inflight_epilogue(
     o_ref[b] = (acc / l_safe).astype(o_ref.dtype)
 
 
-def _decode_kernel_ragged_grouped(
+def _decode_kernel_ragged(
     # scalar prefetch
-    layer_ref,  # (1,) int32, SMEM
+    layer_ref,  # (1,) int32, SMEM — which layer of the [L, P, ...] cache
     page_tables_ref,  # (B * pages_per_seq,) int32, SMEM
-    prefix_lens_ref,  # (B,) int32, SMEM
-    # inputs (same constant-index full-array blocks as v3)
+    prefix_lens_ref,  # (B,) int32, SMEM — tokens already IN the cache
+    # inputs — FULL arrays as single constant-index blocks: Mosaic skips the
+    # re-fetch when a block's index map is unchanged between grid steps, so
+    # q/k_new/v_new stream into VMEM once per pallas_call instead of paying
+    # 4 small block DMAs per program (measured ~18 us/program of pure
+    # overhead at 7B shapes with per-program (1, H, D) blocks)
     q_ref,  # (B, Hq, D) VMEM
-    k_new_ref,  # (B, Hkv, D) VMEM
+    k_new_ref,  # (B, Hkv, D) VMEM — current token's K (not yet written)
     v_new_ref,  # (B, Hkv, D) VMEM
-    k_hbm,  # (L, n_pages, page_size, Hkv, D) ANY/HBM
-    v_hbm,
-    # quantized=True adds ks_ref/vs_ref: this sequence's per-head scale rows,
-    # (1, Hkv, n_chunks, chunk*page_size) f32 VMEM blocks (see
-    # _gathered_scale_rows)
-    *rest,  # [ks_ref, vs_ref,] o_ref, k_scr, v_scr, acc_scr, sems
+    k_hbm,  # ANY/HBM: flat (L, n_pages, page_size*Hkv, D), grouped
+    v_hbm,  #          (L, n_pages, page_size, Hkv, D)
+    # quantized=True adds ks_ref/vs_ref: this sequence's scale rows, one
+    # row per softmax update (see _gathered_scale_rows)
+    *rest,  # [ks_ref, vs_ref,] o_ref, k_scr, v_scr, acc_scr, sems, state
+    variant: str,
     page_size: int,
     pages_per_seq: int,
-    group: int,
+    n_kv_heads: int,
+    group: int,  # Hq // Hkv
     sm_scale: float,
-    chunk: int,
+    chunk: int,  # pages one half of the ring holds
+    update: int,  # pages one softmax update covers; divides chunk
     quantized: bool = False,
 ):
-    """Ragged decode attention v4 ("grouped"): per-kv-head contractions
-    over CHUNKS of pages.
+    """Ragged decode attention: one grid step a sequence, its live pages
+    DMAed once from the [L, P, ...] cache, the in-flight token folded in.
 
-    Differences from v3 (`_decode_kernel_ragged`), same online-softmax
-    math:
-    - logits come from Hkv unrolled (G, D) x (D, chunk*page_size) matmuls
-      — one per kv head — instead of one (Hq, page_size*Hkv, D)
-      block-diagonal matmul. Computes EXACTLY the real logits: v3 computes
-      Hkv x more than exist at MHA, and the per-page cost evidence says
-      the masked logits' `exp`s are what the ~2 us/page buys (NOTES r5
-      "attention cost analysis").
-    - no (ps, Hkv, D) -> (ps*Hkv, D) flatten, so the Hkv % 16 Mosaic
-      relayout constraint disappears: GQA models (llama-3.1's Hkv=8) run
-      the kernel instead of falling back to the XLA gather (the
-      reference's serving targets are GQA-era, vllm_inference.py:54-58).
-    - `chunk` pages per softmax update: the logits tile is
-      (Hq, chunk*ps) — chunk=8 at ps=16 fills all 128 VPU lanes (a
-      single-page (Hq, 16) tile wastes 7/8 of each vreg) and amortizes
-      the per-iteration sem-wait/loop overhead by chunk x. The DMA ring
-      is two half-buffers of `chunk` pages (scratch depth = 2*chunk):
-      the next chunk streams while the current one computes.
-    The trade: Hkv small matmuls per chunk at G-row MXU utilization.
+    The pages stay READ-ONLY (the fast decode structure: one scatter per
+    step, after the layer scan): the current token's K/V, still in
+    registers, joins the online softmax as one extra logit column, exactly
+    like ``paged_decode_attention_inflight`` does in XLA, and the layer
+    scan never slices (= copies) a per-layer cache view: the layer is a
+    prefetched scalar.
 
-    ``quantized=True`` streams int8 pages and multiplies each head's scores
-    and probabilities by its lane-major scale row — same online softmax,
-    half the KV HBM traffic.
+    *The fetch.* A ring of two halves of ``chunk`` pages for K and for V.
+    A sequence's pages are fetched a chunk at a time, ``ceil(prefix /
+    page_size)`` of them in all (the last chunk only as far as it is live),
+    one DMA a page, all of a half's K (V) pages on ONE semaphore; a full
+    chunk is awaited by one wait sized to the half, a partial one page by
+    page. While a chunk is computed the next is in flight, and the next of a
+    sequence's LAST chunk is the first chunk of the next live sequence: the
+    ring and ``state`` (which half holds it) persist across grid steps, so
+    no sequence but the first starts with nothing in flight (the cold start
+    was 15% of the call at 4-5 chunks a sequence; PERF.md section 6, PR 35).
+    Rows of a half past a partial chunk hold an earlier chunk's pages or the
+    zeros written at the first grid step: finite, so a masked probability
+    (exactly 0) times them is 0.
+
+    *The products*, per update of ``update`` pages, same online softmax:
+    - ``"flat"``: one block-diagonal all-heads matmul. A page is
+      ``(ps*Hkv, D)`` rows (token-major), q's Hq rows meet all of them and
+      a static mask keeps row r's own head (column c % Hkv == r // group).
+      The off-head logits are the price of operands that need no relayout:
+      Hkv x more exps than exist, on a VPU that has the time.
+    - ``"grouped"``: Hkv unrolled (G, D) x (D, update*ps) matmuls, only
+      real logits; each head's slice of the token-major pages is a strided
+      relayout of the whole chunk, which is what bounds it.
+
+    With ``quantized=True`` the pages stream as int8 and the per-token-head
+    scales arrive as lane-major rows (one f32 per logit column), so the
+    dequant is a multiply on the scores and probabilities instead of on
+    every page element — KV HBM traffic is halved, the online softmax math
+    is unchanged.
     """
     if quantized:
-        ks_ref, vs_ref, o_ref, k_scr, v_scr, acc_scr, sems = rest
+        ks_ref, vs_ref, o_ref, k_scr, v_scr, acc_scr, sems, state = rest
     else:
-        o_ref, k_scr, v_scr, acc_scr, sems = rest
+        o_ref, k_scr, v_scr, acc_scr, sems, state = rest
     b = pl.program_id(0)
+    B = q_ref.shape[0]
     li = layer_ref[0]
+    C, U, ps, pp = chunk, update, page_size, pages_per_seq
+    Hkv, G = n_kv_heads, group
     prefix = prefix_lens_ref[b]
-    C = chunk
-    # chunk-granular streaming: a processed chunk loads ALL C of its page
-    # slots — trailing lanes past the context clamp to a real table entry
-    # (a duplicate page), so scratch never holds uninitialized data. The
-    # duplicate's logits are masked to -inf, which matters in the p.V
-    # matmul: 0 x finite = 0, whereas a garbage (NaN) page would poison
-    # the contraction despite the mask.
-    n_chunks = pl.cdiv(prefix, C * page_size)
-    n_pages = pl.cdiv(prefix, page_size)
+    n_pages = ragged_pages_read(prefix, ps)
+    n_chunks = pl.cdiv(n_pages, C)
 
-    def page_id(i):
-        # clamp into the sequence's ALLOCATED pages (n_pages >= 1 whenever
-        # any DMA is issued, since n_chunks > 0 implies prefix > 0): table
-        # entries beyond the allocation may be caller padding
-        return page_tables_ref[
-            b * pages_per_seq + jax.lax.min(i, n_pages - 1)
-        ]
+    def start_chunk(seq, i, half):
+        """Start the DMAs of ``seq``'s live pages in its chunk ``i``."""
+        live = jnp.minimum(C, ragged_pages_read(prefix_lens_ref[seq], ps) - i * C)
 
-    def k_dma(slot, i):
-        return pltpu.make_async_copy(
-            k_hbm.at[li, page_id(i)], k_scr.at[slot], sems.at[slot, 0]
-        )
+        def one(j, _):
+            page = page_tables_ref[seq * pp + i * C + j]
+            pltpu.make_async_copy(
+                k_hbm.at[li, page], k_scr.at[half * C + j], sems.at[half, 0]
+            ).start()
+            pltpu.make_async_copy(
+                v_hbm.at[li, page], v_scr.at[half * C + j], sems.at[half, 1]
+            ).start()
+            return 0
 
-    def v_dma(slot, i):
-        return pltpu.make_async_copy(
-            v_hbm.at[li, page_id(i)], v_scr.at[slot], sems.at[slot, 1]
-        )
+        jax.lax.fori_loop(0, live, one, 0)
 
-    # warm-up: chunk 0 into half 0 (every chunk's start has exactly one
-    # matching wait in the body: warmup pairs with iteration 0)
-    @pl.when(n_chunks > 0)
+    def wait_chunk(live, half):
+        # a wait takes its byte count from the descriptor, not its address
+        @pl.when(live == C)
+        def _():
+            for scr, hbm, kv in ((k_scr, k_hbm, 0), (v_scr, v_hbm, 1)):
+                pltpu.make_async_copy(
+                    hbm.at[li, pl.ds(0, C)], scr.at[pl.ds(half * C, C)],
+                    sems.at[half, kv],
+                ).wait()
+
+        @pl.when(live < C)
+        def _():
+            def one(j, _):
+                for scr, hbm, kv in ((k_scr, k_hbm, 0), (v_scr, v_hbm, 1)):
+                    pltpu.make_async_copy(
+                        hbm.at[li, 0], scr.at[half * C + j], sems.at[half, kv]
+                    ).wait()
+                return 0
+
+            jax.lax.fori_loop(0, live, one, 0)
+
+    @pl.when(b == 0)
     def _():
-        for j in range(C):
-            k_dma(j, j).start()
-            v_dma(j, j).start()
+        state[0] = 0  # the half that holds the next live sequence's chunk 0
+        state[1] = 0  # whether an earlier grid step started that chunk
+        v_scr[...] = jnp.zeros_like(v_scr)
+
+    fetched = state[1] == 1
+    half0 = jnp.where(fetched, state[0], 0)
+    # the next live sequence, B if there is none
+    nxt_seq = jax.lax.while_loop(
+        lambda j: jnp.logical_and(
+            j < B, prefix_lens_ref[jnp.minimum(j, B - 1)] == 0
+        ),
+        lambda j: j + 1,
+        b + 1,
+    )
+
+    @pl.when(jnp.logical_and(n_chunks > 0, jnp.logical_not(fetched)))
+    def _():
+        start_chunk(b, 0, 0)
 
     acc_scr[:] = jnp.zeros_like(acc_scr)
-    q = q_ref[b]  # (Hq, D) model dtype into the MXU, f32 accumulate
+    q = q_ref[b]  # (Hq, D) — stays in model dtype INTO the MXU (native
+    # mixed-precision, f32 accumulate); sm_scale is applied to the f32
+    # scores. Explicit astype(f32) on the page operands forces a Mosaic
+    # retile of every page.
     Hq, D = q.shape
-    Hkv = k_scr.shape[2]
-    G = group
-    ps = page_size
-    W = C * ps  # chunk row = (page_in_chunk, token_in_page), row-major
-    col_tok = jax.lax.broadcasted_iota(jnp.int32, (Hq, W), 1)
+    if variant == "flat":
+        R = ps * Hkv  # a page's rows: row = (token, head)
+        cols = jax.lax.broadcasted_iota(jnp.int32, (Hq, U * R), 1)
+        rows = jax.lax.broadcasted_iota(jnp.int32, (Hq, U * R), 0)
+        own_head = (rows // G) == (cols % Hkv)
+        col_tok = cols // Hkv
+    else:
+        W = U * ps  # an update's columns: (page, token in page)
+        col_tok = jax.lax.broadcasted_iota(jnp.int32, (Hq, W), 1)
 
-    def body(i, carry):
+    def operand(scr, page0, h=None):
+        """An update's keys/values at scratch page ``page0`` (one head's for
+        the grouped form). int8 values are exact at the query's dtype; their
+        scales multiply the scores and probabilities instead."""
+        if h is None:
+            x = scr[pl.ds(page0, U)]
+        else:
+            x = scr[pl.ds(page0, U), :, h, :]
+        if quantized:
+            x = x.astype(q.dtype)
+        return x.reshape(-1, D)
+
+    def scale_rows(scale_ref, u):
+        """(1 or Hq, columns) scales of the sequence's update ``u``."""
+        if variant == "flat":
+            return scale_ref[0, pl.ds(u, 1), :]
+        return jnp.concatenate(
+            [
+                jnp.broadcast_to(scale_ref[0, h, pl.ds(u, 1), :], (G, W))
+                for h in range(Hkv)
+            ],
+            axis=0,
+        )
+
+    def softmax_update(carry, page0, u):
         m_prev, l_prev = carry  # (Hq, 1) each
-        base = jax.lax.rem(i, 2) * C
-        nxt_base = jax.lax.rem(i + 1, 2) * C
-
-        # stream the NEXT chunk into the other half while this one computes
-        @pl.when(i + 1 < n_chunks)
-        def _():
-            for j in range(C):
-                k_dma(nxt_base + j, (i + 1) * C + j).start()
-                v_dma(nxt_base + j, (i + 1) * C + j).start()
-        # wait this chunk's pages (all C were started: warmup or prefetch)
-        for j in range(C):
-            k_dma(base + j, i * C + j).wait()
-            v_dma(base + j, i * C + j).wait()
-
-        def head_slice(scr, h):
-            """The head's (chunk*ps, D) keys/values; int8 values are exact
-            at the query's compute dtype (their scales multiply the scores
-            and probabilities instead)."""
-            x = scr[pl.ds(base, C), :, h, :]
-            if quantized:
-                x = x.astype(q.dtype)
-            return x.reshape(W, D)
-
-        def head_rows(scale_ref):
-            """(Hq, W) scale rows for this chunk: row h*G+g carries kv head
-            h's per-column scales."""
-            return jnp.concatenate(
+        nt = (((1,), (1,)), ((), ()))
+        nn = (((1,), (0,)), ((), ()))
+        if variant == "flat":
+            s = jax.lax.dot_general(
+                q, operand(k_scr, page0), nt,
+                preferred_element_type=jnp.float32,
+            )  # (Hq, U * R)
+        else:
+            s = jnp.concatenate(
                 [
-                    jnp.broadcast_to(scale_ref[0, h, pl.ds(i, 1), :], (G, W))
+                    jax.lax.dot_general(
+                        q[h * G : (h + 1) * G], operand(k_scr, page0, h), nt,
+                        preferred_element_type=jnp.float32,
+                    )
                     for h in range(Hkv)
                 ],
                 axis=0,
-            )
-
-        # per-kv-head: query rows h*G:(h+1)*G against the head's
-        # (chunk*ps, D) keys — static head slices, unrolled over Hkv
-        s_parts = []
-        for h in range(Hkv):
-            s_parts.append(
-                jax.lax.dot_general(
-                    q[h * G : (h + 1) * G], head_slice(k_scr, h),
-                    (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32,
-                )
-            )
-        s = jnp.concatenate(s_parts, axis=0) * sm_scale  # (Hq, W) f32
+            )  # (Hq, W)
+        s = s * sm_scale
         if quantized:
-            s = s * head_rows(ks_ref)
-        s = jnp.where(i * W + col_tok < prefix, s, -jnp.inf)
+            s = s * scale_rows(ks_ref, u)
+        valid = u * (U * ps) + col_tok < prefix
+        if variant == "flat":
+            valid = own_head & valid
+        s = jnp.where(valid, s, -jnp.inf)
 
         m_cur = jnp.max(s, axis=-1, keepdims=True)
         m_new = jnp.maximum(m_prev, m_cur)
@@ -728,34 +647,72 @@ def _decode_kernel_ragged_grouped(
         )
         l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
         if quantized:
-            p = p * head_rows(vs_ref)
-        pv_parts = []
-        for h in range(Hkv):
-            v_h = head_slice(v_scr, h)
-            pv_parts.append(
-                jax.lax.dot_general(
-                    p[h * G : (h + 1) * G].astype(v_h.dtype), v_h,
-                    (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32,
-                )
+            p = p * scale_rows(vs_ref, u)
+        # flash-attention numerics: f32 softmax, cache-dtype PV operands
+        if variant == "flat":
+            v = operand(v_scr, page0)
+            pv = jax.lax.dot_general(
+                p.astype(v.dtype), v, nn, preferred_element_type=jnp.float32
             )
-        acc_scr[:] = acc_scr[:] * alpha + jnp.concatenate(pv_parts, axis=0)
+        else:
+            parts = []
+            for h in range(Hkv):
+                v = operand(v_scr, page0, h)
+                parts.append(
+                    jax.lax.dot_general(
+                        p[h * G : (h + 1) * G].astype(v.dtype), v, nn,
+                        preferred_element_type=jnp.float32,
+                    )
+                )
+            pv = jnp.concatenate(parts, axis=0)
+        acc_scr[:] = acc_scr[:] * alpha + pv
         return m_new, l_new
+
+    def chunk_body(i, carry):
+        half = jax.lax.rem(half0 + i, 2)
+        more = i + 1 < n_chunks
+
+        # into the half computed last: this sequence's next chunk, or after
+        # its last the next live sequence's first
+        @pl.when(jnp.logical_or(more, nxt_seq < B))
+        def _():
+            start_chunk(
+                jnp.where(more, b, jnp.minimum(nxt_seq, B - 1)),
+                jnp.where(more, i + 1, 0),
+                1 - half,
+            )
+
+        live = jnp.minimum(C, n_pages - i * C)
+        wait_chunk(live, half)
+        return jax.lax.fori_loop(
+            0, pl.cdiv(live, U),
+            lambda k, carry: softmax_update(
+                carry, half * C + k * U, i * (C // U) + k
+            ),
+            carry,
+        )
 
     init = (
         jnp.full((Hq, 1), -jnp.inf, jnp.float32),
         jnp.zeros((Hq, 1), jnp.float32),
     )
-    m_prev, l_prev = jax.lax.fori_loop(0, n_chunks, body, init)
+    m_prev, l_prev = jax.lax.fori_loop(0, n_chunks, chunk_body, init)
+
+    @pl.when(n_chunks > 0)
+    def _():
+        state[0] = jax.lax.rem(half0 + n_chunks, 2)
+        state[1] = (nxt_seq < B).astype(jnp.int32)
+
     _inflight_epilogue(
         q, k_new_ref, v_new_ref, b, o_ref, acc_scr, m_prev, l_prev, group,
         sm_scale,
     )
 
 
-def _gathered_scale_rows(scale, layer, page_tables, variant, chunk):
+def _gathered_scale_rows(scale, layer, page_tables, variant, update):
     """Each sequence's int8-KV scales as lane-major rows, one row per
-    softmax update of the kernel, gathered by XLA outside it.
+    softmax update of the kernel (``update`` pages), gathered by XLA
+    outside it.
 
     The cache keeps scales as ``[L, P, page_size, Hkv]`` f32. Mosaic cannot
     DMA a page's ``(page_size, Hkv)`` slab out of that (an HBM slice must
@@ -763,21 +720,21 @@ def _gathered_scale_rows(scale, layer, page_tables, variant, chunk):
     kernels want the scales along the LOGIT axis anyway — one f32 per score
     column — so they arrive as an ordinary VMEM-blocked input:
 
-    - ``flat``: ``[B, pages_per_seq, page_size*Hkv]`` — row i is page i's
-      scales in the kernel's column order c = tok*Hkv + head;
-    - ``grouped``: ``[B, Hkv, n_chunks, chunk*page_size]`` — row (h, i) is
-      kv head h's scales for chunk i's columns (page_in_chunk, tok).
+    - ``flat``: ``[B, n_updates, update*page_size*Hkv]`` — row u is update
+      u's scales in the kernel's column order c = (page, tok, head);
+    - ``grouped``: ``[B, Hkv, n_updates, update*page_size]`` — row (h, u)
+      is kv head h's scales for update u's columns (page, tok).
 
     Unlike the pages this reads all ``pages_per_seq`` rows whatever the
     context; scales are 1/32 of the int8 page bytes at D=128."""
     B, pp = page_tables.shape
     ps, Hkv = scale.shape[2:]
     rows = scale[layer, page_tables]  # [B, pp, ps, Hkv]
+    n_updates = -(-pp // update)
+    rows = jnp.pad(rows, ((0, 0), (0, n_updates * update - pp), (0, 0), (0, 0)))
     if variant == "flat":
-        return rows.reshape(B, pp, ps * Hkv)
-    n_chunks = -(-pp // chunk)
-    rows = jnp.pad(rows, ((0, 0), (0, n_chunks * chunk - pp), (0, 0), (0, 0)))
-    return rows.transpose(0, 3, 1, 2).reshape(B, Hkv, n_chunks, chunk * ps)
+        return rows.reshape(B, n_updates, update * ps * Hkv)
+    return rows.transpose(0, 3, 1, 2).reshape(B, Hkv, n_updates, update * ps)
 
 
 def paged_decode_attention_ragged(
@@ -792,19 +749,21 @@ def paged_decode_attention_ragged(
     *,
     sm_scale: float | None = None,
     interpret: bool | None = None,
-    variant: str | None = None,  # None: "flat" if Hkv%16==0 else "grouped"
+    variant: str | None = None,  # None: ragged_variant_for(Hkv)
+    chunk_pages: int | None = None,  # None: ragged_kernel_sizes (to A/B)
+    update_pages: int | None = None,
 ) -> jax.Array:  # [B, Hq, D]
     """Pallas ragged decode attention over prefix pages + the in-flight
     token. Drop-in exact match for ``paged_decode_attention_inflight``
-    given ``ks = k_pages[layer, page_tables]``.
+    given ``ks = k_pages[layer, page_tables]``; reads ``ragged_pages_read``
+    pages a sequence, nothing for a dead slot.
 
-    Two kernel formulations share the DMA/online-softmax structure:
-    - ``"flat"`` (v3, `_decode_kernel_ragged`): one block-diagonal
-      all-heads matmul per page; needs Hkv%16 for the page flatten.
-    - ``"grouped"`` (v4, `_decode_kernel_ragged_grouped`): Hkv per-kv-head
-      matmuls — only real logits, any Hkv (GQA's Hkv=8 included).
-    Default picks flat where legal (the round-4 measured configuration)
-    and grouped otherwise; pass ``variant=`` explicitly to A/B.
+    Two formulations of the products share the fetch and the online softmax
+    (`_decode_kernel_ragged`): ``"flat"`` (one block-diagonal all-heads
+    matmul; needs Hkv%8 on the chip) and ``"grouped"`` (Hkv per-kv-head
+    matmuls, any Hkv). ``variant=`` / ``chunk_pages=`` / ``update_pages=``
+    override what ``ragged_variant_for`` and ``ragged_kernel_sizes`` pick,
+    to A/B (``benchmarks/ragged_micro.py``).
 
     ``k_pages``/``v_pages`` may be int8 :class:`~.kv_quant.QuantizedKV`
     caches: both variants then DMA the int8 pages and apply the scales
@@ -823,9 +782,8 @@ def paged_decode_attention_ragged(
         sm_scale = D**-0.5
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    kv_dtype = "int8" if quantized else "bfloat16"
     if variant is None:
-        variant = ragged_variant_for(Hkv, kv_dtype)
+        variant = ragged_variant_for(Hkv)
     if variant not in ("flat", "grouped"):
         raise ValueError(f"unknown variant {variant!r}: flat | grouped")
     if not interpret and not ragged_shapes_ok(D, page_size):
@@ -835,30 +793,42 @@ def paged_decode_attention_ragged(
             f"paged_decode_attention_ragged needs head_dim%128==0 and "
             f"page_size%16==0 on TPU; got D={D}, page_size={page_size}"
         )
-    flat_mult = flat_variant_hkv_multiple(kv_dtype)
-    if not interpret and variant == "flat" and Hkv % flat_mult:
+    if not interpret and variant == "flat" and Hkv % FLAT_VARIANT_HKV_MULTIPLE:
         raise ValueError(
-            f"variant='flat' needs n_kv_heads%{flat_mult}==0 on TPU for "
-            f"{kv_dtype} pages (the (ps, Hkv, D) -> (ps*Hkv, D) flatten); "
-            f"got Hkv={Hkv} — use variant='grouped' (the default for this "
-            "shape)"
+            f"variant='flat' needs n_kv_heads%{FLAT_VARIANT_HKV_MULTIPLE}==0 "
+            f"on TPU (a page read as (ps*Hkv, D) rows without a copy of the "
+            f"cache); got Hkv={Hkv} — use variant='grouped' (the default "
+            "for this shape)"
         )
 
     # int8 caches compute at (and fold the in-flight token at) the query's
-    # dtype; plain caches keep their own dtype into the MXU exactly as
-    # before (no retile, bit-identical default path)
+    # dtype; plain caches keep their own dtype into the MXU (no retile)
     compute_dtype = q.dtype if quantized else k_pages.dtype
-    # DMA ring depth: enough in-flight pages to hide issue latency (measured
-    # ~2.3 us/page at depth 2), capped so K+V scratch stays ~<=4 MB of VMEM.
-    # int8 pages are half the bytes, so the same budget holds twice the ring
-    page_bytes = page_size * Hkv * D * k_pages.dtype.itemsize
-    depth = max(2, min(pages_per_seq, (2 * 1024 * 1024) // max(page_bytes, 1)))
-    chunk = 1
-    if variant == "grouped":
-        # chunked updates: up to 8 pages per softmax step (8*ps=128 lanes
-        # at ps=16 — a full vreg row), double-buffered halves
-        chunk = max(1, min(8, pages_per_seq, depth // 2))
-        depth = 2 * chunk
+    k_data, v_data = (
+        (k_pages.data, v_pages.data) if quantized else (k_pages, v_pages)
+    )
+    chunk, update = ragged_kernel_sizes(
+        variant, page_size, Hkv, D, k_data.dtype.itemsize, pages_per_seq
+    )
+    if update_pages is not None:
+        update = update_pages
+        chunk = max(update, chunk // update * update)
+    if chunk_pages is not None:
+        chunk = chunk_pages
+    else:  # the wait of a whole half is described over that many pages
+        chunk = max(update, min(chunk, n_pages // update * update))
+    if chunk % update or chunk > n_pages:
+        raise ValueError(
+            f"chunk_pages={chunk} must be a multiple of update_pages="
+            f"{update} and at most the cache's {n_pages} pages"
+        )
+    page_shape = (page_size, Hkv, D)
+    if variant == "flat":
+        # a bitcast in HBM (FLAT_VARIANT_HKV_MULTIPLE), so the kernel's
+        # operands need no relayout in VMEM
+        page_shape = (page_size * Hkv, D)
+        k_data = k_data.reshape(L, n_pages, *page_shape)
+        v_data = v_data.reshape(L, n_pages, *page_shape)
 
     def _const3(shape):
         return pl.BlockSpec(
@@ -866,7 +836,7 @@ def paged_decode_attention_ragged(
         )
 
     # full arrays, constant index maps: fetched into VMEM once per call,
-    # not once per program (see _decode_kernel_ragged docstring)
+    # not once per program (see _decode_kernel_ragged)
     in_specs = [
         _const3((B, Hq, D)),
         _const3((B, Hkv, D)),
@@ -878,11 +848,13 @@ def paged_decode_attention_ragged(
         q,
         k_new.astype(compute_dtype),
         v_new.astype(compute_dtype),
+        k_data,
+        v_data,
     ]
     if quantized:
         scale_rows = [
             _gathered_scale_rows(
-                pages.scale, layer, page_tables, variant, chunk
+                pages.scale, layer, page_tables, variant, update
             )
             for pages in (k_pages, v_pages)
         ]
@@ -893,15 +865,7 @@ def paged_decode_attention_ragged(
                 memory_space=pltpu.VMEM,
             )
         ] * 2
-        operands += [k_pages.data, v_pages.data, *scale_rows]
-    else:
-        operands += [k_pages, v_pages]
-    scratch = [
-        pltpu.VMEM((depth, page_size, Hkv, D), k_pages.dtype),
-        pltpu.VMEM((depth, page_size, Hkv, D), v_pages.dtype),
-        pltpu.VMEM((Hq, D), jnp.float32),
-        pltpu.SemaphoreType.DMA((depth, 2)),
-    ]
+        operands += scale_rows
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(B,),
@@ -910,34 +874,40 @@ def paged_decode_attention_ragged(
             (B, Hq, D), lambda b, *_refs: (0, 0, 0),
             memory_space=pltpu.VMEM,
         ),
-        scratch_shapes=scratch,
+        scratch_shapes=[
+            pltpu.VMEM((2 * chunk, *page_shape), k_data.dtype),
+            pltpu.VMEM((2 * chunk, *page_shape), v_data.dtype),
+            pltpu.VMEM((Hq, D), jnp.float32),
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.SMEM((2,), jnp.int32),
+        ],
     )
-    kernel_kw = dict(
+    kernel = functools.partial(
+        _decode_kernel_ragged,
+        variant=variant,
         page_size=page_size,
         pages_per_seq=pages_per_seq,
+        n_kv_heads=Hkv,
         group=G,
         sm_scale=sm_scale,
+        chunk=chunk,
+        update=update,
         quantized=quantized,
     )
-    if variant == "flat":
-        kernel = functools.partial(_decode_kernel_ragged, **kernel_kw)
-    else:
-        kernel = functools.partial(
-            _decode_kernel_ragged_grouped, chunk=chunk, **kernel_kw
-        )
     scale_bytes = 4 * page_size * Hkv if quantized else 0
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hq, D), q.dtype),
         compiler_params=pltpu.CompilerParams(
+            # the ring and its state carry from one grid step to the next
             dimension_semantics=("arbitrary",),
         ),
         cost_estimate=pl.CostEstimate(
             flops=int(4 * B * Hq * pages_per_seq * page_size * D),
             bytes_accessed=int(
                 2 * B * pages_per_seq
-                * (Hkv * page_size * D * k_pages.dtype.itemsize + scale_bytes)
+                * (Hkv * page_size * D * k_data.dtype.itemsize + scale_bytes)
             ),
             transcendentals=int(B * Hq * pages_per_seq * page_size),
         ),

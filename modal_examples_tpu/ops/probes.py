@@ -2,7 +2,7 @@
 reference, return a small dict of floats.
 
 ``KERNEL_PROBES`` runs every kernel on the smallest Mosaic-legal shapes
-(D=128 lanes, page_size%16 sublanes, Hkv%16 for the flattened page matmuls);
+(D=128 lanes, page_size%16 sublanes, Hkv%8 for the flat page matmuls);
 :func:`model_geometry_probes` runs the serving-path kernels at the shapes an
 engine for a given model will actually ask for. ``chip_smoke.py`` calls both
 on the TPU, where each kernel goes through Mosaic; on the CPU the same
@@ -25,8 +25,9 @@ PROBED_MODULES: dict[str, list[str]] = {
         "flash_fwd", "flash_bwd", "flash_chunked",
     ],
     "modal_examples_tpu.ops.paged_attention": [
-        "ragged_decode", "ragged_decode_gqa", "ragged_decode_int8kv",
-        "ragged_decode_gqa_int8kv", "ragged_decode_tp_shard_int8kv",
+        "ragged_decode", "ragged_decode_gqa", "ragged_decode_gqa_flat",
+        "ragged_decode_int8kv", "ragged_decode_gqa_int8kv",
+        "ragged_decode_gqa_flat_int8kv", "ragged_decode_tp_shard_int8kv",
         "scatter_kv", "scatter_kv_int8",
     ],
     "modal_examples_tpu.ops.quantized_matmul": ["int8_matmul"],
@@ -268,19 +269,25 @@ KERNEL_PROBES: dict[str, Callable[[], dict]] = {
     "flash_chunked": probe_flash_chunked,
     "int8_matmul": probe_int8_matmul,
     "ragged_decode": functools.partial(probe_ragged, 16, 16, "flat"),
-    # the "grouped" per-kv-head formulation at a GQA shape (Hkv=8, G=4): no
-    # (ps*Hkv) flatten, so Hkv%16 doesn't apply
+    # the "grouped" per-kv-head formulation at a GQA shape (Hkv=8, G=4): it
+    # slices heads out of the (ps, Hkv, D) pages, so any Hkv
     "ragged_decode_gqa": functools.partial(probe_ragged, 32, 8, "grouped"),
-    # int8 KV: the flat page flatten needs Hkv%32 ((32, 128) int8 tiles)
+    # the same shape as the plan runs it since PR 35: pages read in place as
+    # (ps*Hkv, D) rows under a block-diagonal head mask
+    "ragged_decode_gqa_flat": functools.partial(probe_ragged, 32, 8, "flat"),
+    # int8 KV: int8 pages stream and the scale rows multiply the logits
     "ragged_decode_int8kv": functools.partial(
         probe_ragged, 32, 32, "flat", int8=True
     ),
     "ragged_decode_gqa_int8kv": functools.partial(
         probe_ragged, 32, 8, "grouped", int8=True
     ),
+    "ragged_decode_gqa_flat_int8kv": functools.partial(
+        probe_ragged, 32, 8, "flat", int8=True
+    ),
     # the TP=2 shard of the 7B MHA head geometry (Hq=Hkv=16, G=1): what each
-    # device compiles inside the shard_map dispatch (ops.sharded) — int8
-    # flat needs Hkv%32, so the 16-head shard runs grouped
+    # device compiles inside the shard_map dispatch (ops.sharded), in the
+    # grouped form
     "ragged_decode_tp_shard_int8kv": functools.partial(
         probe_ragged, 16, 16, "grouped", int8=True
     ),

@@ -31,7 +31,7 @@ sharding because int8 scales are per (token, head).
 
 Per-shard legality: inside ``shard_map`` the kernels see ``Hkv // tp`` and
 ``Hq // tp`` heads, so Mosaic shape legality — the flat variant's
-``Hkv % 16`` (bf16) / ``% 32`` (int8) page flatten, GQA grouping — must be
+``Hkv % 8`` (a page read as rows in place), GQA grouping — must be
 evaluated against the LOCAL shard shapes. The wrappers do this implicitly
 (the kernel sees local shapes); ``llama.paged_impl_plan(mesh=...)`` is the
 reporting mirror, so a plan and the kernels can't drift.
@@ -136,10 +136,10 @@ def sharded_ragged_decode(
     axis (no psum — attention is head-local; ``wo`` reduces outside).
 
     ``variant=None`` resolves per SHARD: inside ``shard_map`` the kernel
-    sees ``Hkv // tp`` heads, so e.g. a 32-head bf16 cache runs "flat" on
-    one chip but its 16-head TP=2 shard still runs "flat", while its int8
-    form (Hkv%32 flatten) drops to "grouped" — exactly what
-    ``llama.paged_impl_plan(mesh=...)`` reports.
+    sees ``Hkv // tp`` heads, so e.g. a 32-head cache runs "flat" on one
+    chip and as a 16-head TP=2 shard, while an 8-head GQA cache is "flat" on
+    one chip and "grouped" once sharded (flat wants Hkv%8 per shard) —
+    exactly what ``llama.paged_impl_plan(mesh=...)`` reports.
     """
     tp = mesh_tp_degree(mesh, axis)
     if tp <= 1:

@@ -60,7 +60,11 @@ from ..scheduling.policy import (
 from ..faults import inject as _inject
 from ..faults.inject import FaultError as _FaultError
 from ..observability.canary import CANARY_TENANT as _CANARY_TENANT
-from ..ops.paged_attention import decode_chunk_pages, decode_chunk_trips
+from ..ops.paged_attention import (
+    decode_chunk_pages,
+    decode_chunk_trips,
+    ragged_pages_read,
+)
 from ..utils.log import get_logger
 from .health import EngineWatermarks
 from .kv_cache import OutOfPages, PagedKVCache
@@ -486,7 +490,7 @@ class LLMEngine:
         # EngineReplica(role="prefill") zeroes the budget explicitly.
         max_prefill_tokens_per_tick: int | None = None,
         mesh=None,  # jax Mesh with a "tensor" axis: tensor-parallel serving
-        paged_impl: str | None = None,  # decode structure; None: env/default
+        paged_impl: str | None = None,  # decode attention; None: env, else the plan's choice
         scatter_impl: str | None = None,  # KV scatter; None: env/default
         vision: tuple | None = None,  # (models.vlm.VLMConfig, vision_params)
         policy: SchedulerPolicy | None = None,  # waiting-set ordering
@@ -510,10 +514,12 @@ class LLMEngine:
         import os as _os
 
         # resolved ONCE here and passed explicitly into every jitted decode:
-        # the env vars are not part of any jit cache key (ADVICE r3)
-        self.paged_impl = paged_impl or _os.environ.get("MTPU_PAGED_IMPL", "xla")
+        # the env vars are not part of any jit cache key (ADVICE r3). Left
+        # unset (None) the model's paged_impl_plan picks from the backend and
+        # the shapes; "xla" / "pallas" force the loop / the ragged kernel
+        self.paged_impl = paged_impl or _os.environ.get("MTPU_PAGED_IMPL") or None
         _known_impls = ("xla", "pallas")
-        if self.paged_impl not in _known_impls:
+        if self.paged_impl is not None and self.paged_impl not in _known_impls:
             raise ValueError(
                 f"unknown paged_impl {self.paged_impl!r}; known: {_known_impls}"
             )
@@ -559,7 +565,7 @@ class LLMEngine:
             (bool(tiered_prefix), "disaggregated transfer"),
             (bool(enable_prefix_cache), "prefix caching"),
             (
-                self.paged_impl != "xla" or self.scatter_impl != "xla",
+                self.paged_impl == "pallas" or self.scatter_impl != "xla",
                 "a Pallas paged_impl or scatter_impl",
             ),
         ):
@@ -1164,20 +1170,24 @@ class LLMEngine:
     def _count_decode_kv(self, positions, active, steps: int) -> None:
         """What the ``steps`` decode steps of one dispatch read of the KV
         cache, from the positions the host hands the program: step j sees
-        every live slot j tokens further on, and the chunked loop
+        every live slot j tokens further on. The chunked loop
         (ops.paged_decode_attention_chunked) makes as many trips as that
-        step's longest context needs, each over every slot. The macro-step
-        program can kill a lane before its last step; it is counted as
-        running them all."""
+        step's longest context needs, each over every slot; the ragged
+        kernel (ops.paged_decode_attention_ragged) DMAs each live slot's own
+        live pages and nothing for a dead one. The macro-step program can
+        kill a lane before its last step; it is counted as running them
+        all."""
         self._count_sparse(
             lambda: positions[active].astype(np.int64)[:, None] + np.arange(steps), "decode"
         )
-        if self.impl_plan["attention"] != "xla-gather":
-            return  # the ragged kernels do not loop
         live = positions[active].astype(np.int64)
         ps, pp = self.cache.page_size, self.pages_per_slot
         read = 0
-        if live.size:
+        if live.size and self.impl_plan["attention"] == "ragged":
+            read = int(
+                ragged_pages_read(live[:, None] + np.arange(steps), ps).sum()
+            ) * ps
+        elif live.size:
             trips = decode_chunk_trips(live.max() + np.arange(steps), ps, pp)
             read = (
                 int(trips.sum()) * decode_chunk_pages(ps, pp) * ps

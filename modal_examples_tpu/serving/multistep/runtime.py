@@ -52,7 +52,7 @@ def resolve_decode_steps(arg: int | None = None) -> int:
 def build_multistep_fn(
     cfg,
     *,
-    paged_impl: str,
+    paged_impl: str | None,
     scatter_impl: str,
     mesh,
     eos_id: int,

@@ -174,7 +174,7 @@ def build_spec_round_fn(
     cfg,
     draft_cfg,
     *,
-    paged_impl: str,
+    paged_impl: str | None,
     scatter_impl: str,
     mesh,
     gamma: int,

@@ -199,6 +199,77 @@ class TestEngineCountsWhatTheDeviceLoops:
         assert delta["table"] == len(seen) * BLOCK * eng.max_slots * pp * PS
         assert 0 < delta["live"] < delta["read"] < delta["table"]
 
+    def test_under_the_ragged_plan_read_positions_are_the_kernels_pages(self, jax, monkeypatch):
+        """The same two requests with the ragged kernel serving
+        (``paged_impl="pallas"``: on the CPU the plan picks it only when
+        asked): ``read`` is, step by step, the pages the kernel's own loop
+        bounds name for each live slot (``ragged_pages_read``: what it DMAs),
+        nothing for a dead slot, across an edge of the kernel's chunk inside
+        a block; it stays under the loop's count for the same steps."""
+        import jax.numpy as jnp
+
+        from modal_examples_tpu.ops import (
+            decode_chunk_pages, decode_chunk_trips, paged_attention, ragged_kernel_sizes,
+            ragged_pages_read,
+        )
+        from modal_examples_tpu.serving import SamplingParams
+
+        eng = _engine(jax, paged_impl="pallas")
+        assert eng.impl_plan["attention"] == "ragged"
+        pp, cfg = eng.pages_per_slot, eng.cfg
+        # a ring of 8-page halves and 4-page updates, so that the table
+        # holds several chunks (the sizes picked for this toy cover it whole)
+        itemsize = eng.cache.k_pages.dtype.itemsize
+        monkeypatch.setattr(paged_attention, "_FLAT_UPDATE_COLUMNS", 4 * PS * cfg.n_kv_heads)
+        monkeypatch.setattr(paged_attention, "_GROUPED_UPDATE_POSITIONS", 4 * PS)
+        monkeypatch.setattr(
+            paged_attention, "_RING_BYTES", 4 * 8 * PS * cfg.n_kv_heads * cfg.head_dim * itemsize
+        )
+        chunk, update = ragged_kernel_sizes(
+            eng.impl_plan["ragged_variant"], PS, cfg.n_kv_heads, cfg.head_dim, itemsize, pp
+        )
+        assert (chunk, update) == (8, 4) and 3 * chunk <= pp
+        seen = []
+        block = eng._block_jit
+
+        def spy(*args):
+            seen.append((np.asarray(args[6]), np.asarray(args[8])))
+            return block(*args)
+
+        eng._block_jit = spy
+
+        @jax.jit
+        def device_pages(positions, active):
+            def step(pos, _):  # the kernel's bounds, from the step's prefix_lens
+                prefix = jnp.where(active, pos, 0)
+                pages = ragged_pages_read(prefix, PS)
+                return pos + 1, (pages.sum(), jnp.max(-(-pages // chunk)), jnp.max(prefix))
+            return jax.lax.scan(step, positions, None, length=BLOCK)[1]
+
+        before = {k: _counter(k) for k in ("read", "live", "table")}
+        try:
+            params = SamplingParams(max_tokens=2 * BLOCK, temperature=0.0)
+            long = eng.submit("a" * (chunk * PS - BLOCK // 2 - 2), params)
+            short = eng.submit("hello", params)
+            for r in (long, short):
+                "".join(eng.stream(r))
+        finally:
+            eng.stop()
+        assert len(long.generated_tokens) == 2 * BLOCK and seen
+        pages = loop = 0
+        crossed_inside_a_block = False
+        for positions, active in seen:
+            n, chunks, longest = device_pages(jnp.asarray(positions), jnp.asarray(active))
+            pages += int(n.sum())
+            loop += int(decode_chunk_trips(np.asarray(longest), PS, pp).sum())
+            crossed_inside_a_block |= {1, 2} <= set(np.asarray(chunks).tolist())
+        assert crossed_inside_a_block
+        delta = {k: _counter(k) - before[k] for k in before}
+        assert delta["read"] == pages * PS
+        assert delta["table"] == len(seen) * BLOCK * eng.max_slots * pp * PS
+        assert 0 < delta["live"] <= delta["read"] < delta["live"] + 2 * PS * len(seen) * BLOCK
+        assert delta["read"] < loop * decode_chunk_pages(PS, pp) * PS * eng.max_slots
+
     def test_greedy_tokens_across_the_edge_match_a_whole_forward(self, jax, span):
         """End to end through the engine: the tokens a request decodes on
         both sides of a chunk edge are the argmax of a teacher-forced full

@@ -203,11 +203,11 @@ class TestInt8RaggedKernels:
     def test_variant_auto_selection_respects_kv_dtype(self):
         from modal_examples_tpu.ops.paged_attention import ragged_variant_for
 
-        assert ragged_variant_for(32) == "flat"
-        assert ragged_variant_for(32, "int8") == "flat"
-        assert ragged_variant_for(16) == "flat"
-        assert ragged_variant_for(16, "int8") == "grouped"  # int8: Hkv%32
-        assert ragged_variant_for(8, "int8") == "grouped"
+        # since PR 35 the flat form reads a page as (ps * Hkv, D) rows through
+        # a reshape of the cache in HBM, a bitcast at Hkv % 8 for bf16 and
+        # int8 pages alike (the flatten in VMEM wanted 16 / 32)
+        assert [ragged_variant_for(n) for n in (32, 16, 8)] == ["flat"] * 3
+        assert [ragged_variant_for(n) for n in (4, 2, 1)] == ["grouped"] * 3
 
 
 class TestInt8Scatter:

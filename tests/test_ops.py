@@ -388,6 +388,76 @@ class TestPagedAttention:
         for a, b in zip(outs["xla"], outs["pallas"]):
             np.testing.assert_allclose(a, b, atol=3e-5)
 
+    #: chunk 4 pages (64 positions) a half of the ring, update 2 pages
+    RING = dict(chunk_pages=4, update_pages=2)
+
+    @pytest.mark.parametrize(
+        "case, prefix, kw",
+        [
+            ("ragged-lengths", (0, 1, 65, 191, 100, 16), RING),
+            ("dead-slots-between", (0, 0, 33, 0, 192, 0), RING),
+            ("dead-first-and-last", (0, 130, 0, 0, 64, 0), RING),
+            ("ends-on-a-chunk-edge", (64, 128, 192, 0, 64, 128), RING),
+            ("one-past-a-chunk-edge", (65, 129, 0, 193, 65, 1), RING),
+            ("nothing-live", (0, 0, 0, 0, 0, 0), RING),
+            # 13 table columns under a 16-page half: every chunk is partial
+            ("table-shorter-than-a-chunk", (192, 7, 0, 100, 191, 16),
+             dict(chunk_pages=16, update_pages=8)),
+            ("sizes-the-kernel-picks", (192, 7, 0, 100, 191, 16), {}),
+        ],
+    )
+    @pytest.mark.parametrize("pages", ["bf16", "int8"])
+    @pytest.mark.parametrize("heads", [(8, 2), (4, 4)], ids=["gqa-g4", "group-of-one"])
+    @pytest.mark.parametrize("variant", ["flat", "grouped"])
+    def test_ragged_ring_against_the_reference(
+        self, jax, jnp, variant, heads, pages, case, prefix, kw
+    ):
+        """The kernel's ring (a chunk in flight behind the one computed, the
+        next sequence's first chunk started by the last of the one before,
+        partial chunks fetched and awaited page by page, dead slots skipped)
+        against ``reference.paged_decode_attention`` over the same pages with
+        the in-flight token written behind each prefix."""
+        from modal_examples_tpu.ops import (
+            paged_decode_attention_ragged, quantize_kv, reference,
+        )
+        from modal_examples_tpu.ops.kv_quant import QuantizedKV
+
+        (Hq, Hkv), D, L, ps, pp, P = heads, 128, 2, 16, 13, 80
+        B = len(prefix)
+        ks = jax.random.split(jax.random.PRNGKey(35), 6)
+        q = jax.random.normal(ks[0], (B, Hq, D), jnp.bfloat16)
+        kp = jax.random.normal(ks[1], (L, P, ps, Hkv, D), jnp.bfloat16)
+        vp = jax.random.normal(ks[2], kp.shape, jnp.bfloat16)
+        k_new = jax.random.normal(ks[3], (B, Hkv, D), jnp.bfloat16)
+        v_new = jax.random.normal(ks[4], (B, Hkv, D), jnp.bfloat16)
+        pt = 1 + jax.random.permutation(ks[5], P - 1)[: B * pp].reshape(B, pp).astype(jnp.int32)
+        lens = jnp.asarray(prefix, jnp.int32)
+        if pages == "int8":
+            kp, vp = quantize_kv(kp), quantize_kv(vp)
+        got = paged_decode_attention_ragged(
+            q, kp, vp, jnp.int32(1), pt, lens, k_new, v_new, variant=variant, **kw
+        )
+        # the reference reads the in-flight token back from the pages, and
+        # computes in float32 on the same bf16 / int8 values
+        page = pt[jnp.arange(B), lens // ps]
+        f32 = jnp.float32
+
+        def written(x, new):
+            if pages == "bf16":
+                return x[1].astype(f32).at[page, lens % ps].set(new.astype(f32))
+            new = quantize_kv(new)
+            return QuantizedKV(
+                data=x.data[1].at[page, lens % ps].set(new.data),
+                scale=x.scale[1].at[page, lens % ps].set(new.scale),
+            )
+
+        want = reference.paged_decode_attention(
+            q.astype(f32), written(kp, k_new), written(vp, v_new), pt, lens + 1
+        )
+        np.testing.assert_allclose(
+            np.asarray(got, np.float32), np.asarray(want), atol=3e-2, err_msg=case,
+        )
+
     def test_mha_group_of_one(self, jax, jnp):
         """Hq == Hkv (a group of one) through both decode attentions
         ``decode_step`` chooses between, against the plain reference over
@@ -468,35 +538,88 @@ class TestPagedImplOption:
     ):
         """Whatever the engine accepts resolves to one of the two attentions
         ``decode_step`` has, and a request the shapes cannot honour (on the
-        chip: ``tiny``'s head_dim) is named in ``downgraded``. The plan is
-        shape arithmetic: ``backend`` only steers its legality branch."""
+        chip: ``tiny``'s head_dim) is named in ``downgraded``. Left unset
+        the plan decides: the kernel on the chip where the shapes allow it,
+        the loop elsewhere, and nothing was asked for, so nothing is
+        downgraded. The plan is shape arithmetic: ``backend`` only steers
+        its legality branch."""
         from modal_examples_tpu.models import llama
 
         cfg = getattr(llama.LlamaConfig, shape)()
         accepted = self._known(self._refusal(monkeypatch, "arg", "no-such-impl"))
         monkeypatch.setattr(jax, "default_backend", lambda: backend)
-        for impl in accepted:
+        kernel_serves = backend == "tpu" and shape != "tiny"
+        for impl in [None] + accepted:
             for scatter in ("xla", "pallas"):
                 for kvd in ("bfloat16", "int8"):
                     plan = llama.paged_impl_plan(
                         cfg, 16, impl, scatter, kv_dtype=kvd, warn=False
                     )
                     assert plan["attention"] in {"ragged", "xla-gather"}, plan
-                    asked = {"pallas": "ragged", "xla": "xla-gather"}[impl]
                     down = " ".join(plan["downgraded"])
-                    assert (plan["attention"] == asked) != (
-                        f"paged_impl={impl} ->" in down
-                    ), plan
+                    if impl is None:
+                        assert plan["attention"] == (
+                            "ragged" if kernel_serves else "xla-gather"
+                        ), plan
+                        assert "paged_impl" not in down, plan
+                    else:
+                        asked = {"pallas": "ragged", "xla": "xla-gather"}[impl]
+                        assert (plan["attention"] == asked) != (
+                            f"paged_impl={impl} ->" in down
+                        ), plan
                     assert (plan["scatter"] == scatter) != (
                         f"scatter_impl={scatter} ->" in down
                     ), plan
-        if backend == "tpu" and shape != "tiny":
-            # both serving geometries keep the kernel on the chip: 8 KV
-            # heads take the per-kv-head variant
-            plan = llama.paged_impl_plan(cfg, 16, "pallas", warn=False)
-            assert (plan["attention"], plan["ragged_variant"]) == (
-                "ragged", "grouped"
-            ), plan
+        if kernel_serves:
+            # both serving geometries keep the kernel on the chip, asked for
+            # or not: 8 KV heads read a page as (ps * Hkv, D) rows in place
+            # and take the all-heads variant (PERF.md section 6, PR 35)
+            for impl in (None, "pallas"):
+                plan = llama.paged_impl_plan(cfg, 16, impl, warn=False)
+                assert (plan["attention"], plan["ragged_variant"]) == (
+                    "ragged", "flat"
+                ), plan
+
+    @pytest.mark.parametrize("backend", ["cpu", "tpu"])
+    @pytest.mark.parametrize("family", ["deepseek_v2", "granite_hybrid", "glm_dsa"])
+    def test_the_other_families_take_unset_as_xla(self, monkeypatch, jax, family, backend):
+        """A latent cache and a 64-wide head keep their own plan, the loop:
+        unset reads as ``xla`` does, whatever the backend, and the kernel
+        asked for by name is refused as before."""
+        import importlib
+
+        module = importlib.import_module(f"modal_examples_tpu.models.{family}")
+        cfg = next(
+            getattr(module, name) for name in dir(module) if name.endswith("Config")
+            and hasattr(getattr(module, name), "tiny")
+        ).tiny()
+        monkeypatch.setattr(jax, "default_backend", lambda: backend)
+        unset = module.paged_impl_plan(cfg, 16)
+        assert unset == module.paged_impl_plan(cfg, 16, None, "xla")
+        assert unset == module.paged_impl_plan(cfg, 16, "xla", "xla")
+        assert (unset["attention"], unset["ragged_variant"]) == ("xla-gather", None)
+        with pytest.raises(NotImplementedError, match="Pallas paged_impl"):
+            module.paged_impl_plan(cfg, 16, "pallas")
+
+    def test_engine_leaves_an_unset_paged_impl_to_the_plan(self, monkeypatch):
+        """No argument and no ``MTPU_PAGED_IMPL``: the engine holds None, not
+        a default of its own, and hands it to the model's plan (on the CPU:
+        the loop); an empty variable is unset too."""
+        from modal_examples_tpu.models import llama
+        from modal_examples_tpu.serving import LLMEngine
+
+        for env in (None, ""):
+            if env is None:
+                monkeypatch.delenv("MTPU_PAGED_IMPL", raising=False)
+            else:
+                monkeypatch.setenv("MTPU_PAGED_IMPL", env)
+            eng = LLMEngine(llama.LlamaConfig.tiny(), seed=0, max_slots=2, max_model_len=64)
+            try:
+                assert eng.paged_impl is None
+                assert eng.impl_plan["attention"] == "xla-gather"
+                assert eng.impl_plan["downgraded"] == []
+            finally:
+                eng.stop()
 
     def test_model_and_kernels_do_not_read_the_environment(self):
         """``decode_step`` is jitted by its callers: an environment read in

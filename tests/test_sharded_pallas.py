@@ -243,16 +243,17 @@ class TestPerShardLegality:
     @pytest.mark.parametrize(
         "n_kv_heads,n_heads,tp,kv_dtype,want_attn,want_variant",
         [
-            # flat needs Hkv%16 (bf16) per SHARD: 32 heads stay flat at
-            # tp=2 (16 per shard) but 16 heads drop to grouped at tp=2
+            # flat needs Hkv%8 per SHARD (a page read as (ps * Hkv, D) rows
+            # in place, bf16 and int8 alike: PR 35): 32 and 16 heads stay
+            # flat at tp=2 (16 and 8 per shard)
             (32, 32, 1, "bfloat16", "ragged", "flat"),
             (32, 32, 2, "bfloat16", "ragged", "flat"),
-            (16, 32, 2, "bfloat16", "ragged", "grouped"),
-            # int8 flat needs Hkv%32 per shard: 32 heads are flat on one
-            # chip, grouped the moment the shard halves them
+            (16, 32, 2, "bfloat16", "ragged", "flat"),
             (32, 32, 1, "int8", "ragged", "flat"),
-            (32, 32, 2, "int8", "ragged", "grouped"),
-            # GQA (llama-3 geometry) is grouped everywhere
+            (32, 32, 2, "int8", "ragged", "flat"),
+            # GQA (llama-3 geometry) is flat on one chip and grouped the
+            # moment the shard halves its 8 heads
+            (8, 32, 1, "bfloat16", "ragged", "flat"),
             (8, 32, 2, "bfloat16", "ragged", "grouped"),
             (2, 4, 2, "float32", "ragged", "grouped"),
             # heads not divisible by tp: loud downgrade to the XLA gather
@@ -367,7 +368,7 @@ class TestEngineShardedPallas:
             )
             assert isinstance(out, str) and eng.error_count == 0
             assert eng.impl_plan["kv_dtype"] == "int8"
-            # Hkv//tp = 1: int8 flat needs Hkv%32 -> grouped per shard
+            # Hkv//tp = 1: under the 8 heads flat reads in place -> grouped per shard
             assert eng.impl_plan["ragged_variant"] == "grouped"
             kp = eng.cache.k_pages
             assert len(kp.data.sharding.device_set) == 2

@@ -95,6 +95,36 @@ def test_the_flash_call_of_a_tail_chunk_takes_these_tiles(D, Dv):
     assert choose_blocks(128, 2048 + 128, D, Dv, 2) == (128, 128)
 
 
+def _cell_operands(one_chip, family):
+    """A configuration of the benchmark (its file, its pages) as shapes on
+    the described chip: ``(module, cfg, params, k_pages, v_pages, S)``."""
+    import jax
+    import jax.numpy as jnp
+
+    from modal_examples_tpu.models import deepseek_v2, llama
+    from modal_examples_tpu.models.quantize import quantize_llama
+
+    module, config_class, name, n_pages = {  # the cell's file, its n_pages
+        "mistral": (llama, llama.LlamaConfig, "mistral-7b-int8", 3072),
+        "mixtral": (llama, llama.LlamaConfig, "mixtral-8x7b-int8-1chip", 4096),
+        "deepseek": (deepseek_v2, deepseek_v2.DeepseekV2Config, "deepseek-v2-int8-ep4", 12288),
+    }[family]
+    S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+    cfg = config_class.from_hf_config(f"benchmarks/serving/configs/{name}.json")
+    params = jax.tree.map(
+        lambda a: S(a.shape, a.dtype),
+        jax.eval_shape(
+            lambda k: quantize_llama(module.init_params(k, cfg), cfg.quant_targets),
+            jax.random.PRNGKey(0),
+        ),
+    )
+    layers = getattr(cfg, "n_cache_layers", cfg.n_layers)
+    k_pages, v_pages = (
+        S((layers, n_pages, 16, *leaf), jnp.bfloat16) for leaf in cfg.cache_leaf_shapes
+    )
+    return module, cfg, params, k_pages, v_pages, S
+
+
 @pytest.fixture(scope="module")
 def chunk_program(one_chip):
     """``compile(family, width)``: the engine's chunk program at offset 2048
@@ -103,32 +133,13 @@ def chunk_program(one_chip):
     import jax
     import jax.numpy as jnp
 
-    from modal_examples_tpu.models import deepseek_v2, llama
-    from modal_examples_tpu.models.quantize import quantize_llama
     from modal_examples_tpu.serving.engine import LLMEngine
 
-    configs = {  # module, its configuration class, the cell's file, its n_pages
-        "mistral": (llama, llama.LlamaConfig, "mistral-7b-int8", 3072),
-        "deepseek": (deepseek_v2, deepseek_v2.DeepseekV2Config, "deepseek-v2-int8-ep4", 12288),
-    }
-    S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
     eng = object.__new__(LLMEngine)  # the program's body, without an engine's arrays
     eng._attn_impl, eng.mesh, eng._chunk_jits = "flash", None, {}
 
     def compile(family, width):
-        module, config_class, name, n_pages = configs[family]
-        cfg = config_class.from_hf_config(f"benchmarks/serving/configs/{name}.json")
-        params = jax.tree.map(
-            lambda a: S(a.shape, a.dtype),
-            jax.eval_shape(
-                lambda k: quantize_llama(module.init_params(k, cfg), cfg.quant_targets),
-                jax.random.PRNGKey(0),
-            ),
-        )
-        layers = getattr(cfg, "n_cache_layers", cfg.n_layers)
-        k_pages, v_pages = (
-            S((layers, n_pages, 16, *leaf), jnp.bfloat16) for leaf in cfg.cache_leaf_shapes
-        )
+        _, cfg, params, k_pages, v_pages, S = _cell_operands(one_chip, family)
         i32 = lambda *s: S(s, jnp.int32)  # noqa: E731
         return eng._chunk_jit(2048).lower(
             params, i32(1, width), k_pages, v_pages, i32(1, 256), i32(1), cfg=cfg
@@ -165,6 +176,72 @@ def test_deepseeks_tail_program_compiles_at_widths_192_and_128_for_a_v5e(chunk_p
     assert compiled.memory_analysis().temp_size_in_bytes < 1.0 * GIB  # 0.81
 
 
+# -- the decode block's attention at the serving cells' own shapes (PR 35) -------------------
+
+
+@pytest.fixture(scope="module")
+def decode_block(one_chip):
+    """``lowered(family)``: the engine's decode block of 8 steps for a
+    configuration of the benchmark (its file, its slots and pages), with
+    ``paged_impl`` left unset, as shapes on the described chip."""
+    import jax
+    import jax.numpy as jnp
+
+    from modal_examples_tpu.serving.engine import LLMEngine
+
+    def lowered(family):
+        module, cfg, params, k_pages, v_pages, S = _cell_operands(one_chip, family)
+        i32 = lambda *s: S(s, jnp.int32)  # noqa: E731
+        f32 = lambda *s: S(s, jnp.float32)  # noqa: E731
+        eng = object.__new__(LLMEngine)  # the program's body, without an engine's arrays
+        eng._model, eng.cfg, eng.mesh = module, cfg, None
+        eng.paged_impl, eng.scatter_impl = None, "xla"
+        eng._counts_routed, eng.decode_block = bool(cfg.counts_routed_pairs), 8
+        B = 16
+        return jax.jit(eng._decode_block_fn, donate_argnums=(1, 2)).lower(
+            params, k_pages, v_pages, i32(B), i32(B), S((B,), bool), i32(B),
+            i32(B, 256), S((B,), bool), S((2,), jnp.uint32), f32(B), f32(B), i32(B), i32(B),
+        )
+
+    # the plan and the kernels read the backend at trace time
+    backend, jax.default_backend = jax.default_backend, lambda: "tpu"
+    try:
+        yield lowered
+    finally:
+        jax.default_backend = backend
+
+
+@pytest.mark.parametrize("family, n_pages", [("mistral", 3072), ("mixtral", 4096)])
+def test_decode_block_reads_the_pages_through_the_ragged_kernel_on_a_v5e(
+    decode_block, family, n_pages
+):
+    """With nothing set, the plan picks the ragged kernel for 8 KV heads of
+    128 on the chip: the block goes through Mosaic inside the default VMEM
+    limit (a 2 MiB ring), the cache is handed to the kernel as it lies (the
+    flat form's ``(ps * Hkv, D)`` view is a bitcast: no copy of a leaf), and
+    the loop's gathered chunk ``[256 positions, 16 slots, 8, 128]`` is gone
+    with its two fusions (PERF.md section 6, PR 35)."""
+    compiled = decode_block(family).compile()
+    text = compiled.as_text()
+    layers = {"mistral": 32, "mixtral": 7}[family]
+    leaf = f"bf16[{layers},{n_pages},16,8,128]"
+    assert "tpu_custom_call" in text and "mtpu.attention" in text
+    assert "bf16[256,16,8,128]" not in text  # kv_gather's chunk
+    assert leaf in text and not _relaid_out(text, leaf)
+    assert not _relaid_out(text, f"bf16[{layers},{n_pages},128,128]")
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 2 * layers * n_pages * 16 * 8 * 128 * 2  # pages in place
+
+
+def test_a_latent_cache_still_decodes_through_the_loop(decode_block):
+    """DeepSeek-V2's plan for an unset ``paged_impl`` is what it was for
+    ``xla``: the chunked loop over the latent pages, no Pallas call in the
+    decode block (a 576-wide head is not the ragged kernel's)."""
+    text = decode_block("deepseek").as_text()
+    assert "tpu_custom_call" not in text
+    assert "stablehlo.while" in text
+
+
 # -- Granite-4.0-H-Micro at its published widths (PR 31) ------------------------------------
 
 
@@ -190,7 +267,7 @@ def granite(one_chip):
     state = tuple(S((n, slots, *shape), jnp.dtype(dt)) for n, shape, dt in cfg.state_leaves)
     eng = object.__new__(LLMEngine)  # the two program bodies, without an engine's arrays
     eng._model, eng.cfg, eng.mesh, eng._attn_impl = G, cfg, None, "flash"
-    eng.paged_impl = eng.scatter_impl = "xla"
+    eng.paged_impl, eng.scatter_impl = None, "xla"  # unset: this family's plan is the loop
     eng._counts_routed, eng.decode_block = False, 8
     i32 = lambda *s: S(s, jnp.int32)  # noqa: E731
     f32 = lambda *s: S(s, jnp.float32)  # noqa: E731
@@ -254,6 +331,7 @@ def test_granite_decode_block_updates_its_state_in_place_on_a_v5e(granite):
     assert "f32[36,64,64,64,128]" in text  # the state leaf, indexed [layer] out of the stack
     assert "f32[36,64,64,64,128]{4,3,2,1,0:T(8,128)} copy(" not in text
     assert "mtpu.ssm_step" in text and "mtpu.ssm_proj" in text and "mtpu.attention" in text
+    assert "tpu_custom_call" not in text  # a 64-wide head: the loop, whatever the backend
 
 
 def test_granite_prefill_call_compiles_at_head_width_64_on_a_v5e(granite):
