@@ -822,12 +822,20 @@ def cmd_profile(argv: list[str]) -> int:
                 f"ahead={entry['ahead']:<5} hit={entry['hit']}"
             )
     if top:
+        # what JAX's own events made of a build's seconds (rows from before
+        # the split carry none: shown as 0)
+        kinds = [k + "_s" for k in C.COMPILE_KINDS]
         print(f"\ntop compiles ({len(builds)} ledgered builds):")
+        print(
+            f"  {'SECONDS':>9}  {'PROGRAM':<16} {'SHAPE':<14} "
+            + " ".join(f"{k.upper():>13}" for k in kinds)
+        )
         for r in top:
             print(
                 f"  {r.get('seconds', 0.0):>8.3f}s  "
                 f"{r.get('program', '?'):<16} {r.get('shape_key', '?'):<14} "
-                f"({r.get('replica', '?')})"
+                + " ".join(f"{r.get(k) or 0.0:>13.3f}" for k in kinds)
+                + f"  ({r.get('replica', '?')})"
             )
     if unfinished:
         # the ≥40-slot ceiling diagnosis: a begin event with no end means

@@ -51,6 +51,7 @@ from .._internal import config as _config
 from ..faults import inject as _inject
 from ..observability import journal as _journal
 from ..observability import metrics as _obs
+from ..observability import profiler as _profiler
 from ..observability import trace as _tr
 from ..scheduling.policy import CLASS_RANK
 from ..utils.log import get_logger
@@ -139,6 +140,9 @@ class ContainerConfig:
     # client-side so supervisor and container agree on the store entry
     snapshot_key: str | None = None
     snapshot_dir: str | None = None
+    # the supervisor's time.monotonic() at this container's Popen (one
+    # clock for every process of a host): where its boot profile starts
+    spawned_at: float | None = None
 
 
 def _mount_volumes(volumes: list[tuple[str, str]]) -> None:
@@ -160,6 +164,9 @@ def _mount_volumes(volumes: list[tuple[str, str]]) -> None:
 def _container_main(conn, cfg_bytes: bytes) -> None:
     """Entry point of a container process."""
     cfg: ContainerConfig = ser.deserialize(cfg_bytes)
+    # the boot as phase spans (catalog.BOOT_PHASES): ``spawn`` runs from
+    # the supervisor's Popen to here
+    _profiler.begin_boot(cfg.spawned_at)
     os.environ.update(cfg.env)
     os.environ[_config.TASK_ID_ENV] = f"ta-{uuid.uuid4().hex[:12]}"
     import sys
@@ -182,9 +189,12 @@ def _container_main(conn, cfg_bytes: bytes) -> None:
     boot_info: dict = {}
     try:
         if os.environ.pop(_TPU_ATTACH_ENV, None):
+            _profiler.boot_enter("attach")
             # held (by this reference) until the process exits
             _lease = tpu_lease.acquire(cfg.function_tag)  # noqa: F841
             tpu_lease.require_tpu_backend(cfg.function_tag)
+        # the user's code from here: unpickling it runs its imports
+        _profiler.boot_enter("enter")
         target = ser.function_from_bytes(cfg.fn_bytes)
         if cfg.is_cls:
             cls, meta = target  # (user class, lifecycle metadata dict)
@@ -211,6 +221,7 @@ def _container_main(conn, cfg_bytes: bytes) -> None:
             def call_fn(method_name, args, kwargs):
                 return target(*args, **kwargs)
 
+        boot_info = {**boot_info, "phases": _profiler.finish_boot()}
         send(("ready", boot_info))
     except BaseException as e:  # boot failure
         send(("boot_error", ser.serialize_exception(e)))
@@ -537,6 +548,13 @@ class _Container:
             env["JAX_PLATFORMS"] = "cpu"
         env["PYTHONPATH"] = os.pathsep.join(py_paths)
         env.update(self.extra_env)
+        # observability: the boot's window on the wall clock (spans) and on
+        # CLOCK_MONOTONIC (handed to the container: its boot profile starts
+        # here), and the snapshot outcome. The ``boot`` span is recorded
+        # when the container reports ready, under a trace of its own; the
+        # first dispatched input's trace links to it
+        self.boot_wall_start = time.time()
+        self.boot_spawned_at = time.monotonic()
         self.proc = subprocess.Popen(
             [sys.executable, "-m", "modal_examples_tpu.core.container_worker"],
             env=env,
@@ -545,11 +563,9 @@ class _Container:
         self.kill_reason: str | None = None
         self.ready = threading.Event()
         self.ever_ready = False
-        # observability: boot wall-clock window + snapshot outcome, consumed
-        # by the first dispatched input's "boot" span
-        self.boot_wall_start = time.time()
         self.ready_wall: float | None = None
         self.boot_info: dict = {}
+        self.boot_trace_id: str | None = None
         self._boot_span_pending = True
         self.retired = False  # single-use containers retire after one dispatch
         self.reaped = False  # autoscaler issued (and journaled) a scale-down
@@ -581,10 +597,54 @@ class _Container:
                 return 0
             return self.pool.spec_max_concurrent - len(self.active)
 
+    def _record_boot(self) -> None:
+        """The container reported ready: observe the boot once
+        (``mtpu_call_duration_seconds{phase="boot"}``) and record it as a
+        ``boot`` span with the container's phases and nested marks as child
+        spans, under a trace of its own: a server replica is booted by the
+        autoscaler and no input is ever dispatched to it. The container's
+        phases are on CLOCK_MONOTONIC; ``boot_spawned_at`` and
+        ``boot_wall_start`` are one instant, which places them on the
+        spans' wall clock."""
+        tag = self.pool.spec.tag
+        info = self.boot_info or {}
+        _obs.record_phase(tag, "boot", self.ready_wall - self.boot_wall_start)
+        if not _tr.tracing_enabled():
+            return
+        self.boot_trace_id = f"boot-{uuid.uuid4().hex[:12]}"
+        root = _tr.Span(
+            trace_id=self.boot_trace_id,
+            name="boot",
+            start=self.boot_wall_start,
+            attrs={
+                "function": tag,
+                "mode": "cold",
+                "container": self.idx,
+                "snapshot": info.get("snapshot", "off"),
+            },
+        )
+        root.end = self.ready_wall
+        to_wall = self.boot_wall_start - self.boot_spawned_at
+        # a span names what it opened in (None: a top-level phase); what
+        # holds a span closes after it, so the ids are filled backwards
+        ids = {None: root.span_id}
+        for name, start, end, parent in reversed(
+            (info.get("phases") or {}).get("spans", ())
+        ):
+            sp = _tr.Span(
+                trace_id=self.boot_trace_id, name=name, start=start + to_wall,
+                end=end + to_wall, parent_id=ids.get(parent, root.span_id),
+            )
+            ids[name] = sp.span_id
+            _tr.default_store.record(sp)
+        _tr.default_store.record(root)
+
     def _trace_dispatch(self, qi: _QueuedInput) -> None:
         """Phase-span bookkeeping at dispatch: close the queue span (observe
-        queue wait), emit the boot-or-warm span (cold boots carry the
-        snapshot outcome from the ready message), open the dispatch span."""
+        queue wait), emit the boot-or-warm span (the first input a cold
+        container gets carries the boot's window, the snapshot outcome from
+        the ready message and the id of the boot's own trace), open the
+        dispatch span."""
         call = qi.call
         if call.root_span is None:
             return
@@ -606,11 +666,11 @@ class _Container:
                     "mode": "cold",
                     "container": self.idx,
                     "snapshot": (self.boot_info or {}).get("snapshot", "off"),
+                    "boot_trace": self.boot_trace_id,
                 },
             )
             sp.end = self.ready_wall or time.time()
             _tr.default_store.record(sp)
-            _obs.record_phase(tag, "boot", sp.duration)
         else:
             sp = _tr.Span(
                 trace_id=call.trace_id,
@@ -699,7 +759,9 @@ class _Container:
                     os.unlink(self._sock_path)
                 except OSError:
                     pass
-            conn.send(ser.serialize(self.pool.container_config))
+            conn.send(ser.serialize(dataclasses.replace(
+                self.pool.container_config, spawned_at=self.boot_spawned_at
+            )))
             self.conn = conn
             while True:
                 msg = conn.recv()
@@ -711,6 +773,10 @@ class _Container:
                     self.boot_info = info or {}
                     try:
                         self.pool.on_container_ready(self, self.boot_info)
+                    except Exception:
+                        traceback.print_exc()
+                    try:  # of its own: tracing may not suppress the above
+                        self._record_boot()
                     except Exception:
                         traceback.print_exc()
                     self.ready.set()

@@ -29,6 +29,35 @@ RETRIES_TOTAL = "mtpu_retries_total"
 #: (reason = timeout is the only kill the scheduler issues today)
 CONTAINER_KILLS_TOTAL = "mtpu_container_kills_total"
 
+# -- container boot (core/executor.py, observability/profiler.BootProfile) ---
+
+#: the top-level phases of one container boot, in the order they run —
+#: THE boot vocabulary: ``_container_main`` (and ``snapshot.build_and_enter``
+#: for ``restore``) enter phases only through these names, one thread, so
+#: they partition the boot from the supervisor's ``Popen`` to ``ready``.
+#: ``tests/test_static.py`` holds the closure in both directions
+BOOT_PHASES = (
+    "spawn",    # supervisor's Popen -> _container_main entered: interpreter,
+                # the core imports, the pipe and the pickled config
+    "attach",   # tpu_lease.acquire + require_tpu_backend: JAX's import and
+                # the backend's start (tpu= containers only)
+    "restore",  # snapshot.try_restore (a snapshot key is set; else absent)
+    "enter",    # the user's code: unpickling it (its imports) and @enter
+)
+#: marks the library opens INSIDE a phase, where the work happens, reported
+#: under their own names and never added to the partition: ``engine_init``
+#: (``LLMEngine.__init__``), ``kv_alloc`` inside it (``PagedKVCache.create``:
+#: the pages and the allocator), ``server_start`` (``OpenAIServer.start``)
+BOOT_MARKS = ("engine_init", "kv_alloc", "server_start")
+#: gauge {phase}: seconds of this process's one boot in a phase
+#: (phase = BOOT_PHASES, which partition it, or a BOOT_MARKS member, which
+#: nests); written once, before the container reports ready
+BOOT_PHASE_SECONDS = "mtpu_boot_phase_seconds"
+#: gauge {mark}: the boot's two ends as CLOCK_MONOTONIC readings
+#: (mark = spawned | ready), so a reader on the same host places the boot
+#: on its own clock
+BOOT_MARK_SECONDS = "mtpu_boot_mark_seconds"
+
 # -- memory snapshots (modal_examples_tpu/snapshot, PR 1) -------------------
 
 #: counter {function, result}: snapshot-enabled container boots;
@@ -60,11 +89,6 @@ PREFILL_POSITIONS_TOTAL = "mtpu_prefill_positions_total"
 #: summed) | table (slots x pages_per_slot x page_size: what a full-table
 #: gather would read). read / table is how far the loop runs
 DECODE_KV_POSITIONS_TOTAL = "mtpu_decode_kv_positions_total"
-#: counter: cached prefix positions a prefill call attends to, counted at
-#: the dispatch of a chunk at an offset (host-known: the offset): the
-#: positions whose cached state the program reads back, and, for a latent
-#: cache, expands again (models/deepseek_v2.py)
-PREFILL_PREFIX_POSITIONS_TOTAL = "mtpu_prefill_prefix_positions_total"
 #: counter {where}: (token, expert) pairs the decode blocks' routed layers
 #: chose, over their live slots, steps and layers; where = held (the expert
 #: is one this chip holds: its part of the sum is computed) | elsewhere
@@ -344,6 +368,24 @@ COMPILE_SECONDS = "mtpu_compile_seconds"
 #: ahead (built off the dispatch path before any dispatch asked for it:
 #: ``HotPathProfiler.build``; timed and ledgered like a miss)
 COMPILES_TOTAL = "mtpu_compiles_total"
+#: what a program build is made of, as JAX's own monitoring events time it
+#: (``jax.monitoring``; the engine hands the profiler the module): tracing
+#: the Python function to a jaxpr | lowering the jaxpr to MLIR | XLA's
+#: compile of the module | reading a compiled program back from the
+#: persistent cache. Only an event no other encloses on its thread counts,
+#: so the kinds never overlap
+COMPILE_KINDS = ("trace", "lower", "xla_compile", "cache_load")
+#: the ``program`` of build work done outside any ``dispatch()`` /
+#: ``build()``: the one-operation helpers the host path runs eagerly
+EAGER_PROGRAM = "(eager)"
+#: counter {program, kind}: seconds of JAX's build work by kind
+#: (COMPILE_KINDS), under the program whose dispatch() or build() was open
+#: on the thread that did it, else program="(eager)"
+COMPILE_PHASE_SECONDS_TOTAL = "mtpu_compile_phase_seconds_total"
+#: counter {result}: the persistent compile cache's answers
+#: (result = hit: a compiled program read back | miss: compiled, then
+#: written)
+COMPILE_CACHE_TOTAL = "mtpu_compile_cache_total"
 
 # -- macro-step decode runtime (serving/multistep/, docs/multistep.md) -------
 
@@ -527,6 +569,19 @@ CATALOG: dict[str, dict] = {
         "labels": ["function", "reason"],
         "help": "containers killed by the supervisor",
     },
+    BOOT_PHASE_SECONDS: {
+        "type": "gauge",
+        "labels": ["phase"],
+        "help": "seconds of this process's container boot by phase "
+                "(phase=spawn|attach|restore|enter partition it; "
+                "engine_init|kv_alloc|server_start nest inside)",
+    },
+    BOOT_MARK_SECONDS: {
+        "type": "gauge",
+        "labels": ["mark"],
+        "help": "the boot's ends on CLOCK_MONOTONIC (mark=spawned: the "
+                "supervisor's Popen | ready: the container reported ready)",
+    },
     SNAPSHOT_BOOTS_METRIC: {
         "type": "counter",
         "labels": ["function", "result"],
@@ -565,12 +620,6 @@ CATALOG: dict[str, dict] = {
         "help": "KV positions per decode step at block dispatch (kind="
                 "read: chunk trips x chunk positions x slots | live: live "
                 "contexts | table: slots x table positions)",
-    },
-    PREFILL_PREFIX_POSITIONS_TOTAL: {
-        "type": "counter",
-        "labels": [],
-        "help": "cached prefix positions that prefill chunk calls at an "
-                "offset attended to (read back from the cache)",
     },
     ROUTED_PAIRS_TOTAL: {
         "type": "counter",
@@ -889,6 +938,17 @@ CATALOG: dict[str, dict] = {
         "help": "program-cache lookups at jit dispatch sites "
                 "(cache=miss fresh build, ledgered | hit served compiled | "
                 "ahead built before any dispatch asked, ledgered)",
+    },
+    COMPILE_PHASE_SECONDS_TOTAL: {
+        "type": "counter", "labels": ["program", "kind"],
+        "help": "seconds of JAX's program-build work (kind=trace|lower|"
+                "xla_compile|cache_load) under the program whose dispatch "
+                "or build was open on the thread, else program=(eager)",
+    },
+    COMPILE_CACHE_TOTAL: {
+        "type": "counter", "labels": ["result"],
+        "help": "persistent compile cache answers (result=hit read back | "
+                "miss compiled and written)",
     },
     TSDB_SAMPLES_TOTAL: {
         "type": "counter", "labels": [],
@@ -1210,7 +1270,7 @@ ALL_SPAN_NAMES = frozenset(SPAN_CATALOG)
 #: tell the two trace kinds apart
 CALL_SPAN_NAMES = frozenset(
     {"call", "queue", "boot", "dispatch", "execute", "serialize", "retry"}
-)
+) | frozenset(BOOT_PHASES + BOOT_MARKS)  # a boot span's children
 
 #: buckets for batch-size-style histograms (counts, not seconds)
 COUNT_BUCKETS = (1, 2, 4, 8, 16, 32, 64, 128)

@@ -166,17 +166,6 @@ def set_state_bytes(nbytes: int, *, registry: Registry | None = None) -> None:
     )
 
 
-def record_prefill_prefix_positions(
-    positions: int, *, registry: Registry | None = None
-) -> None:
-    """One prefill chunk call at an offset: the cached prefix positions it
-    attends to."""
-    _reg(registry).counter_inc(
-        C.PREFILL_PREFIX_POSITIONS_TOTAL, float(positions),
-        help=C.CATALOG[C.PREFILL_PREFIX_POSITIONS_TOTAL]["help"],
-    )
-
-
 def record_routed_pairs(
     held: int, elsewhere: int, *, registry: Registry | None = None
 ) -> None:
@@ -677,6 +666,49 @@ def record_compile(
             C.COMPILE_SECONDS, seconds,
             labels={"program": program},
             help=C.CATALOG[C.COMPILE_SECONDS]["help"],
+        )
+
+
+def record_compile_phase(
+    program: str, kind: str, seconds: float, *,
+    registry: Registry | None = None,
+) -> None:
+    """Seconds of JAX's own build work of one ``catalog.COMPILE_KINDS``
+    kind, under the program open on the thread that did it (the profiler's
+    monitoring listener; nothing reaches here where no profiler is)."""
+    _reg(registry).counter_inc(
+        C.COMPILE_PHASE_SECONDS_TOTAL, float(seconds),
+        labels={"program": program, "kind": kind},
+        help=C.CATALOG[C.COMPILE_PHASE_SECONDS_TOTAL]["help"],
+    )
+
+
+def record_compile_cache(
+    hit: bool, *, registry: Registry | None = None
+) -> None:
+    """One answer of the persistent compile cache."""
+    _reg(registry).counter_inc(
+        C.COMPILE_CACHE_TOTAL, 1.0,
+        labels={"result": "hit" if hit else "miss"},
+        help=C.CATALOG[C.COMPILE_CACHE_TOTAL]["help"],
+    )
+
+
+def set_boot_profile(
+    phases: dict, ends: dict, *, registry: Registry | None = None
+) -> None:
+    """This process's one boot, written once: seconds by phase and nested
+    mark, and the boot's two ends on CLOCK_MONOTONIC."""
+    reg = _reg(registry)
+    for phase, seconds in phases.items():
+        reg.gauge_set(
+            C.BOOT_PHASE_SECONDS, float(seconds), labels={"phase": phase},
+            help=C.CATALOG[C.BOOT_PHASE_SECONDS]["help"],
+        )
+    for mark, at in ends.items():
+        reg.gauge_set(
+            C.BOOT_MARK_SECONDS, float(at), labels={"mark": mark},
+            help=C.CATALOG[C.BOOT_MARK_SECONDS]["help"],
         )
 
 

@@ -11,7 +11,7 @@ recorded when/what XLA compiles. This module is that instrument — the
 measurement foundation every subsequent perf PR (multi-step decode, spec
 adaptivity) is judged against.
 
-Three legs:
+Its legs:
 
 - **Tick anatomy** — the scheduler thread accounts each ``step()`` into
   named phases (:data:`~.catalog.TICK_PHASES`) as SCOPED spans on the
@@ -52,6 +52,21 @@ Three legs:
   crashes or hangs mid-build (the ≥40-slot ceiling) leaves a
   begin-without-end row naming exactly which program/shape killed it,
   diagnosable offline from the ledger alone.
+- **A build split where JAX does the work** — the engine also hands the
+  profiler ``jax.monitoring`` (:func:`install_compile_listener`): JAX's own
+  events time tracing, lowering, XLA's compile and a persistent-cache read
+  (:data:`~.catalog.COMPILE_KINDS`), and the seconds go to
+  ``mtpu_compile_phase_seconds_total{program,kind}`` under the program
+  whose :meth:`~HotPathProfiler.dispatch` or :meth:`~HotPathProfiler.build`
+  is open on the thread that did the work, else ``program="(eager)"``; the
+  ledger's ``end`` row carries the four. It fires only when JAX traces or
+  compiles.
+- **Boot anatomy** — :class:`BootProfile`, of :class:`TickProfile`'s form:
+  the container's one boot as top-level phases
+  (:data:`~.catalog.BOOT_PHASES`) that partition it from the supervisor's
+  ``Popen`` to ``ready``, on ``time.monotonic()`` (one clock for every
+  process of a host), with nested marks (:data:`~.catalog.BOOT_MARKS`) the
+  library opens where the work happens. A dozen clock reads a process.
 - **Surfaces** — ``tpurun profile`` (phase table, host fraction, top
   compiles), the gateway's ``/profile`` route, Perfetto counter tracks +
   compile slices merged into the replica-aware trace export, and the
@@ -75,6 +90,7 @@ ledger.
 
 from __future__ import annotations
 
+import contextlib
 import threading
 import time
 import weakref
@@ -119,6 +135,131 @@ def profiling_enabled(explicit=None) -> bool:
     if explicit is not None:
         return bool(explicit)
     return os.environ.get(PROFILE_ENV, "") != "0"
+
+
+class BootProfile:
+    """One container boot as a sequence of scoped phase spans, of
+    :class:`TickProfile`'s form: :meth:`enter` opens a top-level phase
+    (:data:`~.catalog.BOOT_PHASES`) and closes the one before it. The boot
+    runs on one thread, so the phases partition it from ``spawned`` (the
+    supervisor's monotonic stamp at ``Popen``, handed down in the
+    container's config) to :meth:`finish`. :meth:`mark` times work nested
+    inside a phase (:data:`~.catalog.BOOT_MARKS`) under its own name and
+    adds nothing to the partition. ``time.monotonic()`` is Linux's
+    CLOCK_MONOTONIC: one clock for the supervisor, the container and
+    whoever reads the two ends from ``/metrics``."""
+
+    def __init__(self, spawned: float | None = None, clock=None):
+        self._clock = clock or time.monotonic
+        self.spawned = self._clock() if spawned is None else float(spawned)
+        self.ready: float | None = None
+        # the boot opens in ``spawn``, at the supervisor's stamp
+        self._last = self.spawned
+        self._phase: str | None = C.BOOT_PHASES[0]
+        self.phases: dict[str, float] = {}
+        self.marks: dict[str, float] = {}
+        #: the marks open now, innermost last: (name, start, parent)
+        self._open: list[tuple[str, float, str | None]] = []
+        #: (name, start, end, parent) on the clock, for the supervisor's
+        #: child spans; a top-level phase has no parent
+        self.spans: list[tuple[str, float, float, str | None]] = []
+
+    def enter(self, phase: str | None) -> float:
+        """Close the open phase and open ``phase`` (None: only close).
+        Entering a phase twice adds to it. Returns the closed seconds."""
+        now = self._clock()
+        dt = max(0.0, now - self._last)
+        old = self._phase
+        if old is not None:
+            self.phases[old] = self.phases.get(old, 0.0) + dt
+            self.spans.append((old, self._last, now, None))
+        self._last = now
+        self._phase = phase
+        return dt
+
+    def open_mark(self, name: str) -> None:
+        """Open a mark nested in the innermost open mark, else in the open
+        phase."""
+        parent = self._open[-1][0] if self._open else self._phase
+        self._open.append((name, self._clock(), parent))
+
+    def close_mark(self) -> None:
+        """Close the innermost open mark."""
+        if not self._open:
+            return
+        name, t0, parent = self._open.pop()
+        now = self._clock()
+        self.marks[name] = self.marks.get(name, 0.0) + max(0.0, now - t0)
+        self.spans.append((name, t0, now, parent))
+
+    @contextlib.contextmanager
+    def mark(self, name: str):
+        self.open_mark(name)
+        try:
+            yield
+        finally:
+            self.close_mark()
+
+    def finish(self, registry=None) -> dict:
+        """The boot is over: close the open phase, stamp ``ready``, write
+        the gauges (once), and return what the ``ready`` message carries."""
+        self.enter(None)
+        self.ready = self._last
+        ends = {"spawned": self.spawned, "ready": self.ready}
+        _obs.set_boot_profile(
+            {**self.phases, **self.marks}, ends, registry=registry
+        )
+        return {
+            **ends,
+            "phases": dict(self.phases),
+            "marks": dict(self.marks),
+            "spans": [list(sp) for sp in self.spans],
+        }
+
+
+#: this process's boot, while it is under way (a container between
+#: ``_container_main``'s entry and its ``ready``); None everywhere else
+_boot: BootProfile | None = None
+
+
+def begin_boot(spawned: float | None = None, clock=None) -> BootProfile:
+    """Open this process's boot profile (in the phase ``spawn``)."""
+    global _boot
+    _boot = BootProfile(spawned, clock)
+    return _boot
+
+
+def finish_boot(registry=None) -> dict:
+    """Close this process's boot; what the ``ready`` message carries
+    (empty where no boot was open)."""
+    global _boot
+    boot, _boot = _boot, None
+    return boot.finish(registry) if boot is not None else {}
+
+
+def boot_enter(phase: str) -> None:
+    """Enter a top-level boot phase; nothing where no boot is under way
+    (the inline backend, a process that is no container)."""
+    if _boot is not None:
+        _boot.enter(phase)
+
+
+class boot_mark(contextlib.ContextDecorator):
+    """Around work nested in the boot (the library's ``engine_init``,
+    ``kv_alloc``, ``server_start``), as a ``with`` block or as a decorator
+    of a whole function (an ``__init__``); nothing once the boot is over
+    or where there is none."""
+
+    def __init__(self, name: str):
+        self.name = name
+
+    def __enter__(self):
+        if _boot is not None:
+            _boot.open_mark(self.name)
+
+    def __exit__(self, *_exc):
+        if _boot is not None:
+            _boot.close_mark()
 
 
 class TickProfile:
@@ -194,8 +335,10 @@ class HotPathProfiler:
     by the fleet AFTER construction); ``annotate`` is the engine's trace-
     annotation factory (``jax.profiler.TraceAnnotation`` — this package
     stays free of JAX), called as ``annotate(name, **attrs)`` for a context
-    manager. Ticks and starvation belong to the scheduler thread; :meth:`dispatch`
-    is also safe from ``prefill_sync`` server threads.
+    manager; ``monitoring`` is ``jax.monitoring``, handed over the same way
+    (:func:`install_compile_listener`). Ticks and starvation belong to the
+    scheduler thread; :meth:`dispatch` is also safe from ``prefill_sync``
+    server threads.
     """
 
     def __init__(
@@ -207,11 +350,14 @@ class HotPathProfiler:
         ledger_path=None,
         ring: int = RING_TICKS,
         annotate=None,
+        monitoring=None,
     ):
         self._clock = clock or time.monotonic
         self._name = name
         self._registry = registry
         self._annotate = annotate
+        if monitoring is not None:
+            install_compile_listener(monitoring)
         #: programs dispatched / the highest dispatch number a blocking
         #: read has harvested (the device runs them in order, so all up to
         #: it are done); equal = nothing on the device that the host knows of
@@ -369,6 +515,8 @@ class HotPathProfiler:
                 DISPATCH_ANNOTATION_PREFIX + program, shape=key[1]
             )
             ann.__enter__()
+        kinds: dict[str, float] = {}
+        _builds.open.append((self, program, kinds))
         t0 = self._clock()
         try:
             out = fn(*args, **kwargs)
@@ -378,12 +526,14 @@ class HotPathProfiler:
                     self._seen.discard(key)
             raise
         finally:
+            _builds.open.pop()
             if ann is not None:
                 ann.__exit__(None, None, None)
         built = first or (cache_size is not None and cache_size() > n0)
         self.note_compile(
             program, shape_key,
             self._clock() - t0 if built else 0.0, cache_hit=not built,
+            kinds=kinds,
         )
         # the program is on the device's queue: a starvation interval ends
         # here, charged to the phase the scheduler thread is in
@@ -411,6 +561,8 @@ class HotPathProfiler:
         with self._lock:
             self._seen.add(key)
         self._ledger_begin(key)
+        kinds: dict[str, float] = {}
+        _builds.open.append((self, program, kinds))
         t0 = self._clock()
         try:
             out = build_fn()
@@ -418,20 +570,25 @@ class HotPathProfiler:
             with self._lock:
                 self._seen.discard(key)
             raise
+        finally:
+            _builds.open.pop()
         self.note_compile(
-            program, shape_key, self._clock() - t0, cache_hit=False, ahead=ahead
+            program, shape_key, self._clock() - t0, cache_hit=False,
+            ahead=ahead, kinds=kinds,
         )
         return out
 
     def note_compile(
         self, program: str, shape_key, seconds: float, cache_hit: bool,
-        ahead: bool = False,
+        ahead: bool = False, kinds: dict | None = None,
     ) -> None:
         """THE chokepoint every build site reports through: counts the
         lookup (``mtpu_compiles_total{program,cache}``); a build (a miss at
         a dispatch site, or one made ``ahead`` of any) also observes
         ``mtpu_compile_seconds{program}`` and appends the ``end`` event to
-        the ledger."""
+        the ledger, with what JAX's events made of the seconds (``kinds``:
+        ``trace_s``, ``lower_s``, ``xla_compile_s``, ``cache_load_s``; 0.0
+        where no listener is)."""
         _obs.record_compile(
             program, seconds, cache_hit, ahead=ahead, registry=self._registry
         )
@@ -445,6 +602,10 @@ class HotPathProfiler:
             "shape_key": str(shape_key),
             "seconds": round(float(seconds), 6),
             "cache": "ahead" if ahead else "miss",
+            **{
+                kind + "_s": round((kinds or {}).get(kind, 0.0), 6)
+                for kind in C.COMPILE_KINDS
+            },
         }
         with self._lock:
             self._compiles += 1
@@ -544,6 +705,118 @@ class HotPathProfiler:
                 "ticks": [dict(e) for e in self._ring],
                 "compiles": [dict(r) for r in self._compile_log],
             }
+
+
+# -- a build split where JAX does the work -----------------------------------
+
+#: JAX's monitoring events (jax 0.9: ``jax/_src/dispatch.py``,
+#: ``compiler.py``) -> :data:`~.catalog.COMPILE_KINDS`. The three
+#: ``/jax/core/compile/`` events are spans: a scalar at entry, a duration
+#: at exit, and they nest (a jitted function traced inside another's trace,
+#: an eager operation compiled at trace time). The cache read is a bare
+#: duration recorded inside ``backend_compile``, whose own duration holds it
+COMPILE_EVENT_KIND = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "xla_compile",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_load",
+}
+#: the kinds JAX records as spans (entered, then exited with a duration)
+_SPAN_KINDS = frozenset(C.COMPILE_KINDS) - {"cache_load"}
+#: the persistent cache's answers: a compiled program read back | one
+#: compiled and written
+CACHE_EVENT_HIT = {
+    "/jax/compilation_cache/cache_hits": True,
+    "/jax/compilation_cache/cache_misses": False,
+}
+
+
+class _ThreadBuilds(threading.local):
+    """What the listener knows of the calling thread: the builds open on it
+    (``dispatch()`` / ``build()`` push ``(profiler, program, kinds)``; helper
+    threads build too, and overlap the scheduler's), how many of JAX's
+    compile spans are open, and the cache-read seconds inside the open
+    backend compile."""
+
+    def __init__(self):
+        self.open: list = []
+        self.depth = 0
+        self.loaded = 0.0
+
+
+_builds = _ThreadBuilds()
+#: the monitoring modules a listener is registered with (JAX's, or a
+#: test's fake): one listener each, for the life of the process
+_listening: list = []
+
+
+def install_compile_listener(monitoring) -> None:
+    """Register this module's listeners with ``monitoring``
+    (``jax.monitoring``, which the engine hands over: this package stays
+    free of JAX), once a process however many profilers ask. They fire only
+    when JAX traces or compiles."""
+    with _registry_lock:
+        if any(m is monitoring for m in _listening):
+            return
+        _listening.append(monitoring)
+    monitoring.register_scalar_listener(_on_compile_span_entered)
+    monitoring.register_event_duration_secs_listener(_on_compile_seconds)
+    monitoring.register_event_listener(_on_cache_answer)
+
+
+def _on_compile_span_entered(event: str, value=None, **_kw) -> None:
+    if COMPILE_EVENT_KIND.get(event) in _SPAN_KINDS:
+        _builds.depth += 1
+
+
+def _on_compile_seconds(event: str, seconds: float, **_kw) -> None:
+    kind = COMPILE_EVENT_KIND.get(event)
+    if kind is None:
+        return
+    tls = _builds
+    if kind == "cache_load":
+        if tls.depth <= 1:  # in a backend compile no other span encloses
+            tls.loaded += seconds
+            _credit(kind, seconds)
+        return
+    tls.depth = max(0, tls.depth - 1)
+    if tls.depth:
+        return  # the enclosing span's duration holds these seconds
+    if kind == "xla_compile":
+        seconds = max(0.0, seconds - tls.loaded)
+    tls.loaded = 0.0
+    _credit(kind, seconds)
+
+
+def _credit(kind: str, seconds: float) -> None:
+    open_builds = _builds.open
+    if open_builds:
+        prof, program, kinds = open_builds[-1]
+        kinds[kind] = kinds.get(kind, 0.0) + seconds
+        _obs.record_compile_phase(
+            program, kind, seconds, registry=prof._registry
+        )
+        return
+    for registry in _live_registries():
+        _obs.record_compile_phase(
+            C.EAGER_PROGRAM, kind, seconds, registry=registry
+        )
+
+
+def _on_cache_answer(event: str, **_kw) -> None:
+    hit = CACHE_EVENT_HIT.get(event)
+    if hit is not None:
+        for registry in _live_registries():
+            _obs.record_compile_cache(hit, registry=registry)
+
+
+def _live_registries() -> list:
+    """The registries of the live profilers, each once (None: the
+    process's default): work no open build claims is the process's."""
+    seen: dict[int, object] = {}
+    for prof in active_profilers():
+        seen.setdefault(id(prof._registry), prof._registry)
+    return list(seen.values())
 
 
 # -- process registry (the gateway's /profile source) ------------------------

@@ -439,6 +439,7 @@ class LLMEngine:
     #: configuration's ``chunk_offset_runtime``; ``__init__`` reads it)
     _runtime_offset = False
 
+    @_profiler.boot_mark("engine_init")  # a container's boot names it
     def __init__(
         self,
         cfg,  # a model's configuration object: llama.LlamaConfig, deepseek_v2.DeepseekV2Config
@@ -641,16 +642,17 @@ class LLMEngine:
         # the paged leaves cover the layers that keep K/V per token (all of
         # them unless the model says otherwise); a model with per-sequence
         # state declares per-slot leaves beside them (docs/recurrent_state.md)
-        self.cache = PagedKVCache.create(
-            n_layers=getattr(cfg, "n_cache_layers", cfg.n_layers),
-            leaf_shapes=cfg.cache_leaf_shapes,
-            leaf_layers=getattr(cfg, "cache_leaf_layers", None),
-            n_pages=n_pages,
-            page_size=page_size,
-            kv_dtype=kv_dtype,
-            state_leaves=getattr(cfg, "state_leaves", ()),
-            max_slots=max_slots,
-        )
+        with _profiler.boot_mark("kv_alloc"):
+            self.cache = PagedKVCache.create(
+                n_layers=getattr(cfg, "n_cache_layers", cfg.n_layers),
+                leaf_shapes=cfg.cache_leaf_shapes,
+                leaf_layers=getattr(cfg, "cache_leaf_layers", None),
+                n_pages=n_pages,
+                page_size=page_size,
+                kv_dtype=kv_dtype,
+                state_leaves=getattr(cfg, "state_leaves", ()),
+                max_slots=max_slots,
+            )
         _obs.set_state_bytes(self.cache.state_bytes())
         if mesh is not None:
             self._shard_cache(self.cache)
@@ -750,12 +752,15 @@ class LLMEngine:
         # resolved ONCE — explicit arg beats MTPU_PROFILE, unset is on. The
         # lazy name callable picks up the fleet's trace_name assignment;
         # the annotation factory puts the profiler's spans into the device
-        # trace of whoever has a profiler session open (observability/
-        # itself never imports JAX).
+        # trace of whoever has a profiler session open, and JAX's
+        # monitoring events split a build into trace, lowering, XLA's
+        # compile and a cache read (observability/ itself never imports
+        # JAX).
         self.profiler = (
             _profiler.HotPathProfiler(
                 clock=self._clock, name=lambda: self.trace_name,
                 annotate=jax.profiler.TraceAnnotation,
+                monitoring=jax.monitoring,
             )
             if _profiler.profiling_enabled(profile)
             else None
@@ -3165,8 +3170,6 @@ class LLMEngine:
             computed=width,
             needed=max(0, offset + len(chunk) - max(offset, cached)),
         )
-        if offset:
-            _obs.record_prefill_prefix_positions(offset)
         self._count_sparse(lambda: offset + np.arange(len(chunk)), "prefill")
         (
             logits, self.cache.k_pages, self.cache.v_pages, self.cache.beside,

@@ -19,6 +19,7 @@ from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 import math
 
 from ..observability import catalog as _C
+from ..observability import profiler as _profiler
 from ..observability import reqtrace as _rt
 from ..scheduling.admission import ShedError
 from ..utils.prometheus import default_registry
@@ -614,11 +615,12 @@ class OpenAIServer:
         return [self.engine]
 
     def start(self) -> "OpenAIServer":
-        for eng in self._engines():
-            eng.start()
-        self._maybe_start_canary()
-        self._thread = threading.Thread(target=self.httpd.serve_forever, daemon=True)
-        self._thread.start()
+        with _profiler.boot_mark("server_start"):
+            for eng in self._engines():
+                eng.start()
+            self._maybe_start_canary()
+            self._thread = threading.Thread(target=self.httpd.serve_forever, daemon=True)
+            self._thread.start()
         return self
 
     def serve_forever(self) -> None:

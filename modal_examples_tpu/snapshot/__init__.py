@@ -20,6 +20,7 @@ boot (and the inline backend) calls for every Cls container.
 
 from __future__ import annotations
 
+from ..observability import profiler as _profiler
 from .capture import capture
 from .codec import CodecError
 from .restore import RestoreResult, try_restore
@@ -77,7 +78,11 @@ def build_and_enter(
 
     store = SnapshotStore(root=snapshot_dir)
     had_entry = store.has(snapshot_key)
+    # a boot phase of its own (catalog.BOOT_PHASES); the hooks run in
+    # ``enter`` again
+    _profiler.boot_enter("restore")
     res = try_restore(store, snapshot_key, obj, snap_hooks)
+    _profiler.boot_enter("enter")
     if res is not None:
         ran_non_snap = False
         try:

@@ -9,6 +9,7 @@ orders under what bf16 would give (~2e-2).
 """
 
 import json
+import re
 import sys
 from pathlib import Path
 
@@ -406,7 +407,7 @@ def test_the_engines_cache_and_counters_are_the_latent_ones(jax, ds, model):
     before = {
         "held": value(C.ROUTED_PAIRS_TOTAL, where="held"),
         "elsewhere": value(C.ROUTED_PAIRS_TOTAL, where="elsewhere"),
-        "prefix": value(C.PREFILL_PREFIX_POSITIONS_TOTAL),
+        "computed": value(C.PREFILL_POSITIONS_TOTAL, kind="computed"),
     }
     eng = _engine(ds, cfg, params)
     try:
@@ -423,9 +424,15 @@ def test_the_engines_cache_and_counters_are_the_latent_ones(jax, ds, model):
     per_step = cfg.n_moe_layers * cfg.top_k_experts
     assert held + elsewhere >= 8 * per_step and (held + elsewhere) % per_step == 0
     assert 0 < held < held + elsewhere  # experts 4..11 of 16 are held
-    # the chunk calls at offsets 32, 64, ... each attended to that many cached positions
+    # the chunk calls at offsets 32, 64, ... each attended to that many cached positions:
+    # a program an offset, so the dispatches' shape keys name them, and each computed 32
     offsets = range(32, len(prompt_ids), 32)
-    assert value(C.PREFILL_PREFIX_POSITIONS_TOTAL) - before["prefix"] == sum(offsets) > 0
+    built = [r["shape_key"] for r in eng.profiler.perfetto_snapshot()["compiles"]
+             if r["program"] == "prefill_chunk"]
+    dispatched = sorted(int(re.fullmatch(r"off(\d+)w32", key).group(1)) for key in built)
+    assert dispatched == [0, *offsets] and sum(dispatched) == sum(offsets) > 0
+    computed = value(C.PREFILL_POSITIONS_TOTAL, kind="computed") - before["computed"]
+    assert computed == 32 * len(dispatched)
 
 
 @pytest.fixture(scope="module")

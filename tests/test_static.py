@@ -744,7 +744,7 @@ def test_watchdog_series_declared_and_emitted():
 
 def test_profiler_series_declared_and_emitted():
     """Closure for the hot-path profiler series (``mtpu_tick_phase_*``,
-    ``mtpu_host_overhead_*``, ``mtpu_compile*``), both directions (the
+    ``mtpu_host_overhead_*``, ``mtpu_compile*``, ``mtpu_boot_*``), both directions (the
     fleet/failover/watchdog-series guard pattern): every declared profiler
     catalog constant must be referenced by a live emitter/reader, AND every
     profiler recorder in observability/metrics.py must have a call site
@@ -759,10 +759,10 @@ def test_profiler_series_declared_and_emitted():
         if isinstance(val, str)
         and val.startswith(
             ("mtpu_tick_phase", "mtpu_host_overhead", "mtpu_compile",
-             "mtpu_device_starved")
+             "mtpu_device_starved", "mtpu_boot_")
         )
     }
-    assert len(consts) >= 4, consts
+    assert len(consts) >= 8, consts
     catalog_path = PKG_ROOT / "observability" / "catalog.py"
     package_src = {
         path: path.read_text()
@@ -782,7 +782,8 @@ def test_profiler_series_declared_and_emitted():
     metrics_path = PKG_ROOT / "observability" / "metrics.py"
     recorders = (
         "record_tick_phase", "set_host_overhead_ratio", "record_compile",
-        "record_device_starved",
+        "record_device_starved", "record_compile_phase", "record_compile_cache",
+        "set_boot_profile",
     )
     orphans = [
         fn for fn in recorders
@@ -866,6 +867,32 @@ def test_tick_phase_names_declared_and_wired():
     )
     # the guard must actually be guarding the full taxonomy
     assert len(sites) >= 9, sites
+
+
+def test_boot_phase_names_declared_and_wired():
+    """Both directions of the boot vocabulary's closure, as for the tick's:
+    every ``boot_enter("...")`` names a ``catalog.BOOT_PHASES`` member and
+    every ``boot_mark("...")`` (a ``with`` block or a decorator) a
+    ``BOOT_MARKS`` member, with a literal; and every declared name has a live site
+    (``spawn`` is the phase a boot opens in: ``BootProfile.__init__``)."""
+    from modal_examples_tpu.observability.catalog import BOOT_MARKS, BOOT_PHASES
+
+    funcs = {"boot_enter": "phase", "boot_mark": "mark"}
+    sites: dict[str, set] = {"phase": {BOOT_PHASES[0]}, "mark": set()}
+    violations = []
+    for path in sorted(PKG_ROOT.rglob("*.py")):
+        for node in ast.walk(ast.parse(path.read_text())):
+            if not (isinstance(node, ast.Call) and isinstance(node.func, ast.Attribute)
+                    and node.func.attr in funcs):
+                continue
+            name = _const_str(node.args[0]) if node.args else None
+            if name is None:
+                violations.append(f"{path.relative_to(REPO_ROOT)}:{node.lineno}: non-literal name")
+            else:
+                sites[funcs[node.func.attr]].add(name)
+    assert not violations, violations
+    assert sites["phase"] == set(BOOT_PHASES), sites["phase"] ^ set(BOOT_PHASES)
+    assert sites["mark"] == set(BOOT_MARKS), sites["mark"] ^ set(BOOT_MARKS)
 
 
 #: (file, qualified function) pairs in serving/ that may call the raw
