@@ -24,7 +24,10 @@ convolution tail is taken at the row's own length. **Decode**
 (``mtpu.ssm_step``) is the one-token form over all ``max_slots`` rows of the
 state at once; a row whose slot is not decoding stands still (``dt = 0``, its
 tail kept), so a slot whose first token is not harvested yet, or whose prompt
-is between two chunk calls, keeps what its prefill wrote.
+is between two chunk calls, keeps what its prefill wrote. The state's update
+and the output are one pass over the state where ``paged_impl_plan`` finds
+the kernel's conditions (ops/ssm_step.py: a TPU, a float32 state in whole
+vregs), XLA's update and a reduction that reads the state again elsewhere.
 
 The layers of a kind are stacked (``mamba_layers``, ``attention_layers``);
 the layer pattern is ``cfg.layer_types``. The Mamba layers run as scans over
@@ -58,6 +61,7 @@ import jax.numpy as jnp
 from ..ops import is_quantized, kv_gather, paged_decode_attention_chunked
 from ..ops import scopes as _scopes
 from ..ops.flash_attention import flash_attention, flash_attention_chunked
+from ..ops.ssm_step import ssm_step, ssm_step_shapes_ok, ssm_step_xla
 from . import layers
 from .layers import refuse
 from .layers import scatter_rows as _scatter_rows
@@ -418,10 +422,19 @@ def load_hf_weights(model_dir, cfg: GraniteHybridConfig, *, quantization=None, d
 def paged_impl_plan(
     cfg: GraniteHybridConfig, page_size: int, impl: str | None = None,
     scatter_impl: str = "xla", *, kv_dtype="bfloat16", mesh=None, warn: bool = True,
+    state_dtype=None,
 ) -> dict:
-    """What runs for this model: the chunked XLA loop over the attention
-    layers' pages and the XLA scatter (the ragged kernel wants a head of 128:
-    ops.paged_attention.ragged_shapes_ok); anything else is refused here."""
+    """What runs for this model. Attention: the chunked XLA loop over the
+    attention layers' pages and the XLA scatter (the ragged kernel wants a
+    head of 128: ops.paged_attention.ragged_shapes_ok); anything else is
+    refused here. The Mamba layers' state step (``state_step``): the one-pass
+    kernel (``"pallas"``, ops.ssm_step) where the backend is a TPU and the
+    state leaf (``state_dtype``; unset: what ``cfg.state_leaves`` declares)
+    is float32 in whole vregs (``ssm_step_shapes_ok``); XLA's update and
+    reduction (``"xla"``) everywhere else: the CPU, where the kernel would
+    run in the interpreter, and the tests' tiny shapes. One computation, and
+    two counts of passes over the state; chosen from what can be seen here,
+    by no option."""
     from ..ops.kv_quant import resolve_kv_dtype
 
     if impl not in (None, "xla") or scatter_impl != "xla":  # unset: as "xla"
@@ -431,9 +444,17 @@ def paged_impl_plan(
     kvd = resolve_kv_dtype(kv_dtype)
     if kvd == "int8":
         refuse(cfg, "int8 KV cache")
+    kernel = (
+        jax.default_backend() == "tpu" and bool(cfg.state_leaves)
+        and ssm_step_shapes_ok(
+            cfg.mamba_d_head, cfg.mamba_d_state,
+            cfg.state_leaves[0][2] if state_dtype is None else state_dtype,
+        )
+    )
     return {
         "attention": "xla-gather", "ragged_variant": None, "scatter": "xla",
         "kv_dtype": str(kvd), "tp": 1, "downgraded": [],
+        "state_step": "pallas" if kernel else "xla",
     }
 
 
@@ -584,36 +605,38 @@ def _mamba_prefill(layer, u, valid, lens, h0, tail0, cfg):
         return layers.mm(y, layer["out_proj"]), h, tail
 
 
-def _mamba_step(layer, u, live, ssm, tails, i, cfg):
+def _mamba_step(layer, u, live, ssm, tails, i, cfg, state_step: str):
     """The mixer's one-token form over every slot. u [S, D] (normed), live
     [S] bool; ``ssm`` [L, S, H, P, N] and ``tails`` [L, S, K - 1, conv_dim]
     are the whole per-slot leaves, of which layer ``i`` is read and written
     in place (inside the scope, so that the trace charges the state's
-    traffic to ``mtpu.ssm_step``). A row that is not live keeps its state and
-    its tail. Returns (out [S, D], ssm, tails)."""
+    traffic to ``mtpu.ssm_step``), by the form ``state_step`` names
+    (``paged_impl_plan``): the kernel's one pass or XLA's update and
+    reduction. A row that is not live keeps its state and its tail. Returns
+    (out [S, D], ssm, tails)."""
     S = u.shape[0]
     dt_ = u.dtype
-    di, H, G = cfg.d_inner, cfg.mamba_n_heads, cfg.mamba_n_groups
+    di, G = cfg.d_inner, cfg.mamba_n_groups
     with jax.named_scope(_scopes.SSM_PROJ):
         z, xbc, dt = _in_proj(layer, u)
     with jax.named_scope(_scopes.SSM_STEP):
-        h, tail = ssm[i], tails[i]
+        tail = tails[i]
         window = jnp.concatenate([tail, xbc[:, None, :].astype(tail.dtype)], axis=1)
         conv = layer["conv_b"].astype(jnp.float32) + jnp.einsum(
             "skc,kc->sc", window.astype(jnp.float32), layer["conv_w"].astype(jnp.float32)
         )
         x, B, C = _split_xbc(jax.nn.silu(conv).astype(dt_), cfg)
         x = x.astype(jnp.float32)
-        B, C = (jnp.repeat(a.astype(jnp.float32), H // G, axis=1) for a in (B, C))
         dt = jnp.where(live[:, None], jax.nn.softplus(dt + layer["dt_bias"]), 0.0)
         A = -jnp.exp(layer["A_log"].astype(jnp.float32))
-        h_new = (
-            jnp.exp(dt * A)[..., None, None] * h.astype(jnp.float32)
-            + (dt[..., None] * x)[..., None] * B[:, :, None, :]
+        step = ssm_step if state_step == "pallas" else ssm_step_xla
+        # h' = exp(dt A) h + dt x (x) B, y = h' . C; layer i of ``ssm`` set to h'
+        ssm, y = step(
+            ssm, i, jnp.exp(dt * A), dt[..., None] * x,
+            B.astype(jnp.float32), C.astype(jnp.float32),
         )
-        y = jnp.einsum("shpn,shn->shp", h_new, C) + layer["D"].astype(jnp.float32)[:, None] * x
+        y = y + layer["D"].astype(jnp.float32)[:, None] * x
         tails = tails.at[i].set(jnp.where(live[:, None, None], window[:, 1:], tail))
-        ssm = ssm.at[i].set(h_new.astype(ssm.dtype))
     with jax.named_scope(_scopes.SSM_PROJ):
         y = _gated_norm(
             y.reshape(S, di).astype(dt_), z, layer["gate_norm"], cfg.norm_eps, G
@@ -895,7 +918,10 @@ def decode_step(
     not ``active`` keeps its state. Returns (logits [B, vocab], k_pages,
     v_pages, state)."""
     _check_serving(cfg, k_pages, mesh)
-    paged_impl_plan(cfg, k_pages.shape[2], impl, scatter_impl, kv_dtype=k_pages.dtype)
+    plan = paged_impl_plan(
+        cfg, k_pages.shape[2], impl, scatter_impl, kv_dtype=k_pages.dtype,
+        state_dtype=state[0].dtype,
+    )
     page_size = k_pages.shape[2]
     B = tokens.shape[0]
     page_idx = jnp.take_along_axis(page_tables, (positions // page_size)[:, None], axis=1)[:, 0]
@@ -907,7 +933,9 @@ def decode_step(
         x, ssm, tails = carry
         layer = _row(params["mamba_layers"], i)
         u = layers.rms_norm(x, layer["mixer_norm"], cfg.norm_eps)
-        mixed, ssm, tails = _mamba_step(layer, u, active, ssm, tails, i, cfg)
+        mixed, ssm, tails = _mamba_step(
+            layer, u, active, ssm, tails, i, cfg, plan["state_step"]
+        )
         x = _mlp(layer, _residual(x, mixed, cfg), cfg)
         return (x, ssm, tails), None
 

@@ -1004,14 +1004,15 @@ CATALOG: dict[str, dict] = {
         "type": "gauge",
         "labels": [
             "attention", "scatter", "kv_dtype", "tp", "variant",
-            "downgraded", "allocator",
+            "downgraded", "allocator", "state_step",
         ],
         "help": (
             "resolved decode implementation plan (info metric, value 1); "
             "tp = tensor-parallel degree, variant = the PER-SHARD ragged "
             "kernel formulation actually run, downgraded = requested Pallas "
             "impls that fell back to XLA, allocator = native|python page "
-            "allocator"
+            "allocator, state_step = pallas|xla form of a recurrent model's "
+            "decode state step (- without per-slot state)"
         ),
     },
     SPEC_PROPOSED_TOTAL: {

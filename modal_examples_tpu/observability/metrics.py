@@ -205,9 +205,10 @@ def set_decode_impl(plan: dict, *, registry: Registry | None = None) -> None:
     """Info gauge for the engine's resolved decode plan: the attention /
     scatter impls, cache dtype, tensor-parallel degree, the PER-SHARD
     ragged variant (``paged_impl_plan(mesh=...)``), how many requested
-    Pallas impls were downgraded and which page allocator loaded — so
-    dashboards, benches and ``chip_smoke.py`` report the plan actually run,
-    not the requested one."""
+    Pallas impls were downgraded, which page allocator loaded and which
+    form steps a model's per-slot state (``state_step``; ``"-"`` for a model
+    without any) — so dashboards, benches and ``chip_smoke.py`` report the
+    plan actually run, not the requested one."""
     _reg(registry).gauge_set(
         C.DECODE_IMPL,
         1.0,
@@ -219,6 +220,7 @@ def set_decode_impl(plan: dict, *, registry: Registry | None = None) -> None:
             "variant": str(plan.get("ragged_variant") or "-"),
             "downgraded": str(len(plan.get("downgraded") or ())),
             "allocator": str(plan.get("allocator") or "-"),
+            "state_step": str(plan.get("state_step") or "-"),
         },
         help=C.CATALOG[C.DECODE_IMPL]["help"],
     )

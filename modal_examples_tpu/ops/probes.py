@@ -32,6 +32,7 @@ PROBED_MODULES: dict[str, list[str]] = {
     ],
     "modal_examples_tpu.ops.quantized_matmul": ["int8_matmul"],
     "modal_examples_tpu.ops.sparse_attention": ["selected_attention"],
+    "modal_examples_tpu.ops.ssm_step": ["ssm_step"],
 }
 
 #: every attention probe's bound against its reference: bf16 operands with
@@ -262,6 +263,30 @@ def probe_selected_attention(H=4, C=256, S=512, D=256, k=64) -> dict:
     return {"max_err": round(err, 4)}
 
 
+def probe_ssm_step(L=2, S=4, H=16, P=8, N=128, G=2) -> dict:
+    """A Mamba-2 layer's decode state step in one pass (the last layer of a
+    stacked float32 leaf, a slot that is not live, two groups) against XLA's
+    update and reduction: the same float32 arithmetic, the 128-term sums in
+    another order."""
+    import jax
+    import jax.numpy as jnp
+
+    from modal_examples_tpu.ops.ssm_step import ssm_step, ssm_step_xla
+
+    ks = jax.random.split(jax.random.PRNGKey(0), 5)
+    live = (jnp.arange(S) != 1)[:, None]
+    ssm = jax.random.normal(ks[0], (L, S, H, P, N), jnp.float32)
+    decay = jnp.where(live, jax.random.uniform(ks[1], (S, H), jnp.float32, 0.5, 1.0), 1.0)
+    dtx = jnp.where(live[..., None], jax.random.normal(ks[2], (S, H, P), jnp.float32), 0.0)
+    B, C = (jax.random.normal(k, (S, G, N), jnp.float32) for k in ks[3:])
+    (got_h, got_y), (want_h, want_y) = (
+        jax.jit(step)(ssm, jnp.int32(L - 1), decay, dtx, B, C) for step in (ssm_step, ssm_step_xla)
+    )
+    errs = {"state_err": _err(got_h, want_h), "y_err": _err(got_y, want_y)}
+    assert errs["state_err"] < 1e-5 and errs["y_err"] < 1e-3, errs
+    return {k: round(v, 7) for k, v in errs.items()}
+
+
 #: probe name -> zero-argument callable, on the smallest legal shapes
 KERNEL_PROBES: dict[str, Callable[[], dict]] = {
     "flash_fwd": probe_flash_fwd,
@@ -294,6 +319,7 @@ KERNEL_PROBES: dict[str, Callable[[], dict]] = {
     "scatter_kv": functools.partial(probe_scatter, 16),
     "scatter_kv_int8": functools.partial(probe_scatter, 32, int8=True),
     "selected_attention": probe_selected_attention,
+    "ssm_step": probe_ssm_step,
 }
 
 

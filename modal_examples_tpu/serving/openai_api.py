@@ -224,7 +224,8 @@ class _Handler(BaseHTTPRequestHandler):
                     f'"{eng.impl_plan.get("tp", 1)}",variant='
                     f'"{eng.impl_plan.get("ragged_variant") or "-"}",'
                     f'downgraded="{len(eng.impl_plan["downgraded"])}",'
-                    f'allocator="{eng.impl_plan["allocator"]}"}} 1'
+                    f'allocator="{eng.impl_plan["allocator"]}",state_step='
+                    f'"{eng.impl_plan.get("state_step") or "-"}"}} 1'
                 )
             import jax
 

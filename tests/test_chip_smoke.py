@@ -77,6 +77,7 @@ class TestRehearsal:
         )
         assert plan["downgraded"] == "0"
         assert plan["allocator"] in ("native", "python")
+        assert plan["state_step"] == "-"  # a llama model keeps no per-slot state
         summary = json.loads(lines[-2])
         assert summary["requests_sent"] == summary["succeeded"] == 9
         assert summary["failed"] == summary["error_count"] == 0

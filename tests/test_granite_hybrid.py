@@ -96,6 +96,14 @@ def _tables(rows, pages_per_seq=8, first=1):
     return t
 
 
+def _force_state_step(monkeypatch, G, form):
+    """Make ``paged_impl_plan`` name ``form`` for the Mamba layers' state
+    step, whatever the backend and the shapes: on the CPU ``"pallas"`` runs
+    the kernel in the interpreter, which takes any shape."""
+    plan = G.paged_impl_plan
+    monkeypatch.setattr(G, "paged_impl_plan", lambda *a, **kw: {**plan(*a, **kw), "state_step": form})
+
+
 # -- the configuration ------------------------------------------------------------------
 
 
@@ -219,10 +227,13 @@ def _serve(jax, G, cfg, params, prompts, n_decode, *, bucket=32, slots=4, feed=N
     return np.stack(out, axis=1), state
 
 
-def test_prefill_then_decode_is_the_references_full_pass(jax, G, ref, model):
+@pytest.mark.parametrize("state_step", ["xla", "pallas"])
+def test_prefill_then_decode_is_the_references_full_pass(jax, G, ref, model, state_step, monkeypatch):
     """Two requests of different lengths in one prefill call and one decode
     batch (slots 2 and 3 empty): at every served position the logits are the
-    reference's over prompt + fed tokens, to float32 rounding."""
+    reference's over prompt + fed tokens, to float32 rounding, under either
+    form of the state step."""
+    _force_state_step(monkeypatch, G, state_step)
     cfg, params = model
     rng = np.random.default_rng(1)
     prompts = [rng.integers(3, 512, size=n).tolist() for n in (21, 9)]
@@ -432,15 +443,21 @@ PROMPTS = {
 }
 
 
-def test_the_engine_serves_the_references_first_choice_and_reuses_slots(jax, G, ref, model):
+@pytest.mark.parametrize("state_step", ["xla", "pallas"])
+def test_the_engine_serves_the_references_first_choice_and_reuses_slots(
+    jax, G, ref, model, state_step, monkeypatch
+):
     """Through ``LLMEngine``: a bucketed prompt and a chunked one (three
     chunk calls, the state carried in the slot between them), five requests
     over three slots so that every slot is taken a second time, requests of
     different lengths in one decode batch. A slot's second tenant is served
-    what a fresh engine serves it."""
+    what a fresh engine serves it. Under either form of the state step (the
+    kernel in the interpreter), and the engine's plan and its
+    ``mtpu_decode_impl`` series name the form that served."""
     from modal_examples_tpu.observability import catalog as C
     from modal_examples_tpu.utils.prometheus import default_registry
 
+    _force_state_step(monkeypatch, G, state_step)
     cfg, params = model
     texts = [PROMPTS["short"], PROMPTS["chunked"], "third", PROMPTS["chunked"][::-1], "fifth one"]
     stepped0 = default_registry.value(C.STATE_ROWS_TOTAL, {"kind": "stepped"}) or 0.0
@@ -450,6 +467,8 @@ def test_the_engine_serves_the_references_first_choice_and_reuses_slots(jax, G, 
         assert len(eng.cache.state) == 2 and eng.cache.k_pages.shape[0] == cfg.n_cache_layers
         assert eng.cache.state[0].shape == (4, 3, 8, 16, 16)  # [mamba layers, slots, H, P, N]
         assert default_registry.value(C.STATE_BYTES) == eng.cache.state_bytes() > 0
+        assert eng.impl_plan["state_step"] == state_step
+        assert state_step in [labels["state_step"] for labels, _ in default_registry.series(C.DECODE_IMPL)]
         served = [_tokens(eng, r) for r in [_submit(eng, t) for t in texts]]
     finally:
         eng.stop()
@@ -503,6 +522,28 @@ REFUSED = {
     "disaggregated transfer": dict(tiered_prefix=True),
     "a Pallas paged_impl or scatter_impl": dict(paged_impl="pallas"),
 }
+
+
+@pytest.mark.parametrize(
+    "backend,size,state_dtype,want",
+    [
+        ("cpu", "published", None, "xla"),  # the interpreter is no serving path
+        ("tpu", "published", None, "pallas"),
+        ("tpu", "published", "bfloat16", "xla"),  # the kernel is float32's
+        ("tpu", "tiny", None, "xla"),  # a [16, 16] state is no whole vreg
+    ],
+    ids=["cpu", "tpu-published", "tpu-bf16-leaf", "tpu-tiny"],
+)
+def test_the_plan_names_the_state_steps_form_from_backend_dtype_and_shape(
+    jax, G, monkeypatch, backend, size, state_dtype, want
+):
+    """``paged_impl_plan`` chooses the state step from what it can see, and
+    no argument or environment variable says otherwise."""
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    cfg = G.GraniteHybridConfig() if size == "published" else G.GraniteHybridConfig.tiny()
+    plan = G.paged_impl_plan(cfg, 16, state_dtype=state_dtype)
+    assert plan["state_step"] == want
+    assert plan["attention"] == "xla-gather" and plan["scatter"] == "xla"
 
 
 @pytest.mark.parametrize("feature", list(REFUSED))

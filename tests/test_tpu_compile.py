@@ -318,7 +318,16 @@ def test_granite_decode_block_updates_its_state_in_place_on_a_v5e(granite):
     (``cfg.kv_fold``): as ``(8, 64)`` the device laid them out pages-minor
     and relaid each out on the way in and out of the block, 1.6 GiB of
     temporaries. Weights 5.6 + state 4.6 + pages 0.75 + this fit the chip's
-    15.75 GiB."""
+    15.75 GiB.
+
+    Since PR 37 the state step is the one-pass kernel (the plan's
+    ``state_step`` on a TPU at these shapes): a Mosaic call a Mamba segment
+    under ``mtpu.ssm_step``, handed the whole leaf and aliased to it, and no
+    XLA operation of a layer's ``[64, 64, 64, 128]`` state is left (the
+    update fusion and the reduction that read ``h'`` again are gone).
+    Attention stays the loop: a 64-wide head is not the ragged kernel's."""
+    import re
+
     compiled = granite["block"]()
     mem = compiled.memory_analysis()
     held = granite["state_bytes"] + granite["page_bytes"]
@@ -328,10 +337,43 @@ def test_granite_decode_block_updates_its_state_in_place_on_a_v5e(granite):
     text = compiled.as_text()
     assert granite["cfg"].cache_leaf_shapes == ((4, 128), (4, 128))
     assert "bf16[4,6144,16,4,128]" in text and " copy(bf16[4,6144,16,4,128]" not in text
-    assert "f32[36,64,64,64,128]" in text  # the state leaf, indexed [layer] out of the stack
-    assert "f32[36,64,64,64,128]{4,3,2,1,0:T(8,128)} copy(" not in text
+    assert "f32[36,64,64,64,128]" in text  # the state leaf, handed whole to the kernel
+    assert not re.search(r"f32\[36,64,64,64,128\]\S* copy\(", text)
+    assert "f32[64,64,64,128]" not in text  # no XLA pass over a layer's state
     assert "mtpu.ssm_step" in text and "mtpu.ssm_proj" in text and "mtpu.attention" in text
-    assert "tpu_custom_call" not in text  # a 64-wide head: the loop, whatever the backend
+    calls = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    segments = [s for s in granite["cfg"].segments if s[0] == "mamba"]
+    assert len(calls) == len(segments) == 5
+    assert all("mtpu.ssm_step/" in c and "mtpu.attention" not in c for c in calls)
+    assert all("f32[36,64,64,64,128]" in c.split(" custom-call(")[0] for c in calls)  # its output: the leaf
+
+
+@pytest.mark.parametrize("groups", [1, 8])
+def test_the_state_steps_tile_fits_the_scoped_vmem_of_a_v5e(one_chip, groups):
+    """The kernel alone at the published widths and the cell's 64 slots,
+    with the tile it chooses: the tile in and out, each double-buffered, and
+    the 8 MiB the call leaves for the rest are the 16 MiB a v5e's kernel
+    gets by default, no more, and Mosaic takes the kernel inside them (it
+    refuses the compile otherwise). ``groups`` 8: ``B`` and ``C`` rows
+    picked by head."""
+    import jax
+    import jax.numpy as jnp
+
+    from modal_examples_tpu.ops.ssm_step import TILE_BYTES, ssm_step, ssm_step_tile
+
+    L, S, H, P, N = 36, 64, 64, 64, 128
+    ts, th = ssm_step_tile(S, H, P, N)
+    assert 4 * ts * th * P * N * 4 + 8 * 2**20 <= 4 * TILE_BYTES + 8 * 2**20 <= 16 * 2**20
+    f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)  # noqa: E731
+    compiled = jax.jit(
+        lambda ssm, i, *rest: ssm_step(ssm, i, *rest, interpret=False), donate_argnums=0
+    ).lower(
+        f32(L, S, H, P, N), jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip),
+        f32(S, H), f32(S, H, P), f32(S, groups, N), f32(S, groups, N),
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == L * S * H * P * N * 4 and mem.temp_size_in_bytes < 2**20
 
 
 def test_granite_prefill_call_compiles_at_head_width_64_on_a_v5e(granite):
