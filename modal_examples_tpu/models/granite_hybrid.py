@@ -132,10 +132,7 @@ class GraniteHybridConfig:
         out of every program that gathers or scatters it: four copies of
         0.375 GiB a decode block at the benchmark's size, found compile-only
         (PERF.md section 6, PR 31); rows of 128 are not."""
-        return max(
-            f for f in range(1, self.n_kv_heads + 1)
-            if self.n_kv_heads % f == 0 and (f == 1 or f * self.head_dim <= 128)
-        )
+        return kv_fold(self.n_kv_heads, self.head_dim)
 
     @property
     def cache_leaf_shapes(self) -> tuple[tuple[int, int], tuple[int, int]]:
@@ -274,6 +271,15 @@ class GraniteHybridConfig:
             max_seq_len=cfg.get("max_position_embeddings", 4096),
             tie_embeddings=cfg.get("tie_word_embeddings", True),
         )
+
+
+def kv_fold(n_kv_heads: int, head_dim: int) -> int:
+    """The most K/V heads that divide ``n_kv_heads`` and keep a page row
+    within the TPU's 128 lanes (``GraniteHybridConfig.kv_fold`` says why)."""
+    return max(
+        f for f in range(1, n_kv_heads + 1)
+        if n_kv_heads % f == 0 and (f == 1 or f * head_dim <= 128)
+    )
 
 
 # -- parameters -------------------------------------------------------------

@@ -96,6 +96,15 @@ GRANITE_HYBRID_TARGETS = (
 )
 
 
+#: ... and in an LFM2 tree (models/lfm2.py): the convolution mixer's two
+#: projections, the attention projections, the dense and the routed SwiGLUs;
+#: the taps, the norms, the router and its selection bias stay
+LFM2_TARGETS = (
+    "in_proj", "out_proj", "wq", "wk", "wv", "wo", "gate", "up", "down",
+    "moe_gate", "moe_up", "moe_down",
+)
+
+
 def bits_of(quantization: str) -> int:
     if quantization not in ("int8", "int4"):
         raise ValueError(f"unknown quantization {quantization!r}")

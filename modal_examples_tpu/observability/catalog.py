@@ -96,6 +96,13 @@ DECODE_KV_POSITIONS_TOTAL = "mtpu_decode_kv_positions_total"
 #: block's tokens at its harvest. Only a model that holds a share of its
 #: experts reports it
 ROUTED_PAIRS_TOTAL = "mtpu_routed_pairs_total"
+#: counter {kind}: rows of a routed layer's expert tiles in decode blocks,
+#: counted on the device from the route's ids and read with the block's
+#: tokens at its harvest: kind = pairs (the (token, expert) pairs of live
+#: slots) | rows (the rows of the tiles computed for them: each reached
+#: expert's pairs padded to whole tiles). pairs / rows is how full the tiles
+#: ran. Only a model that declares ``counts_expert_tile_rows`` reports it
+EXPERT_TILE_ROWS_TOTAL = "mtpu_expert_tile_rows_total"
 #: counter {kind}: rows of the cache's per-slot leaves (a recurrent layer's
 #: state, one row a slot and layer) that the decode blocks' steps read and
 #: wrote, counted at each block dispatch from what the host knows: kind =
@@ -626,6 +633,12 @@ CATALOG: dict[str, dict] = {
         "labels": ["where"],
         "help": "(token, expert) pairs routed in decode blocks (where=held: "
                 "on an expert this chip holds | elsewhere: another share's)",
+    },
+    EXPERT_TILE_ROWS_TOTAL: {
+        "type": "counter",
+        "labels": ["kind"],
+        "help": "rows of the routed experts' tiles in decode blocks (kind=pairs: "
+                "real (token, expert) pairs | rows: tile rows computed for them)",
     },
     STATE_ROWS_TOTAL: {
         "type": "counter",

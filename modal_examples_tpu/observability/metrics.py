@@ -201,6 +201,19 @@ def set_engine_gauges(
     )
 
 
+def record_expert_tile_rows(
+    pairs: int, rows: int, *, registry: Registry | None = None
+) -> None:
+    """One harvested decode block of a model that counts its expert tiles:
+    the routed pairs of live slots, and the tile rows computed for them."""
+    reg = _reg(registry)
+    for kind, n in (("pairs", pairs), ("rows", rows)):
+        reg.counter_inc(
+            C.EXPERT_TILE_ROWS_TOTAL, float(n), labels={"kind": kind},
+            help=C.CATALOG[C.EXPERT_TILE_ROWS_TOTAL]["help"],
+        )
+
+
 def set_decode_impl(plan: dict, *, registry: Registry | None = None) -> None:
     """Info gauge for the engine's resolved decode plan: the attention /
     scatter impls, cache dtype, tensor-parallel degree, the PER-SHARD

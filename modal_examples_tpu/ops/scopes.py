@@ -26,10 +26,11 @@ SSM_SCAN = "mtpu.ssm_scan"  # prefill: the causal convolution and the chunked sc
 SSM_STEP = "mtpu.ssm_step"  # decode: convolution shift, one state update, output
 INDEXER = "mtpu.indexer"  # sparse attention: index projections, key gather, index scores
 TOPK_SELECT = "mtpu.topk_select"  # ... and the exact top-k of the scores
+CONV_MIX = "mtpu.conv_mix"  # a gated short convolution: projections, gates, taps, window
 
 ALL = (
     PAGE_GATHER, ATTENTION, DENSE_MLP, ROUTER, EXPERT_SCAN, KV_SCATTER,
     SAMPLING, LATENT_EXPAND, EXPERT_DISPATCH, SSM_PROJ, SSM_SCAN, SSM_STEP,
-    INDEXER, TOPK_SELECT,
+    INDEXER, TOPK_SELECT, CONV_MIX,
 )
 

@@ -41,7 +41,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..models import deepseek_v2, glm_dsa, granite_hybrid, llama
+from ..models import deepseek_v2, glm_dsa, granite_hybrid, lfm2, llama
 from ..models.layers import refuse as _refuse
 from ..observability import incident as _incident
 from ..observability import metrics as _obs
@@ -426,6 +426,7 @@ MODEL_PRESETS = {
     "tiny-deepseek-v2": deepseek_v2.DeepseekV2Config.tiny,
     "tiny-granite-hybrid": granite_hybrid.GraniteHybridConfig.tiny,
     "tiny-glm-dsa": glm_dsa.GlmDsaConfig.tiny,
+    "tiny-lfm2": lfm2.Lfm2Config.tiny,
 }
 
 
@@ -554,7 +555,12 @@ class LLMEngine:
         # what a model's programs do not implement yet is refused here, by
         # name, never silently
         self._model = cfg.model
-        self._counts_routed = bool(getattr(cfg, "counts_routed_pairs", False))
+        # what the model counts on the device in its decode steps and hands
+        # back beside the logits, [2] int32 each, in decode_step's order
+        self._block_counts = tuple(
+            kind for kind in ("routed_pairs", "expert_tile_rows")
+            if getattr(cfg, f"counts_{kind}", False)
+        )
         # a model whose chunk program takes the chunk's offset as an argument
         # (one program a prefix bucket and width, not one an offset)
         self._runtime_offset = bool(getattr(cfg, "chunk_offset_runtime", False))
@@ -1116,11 +1122,12 @@ class LLMEngine:
         pages. Returns (tokens [K, B], last [B], caches, state).
         """
         tok0 = jnp.where(override_mask, override, prev_tokens)
-        # a model that counts its routed pairs hands them back beside the
-        # logits; summed over the block's steps they leave it as two more
-        # rows of the token matrix ([held], [all]: _process_block splits
-        # them off), so the harvest's one read of the tokens brings them
-        counted = {"return_counts": True} if self._counts_routed else {}
+        # what a model counts (its routed pairs, its expert tiles' rows) it
+        # hands back beside the logits; summed over the block's steps each
+        # count leaves it as two more rows of the token matrix
+        # (_process_block splits them off), so the harvest's one read of
+        # the tokens brings them
+        counted = {"return_counts": True} if self._block_counts else {}
 
         def body(carry, k_i):
             tok, pos, kp, vp, st = carry
@@ -4012,12 +4019,16 @@ class LLMEngine:
         toks, valid, snapshot, spec_meta, seq = self._inflight.popleft()
         t0 = self._harvest_begin()
         toks_np = np.asarray(toks)  # [K, B] — the ONE blocking read per block
-        if self._counts_routed and valid is None and spec_meta is None:
-            # the classic block of a model that counts its routed pairs
-            # carries them as its last two rows: the same read brought them
-            held, pairs = int(toks_np[-2, 0]), int(toks_np[-1, 0])
-            toks_np = toks_np[:-2]
-            _obs.record_routed_pairs(held=held, elsewhere=pairs - held)
+        if self._block_counts and valid is None and spec_meta is None:
+            # the classic block of a model that counts on the device carries
+            # each count as two rows after the tokens: the same read brought them
+            n = 2 * len(self._block_counts)
+            counted, toks_np = toks_np[-n:, 0].reshape(-1, 2), toks_np[:-n]
+            for kind, (first, second) in zip(self._block_counts, counted.tolist()):
+                if kind == "routed_pairs":  # [held, all]
+                    _obs.record_routed_pairs(held=first, elsewhere=second - first)
+                else:  # [pairs, rows]
+                    _obs.record_expert_tile_rows(pairs=first, rows=second)
         # the macro-step harvest plane (docs/multistep.md): the validity
         # mask rides the SAME round trip as the tokens — per-slot accept
         # stops at the first invalid row (the lane died at its stop token

@@ -15,13 +15,14 @@ import json
 def _engine(family):
     import jax.numpy as jnp
 
-    from modal_examples_tpu.models import deepseek_v2, granite_hybrid, llama
+    from modal_examples_tpu.models import deepseek_v2, glm_dsa, granite_hybrid, llama
     from modal_examples_tpu.serving import LLMEngine
 
     cfg = {
         "llama": lambda: llama.LlamaConfig.tiny(),
         "deepseek_v2": lambda: deepseek_v2.DeepseekV2Config.tiny(n_held_experts=8, expert_offset=4),
         "granite_hybrid": lambda: granite_hybrid.GraniteHybridConfig.tiny(),
+        "glm_dsa": lambda: glm_dsa.GlmDsaConfig.tiny(n_held_experts=8, expert_offset=4),
     }[family]()
     extra = {"enable_prefix_cache": False} if family == "granite_hybrid" else {}
     return LLMEngine(
@@ -57,7 +58,7 @@ def hashes(family: str) -> dict:
             ).as_text(),
             "chunk": eng._chunk_jit(16).lower(
                 eng.params, i32(1, 16), kp, vp, i32(1, pp), i32(1), **eng._state_args([0], 1),
-                cfg=cfg,
+                **({"q_offset": jnp.int32(16)} if eng._runtime_offset else {}), cfg=cfg,
             ).as_text(),
         }
     finally:
@@ -65,7 +66,7 @@ def hashes(family: str) -> dict:
     return {name: hashlib.sha256(text.encode()).hexdigest()[:16] for name, text in texts.items()}
 
 
-FAMILIES = ("llama", "deepseek_v2", "granite_hybrid")
+FAMILIES = ("llama", "deepseek_v2", "granite_hybrid", "glm_dsa")
 
 if __name__ == "__main__":
     print(json.dumps({f: hashes(f) for f in FAMILIES}, indent=1))

@@ -108,7 +108,7 @@ def test_bucket_prefill_program_is_named_and_scoped(engine):
 
 
 def test_every_scope_is_one_the_list_names():
-    assert len(set(scopes.ALL)) == len(scopes.ALL) == 14  # PR 34: mtpu.indexer, mtpu.topk_select
+    assert len(set(scopes.ALL)) == len(scopes.ALL) == 15  # PR 34: mtpu.indexer, mtpu.topk_select; PR 39: mtpu.conv_mix
     assert all(s.startswith("mtpu.") for s in scopes.ALL)
 
 
