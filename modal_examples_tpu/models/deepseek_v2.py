@@ -320,10 +320,14 @@ def load_hf_weights(model_dir, cfg, **kwargs):
 def paged_impl_plan(
     cfg: DeepseekV2Config, page_size: int, impl: str | None = None,
     scatter_impl: str = "xla", *, kv_dtype="bfloat16", mesh=None, warn: bool = True,
+    expert_dtype=None,
 ) -> dict:
     """What runs for this model: the chunked XLA loop over the latent pages
     and the XLA scatter. The ragged kernel refuses a 576-wide head
-    (ops.paged_attention.ragged_shapes_ok), so anything else is refused here."""
+    (ops.paged_attention.ragged_shapes_ok), so anything else is refused here.
+    ``expert_scan``: the form of the routed experts' tile loop in a decode
+    step, ``moe.expert_scan_form``'s choice for experts of ``expert_dtype``
+    (unset: the model's own)."""
     from ..ops.kv_quant import resolve_kv_dtype
 
     if impl not in (None, "xla") or scatter_impl != "xla":  # unset: as "xla"
@@ -336,6 +340,9 @@ def paged_impl_plan(
     return {
         "attention": "xla-gather", "ragged_variant": None, "scatter": "xla",
         "kv_dtype": str(kvd), "tp": 1, "downgraded": [],
+        "expert_scan": _moe.expert_scan_form(
+            1, cfg.dim, cfg.moe_ffn_dim, expert_dtype or cfg.dtype
+        ) if cfg.n_moe_layers else None,
     }
 
 

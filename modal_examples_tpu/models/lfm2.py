@@ -428,13 +428,18 @@ def load_hf_weights(model_dir, cfg: Lfm2Config, *, quantization=None, dtype=None
 def paged_impl_plan(
     cfg: Lfm2Config, page_size: int, impl: str | None = None,
     scatter_impl: str = "xla", *, kv_dtype="bfloat16", mesh=None, warn: bool = True,
+    expert_dtype=None,
 ) -> dict:
     """What runs for this model, chosen from what can be seen here and by no
     option. Attention: the chunked XLA loop over the attention layers' pages
     and the XLA scatter (the ragged kernel wants a head of 128:
     ops.paged_attention.ragged_shapes_ok; this model's is ``head_dim``);
     anything else is refused here. The convolution's window step
-    (``state_step``): XLA's, three multiply-adds and a shift of 8 KB a slot."""
+    (``state_step``): XLA's, three multiply-adds and a shift of 8 KB a slot.
+    The routed experts' tile loop in a decode step (``expert_scan``):
+    ``moe.expert_scan_form``'s choice for experts of ``expert_dtype`` (unset:
+    the model's own), the grouped-matmul kernel on a TPU, XLA's loop
+    elsewhere."""
     from ..ops.kv_quant import resolve_kv_dtype
 
     if impl not in (None, "xla") or scatter_impl != "xla":  # unset: as "xla"
@@ -448,6 +453,9 @@ def paged_impl_plan(
         "attention": "xla-gather", "ragged_variant": None, "scatter": "xla",
         "kv_dtype": str(kvd), "tp": 1, "downgraded": [],
         "state_step": "xla" if cfg.state_leaves else None,
+        "expert_scan": _moe.expert_scan_form(
+            1, cfg.dim, cfg.moe_ffn_dim, expert_dtype or cfg.dtype
+        ) if cfg.n_moe_layers else None,
     }
 
 
@@ -569,7 +577,10 @@ def _ffn(layer, x, cfg, dense: bool, token_mask):
         *(layer[n] for n in _moe.EXPERT_LEAVES), flat, ids, weights,
         token_mask=mask, layer=layer["expert_layer"],
     )
-    counts = tile_rows(ids, mask, cfg.n_experts, _moe.expert_tile(flat.shape[0]))
+    counts = tile_rows(
+        ids, mask, cfg.n_experts,
+        _moe.expert_tile(flat.shape[0], cfg.top_k_experts, cfg.n_experts),
+    )
     return x + out.astype(x.dtype).reshape(x.shape), counts
 
 

@@ -355,7 +355,7 @@ def _mlp_block(
 
 
 def _serving_mlp(
-    layer: dict, h: jax.Array, cfg: LlamaConfig, token_mask=None
+    layer: dict, h: jax.Array, cfg: LlamaConfig, token_mask=None, mesh=None
 ) -> tuple[jax.Array, jax.Array]:
     """Post-norm MLP for one layer of a serving program (``layer`` as
     ``moe.scan_layers`` hands it over): dense SwiGLU, or the routed layer as
@@ -363,7 +363,10 @@ def _serving_mlp(
     and only those (token, expert) pairs multiplied, out of the experts'
     whole stacks at ``layer["expert_layer"]``. Returns (out, [2] int32: the
     routed pairs of the tokens ``token_mask`` counts, held here and all;
-    zeros for a dense layer)."""
+    zeros for a dense layer). Under tensor parallelism (``mesh``) the experts'
+    matrices are sharded over their width and the compiler partitions XLA's
+    tile loop; the grouped-matmul kernel is one device's program, so the
+    loop stays (``paged_impl_plan``'s ``expert_scan``)."""
     if cfg.n_experts == 0:
         out = layers.swiglu_mlp({k: layer[k] for k in ("gate", "up", "down")}, h)
         return out, jnp.zeros((2,), jnp.int32)
@@ -372,6 +375,7 @@ def _serving_mlp(
         h.reshape(-1, cfg.dim), cfg.top_k_experts, renormalize=True,
         layer=layer["expert_layer"],
         token_mask=None if token_mask is None else token_mask.reshape(-1),
+        scan="xla" if mesh_tp_degree(mesh) > 1 else None,
     )
     return flat.astype(h.dtype).reshape(h.shape), counts
 
@@ -510,7 +514,7 @@ def prefill(
         o = o.transpose(0, 2, 1, 3).reshape(B, S, cfg.n_heads * D)
         x = x + layers.mm(o, layer["wo"]).astype(x.dtype)
         h = layers.rms_norm(x, layer["mlp_norm"], cfg.norm_eps)
-        h, _ = _serving_mlp(layer, h, cfg)
+        h, _ = _serving_mlp(layer, h, cfg, mesh=mesh)
         x = x + h
         # stack KV for a single scatter outside the scan: [Hkv, B, S, D]
         return x, (k.transpose(1, 0, 2, 3), v.transpose(1, 0, 2, 3))
@@ -625,7 +629,7 @@ def prefill_chunk(
         o = o.transpose(0, 2, 1, 3).reshape(B, C, cfg.n_heads * D)
         x = x + layers.mm(o, layer["wo"]).astype(x.dtype)
         h = layers.rms_norm(x, layer["mlp_norm"], cfg.norm_eps)
-        h, _ = _serving_mlp(layer, h, cfg)
+        h, _ = _serving_mlp(layer, h, cfg, mesh=mesh)
         x = x + h
         return x, (k.transpose(1, 0, 2, 3), v.transpose(1, 0, 2, 3))
 
@@ -663,6 +667,7 @@ def paged_impl_plan(
     kv_dtype="bfloat16",
     mesh=None,
     warn: bool = True,
+    expert_dtype=None,
 ) -> dict:
     """Resolve the decode structure that will ACTUALLY run for these shapes
     on the current backend — the single source of truth shared by
@@ -690,9 +695,15 @@ def paged_impl_plan(
     the degree. Head counts not divisible by tp downgrade loudly to the
     auto-partitioned XLA paths (the only genuinely illegal sharding).
 
+    ``expert_scan`` names the form of a routed model's tile loop in a decode
+    step (None for a dense model): ``moe.expert_scan_form``'s choice for
+    experts of ``expert_dtype`` (unset: the model's own) on one device, the
+    XLA loop under tensor parallelism (``_serving_mlp``).
+
     Returns ``{"attention": "ragged"|"xla-gather",
     "ragged_variant": "flat"|"grouped"|None, "scatter": "pallas"|"xla",
-    "kv_dtype": str, "tp": int, "downgraded": [...]}``.
+    "kv_dtype": str, "tp": int, "downgraded": [...],
+    "expert_scan": "pallas"|"xla"|None}``.
     """
     from ..ops.kv_quant import resolve_kv_dtype
     # legality predicates live with the kernels (ops.paged_attention) so the
@@ -746,6 +757,11 @@ def paged_impl_plan(
                 f"scatter_impl=pallas -> xla (head_dim={cfg.head_dim} "
                 "fails D%128 tiling)"
             )
+    expert_scan = None
+    if cfg.n_experts > 0:
+        expert_scan = "xla" if tp > 1 else _moe.expert_scan_form(
+            1, cfg.dim, cfg.ffn_dim, expert_dtype or cfg.dtype
+        )
     if warn and downgraded:
         import warnings
 
@@ -758,7 +774,7 @@ def paged_impl_plan(
     return {
         "attention": attention, "ragged_variant": ragged_variant,
         "scatter": scatter, "kv_dtype": kvd_name, "tp": tp,
-        "downgraded": downgraded,
+        "downgraded": downgraded, "expert_scan": expert_scan,
     }
 
 
@@ -876,7 +892,7 @@ def decode_step(
         o = o.reshape(B, cfg.n_heads * D)
         x = x + layers.mm(o, layer["wo"]).astype(x.dtype)
         h = layers.rms_norm(x, layer["mlp_norm"], cfg.norm_eps)
-        h, counts = _serving_mlp(layer, h, cfg, active)
+        h, counts = _serving_mlp(layer, h, cfg, active, mesh)
         return x + h, (k_tok, v_tok, counts)
 
     x, (k_all, v_all, counts) = _moe.scan_layers(
@@ -918,6 +934,7 @@ def verify_step(
     page_tables: jax.Array,  # [B, pages_per_seq]
     active: jax.Array,  # [B] bool
     cfg: LlamaConfig,
+    mesh=None,  # jax Mesh with a "tensor" axis: a routed layer keeps XLA's tile loop
 ):
     """T tokens of teacher-forced decode against the paged cache — the
     target-model scoring half of speculative decoding (the reference enables
@@ -975,7 +992,7 @@ def verify_step(
         o = o.reshape(B, T, cfg.n_heads * D)
         x = x + layers.mm(o, layer["wo"]).astype(x.dtype)
         h = layers.rms_norm(x, layer["mlp_norm"], cfg.norm_eps)
-        h, _ = _serving_mlp(layer, h, cfg)
+        h, _ = _serving_mlp(layer, h, cfg, mesh=mesh)
         return x + h, (k_pg, v_pg)
 
     x, (k_pages, v_pages) = _moe.scan_layers(

@@ -32,6 +32,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from ..ops.expert_swiglu import expert_swiglu, expert_swiglu_shapes_ok
 from ..ops.scopes import EXPERT_DISPATCH, EXPERT_SCAN, ROUTER
 
 
@@ -275,19 +276,68 @@ def scan_layers(stack: dict, layer_fn, x, *per_layer):
     return jax.lax.scan(body, x, (sliced, jnp.arange(n), *per_layer))
 
 
-def expert_tile(n_tokens: int) -> int:
-    """Rows of one tile of ``moe_swiglu_sparse``, from the shape of the call:
-    128, or every token (rounded up to 16 rows) where that is less, so that
-    a decode step has one tile an expert. 128 rows are where an int8 expert
-    matrix's read and a tile's matmul take a v5e about as long, so the
-    weights an expert's next tile reads again stream under its matmuls, and
-    what a larger tile adds is padding, half a tile an expert. Measured on
-    the chip, one Mixtral layer (8 experts of 4096 x 14336 int8, 2 a token,
-    PR 28): 2048 tokens 12.5 / 13.4 / 15.1 / 18.1 ms at 128 / 256 / 512 /
-    1024 rows, 8192 tokens 39.6 / 40.2 / 43.7 / 53.7 ms at 256 / 512 / 1024 /
-    2048, 128 also ahead at 512 and 1024 tokens; DeepSeek-V2's 77 pairs an
-    expert a chunk sit in one such tile."""
-    return min(128, _round_up(n_tokens, 16))
+#: a decode-shaped call's tile holds this many times the mean pairs an expert
+TILE_SPREAD = 4
+
+
+def expert_tile(n_tokens: int, top_k: int, n_held: int) -> int:
+    """Rows of one tile of ``moe_swiglu_sparse``, from the shapes of the call
+    (its tokens, the experts each chooses, the experts held here).
+
+    A call of 128 tokens or more (a prefill bucket or chunk): 128 rows, where
+    an int8 expert matrix's read and a tile's matmul take a v5e about as
+    long, so the weights an expert's next tile reads again stream under its
+    matmuls, and what a larger tile adds is padding, half a tile an expert.
+    Measured on the chip, one Mixtral layer (8 experts of 4096 x 14336 int8,
+    2 a token, PR 28): 2048 tokens 12.5 / 13.4 / 15.1 / 18.1 ms at 128 / 256 /
+    512 / 1024 rows, 8192 tokens 39.6 / 40.2 / 43.7 / 53.7 ms at 256 / 512 /
+    1024 / 2048, 128 also ahead at 512 and 1024 tokens; DeepSeek-V2's 77 pairs
+    an expert a chunk sit in one such tile.
+
+    A decode-shaped call (fewer than 128 tokens) reads each reached expert's
+    matrices for a handful of rows, and its tile is what holds ``TILE_SPREAD``
+    times the mean pairs an expert, ``tokens x top_k / held``, in whole
+    16-row vregs of bf16 (the smallest the MXU's operand layout takes), at
+    most every token: 16 rows at LFM2's 64 x 4 / 64 and at Mixtral's
+    16 x 2 / 8, where the 64 rows of "every token in one tile" were 94%
+    padding. An expert with more pairs than a tile takes several tiles (with
+    F in one block its matrices are read once for them). Measured on the
+    chip, one LFM2 layer (62 tokens, 4 of 64 experts of 2048 x 1536 int8, the
+    most pairs on one expert 13, PR 40): the grouped matmul 840 / 854 / 878 us
+    at 16 / 32 / 64 rows, XLA's loop 1224 / 1313 at 16 / 64."""
+    if n_tokens >= 128:
+        return 128
+    mean = -(-n_tokens * top_k // n_held)
+    return min(_round_up(n_tokens, 16), _round_up(TILE_SPREAD * mean, 16))
+
+
+def expert_scan_form(n_tokens: int, d_model: int, d_ff: int, dtype) -> str:
+    """Which form of the tile loop ``moe_swiglu_sparse`` runs, from what can
+    be seen here and by no option: ``"pallas"`` (ops.expert_swiglu: one
+    grouped matmul whose pipeline fetches the next tile's expert under this
+    tile's products) for a decode-shaped call (fewer than 128 tokens) on a
+    TPU whose expert shapes the kernel takes (``expert_swiglu_shapes_ok``);
+    ``"xla"`` (the ``fori_loop`` of a trip a tile) for every other call: a
+    prefill bucket or chunk at 128-row tiles, the CPU, where the kernel would
+    run in the interpreter, and any shape the kernel refuses. The families'
+    ``paged_impl_plan`` names a decode step's form with this function
+    (``expert_scan``)."""
+    ok = (
+        jax.default_backend() == "tpu" and n_tokens < 128
+        and expert_swiglu_shapes_ok(d_model, d_ff, dtype)
+    )
+    return "pallas" if ok else "xla"
+
+
+def expert_dtype(params):
+    """The dtype of the experts' matrices in a tree of parameters (a
+    QuantizedWeight's is its ``q``'s), or None where the tree holds none:
+    what the engine hands a family's ``paged_impl_plan``."""
+    if not isinstance(params, dict):
+        return None
+    if EXPERT_LEAVES[0] in params:
+        return params[EXPERT_LEAVES[0]].dtype
+    return next((d for d in map(expert_dtype, params.values()) if d is not None), None)
 
 
 def moe_swiglu_routed(
@@ -301,6 +351,7 @@ def moe_swiglu_routed(
     layer: jax.Array | None = None,
     expert_offset: int = 0,
     token_mask: jax.Array | None = None,
+    scan: str | None = None,  # moe_swiglu_sparse's
     score: str = "softmax",  # or "sigmoid": each expert scored on its own
     **routing,  # route_group_limited's: n_group, topk_group, scale, renormalize, bias
 ) -> tuple[jax.Array, jax.Array]:
@@ -321,7 +372,7 @@ def moe_swiglu_routed(
         weights, ids = route_group_limited(scores, top_k, **routing)
     return moe_swiglu_sparse(
         w_gate, w_up, w_down, x, ids, weights,
-        expert_offset=expert_offset, token_mask=token_mask, layer=layer,
+        expert_offset=expert_offset, token_mask=token_mask, layer=layer, scan=scan,
     )
 
 
@@ -337,6 +388,7 @@ def moe_swiglu_sparse(
     token_mask: jax.Array | None = None,  # [T] bool — tokens that count
     tile: int | None = None,
     layer: jax.Array | None = None,  # weights are [L, E_held, ...]: this layer
+    scan: str | None = None,  # "pallas" / "xla"; unset: expert_scan_form's choice
 ) -> tuple[jax.Array, jax.Array]:
     """The held experts' part of a routed SwiGLU layer, computing only the
     (token, expert) pairs that land on them: exact, no capacity, no dropped
@@ -346,21 +398,27 @@ def moe_swiglu_sparse(
     expert_offset + E_held - 1`` of a router that is wider; pairs routed
     elsewhere add nothing (another chip's share would). The pairs are sorted
     by expert, each expert's run padded to whole tiles of ``tile`` rows, and
-    a loop whose trip count is the number of tiles *in use* runs one tile a
-    trip: gather the tile's token rows, the three matmuls against that one
-    expert's weights (indexed out of the stack, so an expert no pair reaches
-    is never read), write the tile's rows. The combine gathers each token's
-    ``k`` rows back (a pair not computed here points at a row of zeros) and
-    sums them under the weights in f32. Static shapes throughout: the row
-    buffer holds the worst case, all ``T * k`` pairs held plus a partial tile
-    an expert; the loop touches only the rows in use.
+    the tiles *in use* are computed one by one: a tile's token rows through
+    the three matmuls against that one expert's weights (indexed out of the
+    stack, so an expert no pair reaches is never read). The combine gathers
+    each token's ``k`` rows back (a pair not computed here adds zeros) and
+    sums them under the weights in f32. Static shapes throughout: the rows
+    hold the worst case, all ``T * k`` pairs held plus a partial tile an
+    expert; only the rows in use are touched.
+
+    Two forms of the tile loop, one arithmetic (``scan``; unset:
+    ``expert_scan_form``): ``"xla"``, a ``fori_loop`` whose trip gathers a
+    tile's rows, runs three matmul fusions and writes the tile into a float32
+    row buffer; ``"pallas"``, for a decode-shaped call on the chip, one
+    grouped matmul over the rows gathered once (ops.expert_swiglu), which
+    writes each live tile once and needs no buffer zeroed.
 
     With ``layer`` (a traced scalar) the weights keep a leading layer axis
     and a tile indexes ``[layer, expert]`` out of the whole stack: a layer
     scan that sliced the stack per layer instead would copy every expert of
     the layer, reached or not, before the loop ran (``scan_layers``).
 
-    ``tile`` defaults to ``expert_tile(T)``.
+    ``tile`` defaults to ``expert_tile(T, k, E_held)``.
 
     Returns (out [T, D] f32, counts [2] int32: the pairs of counted tokens
     that landed on held experts, and all their pairs).
@@ -369,8 +427,9 @@ def moe_swiglu_sparse(
 
     T, D = x.shape
     k = ids.shape[1]
-    E = w_gate.shape[0 if layer is None else 1]
-    TM = tile or expert_tile(T)
+    E, F = w_gate.shape[-3], w_gate.shape[-1]
+    TM = tile or expert_tile(T, k, E)
+    scan = scan or expert_scan_form(T, D, F, w_gate.dtype)
     M = T * k
     M_pad = _round_up(M + E * (TM - 1), TM)  # rows; row M_pad stays zero
     with jax.named_scope(EXPERT_DISPATCH):
@@ -406,6 +465,22 @@ def moe_swiglu_sparse(
             E - 1,
         ).astype(jnp.int32)
         x_rows = jnp.concatenate([x, jnp.zeros((1, D), x.dtype)], axis=0)
+
+    if scan == "pallas":
+        with jax.named_scope(EXPERT_DISPATCH):
+            rows = x_rows[row_token]  # [M_pad, D]: every tile's rows, gathered once
+        with jax.named_scope(EXPERT_SCAN):
+            whole = (lambda a: a) if layer is not None else (lambda a: a[None])
+            buf = expert_swiglu(
+                *(jax.tree.map(whole, w) for w in (w_gate, w_up, w_down)),
+                rows, tile_expert, n_tiles, 0 if layer is None else layer, tile=TM,
+            )
+        with jax.named_scope(EXPERT_DISPATCH):
+            # a tile past n_tiles was never written: no held pair points there
+            picked = jnp.where(
+                held[..., None], buf[jnp.minimum(row_of_pair, M_pad - 1)], 0.0
+            )
+            return jnp.einsum("tk,tkd->td", weights, picked), counts_out
 
     def one(w, e):
         """Expert ``e``'s matrix, sliced out of the stack where it is used."""

@@ -1017,7 +1017,7 @@ CATALOG: dict[str, dict] = {
         "type": "gauge",
         "labels": [
             "attention", "scatter", "kv_dtype", "tp", "variant",
-            "downgraded", "allocator", "state_step",
+            "downgraded", "allocator", "state_step", "expert_scan",
         ],
         "help": (
             "resolved decode implementation plan (info metric, value 1); "
@@ -1025,7 +1025,9 @@ CATALOG: dict[str, dict] = {
             "kernel formulation actually run, downgraded = requested Pallas "
             "impls that fell back to XLA, allocator = native|python page "
             "allocator, state_step = pallas|xla form of a recurrent model's "
-            "decode state step (- without per-slot state)"
+            "decode state step (- without per-slot state), expert_scan = "
+            "pallas|xla form of a routed model's expert tiles in a decode "
+            "step (- for a dense model)"
         ),
     },
     SPEC_PROPOSED_TOTAL: {

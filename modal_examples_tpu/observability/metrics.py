@@ -220,7 +220,8 @@ def set_decode_impl(plan: dict, *, registry: Registry | None = None) -> None:
     ragged variant (``paged_impl_plan(mesh=...)``), how many requested
     Pallas impls were downgraded, which page allocator loaded and which
     form steps a model's per-slot state (``state_step``; ``"-"`` for a model
-    without any) — so dashboards, benches and ``chip_smoke.py`` report the
+    without any) and runs a routed model's expert tiles in a decode step
+    (``expert_scan``; ``"-"`` for a dense model) — so dashboards, benches and ``chip_smoke.py`` report the
     plan actually run, not the requested one."""
     _reg(registry).gauge_set(
         C.DECODE_IMPL,
@@ -234,6 +235,7 @@ def set_decode_impl(plan: dict, *, registry: Registry | None = None) -> None:
             "downgraded": str(len(plan.get("downgraded") or ())),
             "allocator": str(plan.get("allocator") or "-"),
             "state_step": str(plan.get("state_step") or "-"),
+            "expert_scan": str(plan.get("expert_scan") or "-"),
         },
         help=C.CATALOG[C.DECODE_IMPL]["help"],
     )

@@ -33,6 +33,7 @@ PROBED_MODULES: dict[str, list[str]] = {
     "modal_examples_tpu.ops.quantized_matmul": ["int8_matmul"],
     "modal_examples_tpu.ops.sparse_attention": ["selected_attention"],
     "modal_examples_tpu.ops.ssm_step": ["ssm_step"],
+    "modal_examples_tpu.ops.expert_swiglu": ["expert_swiglu"],
 }
 
 #: every attention probe's bound against its reference: bf16 operands with
@@ -287,6 +288,50 @@ def probe_ssm_step(L=2, S=4, H=16, P=8, N=128, G=2) -> dict:
     return {k: round(v, 7) for k, v in errs.items()}
 
 
+def probe_expert_swiglu(L=2, E=4, D=128, F=256, tile=16, tiles=6, live=4) -> dict:
+    """The routed experts' grouped matmul (the last layer of int8 stacks, F in
+    two blocks, two tiles of one expert in a row, two trips past the live
+    tiles) against the same SwiGLU a tile in XLA: int8 -> bf16 into the
+    products, float32 sums, scales after; the F blocks' float32 sum in
+    another order."""
+    import types
+
+    import jax
+    import jax.numpy as jnp
+
+    from modal_examples_tpu.ops.expert_swiglu import expert_swiglu
+
+    ks = jax.random.split(jax.random.PRNGKey(0), 7)
+    q = lambda k, din, dout: jax.random.randint(k, (L, E, din, dout), -127, 128, jnp.int8)  # noqa: E731
+    scale = lambda k, din, dout: jax.random.uniform(  # noqa: E731
+        k, (L, E, 1, dout), jnp.float32, 0.5, 1.5) * din**-0.5 / 73.0
+    stacks = [(q(ks[0], D, F), scale(ks[1], D, F)), (q(ks[2], D, F), scale(ks[3], D, F)),
+              (q(ks[4], F, D), scale(ks[5], F, D))]
+    rows = jax.random.normal(ks[6], (tiles * tile, D), jnp.float32).astype(jnp.bfloat16)
+    tile_expert = jnp.asarray([3, 3, 0, 2, 1, 1], jnp.int32)[:tiles]
+
+    @jax.jit
+    def kernel(stacks, rows):
+        weights = [types.SimpleNamespace(q=q, scale=s) for q, s in stacks]
+        return expert_swiglu(
+            *weights, rows, tile_expert, jnp.int32(live), jnp.int32(L - 1), tile=tile, block_f=F // 2
+        )
+
+    @jax.jit
+    def reference(stacks, rows):
+        x = rows.reshape(tiles, tile, D)
+        (gq, gs), (uq, us), (dq, ds) = [(q[L - 1][tile_expert], s[L - 1][tile_expert]) for q, s in stacks]
+        mm = lambda h, w: jnp.einsum(  # noqa: E731
+            "trd,tdf->trf", h, w.astype(h.dtype), preferred_element_type=jnp.float32)
+        a, b = mm(x, gq) * gs, mm(x, uq) * us
+        return (mm((jax.nn.silu(a) * b).astype(x.dtype), dq) * ds).reshape(tiles * tile, D)
+
+    n = live * tile
+    err = _err(kernel(stacks, rows)[:n], reference(stacks, rows)[:n])
+    assert err < 1e-4, err
+    return {"max_err": round(err, 7)}
+
+
 #: probe name -> zero-argument callable, on the smallest legal shapes
 KERNEL_PROBES: dict[str, Callable[[], dict]] = {
     "flash_fwd": probe_flash_fwd,
@@ -320,6 +365,7 @@ KERNEL_PROBES: dict[str, Callable[[], dict]] = {
     "scatter_kv_int8": functools.partial(probe_scatter, 32, int8=True),
     "selected_attention": probe_selected_attention,
     "ssm_step": probe_ssm_step,
+    "expert_swiglu": probe_expert_swiglu,
 }
 
 

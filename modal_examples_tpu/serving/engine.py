@@ -43,6 +43,7 @@ import numpy as np
 
 from ..models import deepseek_v2, glm_dsa, granite_hybrid, lfm2, llama
 from ..models.layers import refuse as _refuse
+from ..models.moe import expert_dtype as _expert_dtype
 from ..observability import incident as _incident
 from ..observability import metrics as _obs
 from ..observability import profiler as _profiler
@@ -668,10 +669,12 @@ class LLMEngine:
         # round 5), and the kv dtype changes the flat-variant legality —
         # record it so benches/metrics report the real path instead of the
         # requested one (ADVICE r4)
+        expert_dtype = _expert_dtype(self.params)  # None: no routed layer
         self.impl_plan = {
             **self._model.paged_impl_plan(
                 cfg, page_size, self.paged_impl, self.scatter_impl,
                 kv_dtype=self.kv_dtype, mesh=mesh,
+                **({} if expert_dtype is None else {"expert_dtype": expert_dtype}),
             ),
             "allocator": self.cache.allocator_impl,
         }

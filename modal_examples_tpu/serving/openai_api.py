@@ -225,7 +225,8 @@ class _Handler(BaseHTTPRequestHandler):
                     f'"{eng.impl_plan.get("ragged_variant") or "-"}",'
                     f'downgraded="{len(eng.impl_plan["downgraded"])}",'
                     f'allocator="{eng.impl_plan["allocator"]}",state_step='
-                    f'"{eng.impl_plan.get("state_step") or "-"}"}} 1'
+                    f'"{eng.impl_plan.get("state_step") or "-"}",expert_scan='
+                    f'"{eng.impl_plan.get("expert_scan") or "-"}"}} 1'
                 )
             import jax
 

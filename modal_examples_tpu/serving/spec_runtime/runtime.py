@@ -266,7 +266,7 @@ def build_spec_round_fn(
         # the classic decode_step's scatter, chain position 0)
         chain = jnp.concatenate([tokens[:, None], draft_toks], axis=1)
         t_logits, tk, tv = llama.verify_step(
-            params, chain, positions, tk, tv, page_tables, active, cfg
+            params, chain, positions, tk, tv, page_tables, active, cfg, mesh=mesh
         )  # [B, γ+1, V]
         out, n_emit = accept_reject(
             t_logits, draft_toks, temps, (keys[gamma], keys[gamma + 1]),
@@ -304,7 +304,7 @@ def build_ngram_round_fn(cfg, *, gamma: int):
         k1, k2, k3 = jax.random.split(key, 3)
         chain = jnp.concatenate([tokens[:, None], proposals], axis=1)
         t_logits, tk, tv = llama.verify_step(
-            params, chain, positions, tk, tv, page_tables, active, cfg
+            params, chain, positions, tk, tv, page_tables, active, cfg, mesh=mesh
         )  # [B, γ+1, V]
         prop_valid = jnp.arange(gamma)[None, :] < n_prop[:, None]
         out, n_emit = accept_reject(

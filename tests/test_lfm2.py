@@ -645,13 +645,20 @@ def test_each_feature_the_model_lacks_is_refused_by_name(jax, L, model, feature)
 @pytest.mark.parametrize("backend", ["cpu", "tpu"])
 def test_the_plan_names_the_forms_it_picked_and_no_option_picks_another(jax, L, monkeypatch, backend):
     """``paged_impl_plan`` says what runs: the chunk loop over the pages (a
-    64-wide head is not the ragged kernel's, on either backend) and XLA's
-    window step; asking for another form is refused by name."""
+    64-wide head is not the ragged kernel's, on either backend), XLA's
+    window step, and for a decode step's expert tiles the grouped-matmul
+    kernel on a TPU (int8 or bf16 experts of 2048 x 1536; the int4 control's
+    keep the loop) and XLA's loop on the CPU; asking for another form is
+    refused by name."""
     monkeypatch.setattr(jax, "default_backend", lambda: backend)
     cfg = L.Lfm2Config.from_hf_config(PUBLISHED)
     plan = L.paged_impl_plan(cfg, 16)
     assert plan["attention"] == "xla-gather" and plan["scatter"] == "xla"
     assert plan["state_step"] == "xla" and plan["ragged_variant"] is None
+    kernel = "pallas" if backend == "tpu" else "xla"
+    assert plan["expert_scan"] == L.paged_impl_plan(cfg, 16, expert_dtype="int8")["expert_scan"] == kernel
+    assert L.paged_impl_plan(cfg, 16, expert_dtype="int4")["expert_scan"] == "xla"
+    assert L.paged_impl_plan(L.Lfm2Config.tiny(), 16)["expert_scan"] == "xla"  # no whole vregs
     for kw in (dict(impl="pallas"), dict(impl="ragged"), dict(scatter_impl="pallas")):
         with pytest.raises(NotImplementedError, match="a Pallas paged_impl or scatter_impl"):
             L.paged_impl_plan(cfg, 16, **kw)

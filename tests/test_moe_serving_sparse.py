@@ -277,16 +277,21 @@ def test_the_search_finds_the_fault_where_it_is(jax, llama, mixtral_shaped):
 
 
 @pytest.mark.parametrize(
-    "tokens,want",
+    "tokens,top_k,held,want",
     [
-        (1, 16), (16, 16), (100, 112),  # a decode step: every token in one tile an expert
-        (128, 128), (2048, 128), (4 * 2048, 128),  # the chip's measured best at every wider T
+        # a decode step whose pairs crowd the experts: every token in one tile an expert
+        (1, 2, 8, 16), (16, 2, 8, 16), (100, 2, 4, 112),
+        # ... and one whose pairs spread to a few an expert: four times their mean, in 16-row vregs
+        (64, 4, 64, 16), (62, 4, 64, 16), (16, 6, 40, 16), (16, 8, 16, 16), (4, 4, 64, 16),
+        (64, 2, 8, 64), (100, 2, 8, 112), (96, 4, 64, 32), (127, 8, 256, 16),
+        # the chip's measured best at every wider T, whatever the spread
+        (128, 2, 8, 128), (2048, 2, 8, 128), (4 * 2048, 2, 8, 128), (128, 4, 64, 128),
     ],
 )
-def test_tile_follows_the_calls_shape(tokens, want):
+def test_tile_follows_the_calls_shape(tokens, top_k, held, want):
     from modal_examples_tpu.models import moe
 
-    assert moe.expert_tile(tokens) == want
+    assert moe.expert_tile(tokens, top_k, held) == want
 
 
 @pytest.mark.parametrize("tile", [16, 32, 128])

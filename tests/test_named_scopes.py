@@ -63,6 +63,39 @@ def test_decode_block_program_is_named_and_scoped(engine):
     assert wanted <= found, wanted - found
 
 
+def test_the_expert_kernel_sits_under_expert_scan_and_the_layout_under_dispatch(engine, monkeypatch):
+    """The decode block of the routed model as a TPU lowers it (cross-platform
+    lowering: nothing compiles, nothing runs): the grouped-matmul kernel is
+    one Mosaic call a routed layer scan, under ``mtpu.expert_scan``, where a
+    device trace's ``expert_scan_roofline`` looks for it; the sort, the row
+    gather and the combine stay under ``mtpu.expert_dispatch``; the loop's
+    float32 row buffer and its update a trip are gone. The dense model's block
+    holds no kernel at these widths."""
+    import jax
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # read at trace time
+
+    def block(*args):  # a function of its own: not the CPU trace of the test above
+        return engine._decode_block_fn(*args)
+
+    text = (
+        jax.jit(block).trace(*_block_args(engine)).lower(lowering_platforms=("tpu",))
+        .as_text(debug_info=True)
+    )
+    locs = dict(re.findall(r'^(#loc\d+) = loc\("([^"]*)"', text, re.M))
+    kernels = [locs[ref] for ref in re.findall(r"@tpu_custom_call\(.*loc\((#loc\d+)\)$", text, re.M)]
+    if engine.cfg.n_experts == 0:  # and a 32-wide head is not the ragged kernel's
+        assert kernels == []
+        return
+    assert len(kernels) == 1 and kernels[0].endswith(f"{scopes.EXPERT_SCAN}/pallas_call"), kernels
+    dispatch = [name for name in locs.values() if scopes.EXPERT_DISPATCH in name]
+    assert any("sort" in name for name in dispatch) and any("gather" in name for name in dispatch)
+    assert any("dot_general" in name for name in dispatch)  # the combine
+    assert not any("dynamic_update_slice" in name for name in dispatch)  # the loop's write a trip
+    T, k, E = engine.max_slots, engine.cfg.top_k_experts, engine.cfg.n_experts
+    assert f"tensor<{T * k + E * 15 + 1}x{engine.cfg.dim}xf32>" not in text  # ... and its buffer
+
+
 def test_chunk_program_is_named_and_scoped(engine):
     import jax.numpy as jnp
 

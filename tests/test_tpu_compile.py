@@ -8,6 +8,8 @@ under pytest-xdist a file goes to one worker. The topology is described
 inside a fixture, never at import.
 """
 
+import re
+
 import pytest
 
 
@@ -235,10 +237,16 @@ def test_decode_block_reads_the_pages_through_the_ragged_kernel_on_a_v5e(
 
 def test_a_latent_cache_still_decodes_through_the_loop(decode_block):
     """DeepSeek-V2's plan for an unset ``paged_impl`` is what it was for
-    ``xla``: the chunked loop over the latent pages, no Pallas call in the
-    decode block (a 576-wide head is not the ragged kernel's)."""
-    text = decode_block("deepseek").as_text()
-    assert "tpu_custom_call" not in text
+    ``xla``: the chunked loop over the latent pages, no Pallas call for the
+    attention in the decode block (a 576-wide head is not the ragged
+    kernel's). The one kernel in it is the routed layers' grouped matmul
+    (PR 40), under ``mtpu.expert_scan``."""
+    import re
+
+    text = decode_block("deepseek").as_text(debug_info=True)
+    locs = dict(re.findall(r'^(#loc\d+) = loc\("([^"]*)"', text, re.M))
+    kernels = [locs[ref] for ref in re.findall(r"@tpu_custom_call\(.*loc\((#loc\d+)\)$", text, re.M)]
+    assert len(kernels) == 1 and kernels[0].endswith("mtpu.expert_scan/pallas_call"), kernels
     assert "stablehlo.while" in text
 
 
@@ -463,6 +471,15 @@ def _relaid_out(text, shape):
     return re.search(re.escape(shape) + r"\{[^}]*\} copy\(", text) is not None
 
 
+def _kernel_scopes(hlo_text):
+    """The ``op_name`` of every Mosaic call in a compiled program's text."""
+    import re
+
+    return re.findall(
+        r'custom_call_target="tpu_custom_call".*?metadata=\{op_name="([^"]*)"', hlo_text
+    )
+
+
 def test_glm_decode_block_gathers_the_selection_in_place_on_a_v5e(glm):
     """The 16-slot decode block over 3.56 GiB of pages in three leaves beside
     6.0 GiB of weights: every leaf aliased in and out, the latent leaf and the
@@ -483,6 +500,10 @@ def test_glm_decode_block_gathers_the_selection_in_place_on_a_v5e(glm):
     assert "[32,16,18432]" not in text and "[16,32,18432]" not in text  # scores over heads, whole
     for scope in ("mtpu.indexer", "mtpu.topk_select", "mtpu.attention", "mtpu.page_gather"):
         assert scope in text
+    # the routed layers' tiles go through the grouped-matmul kernel (PR 40): a chip's share,
+    # 16 of 256 experts held at an offset, 6144 x 2048 int8 in blocks of 512 columns
+    kernels = _kernel_scopes(text)
+    assert kernels and all(name.endswith("mtpu.expert_scan/pallas_call") for name in kernels)
 
 
 def test_glm_chunk_call_over_a_16k_prefix_compiles_for_a_v5e(glm):
@@ -509,6 +530,60 @@ def test_glm_chunk_call_over_a_16k_prefix_compiles_for_a_v5e(glm):
     assert "bf16[18,1,64,1024,256]" in text  # keys and values in blocks of 1024 positions
     for scope in ("mtpu.indexer", "mtpu.topk_select", "mtpu.latent_expand", "mtpu.attention"):
         assert scope in text
+
+
+# -- the routed experts' grouped matmul alone, at the cells' decode shapes (PR 40) ----------
+
+
+@pytest.mark.parametrize(
+    "tokens,top_k,layers,experts,D,F,dtype,block",
+    [
+        (64, 4, 16, 64, 2048, 1536, "int8", 1536),
+        (16, 2, 7, 8, 4096, 14336, "int8", 512),
+        (16, 8, 7, 16, 6144, 2048, "bfloat16", 256),
+    ],
+    ids=["lfm2", "mixtral", "glm-unquantised"],
+)
+def test_the_expert_kernel_compiles_at_a_decode_steps_shapes_on_a_v5e(
+    one_chip, monkeypatch, tokens, top_k, layers, experts, D, F, dtype, block
+):
+    """``moe_swiglu_sparse`` through the kernel on the whole stacks, with the
+    tile and the F block the shapes choose: an LFM2 expert's 9.4 MB of int8
+    in one block (19 MB double-buffered: the call raises the scoped VMEM
+    limit for it), a Mixtral expert's 176 MB in 28 blocks of 512 columns,
+    GLM-5.2's experts left in bf16 (no scales) in 8 blocks of 256. Mosaic
+    takes each, no layer's slice of a stack is a temporary, and what the
+    layer keeps beside the stacks is the tiles' rows."""
+    import jax
+    import jax.numpy as jnp
+
+    from modal_examples_tpu.models import moe
+    from modal_examples_tpu.models.quantize import QuantizedWeight
+    from modal_examples_tpu.ops.expert_swiglu import expert_swiglu_block
+
+    assert moe.expert_tile(tokens, top_k, experts) == 16
+    assert expert_swiglu_block(D, F, dtype) == block
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the kernel picks interpret= from it
+    S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+
+    def stack(din, dout):
+        if dtype != "int8":
+            return S((layers, experts, din, dout), jnp.dtype(dtype))
+        return QuantizedWeight(
+            q=S((layers, experts, din, dout), jnp.int8), scale=S((layers, experts, 1, dout), jnp.float32)
+        )
+
+    compiled = jax.jit(
+        lambda *a: moe.moe_swiglu_sparse(*a[:-1], layer=a[-1], scan="pallas")
+    ).lower(
+        stack(D, F), stack(D, F), stack(F, D), S((tokens, D), jnp.bfloat16),
+        S((tokens, top_k), jnp.int32), S((tokens, top_k), jnp.float32), S((), jnp.int32),
+    ).compile()
+    text = compiled.as_text()
+    assert _kernel_scopes(text) == ["jit(<lambda>)/mtpu.expert_scan/pallas_call"]
+    assert not re.search(rf"(s8|bf16)\[(1,)?{experts},{D},{F}\]", text)
+    rows = tokens * top_k + experts * 15
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * rows * D * (2 + 4) + 2**20
 
 
 # -- LFM2-24B-A2B's first 18 layers at their published widths (PR 39) -----------------------
@@ -580,9 +655,13 @@ def test_lfm2_decode_block_reads_an_expert_where_it_multiplies_on_a_v5e(lfm2):
     """The 64-slot decode block beside 9.5 GiB of int8 weights, every expert
     of 16 routed layers among them: pages and windows aliased in and out, no
     temporary a copy of a layer's experts (``[64, 2048, 1536]`` int8 is 201
-    MB a matrix: the tile loop indexes ``[layer, expert]`` out of the whole
-    stack) nor of a paged leaf, two K/V heads of 64 to a 128-wide page row.
-    Weights 9.5 + pages 0.75 + this fit the chip's 15.75 GiB."""
+    MB a matrix: the grouped-matmul kernel's index maps pick ``[layer,
+    expert]`` out of the whole stack) nor of a paged leaf, two K/V heads of 64
+    to a 128-wide page row. The routed layers' tiles are Mosaic calls under
+    ``mtpu.expert_scan`` (PR 40: 8 call sites, 4 attention layers and 4
+    scanned runs of 3 convolution layers), 76 tiles of 16 rows each, and the
+    loop's float32 row buffer ``[4289, 2048]`` is gone. Weights 9.5 + pages
+    0.75 + this fit the chip's 15.75 GiB."""
     import re
 
     compiled = lfm2["block"]()
@@ -601,7 +680,10 @@ def test_lfm2_decode_block_reads_an_expert_where_it_multiplies_on_a_v5e(lfm2):
     for scope in ("mtpu.conv_mix", "mtpu.expert_scan", "mtpu.expert_dispatch", "mtpu.router",
                   "mtpu.attention", "mtpu.dense_mlp"):
         assert scope in text
-    assert "tpu_custom_call" not in text  # the plan's forms: no kernel in this block
+    kernels = _kernel_scopes(text)  # the plan's forms: the routed layers' kernel, no other
+    assert len(kernels) == 8 and all(k.endswith("mtpu.expert_scan/pallas_call") for k in kernels)
+    assert "bf16[1216,2048]" in text and "f32[1216,2048]" in text  # the tiles' rows in and out
+    assert "f32[4289,2048]" not in text and "f32[1217,2048]" not in text  # no zeroed row buffer
 
 
 def test_lfm2_widest_prefill_call_compiles_at_head_width_64_on_a_v5e(lfm2):
