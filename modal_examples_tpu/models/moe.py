@@ -389,8 +389,9 @@ def moe_swiglu_sparse(
     tile: int | None = None,
     layer: jax.Array | None = None,  # weights are [L, E_held, ...]: this layer
     scan: str | None = None,  # "pallas" / "xla"; unset: expert_scan_form's choice
+    activation: str = "silu",  # the gate's: "silu" (SwiGLU) or "relu" (ReGLU)
 ) -> tuple[jax.Array, jax.Array]:
-    """The held experts' part of a routed SwiGLU layer, computing only the
+    """The held experts' part of a routed SwiGLU (or ReGLU) layer, computing only the
     (token, expert) pairs that land on them: exact, no capacity, no dropped
     pair, work proportional to the pairs up to a tile's padding.
 
@@ -474,6 +475,7 @@ def moe_swiglu_sparse(
             buf = expert_swiglu(
                 *(jax.tree.map(whole, w) for w in (w_gate, w_up, w_down)),
                 rows, tile_expert, n_tiles, 0 if layer is None else layer, tile=TM,
+                activation=activation,
             )
         with jax.named_scope(EXPERT_DISPATCH):
             # a tile past n_tiles was never written: no held pair points there
@@ -493,6 +495,8 @@ def moe_swiglu_sparse(
 
         return jax.tree.map(pick, w)
 
+    act = {"silu": jax.nn.silu, "relu": jax.nn.relu}[activation]
+
     def body(i, buf):
         with jax.named_scope(EXPERT_DISPATCH):
             rows = jax.lax.dynamic_slice_in_dim(row_token, i * TM, TM)
@@ -501,7 +505,7 @@ def moe_swiglu_sparse(
             e = tile_expert[i]
             a = mm(xt, one(w_gate, e))
             b = mm(xt, one(w_up, e))
-            y = mm((jax.nn.silu(a) * b).astype(x.dtype), one(w_down, e))
+            y = mm((act(a) * b).astype(x.dtype), one(w_down, e))
         with jax.named_scope(EXPERT_DISPATCH):
             return jax.lax.dynamic_update_slice_in_dim(buf, y, i * TM, 0)
 

@@ -104,6 +104,11 @@ LFM2_TARGETS = (
     "moe_gate", "moe_up", "moe_down",
 )
 
+#: ... and in a SmallThinker tree (models/smallthinker.py): the attention
+#: projections and the routed ReGLUs; the router, the norms and the embedding
+#: stay (``lm_head`` goes the way ``quantize_llama`` takes every tree's)
+SMALLTHINKER_TARGETS = ("wq", "wk", "wv", "wo", "moe_gate", "moe_up", "moe_down")
+
 
 def bits_of(quantization: str) -> int:
     if quantization not in ("int8", "int4"):

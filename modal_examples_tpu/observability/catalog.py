@@ -87,8 +87,24 @@ PREFILL_POSITIONS_TOTAL = "mtpu_prefill_positions_total"
 #: block; kind = read (trips of the chunked loop x positions a trip x
 #: slots: what the device gathers and scores) | live (the live contexts,
 #: summed) | table (slots x pages_per_slot x page_size: what a full-table
-#: gather would read). read / table is how far the loop runs
+#: gather would read). read / table is how far the loop runs. A model with a
+#: sliding-window page group counts each group's layers on their own, under a
+#: second label: layers = global (the layers that keep whole contexts) |
+#: window (read: what the loop reads of the rings; live: min(context, window)
+#: a sequence; table: slots x ring x page_size); other models set no such label
 DECODE_KV_POSITIONS_TOTAL = "mtpu_decode_kv_positions_total"
+#: gauges: pages of the sliding-window page group (serving/kv_cache.py
+#: WindowGroup) that sequences hold now, the most they held since the
+#: process started, and the group's budget (its trash page left out). Only
+#: a model that declares ``window_group`` reports them; mtpu_kv_pages_* go
+#: on counting the group that keeps whole contexts
+KV_WINDOW_PAGES_USED = "mtpu_kv_window_pages_used"
+KV_WINDOW_PAGES_PEAK = "mtpu_kv_window_pages_peak"
+KV_WINDOW_PAGES_TOTAL = "mtpu_kv_window_pages_total"
+#: counter: pages of the window group written over by a later page of the
+#: same sequence (the ring's turns), counted at each prefill and decode-block
+#: dispatch from the positions the host hands the program
+KV_WINDOW_PAGES_RECYCLED_TOTAL = "mtpu_kv_window_pages_recycled_total"
 #: counter {where}: (token, expert) pairs the decode blocks' routed layers
 #: chose, over their live slots, steps and layers; where = held (the expert
 #: is one this chip holds: its part of the sum is computed) | elsewhere
@@ -623,10 +639,27 @@ CATALOG: dict[str, dict] = {
     },
     DECODE_KV_POSITIONS_TOTAL: {
         "type": "counter",
-        "labels": ["kind"],
+        "labels": ["kind", "layers"],
         "help": "KV positions per decode step at block dispatch (kind="
                 "read: chunk trips x chunk positions x slots | live: live "
-                "contexts | table: slots x table positions)",
+                "contexts | table: slots x table positions; layers=global | "
+                "window where a model keeps a sliding-window page group)",
+    },
+    KV_WINDOW_PAGES_USED: {
+        "type": "gauge", "labels": [],
+        "help": "pages currently allocated out of the sliding-window page group",
+    },
+    KV_WINDOW_PAGES_PEAK: {
+        "type": "gauge", "labels": [],
+        "help": "most pages of the sliding-window page group held at once",
+    },
+    KV_WINDOW_PAGES_TOTAL: {
+        "type": "gauge", "labels": [],
+        "help": "usable pages of the sliding-window page group (its budget)",
+    },
+    KV_WINDOW_PAGES_RECYCLED_TOTAL: {
+        "type": "counter", "labels": [],
+        "help": "window-group pages written over by a later page of their sequence",
     },
     ROUTED_PAIRS_TOTAL: {
         "type": "counter",

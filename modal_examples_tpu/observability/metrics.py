@@ -123,15 +123,38 @@ def record_prefill_positions(
 
 
 def record_decode_kv_positions(
-    read: int, live: int, table: int, *, registry: Registry | None = None
+    read: int, live: int, table: int, *, layers: str | None = None,
+    registry: Registry | None = None,
 ) -> None:
     """One decode-block dispatch: the KV positions its steps' attention
-    reads, those that are live, and what the whole table holds."""
+    reads, those that are live, and what the whole table holds. ``layers``
+    (global | window): the page group counted, for a model with two."""
     reg = _reg(registry)
     for kind, n in (("read", read), ("live", live), ("table", table)):
+        labels = {"kind": kind} if layers is None else {"kind": kind, "layers": layers}
         reg.counter_inc(
-            C.DECODE_KV_POSITIONS_TOTAL, float(n), labels={"kind": kind},
+            C.DECODE_KV_POSITIONS_TOTAL, float(n), labels=labels,
             help=C.CATALOG[C.DECODE_KV_POSITIONS_TOTAL]["help"],
+        )
+
+
+def set_kv_window_pages(
+    *, used: int, peak: int, total_usable: int, registry: Registry | None = None
+) -> None:
+    """The sliding-window page group's occupancy, at each claim and release."""
+    reg = _reg(registry)
+    for name, n in (
+        (C.KV_WINDOW_PAGES_USED, used), (C.KV_WINDOW_PAGES_PEAK, peak),
+        (C.KV_WINDOW_PAGES_TOTAL, total_usable),
+    ):
+        reg.gauge_set(name, float(n), help=C.CATALOG[name]["help"])
+
+
+def record_kv_window_pages_recycled(n: int, *, registry: Registry | None = None) -> None:
+    if n:
+        _reg(registry).counter_inc(
+            C.KV_WINDOW_PAGES_RECYCLED_TOTAL, float(n),
+            help=C.CATALOG[C.KV_WINDOW_PAGES_RECYCLED_TOTAL]["help"],
         )
 
 

@@ -27,9 +27,12 @@ from .paged_attention import (
     paged_latent_decode_attention_chunked,
     paged_decode_attention_inflight,
     paged_decode_attention_ragged,
+    paged_window_decode_attention_chunked,
     ragged_kernel_sizes,
     ragged_pages_read,
     scatter_kv_pages,
+    window_decode_view,
+    window_ring_pages,
 )
 from .quantized_matmul import dequantize_int8, quantize_int8, quantized_matmul
 from .scan_loop import masked_scan
@@ -62,6 +65,7 @@ __all__ = [
     "paged_latent_decode_attention_chunked",
     "paged_decode_attention_inflight",
     "paged_decode_attention_ragged",
+    "paged_window_decode_attention_chunked",
     "ragged_kernel_sizes",
     "ragged_pages_read",
     "is_quantized",
@@ -84,4 +88,6 @@ __all__ = [
     "ring_attention_sharded",
     "ulysses_attention",
     "ulysses_attention_sharded",
+    "window_decode_view",
+    "window_ring_pages",
 ]

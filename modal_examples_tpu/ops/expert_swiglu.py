@@ -71,7 +71,15 @@ def expert_swiglu_block(d_model: int, d_ff: int, dtype, block_bytes: int = BLOCK
     return max((f for f in blocks if f * one <= block_bytes), default=blocks[0])
 
 
-def _expert_swiglu_kernel(*refs, n_f: int, quantized: bool):
+#: the gate's activation, by the name a caller gives (``activation=``):
+#: SwiGLU's ``silu`` (written out: ``a * sigmoid(a)``), ReGLU's ``relu``
+ACTIVATIONS = {
+    "silu": lambda a: a * jax.nn.sigmoid(a),
+    "relu": lambda a: jnp.maximum(a, 0.0),
+}
+
+
+def _expert_swiglu_kernel(*refs, n_f: int, quantized: bool, activation: str = "silu"):
     # scalar prefetch: layer (read by the index maps), tile_expert (likewise), n_tiles
     _, _, n_tiles = refs[:3]
     if quantized:
@@ -87,7 +95,7 @@ def _expert_swiglu_kernel(*refs, n_f: int, quantized: bool):
         a, b = dot(x, gate[...].astype(x.dtype)), dot(x, up[...].astype(x.dtype))
         if quantized:
             a, b = a * gate_s[...], b * up_s[...]
-        y = dot((a * jax.nn.sigmoid(a) * b).astype(x.dtype), down[...].astype(x.dtype))
+        y = dot((ACTIVATIONS[activation](a) * b).astype(x.dtype), down[...].astype(x.dtype))
         if n_f == 1:
             o_ref[...] = y * down_s[...] if quantized else y
             return
@@ -118,9 +126,12 @@ def expert_swiglu(
     tile: int,
     block_f: int | None = None,
     interpret: bool | None = None,
+    activation: str = "silu",  # the gate's: a key of ACTIVATIONS (static)
 ) -> jax.Array:
     """``[tiles * tile, D]`` float32: each live tile's rows through its
-    expert's SwiGLU. Rows of tiles past ``n_tiles`` are not written.
+    expert's gated FFN, ``act(x W_gate) * (x W_up)`` through ``W_down``
+    (SwiGLU unless ``activation`` says ReGLU). Rows of tiles past ``n_tiles``
+    are not written.
 
     ``block_f`` (columns of F a grid step) is ``expert_swiglu_block``'s
     unless given; ``interpret`` is taken from the backend at trace time."""
@@ -168,7 +179,9 @@ def expert_swiglu(
     wbytes = 3 * D * bf * gate_q.dtype.itemsize
     xbytes = rows.dtype.itemsize
     return pl.pallas_call(
-        functools.partial(_expert_swiglu_kernel, n_f=n_f, quantized=quantized),
+        functools.partial(
+            _expert_swiglu_kernel, n_f=n_f, quantized=quantized, activation=activation
+        ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(tiles, n_f),

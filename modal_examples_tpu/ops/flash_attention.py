@@ -120,6 +120,16 @@ def choose_blocks(
     return max(fits, key=lambda p: (p[0] * p[1], min(p), p[1]))
 
 
+def _window_first_block(q_start, window: int, block_k: int):
+    """The first key block a query tile that starts at ``q_start`` reaches
+    under a window of ``window`` positions (a query sees itself and the
+    ``window - 1`` before it): whole-number arithmetic on a Python int (the
+    grid's extent) or a traced scalar (the kernel, the index maps)."""
+    first = q_start - (window - 1)
+    first = max(first, 0) if isinstance(first, int) else jnp.maximum(first, 0)
+    return first // block_k
+
+
 def _fwd_kernel(
     q_ref,  # (1, block_q, D)
     k_ref,  # (1, block_k, D)
@@ -136,7 +146,12 @@ def _fwd_kernel(
     sm_scale: float,
     causal: bool,
     q_offset: int = 0,
+    window: int | None = None,
+    # (1,) int32 in SMEM: keys below this index are no one's (a gathered
+    # prefix's rows from before the sequence began)
+    k_first_ref=None,
 ):
+    has_k_first = k_first_ref is not None
     block_q, block_k = q_ref.shape[1], k_ref.shape[1]
     qi = pl.program_id(1)
     ki = pl.program_id(2)
@@ -150,7 +165,10 @@ def _fwd_kernel(
     # q_offset shifts query GLOBAL positions (chunked prefill: this q chunk
     # starts at q_offset within the full sequence the K/V cover).
     q_start = qi * block_q + q_offset
-    k_start = ki * block_k
+    bounded = window is not None or has_k_first  # then always causal
+    # under a window the k axis counts from the first block the tile reaches
+    first_k = _window_first_block(q_start, window, block_k) if window is not None else 0
+    k_start = (ki + first_k) * block_k if window is not None else ki * block_k
 
     def step(masked: bool):
         # operands as stored (bf16 straight into the MXU), sums in f32
@@ -164,7 +182,13 @@ def _fwd_kernel(
             diff = jax.lax.broadcasted_iota(
                 jnp.int32, s.shape, 0
             ) - jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            s = jnp.where(diff >= k_start - q_start, s, _MASK)
+            keep = diff >= k_start - q_start
+            if window is not None:  # row - col < window, in global positions
+                keep = jnp.logical_and(keep, diff < window + k_start - q_start)
+            if has_k_first:
+                col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+                keep = jnp.logical_and(keep, col >= k_first_ref[0] - k_start)
+            s = jnp.where(keep, s, _MASK)
         m_prev = m_scr[:, :1]  # (block_q, 1)
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
@@ -179,7 +203,26 @@ def _fwd_kernel(
         m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
         l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
 
-    if causal:
+    if bounded:
+        # as below, and the k axis starts late: trip ki is key block
+        # first_k + ki, the trips past the diagonal's block are skipped. A
+        # tile pays for the mask where the diagonal, the window's edge (its
+        # first key is out of the last row's window) or k_first crosses it.
+        # A row whose first tiles are all masked carries m = _MASK and sums
+        # that the first real score's alpha = 0 wipes
+        last_k = (q_start + block_q - 1) // block_k - first_k
+        crossed = k_start + block_k - 1 > q_start
+        if window is not None:
+            crossed = jnp.logical_or(crossed, k_start <= q_start + block_q - 1 - window)
+        if has_k_first:
+            crossed = jnp.logical_or(crossed, k_start < k_first_ref[0])
+        pl.when(jnp.logical_and(ki <= last_k, jnp.logical_not(crossed)))(
+            functools.partial(step, False)
+        )
+        pl.when(jnp.logical_and(ki <= last_k, crossed))(
+            functools.partial(step, True)
+        )
+    elif causal:
         # the last key block this query tile sees; the ones after it are
         # neither fetched (the index map stays on last_k) nor computed.
         # Only a tile the diagonal crosses pays for the mask.
@@ -204,11 +247,20 @@ def _fwd_kernel(
         )
 
 
+def _fwd_kernel_k_first(k_first_ref, *refs, **static):
+    """``_fwd_kernel`` for a call whose first input is ``k_first``."""
+    _fwd_kernel(*refs, k_first_ref=k_first_ref, **static)
+
+
 def _flash_forward(
     q, k, v, *, causal: bool, sm_scale: float, interpret: bool,
     block_q: int | None = None, block_k: int | None = None, q_offset: int = 0,
+    window: int | None = None, k_first=None,
 ):
-    """``block_q`` / ``block_k`` None: chosen from the shapes."""
+    """``block_q`` / ``block_k`` None: chosen from the shapes. ``window``
+    (static) and ``k_first`` (an int32 scalar, traced or not) are
+    ``flash_attention_chunked``'s; a call with neither builds what it always
+    built."""
     B, Hq, S, D = q.shape  # S = query length
     Hkv, Skv = k.shape[1], k.shape[2]
     Dv = v.shape[-1]  # values may be narrower than q/k (latent attention)
@@ -236,21 +288,50 @@ def _flash_forward(
     # and a query tile's k index stops at its own last block: a block index
     # that repeats is not fetched again
     kv_len = min(Skv, q_offset + S) if causal else Skv
-    grid = (B * Hkv * group, S // block_q, pl.cdiv(kv_len, block_k))
+    bounded = window is not None or k_first is not None
+    if bounded and not causal:
+        raise ValueError("a window or k_first needs a causal call")
+
+    def k_blocks(qi: int) -> tuple[int, int]:
+        """(first, last) key block of query tile ``qi``, as the kernel has them."""
+        q_start = qi * block_q + q_offset
+        first = _window_first_block(q_start, window, block_k) if window is not None else 0
+        return first, (q_start + block_q - 1) // block_k
+
+    if bounded:
+        # the k axis holds the widest tile's blocks, first to diagonal
+        n_k = max(last - first + 1 for first, last in map(k_blocks, range(S // block_q)))
+    else:
+        n_k = pl.cdiv(kv_len, block_k)
+    grid = (B * Hkv * group, S // block_q, n_k)
 
     def kv_index(bh, qi, ki):
-        if causal:
+        if bounded:
+            q_start = qi * block_q + q_offset
+            if window is not None:
+                ki = ki + _window_first_block(q_start, window, block_k)
+            ki = jnp.minimum(ki, (q_start + block_q - 1) // block_k)
+        elif causal:
             ki = jnp.minimum(ki, (qi * block_q + q_offset + block_q - 1) // block_k)
         return (bh // group, ki, 0)
 
     pairs = S * q_offset + S * (S + 1) // 2 if causal else S * Skv
-    kernel = functools.partial(
-        _fwd_kernel, sm_scale=sm_scale, causal=causal, q_offset=q_offset
-    )
+    if bounded:
+        if window is not None:
+            pairs = sum(min(t + 1, window) for t in range(q_offset, q_offset + S))
+        kernel = functools.partial(
+            _fwd_kernel if k_first is None else _fwd_kernel_k_first,
+            sm_scale=sm_scale, causal=True, q_offset=q_offset, window=window,
+        )
+    else:
+        kernel = functools.partial(
+            _fwd_kernel, sm_scale=sm_scale, causal=causal, q_offset=q_offset
+        )
+    scalars = () if k_first is None else (jnp.reshape(k_first, (1,)).astype(jnp.int32),)
     o, lse = pl.pallas_call(
         kernel,
         grid=grid,
-        in_specs=[
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)] * len(scalars) + [
             pl.BlockSpec(
                 (1, block_q, D), lambda bh, qi, ki: (bh, qi, 0),
                 memory_space=pltpu.VMEM,
@@ -289,7 +370,7 @@ def _flash_forward(
             transcendentals=B * Hq * pairs,
         ),
         interpret=interpret,
-    )(qf, kf, vf)
+    )(*scalars, qf, kf, vf)
     return o.reshape(B, Hq, S, Dv), lse[:, :, 0].reshape(B, Hq, S)
 
 
@@ -622,6 +703,8 @@ def flash_attention_chunked(
     q_offset: int,
     causal: bool = True,
     sm_scale: float | None = None,
+    window: int | None = None,
+    k_first=None,
 ) -> jax.Array:
     """Rectangular attention for chunked prefill: one query chunk against a
     longer K/V prefix (the engine processes long prompts chunk by chunk with
@@ -633,12 +716,23 @@ def flash_attention_chunked(
     kernel's time: past ``_LONG_KEY_BLOCK`` keys the call pads K/V with zero
     rows to the next multiple of it. They lie after every query's position,
     so the causal mask covers them and no step is taken for a block of them
-    alone (0.36 -> 0.21 ms a call at 128 rows over 2048, PERF.md section 6)."""
+    alone (0.36 -> 0.21 ms a call at 128 rows over 2048, PERF.md section 6).
+
+    ``window`` (static; sliding-window attention): a query sees itself and
+    the ``window - 1`` positions before it, and the k grid of a query tile
+    starts at the first block any of its rows reaches as well as stopping at
+    the diagonal. ``k_first`` (an int32 scalar, traced or not): keys below
+    that index are masked for every query, for a caller whose prefix rows
+    were gathered at a static length longer than the sequence (their tiles
+    are computed and masked, not skipped: the grid is static). A call with
+    neither lowers to what it did before they existed."""
     pad = padded_kv_len(k.shape[2]) - k.shape[2] if causal else 0
     if pad:
         k, v = (jnp.pad(x, ((0, 0), (0, 0), (0, pad), (0, 0))) for x in (k, v))
     o, _ = _flash_forward(
         q, k, v, causal=causal, sm_scale=_resolve_scale(q, sm_scale),
         interpret=_use_interpret(), q_offset=q_offset,
+        **({} if window is None else {"window": window}),
+        **({} if k_first is None else {"k_first": k_first}),
     )
     return o

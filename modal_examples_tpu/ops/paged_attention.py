@@ -51,7 +51,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .kv_quant import QuantizedKV, is_quantized, kv_gather, quantize_kv
-from .scopes import ATTENTION
+from .scopes import ATTENTION, WINDOW_ATTENTION
 
 
 @jax.named_scope(ATTENTION)
@@ -151,8 +151,7 @@ def decode_chunk_trips(longest, page_size: int, pages_per_seq: int):
     return xp.minimum((longest + span - 1) // span, -(-pages_per_seq // w))
 
 
-@jax.named_scope(ATTENTION)
-def paged_decode_attention_chunked(
+def _decode_attention_chunked(
     q: jax.Array,  # [B, Hq, D]
     k_pages,  # [L, n_pages, page_size, Hkv, D] — the cache, in place
     v_pages,
@@ -163,6 +162,7 @@ def paged_decode_attention_chunked(
     v_new: jax.Array,
     *,
     sm_scale: float | None = None,
+    starts: jax.Array | None = None,  # [B] int32 — first position a slot sees
 ) -> jax.Array:  # [B, Hq, D]
     """``paged_decode_attention_inflight`` over the live context only.
 
@@ -183,6 +183,14 @@ def paged_decode_attention_chunked(
     what a slot gets does not depend on how far a longer neighbour makes the
     loop run. A ``while`` of gathers and einsums, so still auto-partitionable
     over the KV-head axis under a sharded jit.
+
+    ``starts`` (``paged_window_decode_attention_chunked``'s): table
+    positions below a slot's start are masked like those past its prefix,
+    and the trips' gathers are plain indexing under the caller's scope
+    instead of ``kv_gather``'s ``mtpu.page_gather``: a window layer's whole
+    cost, fetching its ring included, reads under ``mtpu.window_attention``
+    (its roofline share holds the ring's bytes against that time). Unset,
+    the program is what it was.
     """
     B, Hq, D = q.shape
     _, _, page_size, Hkv, _ = k_pages.shape
@@ -207,14 +215,18 @@ def paged_decode_attention_chunked(
     def chunk(c, carry):
         m, l, acc = carry  # [B, Hkv, G], [B, Hkv, G], [B, Hkv, G, D] f32
         cols = jax.lax.dynamic_slice_in_dim(page_tables, c * W, W, axis=1)
-        ks = kv_gather(k_pages, cols, layer=layer, dtype=q.dtype)
-        vs = kv_gather(v_pages, cols, layer=layer, dtype=q.dtype)
+        if starts is None:
+            ks = kv_gather(k_pages, cols, layer=layer, dtype=q.dtype)
+            vs = kv_gather(v_pages, cols, layer=layer, dtype=q.dtype)
+        else:
+            ks, vs = k_pages[layer, cols], v_pages[layer, cols]
         s = jnp.einsum(
             "bhgd,bpthd->bhgpt", qg, ks, preferred_element_type=jnp.float32
         ) * sm_scale  # [B, Hkv, G, W, ps]
-        valid = (c * W * page_size + in_chunk)[None] < prefix_lens[
-            :, None, None
-        ]  # [B, W, ps]
+        pos = (c * W * page_size + in_chunk)[None]
+        valid = pos < prefix_lens[:, None, None]  # [B, W, ps]
+        if starts is not None:
+            valid = valid & (pos >= starts[:, None, None])
         s = jnp.where(valid[:, None, None], s, -jnp.inf)
         m_new = jnp.maximum(m, jnp.max(s, axis=(-2, -1)))
         p = jnp.exp(s - m_new[..., None, None])
@@ -238,6 +250,49 @@ def paged_decode_attention_chunked(
     )
     return (acc / l[..., None]).reshape(B, Hq, D).astype(q.dtype)
 
+
+@jax.named_scope(ATTENTION)
+def paged_decode_attention_chunked(
+    q, k_pages, v_pages, layer, page_tables, prefix_lens, k_new, v_new, *,
+    sm_scale: float | None = None,
+) -> jax.Array:
+    return _decode_attention_chunked(
+        q, k_pages, v_pages, layer, page_tables, prefix_lens, k_new, v_new,
+        sm_scale=sm_scale,
+    )
+
+
+paged_decode_attention_chunked.__doc__ = _decode_attention_chunked.__doc__
+
+
+@jax.named_scope(WINDOW_ATTENTION)
+def paged_window_decode_attention_chunked(
+    q: jax.Array,  # [B, Hq, D]
+    k_pages,  # [Lw, n_window_pages, page_size, Hkv, D]: the window group's pages
+    v_pages,
+    layer: jax.Array,  # scalar int32: the layer's row in the group
+    window_tables: jax.Array,  # [B, ring] int32: each slot's ring of pages
+    positions: jax.Array,  # [B] int32: the token's position (0 for a dead slot)
+    k_new: jax.Array,  # [B, Hkv, D]
+    v_new: jax.Array,
+    *,
+    window: int,
+    sm_scale: float | None = None,
+) -> jax.Array:  # [B, Hq, D]
+    """The chunked decode loop over a sliding-window layer's ring of pages:
+    the token at ``positions[b]`` attends to itself and the ``window - 1``
+    positions before it. The table is walked from the oldest page the
+    window reaches (``window_decode_view``), so the trips cover a window and
+    its page of slack whatever the context."""
+    if is_quantized(k_pages):
+        raise NotImplementedError("a window group's pages in int8")
+    tables, prefix_lens, starts = window_decode_view(
+        window_tables, positions, window, k_pages.shape[2]
+    )
+    return _decode_attention_chunked(
+        q, k_pages, v_pages, layer, tables, prefix_lens, k_new, v_new,
+        sm_scale=sm_scale, starts=starts,
+    )
 
 
 @jax.named_scope(ATTENTION)
@@ -323,6 +378,38 @@ def paged_latent_decode_attention_chunked(
         0, trips, chunk, (s_new, jnp.ones_like(s_new), acc0)
     )
     return acc / l[..., None]
+
+
+def window_ring_pages(window: int, page_size: int) -> int:
+    """Pages a sequence holds in a sliding-window layer's page group: the
+    pages ``window`` positions span wherever they start (a query at ``t``
+    sees ``t - window + 1 .. t``), which is the window's own pages and one
+    page of slack. The cache manager's claim, the programs' ring arithmetic
+    and the engine's counts all read it here."""
+    return -(-window // page_size) + 1
+
+
+def window_decode_view(window_tables, positions, window: int, page_size: int):
+    """A window layer's page table as the decode loop walks it. A sequence
+    uses its ``ring`` window pages as a ring: the page of position ``p`` is
+    ``window_tables[b, (p // page_size) % ring]``, so a page is written over
+    once the window has left it. For a step whose token is at
+    ``positions[b]`` (that many positions cached): the table's columns
+    rolled so that column 0 is the oldest page the window can reach,
+    ``first`` = that page's first position, and on that footing the prefix
+    length and the first position inside the window. Returns (tables
+    [B, ring], prefix_lens [B], starts [B]); whole-number arithmetic, on
+    traced arrays in the op or numpy ones where the engine counts."""
+    xp = jnp if isinstance(positions, jax.Array) else np
+    ring = window_tables.shape[1]
+    first_page = xp.maximum(positions // page_size - (ring - 1), 0)
+    cols = (first_page[:, None] + xp.arange(ring)[None, :]) % ring
+    first = first_page * page_size
+    starts = xp.maximum(positions - (window - 1) - first, 0)
+    return (
+        xp.take_along_axis(window_tables, cols, axis=1),
+        (positions - first).astype(xp.int32), starts.astype(xp.int32),
+    )
 
 
 def ragged_shapes_ok(head_dim: int, page_size: int) -> bool:

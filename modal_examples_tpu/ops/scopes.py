@@ -27,10 +27,13 @@ SSM_STEP = "mtpu.ssm_step"  # decode: convolution shift, one state update, outpu
 INDEXER = "mtpu.indexer"  # sparse attention: index projections, key gather, index scores
 TOPK_SELECT = "mtpu.topk_select"  # ... and the exact top-k of the scores
 CONV_MIX = "mtpu.conv_mix"  # a gated short convolution: projections, gates, taps, window
+#: a sliding-window layer's attention, prefill and decode (a layer that sees
+#: its whole context stays under ``mtpu.attention``)
+WINDOW_ATTENTION = "mtpu.window_attention"
 
 ALL = (
     PAGE_GATHER, ATTENTION, DENSE_MLP, ROUTER, EXPERT_SCAN, KV_SCATTER,
     SAMPLING, LATENT_EXPAND, EXPERT_DISPATCH, SSM_PROJ, SSM_SCAN, SSM_STEP,
-    INDEXER, TOPK_SELECT, CONV_MIX,
+    INDEXER, TOPK_SELECT, CONV_MIX, WINDOW_ATTENTION,
 )
 

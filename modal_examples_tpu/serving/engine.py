@@ -41,7 +41,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..models import deepseek_v2, glm_dsa, granite_hybrid, lfm2, llama
+from ..models import deepseek_v2, glm_dsa, granite_hybrid, lfm2, llama, smallthinker
 from ..models.layers import refuse as _refuse
 from ..models.moe import expert_dtype as _expert_dtype
 from ..observability import incident as _incident
@@ -65,6 +65,7 @@ from ..ops.paged_attention import (
     decode_chunk_pages,
     decode_chunk_trips,
     ragged_pages_read,
+    window_decode_view,
 )
 from ..utils.log import get_logger
 from .health import EngineWatermarks
@@ -428,6 +429,7 @@ MODEL_PRESETS = {
     "tiny-granite-hybrid": granite_hybrid.GraniteHybridConfig.tiny,
     "tiny-glm-dsa": glm_dsa.GlmDsaConfig.tiny,
     "tiny-lfm2": lfm2.Lfm2Config.tiny,
+    "tiny-smallthinker": smallthinker.SmallThinkerConfig.tiny,
 }
 
 
@@ -452,6 +454,10 @@ class LLMEngine:
         page_size: int = 16,
         max_model_len: int = 1024,
         n_pages: int | None = None,
+        # the page budget of a model's sliding-window page group
+        # (``cfg.window_group``; docs/kv_cache.md): unset, a ring of pages
+        # for every slot. A model with no such group takes none
+        n_window_pages: int | None = None,
         prefill_buckets: tuple[int, ...] = (128, 256, 512, 1024, 2048),
         prefill_batch: int = 4,  # the one compiled prefill batch shape
         enable_prefix_cache: bool = True,
@@ -659,6 +665,12 @@ class LLMEngine:
                 kv_dtype=kv_dtype,
                 state_leaves=getattr(cfg, "state_leaves", ()),
                 max_slots=max_slots,
+                window_group=getattr(cfg, "window_group", None),
+                n_window_pages=n_window_pages,
+            )
+        if n_window_pages is not None and self.cache.window is None:
+            raise ValueError(
+                f"n_window_pages: {type(cfg).__name__} declares no window group"
             )
         _obs.set_state_bytes(self.cache.state_bytes())
         if mesh is not None:
@@ -1113,7 +1125,7 @@ class LLMEngine:
     def _decode_block_fn(
         self, params, k_pages, v_pages, prev_tokens, override, override_mask,
         positions, page_tables, active, key, temps, top_ps, top_ks, seeds,
-        state=(),
+        state=(), window_tables=None,
     ):
         """`decode_block` decode+sample steps in one program: tokens feed
         forward in-graph (lax.scan), so nothing crosses the host boundary
@@ -1131,10 +1143,11 @@ class LLMEngine:
         # (_process_block splits them off), so the harvest's one read of
         # the tokens brings them
         counted = {"return_counts": True} if self._block_counts else {}
+        windowed = {} if window_tables is None else {"window_tables": window_tables}
 
         def body(carry, k_i):
             tok, pos, kp, vp, st = carry
-            stateful = {"state": st} if st else {}
+            stateful = {"state": st, **windowed} if st else {}
             logits, kp, vp, st, counts = _program_outputs(
                 self._model.decode_step(
                     params, tok, pos, kp, vp, page_tables, active, self.cfg,
@@ -1165,14 +1178,26 @@ class LLMEngine:
         then per-slot ones): ``state`` and, for a prefill call of ``rows``
         rows whose first ones fill ``slots``, the rows' ``slot_ids`` (a row
         with no slot gets ``max_slots``: written nowhere). None for a model
-        with no such leaves: its programs are called as they always were."""
+        with no such leaves: its programs are called as they always were.
+        A model with a window page group also gets ``window_tables``, that
+        group's page table: every slot's row for a decode block, the rows'
+        slots' for a prefill call (a row with no slot: the trash page)."""
         if not self.cache.beside:
             return {}
+        window = self.cache.window
         if slots is None:
-            return {"state": self.cache.beside}
+            args = {"state": self.cache.beside}
+            if window is not None:
+                args["window_tables"] = jnp.asarray(window.tables.copy())
+            return args
         ids = np.full((rows,), self.max_slots, np.int32)
         ids[: len(slots)] = slots
-        return {"state": self.cache.beside, "slot_ids": jnp.asarray(ids)}
+        args = {"state": self.cache.beside, "slot_ids": jnp.asarray(ids)}
+        if window is not None:
+            tables = np.zeros((rows, window.ring), np.int32)
+            tables[: len(slots)] = window.tables[slots]
+            args["window_tables"] = jnp.asarray(tables)
+        return args
 
     def _count_sparse(self, queries, phase: str) -> None:
         """A dispatch of a model with an indexer (``cfg.sparse_positions``):
@@ -1208,10 +1233,35 @@ class LLMEngine:
                 int(trips.sum()) * decode_chunk_pages(ps, pp) * ps
                 * self.max_slots
             )
+        window = self.cache.window
         _obs.record_decode_kv_positions(
             read=read,
             live=int(live.sum()) * steps + live.size * steps * (steps - 1) // 2,
             table=self.max_slots * pp * ps * steps,
+            **({} if window is None else {"layers": "global"}),
+        )
+        if window is None:
+            return
+        # the window group's layers: the loop walks a slot's ring from the
+        # oldest page its window reaches, as far as the longest needs and
+        # over every slot
+        read = held = 0
+        if live.size:
+            at = live[:, None] + np.arange(steps)  # [live, steps]
+            _, lens, starts = window_decode_view(
+                np.zeros((1, window.ring), np.int32), at.reshape(-1), window.window, ps
+            )
+            lens = lens.reshape(at.shape)
+            trips = decode_chunk_trips(lens.max(axis=0), ps, window.ring)
+            read = (
+                int(trips.sum()) * decode_chunk_pages(ps, window.ring) * ps
+                * self.max_slots
+            )
+            held = int((lens - starts.reshape(at.shape)).sum())
+            _obs.record_kv_window_pages_recycled(window.recycled(live, live + steps))
+        _obs.record_decode_kv_positions(
+            read=read, live=held, table=self.max_slots * window.ring * ps * steps,
+            layers="window",
         )
 
     def _multistep_jit(self, n: int):
@@ -1255,9 +1305,11 @@ class LLMEngine:
 
     def _prefill_and_sample(
         self, params, k_pages, v_pages, tokens, page_tables, seq_lens, key,
-        temps, top_ps, top_ks, seeds, state=(), slot_ids=None,
+        temps, top_ps, top_ks, seeds, state=(), slot_ids=None, window_tables=None,
     ):
         stateful = {"state": state, "slot_ids": slot_ids} if state else {}
+        if window_tables is not None:
+            stateful["window_tables"] = window_tables
         logits, k_pages, v_pages, state, _ = _program_outputs(
             self._model.prefill(
                 params, tokens, k_pages, v_pages, page_tables, seq_lens, self.cfg,
@@ -1309,10 +1361,12 @@ class LLMEngine:
 
             def prefill_chunk(
                 params, toks, k_pages, v_pages, tables, lens, state=(),
-                slot_ids=None, q_offset=None, *, cfg,
+                slot_ids=None, q_offset=None, window_tables=None, *, cfg,
             ):
                 # cfg is the target's or the draft's: each names its module
                 stateful = {"state": state, "slot_ids": slot_ids} if state else {}
+                if window_tables is not None:
+                    stateful["window_tables"] = window_tables
                 at = (
                     {"q_offset": q_offset, "prefix_len": offset} if runtime
                     else {"q_offset": offset}
@@ -3032,6 +3086,11 @@ class LLMEngine:
         owned pages, clear the slot, and release the caller."""
         for slot_idx, req, claim in chunk:
             self._unwind_claim(claim)
+            window = self.cache.window
+            if window is not None and window.held(slot_idx):
+                window.release(slot_idx)  # the slot had been given them
+            elif window is not None:
+                window.free(claim["window_pages"])
             slot = self.slots[slot_idx]
             slot.request = None
             slot.pages = slot.trie_pages = slot.private_pages = []
@@ -3095,6 +3154,18 @@ class LLMEngine:
                     return None
             else:
                 return None
+        # the second budget, where the model keeps its sliding-window layers
+        # in a page group of their own: a window's pages and their slack, or
+        # fewer for a context that never fills them. Short of it, nothing
+        # is held (a model with such a group refuses the prefix cache, so
+        # ``fresh`` is the whole claim)
+        window_pages: list[int] = []
+        if self.cache.window is not None:
+            try:
+                window_pages = self.cache.window.claim(max_total)
+            except OutOfPages:
+                self.cache.allocator.free(fresh)
+                return None
         pages = shared + promoted + fresh
         # prefix-cache usage accounting (the OpenAI contract's
         # prompt_tokens_details.cached_tokens): prompt tokens served from
@@ -3122,6 +3193,7 @@ class LLMEngine:
             "trie_pages": trie_pages,
             "private_pages": private_pages,
             "n_prompt": n_prompt,
+            "window_pages": window_pages,
         }
 
     def _charge_slot_usage(self, slot: _Slot) -> None:
@@ -3137,6 +3209,18 @@ class LLMEngine:
             )
         slot.claimed_at = 0.0
 
+    def _install_window_pages(self, slot_idx: int, claim: dict) -> None:
+        """The slot's row of the window group's page table: the claim's
+        pages (a model with no such group: nothing)."""
+        if self.cache.window is not None:
+            self.cache.window.install(slot_idx, claim["window_pages"])
+
+    def _release_window_pages(self, slot: _Slot) -> None:
+        if self.cache.window is not None:
+            # by identity: two slots with equal fields are two slots
+            index = next(i for i, s in enumerate(self.slots) if s is slot)
+            self.cache.window.release(index)
+
     def _release_slot_pages(self, slot: _Slot) -> None:
         self._charge_slot_usage(slot)
         if self.prefix_cache is not None:
@@ -3144,6 +3228,7 @@ class LLMEngine:
             self.cache.allocator.free(slot.private_pages)
         else:
             self.cache.allocator.free(slot.pages)
+        self._release_window_pages(slot)
         slot.pages, slot.trie_pages, slot.private_pages = [], [], []
         slot.ngram = None
         slot.prefill = None
@@ -3181,6 +3266,10 @@ class LLMEngine:
             needed=max(0, offset + len(chunk) - max(offset, cached)),
         )
         self._count_sparse(lambda: offset + np.arange(len(chunk)), "prefill")
+        if self.cache.window is not None:
+            _obs.record_kv_window_pages_recycled(
+                self.cache.window.recycled(offset, offset + len(chunk))
+            )
         (
             logits, self.cache.k_pages, self.cache.v_pages, self.cache.beside,
         ) = self._profiled(
@@ -3337,6 +3426,7 @@ class LLMEngine:
         table = np.zeros((self.pages_per_slot,), np.int32)
         table[: len(pages)] = pages
         self._page_tables[slot_idx] = table
+        self._install_window_pages(slot_idx, claim)
         slot.prefill = _PendingPrefill(req=req, table=table)
 
     def _advance_pending_prefills(self, budget: int | None, spent: int) -> int:
@@ -3609,6 +3699,7 @@ class LLMEngine:
             "trie_pages": slot.trie_pages,
             "private_pages": slot.private_pages,
         })
+        self._release_window_pages(slot)
         slot.pages, slot.trie_pages, slot.private_pages = [], [], []
         slot.prefill = None
         slot.pending_first = False
@@ -3660,6 +3751,7 @@ class LLMEngine:
             table = np.zeros((self.pages_per_slot,), np.int32)
             table[: len(pages)] = pages
             self._page_tables[slot_idx] = table
+            self._install_window_pages(slot_idx, claim)
             tokens[i, :n_prompt] = req.prompt_tokens
             tables[i] = table
             seq_lens[i] = n_prompt

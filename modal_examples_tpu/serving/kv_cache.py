@@ -27,9 +27,11 @@ import threading
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..observability import metrics as _obs
 from ..ops.kv_quant import is_quantized, kv_dtype_name, kv_empty
+from ..ops.paged_attention import window_ring_pages
 from ..utils.log import get_logger
 
 _log = get_logger("kv_cache")
@@ -97,6 +99,87 @@ class PageAllocator:
         return self.used / usable if usable > 0 else 0.0
 
 
+class WindowGroup:
+    """The host side of a second group of paged K/V: the layers whose
+    attention sees a sliding window (docs/kv_cache.md, "Two page groups").
+    Its device leaves are ``PagedKVCache.window_pages``; here are its own
+    allocator (a page budget apart from the whole-context group's), the pages
+    a sequence holds at most (``ring``: the window's pages and one of slack)
+    and its own page table, one row a slot.
+
+    A sequence claims ``min(pages its longest context needs, ring)`` pages
+    when it is admitted and uses them as a ring: position ``p`` lives in page
+    ``tables[slot, (p // page_size) % ring]``, so the page the window has
+    left is written over by the page that enters it. Nothing is allocated or
+    freed while a sequence runs; all its pages go back at release."""
+
+    def __init__(self, *, window: int, page_size: int, n_pages: int, max_slots: int):
+        self.window = window
+        self.page_size = page_size
+        self.ring = window_ring_pages(window, page_size)
+        self.allocator = PageAllocator(n_pages, track=False)
+        self.tables = np.zeros((max_slots, self.ring), np.int32)
+        self._held: dict[int, list[int]] = {}
+        self.peak_used = 0
+
+    def pages_for(self, n_tokens: int) -> int:
+        """Pages a sequence whose context reaches ``n_tokens`` claims."""
+        return min(-(-n_tokens // self.page_size), self.ring)
+
+    def claim(self, n_tokens: int) -> list[int]:
+        """Pages for one sequence; ``OutOfPages`` when the budget is short."""
+        pages = self.allocator.alloc(self.pages_for(n_tokens))
+        self.peak_used = max(self.peak_used, self.allocator.used)
+        self._emit()
+        return pages
+
+    def free(self, pages: list[int]) -> None:
+        """Give back a claim no slot was given (a failed admission)."""
+        self.allocator.free(pages)
+        self._emit()
+
+    def install(self, slot: int, pages: list[int]) -> None:
+        """Slot ``slot`` runs a sequence that holds ``pages``."""
+        self.release(slot)
+        self._held[slot] = pages
+        self.tables[slot] = 0
+        self.tables[slot, : len(pages)] = pages
+
+    def release(self, slot: int) -> None:
+        """The slot's sequence is over: its pages go back, its row reads the
+        trash page."""
+        pages = self._held.pop(slot, None)
+        if pages:
+            self.allocator.free(pages)
+            self.tables[slot] = 0
+            self._emit()
+
+    def held(self, slot: int) -> int:
+        return len(self._held.get(slot, ()))
+
+    def recycled(self, first, end) -> int:
+        """Pages written over when positions ``first .. end - 1`` of a
+        sequence are written (numpy arrays or ints): the pages begun there
+        beyond the ring's first turn."""
+        ps = self.page_size
+        begun_from = np.maximum(-(-np.asarray(first) // ps), self.ring)
+        return int(np.maximum(-(-np.asarray(end) // ps) - begun_from, 0).sum())
+
+    def occupancy(self) -> dict:
+        usable = self.allocator.n_pages - 1
+        used = self.allocator.used
+        return {
+            "pages_used": used, "pages_free": usable - used, "pages_total": usable,
+            "pages_peak": self.peak_used, "ring": self.ring,
+        }
+
+    def _emit(self) -> None:
+        _obs.set_kv_window_pages(
+            used=self.allocator.used, peak=self.peak_used,
+            total_usable=self.allocator.n_pages - 1,
+        )
+
+
 @dataclasses.dataclass
 class PagedKVCache:
     # plain [L, P, page_size, Hkv, hd] arrays, or QuantizedKV (int8 data +
@@ -121,6 +204,14 @@ class PagedKVCache:
     # of every paged leaf, so what the prefix cache shares or frees, it
     # shares or frees in all of them. A model that declares two has none
     more_pages: tuple = ()
+    # the K and V of the layers whose attention sees a sliding window,
+    # ``[window layers, n_window_pages, page_size, *leaf]`` each: a second
+    # page group with a page table and a budget of its own (``window``, a
+    # ``WindowGroup``), because such a layer keeps a window's worth of a
+    # context and the first two leaves keep all of it. A model that declares
+    # no window group (``cfg.window_group``) has neither
+    window_pages: tuple = ()
+    window: WindowGroup | None = None
 
     @classmethod
     def create(
@@ -147,6 +238,11 @@ class PagedKVCache:
         # dtype)`` for each per-slot leaf, made for ``max_slots`` slots
         state_leaves: tuple = (),
         max_slots: int = 0,
+        # a configuration's ``window_group``: ``(layers, window)``, the layers
+        # kept in the second page group and the positions their attention
+        # sees; ``n_window_pages`` is that group's budget (page 0 its trash)
+        window_group: tuple | None = None,
+        n_window_pages: int | None = None,
     ) -> "PagedKVCache":
         if kv_dtype is not None and dtype is not None:
             raise ValueError("pass kv_dtype= or dtype=, not both")
@@ -175,6 +271,20 @@ class PagedKVCache:
                 allocator = NativePageAllocator(n_pages)
             except Exception as e:
                 _log.warning("page allocator: python fallback (%s)", e)
+        window, window_pages = None, ()
+        if window_group is not None:
+            w_layers, w_positions = window_group
+            ring = window_ring_pages(w_positions, page_size)
+            if n_window_pages is None:
+                n_window_pages = 1 + max_slots * ring
+            window = WindowGroup(
+                window=w_positions, page_size=page_size, n_pages=n_window_pages,
+                max_slots=max_slots,
+            )
+            window_pages = tuple(
+                kv_empty((w_layers, n_window_pages, page_size, *leaf), kv_dtype)
+                for leaf in leaf_shapes[:2]
+            )
         return cls(
             k_pages=kv_empty(k_shape, kv_dtype),
             v_pages=kv_empty(v_shape, kv_dtype),
@@ -185,6 +295,8 @@ class PagedKVCache:
                 for layers, shape, dtype in state_leaves
             ),
             more_pages=tuple(kv_empty(shape, kv_dtype) for shape in more_shapes),
+            window_pages=window_pages,
+            window=window,
         )
 
     @property
@@ -217,20 +329,23 @@ class PagedKVCache:
         (``nbytes`` is a property on QuantizedKV and jax.Array alike.)"""
         return (
             self.k_pages.nbytes + self.v_pages.nbytes
-            + sum(leaf.nbytes for leaf in self.more_pages)
+            + sum(leaf.nbytes for leaf in self.more_pages + self.window_pages)
         )
 
     @property
     def beside(self) -> tuple:
         """The leaves the programs carry beside ``k_pages`` and ``v_pages``,
         as one tuple (the ``state=`` they take and hand back: docs/mla.md):
-        the further paged leaves first, then the per-slot ones."""
-        return self.more_pages + self.state
+        the further paged leaves first, then the window group's, then the
+        per-slot ones."""
+        return self.more_pages + self.window_pages + self.state
 
     @beside.setter
     def beside(self, leaves: tuple) -> None:
-        n = len(self.more_pages)
-        self.more_pages, self.state = tuple(leaves[:n]), tuple(leaves[n:])
+        n, w = len(self.more_pages), len(self.window_pages)
+        self.more_pages, self.window_pages, self.state = (
+            tuple(leaves[:n]), tuple(leaves[n:n + w]), tuple(leaves[n + w:])
+        )
 
     def state_bytes(self) -> int:
         """Device bytes of the per-slot leaves (0 for a model with none)."""
@@ -248,8 +363,12 @@ class PagedKVCache:
         usable = self.n_pages - 1
         free = self.allocator.available
         used = usable - free
-        bytes_per_page = self.bytes() // self.n_pages
+        whole = self.bytes() - sum(leaf.nbytes for leaf in self.window_pages)
+        bytes_per_page = whole // self.n_pages
         return {
+            # the pages of the group that keeps whole contexts; the window
+            # group's count is beside it (absent: a model with no such group)
+            **({} if self.window is None else {"window": self.window.occupancy()}),
             "pages_used": used,
             "pages_free": free,
             "pages_total": usable,
@@ -275,6 +394,6 @@ class PagedKVCache:
 # a model that declares them refuses disaggregated transfer.
 jax.tree_util.register_dataclass(
     PagedKVCache,
-    data_fields=("k_pages", "v_pages", "state", "more_pages"),
-    meta_fields=("page_size", "allocator"),
+    data_fields=("k_pages", "v_pages", "state", "more_pages", "window_pages"),
+    meta_fields=("page_size", "allocator", "window"),
 )

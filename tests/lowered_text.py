@@ -15,16 +15,18 @@ import json
 def _engine(family):
     import jax.numpy as jnp
 
-    from modal_examples_tpu.models import deepseek_v2, glm_dsa, granite_hybrid, llama
+    from modal_examples_tpu.models import deepseek_v2, glm_dsa, granite_hybrid, lfm2, llama
     from modal_examples_tpu.serving import LLMEngine
 
     cfg = {
         "llama": lambda: llama.LlamaConfig.tiny(),
+        "llama_moe": lambda: llama.LlamaConfig.tiny_moe(),
+        "lfm2": lambda: lfm2.Lfm2Config.tiny(),
         "deepseek_v2": lambda: deepseek_v2.DeepseekV2Config.tiny(n_held_experts=8, expert_offset=4),
         "granite_hybrid": lambda: granite_hybrid.GraniteHybridConfig.tiny(),
         "glm_dsa": lambda: glm_dsa.GlmDsaConfig.tiny(n_held_experts=8, expert_offset=4),
     }[family]()
-    extra = {"enable_prefix_cache": False} if family == "granite_hybrid" else {}
+    extra = {"enable_prefix_cache": False} if family in ("granite_hybrid", "lfm2") else {}
     return LLMEngine(
         cfg, max_slots=4, page_size=8, max_model_len=64, prefill_buckets=(16,),
         prefill_batch=2, decode_block=4, kv_dtype=jnp.bfloat16, seed=0, **extra,
@@ -66,7 +68,7 @@ def hashes(family: str) -> dict:
     return {name: hashlib.sha256(text.encode()).hexdigest()[:16] for name, text in texts.items()}
 
 
-FAMILIES = ("llama", "deepseek_v2", "granite_hybrid", "glm_dsa")
+FAMILIES = ("llama", "llama_moe", "deepseek_v2", "granite_hybrid", "glm_dsa", "lfm2")
 
 if __name__ == "__main__":
     print(json.dumps({f: hashes(f) for f in FAMILIES}, indent=1))

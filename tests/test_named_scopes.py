@@ -141,7 +141,7 @@ def test_bucket_prefill_program_is_named_and_scoped(engine):
 
 
 def test_every_scope_is_one_the_list_names():
-    assert len(set(scopes.ALL)) == len(scopes.ALL) == 15  # PR 34: mtpu.indexer, mtpu.topk_select; PR 39: mtpu.conv_mix
+    assert len(set(scopes.ALL)) == len(scopes.ALL) == 16  # PR 34: mtpu.indexer, mtpu.topk_select; PR 39: mtpu.conv_mix; PR 41: mtpu.window_attention
     assert all(s.startswith("mtpu.") for s in scopes.ALL)
 
 
