@@ -46,14 +46,23 @@ ROADMAP.md D12). A window layer writes only the rows of the last ``ring``
 pages up to the row's end, so one call never writes a ring page twice.
 
 **Decode**: a global layer attends over the engine's table, a window layer
-over its ring rolled to start at the oldest page the window reaches
-(``ops.window_decode_view``), both through the chunked XLA loop
-(``paged_impl_plan`` names it); the first page's positions from before the
-window are masked by ``starts``; both groups' new rows are scattered once
-after the layers. A window layer's gathers, its ring's in a decode step and
-the window's worth before a chunk, run under ``mtpu.window_attention`` with
-its scores, not under ``mtpu.page_gather``: the scope is the layer's whole
-attention.
+over its ring from the oldest page the window reaches
+(``ops.window_decode_span``), the first page's positions from before the
+window masked by ``starts``; both groups' new rows are scattered once after
+the layers. ``paged_impl_plan`` names what runs. On a TPU at the published
+shapes (4 K/V heads of 128, pages of 16, bf16) both groups go through the
+ragged kernel's all-heads ``flat`` form, which reads a slot's live pages in
+place, a ring from its first page with a wrap: a page of ``[16, 4, 128]``
+reaches it as ``(64, 128)`` rows by a reshape the compiler proves a bitcast
+(``ops.paged_attention.flat_view_is_free``), the 28 query heads as 32 rows.
+The kernel's ``grouped`` form is not used: it slices every token-major page
+by head in VMEM and a group of 7 fills 7 of a product's 128 rows, 2.6-2.9x
+slower than the loop (PERF.md section 6, PR 41). Elsewhere (the CPU, other
+shapes, ``paged_impl="xla"``) both run the chunked XLA loop, a window layer
+over its table rolled to start at that page (``ops.window_decode_view``). A
+window layer's attention, the kernel or the loop's gathers and the window's
+worth gathered before a chunk, runs under ``mtpu.window_attention``, not
+under ``mtpu.page_gather``: the scope is the layer's whole attention.
 
 The layers are scanned a *period* at a time (the shortest repeating unit of
 the two layouts, four layers here), the period's layers unrolled in the
@@ -79,6 +88,8 @@ from ..ops import (
     kv_gather,
     paged_decode_attention_chunked,
     paged_window_decode_attention_chunked,
+    paged_window_decode_attention_ragged,
+    sharded_ragged_decode,
 )
 from ..ops import scopes as _scopes
 from ..ops.flash_attention import flash_attention_chunked
@@ -115,7 +126,7 @@ class SmallThinkerConfig:
     unsupported = (
         "prefix caching", "int8 KV cache", "speculative decoding",
         "multistep decode", "disaggregated transfer", "tensor parallelism",
-        "LoRA", "vision", "a Pallas paged_impl or scatter_impl",
+        "LoRA", "vision", "a Pallas scatter_impl",
     )
     #: ``decode_step(return_counts=True)`` hands back [pairs, tile rows]
     counts_expert_tile_rows = True
@@ -355,30 +366,64 @@ def paged_impl_plan(
     scatter_impl: str = "xla", *, kv_dtype="bfloat16", mesh=None, warn: bool = True,
     expert_dtype=None,
 ) -> dict:
-    """What runs for this model, chosen from what can be seen here and by no
-    option. Attention (``attention`` for the global layers,
-    ``window_attention`` for the window layers): the chunked XLA loop over
-    each group's pages, a window layer's over its ring from the first page
-    its window reaches (``ops.window_decode_view``), and the XLA scatter;
-    anything else is refused here. (The ragged kernel's ``flat`` variant
-    wants K/V heads in multiples of 8, and its ``grouped`` variant, tried at
-    the published 4 K/V heads with a group of 7, was slower than the loop on
-    the v5e: PERF.md section 6, PR 41.) The routed experts' tile loop in a
-    decode step (``expert_scan``): ``moe.expert_scan_form``'s choice for
-    experts of ``expert_dtype`` (unset: the model's own)."""
+    """What runs for this model, chosen from what can be seen here.
+    Attention (``attention`` for the global layers, ``window_attention`` for
+    the window layers, one choice for both): ``ragged`` / ``ragged-ring``,
+    the ragged kernel's ``flat`` form over each group's pages in place, a
+    window layer's ring from the first page its window reaches
+    (``ops.window_decode_span``), where the backend is a TPU, the pages are
+    whole tiles (``ragged_shapes_ok``), they reach the kernel as
+    ``(page x heads, head_dim)`` rows without a copy of the cache
+    (``flat_view_is_free``: 4 K/V heads, or a multiple of 8) and are two
+    bytes an element; ``xla-gather`` / ``xla-gather-ring``, the chunked XLA
+    loop, everywhere else. ``impl`` ``"xla"`` / ``"pallas"`` force one (the
+    kernel runs in the interpreter off the chip); a forced kernel the shapes
+    refuse on a TPU is named in ``downgraded``. The kernel's ``grouped``
+    form is never chosen: at the published 4 K/V heads with a group of 7 it
+    was 2.6-2.9x slower than the loop on the v5e (it slices every token-major
+    page by head in VMEM; PERF.md section 6, PR 41), where ``flat`` is
+    2.9-3.9x faster (PR 42). The scatter is XLA's; another is refused. The
+    routed experts' tile loop in a decode step (``expert_scan``):
+    ``moe.expert_scan_form``'s choice for experts of ``expert_dtype``
+    (unset: the model's own)."""
     from ..ops.kv_quant import resolve_kv_dtype
+    from ..ops.paged_attention import flat_view_is_free, ragged_shapes_ok
 
-    if impl not in (None, "xla") or scatter_impl != "xla":  # unset: as "xla"
-        refuse(cfg, "a Pallas paged_impl or scatter_impl")
+    if scatter_impl != "xla":
+        refuse(cfg, "a Pallas scatter_impl")
     if mesh is not None:
         refuse(cfg, "tensor parallelism")
     kvd = resolve_kv_dtype(kv_dtype)
     if kvd == "int8":
         refuse(cfg, "int8 KV cache")
+    on_tpu = jax.default_backend() == "tpu"
+    shapes_ok = (
+        ragged_shapes_ok(cfg.head_dim, page_size) and flat_view_is_free(cfg.n_kv_heads)
+        and jnp.dtype(kvd).itemsize == 2
+    )
+    if impl is None:
+        ragged = on_tpu and shapes_ok
+    else:
+        ragged = impl == "pallas" and (shapes_ok or not on_tpu)
+    downgraded = []
+    if impl == "pallas" and not ragged:
+        downgraded.append(
+            f"paged_impl=pallas -> xla-gather (n_kv_heads={cfg.n_kv_heads}, head_dim="
+            f"{cfg.head_dim}, page_size={page_size}, {kvd} pages: the flat form wants 4 or "
+            "8k heads of 128k, pages of 16k, two bytes an element)"
+        )
+        if warn:
+            import warnings
+
+            warnings.warn("requested Pallas impl downgraded: " + downgraded[0], stacklevel=2)
+    ring = None
+    if cfg.window_group:
+        ring = "ragged-ring" if ragged else "xla-gather-ring"
     return {
-        "attention": "xla-gather", "ragged_variant": None, "scatter": "xla",
-        "kv_dtype": str(kvd), "tp": 1, "downgraded": [],
-        "window_attention": "xla-gather-ring" if cfg.window_group else None,
+        "attention": "ragged" if ragged else "xla-gather",
+        "ragged_variant": "flat" if ragged else None, "scatter": "xla",
+        "kv_dtype": str(kvd), "tp": 1, "downgraded": downgraded,
+        "window_attention": ring,
         "expert_scan": _moe.expert_scan_form(
             1, cfg.dim, cfg.moe_ffn_dim, expert_dtype or cfg.dtype
         ),
@@ -689,8 +734,8 @@ def decode_step(
     (logits [B, vocab], k_pages, v_pages, state) and, with
     ``return_counts``, [pairs, tile rows] of the routed layers."""
     _check_serving(cfg, k_pages, state, window_tables, mesh)
-    # one form; the plan refuses what is asked for beyond it
-    paged_impl_plan(cfg, k_pages.shape[2], impl, scatter_impl, kv_dtype=k_pages.dtype)
+    plan = paged_impl_plan(cfg, k_pages.shape[2], impl, scatter_impl, kv_dtype=k_pages.dtype)
+    ragged = plan["attention"] == "ragged"
     ps = k_pages.shape[2]
     B = tokens.shape[0]
     W = cfg.sliding_window
@@ -707,10 +752,21 @@ def decode_step(
         logits = _router_logits(layer, x)
         u = layers.rms_norm(x, layer["attn_norm"], cfg.norm_eps)
         q, k, v = _qkv(layer, u, cos, sin, cfg, rotate)  # [B, heads, hd]
-        if window:
+        # the kernel's form is always the all-heads one (paged_impl_plan says why)
+        if window and ragged:
+            o = paged_window_decode_attention_ragged(
+                q, wk_pages, wv_pages, rows[l], window_tables, live_pos, k, v,
+                window=W, sm_scale=cfg.softmax_scale, variant="flat",
+            )
+        elif window:
             o = paged_window_decode_attention_chunked(
                 q, wk_pages, wv_pages, rows[l], window_tables, live_pos, k, v,
                 window=W, sm_scale=cfg.softmax_scale,
+            )
+        elif ragged:
+            o = sharded_ragged_decode(
+                None, q, k_pages, v_pages, rows[l], page_tables, live_pos, k, v,
+                sm_scale=cfg.softmax_scale, variant="flat",
             )
         else:
             o = paged_decode_attention_chunked(

@@ -28,9 +28,11 @@ from .paged_attention import (
     paged_decode_attention_inflight,
     paged_decode_attention_ragged,
     paged_window_decode_attention_chunked,
+    paged_window_decode_attention_ragged,
     ragged_kernel_sizes,
     ragged_pages_read,
     scatter_kv_pages,
+    window_decode_span,
     window_decode_view,
     window_ring_pages,
 )
@@ -66,6 +68,7 @@ __all__ = [
     "paged_decode_attention_inflight",
     "paged_decode_attention_ragged",
     "paged_window_decode_attention_chunked",
+    "paged_window_decode_attention_ragged",
     "ragged_kernel_sizes",
     "ragged_pages_read",
     "is_quantized",
@@ -88,6 +91,7 @@ __all__ = [
     "ring_attention_sharded",
     "ulysses_attention",
     "ulysses_attention_sharded",
+    "window_decode_span",
     "window_decode_view",
     "window_ring_pages",
 ]

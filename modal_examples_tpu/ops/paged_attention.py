@@ -389,27 +389,39 @@ def window_ring_pages(window: int, page_size: int) -> int:
     return -(-window // page_size) + 1
 
 
-def window_decode_view(window_tables, positions, window: int, page_size: int):
-    """A window layer's page table as the decode loop walks it. A sequence
-    uses its ``ring`` window pages as a ring: the page of position ``p`` is
-    ``window_tables[b, (p // page_size) % ring]``, so a page is written over
-    once the window has left it. For a step whose token is at
-    ``positions[b]`` (that many positions cached): the table's columns
-    rolled so that column 0 is the oldest page the window can reach,
-    ``first`` = that page's first position, and on that footing the prefix
-    length and the first position inside the window. Returns (tables
-    [B, ring], prefix_lens [B], starts [B]); whole-number arithmetic, on
-    traced arrays in the op or numpy ones where the engine counts."""
+def window_decode_span(positions, window: int, page_size: int, ring: int):
+    """Where a window layer's decode step reads in a sequence's ring. A
+    sequence uses its ``ring`` window pages as a ring: the page of position
+    ``p`` is ``window_tables[b, (p // page_size) % ring]``, so a page is
+    written over once the window has left it. For a step whose token is at
+    ``positions[b]`` (that many positions cached): ``first_page``, the ring
+    column of the oldest page the window can reach (the walk goes
+    ``(first_page + i) % ring``), and, counted from that page's first
+    position, the prefix length and the first position inside the window.
+    Returns (first_page [B], prefix_lens [B], starts [B]); whole-number
+    arithmetic, on traced arrays in the op or numpy ones where the engine
+    counts."""
     xp = jnp if isinstance(positions, jax.Array) else np
-    ring = window_tables.shape[1]
-    first_page = xp.maximum(positions // page_size - (ring - 1), 0)
-    cols = (first_page[:, None] + xp.arange(ring)[None, :]) % ring
-    first = first_page * page_size
+    oldest = xp.maximum(positions // page_size - (ring - 1), 0)
+    first = oldest * page_size
     starts = xp.maximum(positions - (window - 1) - first, 0)
     return (
-        xp.take_along_axis(window_tables, cols, axis=1),
-        (positions - first).astype(xp.int32), starts.astype(xp.int32),
+        (oldest % ring).astype(xp.int32), (positions - first).astype(xp.int32),
+        starts.astype(xp.int32),
     )
+
+
+def window_decode_view(window_tables, positions, window: int, page_size: int):
+    """A window layer's page table as the chunked loop walks it: the
+    table's columns rolled so that column 0 is the oldest page the window
+    can reach (``window_decode_span``), with the prefix length and the first
+    position inside the window on that footing. Returns (tables [B, ring],
+    prefix_lens [B], starts [B])."""
+    xp = jnp if isinstance(positions, jax.Array) else np
+    ring = window_tables.shape[1]
+    first_page, prefix_lens, starts = window_decode_span(positions, window, page_size, ring)
+    cols = (first_page[:, None] + xp.arange(ring)[None, :]) % ring
+    return xp.take_along_axis(window_tables, cols, axis=1), prefix_lens, starts
 
 
 def ragged_shapes_ok(head_dim: int, page_size: int) -> bool:
@@ -425,18 +437,34 @@ def ragged_shapes_ok(head_dim: int, page_size: int) -> bool:
 #: in HBM, and that reshape is a bitcast (no copy of the cache) when a token's
 #: ``(Hkv, D)`` slab is whole ``T(8, 128)`` tiles: 8 rows, for bf16 and for
 #: int8 pages alike (compiled for the v5e, PR 35; until then the flatten was
-#: done in VMEM and wanted 16 / 32)
+#: done in VMEM and wanted 16 / 32). **At 4 heads** the view is free too, by
+#: another road (``flat_view_is_free``; compiled for the v5e, PR 42): the
+#: compiler lays a leaf whose second-minor dimension is 4 out in tiles of 4
+#: rows, ``T(4, 128)(2, 1)`` for bf16, and two such tiles one after the other
+#: are the bytes of one ``T(8, 128)(2, 1)`` tile of the flattened rows (two
+#: tokens to a tile, row ``r`` K/V head ``r % 4``), so the reshape is a
+#: bitcast again. 12 heads are neither: the compiler copies the leaf.
 FLAT_VARIANT_HKV_MULTIPLE = 8
+
+
+def flat_view_is_free(n_kv_heads: int) -> bool:
+    """Whether the cache reaches the "flat" form as ``(ps*Hkv, D)`` rows
+    without a copy (``FLAT_VARIANT_HKV_MULTIPLE``'s comment has the rule)."""
+    return n_kv_heads == 4 or n_kv_heads % FLAT_VARIANT_HKV_MULTIPLE == 0
 
 
 def ragged_variant_for(n_kv_heads: int) -> str:
     """Default kernel formulation: "flat" (one all-heads block-diagonal
-    matmul over pages read as ``(ps*Hkv, D)`` rows) wherever that view is
-    free (``FLAT_VARIANT_HKV_MULTIPLE``): on the v5e at 8 KV heads of 128
-    it runs 1.9x faster than "grouped" (per-kv-head contractions), whose
-    head slices of a token-major page are a relayout of every K/V element
-    (PERF.md section 6, PR 35). Everything else (a head shard of 1, 2 or 4
-    KV heads under tensor parallelism) takes "grouped"."""
+    matmul over pages read as ``(ps*Hkv, D)`` rows) where a token's heads
+    are whole tiles (``FLAT_VARIANT_HKV_MULTIPLE``): on the v5e at 8 KV heads
+    of 128 it runs 1.9x faster than "grouped" (per-kv-head contractions),
+    whose head slices of a token-major page are a relayout of every K/V
+    element (PERF.md section 6, PR 35). Everything else (a head shard of 1,
+    2 or 4 KV heads under tensor parallelism) takes "grouped". A model of 4
+    K/V heads on one chip asks for "flat" itself
+    (``smallthinker.paged_impl_plan``: the view is free there too and
+    "grouped" loses to the XLA loop, PERF.md section 6, PRs 41 and 42); a
+    4-head *shard* has not been measured and keeps what it had."""
     return "grouped" if n_kv_heads % FLAT_VARIANT_HKV_MULTIPLE else "flat"
 
 
@@ -522,7 +550,8 @@ def _decode_kernel_ragged(
     # scalar prefetch
     layer_ref,  # (1,) int32, SMEM — which layer of the [L, P, ...] cache
     page_tables_ref,  # (B * pages_per_seq,) int32, SMEM
-    prefix_lens_ref,  # (B,) int32, SMEM — tokens already IN the cache
+    prefix_lens_ref,  # (B,) int32, SMEM — tokens already IN the cache; ``ring``:
+    #   (3 * B,), then each slot's first table column and first seen position
     # inputs — FULL arrays as single constant-index blocks: Mosaic skips the
     # re-fetch when a block's index map is unchanged between grid steps, so
     # q/k_new/v_new stream into VMEM once per pallas_call instead of paying
@@ -545,6 +574,8 @@ def _decode_kernel_ragged(
     chunk: int,  # pages one half of the ring holds
     update: int,  # pages one softmax update covers; divides chunk
     quantized: bool = False,
+    ring: bool = False,  # the table is a ring walked from a slot's first column
+    products: bool = True,  # False: the fetch alone (benchmarks/ragged_micro.py)
 ):
     """Ragged decode attention: one grid step a sequence, its live pages
     DMAed once from the [L, P, ...] cache, the in-flight token folded in.
@@ -569,6 +600,14 @@ def _decode_kernel_ragged(
     Rows of a half past a partial chunk hold an earlier chunk's pages or the
     zeros written at the first grid step: finite, so a masked probability
     (exactly 0) times them is 0.
+
+    *A ring* (``ring=True``, a sliding-window layer's table: the wrapper's
+    ``first_pages`` / ``starts``). The same walk, begun at the slot's first
+    column and wrapping at the table's end, ``(first + i) % pages_per_seq``;
+    positions count from that page's first, and those below the slot's
+    ``start`` (the head of the first page, which the window has left) are
+    masked like those past the prefix. Both ride the prefix lengths' scalar
+    array. Unset, neither is read and the kernel is what it was.
 
     *The products*, per update of ``update`` pages, same online softmax:
     - ``"flat"``: one block-diagonal all-heads matmul. A page is
@@ -604,7 +643,11 @@ def _decode_kernel_ragged(
         live = jnp.minimum(C, ragged_pages_read(prefix_lens_ref[seq], ps) - i * C)
 
         def one(j, _):
-            page = page_tables_ref[seq * pp + i * C + j]
+            if ring:  # first column < pp and i * C + j < pp: one turn at most
+                col = prefix_lens_ref[B + seq] + i * C + j
+                page = page_tables_ref[seq * pp + jnp.where(col >= pp, col - pp, col)]
+            else:
+                page = page_tables_ref[seq * pp + i * C + j]
             pltpu.make_async_copy(
                 k_hbm.at[li, page], k_scr.at[half * C + j], sems.at[half, 0]
             ).start()
@@ -720,7 +763,10 @@ def _decode_kernel_ragged(
         s = s * sm_scale
         if quantized:
             s = s * scale_rows(ks_ref, u)
-        valid = u * (U * ps) + col_tok < prefix
+        pos = u * (U * ps) + col_tok
+        valid = pos < prefix
+        if ring:
+            valid = valid & (pos >= prefix_lens_ref[2 * B + b])
         if variant == "flat":
             valid = own_head & valid
         s = jnp.where(valid, s, -jnp.inf)
@@ -771,6 +817,8 @@ def _decode_kernel_ragged(
 
         live = jnp.minimum(C, n_pages - i * C)
         wait_chunk(live, half)
+        if not products:
+            return carry
         return jax.lax.fori_loop(
             0, pl.cdiv(live, U),
             lambda k, carry: softmax_update(
@@ -839,6 +887,9 @@ def paged_decode_attention_ragged(
     variant: str | None = None,  # None: ragged_variant_for(Hkv)
     chunk_pages: int | None = None,  # None: ragged_kernel_sizes (to A/B)
     update_pages: int | None = None,
+    first_pages: jax.Array | None = None,  # [B] int32: a ring's first column
+    starts: jax.Array | None = None,  # [B] int32: ... and first position seen
+    products: bool = True,  # False: the fetch alone, to time it
 ) -> jax.Array:  # [B, Hq, D]
     """Pallas ragged decode attention over prefix pages + the in-flight
     token. Drop-in exact match for ``paged_decode_attention_inflight``
@@ -847,10 +898,18 @@ def paged_decode_attention_ragged(
 
     Two formulations of the products share the fetch and the online softmax
     (`_decode_kernel_ragged`): ``"flat"`` (one block-diagonal all-heads
-    matmul; needs Hkv%8 on the chip) and ``"grouped"`` (Hkv per-kv-head
-    matmuls, any Hkv). ``variant=`` / ``chunk_pages=`` / ``update_pages=``
-    override what ``ragged_variant_for`` and ``ragged_kernel_sizes`` pick,
-    to A/B (``benchmarks/ragged_micro.py``).
+    matmul; on the chip where ``flat_view_is_free``: 4 K/V heads or a
+    multiple of 8) and ``"grouped"`` (Hkv per-kv-head matmuls, any Hkv).
+    ``variant=`` / ``chunk_pages=`` / ``update_pages=`` override what
+    ``ragged_variant_for`` and ``ragged_kernel_sizes`` pick, to A/B
+    (``benchmarks/ragged_micro.py``). The flat form pads the query heads to
+    whole 8-row tiles (28 heads run as 32 rows; a padded row has no K/V head
+    of its own and comes out 0).
+
+    ``first_pages`` and ``starts`` (both or neither) make each row of
+    ``page_tables`` a ring (``window_decode_span``): slot b's walk starts at
+    column ``first_pages[b]`` and wraps, ``prefix_lens`` counts from that
+    page's first position, and positions below ``starts[b]`` are masked.
 
     ``k_pages``/``v_pages`` may be int8 :class:`~.kv_quant.QuantizedKV`
     caches: both variants then DMA the int8 pages and apply the scales
@@ -880,13 +939,18 @@ def paged_decode_attention_ragged(
             f"paged_decode_attention_ragged needs head_dim%128==0 and "
             f"page_size%16==0 on TPU; got D={D}, page_size={page_size}"
         )
-    if not interpret and variant == "flat" and Hkv % FLAT_VARIANT_HKV_MULTIPLE:
+    if not interpret and variant == "flat" and not flat_view_is_free(Hkv):
         raise ValueError(
-            f"variant='flat' needs n_kv_heads%{FLAT_VARIANT_HKV_MULTIPLE}==0 "
-            f"on TPU (a page read as (ps*Hkv, D) rows without a copy of the "
-            f"cache); got Hkv={Hkv} — use variant='grouped' (the default "
-            "for this shape)"
+            f"variant='flat' needs n_kv_heads == 4 or n_kv_heads%"
+            f"{FLAT_VARIANT_HKV_MULTIPLE}==0 on TPU (a page read as "
+            f"(ps*Hkv, D) rows without a copy of the cache); got Hkv={Hkv} "
+            "— use variant='grouped' (the default for this shape)"
         )
+    ring = first_pages is not None
+    if ring != (starts is not None):
+        raise ValueError("a ring takes first_pages= and starts= together")
+    if ring and quantized:
+        raise NotImplementedError("a ring of int8 pages")
 
     # int8 caches compute at (and fold the in-flight token at) the query's
     # dtype; plain caches keep their own dtype into the MXU (no retile)
@@ -910,12 +974,21 @@ def paged_decode_attention_ragged(
             f"{update} and at most the cache's {n_pages} pages"
         )
     page_shape = (page_size, Hkv, D)
+    rows = Hq  # q's rows in the kernel
     if variant == "flat":
         # a bitcast in HBM (FLAT_VARIANT_HKV_MULTIPLE), so the kernel's
         # operands need no relayout in VMEM
         page_shape = (page_size * Hkv, D)
         k_data = k_data.reshape(L, n_pages, *page_shape)
         v_data = v_data.reshape(L, n_pages, *page_shape)
+        rows = -(-Hq // 8) * 8
+        if rows != Hq:
+            q = jnp.pad(q, ((0, 0), (0, rows - Hq), (0, 0)))
+    lens = prefix_lens.astype(jnp.int32)
+    if ring:
+        lens = jnp.concatenate(
+            [lens, first_pages.astype(jnp.int32), starts.astype(jnp.int32)]
+        )
 
     def _const3(shape):
         return pl.BlockSpec(
@@ -925,7 +998,7 @@ def paged_decode_attention_ragged(
     # full arrays, constant index maps: fetched into VMEM once per call,
     # not once per program (see _decode_kernel_ragged)
     in_specs = [
-        _const3((B, Hq, D)),
+        _const3((B, rows, D)),
         _const3((B, Hkv, D)),
         _const3((B, Hkv, D)),
         pl.BlockSpec(memory_space=pl.ANY),
@@ -958,13 +1031,13 @@ def paged_decode_attention_ragged(
         grid=(B,),
         in_specs=in_specs,
         out_specs=pl.BlockSpec(
-            (B, Hq, D), lambda b, *_refs: (0, 0, 0),
+            (B, rows, D), lambda b, *_refs: (0, 0, 0),
             memory_space=pltpu.VMEM,
         ),
         scratch_shapes=[
             pltpu.VMEM((2 * chunk, *page_shape), k_data.dtype),
             pltpu.VMEM((2 * chunk, *page_shape), v_data.dtype),
-            pltpu.VMEM((Hq, D), jnp.float32),
+            pltpu.VMEM((rows, D), jnp.float32),
             pltpu.SemaphoreType.DMA((2, 2)),
             pltpu.SMEM((2,), jnp.int32),
         ],
@@ -980,12 +1053,14 @@ def paged_decode_attention_ragged(
         chunk=chunk,
         update=update,
         quantized=quantized,
+        ring=ring,
+        products=products,
     )
     scale_bytes = 4 * page_size * Hkv if quantized else 0
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Hq, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, rows, D), q.dtype),
         compiler_params=pltpu.CompilerParams(
             # the ring and its state carry from one grid step to the next
             dimension_semantics=("arbitrary",),
@@ -1002,10 +1077,38 @@ def paged_decode_attention_ragged(
     )(
         jnp.reshape(layer, (1,)).astype(jnp.int32),
         page_tables.reshape(-1).astype(jnp.int32),
-        prefix_lens.astype(jnp.int32),
+        lens,
         *operands,
     )
-    return out
+    return out if rows == Hq else out[:, :Hq]
+
+
+@jax.named_scope(WINDOW_ATTENTION)
+def paged_window_decode_attention_ragged(
+    q: jax.Array,  # [B, Hq, D]
+    k_pages: jax.Array,  # [Lw, n_window_pages, page_size, Hkv, D]: the window group's pages
+    v_pages: jax.Array,
+    layer: jax.Array,  # scalar int32: the layer's row in the group
+    window_tables: jax.Array,  # [B, ring] int32: each slot's ring of pages
+    positions: jax.Array,  # [B] int32: the token's position (0 for a dead slot)
+    k_new: jax.Array,  # [B, Hkv, D]
+    v_new: jax.Array,
+    *,
+    window: int,
+    sm_scale: float | None = None,
+    **kernel,  # paged_decode_attention_ragged's (variant=, interpret=, ...)
+) -> jax.Array:  # [B, Hq, D]
+    """``paged_window_decode_attention_chunked``'s contract through the
+    ragged kernel: a slot's ring read in place from the first page its
+    window reaches (``window_decode_span``), that page's head masked by
+    ``starts``; no rolled table, no gathered copy."""
+    first_pages, prefix_lens, starts = window_decode_span(
+        positions, window, k_pages.shape[2], window_tables.shape[1]
+    )
+    return paged_decode_attention_ragged(
+        q, k_pages, v_pages, layer, window_tables, prefix_lens, k_new, v_new,
+        sm_scale=sm_scale, first_pages=first_pages, starts=starts, **kernel,
+    )
 
 
 def _kv_scatter_kernel(

@@ -65,7 +65,7 @@ from ..ops.paged_attention import (
     decode_chunk_pages,
     decode_chunk_trips,
     ragged_pages_read,
-    window_decode_view,
+    window_decode_span,
 )
 from ..utils.log import get_logger
 from .health import EngineWatermarks
@@ -582,6 +582,7 @@ class LLMEngine:
                 self.paged_impl == "pallas" or self.scatter_impl != "xla",
                 "a Pallas paged_impl or scatter_impl",
             ),
+            (self.scatter_impl != "xla", "a Pallas scatter_impl"),
         ):
             if asked:
                 _refuse(cfg, feature)
@@ -1214,8 +1215,10 @@ class LLMEngine:
         (ops.paged_decode_attention_chunked) makes as many trips as that
         step's longest context needs, each over every slot; the ragged
         kernel (ops.paged_decode_attention_ragged) DMAs each live slot's own
-        live pages and nothing for a dead one. The macro-step program can
-        kill a lane before its last step; it is counted as running them
+        live pages and nothing for a dead one (a window layer's ring from
+        the first page its window reaches: the plan's ``window_attention``
+        says which of the two read the second group). The macro-step program
+        can kill a lane before its last step; it is counted as running them
         all."""
         self._count_sparse(
             lambda: positions[active].astype(np.int64)[:, None] + np.arange(steps), "decode"
@@ -1242,21 +1245,22 @@ class LLMEngine:
         )
         if window is None:
             return
-        # the window group's layers: the loop walks a slot's ring from the
-        # oldest page its window reaches, as far as the longest needs and
-        # over every slot
+        # the window group's layers: a slot's ring from the oldest page its
+        # window reaches; the kernel reads each live slot's own live pages
+        # from there, the loop as far as the longest needs and over every slot
         read = held = 0
         if live.size:
             at = live[:, None] + np.arange(steps)  # [live, steps]
-            _, lens, starts = window_decode_view(
-                np.zeros((1, window.ring), np.int32), at.reshape(-1), window.window, ps
-            )
+            _, lens, starts = window_decode_span(at.reshape(-1), window.window, ps, window.ring)
             lens = lens.reshape(at.shape)
-            trips = decode_chunk_trips(lens.max(axis=0), ps, window.ring)
-            read = (
-                int(trips.sum()) * decode_chunk_pages(ps, window.ring) * ps
-                * self.max_slots
-            )
+            if self.impl_plan["window_attention"] == "ragged-ring":
+                read = int(ragged_pages_read(lens, ps).sum()) * ps
+            else:
+                trips = decode_chunk_trips(lens.max(axis=0), ps, window.ring)
+                read = (
+                    int(trips.sum()) * decode_chunk_pages(ps, window.ring) * ps
+                    * self.max_slots
+                )
             held = int((lens - starts.reshape(at.shape)).sum())
             _obs.record_kv_window_pages_recycled(window.recycled(live, live + steps))
         _obs.record_decode_kv_positions(

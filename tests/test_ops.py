@@ -407,7 +407,10 @@ class TestPagedAttention:
         ],
     )
     @pytest.mark.parametrize("pages", ["bf16", "int8"])
-    @pytest.mark.parametrize("heads", [(8, 2), (4, 4)], ids=["gqa-g4", "group-of-one"])
+    # the last: the published SmallThinker's 28 heads over 4 (32 rows in the flat form)
+    @pytest.mark.parametrize(
+        "heads", [(8, 2), (4, 4), (28, 4)], ids=["gqa-g4", "group-of-one", "gqa-g7-of-4"]
+    )
     @pytest.mark.parametrize("variant", ["flat", "grouped"])
     def test_ragged_ring_against_the_reference(
         self, jax, jnp, variant, heads, pages, case, prefix, kw
@@ -457,6 +460,86 @@ class TestPagedAttention:
         np.testing.assert_allclose(
             np.asarray(got, np.float32), np.asarray(want), atol=3e-2, err_msg=case,
         )
+
+    #: positions of a batch's tokens under a test window of 64 (a ring of 5
+    #: pages of 16), the published 28 query heads over 4 K/V heads of 128
+    WINDOW_CASES = [
+        # contexts window .. window + page: the ring's first wrap, position by position
+        ("first-turn-position-by-position", tuple(range(64, 81))),
+        ("a-window-starting-mid-page", (69, 87, 101, 135, 150, 77)),
+        ("dead-slots", (0, 70, 0, 0, 133, 0)),
+        ("shorter-than-the-window", (1, 15, 16, 17, 40, 63)),
+        ("several-turns-on", (80, 81, 160, 161, 400, 415)),
+        ("nothing-live", (0, 0, 0)),
+    ]
+
+    @pytest.mark.parametrize("case, positions", WINDOW_CASES)
+    @pytest.mark.parametrize(
+        "sizes", [{}, dict(chunk_pages=2, update_pages=1)],
+        ids=["sizes-the-kernel-picks", "chunks-of-2-pages"],
+    )
+    def test_flat_at_4_kv_heads_over_a_ring_against_the_reference_and_the_loop(
+        self, jax, jnp, sizes, case, positions
+    ):
+        """The all-heads form at 4 K/V heads with a group of 7 (28 query
+        heads run as 32 rows; two tokens' heads to a tile of the flattened
+        page), a sliding-window layer's ring read in place from the first
+        page the window reaches with a wrap, that page's head masked by
+        ``starts``: against ``reference.paged_decode_attention`` over the
+        window's positions copied out of the ring into a table of their own
+        with the in-flight token behind them, and against the chunked loop
+        over the rolled table."""
+        from modal_examples_tpu.ops import (
+            paged_window_decode_attention_chunked, paged_window_decode_attention_ragged,
+            reference, window_ring_pages,
+        )
+
+        Hq, Hkv, D, ps, window = 28, 4, 128, 16, 64
+        ring, B = window_ring_pages(window, ps), len(positions)
+        ks = jax.random.split(jax.random.PRNGKey(42), 6)
+        q = jax.random.normal(ks[0], (B, Hq, D), jnp.bfloat16)
+        kp = jax.random.normal(ks[1], (2, 1 + B * ring, ps, Hkv, D), jnp.bfloat16)
+        vp = jax.random.normal(ks[2], kp.shape, jnp.bfloat16)
+        k_new = jax.random.normal(ks[3], (B, Hkv, D), jnp.bfloat16)
+        v_new = jax.random.normal(ks[4], (B, Hkv, D), jnp.bfloat16)
+        tables = 1 + jax.random.permutation(ks[5], B * ring).reshape(B, ring).astype(jnp.int32)
+        args = (q, kp, vp, jnp.int32(1), tables, jnp.asarray(positions, jnp.int32), k_new, v_new)
+        got = paged_window_decode_attention_ragged(*args, window=window, variant="flat", **sizes)
+        loop = paged_window_decode_attention_chunked(*args, window=window)
+        np.testing.assert_allclose(
+            np.asarray(got, np.float32), np.asarray(loop, np.float32), atol=2e-2, err_msg=case
+        )
+        # the reference: each slot's window, oldest first, then its own token
+        f32, pp = jnp.float32, window // ps + 1
+        lin = [np.zeros((B * pp, ps, Hkv, D), np.float32) for _ in range(2)]
+        lens = []
+        for b, t in enumerate(positions):
+            seen = np.arange(max(t - window + 1, 0), t)
+            at = (np.asarray(tables)[b, (seen // ps) % ring], seen % ps)
+            for dst, pages, new in zip(lin, (kp, vp), (k_new, v_new)):
+                rows = np.concatenate([np.asarray(pages[1].astype(f32))[at], np.asarray(new[b].astype(f32))[None]])
+                dst.reshape(B, pp * ps, Hkv, D)[b, : len(rows)] = rows
+            lens.append(len(seen) + 1)
+        want = reference.paged_decode_attention(
+            q.astype(f32), jnp.asarray(lin[0]), jnp.asarray(lin[1]),
+            jnp.arange(B * pp, dtype=jnp.int32).reshape(B, pp), jnp.asarray(lens, jnp.int32),
+        )
+        np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(want), atol=3e-2, err_msg=case)
+
+    def test_a_ring_takes_both_inputs_and_no_int8_pages(self, jax, jnp):
+        from modal_examples_tpu.ops import paged_decode_attention_ragged, quantize_kv
+
+        q = jnp.zeros((2, 8, 128), jnp.bfloat16)
+        kp = jnp.zeros((1, 9, 16, 4, 128), jnp.bfloat16)
+        new = jnp.zeros((2, 4, 128), jnp.bfloat16)
+        pt, lens = jnp.zeros((2, 4), jnp.int32), jnp.zeros((2,), jnp.int32)
+        with pytest.raises(ValueError, match="together"):
+            paged_decode_attention_ragged(q, kp, kp, jnp.int32(0), pt, lens, new, new, starts=lens)
+        with pytest.raises(NotImplementedError, match="int8"):
+            paged_decode_attention_ragged(
+                q, quantize_kv(kp), quantize_kv(kp), jnp.int32(0), pt, lens, new, new,
+                first_pages=lens, starts=lens,
+            )
 
     def test_mha_group_of_one(self, jax, jnp):
         """Hq == Hkv (a group of one) through both decode attentions
@@ -638,6 +721,127 @@ class TestPagedImplOption:
                 or (isinstance(n, ast.Name) and n.id in ("environ", "getenv"))
             ]
             assert not reads, reads
+
+
+class TestWhatThe4HeadFormLeftAlone:
+    """PR 42 gave the ragged kernel a ring's first page and ``starts`` and
+    let 4 K/V heads take the all-heads form; what ran before runs as it did:
+    the 8-K/V-head call, the sizes, the other configurations' cache leaves
+    and the plans of the families that look like 4 heads of 128 and are not
+    (pinned to the parent commit ac1c821, PR 41)."""
+
+    #: sha256 (16 hex) of the lowered text of the call below at the parent
+    PARENT_CALL = {"flat": "989899418ecc2cf8", "grouped": "60347f364921d23c"}
+
+    @pytest.mark.parametrize("variant", ["flat", "grouped"])
+    def test_the_8_kv_head_call_lowers_to_the_parents_text(self, jax, jnp, variant):
+        """Mistral's shapes (16 slots, 32 heads over 8 K/V heads of 128,
+        pages of 16, a 256-page table, bf16), the ring's inputs unset: no
+        row is padded, no scalar added, the kernel's body is the parent's."""
+        import hashlib
+
+        from modal_examples_tpu.ops import paged_decode_attention_ragged
+
+        S, bf16 = jax.ShapeDtypeStruct, jnp.bfloat16
+        text = jax.jit(
+            lambda q, kp, vp, pt, lens, kn, vn: paged_decode_attention_ragged(
+                q, kp, vp, jnp.int32(1), pt, lens, kn, vn, variant=variant
+            )
+        ).lower(
+            S((16, 32, 128), bf16), S((2, 96, 16, 8, 128), bf16), S((2, 96, 16, 8, 128), bf16),
+            S((16, 256), jnp.int32), S((16,), jnp.int32), S((16, 8, 128), bf16), S((16, 8, 128), bf16),
+        ).as_text()
+        assert hashlib.sha256(text.encode()).hexdigest()[:16] == self.PARENT_CALL[variant]
+
+    @pytest.mark.parametrize(
+        "args, want",
+        [
+            (("flat", 16, 8, 128, 2, 256), (16, 16)),  # Mistral, Mixtral: bf16 pages
+            (("flat", 16, 8, 128, 1, 256), (32, 16)),  # ... int8 pages
+            (("grouped", 16, 8, 128, 2, 256), (16, 8)),
+            (("grouped", 16, 2, 128, 2, 256), (64, 8)),  # a head shard under tensor parallelism
+            # SmallThinker's, derived by the same rule: 2048 logit columns an
+            # update = 32 pages at 4 heads, a 2 MiB ring = 32 pages a half
+            (("flat", 16, 4, 128, 2, 257), (32, 32)),
+            (("flat", 16, 4, 128, 2, 512), (32, 32)),
+        ],
+    )
+    def test_the_kernels_sizes_come_from_the_shapes(self, args, want):
+        from modal_examples_tpu.ops import ragged_kernel_sizes
+
+        assert ragged_kernel_sizes(*args) == want
+
+    def test_the_default_variant_and_where_the_flat_view_is_free(self):
+        from modal_examples_tpu.ops.paged_attention import (
+            FLAT_VARIANT_HKV_MULTIPLE, flat_view_is_free, ragged_variant_for,
+        )
+
+        assert FLAT_VARIANT_HKV_MULTIPLE == 8
+        assert [ragged_variant_for(n) for n in (8, 16, 32)] == ["flat"] * 3
+        # a 4-head shard under tensor parallelism keeps what it had; a model
+        # of 4 K/V heads on one chip asks for "flat" by name (its plan)
+        assert [ragged_variant_for(n) for n in (1, 2, 4, 12)] == ["grouped"] * 4
+        assert [n for n in range(1, 33) if flat_view_is_free(n)] == [4, 8, 16, 24, 32]
+
+    #: [k_pages, v_pages, *beside] at 3 pages of 16 and one slot, bf16
+    PARENT_LEAVES = {
+        "mistral-7b-int8": ("llama.LlamaConfig", [[32, 3, 16, 8, 128]] * 2),
+        "mixtral-8x7b-int8-1chip": ("llama.LlamaConfig", [[7, 3, 16, 8, 128]] * 2),
+        "deepseek-v2-int8-ep4": (
+            "deepseek_v2.DeepseekV2Config", [[8, 3, 16, 1, 512], [8, 3, 16, 1, 64]]),
+        "glm-5.2-int8-ep16": (
+            "glm_dsa.GlmDsaConfig", [[8, 3, 16, 1, 512], [8, 3, 16, 1, 64], [2, 3, 16, 1, 128]]),
+        "granite-4.0-h-micro-bf16": (
+            "granite_hybrid.GraniteHybridConfig",
+            [[4, 3, 16, 4, 128], [4, 3, 16, 4, 128], [36, 1, 64, 64, 128], [36, 1, 3, 4352]]),
+        "lfm2-24b-a2b-int8-1chip": (
+            "lfm2.Lfm2Config", [[4, 3, 16, 4, 128], [4, 3, 16, 4, 128], [14, 1, 2, 2048]]),
+    }
+
+    @staticmethod
+    def _config(name, where):
+        import importlib
+
+        module, cls = where.split(".")
+        module = importlib.import_module(f"modal_examples_tpu.models.{module}")
+        return module, getattr(module, cls).from_hf_config(f"benchmarks/serving/configs/{name}.json")
+
+    @pytest.mark.parametrize("name", sorted(PARENT_LEAVES))
+    def test_the_other_configurations_cache_leaves_are_the_parents(self, jnp, name):
+        """The free view needed no new leaf shape, so ``serving/kv_cache.py``
+        is the parent's and every other configuration of the benchmark gets
+        the leaves it had."""
+        from modal_examples_tpu.serving.kv_cache import PagedKVCache
+
+        where, want = self.PARENT_LEAVES[name]
+        _, cfg = self._config(name, where)
+        cache = PagedKVCache.create(
+            n_layers=getattr(cfg, "n_cache_layers", cfg.n_layers), leaf_shapes=cfg.cache_leaf_shapes,
+            leaf_layers=getattr(cfg, "cache_leaf_layers", None), n_pages=3, page_size=16,
+            kv_dtype=jnp.bfloat16, state_leaves=getattr(cfg, "state_leaves", ()), max_slots=1,
+            window_group=getattr(cfg, "window_group", None), prefer_native=False,
+        )
+        assert [list(a.shape) for a in (cache.k_pages, cache.v_pages, *cache.beside)] == want
+
+    @pytest.mark.parametrize("backend", ["cpu", "tpu"])
+    @pytest.mark.parametrize("name", ["granite-4.0-h-micro-bf16", "lfm2-24b-a2b-int8-1chip"])
+    def test_heads_of_64_folded_into_rows_of_128_keep_the_loop(self, monkeypatch, jax, name, backend):
+        """Granite's and LFM2's pages are ``[16, 4, 128]`` too, but a row is
+        two heads of 64: the plan is the model's, and theirs is the loop."""
+        module, cfg = self._config(name, self.PARENT_LEAVES[name][0])
+        monkeypatch.setattr(jax, "default_backend", lambda: backend)
+        kw = {"expert_dtype": "int8"} if name.startswith("lfm2") else {}
+        plan = module.paged_impl_plan(cfg, 16, **kw)
+        want = {
+            "attention": "xla-gather", "ragged_variant": None, "scatter": "xla",
+            "kv_dtype": "bfloat16", "tp": 1, "downgraded": [],
+            "state_step": "pallas" if backend == "tpu" and name.startswith("granite") else "xla",
+        }
+        if name.startswith("lfm2"):
+            want["expert_scan"] = "pallas" if backend == "tpu" else "xla"
+        assert plan == want
+        with pytest.raises(NotImplementedError, match="Pallas paged_impl"):
+            module.paged_impl_plan(cfg, 16, "pallas")
 
 
 class TestChunkedDecodeAttention:

@@ -182,7 +182,7 @@ class _Served:
     call or by chunk calls at run-time offsets, tokens are fed to the decode
     step, and the logits come back row by row."""
 
-    def __init__(self, jax, L, cfg, params, slots=4, **cache_kw):
+    def __init__(self, jax, L, cfg, params, slots=4, impl=None, **cache_kw):
         import jax.numpy as jnp
 
         self.jax, self.jnp, self.L, self.cfg, self.params = jax, jnp, L, cfg, params
@@ -194,7 +194,7 @@ class _Served:
         self.active = np.zeros((slots,), bool)
         self._decode = jax.jit(
             lambda p, tok, pos, kp, vp, tab, act, st, wt: L.decode_step(
-                p, tok, pos, kp, vp, tab, act, cfg, state=st, window_tables=wt
+                p, tok, pos, kp, vp, tab, act, cfg, impl=impl, state=st, window_tables=wt
             )
         )
         self._chunk = jax.jit(
@@ -271,17 +271,20 @@ def _case(seed, lengths, n):
     return prompts, [rng.integers(3, 512, size=n).tolist() for _ in prompts]
 
 
-def test_bucketed_prefill_then_three_windows_of_decode_is_the_references_full_pass(jax, L, ref, model):
+@pytest.mark.parametrize("impl", [None, "pallas"], ids=["the-loop", "the-kernel"])
+def test_bucketed_prefill_then_three_windows_of_decode_is_the_references_full_pass(jax, L, ref, model, impl):
     """Two requests in one bucket call of 64 rows, one shorter than the
     window (21) and one longer than a ring holds (50 > 40: the call keeps
     only its last five pages in the window group), then 100 decode steps,
     three windows' worth: every sequence's ring turns at least twice (the
     pages a sequence holds never pass ``RING``), slots 2 and 3 idle. At
     every served position the logits are the reference's over prompt + fed
-    tokens."""
+    tokens: through the chunked loop (what the CPU's plan picks), and
+    through the ragged kernel's all-heads form in both page groups (the
+    interpreter here), a ring read from its first live page with a wrap."""
     cfg, params = model
     prompts, feed = _case(1, (21, 50), 100)
-    served = _Served(jax, L, cfg, params)
+    served = _Served(jax, L, cfg, params, impl=impl)
     for slot, p in enumerate(prompts):
         served.admit(slot, len(p) + 100)
     first = served.bucket(prompts, 64)
@@ -523,36 +526,57 @@ def test_the_loop_over_a_ring_is_a_masked_softmax_over_the_window(jax):
     assert "mtpu.window_attention" in text and "mtpu.page_gather" not in text
 
 
-def test_the_plan_names_the_one_form_and_refuses_another(jax, L, model):
-    """The chunked loop in both page groups, on the CPU and on a TPU, at the
-    preset's heads and at the published 4 K/V heads of 128; a Pallas
-    ``paged_impl`` or scatter is refused by name, by the plan and where the
-    engine is built."""
+def test_the_plan_chooses_from_backend_and_shapes_and_refuses_another_scatter(jax, L, model):
+    """Unset, the plan picks: on a TPU at the published 4 K/V heads of 128,
+    pages of 16 and bf16 pages, the ragged kernel's ``flat`` form in both
+    page groups; the chunked loop on the CPU, at the preset's 16-wide heads,
+    at pages of 8 and for float32 pages. ``"xla"`` forces the loop,
+    ``"pallas"`` the kernel wherever it can run (the interpreter off the
+    chip) and is named in ``downgraded`` where the chip's shapes refuse it.
+    ``grouped`` is never the variant. A Pallas scatter is refused by name,
+    by the plan and where the engine is built."""
     from modal_examples_tpu.serving import LLMEngine
 
     cfg, params = model
     published = L.SmallThinkerConfig()
+    loop, kernel = ("xla-gather", "xla-gather-ring", None), ("ragged", "ragged-ring", "flat")
+
+    def forms(c, page, impl=None, **kw):
+        plan = L.paged_impl_plan(c, page, impl, warn=False, **kw)
+        return (plan["attention"], plan["window_attention"], plan["ragged_variant"]), plan["downgraded"]
+
     backend = jax.default_backend
-    for on in ("cpu", "tpu"):
-        jax.default_backend = lambda on=on: on
-        try:
-            for c, page in ((cfg, PAGE), (published, 16)):
-                for impl in (None, "xla"):
-                    plan = L.paged_impl_plan(c, page, impl)
-                    assert (plan["attention"], plan["window_attention"], plan["ragged_variant"]) == (
-                        "xla-gather", "xla-gather-ring", None)
-                    assert plan["downgraded"] == []
-        finally:
-            jax.default_backend = backend
+    try:
+        jax.default_backend = lambda: "cpu"
+        for c, page in ((cfg, PAGE), (published, 16)):
+            assert forms(c, page) == forms(c, page, "xla") == (loop, [])
+            assert forms(c, page, "pallas") == (kernel, [])  # the interpreter takes any shape
+        jax.default_backend = lambda: "tpu"
+        assert forms(published, 16) == forms(published, 16, "pallas") == (kernel, [])
+        assert forms(published, 16, "xla") == (loop, [])
+        eight_heads = L.SmallThinkerConfig(n_heads=32, n_kv_heads=8)
+        assert forms(eight_heads, 16) == (kernel, [])
+        for c, page, kw in (
+            (cfg, 16, {}),  # heads of 16
+            (published, 8, {}),  # half a tile of positions a page
+            (published, 16, {"kv_dtype": "float32"}),
+            (L.SmallThinkerConfig(n_heads=24, n_kv_heads=12), 16, {}),  # the view would be a copy
+            (L.SmallThinkerConfig(n_heads=28, n_kv_heads=2), 16, {}),  # not measured
+        ):
+            assert forms(c, page, **kw) == forms(c, page, "xla", **kw) == (loop, [])
+            got, downgraded = forms(c, page, "pallas", **kw)
+            assert got == loop and len(downgraded) == 1 and "xla-gather" in downgraded[0]
+    finally:
+        jax.default_backend = backend
     no_window = L.SmallThinkerConfig.tiny(window_layout=(0, 0), rope_layout=(0, 1))
     assert L.paged_impl_plan(no_window, PAGE)["window_attention"] is None
-    for kw in ({"impl": "pallas"}, {"scatter_impl": "pallas"}):
-        with pytest.raises(NotImplementedError, match="Pallas paged_impl"):
-            L.paged_impl_plan(cfg, PAGE, **kw)
-    with pytest.raises(NotImplementedError, match="Pallas paged_impl"):
+    assert L.paged_impl_plan(no_window, PAGE, "pallas")["window_attention"] is None
+    with pytest.raises(NotImplementedError, match="Pallas scatter_impl"):
+        L.paged_impl_plan(cfg, PAGE, scatter_impl="pallas")
+    with pytest.raises(NotImplementedError, match="Pallas scatter_impl"):
         LLMEngine(
             cfg, params, max_slots=2, page_size=PAGE, max_model_len=64, prefill_buckets=(32,),
-            enable_prefix_cache=False, paged_impl="pallas",
+            enable_prefix_cache=False, scatter_impl="pallas",
         )
 
 
@@ -671,6 +695,65 @@ def test_the_engine_serves_the_references_first_choice_past_the_window_and_reuse
     assert moved["live", "window"] < moved["live", "global"]
     assert moved["table", "window"] == moved["table", "global"] * RING / (192 // PAGE)
     assert _metric(C.DECODE_KV_POSITIONS_TOTAL, kind="read") == unlabelled0  # none without the label
+
+
+def test_the_engine_serves_the_same_tokens_through_the_kernel_and_counts_what_it_reads(jax, model):
+    """``paged_impl="pallas"`` (what the plan picks unasked on a TPU at the
+    published shapes; the interpreter here): the plan and the engine name
+    the kernel in both page groups, greedy answers of 44 tokens past the
+    window (three requests over two slots, decode blocks of 4) are the
+    loop's token for token, and ``mtpu_decode_kv_positions_total{kind=
+    "read"}`` counts, for both ``layers``, each live slot's own live pages
+    step by step (``ragged_pages_read``): a global layer's from the
+    context's first page, a window layer's from the first page its window
+    reaches; nothing for a dead slot, nothing rounded up to a neighbour."""
+    import jax.numpy as jnp
+
+    from modal_examples_tpu.observability import catalog as C
+    from modal_examples_tpu.ops import ragged_pages_read, window_decode_span
+    from modal_examples_tpu.serving import LLMEngine, SamplingParams
+
+    cfg, params = model
+    texts = ["hello window", "a second, somewhat longer, tenant of a slot: past the window soon", "third"]
+    served = {}
+    for impl in (None, "pallas"):
+        eng = LLMEngine(
+            cfg, params, max_slots=2, page_size=PAGE, max_model_len=192, prefill_buckets=(16, 32),
+            prefill_batch=2, kv_dtype=jnp.float32, enable_prefix_cache=False, decode_block=4, seed=0,
+            paged_impl=impl,
+        )
+        want = ("ragged", "ragged-ring", "flat") if impl else ("xla-gather", "xla-gather-ring", None)
+        assert tuple(eng.impl_plan[k] for k in ("attention", "window_attention", "ragged_variant")) == want
+        eng.start()
+        try:
+            reqs = [eng.submit(t, SamplingParams(max_tokens=44, temperature=0.0)) for t in texts]
+            for req in reqs:
+                "".join(eng.stream(req))
+        finally:
+            eng.stop()
+        served[impl] = [list(r.generated_tokens) for r in reqs]
+    assert all(len(t) == 44 for t in served[None]) and served["pallas"] == served[None]
+    # the count, on the engine that served through the kernel: a block of 4
+    # steps over a slot in its ring's first turn, one several turns on, a dead one
+    eng.max_slots = 3  # only the loop's count reads it
+    positions, active, steps = np.array([35, 150, 77], np.int64), np.array([True, True, False]), 4
+    before = {
+        layers: _metric(C.DECODE_KV_POSITIONS_TOTAL, kind="read", layers=layers)
+        for layers in ("global", "window")
+    }
+    eng._count_decode_kv(positions, active, steps)
+    read = {layers: _metric(C.DECODE_KV_POSITIONS_TOTAL, kind="read", layers=layers) - was
+            for layers, was in before.items()}
+    want = {"global": 0, "window": 0}
+    for t in (p + j for p in positions[active] for j in range(steps)):
+        oldest = max(t // PAGE - (RING - 1), 0)  # the first page the window can reach
+        want["global"] += -(-t // PAGE) * PAGE
+        want["window"] += -(-(t - oldest * PAGE) // PAGE) * PAGE
+    assert read == want
+    at = (positions[active][:, None] + np.arange(steps)).reshape(-1)
+    _, lens, _ = window_decode_span(at, WINDOW, PAGE, RING)
+    assert want["window"] == int(ragged_pages_read(lens, PAGE).sum()) * PAGE
+    assert want["global"] == int(ragged_pages_read(at, PAGE).sum()) * PAGE
 
 
 # -- the models there were get the programs they had ------------------------------------------------

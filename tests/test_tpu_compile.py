@@ -770,11 +770,16 @@ def test_smallthinker_decode_block_reads_both_page_groups_in_place_on_a_v5e(smal
     """The 32-slot decode block beside 6.3 GiB of weights and 5 GiB of pages
     in two groups: both groups aliased in and out, no temporary a copy of a
     paged leaf or of a layer's experts (``[64, 2560, 768]`` int8 is 126 MB a
-    matrix). The scan's body is one period of four layers: four grouped
-    matmuls under ``mtpu.expert_scan`` and no other kernel, the attention
-    the chunked loop in both groups; the global layer's gathers sit under
-    ``mtpu.page_gather``, the window layers' under ``mtpu.window_attention``
-    with their scores."""
+    matrix). With nothing set the plan picks the ragged kernel's all-heads
+    form for 4 K/V heads of 128 on the chip, in both groups (PR 42): the
+    scan's body is one period of four layers with eight Mosaic calls, four
+    grouped matmuls under ``mtpu.expert_scan``, one attention under
+    ``mtpu.attention`` and three under ``mtpu.window_attention``. **The
+    view is free**: a leaf ``[L, P, 16, 4, 128]`` (tiles of 4 rows) reaches
+    the kernel as ``[L, P, 64, 128]`` rows (tiles of 8) by a ``bitcast``,
+    no ``copy`` of either shape; the loop's gathered chunk ``[512 = 32 slots
+    x 16 pages, 16, 4, 128]`` is gone with its gathers, and nothing of the
+    block sits under ``mtpu.page_gather``."""
     compiled = smallthinker["block"]()
     mem = compiled.memory_analysis()
     assert 6.2 * GIB < smallthinker["weight_bytes"] < 6.4 * GIB  # 6.7 GB
@@ -783,15 +788,22 @@ def test_smallthinker_decode_block_reads_both_page_groups_in_place_on_a_v5e(smal
     assert mem.temp_size_in_bytes < 0.25 * GIB
     assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes) < 11.6 * GIB
     text = compiled.as_text()
-    for leaf in ("bf16[4,16384,16,4,128]", "bf16[12,8225,16,4,128]"):
+    for leaf, rows in (
+        ("bf16[4,16384,16,4,128]", "bf16[4,16384,64,128]"),
+        ("bf16[12,8225,16,4,128]", "bf16[12,8225,64,128]"),
+    ):
         assert leaf in text and f" copy({leaf}" not in text
+        assert not _relaid_out(text, leaf) and not _relaid_out(text, rows)
+        assert re.search(re.escape(rows) + r"\{[^}]*T\(8,128\)\(2,1\)\} bitcast\(", text)
+    assert "bf16[512,16,4,128]" not in text  # the loop's gathered chunk
     assert "s8[16,64,2560,768]" in text  # the whole stack, an argument
     assert not re.search(r"s8\[(1,)?64,2560,768\]", text)  # never a layer's slice of it
-    kernels = _kernel_scopes(text)
-    assert len(kernels) == 4 and all(k.endswith("mtpu.expert_scan/pallas_call") for k in kernels)
-    assert "mtpu.window_attention" in text and "mtpu.attention" in text
-    assert "mtpu.attention/while/body/mtpu.page_gather" in text
-    assert "mtpu.window_attention/while/body/mtpu.page_gather" not in text
+    kernels = [k.rpartition("closed_call/")[2] for k in _kernel_scopes(text)]
+    assert sorted(kernels) == sorted(
+        ["mtpu.expert_scan/pallas_call"] * 4 + ["mtpu.attention/pallas_call"]
+        + ["mtpu.window_attention/pallas_call"] * 3
+    )
+    assert "mtpu.page_gather" not in text
 
 
 def test_smallthinker_third_chunk_call_compiles_under_the_window_on_a_v5e(smallthinker):
