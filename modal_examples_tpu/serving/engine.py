@@ -236,7 +236,7 @@ class _PendingPrefill:
     offset: int = 0  # token offset of the NEXT chunk to dispatch
     ticks: int = 0  # scheduler ticks that dispatched at least one chunk
     suspensions: int = 0  # times the budget paused this prefill mid-prompt
-    logits: object | None = None  # last dispatched chunk's logits (device)
+    first_token: object | None = None  # last dispatched chunk's sampled token (device)
 
 
 class _NgramIndex:
@@ -846,6 +846,15 @@ class LLMEngine:
         # (counters take deltas; EngineStats holds the running totals)
         self._counter_flush = {"prompt": 0, "generated": 0, "steps": 0}
         self._key = jax.random.PRNGKey(seed)
+        # the sampler's arguments, less the key, of a chunk call whose token
+        # nobody reads (_dispatch_prefill_chunk): greedy and unfiltered, so
+        # sample()'s sorts stay off at run time. Put on the device once, from
+        # numpy: no program is built for them
+        self._unread_sampler_args = jax.device_put((
+            np.zeros((1,), np.float32), np.ones((1,), np.float32),
+            np.zeros((1,), np.int32), np.full((1,), -1, np.int32),
+            np.zeros((1,), np.int32),
+        ))
         self._seed_base = int(seed)
         self._submit_seq = 0  # feeds auto_seed: deterministic per submission
         self._lock = threading.Lock()
@@ -1364,8 +1373,9 @@ class LLMEngine:
             runtime = self._runtime_offset
 
             def prefill_chunk(
-                params, toks, k_pages, v_pages, tables, lens, state=(),
-                slot_ids=None, q_offset=None, window_tables=None, *, cfg,
+                params, toks, k_pages, v_pages, tables, lens, key, temps,
+                top_ps, top_ks, seeds, step_ids, state=(), slot_ids=None,
+                q_offset=None, window_tables=None, *, cfg,
             ):
                 # cfg is the target's or the draft's: each names its module
                 stateful = {"state": state, "slot_ids": slot_ids} if state else {}
@@ -1382,7 +1392,14 @@ class LLMEngine:
                     ),
                     bool(state),
                 )
-                return logits, k_pages, v_pages, state
+                # every chunk samples, as _prefill_and_sample does: the
+                # token of a prompt's last chunk is its first token, any
+                # other chunk's is dropped (one [1, V] row)
+                next_tokens = sample(
+                    logits, key, temps, top_ps, top_ks, seeds=seeds,
+                    step_ids=step_ids,
+                )
+                return next_tokens, k_pages, v_pages, state
 
             # the compiled program's name in a device trace: one per offset
             prefill_chunk.__name__ = (
@@ -1430,6 +1447,10 @@ class LLMEngine:
         def i32(*shape):
             return jax.ShapeDtypeStruct(shape, jnp.int32)
 
+        sampler = [
+            jax.ShapeDtypeStruct(a.shape, a.dtype)
+            for a in (self._key, *self._unread_sampler_args)
+        ]
         at = {"q_offset": i32()} if self._runtime_offset else {}
         models = [(
             False, "prefill_chunk", self._chunk_shape_key(offset), self.cfg,
@@ -1451,7 +1472,8 @@ class LLMEngine:
                 with self._chunk_lowering:
                     lowered = fn.lower(
                         params, i32(1, width), *pages,
-                        i32(1, self.pages_per_slot), i32(1), **state, cfg=cfg,
+                        i32(1, self.pages_per_slot), i32(1), *sampler,
+                        **state, cfg=cfg,
                     )
                 return lowered.compile()
 
@@ -3245,20 +3267,26 @@ class LLMEngine:
             self._spec_ctrl.forget(slot.request.request_id)
 
     def _dispatch_prefill_chunk(
-        self, prompt_tokens: list, table, offset: int, cached: int = 0,
-        slot_idx: int | None = None,
+        self, req: Request, table, offset: int, slot_idx: int | None = None,
     ) -> "jax.Array":
-        """Dispatch ONE prefill chunk (async — the logits come back as a
-        device future, nothing blocks the host): the unit both the atomic
-        loop (``_run_prefill_chunks``) and the budgeted state machine
-        (``_advance_pending_prefills``) advance by, so the two paths can
-        never drift. The chunk is as wide as the bucket that holds what is
-        left of the prompt from ``offset`` (``_chunk_width``), the draft
-        model's beside it. ``cached`` is how many leading prompt tokens
-        sit on cached pages (computed again all the same: the count at the
+        """Dispatch ONE prefill chunk of ``req``'s prompt (async — the
+        sampled token comes back as a device future, nothing blocks the
+        host): the unit both the atomic loop (``_run_prefill_chunks``) and
+        the budgeted state machine (``_advance_pending_prefills``) advance
+        by, so the two paths can never drift. The chunk is as wide as the
+        bucket that holds what is left of the prompt from ``offset``
+        (``_chunk_width``), the draft model's beside it. The program samples
+        (``_chunk_jit``): the prompt's last chunk with the request's
+        parameters, its (seed, position) and the one key a chunked prompt
+        draws from the engine's stream, so its token is the request's first
+        however the budget sliced the prompt; an earlier chunk with
+        ``_unread_sampler_args`` and the stream's head unsplit, and its token
+        is nobody's. ``req.cached_prompt_tokens`` leading prompt tokens sit
+        on cached pages (computed again all the same: the count at the
         prefill boundary says so). ``slot_idx``: the slot the prompt fills; a
         model with per-slot state starts the chunk at offset 0 from zeros and
         a later one from what the chunk before it left in that slot."""
+        prompt_tokens, cached = req.prompt_tokens, req.cached_prompt_tokens
         key = self._chunk_key(offset)
         width = self._chunk_width(key, len(prompt_tokens) - offset)
         pad_tok = self.tokenizer.pad_id % self.cfg.vocab_size
@@ -3274,8 +3302,23 @@ class LLMEngine:
             _obs.record_kv_window_pages_recycled(
                 self.cache.window.recycled(offset, offset + len(chunk))
             )
+        unread = (self._key, *self._unread_sampler_args)
+        if offset + len(chunk) < len(prompt_tokens):
+            sampler = unread
+        else:
+            p = req.params
+            # numpy arrays of the program's own types: a list handed to
+            # jnp.asarray is converted by a program of its own on the device
+            sampler = (
+                self._next_key(),
+                np.asarray([p.temperature], np.float32),
+                np.asarray([p.top_p], np.float32),
+                np.asarray([p.top_k], np.int32),
+                np.asarray([_req_seed(req)], np.int32),
+                np.asarray([len(prompt_tokens)], np.int32),
+            )
         (
-            logits, self.cache.k_pages, self.cache.v_pages, self.cache.beside,
+            token, self.cache.k_pages, self.cache.v_pages, self.cache.beside,
         ) = self._profiled(
             "prefill_chunk", f"{self._chunk_shape_key(key)}w{width}",
             self._chunk_program(key, width),
@@ -3285,9 +3328,10 @@ class LLMEngine:
             self.cache.k_pages,
             self.cache.v_pages,
             jnp.asarray(table[None, :]),
-            jnp.asarray([len(chunk)], np.int32),
+            np.asarray([len(chunk)], np.int32),
+            *sampler,
             **self._state_args([] if slot_idx is None else [slot_idx], 1),
-            **({"q_offset": jnp.int32(offset)} if self._runtime_offset else {}),
+            **({"q_offset": np.int32(offset)} if self._runtime_offset else {}),
         )
         if self.spec_mode == "draft":
             (
@@ -3301,25 +3345,21 @@ class LLMEngine:
                 self.draft_cache.k_pages,
                 self.draft_cache.v_pages,
                 jnp.asarray(table[None, :]),
-                jnp.asarray([len(chunk)], np.int32),
+                np.asarray([len(chunk)], np.int32),
+                *unread,  # the draft proposes from the target's first token
             )
-        return logits
+        return token
 
-    def _run_prefill_chunks(
-        self, prompt_tokens: list, table, cached: int = 0
-    ) -> "jax.Array":
+    def _run_prefill_chunks(self, req: Request, table) -> "jax.Array":
         """The atomic chunked-prefill loop (every chunk in one call), used
         by the slot-free disagg path (``_prefill_pages``) — the slot path
         runs the same chunks through the resumable state machine instead.
-        Returns the final chunk's last-token logits."""
-        n_prompt = len(prompt_tokens)
+        Returns the final chunk's sampled token, the request's first."""
         C = self.prefill_buckets[-1]
-        logits = None
-        for offset in range(0, n_prompt, C):
-            logits = self._dispatch_prefill_chunk(
-                prompt_tokens, table, offset, cached
-            )
-        return logits
+        token = None
+        for offset in range(0, len(req.prompt_tokens), C):
+            token = self._dispatch_prefill_chunk(req, table, offset)
+        return token
 
     def _prefill_pages(self, req: Request, claim: dict) -> int:
         """Fill ``claim``'s pages with ``req``'s prompt KV and sample the
@@ -3332,21 +3372,7 @@ class LLMEngine:
         table[: len(pages)] = pages
         p = req.params
         if n_prompt > self.prefill_buckets[-1]:
-            logits = self._run_prefill_chunks(
-                req.prompt_tokens, table, req.cached_prompt_tokens
-            )
-            # the ops-level first-token helper: eager sample() builds its
-            # own small compiled programs — report them through the same
-            # chokepoint as the big jits
-            first = self._profiled("sample", "first_token", sample)(
-                logits,
-                self._next_key(),
-                jnp.asarray([p.temperature], np.float32),
-                jnp.asarray([p.top_p], np.float32),
-                jnp.asarray([p.top_k], np.int32),
-                seeds=jnp.asarray([_req_seed(req)], np.int32),
-                step_ids=jnp.asarray([n_prompt], np.int32),
-            )
+            first = self._run_prefill_chunks(req, table)
             t0 = self._harvest_begin()
             first = int(np.asarray(first)[0])
             self._harvested(self._dispatch_seq(), "prefill", t0)
@@ -3451,9 +3477,8 @@ class LLMEngine:
                 while pp.offset < n_prompt and (
                     budget is None or spent == 0 or spent < budget
                 ):
-                    pp.logits = self._dispatch_prefill_chunk(
-                        pp.req.prompt_tokens, pp.table, pp.offset,
-                        pp.req.cached_prompt_tokens, slot_idx=i,
+                    pp.first_token = self._dispatch_prefill_chunk(
+                        pp.req, pp.table, pp.offset, slot_idx=i,
                     )
                     step = min(C, n_prompt - pp.offset)
                     pp.offset += step
@@ -3482,26 +3507,17 @@ class LLMEngine:
     def _finish_sliced_prefill(
         self, slot_idx: int, slot: _Slot, pp: _PendingPrefill
     ) -> None:
-        """Every chunk dispatched: sample the first token (async, seeded by
-        (request seed, position) so slicing can never change it) and park
-        it on the harvest queue — the blocking read happens after the next
-        decode dispatch, exactly like a grouped prefill's."""
+        """Every chunk dispatched: park the first token, which the last
+        chunk's program sampled (seeded by (request seed, position), so
+        slicing can never change it), on the harvest queue — the blocking
+        read happens after the next decode dispatch, exactly like a grouped
+        prefill's."""
         req = pp.req
-        p = req.params
         n_prompt = len(req.prompt_tokens)
-        first = self._profiled("sample", "first_token", sample)(
-            pp.logits,
-            self._next_key(),
-            jnp.asarray([p.temperature], np.float32),
-            jnp.asarray([p.top_p], np.float32),
-            jnp.asarray([p.top_k], np.int32),
-            seeds=jnp.asarray([_req_seed(req)], np.int32),
-            step_ids=jnp.asarray([n_prompt], np.int32),
-        )
         slot.prefill = None
         slot.pending_first = True
         self._pending_harvest.append((
-            first,
+            pp.first_token,
             [(slot_idx, req, 0, n_prompt, slot.tenancy)],
             {
                 "seq": self._dispatch_seq(),

@@ -148,8 +148,14 @@ def check(eng, case: str, monkeypatch) -> None:
     # the atomic loop of the slot-free path goes through the same unit
     import numpy as np
 
+    from modal_examples_tpu.serving import SamplingParams
+    from modal_examples_tpu.serving.engine import Request
+
     with dispatched(eng) as seen:
-        eng._run_prefill_chunks(prompt, np.zeros((eng.pages_per_slot,), np.int32))
+        eng._run_prefill_chunks(
+            Request("", SamplingParams(temperature=0.0), prompt_tokens=prompt),
+            np.zeros((eng.pages_per_slot,), np.int32),
+        )
     assert _chunk_keys(seen) == keys
 
     # nothing was built after the first chunked request

@@ -59,7 +59,8 @@ def hashes(family: str) -> dict:
                 f32(2), i32(2), i32(2), **eng._state_args([0, 1], 2),
             ).as_text(),
             "chunk": eng._chunk_jit(16).lower(
-                eng.params, i32(1, 16), kp, vp, i32(1, pp), i32(1), **eng._state_args([0], 1),
+                eng.params, i32(1, 16), kp, vp, i32(1, pp), i32(1), eng._next_key(), f32(1),
+                f32(1), i32(1), i32(1), i32(1), **eng._state_args([0], 1),
                 **({"q_offset": jnp.int32(16)} if eng._runtime_offset else {}), cfg=cfg,
             ).as_text(),
         }

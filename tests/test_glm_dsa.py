@@ -636,14 +636,16 @@ def test_the_engines_counters_count_what_was_scored_selected_and_attended(jax, G
 #: ``decode_step`` and ``block`` are PR 37's: the state step's XLA form moved
 #: into ``ops/ssm_step.py::ssm_step_xla``, the same operations traced in
 #: another order (9f3ed3041dcbd8e9 and 0f55fd87662ffe27 before it); its
-#: prefill programs and the other two families' four are still PR 33's
+#: bucketed prefill call and the other two families' decode step, block and
+#: bucket are still PR 33's; every ``chunk`` is PR 43's, whose chunk program
+#: samples (``tests/test_chunk_sampler.py`` holds its token to the eager call's)
 PARENT_PROGRAMS = {
     "llama": {"decode_step": "e1811a7588348e55", "block": "b9e8a274d64690dc",
-              "bucket": "83033cbdc7920805", "chunk": "863bab3800d298dc"},
+              "bucket": "83033cbdc7920805", "chunk": "75df27ad29b5c8a0"},
     "deepseek_v2": {"decode_step": "47f2d3be0ccb4262", "block": "24a13b4726bd7df2",
-                    "bucket": "fa5a05d32ed93dcd", "chunk": "531addcb5c7cc685"},
+                    "bucket": "fa5a05d32ed93dcd", "chunk": "5ad332426a8b933e"},
     "granite_hybrid": {"decode_step": "d50c82c1cfe7adba", "block": "a8bbe663d06cdbb1",
-                       "bucket": "3f6c0ce6b17158e7", "chunk": "bd4108580f0db85d"},
+                       "bucket": "3f6c0ce6b17158e7", "chunk": "713a762fa06a0fd9"},
 }
 
 

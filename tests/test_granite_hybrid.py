@@ -665,9 +665,10 @@ def test_a_model_without_per_slot_state_gets_none_and_no_new_program_arguments(j
         assert len(jax.tree.leaves(block.in_avals)) == n_params + 2 + 11
         chunk = eng._chunk_jit(16).lower(
             eng.params, i32(1, 16), eng.cache.k_pages, eng.cache.v_pages, i32(1, pp), i32(1),
+            eng._next_key(), f32(1), f32(1), i32(1), i32(1), i32(1),
             **eng._state_args([0], 1), cfg=cfg,
         )
-        assert len(jax.tree.leaves(chunk.in_avals)) == n_params + 1 + 2 + 2
+        assert len(jax.tree.leaves(chunk.in_avals)) == n_params + 1 + 2 + 2 + 6
         assert "ssm" not in block.as_text() and "ssm" not in chunk.as_text()
     finally:
         eng.stop()

@@ -739,16 +739,17 @@ def test_load_hf_weights_maps_the_published_names(jax, L, ref, model, tmp_path):
 
 #: sha256 (16 hex) of the lowered text at the parent commit (0ea440e, PR 37):
 #: ``python tests/lowered_text.py`` in a checkout of it (with this PR's
-#: ``lowered_text.py``, which knows GLM's run-time chunk offset)
+#: ``lowered_text.py``, which knows GLM's run-time chunk offset); every
+#: ``chunk`` is PR 43's, whose chunk program samples
 PARENT_PROGRAMS = {
     "llama": {"decode_step": "e1811a7588348e55", "block": "b9e8a274d64690dc",
-              "bucket": "83033cbdc7920805", "chunk": "863bab3800d298dc"},
+              "bucket": "83033cbdc7920805", "chunk": "75df27ad29b5c8a0"},
     "deepseek_v2": {"decode_step": "47f2d3be0ccb4262", "block": "24a13b4726bd7df2",
-                    "bucket": "fa5a05d32ed93dcd", "chunk": "531addcb5c7cc685"},
+                    "bucket": "fa5a05d32ed93dcd", "chunk": "5ad332426a8b933e"},
     "granite_hybrid": {"decode_step": "d50c82c1cfe7adba", "block": "a8bbe663d06cdbb1",
-                       "bucket": "3f6c0ce6b17158e7", "chunk": "bd4108580f0db85d"},
+                       "bucket": "3f6c0ce6b17158e7", "chunk": "713a762fa06a0fd9"},
     "glm_dsa": {"decode_step": "7298397e7c20935a", "block": "5e05375bd9521504",
-                "bucket": "2b463136643d0057", "chunk": "0be6a232c03ad624"},
+                "bucket": "2b463136643d0057", "chunk": "f9620f0ab5ca3191"},
 }
 
 

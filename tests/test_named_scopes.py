@@ -96,26 +96,64 @@ def test_the_expert_kernel_sits_under_expert_scan_and_the_layout_under_dispatch(
     assert f"tensor<{T * k + E * 15 + 1}x{engine.cfg.dim}xf32>" not in text  # ... and its buffer
 
 
-def test_chunk_program_is_named_and_scoped(engine):
+def _chunk_args(eng, width, **at):
+    """A chunk call's arguments: the chunk, the pages, the table, the
+    sampler's six, then what the family's program takes beside them."""
     import jax.numpy as jnp
 
+    one = lambda dtype: jnp.ones((1,), dtype)  # noqa: E731
+    return (
+        eng.params, jnp.zeros((1, width), jnp.int32),
+        eng.cache.k_pages, eng.cache.v_pages,
+        jnp.zeros((1, eng.pages_per_slot), jnp.int32), jnp.asarray([width], jnp.int32),
+        eng._next_key(), one(jnp.float32), one(jnp.float32), one(jnp.int32),
+        one(jnp.int32), one(jnp.int32),
+    ), {**eng._state_args([0], 1), **at, "cfg": eng.cfg}
+
+
+def test_chunk_program_is_named_and_scoped(engine):
     C = engine.prefill_buckets[-1]
-    name, text = _lowered(
-        engine._chunk_jit(C),
-        engine.params, jnp.zeros((1, C), jnp.int32),
-        engine.cache.k_pages, engine.cache.v_pages,
-        jnp.zeros((1, engine.pages_per_slot), jnp.int32),
-        jnp.asarray([C], jnp.int32), cfg=engine.cfg,
-    )
+    args, kwargs = _chunk_args(engine, C)
+    name, text = _lowered(engine._chunk_jit(C), *args, **kwargs)
     # one program per chunk offset, and "prefill" leads: the benchmark's
     # pattern for prefill programs (^jit_+prefill) finds it
     assert name == f"jit_prefill_chunk_off{C}"
     wanted = {
         scopes.PAGE_GATHER, scopes.ATTENTION, scopes.KV_SCATTER,
+        scopes.SAMPLING,  # the first token comes out of the last chunk's call
         *_mlp_scopes(engine),
     }
     found = set(re.findall(r"mtpu\.[a-z_]+", text))
     assert wanted <= found, wanted - found
+
+
+@pytest.mark.parametrize("family", ["glm_dsa", "smallthinker"])
+def test_a_run_time_offsets_chunk_program_is_named_for_its_prefix_and_samples(family):
+    """The chunk program of a family that takes the offset as an argument
+    (one program a prefix bucket): the name the benchmark's readers find
+    prefill programs by, and the sampler inside, as in the static form."""
+    import jax.numpy as jnp
+
+    from modal_examples_tpu.models import glm_dsa, smallthinker
+    from modal_examples_tpu.serving import LLMEngine
+
+    cfg = (
+        glm_dsa.GlmDsaConfig.tiny(n_held_experts=8, expert_offset=4) if family == "glm_dsa"
+        else smallthinker.SmallThinkerConfig.tiny()
+    )
+    eng = LLMEngine(
+        cfg, max_slots=2, page_size=8, max_model_len=64, prefill_buckets=(16,),
+        prefill_batch=2, decode_block=4, kv_dtype=jnp.bfloat16, enable_prefix_cache=False,
+    )
+    try:
+        assert eng._runtime_offset
+        prefix = eng._chunk_key(32)
+        args, kwargs = _chunk_args(eng, 16, q_offset=jnp.int32(32))
+        name, text = _lowered(eng._chunk_jit(prefix), *args, **kwargs)
+        assert name == f"jit_prefill_chunk_pre{prefix}"
+        assert scopes.SAMPLING in set(re.findall(r"mtpu\.[a-z_]+", text))
+    finally:
+        eng.stop()
 
 
 def test_bucket_prefill_program_is_named_and_scoped(engine):

@@ -600,6 +600,55 @@ class TestEngineIntegration:
         assert {"begin", "end"} <= {r["event"] for r in mine}
         assert not P.unfinished_builds(mine)
 
+    def test_a_chunked_prompt_builds_nothing_and_never_dispatches_the_sampler(self):
+        """Its first token comes out of the last chunk's program: once the
+        programs are built, a chunked prompt makes JAX trace, lower, compile
+        or read back nothing on the scheduler's thread (the eager sampler
+        traced its ``lax.cond`` anew at every call: 61 events a prompt), no
+        dispatch is of program ``sample``, and what follows the last chunk's
+        call is the decode block."""
+        import threading
+
+        import chunk_tail
+        import jax
+
+        from modal_examples_tpu.models import llama
+        from modal_examples_tpu.serving import LLMEngine
+
+        eng = LLMEngine(
+            llama.LlamaConfig.tiny(), max_slots=2, max_model_len=chunk_tail.MAX_MODEL_LEN,
+            prefill_buckets=chunk_tail.BUCKETS, profile=True,
+        )
+        eng.trace_name = f"chunked-{os.getpid()}"
+        n_prompt = 2 * chunk_tail.C + 1  # chunks at offsets 0, C and 2C
+        built, recording, me = [], [False], threading.get_ident()
+
+        def on_seconds(event, seconds, **_kw):  # registered for the life of the process
+            if recording[0] and threading.get_ident() == me and event in P.COMPILE_EVENT_KIND:
+                built.append(event)
+
+        jax.monitoring.register_event_duration_secs_listener(on_seconds)
+        try:
+            chunk_tail.warmed(eng)
+            recording[0] = True
+            with chunk_tail.dispatched(eng) as seen:
+                assert chunk_tail.serve(eng, n_prompt, seed=3)[1]
+        finally:
+            recording[0] = False
+            eng.stop()
+        programs = [program for program, _key in seen]
+        assert programs.count("prefill_chunk") == 3 and "sample" not in programs
+        last_chunk = max(i for i, p in enumerate(programs) if p == "prefill_chunk")
+        assert programs[last_chunk + 1] == "block"
+        assert built == []
+        from modal_examples_tpu.utils.prometheus import default_registry
+
+        registry = eng.profiler._registry or default_registry
+        assert not [
+            labels for name, labels, *_ in registry.all_series()
+            if name == C.COMPILE_PHASE_SECONDS_TOTAL and labels.get("program") == "sample"
+        ]
+
     def test_disabled_engine_has_no_profiler(self):
         from modal_examples_tpu.models import llama
         from modal_examples_tpu.serving import LLMEngine

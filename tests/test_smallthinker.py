@@ -761,12 +761,13 @@ def test_the_engine_serves_the_same_tokens_through_the_kernel_and_counts_what_it
 #: sha256 (16 hex) of the lowered text at the parent commit (4f653c1, PR 40):
 #: ``python tests/lowered_text.py`` in a checkout of it, with this PR's
 #: ``lowered_text.py`` (which knows Mixtral's tiny routed preset and LFM2).
-#: ``tests/test_lfm2.py`` holds the other four families to the same hashes
+#: ``tests/test_lfm2.py`` holds the other four families to the same hashes;
+#: both ``chunk`` entries are PR 43's, whose chunk program samples
 PARENT_PROGRAMS = {
     "llama_moe": {"decode_step": "e4d8c6df425d309c", "block": "f23a182c9d19eb6f",
-                  "bucket": "cb1fa07f61616d4f", "chunk": "4e88a3dfc4e194f2"},
+                  "bucket": "cb1fa07f61616d4f", "chunk": "7f2b0e1c119bed20"},
     "lfm2": {"decode_step": "d82d9545e710e4bc", "block": "bd655d89200f5972",
-             "bucket": "c3bef3039f79951c", "chunk": "f0edde680398a3e1"},
+             "bucket": "c3bef3039f79951c", "chunk": "003c695d4271080c"},
 }
 
 

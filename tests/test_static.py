@@ -1846,6 +1846,77 @@ def test_harvest_path_has_no_per_token_device_reads():
     )
 
 
+def _sampler_uses_outside_a_jitted_function(source: str) -> list[str]:
+    """The enclosing function of every use of the name ``sample`` in ``source``
+    that is not a call inside a function handed to ``jax.jit`` (by its name,
+    or as ``self.<name>``) somewhere in the same module."""
+    tree = ast.parse(source)
+    jitted = set()
+    for node in ast.walk(tree):
+        if (
+            isinstance(node, ast.Call) and node.args
+            and isinstance(node.func, ast.Attribute) and node.func.attr == "jit"
+            and isinstance(node.func.value, ast.Name) and node.func.value.id == "jax"
+        ):
+            first = node.args[0]
+            if isinstance(first, ast.Name | ast.Attribute):
+                jitted.add(first.id if isinstance(first, ast.Name) else first.attr)
+    offenders = []
+
+    def walk(node, stack, called):
+        for child in ast.iter_child_nodes(node):
+            inner = stack
+            if isinstance(child, ast.FunctionDef | ast.AsyncFunctionDef):
+                inner = stack + [child.name]
+            if (
+                isinstance(child, ast.Name) and child.id == "sample"
+                and isinstance(child.ctx, ast.Load)
+                and not (child is called and jitted & set(stack))
+            ):
+                offenders.append(".".join(stack) or "<module>")
+            walk(child, inner, child.func if isinstance(child, ast.Call) else None)
+
+    walk(tree, [], None)
+    return offenders
+
+
+def test_the_engine_samples_only_inside_its_jitted_programs():
+    """Run eagerly, ``sample()`` is a dozen one-operation programs and a
+    ``lax.cond`` traced and lowered anew at every call, on the scheduler's
+    thread, with the chip idle meanwhile (a chunked prompt's first token
+    until PR 43: ~30 ms of idle chip and ~95 ms of host time a request).
+    Every use of it in ``serving/engine.py`` is a call inside a function the
+    module hands to ``jax.jit``: the decode block's step, the two bucketed
+    prefill calls, the chunk call."""
+    source = (PKG_ROOT / "serving" / "engine.py").read_text()
+    assert _sampler_uses_outside_a_jitted_function(source) == []
+    calls = [
+        node for node in ast.walk(ast.parse(source))
+        if isinstance(node, ast.Call) and isinstance(node.func, ast.Name)
+        and node.func.id == "sample"
+    ]
+    assert len(calls) == 4
+    # ... and the check sees the forms the eager call had
+    eager = textwrap.dedent("""
+        import jax
+        from .sampling import sample
+
+        class E:
+            def _a(self, logits):
+                return sample(logits)
+
+            def _b(self):
+                self._jit = jax.jit(self._a)
+
+            def _finish(self, logits):
+                return self._profiled("sample", "first_token", sample)(logits)
+
+            def _pages(self, logits):
+                return sample(logits)
+    """)
+    assert _sampler_uses_outside_a_jitted_function(eager) == ["_finish", "_pages"]
+
+
 def test_every_journal_has_a_docs_table_row():
     """The docs half of the JOURNALS closure (the catalog-series guard
     applied to the journal table): every named journal in

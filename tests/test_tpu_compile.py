@@ -143,8 +143,10 @@ def chunk_program(one_chip):
     def compile(family, width):
         _, cfg, params, k_pages, v_pages, S = _cell_operands(one_chip, family)
         i32 = lambda *s: S(s, jnp.int32)  # noqa: E731
+        f32 = lambda *s: S(s, jnp.float32)  # noqa: E731
+        sampler = (S((2,), jnp.uint32), f32(1), f32(1), i32(1), i32(1), i32(1))
         return eng._chunk_jit(2048).lower(
-            params, i32(1, width), k_pages, v_pages, i32(1, 256), i32(1), cfg=cfg
+            params, i32(1, width), k_pages, v_pages, i32(1, 256), i32(1), *sampler, cfg=cfg
         ).compile()
 
     # the kernels pick interpret= from the backend at trace time
@@ -452,6 +454,7 @@ def glm(one_chip):
     def chunk(prefix, width):
         return eng._chunk_jit(prefix).lower(
             params, i32(1, width), leaves[0], leaves[1], i32(1, pages_per_slot), i32(1),
+            key, f32(1), f32(1), i32(1), i32(1), i32(1),
             state=(leaves[2],), slot_ids=i32(1), q_offset=i32(), cfg=cfg,
         ).compile()
 
@@ -753,7 +756,8 @@ def smallthinker(one_chip):
 
     def chunk(prefix):
         return eng._chunk_jit(prefix).lower(
-            params, i32(1, 2048), pages, pages, i32(1, pages_per_slot), i32(1), state=state,
+            params, i32(1, 2048), pages, pages, i32(1, pages_per_slot), i32(1),
+            key, f32(1), f32(1), i32(1), i32(1), i32(1), state=state,
             slot_ids=i32(1), q_offset=S((), jnp.int32), window_tables=i32(1, ring), cfg=cfg,
         ).compile()
 
