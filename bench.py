@@ -190,10 +190,9 @@ CONFIGS = {
     # benchdiff gates on (speculation pays where acceptance is high,
     # and the controller's retreat must keep the adaptive arm no slower
     # than spec-off where it isn't)
-    # decode_block=1 isolates speculation from macro-step amortization
-    # (same rationale as tiny-multistep): the spec-off arm pays one host
-    # round-trip per token, so the A/B measures what the γ-deep verify
-    # round buys, not what block fusion buys
+    # decode_block=1 isolates speculation from the block's amortization:
+    # the spec-off arm pays one host round-trip per token, so the A/B
+    # measures what the γ-deep verify round buys, not what block fusion buys
     "tiny-spec-adaptive": dict(
         slots=4, max_len=128, max_tokens=16, timeout=420,
         spec=("ngram", 4), spec_ab=True, decode_block=1,
@@ -205,25 +204,6 @@ CONFIGS = {
     "tiny-mixed": dict(
         slots=4, max_len=512, max_tokens=16, timeout=420, prompt_mult=12,
         mixed=True, budget=64,
-    ),
-    # CPU path-proof of the macro-step decode runtime (test_bench_contract,
-    # docs/multistep.md): decode_block=1 makes the classic arm pay one host
-    # round-trip PER TOKEN, so the N=1 vs N=8 A/B on the same warm engine
-    # exposes exactly the per-token host overhead ROADMAP #3 says to
-    # amortize — the json's `multistep` section carries both arms'
-    # host_fraction / tick_p95 and the deltas must favor the N=8 arm
-    "tiny-multistep": dict(
-        slots=4, max_len=128, max_tokens=16, timeout=420, multistep=8,
-        decode_block=1,
-    ),
-    # the on-chip macro-step A/B at the int8 headline shape
-    # (run by name, BENCH_MODEL): what N=8 fused
-    # decode steps buy real llama2-7b streams — tokens-per-dispatch up,
-    # host fraction down, with HBM-sized KV where every saved host
-    # round-trip is real decode time
-    "llama2-7b-int8-multistep": dict(
-        slots=16, max_len=256, max_tokens=128, timeout=1500, quant="int8",
-        kv_dtype="int8", multistep=8,
     ),
     # the on-chip adaptive-speculation A/B at the int8 headline shape
     # (run by name, BENCH_MODEL): prompt-lookup
@@ -490,107 +470,6 @@ def _measure_interference(engine, spec: dict) -> dict:
     }
 
 
-def _measure_multistep(engine, spec: dict) -> dict:
-    """Macro-step decode A/B (docs/multistep.md): the same warm engine runs
-    identical traffic twice — classic one-block-per-dispatch (N=1) vs the
-    config's N-step macro dispatch — and per-arm profiler-ring slices put
-    host_fraction and tick_p95 side by side. ``decode_steps`` is the
-    runtime-mutable knob, so there is no rebuild between arms; each arm
-    pre-warms one request outside its measured slice so a first-dispatch
-    compile (ledgered as a miss) can't pollute the tick tail. On the N-step
-    arm every harvested dispatch carries up to N tokens, so host_fraction
-    and tick-per-token must DROP — the deltas in this section are the
-    CPU path-proof benchdiff gates on."""
-    from modal_examples_tpu.observability import catalog as _C
-    from modal_examples_tpu.serving import SamplingParams
-    from modal_examples_tpu.utils.prometheus import default_registry
-    from modal_examples_tpu.utils.stats import percentile_nearest_rank as _pp
-
-    steps = int(spec["multistep"])
-    prof = engine.profiler
-    sp = SamplingParams(max_tokens=spec["max_tokens"], temperature=1.0)
-
-    def run_arm(n: int) -> dict:
-        engine.decode_steps = n
-        for _ in engine.stream(engine.submit("multistep arm warm", sp)):
-            pass
-        d0 = default_registry.total(_C.MULTISTEP_DISPATCHES_TOTAL)
-        k0 = default_registry.total(_C.MULTISTEP_TOKENS_TOTAL)
-        t_start = time.time()
-        reqs = [
-            engine.submit(f"macro step arm {n} prompt {i}", sp)
-            for i in range(spec["slots"] * 2)
-        ]
-        for r in reqs:
-            for _ in engine.stream(r):
-                pass
-        dispatches = default_registry.total(_C.MULTISTEP_DISPATCHES_TOTAL) - d0
-        tokens = default_registry.total(_C.MULTISTEP_TOKENS_TOTAL) - k0
-        out = {
-            "dispatches": int(dispatches),
-            "tokens": int(tokens),
-            "tokens_per_dispatch": (
-                round(tokens / dispatches, 3) if dispatches else None
-            ),
-        }
-        if prof is not None:
-            # the ring is shared across arms: slice this arm's busy ticks
-            # by wall-clock start (each entry stamps `at` at end_tick)
-            ticks = [
-                e for e in prof.perfetto_snapshot()["ticks"]
-                if e["at"] >= t_start
-            ]
-            totals = sorted(e["total"] for e in ticks)
-            sum_total = sum(totals)
-            if sum_total > 0 and tokens:
-                sum_device = sum(e["device"] for e in ticks)
-                out["host_fraction"] = round(
-                    max(0.0, min(1.0, 1.0 - sum_device / sum_total)), 6
-                )
-                out["tick_p95"] = round(_pp(totals, 0.95), 6)
-                # the quantity macro-stepping amortizes, robust even where
-                # "device" is the host's own cores (the CPU path-proof):
-                # scheduler-thread seconds spent per accepted token
-                out["host_ms_per_token"] = round(
-                    (sum_total - sum_device) / tokens * 1000, 4
-                )
-        return out
-
-    saved = engine.decode_steps
-    try:
-        classic = run_arm(1)
-        multi = run_arm(steps)
-    finally:
-        engine.decode_steps = saved
-    section = {
-        "steps": steps,
-        "classic": classic,
-        "multistep": multi,
-        # the benchdiff-gated scalar (utils/bench_diff.py METRICS)
-        "tokens_per_dispatch": multi.get("tokens_per_dispatch"),
-    }
-    if "host_fraction" in classic and "host_fraction" in multi:
-        # positive = the macro-step arm spent a smaller host share. On a
-        # real chip this is the headline drop; on the CPU path-proof the
-        # "device" is the host's own cores, so wall-clock attribution is
-        # contention noise there — the robust CPU direction check is
-        # host_ms_per_token_delta below
-        section["host_fraction_delta"] = round(
-            classic["host_fraction"] - multi["host_fraction"], 6
-        )
-    if "tick_p95" in classic and "tick_p95" in multi:
-        # per-TOKEN tick tail: an N-step tick hosts up to N tokens, so
-        # normalize before comparing — positive = cheaper per token
-        section["tick_p95_delta"] = round(
-            classic["tick_p95"] - multi["tick_p95"] / steps, 6
-        )
-    if "host_ms_per_token" in classic and "host_ms_per_token" in multi:
-        section["host_ms_per_token_delta"] = round(
-            classic["host_ms_per_token"] - multi["host_ms_per_token"], 4
-        )
-    return section
-
-
 def _measure_spec_adaptive(engine, spec: dict) -> dict:
     """Fused-speculation A/B (docs/speculative.md#gamma-schedule): the same
     warm engine runs an identical MIXED-acceptance population three times
@@ -650,7 +529,6 @@ def _measure_spec_adaptive(engine, spec: dict) -> dict:
         # across requests: spec rounds deliver tokens in bursts, so raw
         # inter-arrival gap quantiles would structurally punish any
         # multi-token dispatch (most gaps ~0, the tail = one whole round)
-        # — the same reason _measure_multistep normalizes tick_p95 by N
         tpots: list[float] = []
         t0 = time.time()
 
@@ -1407,9 +1285,6 @@ def _child(model: str) -> None:
         # stall-free admission (docs/scheduling.md): mixed configs run the
         # measured traffic budgeted; 0 keeps the classic unlimited admit
         max_prefill_tokens_per_tick=spec.get("budget", 0),
-        # macro-step decode (docs/multistep.md): multistep configs run the
-        # measured traffic at the config's N; None resolves the env knob
-        decode_steps=spec.get("multistep"),
         decode_block=spec.get("decode_block", 8),
     )
     build_s = time.time() - t0
@@ -1580,13 +1455,6 @@ def _child(model: str) -> None:
     interference = None
     if spec.get("mixed"):
         interference = _measure_interference(engine, spec)
-
-    # macro-step decode A/B (multistep configs, docs/multistep.md): N=1 vs
-    # N=config on the same warm engine via the runtime-mutable knob —
-    # host_fraction and per-token tick_p95 must favor the macro-step arm
-    multistep_info = None
-    if spec.get("multistep"):
-        multistep_info = _measure_multistep(engine, spec)
 
     # fused adaptive speculation A/B (spec_ab configs,
     # docs/speculative.md#gamma-schedule): spec-off vs fixed-γ vs the
@@ -1826,7 +1694,6 @@ def _child(model: str) -> None:
                 **({"disagg": disagg_info} if disagg_info else {}),
                 **({"faults": faults_info} if faults_info else {}),
                 **({"interference": interference} if interference else {}),
-                **({"multistep": multistep_info} if multistep_info else {}),
                 **({"canary": canary_info} if canary_info else {}),
                 **({"fleet": fleet_info} if fleet_info else {}),
                 **({"failover": failover_info} if failover_info else {}),

@@ -699,8 +699,6 @@ def cmd_profile(argv: list[str]) -> int:
 
     phases: dict = {}
     ratio = None
-    decode_steps = None
-    tokens_per_dispatch = None
     spec_gamma = None
     spec_accept = None
     spec_tpd = None
@@ -727,12 +725,6 @@ def cmd_profile(argv: list[str]) -> int:
                 }
         # a 0..1 fraction must never sum across jobs: show the worst
         ratio = merged.peak(C.HOST_OVERHEAD_RATIO) or None
-        # macro-step decode (docs/multistep.md): configured N + the
-        # harvested tokens-per-dispatch — gauges, so peak, never sum
-        decode_steps = merged.peak(C.MULTISTEP_DECODE_STEPS) or None
-        tokens_per_dispatch = (
-            merged.peak(C.MULTISTEP_TOKENS_PER_DISPATCH) or None
-        )
         # fused speculative rounds (docs/speculative.md#series): dispatched
         # γ p50 + acceptance — gauges, so peak, never sum
         spec_gamma = merged.peak(C.SPEC_GAMMA) or None
@@ -750,8 +742,6 @@ def cmd_profile(argv: list[str]) -> int:
     if as_json:
         print(json.dumps({
             "host_overhead_ratio": ratio,
-            "decode_steps": decode_steps,
-            "tokens_per_dispatch": tokens_per_dispatch,
             "spec_gamma": spec_gamma,
             "spec_acceptance_rate": spec_accept,
             "spec_tokens_per_dispatch": spec_tpd,
@@ -769,15 +759,6 @@ def cmd_profile(argv: list[str]) -> int:
 
     if ratio is not None:
         print(f"host overhead ratio: {ratio:.3f} (1 - device-blocked/total)")
-    if decode_steps is not None:
-        tpd = (
-            f"{tokens_per_dispatch:.1f}"
-            if tokens_per_dispatch is not None else "-"
-        )
-        print(
-            f"macro-step decode: N={decode_steps:.0f} configured, "
-            f"{tpd} tokens/dispatch"
-        )
     if spec_gamma is not None or spec_accept:
         acc = f"{spec_accept:.2f}" if spec_accept is not None else "-"
         stpd = f"{spec_tpd:.1f}" if spec_tpd is not None else "-"
@@ -1528,15 +1509,6 @@ def cmd_top(argv: list[str]) -> int:
             f"ttft p50/p95 ms {fmt_q(C.TTFT_SECONDS)}   "
             f"tpot p50/p95 ms {fmt_q(C.TPOT_SECONDS)}"
         )
-        # macro-step decode (docs/multistep.md): configured N + harvested
-        # tokens-per-dispatch, when a multistep engine has pushed (gauges:
-        # peak, never sum across jobs)
-        ms_n = merged.peak(C.MULTISTEP_DECODE_STEPS)
-        if ms_n:
-            print(
-                f"macro-step decode: N={ms_n:.0f}   tokens/dispatch "
-                f"{merged.peak(C.MULTISTEP_TOKENS_PER_DISPATCH):.1f}"
-            )
         # fused speculative decode (docs/speculative.md#series): dispatched
         # γ p50 + acceptance, when a spec engine has pushed (gauges: peak)
         sp_acc = merged.peak(C.SPEC_ACCEPTANCE_RATE)

@@ -33,7 +33,7 @@ cache keeps ``c_kv`` *after* its RMSNorm. The plain reference is
 
 What this model does not do yet is refused by name where the engine is
 built (``DeepseekV2Config.unsupported``): an int8 KV cache, speculation,
-multistep decode, disaggregated transfer, tensor parallelism, LoRA, vision.
+disaggregated transfer, tensor parallelism, LoRA, vision.
 """
 
 from __future__ import annotations
@@ -93,7 +93,7 @@ class DeepseekV2Config:
     #: features of the engine this model's programs do not implement yet:
     #: ``LLMEngine`` refuses each by name where it is asked for
     unsupported = (
-        "int8 KV cache", "speculative decoding", "multistep decode",
+        "int8 KV cache", "speculative decoding",
         "disaggregated transfer", "tensor parallelism", "LoRA", "vision",
         "a Pallas paged_impl or scatter_impl",
     )

@@ -103,7 +103,7 @@ class GlmDsaConfig:
     tie_embeddings: bool = False
 
     unsupported = (
-        "int8 KV cache", "speculative decoding", "multistep decode",
+        "int8 KV cache", "speculative decoding",
         "disaggregated transfer", "tensor parallelism", "LoRA", "vision",
         "a Pallas paged_impl or scatter_impl",
     )

@@ -103,7 +103,7 @@ class GraniteHybridConfig:
     #: ``LLMEngine`` refuses each by name where it is asked for
     unsupported = (
         "prefix caching", "int8 KV cache", "speculative decoding",
-        "multistep decode", "disaggregated transfer", "tensor parallelism",
+        "disaggregated transfer", "tensor parallelism",
         "LoRA", "vision", "a Pallas paged_impl or scatter_impl",
     )
 

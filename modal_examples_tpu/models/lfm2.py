@@ -113,7 +113,7 @@ class Lfm2Config:
     #: ``LLMEngine`` refuses each by name where it is asked for
     unsupported = (
         "prefix caching", "int8 KV cache", "speculative decoding",
-        "multistep decode", "disaggregated transfer", "tensor parallelism",
+        "disaggregated transfer", "tensor parallelism",
         "LoRA", "vision", "a Pallas paged_impl or scatter_impl",
     )
     #: ``decode_step(return_counts=True)`` hands back [pairs, tile rows]

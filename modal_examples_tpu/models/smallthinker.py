@@ -125,7 +125,7 @@ class SmallThinkerConfig:
     #: in the window group's pages, which belong to one sequence
     unsupported = (
         "prefix caching", "int8 KV cache", "speculative decoding",
-        "multistep decode", "disaggregated transfer", "tensor parallelism",
+        "disaggregated transfer", "tensor parallelism",
         "LoRA", "vision", "a Pallas scatter_impl",
     )
     #: ``decode_step(return_counts=True)`` hands back [pairs, tile rows]

@@ -246,6 +246,10 @@ def probe_engine(
                     gaps.append(now - last)
                 last = now
             e2e = clock() - t0
+            if req.first_token_at is not None:
+                # the engine's own stamps, on the engine's clock: a probe
+                # whose tokens decode to no text has no first piece
+                ttft = req.first_token_at - req.created
             tokens = [int(t) for t in req.generated_tokens]
             rec.update(
                 request_id=req.request_id,

@@ -376,14 +376,13 @@ TICK_PHASE_SECONDS = "mtpu_tick_phase_seconds"
 DEVICE_STARVED_SECONDS_TOTAL = "mtpu_device_starved_seconds_total"
 #: gauge: host share of busy-tick time over the profiler ring —
 #: 1 - (device-blocked seconds / total tick seconds); the per-token host
-#: overhead ROADMAP #3's multi-step decode loop exists to amortize
+#: overhead the decode block's rolled steps exist to amortize
 HOST_OVERHEAD_RATIO = "mtpu_host_overhead_ratio"
 #: histogram {program}: seconds of a dispatch that built its jitted
 #: program (first of a (program, shape_key), or the jitted function's
 #: cache grew during the call); program = block | prefill |
 #: prefill_mm | prefill_chunk | draft_prefill | spec_verify | ngram_verify
-#: | sample (the ops-level first-token helper) | multistep (the N-step
-#: macro-dispatch scan, serving/multistep/)
+#: | sample (the ops-level first-token helper)
 COMPILE_SECONDS = "mtpu_compile_seconds"
 #: counter {program, cache}: program-cache lookups at the engine's jit
 #: dispatch sites; cache = miss (a fresh build — timed and appended to the
@@ -409,26 +408,6 @@ COMPILE_PHASE_SECONDS_TOTAL = "mtpu_compile_phase_seconds_total"
 #: (result = hit: a compiled program read back | miss: compiled, then
 #: written)
 COMPILE_CACHE_TOTAL = "mtpu_compile_cache_total"
-
-# -- macro-step decode runtime (serving/multistep/, docs/multistep.md) -------
-
-#: gauge: the configured decode steps per dispatch (the runtime-mutable
-#: ``decode_steps`` knob / MTPU_DECODE_STEPS; 1 = classic block path)
-MULTISTEP_DECODE_STEPS = "mtpu_multistep_decode_steps"
-#: gauge: accepted tokens per decode dispatch over the last gauge window —
-#: the headline amortization number (classic path reports it too, so the
-#: A/B bench reads one series across both arms)
-MULTISTEP_TOKENS_PER_DISPATCH = "mtpu_multistep_tokens_per_dispatch"
-#: counter: decode dispatches harvested (one per blocking device read)
-MULTISTEP_DISPATCHES_TOTAL = "mtpu_multistep_dispatches_total"
-#: counter: tokens accepted from harvested decode dispatches
-MULTISTEP_TOKENS_TOTAL = "mtpu_multistep_tokens_total"
-#: counter: whole macro-steps the on-device early-exit skipped (every lane
-#: dead — the ``masked_scan`` hold branch ran instead of the transformer)
-MULTISTEP_EARLY_EXIT_STEPS_TOTAL = "mtpu_multistep_early_exit_steps_total"
-#: gauge: events pending on the detokenization worker's queue (a growing
-#: depth means text emission is falling behind the scheduler)
-MULTISTEP_DETOK_QUEUE_DEPTH = "mtpu_multistep_detok_queue_depth"
 
 # -- fused speculative decoding (serving/spec_runtime/, docs/speculative.md) --
 
@@ -977,7 +956,7 @@ CATALOG: dict[str, dict] = {
         "type": "histogram", "labels": ["program"],
         "help": "jitted-program build seconds at first dispatch "
                 "(program=block|prefill|prefill_mm|prefill_chunk|"
-                "draft_prefill|spec_verify|ngram_verify|sample|multistep)",
+                "draft_prefill|spec_verify|ngram_verify|sample)",
     },
     COMPILES_TOTAL: {
         "type": "counter", "labels": ["program", "cache"],
@@ -1150,34 +1129,6 @@ CATALOG: dict[str, dict] = {
     CANARY_FAILING: {
         "type": "gauge", "labels": ["replica"],
         "help": "consecutive failing canary rounds per replica (0=passing)",
-    },
-    MULTISTEP_DECODE_STEPS: {
-        "type": "gauge", "labels": [],
-        "help": "configured decode steps fused per dispatch "
-                "(decode_steps / MTPU_DECODE_STEPS; 1=classic block path)",
-    },
-    MULTISTEP_TOKENS_PER_DISPATCH: {
-        "type": "gauge", "labels": [],
-        "help": "accepted tokens per decode dispatch over the last gauge "
-                "window (the macro-step amortization headline)",
-    },
-    MULTISTEP_DISPATCHES_TOTAL: {
-        "type": "counter", "labels": [],
-        "help": "decode dispatches harvested (one blocking device read "
-                "each)",
-    },
-    MULTISTEP_TOKENS_TOTAL: {
-        "type": "counter", "labels": [],
-        "help": "tokens accepted from harvested decode dispatches",
-    },
-    MULTISTEP_EARLY_EXIT_STEPS_TOTAL: {
-        "type": "counter", "labels": [],
-        "help": "whole macro-steps skipped by on-device early-exit "
-                "(all lanes dead; masked_scan hold branch)",
-    },
-    MULTISTEP_DETOK_QUEUE_DEPTH: {
-        "type": "gauge", "labels": [],
-        "help": "events pending on the detokenization worker queue",
     },
     SPEC_GAMMA: {
         "type": "gauge", "labels": [],

@@ -1181,50 +1181,6 @@ def set_canary_failing(
     )
 
 
-def record_multistep_dispatch(
-    *, tokens: int, steps_saved: int = 0, registry: Registry | None = None
-) -> None:
-    """One harvested decode dispatch: ``tokens`` accepted across its
-    slots, ``steps_saved`` whole macro-steps the on-device early-exit
-    skipped (0 on the classic one-block path — both paths report here so
-    tokens-per-dispatch is one series across the A/B bench arms)."""
-    reg = _reg(registry)
-    reg.counter_inc(
-        C.MULTISTEP_DISPATCHES_TOTAL, 1.0,
-        help=C.CATALOG[C.MULTISTEP_DISPATCHES_TOTAL]["help"],
-    )
-    if tokens:
-        reg.counter_inc(
-            C.MULTISTEP_TOKENS_TOTAL, float(tokens),
-            help=C.CATALOG[C.MULTISTEP_TOKENS_TOTAL]["help"],
-        )
-    if steps_saved:
-        reg.counter_inc(
-            C.MULTISTEP_EARLY_EXIT_STEPS_TOTAL, float(steps_saved),
-            help=C.CATALOG[C.MULTISTEP_EARLY_EXIT_STEPS_TOTAL]["help"],
-        )
-
-
-def set_multistep_gauges(
-    *, decode_steps: int, tokens_per_dispatch: float,
-    detok_queue_depth: int, registry: Registry | None = None,
-) -> None:
-    """Macro-step runtime gauges, refreshed with the engine's gauge sweep."""
-    reg = _reg(registry)
-    reg.gauge_set(
-        C.MULTISTEP_DECODE_STEPS, float(decode_steps),
-        help=C.CATALOG[C.MULTISTEP_DECODE_STEPS]["help"],
-    )
-    reg.gauge_set(
-        C.MULTISTEP_TOKENS_PER_DISPATCH, float(tokens_per_dispatch),
-        help=C.CATALOG[C.MULTISTEP_TOKENS_PER_DISPATCH]["help"],
-    )
-    reg.gauge_set(
-        C.MULTISTEP_DETOK_QUEUE_DEPTH, float(detok_queue_depth),
-        help=C.CATALOG[C.MULTISTEP_DETOK_QUEUE_DEPTH]["help"],
-    )
-
-
 def set_spec_gauges(
     *, gamma: float, tokens_per_dispatch: float, acceptance_rate: float,
     registry: Registry | None = None,

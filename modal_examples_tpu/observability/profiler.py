@@ -8,8 +8,7 @@ ceiling diagnosable offline. Neither was measurable: request traces show
 WHERE a request went, progress watermarks show THAT the scheduler moves,
 but nothing attributed where a scheduler tick's time actually goes or
 recorded when/what XLA compiles. This module is that instrument — the
-measurement foundation every subsequent perf PR (multi-step decode, spec
-adaptivity) is judged against.
+measurement foundation every subsequent perf PR is judged against.
 
 Its legs:
 
@@ -28,7 +27,7 @@ Its legs:
   spans and the device's operations on ONE clock. Blocking device reads
   enter with ``device=True``, so the ring carries a host-vs-device split
   and the ``mtpu_host_overhead_ratio`` gauge falls out: 1 - device-blocked
-  over total — the number the multi-step decode loop must shrink.
+  over total — the number the decode block's rolled steps keep small.
 - **Device starvation** — the profiler numbers every dispatch and the
   engine reports which number each blocking read harvested; the device
   runs one program after another, so everything up to a harvested number
@@ -375,7 +374,6 @@ class HotPathProfiler:
         self._compile_s = 0.0
         self._dispatches = 0
         self._dispatch_tokens = 0
-        self._decode_steps = 1
         self._compile_log: deque[dict] = deque(maxlen=COMPILE_LOG_KEEP)
         self._ledger_path = ledger_path
         self._ledger: DecisionJournal | None = None
@@ -459,17 +457,12 @@ class HotPathProfiler:
         self._harvested = self.dispatched
         self._starved_since = None
 
-    def note_dispatch_tokens(self, n: int, steps: int | None = None) -> None:
-        """One harvested decode dispatch accepted ``n`` tokens (both the
-        classic block path and the macro-step path report here, so the
-        BENCH ``multistep`` section's tokens-per-dispatch compares across
-        arms); ``steps`` is the configured ``decode_steps`` at dispatch
-        time."""
+    def note_dispatch_tokens(self, n: int) -> None:
+        """One harvested decode dispatch (a block or a speculative round)
+        accepted ``n`` tokens."""
         with self._lock:
             self._dispatches += 1
             self._dispatch_tokens += int(n)
-            if steps is not None:
-                self._decode_steps = max(1, int(steps))
 
     def flush(self) -> None:
         """Force the host-overhead gauge current (engine stop / push time:
@@ -642,7 +635,6 @@ class HotPathProfiler:
             compiles_n, compile_s = self._compiles, self._compile_s
             dispatches = self._dispatches
             dispatch_tokens = self._dispatch_tokens
-            decode_steps = self._decode_steps
         tokens_per_dispatch = (
             round(dispatch_tokens / dispatches, 3) if dispatches else None
         )
@@ -657,7 +649,6 @@ class HotPathProfiler:
                 "phases": {},
                 "compile_total_s": round(compile_s, 3),
                 "compiles_n": compiles_n,
-                "decode_steps": decode_steps,
                 "dispatches": dispatches,
                 "tokens_per_dispatch": tokens_per_dispatch,
             }
@@ -691,7 +682,6 @@ class HotPathProfiler:
             "phases": phases,
             "compile_total_s": round(compile_s, 3),
             "compiles_n": compiles_n,
-            "decode_steps": decode_steps,
             "dispatches": dispatches,
             "tokens_per_dispatch": tokens_per_dispatch,
         }

@@ -374,7 +374,7 @@ KERNEL_PROBES: dict[str, Callable[[], dict]] = {
 #: (128 heads, q/k 192 wide, values 128) — does Mosaic take the tiles the
 #: forward chooses inside VMEM, and are they right? Too large for the
 #: interpreter: ``chip_smoke.py``'s kernel leg runs them on the chip, and
-#: tests/test_tpu_compile.py compiles the same calls for the v5e.
+#: tests/tpu_compile/test_llama.py compiles the same calls for the v5e.
 CELL_FLASH_PROBES: dict[str, Callable[[], dict]] = {
     "docqa_flash_chunk_gqa": functools.partial(
         probe_flash_chunked, 1, 32, 8, 4096, 128, 2048

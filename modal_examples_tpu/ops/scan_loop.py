@@ -1,15 +1,11 @@
-"""Masked early-exit scan: the macro-step decode loop's control-flow core.
+"""Masked early-exit scan: a ``lax.scan`` that skips steps no lane needs.
 
 :func:`masked_scan` runs a per-step body over a leading axis of inputs
 while any lane of a boolean ``live`` mask is still set, and skips the body
 entirely — one ``lax.cond`` per step, no transformer math — once every
-lane is dead. It is the shared shape under two loops:
-
-- the multi-step decode runtime (``serving/multistep``): N decode+sample
-  steps fused into one jitted program, lanes dying at stop-token or
-  length-budget boundaries (docs/multistep.md);
-- a gamma-step speculative *verify* loop (ROADMAP #4): lanes die at the
-  first rejected draft token, and the tail steps skip.
+lane is dead. Its caller is the speculative round's draft-propose loop
+(``serving/spec_runtime/runtime.py``, docs/speculative.md): a lane dies
+when its γ budget is spent, and the tail steps skip the draft model.
 
 The contract mirrors ``jax.lax.scan`` with a mask threaded through:
 

@@ -478,14 +478,6 @@ class LLMEngine:
         # benches A/B fixed-vs-adaptive on a live engine.
         spec_adaptive: bool | None = None,
         decode_block: int = 8,  # decode steps rolled into one dispatch
-        # macro-step decode (docs/multistep.md): N decode+sample steps
-        # fused into ONE jitted program per dispatch, with device-side
-        # stop-token/length early exit and per-slot validity masks. None
-        # resolves MTPU_DECODE_STEPS once (the knob rule); 1 = the classic
-        # pipelined block path, byte-identical fall-through. Runtime-
-        # mutable like prefill_budget (read once per dispatch), so benches
-        # A/B it on a live engine.
-        decode_steps: int | None = None,
         # stall-free admission (docs/scheduling.md): max prompt tokens the
         # scheduler may convert into prefill work per tick. None resolves
         # through MTPU_PREFILL_BUDGET (empty env = unlimited); an explicit
@@ -909,20 +901,7 @@ class LLMEngine:
         self._block_jit = jax.jit(
             self._decode_block_fn, donate_argnums=(1, 2), donate_argnames=("state",)
         )
-        # macro-step decode runtime (serving/multistep, docs/multistep.md)
-        from .multistep.runtime import resolve_decode_steps
-
-        self.decode_steps = resolve_decode_steps(decode_steps)
-        if self.decode_steps > 1:
-            _refuse(cfg, "multistep decode")
-        self._multistep_jits: dict[int, object] = {}  # keyed by N
-        self._detok = None  # lazy DetokWorker (first routed token)
-        # tokens-per-dispatch accounting (harvest-side; feeds the
-        # catalog MULTISTEP_* gauges through _refresh_gauges' throttle)
-        self._ms_dispatches = 0
-        self._ms_tokens = 0
-        self._ms_flush = {"dispatches": 0, "tokens": 0}
-        self._ms_tpd = 0.0
+        self._detok = None  # lazy DetokWorker (a speculative round's first token)
         self._prefill_jits: dict[int, object] = {}
         self._chunk_jits: dict[int, object] = {}  # keyed by chunk q_offset
         # the compiled chunk programs, keyed (q_offset, width, is_draft): a
@@ -942,8 +921,8 @@ class LLMEngine:
         # mode (docs/speculative.md): one fused round program per dispatch
         # — draft-propose(γ) on masked_scan + one ragged target verify +
         # accept in-graph (serving/spec_runtime/runtime.py) — emitting the
-        # multistep harvest plane, so spec rounds and macro-step blocks
-        # share ONE harvest site. The draft keeps its own paged KV cache
+        # harvest plane (tokens + validity mask), so spec rounds and decode
+        # blocks share ONE harvest site. The draft keeps its own paged KV cache
         # ADDRESSED BY THE SAME page ids/tables as the target's, so
         # allocation, prefix sharing, and slot recycling are managed once.
         self.spec_gamma = 0
@@ -970,7 +949,9 @@ class LLMEngine:
                 self.spec_mode = "ngram"
                 self.ngram_n = 2  # trailing-bigram lookup (prompt-lookup)
                 self._ngram_jit = jax.jit(
-                    _spec_rt.build_ngram_round_fn(cfg, gamma=self.spec_gamma),
+                    _spec_rt.build_ngram_round_fn(
+                        cfg, gamma=self.spec_gamma, mesh=mesh
+                    ),
                     donate_argnums=(1, 2),
                 )
             else:
@@ -1045,7 +1026,7 @@ class LLMEngine:
             else None
         )
         # spec round accounting (harvest-side; feeds the SPEC_* gauges
-        # through _refresh_gauges' throttle — the _ms_* delta pattern)
+        # through _refresh_gauges' throttle, as deltas since the last flush)
         self._spec_rounds = 0
         self._spec_round_tokens = 0
         self._spec_fallbacks = 0
@@ -1226,9 +1207,7 @@ class LLMEngine:
         kernel (ops.paged_decode_attention_ragged) DMAs each live slot's own
         live pages and nothing for a dead one (a window layer's ring from
         the first page its window reaches: the plan's ``window_attention``
-        says which of the two read the second group). The macro-step program
-        can kill a lane before its last step; it is counted as running them
-        all."""
+        says which of the two read the second group)."""
         self._count_sparse(
             lambda: positions[active].astype(np.int64)[:, None] + np.arange(steps), "decode"
         )
@@ -1277,34 +1256,13 @@ class LLMEngine:
             layers="window",
         )
 
-    def _multistep_jit(self, n: int):
-        """The N-step macro decode program (serving/multistep/runtime.py),
-        built lazily per N — the knob is runtime-mutable, and each value
-        is its own compiled program (shape key ``s{slots}n{N}``)."""
-        jit = self._multistep_jits.get(n)
-        if jit is None:
-            from .multistep.runtime import build_multistep_fn
-
-            fn = build_multistep_fn(
-                self.cfg,
-                paged_impl=self.paged_impl,
-                scatter_impl=self.scatter_impl,
-                mesh=self.mesh,
-                eos_id=self.tokenizer.eos_id,
-                n_steps=n,
-            )
-            jit = self._multistep_jits[n] = jax.jit(
-                fn, donate_argnums=(1, 2)
-            )
-        return jit
-
     def _ensure_detok(self):
-        """The lazy detokenization worker (serving/multistep/detok.py). A
+        """The lazy detokenization worker (serving/spec_runtime/detok.py). A
         dead worker is replaced — owned streams re-register from their
         ``req.emitted_len`` cursor on the next accepted token."""
         w = self._detok
         if w is None or not w.alive:
-            from .multistep.detok import DetokWorker
+            from .spec_runtime.detok import DetokWorker
 
             w = DetokWorker(
                 tokenizer=self.tokenizer,
@@ -1925,34 +1883,6 @@ class LLMEngine:
             jnp.full((B,), -1, jnp.int32),
             **self._state_args(),
         )
-        n_ms = max(1, int(self.decode_steps))
-        if n_ms > 1:
-            # macro-step program (docs/multistep.md): warmed at the
-            # configured N; other N values compile on first dispatch
-            # (runtime knob flips are a bench/test affair)
-            (
-                _toks, _valid, _last,
-                self.cache.k_pages, self.cache.v_pages,
-            ) = self._profiled(
-                "multistep", f"s{self.max_slots}n{n_ms}",
-                self._multistep_jit(n_ms),
-            )(
-                self.params,
-                self.cache.k_pages,
-                self.cache.v_pages,
-                jnp.zeros((B,), jnp.int32),
-                jnp.zeros((B,), jnp.int32),
-                jnp.zeros((B,), bool),
-                jnp.zeros((B,), jnp.int32),
-                jnp.zeros((B, self.pages_per_slot), jnp.int32),
-                jnp.zeros((B,), bool),
-                self._next_key(),
-                jnp.ones((B,), jnp.float32),
-                jnp.ones((B,), jnp.float32),
-                jnp.zeros((B,), jnp.int32),
-                jnp.full((B,), -1, jnp.int32),
-                jnp.ones((B,), jnp.int32),
-            )
         if self.spec_mode == "ngram":
             B = self.max_slots
             (
@@ -2040,7 +1970,7 @@ class LLMEngine:
         """THE terminal routing point: every ``_Finish`` put in this
         engine goes through here. Streams the detok worker owns get their
         marker enqueued BEHIND any pending text (the FIFO ordering
-        contract, docs/multistep.md) — the worker then runs
+        contract, docs/speculative.md#the-harvest-boundary) — the worker then runs
         :meth:`_deliver_finish`; everything else delivers directly."""
         w = self._detok
         if w is not None and w.alive and w.owns(req):
@@ -2384,9 +2314,9 @@ class LLMEngine:
             # token's predecessor was fed through a finished block); later
             # positions an in-flight block may have written are masked by
             # position-bounded attention and overwritten on resume. The
-            # same harvest-boundary argument covers mid-MACRO-step
-            # migration (docs/multistep.md): un-harvested device tokens
-            # are simply never accepted — the checkpoint carries only
+            # same harvest-boundary argument covers a block or a round in
+            # flight (docs/speculative.md#the-harvest-boundary): un-harvested
+            # device tokens are simply never accepted — the checkpoint carries only
             # committed state, and the peer regenerates the rest
             # token-identically from the (seed, position) keying.
             if self._detok is not None and self._detok.owns(req):
@@ -2770,25 +2700,6 @@ class LLMEngine:
                     0, len(s.request.prompt_tokens) - s.prefill.offset
                 )
         _obs.set_prefill_backlog(backlog)
-        # macro-step decode gauges (docs/multistep.md): configured N, the
-        # harvested tokens-per-dispatch over the window since the last
-        # refresh (held when idle), and the detok worker's queue depth
-        d = self._ms_dispatches - self._ms_flush["dispatches"]
-        if d > 0:
-            self._ms_tpd = (
-                self._ms_tokens - self._ms_flush["tokens"]
-            ) / d
-            self._ms_flush = {
-                "dispatches": self._ms_dispatches,
-                "tokens": self._ms_tokens,
-            }
-        _obs.set_multistep_gauges(
-            decode_steps=max(1, int(self.decode_steps)),
-            tokens_per_dispatch=self._ms_tpd,
-            detok_queue_depth=(
-                self._detok.queue_depth() if self._detok is not None else 0
-            ),
-        )
         # speculative gauges (docs/speculative.md#series): dispatched-γ
         # p50 over the window since the last refresh, harvested tokens per
         # spec round (held when idle), lifetime acceptance, and the
@@ -4040,71 +3951,30 @@ class LLMEngine:
         prev = self._device_tokens
         if prev is None:
             prev = jnp.zeros((self.max_slots,), jnp.int32)
-        n = max(1, int(self.decode_steps))  # runtime-mutable: read ONCE
-        if n <= 1:
-            # classic pipelined block: byte-identical fall-through
-            (
-                toks, last, self.cache.k_pages, self.cache.v_pages,
-                self.cache.beside,
-            ) = self._profiled(
-                "block", f"s{self.max_slots}k{self.decode_block}",
-                self._block_jit,
-            )(
-                self.params,
-                self.cache.k_pages,
-                self.cache.v_pages,
-                prev,
-                jnp.asarray(self._override.copy()),
-                jnp.asarray(self._override_mask.copy()),
-                jnp.asarray(self._positions.copy()),
-                jnp.asarray(self._page_tables.copy()),
-                jnp.asarray(self._active.copy()),
-                self._next_key(),
-                jnp.asarray(self._temps.copy()),
-                jnp.asarray(self._top_ps.copy()),
-                jnp.asarray(self._top_ks.copy()),
-                jnp.asarray(self._seeds.copy()),
-                **self._state_args(),
-            )
-            valid = None
-            n = self.decode_block
-        else:
-            # macro-step program (docs/multistep.md): per-slot budgets let
-            # the device die at exactly the token the host would finish on
-            # — remaining max_tokens (counting in-flight un-harvested
-            # tokens) and remaining context, whichever is tighter
-            budgets = np.ones((self.max_slots,), np.int32)
-            for i in live:
-                s = self.slots[i]
-                p = s.request.params
-                g_opt = len(s.generated) + (
-                    int(self._opt_positions[i]) - s.position
-                )
-                budgets[i] = max(1, min(
-                    p.max_tokens - g_opt,
-                    (self.max_model_len - 1) - int(self._opt_positions[i]),
-                ))
-            (
-                toks, valid, last, self.cache.k_pages, self.cache.v_pages,
-            ) = self._profiled(
-                "multistep", f"s{self.max_slots}n{n}", self._multistep_jit(n)
-            )(
-                self.params,
-                self.cache.k_pages,
-                self.cache.v_pages,
-                prev,
-                jnp.asarray(self._override.copy()),
-                jnp.asarray(self._override_mask.copy()),
-                jnp.asarray(self._positions.copy()),
-                jnp.asarray(self._page_tables.copy()),
-                jnp.asarray(self._active.copy()),
-                self._next_key(),
-                jnp.asarray(self._temps.copy()),
-                jnp.asarray(self._top_ps.copy()),
-                jnp.asarray(self._top_ks.copy()),
-                jnp.asarray(self._seeds.copy()),
-                jnp.asarray(budgets),
-            )
+        (
+            toks, last, self.cache.k_pages, self.cache.v_pages,
+            self.cache.beside,
+        ) = self._profiled(
+            "block", f"s{self.max_slots}k{self.decode_block}",
+            self._block_jit,
+        )(
+            self.params,
+            self.cache.k_pages,
+            self.cache.v_pages,
+            prev,
+            jnp.asarray(self._override.copy()),
+            jnp.asarray(self._override_mask.copy()),
+            jnp.asarray(self._positions.copy()),
+            jnp.asarray(self._page_tables.copy()),
+            jnp.asarray(self._active.copy()),
+            self._next_key(),
+            jnp.asarray(self._temps.copy()),
+            jnp.asarray(self._top_ps.copy()),
+            jnp.asarray(self._top_ks.copy()),
+            jnp.asarray(self._seeds.copy()),
+            **self._state_args(),
+        )
+        n = self.decode_block
         self._count_decode_kv(self._positions, self._active, n)
         if self.cache.state:
             # the per-slot state a block's steps read and write: every slot's
@@ -4118,12 +3988,12 @@ class LLMEngine:
         # in a NEW tenancy, and this block belongs to its old one
         self._inflight.append((
             toks,
-            valid,
+            None,  # valid: a block's every row is a token
             [
                 (i, self.slots[i].request, self.slots[i].tenancy)
                 for i in live
             ],
-            None,  # spec_meta: classic/macro-step blocks carry none
+            None,  # spec_meta: a block carries none
             self._dispatch_seq(),
         ))
         for i in live:
@@ -4144,15 +4014,14 @@ class LLMEngine:
                     _obs.record_routed_pairs(held=first, elsewhere=second - first)
                 else:  # [pairs, rows]
                     _obs.record_expert_tile_rows(pairs=first, rows=second)
-        # the macro-step harvest plane (docs/multistep.md): the validity
-        # mask rides the SAME round trip as the tokens — per-slot accept
-        # stops at the first invalid row (the lane died at its stop token
-        # or length budget on-device; in a spec round, at its accept cut)
+        # the harvest plane (docs/speculative.md#the-harvest-boundary): a
+        # speculative round's validity mask rides the SAME round trip as the
+        # tokens — per-slot accept stops at the first invalid row (the lane's
+        # accept cut); a block carries no mask, its every row is a token
         valid_np = None if valid is None else np.asarray(valid)
         self._harvested(seq, "decode", t0)
         n_steps = int(toks_np.shape[0])
-        # only steps with a live lane executed (masked_scan's cond skips
-        # the rest once every lane died): count the truth, not the
+        # only rows with a live lane executed: count the truth, not the
         # program length. A spec round is ONE verify pass regardless of
         # how many chain rows it emitted.
         executed = (
@@ -4171,7 +4040,7 @@ class LLMEngine:
                 if s.request is not req or s.tenancy != tenancy:
                     break  # finished mid-block
                 if valid_np is not None and not valid_np[k, i]:
-                    break  # lane died on-device: the tail rows are holds
+                    break  # past the lane's accept cut: the tail rows are holds
                 s.position += 1
                 s.last_token = int(toks_np[k, i])
                 self._accept_token(i, s.last_token)
@@ -4200,32 +4069,7 @@ class LLMEngine:
                     # dispatch (spec or classic fallback) re-feeds it
                     # through the fresh-slot override lane
                     s.fresh = True
-            elif (
-                valid_np is not None
-                and taken < n_steps
-                and s.request is req
-                and s.tenancy == tenancy
-            ):
-                # the device retired this lane early but the host did NOT
-                # finish the request (a budget/position desync — should
-                # not happen; self-heal rather than diverge): resync the
-                # slot through the fresh-slot override lane, which re-feeds
-                # the last ACCEPTED token at the host-known position
-                s.fresh = True
-        if spec_meta is None:
-            # tokens-per-dispatch accounting covers classic AND macro-step
-            # (N=1 included): the A/B lever the bench reads is one series
-            self._ms_dispatches += 1
-            self._ms_tokens += accepted
-            _obs.record_multistep_dispatch(
-                tokens=accepted, steps_saved=n_steps - executed
-            )
-            prof = self.profiler
-            if prof is not None:
-                prof.note_dispatch_tokens(
-                    accepted, steps=int(self.decode_steps)
-                )
-        else:
+        if spec_meta is not None:
             # spec rounds keep their own tokens-per-dispatch plane
             # (docs/speculative.md#series): γ=0 fallback ROUNDS are counted
             # in _decode_tick, not here — this is a dispatched spec round
@@ -4236,9 +4080,9 @@ class LLMEngine:
                 gw.append(int(spec_meta["gammas"][i]))
             if len(gw) > 4096:
                 del gw[: len(gw) - 4096]
-            prof = self.profiler
-            if prof is not None:
-                prof.note_dispatch_tokens(accepted, steps=1)
+        prof = self.profiler
+        if prof is not None:
+            prof.note_dispatch_tokens(accepted)
         return worked
 
     def _slot_gamma(
@@ -4276,8 +4120,8 @@ class LLMEngine:
     def _spec_round(self, live: list[int], gammas, ngram_props=None) -> bool:
         """One fused speculative round (docs/speculative.md#program-shape):
         propose(γ) + verify + accept in ONE dispatch, harvested through
-        the SAME ``_process_block`` site as macro-step blocks (the [N, B]
-        validity plane). Spec rounds never pipeline — the next round's
+        the SAME ``_process_block`` site as decode blocks (its [N, B]
+        validity plane beside the tokens). Spec rounds never pipeline — the next round's
         positions depend on this round's acceptance — so the block is
         processed immediately after dispatch."""
         _tm(self._tick, "decode_dispatch")
@@ -4414,18 +4258,14 @@ class LLMEngine:
             elif slot.position + 1 >= self.max_model_len:
                 finished, reason = True, "length"
 
-        # macro-step path (docs/multistep.md): token-level bookkeeping
-        # above stays on the scheduler thread — the harvest boundary — but
-        # detokenization, stop-string scanning, and emission move to the
-        # DetokWorker. Streams the worker already owns keep routing even
-        # after the knob drops back to 1 (ordering), and a dead worker
-        # falls through to the inline path below.
+        # a speculating engine (docs/speculative.md#the-harvest-boundary):
+        # token-level bookkeeping above stays on the scheduler thread — the
+        # harvest boundary — but detokenization, stop-string scanning, and
+        # emission move to the DetokWorker. A stream the worker owns keeps
+        # routing through it (ordering); a dead worker falls through to the
+        # inline path below.
         w = self._detok
-        if (
-            self.decode_steps > 1
-            or self.spec_gamma > 0
-            or (w is not None and w.owns(req))
-        ):
+        if self.spec_gamma > 0 or (w is not None and w.owns(req)):
             if w is None or not w.alive:
                 w = self._ensure_detok()
             if w.alive:

@@ -121,11 +121,10 @@ def checkpoint_request(req) -> DecodeCheckpoint:
     the scheduler may still be appending — use ``LLMEngine.migrate_out``
     for a consistent mid-decode extraction instead.
 
-    Under the macro-step decode runtime (docs/multistep.md) this stays
-    exact while a slot holds an in-flight N-step dispatch: the harvest
-    plane appends only device-validated tokens to
-    ``req.generated_tokens``, so a checkpoint taken mid-macro-step
-    contains exactly the committed prefix — the un-harvested tail is
+    This stays exact while a slot holds an in-flight block or speculative
+    round (docs/speculative.md#the-harvest-boundary): the harvest
+    appends only accepted tokens to ``req.generated_tokens``, so a
+    checkpoint taken between harvests contains exactly the committed prefix — the un-harvested tail is
     discarded with the in-flight block and re-decoded on resume, and
     sampling's (seed, position) keying makes the re-decode identical."""
     base = getattr(req, "_orig_prompt_tokens", None)

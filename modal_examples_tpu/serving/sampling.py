@@ -37,10 +37,10 @@ def seeded_row_keys(
 
     A row with ``seeds[i] >= 0`` gets ``fold_in(fold_in(PRNGKey(0),
     seed), step_id)`` — a function of the REQUEST's seed and its absolute
-    decode position only. This is the exactness anchor the multi-step
-    decode runtime relies on (docs/multistep.md): classic one-block-
-    per-dispatch and N-step macro dispatch burn the engine key
-    differently, but every real request carries a seed (submit() assigns
+    decode position only. This is the exactness anchor a resume on another
+    engine relies on (docs/failover.md): engines of different
+    ``decode_block``, and a speculative round's classic lane, burn the
+    engine key differently, but every real request carries a seed (submit() assigns
     ``auto_seed`` when the caller passes none), so its sampled tokens
     depend on nothing the dispatch shape changes. Unseeded rows fall back
     to splits of the per-dispatch engine ``key`` and make no cross-shape

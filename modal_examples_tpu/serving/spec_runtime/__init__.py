@@ -2,7 +2,8 @@
 
 The engine-scheduler-integrated speculation runtime: one jitted
 propose+verify+accept round per dispatch (:mod:`.runtime`, built on
-``ops.scan_loop.masked_scan`` and emitting the multistep harvest plane)
+``ops.scan_loop.masked_scan`` and emitting the harvest plane), the
+detokenization worker its harvests feed (:mod:`.detok`),
 plus the acceptance-driven per-request γ policy (:mod:`.controller`).
 The standalone ``serving.speculative`` loop is NOT part of the serving
 path anymore — it survives only as the reference oracle for parity tests

@@ -2,9 +2,9 @@
 scheduler thread.
 
 The PR-14 profiler attributed a steady slice of every accept to
-``tokenizer.decode`` (the ``detokenize`` tick phase); with N-step macro
-dispatch the scheduler would pay it N times per harvest. This worker
-moves it off-thread: the scheduler feeds ACCEPTED token ids (already
+``tokenizer.decode`` (the ``detokenize`` tick phase); a speculative round
+(docs/speculative.md) harvests up to γ+1 tokens a slot, and the scheduler
+would pay it for each. This worker moves it off-thread: the scheduler feeds ACCEPTED token ids (already
 bookkept — stats, usage, TTFT, length checks all stay on the scheduler,
 where the harvest-boundary invariants live) and the worker owns
 everything text: incremental decode, stop-string scan/truncation, the
@@ -23,8 +23,8 @@ back through the queue.
 
 :meth:`flush` is the migration barrier (serving/failover.py): the
 scheduler drains the queue before reading ``req.emitted_len`` into a
-checkpoint, so mid-macro-step migration resumes from exactly the emitted
-cursor. A worker that dies keeps serving degraded: the engine falls back
+checkpoint, so a migration between harvests resumes from exactly the
+emitted cursor. A worker that dies keeps serving degraded: the engine falls back
 to inline detokenization and direct marker delivery (``alive`` gates
 every route).
 """
@@ -41,9 +41,8 @@ _log = get_logger("detok")
 
 class DetokWorker:
     """One daemon thread per engine, lazily created on the first routed
-    token (the engine only routes while ``decode_steps > 1`` or for
-    requests this worker already owns — mid-stream knob flips never
-    reorder a stream)."""
+    token (the engine only routes while it speculates, or for requests
+    this worker already owns)."""
 
     def __init__(self, *, tokenizer, deliver, safe_len, unstable_tail,
                  name: str = "engine"):
@@ -98,9 +97,6 @@ class DetokWorker:
         self._q.put(("flush", done, None))
         return done.wait(timeout)
 
-    def queue_depth(self) -> int:
-        return self._q.qsize()
-
     def stop(self, timeout: float = 5.0) -> None:
         """Drain every pending event, then stop the thread (engine.stop()
         calls this BEFORE releasing callers, so held text lands ahead of
@@ -154,7 +150,7 @@ class DetokWorker:
             st = self._states.pop(req.request_id, None)
         if st is not None:
             if st["stopped"] and marker.reason == "length":
-                # the stop match landed before a same-macro-step length
+                # the stop match landed before a same-harvest length
                 # finish: the stream was truncated at the stop, report it
                 marker = type(marker)("stop")
             elif not st["stopped"] and marker.reason in ("stop", "length"):

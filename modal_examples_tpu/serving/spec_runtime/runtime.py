@@ -3,8 +3,7 @@ dispatch.
 
 Program shape (docs/speculative.md): one engine round of speculative
 decoding is ONE jitted program — in draft mode a γ-step draft-model propose
-loop on :func:`~...ops.scan_loop.masked_scan` (the same control-flow core
-the macro-step decode runtime scans its decode steps with — lanes die when
+loop on :func:`~...ops.scan_loop.masked_scan` (lanes die when
 their per-slot γ budget is spent or they run out of page-table capacity,
 and a step whose every lane is dead skips the draft transformer entirely),
 then ONE ragged teacher-forced target forward over all γ+1 chain positions
@@ -16,17 +15,17 @@ Per-slot γ rides the batch as a traced ``gammas [B]`` argument, so mixed
 spec/non-spec slots coexist in one compiled program: a lane with
 ``gammas[i] == 0`` proposes nothing and takes the CLASSIC sampling path —
 its one token is drawn by the very same ``serving.sampling.sample`` call
-the block/multistep programs make, (seed, position)-keyed, with
+the block program makes, (seed, position)-keyed, with
 top_p/top_k honored — which is what lets the adaptive controller
 (:mod:`.controller`) shrink γ to 0 per request without switching programs,
 and what makes temperature>0 (always-seeded, see ``auto_seed``) requests
 token-identical to the non-speculative engine.
 
-Output is the multistep harvest plane (docs/multistep.md): ``(toks [N, B],
+Output is the harvest plane (docs/speculative.md#the-harvest-boundary): ``(toks [N, B],
 valid [N, B], last [B], caches...)`` with ``N = γ_max + 1`` —
 ``valid[k, i]`` marks row ``k`` of lane ``i`` as an accepted token, so the
 engine's ONE harvest site (``_process_block``: exactly two blocking reads,
-AST-pinned) accepts spec rounds and macro-step blocks identically and the
+AST-pinned) accepts spec rounds and decode blocks identically and the
 off-thread detok worker never knows which program produced its tokens.
 
 KV rollback is implicit and trie-safe: ``verify_step`` writes KV for every
@@ -61,7 +60,7 @@ SPEC_ADAPTIVE_ENV = "MTPU_SPEC_ADAPTIVE"
 
 def resolve_spec_adaptive(arg: bool | None = None) -> bool:
     """Resolve the adaptive-γ controller switch ONCE at engine build
-    (the MTPU_DECODE_STEPS / MTPU_KV_DTYPE knob rule): explicit arg beats
+    (the MTPU_KV_DTYPE knob rule): explicit arg beats
     ``MTPU_SPEC_ADAPTIVE`` beats off. Lands on a runtime-mutable engine
     attribute so benches A/B fixed-vs-adaptive without a rebuild."""
     if arg is None:
@@ -152,7 +151,7 @@ def accept_reject(
 
 
 def _emit_plane(out, n_emit, active, gammas, classic_tok):
-    """Convert an accept/reject result to the multistep harvest plane.
+    """Convert an accept/reject result to the harvest plane.
 
     ``classic_tok`` replaces row 0 for γ=0 lanes — the token the classic
     sampling path (``sample`` with the full temperature/top_p/top_k/seed
@@ -285,7 +284,7 @@ def build_spec_round_fn(
     return spec_round_fn
 
 
-def build_ngram_round_fn(cfg, *, gamma: int):
+def build_ngram_round_fn(cfg, *, gamma: int, mesh=None):
     """Build the jittable prompt-lookup round: host proposals → one ragged
     target verify + accept, emitting the harvest plane. No draft model, no
     draft cache, no device propose loop.

@@ -3,7 +3,7 @@
 This module is NOT the serving path. The engine's production speculation is
 the fused, batched, paged-KV round in :mod:`serving.spec_runtime`
 (docs/speculative.md) — scheduler-integrated, adaptive-depth, harvested
-through the multistep plane. What lives here is the textbook algorithm in
+through the engine's one harvest site. What lives here is the textbook algorithm in
 its simplest possible form, kept as the correctness yardstick the fused
 runtime is tested against (tests/test_speculative.py; the quarantine is
 enforced by tests/test_static.py — nothing in the package may import this

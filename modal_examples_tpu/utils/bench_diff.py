@@ -78,15 +78,10 @@ METRICS: list[tuple[str, bool, str]] = [
     # host share of serving time and the scheduler-tick tail from the
     # profiler's `overhead` section. host_fraction is a 0..1 rate (abs
     # comparison, like shed_rate); a regression in either means the engine
-    # got chattier per token — the exact lever ROADMAP #3's multi-step
+    # got chattier per token — the exact lever the rolled
     # decode loop exists to shrink, so it must fail the gate loudly.
     ("overhead.host_fraction", True, "abs"),
     ("overhead.tick_p95", True, "ratio"),
-    # macro-step decode (docs/multistep.md): accepted tokens per decode
-    # dispatch on the N-step arm — the amortization the multistep runtime
-    # buys; a drop means dispatches got chattier again (early exits firing
-    # too soon, or the knob silently off)
-    ("multistep.tokens_per_dispatch", False, "ratio"),
     # roofline utilization (docs/observability.md#roofline-and-usage-
     # accounting): achieved-vs-peak fractions are 0..1 rates (abs, like
     # shed_rate); per-chip tok/s is the TP-normalized headline — a drop

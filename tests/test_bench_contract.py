@@ -393,60 +393,6 @@ def test_bench_mixed_config_emits_interference_section():
 
 
 @pytest.mark.slow
-def test_bench_multistep_config_emits_multistep_section():
-    """The macro-step config must ride the same schema plus a ``multistep``
-    section: the N=1 vs N=8 A/B on the same warm engine
-    (docs/multistep.md). Direction checks assert the quantities the
-    macro-step runtime structurally amortizes — tokens-per-dispatch up,
-    per-token tick tail and scheduler-thread seconds per token down. Raw
-    host_fraction direction is an on-chip affair (on the CPU path-proof
-    the "device" is the host's own cores, so wall-clock attribution is
-    contention noise); here it just must be present and sane."""
-    out = subprocess.run(
-        [sys.executable, str(REPO / "bench.py")],
-        capture_output=True,
-        text=True,
-        timeout=500,
-        env={
-            **os.environ,
-            "BENCH_CPU": "1",
-            "BENCH_MODEL": "tiny-multistep",
-            "BENCH_NO_SECONDARY": "1",
-        },
-        cwd=str(REPO),
-    )
-    assert out.returncode == 0, out.stderr[-500:]
-    lines = [l for l in out.stdout.strip().splitlines() if l.strip()]
-    assert len(lines) == 1, lines
-    payload = json.loads(lines[0])
-    assert payload["value"] > 0 and payload["unit"] == "tok/s"
-    ms = payload.get("multistep")
-    assert ms, payload
-    assert {"steps", "classic", "multistep", "tokens_per_dispatch"} <= set(ms)
-    assert ms["steps"] == 8
-    for arm in ("classic", "multistep"):
-        stats = ms[arm]
-        assert {"dispatches", "tokens", "tokens_per_dispatch",
-                "host_fraction", "tick_p95",
-                "host_ms_per_token"} <= set(stats), stats
-        assert stats["dispatches"] > 0 and stats["tokens"] > 0
-        assert 0.0 <= stats["host_fraction"] <= 1.0
-        assert stats["tick_p95"] > 0 and stats["host_ms_per_token"] > 0
-    # the amortization itself: N=8 harvests several-fold more tokens per
-    # blocking device read than one-block-per-dispatch (decode_block=1)
-    assert (
-        ms["multistep"]["tokens_per_dispatch"]
-        > 2 * ms["classic"]["tokens_per_dispatch"]
-    ), ms
-    assert ms["tokens_per_dispatch"] == ms["multistep"]["tokens_per_dispatch"]
-    # ... and it buys real scheduler-thread time per token: the per-token
-    # tick tail and host seconds per token must DROP on the macro-step arm
-    assert ms["tick_p95_delta"] > 0, ms
-    assert ms["host_ms_per_token_delta"] > 0, ms
-    assert payload["engine_errors"] == 0
-
-
-@pytest.mark.slow
 def test_bench_tp_config_emits_sharded_plan():
     """The TP=2 config must ride the same schema plus the resolved
     per-shard plan: ``tp`` at the top level and ``impl_plan`` reporting the

@@ -460,7 +460,6 @@ def test_the_tail_chunk_over_cached_latents_is_as_wide_as_what_is_left(tail_engi
 REFUSED = {
     "int8 KV cache": dict(kv_dtype="int8"),
     "speculative decoding": dict(speculative=("ngram", 2)),
-    "multistep decode": dict(decode_steps=4),
     "tensor parallelism": "mesh",
     "vision": dict(vision=(object(), None)),
     "disaggregated transfer": dict(tiered_prefix=True),

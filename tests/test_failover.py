@@ -72,11 +72,15 @@ def _wait_tokens(req, n, timeout=60.0):
 class TestResumeDeterminism:
     """checkpoint -> resubmit -> byte-compare against the uninterrupted
     run: greedy + seeded, resume positions {first, mid, last}, bf16 +
-    int8 KV."""
+    int8 KV, on the engine that made the reference and on a peer that rolls
+    another number of steps into a dispatch (no resume position is a
+    multiple of either block: a checkpoint holds harvested tokens only, and
+    sampling is (seed, position)-keyed)."""
 
+    @pytest.mark.parametrize("peer", ["same-engine", "decode_block-4"])
     @pytest.mark.parametrize("kv_dtype", ["bfloat16", "int8"])
     @pytest.mark.parametrize("sampling", ["greedy", "seeded"])
-    def test_resume_matrix(self, jax_cpu, kv_dtype, sampling):
+    def test_resume_matrix(self, jax_cpu, kv_dtype, sampling, peer):
         from modal_examples_tpu.serving import SamplingParams
 
         sp = (
@@ -85,6 +89,9 @@ class TestResumeDeterminism:
             else SamplingParams(max_tokens=12, temperature=0.9, seed=7)
         )
         eng = _mk_engine(kv_dtype)
+        to = eng if peer == "same-engine" else _mk_engine(
+            kv_dtype, params=eng.params, decode_block=4,
+        )
         try:
             ref = eng.submit(PROMPT, sp)
             ref_text = "".join(eng.stream(ref))
@@ -94,15 +101,15 @@ class TestResumeDeterminism:
             # {first token, mid-stream, last token}: k tokens were
             # accepted before the failure
             for k in (1, n // 2, n - 1):
-                req = eng.make_request(PROMPT, sp)
+                req = to.make_request(PROMPT, sp)
                 req.auto_seed = ref.auto_seed  # rides the checkpoint
-                eng.submit_resumed(
+                to.submit_resumed(
                     req,
                     prompt_tokens=ref.prompt_tokens,
                     generated=ref_tokens[:k],
                     emitted_len=0,
                 )
-                out = "".join(eng.stream(req))
+                out = "".join(to.stream(req))
                 assert req.generated_tokens == ref_tokens, (
                     sampling, kv_dtype, k,
                 )
@@ -111,9 +118,11 @@ class TestResumeDeterminism:
                 # byte (tokens identical => detok identical)
                 assert out == ref_text, (sampling, kv_dtype, k)
                 assert req.finish_reason == ref.finish_reason
-            assert _drained(eng) == []
+            assert _drained(eng) == [] and _drained(to) == []
         finally:
             eng.stop()
+            if to is not eng:
+                to.stop()
 
     def test_resume_emission_cursor_dedupes(self, jax_cpu):
         """The emitted-text cursor: a resume with emitted_len=E emits

@@ -719,7 +719,6 @@ def test_a_boot_at_nine_chunks_builds_a_program_a_prefix_bucket(jax, G, model):
 REFUSED = {
     "int8 KV cache": dict(kv_dtype="int8"),
     "speculative decoding": dict(speculative=("ngram", 2)),
-    "multistep decode": dict(decode_steps=4),
     "tensor parallelism": "mesh",
     "vision": dict(vision=(object(), None)),
     "disaggregated transfer": dict(tiered_prefix=True),

@@ -620,7 +620,6 @@ REFUSED = {
     "prefix caching": dict(enable_prefix_cache=True),
     "int8 KV cache": dict(kv_dtype="int8"),
     "speculative decoding": dict(speculative=("ngram", 2)),
-    "multistep decode": dict(decode_steps=4),
     "tensor parallelism": "mesh",
     "vision": dict(vision=(object(), None)),
     "disaggregated transfer": dict(tiered_prefix=True),

@@ -279,6 +279,40 @@ class TestEngine:
             time.sleep(0.05)
         assert all(s.free for s in engine.slots)
 
+    @pytest.mark.parametrize("how, reason", [("abort", "stop"), ("deadline", "deadline")])
+    def test_ends_between_harvests_leave_nothing_behind(self, engine, how, reason):
+        """An abort, or a deadline that lapses, after some tokens were
+        accepted and with a decode block in flight: the block's unharvested
+        tokens are dropped at the next harvest, the stream ends with the
+        honest reason, and the slot and its pages are free."""
+        import time
+
+        from modal_examples_tpu.faults.chaos import check_drained
+        from modal_examples_tpu.serving import SamplingParams
+
+        def poll(done, timeout=60.0):
+            deadline = time.monotonic() + timeout
+            while time.monotonic() < deadline and not done():
+                time.sleep(0.01)
+            return done()
+
+        req = engine.submit(
+            "end me early", SamplingParams(max_tokens=96, temperature=0.0),
+        )
+        t = threading.Thread(target=lambda: list(engine.stream(req)))
+        t.start()
+        assert poll(lambda: len(req.generated_tokens) >= 4)
+        if how == "abort":
+            engine.abort(req)
+        else:
+            req.deadline = engine._clock() - 1.0
+        t.join(timeout=120)
+        assert not t.is_alive()
+        assert req.finish_reason == reason
+        assert len(req.generated_tokens) < 96
+        # the marker is delivered at once, the slot is reaped at the next tick
+        assert poll(lambda: check_drained({"eng": engine}) == [])
+
     def test_abort_queued_frees_reservation_and_depth(self, jax):
         """Regression (ISSUE 4 satellite): aborting a request that never
         reached a slot must free its reserved KV pages and decrement the

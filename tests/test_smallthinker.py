@@ -96,7 +96,6 @@ def test_the_preset_is_in_the_engines_table_and_refusals_are_by_name(model):
         ({"enable_prefix_cache": True}, "prefix caching"),
         ({"enable_prefix_cache": False, "kv_dtype": "int8"}, "int8 KV cache"),
         ({"enable_prefix_cache": False, "speculative": ("ngram", 2)}, "speculative decoding"),
-        ({"enable_prefix_cache": False, "decode_steps": 4}, "multistep decode"),
     ):
         kw.setdefault("kv_dtype", jnp.float32)
         with pytest.raises(NotImplementedError, match=said):

@@ -145,7 +145,7 @@ class TestGammaController:
 
     def test_resolve_spec_adaptive_knob_rule(self, monkeypatch):
         """Explicit arg beats MTPU_SPEC_ADAPTIVE beats off (the
-        MTPU_DECODE_STEPS knob rule, resolved once at engine build)."""
+        MTPU_KV_DTYPE knob rule, resolved once at engine build)."""
         from modal_examples_tpu.serving.spec_runtime import (
             SPEC_ADAPTIVE_ENV,
             resolve_spec_adaptive,

@@ -1622,52 +1622,6 @@ def test_canary_series_declared_and_emitted():
     )
 
 
-def test_multistep_series_declared_and_emitted():
-    """Closure for the macro-step decode series (``mtpu_multistep_*``),
-    both directions (the canary-series guard pattern): every declared
-    catalog constant must be referenced by a live emitter/reader, AND
-    every multistep recorder in observability/metrics.py must have a call
-    site outside metrics.py — a recorder nothing calls means the
-    tokens-per-dispatch A/B the bench gates on silently reads zeros."""
-    from modal_examples_tpu.observability import catalog
-
-    consts = {
-        attr: val
-        for attr, val in vars(catalog).items()
-        if isinstance(val, str) and val.startswith("mtpu_multistep_")
-    }
-    assert len(consts) >= 6, consts
-    catalog_path = PKG_ROOT / "observability" / "catalog.py"
-    package_src = {
-        path: path.read_text()
-        for path in sorted(PKG_ROOT.rglob("*.py"))
-        if path != catalog_path
-    }
-    unused = [
-        attr for attr in consts
-        if not any(
-            re.search(rf"\b{attr}\b", src) for src in package_src.values()
-        )
-    ]
-    assert not unused, (
-        "multistep series declared in the catalog but never referenced by "
-        f"an emitter/reader in the package: {unused}"
-    )
-    metrics_path = PKG_ROOT / "observability" / "metrics.py"
-    recorders = ("record_multistep_dispatch", "set_multistep_gauges")
-    orphans = [
-        fn for fn in recorders
-        if not any(
-            re.search(rf"\b{fn}\(", src)
-            for path, src in package_src.items()
-            if path != metrics_path
-        )
-    ]
-    assert not orphans, (
-        f"multistep recorders with no call site outside metrics.py: {orphans}"
-    )
-
-
 def test_spec_series_declared_and_emitted():
     """Closure for the fused-speculative series (``mtpu_spec_*``,
     docs/speculative.md#series), both directions: every declared catalog
@@ -1758,37 +1712,36 @@ def test_speculative_bypass_quarantined_to_oracle_duty():
     )
 
 
-#: the decode harvest/accept path (docs/multistep.md#harvest-boundary):
+#: the decode harvest/accept path (docs/speculative.md#the-harvest-boundary):
 #: these engine functions sit between a harvested token matrix and the
-#: client stream, and the multistep plane's whole point is ONE blocking
-#: device read per dispatch — so blocking host<-device materialization
+#: client stream, and a block or a round pays ONE blocking round trip per
+#: dispatch — so blocking host<-device materialization
 #: (np.asarray / np.array / .item()) is banned here outside the blessed
 #: harvest reads in ``_process_block``
 _HARVEST_PATH_FUNCS = {
     "_process_block", "_accept_token", "_finish_stream", "_deliver_finish",
 }
 #: the blessed sites: the block-level token + validity reads — exactly the
-#: multistep harvest plane, one (rel_path, dotted.func) entry
+#: harvest plane, one (rel_path, dotted.func) entry
 _HARVEST_READ_ALLOWLIST = {
     ("serving/engine.py", "LLMEngine._process_block"),
 }
 
 
 def test_harvest_path_has_no_per_token_device_reads():
-    """AST guard for the macro-step harvest boundary (docs/multistep.md):
-    in the engine's decode harvest/accept functions and everywhere in
-    serving/multistep/, the only blocking device materialization
+    """AST guard for the harvest boundary of a decode block and a
+    speculative round (docs/speculative.md#the-harvest-boundary): in the
+    engine's decode harvest/accept functions and everywhere in the round's
+    detokenization worker, the only blocking device materialization
     (``np.asarray`` / ``np.array`` / ``.item()``) allowed is the
     block-level harvest in ``_process_block`` — and that function performs
-    exactly two (the token matrix and the validity mask). A read anywhere
-    else on this path is a per-token host round-trip, the exact overhead
-    the N-step dispatch exists to amortize (frozen allowlist, exact match
-    both ways — a removed site prunes its entry)."""
+    exactly two (the token matrix and a round's validity mask). A read
+    anywhere else on this path is a per-token host round-trip, the exact
+    overhead a several-token dispatch exists to amortize (frozen allowlist,
+    exact match both ways — a removed site prunes its entry)."""
     targets = [
         (PKG_ROOT / "serving" / "engine.py", _HARVEST_PATH_FUNCS),
-    ] + [
-        (path, None)
-        for path in sorted((PKG_ROOT / "serving" / "multistep").glob("*.py"))
+        (PKG_ROOT / "serving" / "spec_runtime" / "detok.py", None),
     ]
     found = set()
     blessed_reads = 0
@@ -1833,7 +1786,7 @@ def test_harvest_path_has_no_per_token_device_reads():
     new_sites = found - _HARVEST_READ_ALLOWLIST
     assert not new_sites, (
         "blocking device reads on the decode harvest path outside the "
-        "multistep harvest plane — accept/detokenize must work from the "
+        "harvest plane — accept/detokenize must work from the "
         f"already-harvested block: {sorted(new_sites)}"
     )
     stale = _HARVEST_READ_ALLOWLIST - found
