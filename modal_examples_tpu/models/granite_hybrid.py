@@ -1,7 +1,8 @@
 """Granite-4.0-H (``granitemoehybrid``): Mamba-2 layers with a few
-full-attention layers between them, a dense SwiGLU in every layer, no
-position embedding, Granite's four multipliers, served on the engine's normal
-path.
+full-attention layers between them, a dense SwiGLU in every layer (H-Micro)
+or, behind every mixer, a shared SwiGLU beside routed experts (H-Small: 72
+experts, 10 a token), no position embedding, Granite's four multipliers,
+served on the engine's normal path.
 
 Two kinds of state live side by side (docs/recurrent_state.md):
 
@@ -37,11 +38,34 @@ place. In the prefill programs the state leaf is touched once: a gather of
 the rows' slots before the layers (chunk calls at an offset) and one scatter
 after them.
 
+**The routed half** (``cfg.n_experts > 0``; docs/recurrent_state.md "A routed
+layer beside per-slot state"). With ``u = RMSNorm(x)`` after the mixer, ``x +=
+residual_multiplier * (Shared(u) + Routed(u))``: ``Shared`` the SwiGLU of
+``ffn_dim`` every layer had (``mtpu.dense_mlp``), ``Routed(u) = sum_{e in
+top_k} p_e Expert_e(u)`` with ``l = W_r u`` in float32 over the router's whole
+width, the ``top_k`` largest chosen, ``p`` a softmax over the chosen logits
+(computed as the softmax over all, top-k, renormalised: the same ids and
+weights), and only the chosen pairs through ``moe.moe_swiglu_sparse``. The
+experts live in a stack of their own, ``moe_layers``, **indexed by layer**
+where the mixers' stacks are indexed by kind: ``router [L, D, n_experts]``
+and ``moe_gate`` / ``moe_up`` / ``moe_down`` ``[L, held, D, F]``, never sliced
+by a scan (``moe.scan_layers`` says why): a scan body holds two indices, its
+row in the Mamba stack and state leaf and its layer in the expert stacks. A
+chip may hold a share of the experts (``n_held_experts`` from
+``expert_offset`` on): the router keeps its width, pairs routed elsewhere add
+nothing, and no code stands in for the other chips or their exchange. A
+decode step then holds two Mosaic calls a Mamba layer on a TPU
+(``paged_impl_plan``: ``state_step`` and ``expert_scan``) and counts its
+routed pairs and its tiles' rows on the device (``counts_routed_pairs``,
+``counts_expert_tile_rows``).
+
 Departures from ``modeling_granitemoehybrid``, none of which changes a
 result: ``shared_mlp.input_linear`` is kept as its two halves (``gate``,
 ``up``) and ``mamba.in_proj`` as its three column blocks (``in_z``,
 ``in_xbc``, ``in_dt``); ``time_step_limit`` ``(0, inf)`` is a no-op and left
-out. The plain
+out; ``block_sparse_moe.input_linear`` is kept as its halves too
+(``moe_gate``, ``moe_up``); the route's renormalisation adds 1e-20 to the sum
+of ten probabilities. The plain
 reference is ``models/granite_hybrid_reference.py``.
 
 What this model does not do yet is refused by name where the engine is built
@@ -63,6 +87,7 @@ from ..ops import scopes as _scopes
 from ..ops.flash_attention import flash_attention, flash_attention_chunked
 from ..ops.ssm_step import ssm_step, ssm_step_shapes_ok, ssm_step_xla
 from . import layers
+from . import moe as _moe
 from .layers import refuse
 from .layers import scatter_rows as _scatter_rows
 
@@ -83,6 +108,11 @@ class GraniteHybridConfig:
     n_heads: int = 32
     n_kv_heads: int = 8
     ffn_dim: int = 8192  # shared_intermediate_size: the SwiGLU of every layer
+    n_experts: int = 0  # num_local_experts: the router's width; 0: no routed half
+    n_held_experts: int | None = None  # how many of them this chip holds (unset: all) ...
+    expert_offset: int = 0  # ... from this one on
+    top_k: int = 0  # num_experts_per_tok
+    expert_dim: int = 0  # intermediate_size: one routed expert's width
     mamba_n_heads: int = 64
     mamba_d_head: int = 64
     mamba_d_state: int = 128
@@ -115,6 +145,13 @@ class GraniteHybridConfig:
             raise ValueError("mamba_n_heads * mamba_d_head must be mamba_expand * dim")
         if self.mamba_n_heads % self.mamba_n_groups or self.n_heads % self.n_kv_heads:
             raise ValueError("heads must divide into their groups")
+        if self.n_experts and not (0 < self.top_k <= self.n_experts and self.expert_dim > 0):
+            raise ValueError("a routed model needs top_k in 1..n_experts and an expert_dim")
+        if not 0 <= self.expert_offset <= self.n_experts - self.held_experts:
+            raise ValueError(
+                f"experts {self.expert_offset}..+{self.held_experts} lie outside "
+                f"the router's {self.n_experts}"
+            )
 
     # -- the seam LLMEngine reads (docs/mla.md) ---------------------------------
 
@@ -164,6 +201,17 @@ class GraniteHybridConfig:
 
         return GRANITE_HYBRID_TARGETS
 
+    @property
+    def counts_routed_pairs(self) -> bool:
+        """``decode_step(return_counts=True)`` hands back, after the state,
+        [held, all] routed pairs of the live slots ..."""
+        return self.n_experts > 0
+
+    @property
+    def counts_expert_tile_rows(self) -> bool:
+        """... and then [pairs, rows] of the tiles computed for them."""
+        return self.n_experts > 0
+
     # -- sizes -------------------------------------------------------------------
 
     @property
@@ -173,6 +221,11 @@ class GraniteHybridConfig:
     @property
     def n_layers(self) -> int:
         return len(self.layer_types)
+
+    @property
+    def held_experts(self) -> int:
+        """Experts whose matrices this chip holds: all, or its share."""
+        return self.n_experts if self.n_held_experts is None else self.n_held_experts
 
     @property
     def head_dim(self) -> int:
@@ -212,7 +265,7 @@ class GraniteHybridConfig:
             + 3 * self.mamba_n_heads + self.d_inner
         )
         attn = D * (self.n_heads + 2 * self.n_kv_heads) * self.head_dim + self.n_heads * self.head_dim * D
-        per_layer = 3 * D * F + 2 * D
+        per_layer = 3 * D * F + 2 * D + D * self.n_experts + self.held_experts * 3 * D * self.expert_dim
         emb = self.vocab_size * D * (1 if self.tie_embeddings else 2)
         n_mamba = self.layer_types.count(MAMBA)
         return (
@@ -235,11 +288,27 @@ class GraniteHybridConfig:
         return GraniteHybridConfig(**base)
 
     @staticmethod
+    def tiny_moe(vocab_size: int = 512, **overrides) -> "GraniteHybridConfig":
+        """``tiny`` with the routed half behind every mixer: 8 experts of 32,
+        3 a token, beside a shared SwiGLU of 64."""
+        base = dict(ffn_dim=64, n_experts=8, top_k=3, expert_dim=32)
+        base.update(overrides)
+        return GraniteHybridConfig.tiny(vocab_size, **base)
+
+    @staticmethod
     def from_hf_config(path: str | Path) -> "GraniteHybridConfig":
-        """From a published ``config.json`` (``model_type`` ``granitemoehybrid``)."""
+        """From a published ``config.json`` (``model_type`` ``granitemoehybrid``).
+        A file that runs the first layers of the published stack keeps
+        ``layer_types`` whole and says how many in ``num_hidden_layers``; one
+        that states the chip's share of the experts says so beside the
+        published keys (as ``DeepseekV2Config.from_hf_config`` reads it):
+        ``num_local_experts`` then counts the experts held here and
+        ``expert_share`` is ``{"of": the router's width, "offset": the first
+        held expert}``. ``intermediate_size`` is read as one routed expert's
+        width (the published file has no key of its own for it)."""
         cfg = json.loads(Path(path).read_text())
         for key, want in (
-            ("position_embedding_type", "nope"), ("num_local_experts", 0),
+            ("position_embedding_type", "nope"),
             ("attention_bias", False), ("mamba_proj_bias", False),
             ("mamba_conv_bias", True), ("hidden_act", "silu"),
             ("normalization_function", "rmsnorm"),
@@ -249,13 +318,24 @@ class GraniteHybridConfig:
                     f"GraniteHybridConfig: {key}={cfg[key]!r} is not modelled (only {want!r})"
                 )
         shared = cfg.get("shared_intermediate_size", cfg.get("intermediate_size"))
+        kinds = tuple(cfg["layer_types"])
+        n = int(cfg.get("num_hidden_layers", len(kinds)))
+        if len(kinds) < n:
+            raise ValueError(f"layer_types names {len(kinds)} layers of {n}")
+        share = cfg.get("expert_share") or {}
+        held = int(cfg.get("num_local_experts") or 0)
         return GraniteHybridConfig(
             vocab_size=cfg["vocab_size"],
             dim=cfg["hidden_size"],
-            layer_types=tuple(cfg["layer_types"]),
+            layer_types=kinds[:n],
             n_heads=cfg["num_attention_heads"],
             n_kv_heads=cfg["num_key_value_heads"],
             ffn_dim=shared,
+            n_experts=int(share.get("of", held)),
+            n_held_experts=held if share else None,
+            expert_offset=int(share.get("offset", 0)),
+            top_k=int(cfg.get("num_experts_per_tok") or 0) if held else 0,
+            expert_dim=int(cfg["intermediate_size"]) if held else 0,
             mamba_n_heads=cfg["mamba_n_heads"],
             mamba_d_head=cfg["mamba_d_head"],
             mamba_d_state=cfg["mamba_d_state"],
@@ -343,6 +423,14 @@ def init_params(key: jax.Array, cfg: GraniteHybridConfig) -> dict:
             "wo": dense(k[3], L, cfg.n_heads * hd, D),
             **mlp(k[4], L),
         }
+    if cfg.n_experts:
+        k = jax.random.split(keys[4], 4)
+        L, E, Fe = cfg.n_layers, cfg.held_experts, cfg.expert_dim
+        params["moe_layers"] = {  # by layer index, whatever the layer's mixer
+            "router": dense(k[0], L, D, cfg.n_experts),
+            "moe_gate": dense(k[1], L, E, D, Fe), "moe_up": dense(k[2], L, E, D, Fe),
+            "moe_down": dense(k[3], L, E, Fe, D),
+        }
     return params
 
 
@@ -356,11 +444,17 @@ def load_hf_weights(model_dir, cfg: GraniteHybridConfig, *, quantization=None, d
     this module's tree. Published names, per layer ``N`` of
     ``model.layers.N``: ``input_layernorm``, ``post_attention_layernorm``,
     ``mamba.{in_proj,conv1d,A_log,D,dt_bias,norm,out_proj}`` or
-    ``self_attn.{q,k,v,o}_proj``, ``shared_mlp.{input_linear,output_linear}``;
+    ``self_attn.{q,k,v,o}_proj``, ``shared_mlp.{input_linear,output_linear}``
+    and, of a routed model, ``block_sparse_moe.{input_linear,output_linear}``
+    (one tensor for all experts, ``[experts, 2 F, D]`` and ``[experts, D,
+    F]``) and ``block_sparse_moe.router.layer``;
     a torch ``Linear`` is ``[out, in]`` and is transposed, ``conv1d.weight``
     ``[conv_dim, 1, d_conv]`` becomes ``[d_conv, conv_dim]``,
     ``input_linear`` is split into its ``gate`` and ``up`` halves and
-    ``in_proj`` into its ``z``, ``xBC`` and ``dt`` blocks."""
+    ``in_proj`` into its ``z``, ``xBC`` and ``dt`` blocks. The first
+    ``cfg.n_layers`` layers and ``cfg.vocab_size`` rows are read, and of the
+    experts those held here (``cfg.expert_offset`` on); the router keeps its
+    width."""
     import numpy as np
     from safetensors import safe_open
 
@@ -381,6 +475,8 @@ def load_hf_weights(model_dir, cfg: GraniteHybridConfig, *, quantization=None, d
         return get(name + ".weight").T
 
     per_kind: dict = {MAMBA: [], ATTENTION: []}
+    routed: list = []
+    held = slice(cfg.expert_offset, cfg.expert_offset + cfg.held_experts)
     for i, kind in enumerate(cfg.layer_types):
         p = f"model.layers.{i}."
         w_in = linear(p + "shared_mlp.input_linear")  # [D, 2F]: gate | up
@@ -404,6 +500,14 @@ def load_hf_weights(model_dir, cfg: GraniteHybridConfig, *, quantization=None, d
                 "w" + n: linear(p + f"self_attn.{n}_proj") for n in ("q", "k", "v", "o")
             })
         per_kind[kind].append(layer)
+        if cfg.n_experts:
+            e_in = get(p + "block_sparse_moe.input_linear.weight")[held]  # [E, 2F, D]: gate | up
+            routed.append({
+                "router": linear(p + "block_sparse_moe.router.layer"),
+                "moe_gate": e_in[:, : cfg.expert_dim].transpose(0, 2, 1),
+                "moe_up": e_in[:, cfg.expert_dim:].transpose(0, 2, 1),
+                "moe_down": get(p + "block_sparse_moe.output_linear.weight")[held].transpose(0, 2, 1),
+            })
 
     keep_f32 = ("dt_bias", "A_log", "D")
 
@@ -414,21 +518,23 @@ def load_hf_weights(model_dir, cfg: GraniteHybridConfig, *, quantization=None, d
         return jnp.asarray(full, jnp.float32 if name in keep_f32 else dt)
 
     params = {
-        "embed": jnp.asarray(get("model.embed_tokens.weight"), dt),
+        "embed": jnp.asarray(get("model.embed_tokens.weight")[: cfg.vocab_size], dt),
         "final_norm": jnp.asarray(get("model.norm.weight"), dt),
     }
     if not cfg.tie_embeddings:
-        params["lm_head"] = jnp.asarray(linear("lm_head"), dt)
+        params["lm_head"] = jnp.asarray(linear("lm_head")[:, : cfg.vocab_size], dt)
     for kind, rows in per_kind.items():
         if rows:
             params[f"{kind}_layers"] = {name: stack(rows, name) for name in rows[0]}
+    if routed:
+        params["moe_layers"] = {name: stack(routed, name) for name in routed[0]}
     return params
 
 
 def paged_impl_plan(
     cfg: GraniteHybridConfig, page_size: int, impl: str | None = None,
     scatter_impl: str = "xla", *, kv_dtype="bfloat16", mesh=None, warn: bool = True,
-    state_dtype=None,
+    state_dtype=None, expert_dtype=None,
 ) -> dict:
     """What runs for this model. Attention: the chunked XLA loop over the
     attention layers' pages and the XLA scatter (the ragged kernel wants a
@@ -439,8 +545,11 @@ def paged_impl_plan(
     is float32 in whole vregs (``ssm_step_shapes_ok``); XLA's update and
     reduction (``"xla"``) everywhere else: the CPU, where the kernel would
     run in the interpreter, and the tests' tiny shapes. One computation, and
-    two counts of passes over the state; chosen from what can be seen here,
-    by no option."""
+    two counts of passes over the state. A routed model's tile loop in a
+    decode step (``expert_scan``, a key only such a model's plan has):
+    ``moe.expert_scan_form``'s choice for experts of ``expert_dtype`` (unset:
+    the model's own), the grouped-matmul kernel on a TPU, XLA's loop
+    elsewhere. All chosen from what can be seen here, by no option."""
     from ..ops.kv_quant import resolve_kv_dtype
 
     if impl not in (None, "xla") or scatter_impl != "xla":  # unset: as "xla"
@@ -457,10 +566,13 @@ def paged_impl_plan(
             cfg.state_leaves[0][2] if state_dtype is None else state_dtype,
         )
     )
+    routed = {"expert_scan": _moe.expert_scan_form(
+        1, cfg.dim, cfg.expert_dim, expert_dtype or cfg.dtype
+    )} if cfg.n_experts else {}
     return {
         "attention": "xla-gather", "ragged_variant": None, "scatter": "xla",
         "kv_dtype": str(kvd), "tp": 1, "downgraded": [],
-        "state_step": "pallas" if kernel else "xla",
+        "state_step": "pallas" if kernel else "xla", **routed,
     }
 
 
@@ -657,9 +769,61 @@ def _residual(x, mixed, cfg):
     return x + (cfg.residual_multiplier * mixed).astype(x.dtype)
 
 
-def _mlp(layer, x, cfg):
+def route(router, x, cfg):
+    """x [T, D], router [D, n_experts] -> (weights [T, k] f32, expert ids
+    [T, k]): the logits in float32 over the router's whole width, the
+    ``top_k`` largest (a tie to the lower id), a softmax over the chosen
+    ones, computed as Mixtral's route is (``moe.moe_swiglu_routed``): the
+    softmax over all, its top-k, renormalised."""
+    with jax.named_scope(_scopes.ROUTER):
+        logits = jnp.einsum("td,de->te", x.astype(jnp.float32), router.astype(jnp.float32))
+        return _moe.route_group_limited(
+            jax.nn.softmax(logits, axis=-1), cfg.top_k, renormalize=True
+        )
+
+
+def held_tile_rows(ids, token_mask, cfg, tile: int):
+    """[pairs, rows] int32: the counted tokens' pairs that land on the
+    experts held here, and the rows of the tiles ``moe_swiglu_sparse``
+    computes for them (each reached expert's pairs padded to whole tiles)."""
+    E = cfg.held_experts
+    local = ids - cfg.expert_offset
+    here = (local >= 0) & (local < E)
+    if token_mask is not None:
+        here = here & token_mask[:, None]
+    per_expert = jnp.bincount(jnp.where(here, local, E).reshape(-1), length=E + 1)[:E]
+    rows = jnp.sum((per_expert + tile - 1) // tile * tile)
+    return jnp.stack([jnp.sum(per_expert), rows]).astype(jnp.int32)
+
+
+def _mlp(layer, x, cfg, moe=None, index=None, token_mask=None):
+    """``x + residual_multiplier * MLP(RMSNorm(x))`` and what the layer
+    counted. A dense model's MLP is the layer's SwiGLU and it counts nothing
+    (``()``). A routed model's is that SwiGLU, the shared expert, plus the
+    routed experts of layer ``index`` of ``moe``, the experts' whole stacks
+    (``params["moe_layers"]``), and it counts ([held, all] routed pairs,
+    [pairs, rows] of its tiles) over the tokens of ``token_mask``."""
     h = layers.rms_norm(x, layer["mlp_norm"], cfg.norm_eps)
-    return _residual(x, layers.swiglu_mlp({k: layer[k] for k in ("gate", "up", "down")}, h), cfg)
+    shared = layers.swiglu_mlp({k: layer[k] for k in ("gate", "up", "down")}, h)
+    if moe is None:
+        return _residual(x, shared, cfg), ()
+    flat = h.reshape(-1, cfg.dim)
+    mask = None if token_mask is None else token_mask.reshape(-1)
+    weights, ids = route(moe["router"][index], flat, cfg)
+    out, pairs = _moe.moe_swiglu_sparse(
+        *(moe[n] for n in _moe.EXPERT_LEAVES), flat, ids, weights,
+        expert_offset=cfg.expert_offset, token_mask=mask, layer=index,
+    )
+    tile = _moe.expert_tile(flat.shape[0], cfg.top_k, cfg.held_experts)
+    mixed = shared.astype(jnp.float32) + out.reshape(x.shape)
+    return _residual(x, mixed, cfg), (pairs, held_tile_rows(ids, mask, cfg, tile))
+
+
+def _layer_ids(moe, first: int, count: int) -> tuple:
+    """What a scan over a run of Mamba layers scans beside their rows in the
+    Mamba stack: a routed model's layer indices (into ``moe``, the experts'
+    stacks), nothing for a dense model."""
+    return () if moe is None else (jnp.arange(first, first + count),)
 
 
 def _embed(params, tokens, cfg):
@@ -779,15 +943,18 @@ def _prefill_impl(params, tokens, k_pages, v_pages, page_tables, lens, cfg, *,
         n_prefix_pages = q_offset // page_size
         prefix_tables = page_tables[:, :n_prefix_pages]
 
+    moe = params.get("moe_layers")  # the experts' stacks, by layer index; None: a dense model
+
     def mamba_layer(x, scanned):
-        i, h0, tail0 = scanned
+        # i: the row in the Mamba stack; index: the layer, a routed model's second index
+        i, h0, tail0, *index = scanned
         layer = _row(params["mamba_layers"], i)
         u = layers.rms_norm(x, layer["mixer_norm"], cfg.norm_eps)
         mixed, h, tail = _mamba_prefill(layer, u, valid, lens, h0, tail0, cfg)
-        x = _mlp(layer, _residual(x, mixed, cfg), cfg)
+        x, _ = _mlp(layer, _residual(x, mixed, cfg), cfg, moe, *index, token_mask=valid)
         return x, (h, tail)
 
-    def attention_layer(x, j):
+    def attention_layer(x, j, index):
         layer = _row(params["attention_layers"], j)
         u = layers.rms_norm(x, layer["mixer_norm"], cfg.norm_eps)
         q, k, v = _qkv(layer, u, cfg)
@@ -816,22 +983,27 @@ def _prefill_impl(params, tokens, k_pages, v_pages, page_tables, lens, cfg, *,
                 )
         o = o.transpose(0, 2, 1, 3).reshape(B, C, cfg.n_heads * cfg.head_dim)
         x = _residual(x, layers.mm(o, layer["wo"]), cfg)
-        return _mlp(layer, x, cfg), (k, v)
+        x, _ = _mlp(layer, x, cfg, moe, index, token_mask=valid)
+        return x, (k, v)
 
     x = _embed(params, tokens, cfg)
     hs, tails, ks, vs = [], [], [], []
+    at = 0  # the segment's first layer
     for kind, first, count in cfg.segments:
         if kind == MAMBA:
             rows = slice(first, first + count)
             x, (h, tail) = jax.lax.scan(
-                mamba_layer, x, (jnp.arange(first, first + count), h_in[rows], tail_in[rows])
+                mamba_layer, x,
+                (jnp.arange(first, first + count), h_in[rows], tail_in[rows],
+                 *_layer_ids(moe, at, count)),
             )
             hs.append(h)
             tails.append(tail)
         else:
-            x, (k, v) = attention_layer(x, first)
+            x, (k, v) = attention_layer(x, first, at)
             ks.append(k)
             vs.append(v)
+        at += count
     if cached:
         if ks:
             # [La, B, Hkv, C, hd] -> the block [La, B, C, Hkv, hd] at (page, slot)
@@ -914,6 +1086,7 @@ def decode_step(
     scatter_impl: str = "xla",
     ragged_variant: str | None = None,
     mesh=None,
+    return_counts: bool = False,
     *,
     state: tuple,  # per-slot leaves [n_mamba, B, ...]: row b is slot b
 ):
@@ -921,13 +1094,15 @@ def decode_step(
     pages (read-only inside the step, one scatter after it, as
     ``llama.decode_step``), the Mamba layers one state step over every slot,
     the state leaves indexed ``[layer]`` and updated in place. A slot that is
-    not ``active`` keeps its state. Returns (logits [B, vocab], k_pages,
-    v_pages, state)."""
+    not ``active`` keeps its state and routes no pair. Returns (logits [B,
+    vocab], k_pages, v_pages, state) and, with ``return_counts`` (a routed
+    model's), two [2] int32 after them: [held, all] routed pairs of the live
+    slots, and [pairs, rows] of the tiles computed for them."""
     _check_serving(cfg, k_pages, mesh)
     plan = paged_impl_plan(
         cfg, k_pages.shape[2], impl, scatter_impl, kv_dtype=k_pages.dtype,
         state_dtype=state[0].dtype,
-    )
+    )  # (the experts' tile loop picks its own form where it runs: moe_swiglu_sparse)
     page_size = k_pages.shape[2]
     B = tokens.shape[0]
     page_idx = jnp.take_along_axis(page_tables, (positions // page_size)[:, None], axis=1)[:, 0]
@@ -935,24 +1110,34 @@ def decode_step(
     slot = jnp.where(active, positions % page_size, 0)
     prefix_lens = jnp.where(active, positions, 0).astype(jnp.int32)
 
-    def mamba_layer(carry, i):
-        x, ssm, tails = carry
+    moe = params.get("moe_layers")  # the experts' stacks, by layer index; None: a dense model
+    # what the routed layers count, summed over them: () for a dense model
+    counts = () if moe is None else (jnp.zeros((2,), jnp.int32),) * 2
+
+    def mamba_layer(carry, scanned):
+        x, ssm, tails, *counts = carry
+        # i: the row in the Mamba stack and the state leaf; index: the layer,
+        # a routed model's second index
+        i, *index = scanned
         layer = _row(params["mamba_layers"], i)
         u = layers.rms_norm(x, layer["mixer_norm"], cfg.norm_eps)
         mixed, ssm, tails = _mamba_step(
             layer, u, active, ssm, tails, i, cfg, plan["state_step"]
         )
-        x = _mlp(layer, _residual(x, mixed, cfg), cfg)
-        return (x, ssm, tails), None
+        x, counted = _mlp(layer, _residual(x, mixed, cfg), cfg, moe, *index, token_mask=active)
+        return (x, ssm, tails, *(a + b for a, b in zip(counts, counted))), None
 
     x = _embed(params, tokens, cfg)
     ssm, tails = state
     ks, vs = [], []
+    at = 0  # the segment's first layer
     for kind, first, count in cfg.segments:
         if kind == MAMBA:
-            (x, ssm, tails), _ = jax.lax.scan(
-                mamba_layer, (x, ssm, tails), jnp.arange(first, first + count)
+            (x, ssm, tails, *counts), _ = jax.lax.scan(
+                mamba_layer, (x, ssm, tails, *counts),
+                (jnp.arange(first, first + count), *_layer_ids(moe, at, count)),
             )
+            at += count
             continue
         layer = _row(params["attention_layers"], first)
         u = layers.rms_norm(x, layer["mixer_norm"], cfg.norm_eps)
@@ -963,11 +1148,14 @@ def decode_step(
             sm_scale=cfg.attention_multiplier,
         )
         x = _residual(x, layers.mm(_unfold_o(o, cfg).reshape(B, -1), layer["wo"]), cfg)
-        x = _mlp(layer, x, cfg)
+        x, counted = _mlp(layer, x, cfg, moe, at, token_mask=active)
+        counts = [a + b for a, b in zip(counts, counted)]
+        at += count
         ks.append(k)
         vs.append(v)
     if ks:
         # [La, B, Hkv / fold, fold * hd]: one scatter for every attention layer's token
         k_pages = _scatter_rows(k_pages, jnp.stack(ks), page_idx, slot)
         v_pages = _scatter_rows(v_pages, jnp.stack(vs), page_idx, slot)
-    return _logits(params, x, cfg), k_pages, v_pages, (ssm, tails)
+    out = (_logits(params, x, cfg), k_pages, v_pages, (ssm, tails))
+    return (*out, *counts) if return_counts else out

@@ -18,6 +18,19 @@ With ``x`` the residual stream:
   ``x += residual_multiplier * MLP(RMSNorm(x))``, ``MLP(u) = W_out (silu(g) *
   v)`` with ``[g | v] = W_in u`` (the tree keeps ``W_in``'s halves as ``gate``
   and ``up``, and the mixer's ``in_proj`` as ``in_z``, ``in_xbc``, ``in_dt``).
+- a routed model (``num_local_experts`` > 0, Granite-4.0-H-Small's 72): that
+  MLP is the **shared expert** (width ``shared_intermediate_size``) and the
+  second half is ``x += residual_multiplier * (Shared(u) + Routed(u))``,
+  ``Routed(u) = sum_{e in top_k} p_e W_out^e (silu(g_e) * v_e)``, ``[g_e |
+  v_e] = W_in^e u`` (width ``intermediate_size``), ``l = W_r u`` over all the
+  experts, ``top_k`` the ``num_experts_per_tok`` largest ``l`` (a tie to the
+  lower id), ``p = softmax(l[top_k])``: top-k of the logits, then a softmax
+  over the chosen. Every held expert's product is written out for every
+  token and weighed by ``p`` or by zero: no sort, no tiles. ``held=(first,
+  count)``: the tree's expert matrices are experts ``first .. first + count -
+  1`` of a router that is wider (one chip's share of an expert-parallel
+  layer); what the absent experts would add is left out, as the program
+  leaves it out.
 - attention mixer: causal softmax over ``q . k * attention_multiplier``, GQA,
   no bias, no rotary embedding (``position_embedding_type`` ``nope``).
 - Mamba-2 mixer: ``[z | xBC | dt] = W_in u``; ``xBC_t = silu(b + sum_j w_j
@@ -28,7 +41,12 @@ With ``x`` the residual stream:
   ``d_inner`` for ``n_groups`` 1 (over each group's share otherwise).
 
 Departures from the published code: none in the mathematics.
-``time_step_limit`` is ``(0, inf)`` there, a no-op clamp, and is left out.
+``time_step_limit`` is ``(0, inf)`` there, a no-op clamp, and is left out;
+the published routed layer gathers each expert's tokens
+(``GraniteMoeHybridParallelExperts`` over ``index_sorted_experts``) where
+this one multiplies every token by every held expert and weighs the unchosen
+by zero: the same sum; ``block_sparse_moe.input_linear`` is kept as its
+halves (``moe_gate``, ``moe_up``), as ``shared_mlp.input_linear`` is.
 """
 
 from __future__ import annotations
@@ -105,6 +123,29 @@ def mlp(layer, u):
     return (jax.nn.silu(u @ layer["gate"]) * (u @ layer["up"])) @ layer["down"]
 
 
+def route(logits, top_k: int):
+    """``logits`` [S, experts] -> (ids [S, k], weights [S, k]): the ``top_k``
+    largest logits (a tie to the lower id), a softmax over those."""
+    top, ids = jax.lax.top_k(logits, top_k)
+    return ids, jax.nn.softmax(top, axis=-1)
+
+
+def routed(moe, u, top_k: int, held=None):
+    """The routed experts over u [S, D] (normed). ``moe``: one layer's
+    ``router`` [D, experts] and ``moe_gate`` / ``moe_up`` [E, D, F],
+    ``moe_down`` [E, F, D], the matrices of experts ``held = (first, count)``
+    of the router's width (unset: all of it, from 0)."""
+    n = moe["router"].shape[-1]
+    first, count = held or (0, moe["moe_gate"].shape[0])
+    ids, p = route(u @ moe["router"], top_k)
+    combine = (jax.nn.one_hot(ids, n, dtype=u.dtype) * p[..., None]).sum(axis=1)  # [S, experts]
+    out = jnp.zeros_like(u)
+    for j in range(count):  # every held expert's product, written out
+        y = (jax.nn.silu(u @ moe["moe_gate"][j]) * (u @ moe["moe_up"][j])) @ moe["moe_down"][j]
+        out = out + combine[:, first + j, None] * y
+    return out
+
+
 def layer_at(params: dict, cfg, index: int) -> tuple[str, dict]:
     """(kind, the float32 weights of layer ``index``) out of the program's
     tree, which stacks the layers of a kind."""
@@ -113,8 +154,19 @@ def layer_at(params: dict, cfg, index: int) -> tuple[str, dict]:
     return kind, _f32(jax.tree.map(lambda a: a[row], params[f"{kind}_layers"]))
 
 
-def forward(params: dict, tokens, cfg):
-    """tokens [S] -> logits [S, vocab] in float32."""
+def moe_at(params: dict, index: int) -> dict | None:
+    """The float32 router and expert matrices of layer ``index`` (the
+    program's tree stacks them by layer), or None for a dense model."""
+    if "moe_layers" not in params:
+        return None
+    return _f32(jax.tree.map(lambda a: a[index], params["moe_layers"]))
+
+
+def forward(params: dict, tokens, cfg, *, top_k: int | None = None, shared: bool = True):
+    """tokens [S] -> logits [S, vocab] in float32. A routed model's tree
+    holds experts ``cfg.expert_offset .. + cfg.held_experts - 1``. ``top_k``
+    other than the configuration's and ``shared=False`` (the shared expert
+    left out) are what the serving benchmark's controls compute."""
     with jax.default_matmul_precision("highest"):
         embed = params["embed"].astype(jnp.float32)
         x = embed[tokens] * cfg.embedding_multiplier
@@ -127,6 +179,11 @@ def forward(params: dict, tokens, cfg):
                 mixed = attention_mixer(layer, u, cfg)
             x = x + cfg.residual_multiplier * mixed
             u = rms_norm(x, layer["mlp_norm"], cfg.norm_eps)
-            x = x + cfg.residual_multiplier * mlp(layer, u)
+            out = mlp(layer, u) if shared else jnp.zeros_like(u)
+            moe = moe_at(params, index)
+            if moe is not None:
+                held = (cfg.expert_offset, cfg.held_experts)
+                out = out + routed(moe, u, top_k or cfg.top_k, held)
+            x = x + cfg.residual_multiplier * out
         x = rms_norm(x, params["final_norm"].astype(jnp.float32), cfg.norm_eps)
         return (x @ embed.T) / cfg.logits_scaling

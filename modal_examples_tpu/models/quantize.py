@@ -89,10 +89,12 @@ DEEPSEEK_V2_TARGETS = (
 
 
 #: ... and in a Granite hybrid tree (models/granite_hybrid.py): the Mamba-2
-#: mixer's two projections, the attention projections and the SwiGLU; the
-#: convolution, the norms and the per-head ``A_log`` / ``dt_bias`` / ``D`` stay
+#: mixer's two projections, the attention projections, the SwiGLU (a routed
+#: model's shared expert) and the routed experts; the convolution, the norms,
+#: the router and the per-head ``A_log`` / ``dt_bias`` / ``D`` stay
 GRANITE_HYBRID_TARGETS = (
     "in_z", "in_xbc", "in_dt", "out_proj", "wq", "wk", "wv", "wo", "gate", "up", "down",
+    "moe_gate", "moe_up", "moe_down",
 )
 
 

@@ -427,6 +427,7 @@ MODEL_PRESETS = {
     "tiny-moe": llama.LlamaConfig.tiny_moe,
     "tiny-deepseek-v2": deepseek_v2.DeepseekV2Config.tiny,
     "tiny-granite-hybrid": granite_hybrid.GraniteHybridConfig.tiny,
+    "tiny-granite-moe": granite_hybrid.GraniteHybridConfig.tiny_moe,
     "tiny-glm-dsa": glm_dsa.GlmDsaConfig.tiny,
     "tiny-lfm2": lfm2.Lfm2Config.tiny,
     "tiny-smallthinker": smallthinker.SmallThinkerConfig.tiny,

@@ -582,15 +582,11 @@ def test_disaggregated_roles_and_lora_are_refused_too(jax, G, model):
         G.partition_specs(cfg)
 
 
-def test_load_hf_weights_maps_the_published_names(jax, G, ref, model, tmp_path):
-    """A made-up tiny checkpoint under the published tensor names (torch's
-    ``[out, in]`` matrices, ``conv1d.weight`` ``[conv_dim, 1, d_conv]``,
-    ``input_linear`` and ``in_proj`` whole) loads as the tree it was written
-    from, and gives its logits."""
-    import jax.numpy as jnp
+def _write_published_checkpoint(jax, cfg, params, model_dir):
+    """``params`` as ``model.safetensors`` under the published tensor names
+    (a routed model's ``block_sparse_moe.*`` among them)."""
     from safetensors.numpy import save_file
 
-    cfg, params = model
     tensors = {
         "model.embed_tokens.weight": np.asarray(params["embed"]),
         "model.norm.weight": np.asarray(params["final_norm"]),
@@ -605,6 +601,12 @@ def test_load_hf_weights_maps_the_published_names(jax, G, ref, model, tmp_path):
         tensors[p + "shared_mlp.input_linear.weight"] = np.concatenate(
             [layer["gate"], layer["up"]], axis=1).T
         tensors[p + "shared_mlp.output_linear.weight"] = layer["down"].T
+        if "moe_layers" in params:  # one tensor for all experts: [E, 2F, D], [E, D, F]; [E, D]
+            moe = jax.tree.map(lambda a: np.asarray(a[i]), params["moe_layers"])
+            tensors[p + "block_sparse_moe.router.layer.weight"] = moe["router"].T
+            tensors[p + "block_sparse_moe.input_linear.weight"] = np.concatenate(
+                [moe["moe_gate"], moe["moe_up"]], axis=2).transpose(0, 2, 1)
+            tensors[p + "block_sparse_moe.output_linear.weight"] = moe["moe_down"].transpose(0, 2, 1)
         if kind == "mamba":
             tensors[p + "mamba.in_proj.weight"] = np.concatenate(
                 [layer["in_z"], layer["in_xbc"], layer["in_dt"]], axis=1).T
@@ -618,7 +620,18 @@ def test_load_hf_weights_maps_the_published_names(jax, G, ref, model, tmp_path):
             for n in "qkvo":
                 tensors[p + f"self_attn.{n}_proj.weight"] = layer["w" + n].T
     save_file({k: np.ascontiguousarray(v) for k, v in tensors.items()},
-              str(tmp_path / "model.safetensors"))
+              str(model_dir / "model.safetensors"))
+
+
+def test_load_hf_weights_maps_the_published_names(jax, G, ref, model, tmp_path):
+    """A made-up tiny checkpoint under the published tensor names (torch's
+    ``[out, in]`` matrices, ``conv1d.weight`` ``[conv_dim, 1, d_conv]``,
+    ``input_linear`` and ``in_proj`` whole) loads as the tree it was written
+    from, and gives its logits."""
+    import jax.numpy as jnp
+
+    cfg, params = model
+    _write_published_checkpoint(jax, cfg, params, tmp_path)
     loaded = G.load_hf_weights(tmp_path, cfg)
     assert jax.tree.structure(loaded) == jax.tree.structure(params)
     for got, want in zip(jax.tree.leaves(loaded), jax.tree.leaves(params)):

@@ -1,6 +1,6 @@
 import pytest
 
-from .helpers import GIB, _nbytes
+from .helpers import GIB, _kernel_scopes, _nbytes
 
 
 # -- Granite-4.0-H-Micro at its published widths (PR 31) ------------------------------------
@@ -145,3 +145,93 @@ def test_granite_prefill_call_compiles_at_head_width_64_on_a_v5e(granite):
     assert "tpu_custom_call" in text  # the flash kernel went through Mosaic
     assert "mtpu.ssm_scan" in text and "mtpu.ssm_proj" in text
 
+
+
+# -- Granite-4.0-H-Small, one period on one chip of an EP2 layer (PR 45) -------------------------
+
+
+@pytest.fixture(scope="module")
+def granite_small(one_chip):
+    """The engine's decode block for the benchmark's routed configuration
+    (ten layers, 36 of 72 experts held, 128 state heads; 64 slots, 6144
+    pages of 16), as shapes on the described chip: nothing is allocated."""
+    import jax
+    import jax.numpy as jnp
+
+    from modal_examples_tpu.models import granite_hybrid as G
+    from modal_examples_tpu.serving.engine import LLMEngine
+
+    cfg = G.GraniteHybridConfig(
+        vocab_size=25088, dim=4096, mamba_n_heads=128, ffn_dim=1536, n_experts=72,
+        n_held_experts=36, top_k=10, expert_dim=768, attention_multiplier=0.0078125,
+        logits_scaling=16.0, layer_types=("mamba",) * 5 + ("attention",) + ("mamba",) * 4,
+    )
+    slots, n_pages, page_size, pages_per_slot = 64, 6144, 16, 128
+    S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+    params = jax.tree.map(
+        lambda a: S(a.shape, a.dtype),
+        jax.eval_shape(lambda k: G.init_params(k, cfg), jax.random.PRNGKey(0)),
+    )
+    pages = S((cfg.n_cache_layers, n_pages, page_size, *cfg.cache_leaf_shapes[0]), jnp.bfloat16)
+    state = tuple(S((n, slots, *shape), jnp.dtype(dt)) for n, shape, dt in cfg.state_leaves)
+    eng = object.__new__(LLMEngine)  # the program's body, without an engine's arrays
+    eng._model, eng.cfg, eng.mesh, eng._attn_impl = G, cfg, None, "flash"
+    eng.paged_impl, eng.scatter_impl = None, "xla"
+    eng._block_counts, eng.decode_block = ("routed_pairs", "expert_tile_rows"), 8
+    i32 = lambda *s: S(s, jnp.int32)  # noqa: E731
+    f32 = lambda *s: S(s, jnp.float32)  # noqa: E731
+    B = slots
+
+    def block():
+        return jax.jit(
+            eng._decode_block_fn, donate_argnums=(1, 2), donate_argnames=("state",)
+        ).lower(
+            params, pages, pages, i32(B), i32(B), S((B,), bool), i32(B),
+            i32(B, pages_per_slot), S((B,), bool), S((2,), jnp.uint32), f32(B), f32(B),
+            i32(B), i32(B), state=state,
+        ).compile()
+
+    backend, jax.default_backend = jax.default_backend, lambda: "tpu"
+    try:
+        yield {"cfg": cfg, "block": block, "params_bytes": sum(map(_nbytes, jax.tree.leaves(params))),
+               "state_bytes": sum(_nbytes(s) for s in state), "page_bytes": 2 * _nbytes(pages)}
+    finally:
+        jax.default_backend = backend
+
+
+def test_the_routed_decode_block_holds_two_mosaic_calls_a_layer_and_its_state_in_place(granite_small):
+    """The 64-slot decode block of one period of Granite-4.0-H-Small on one
+    chip of two: 9.3 GB of bf16 weights, 2.45 GB of per-slot state (a 4 MiB
+    float32 state a slot a layer at 128 heads, two head tiles of 2 MiB),
+    0.4 GB of pages. Each scan over a run of Mamba layers holds the state
+    step's kernel under ``mtpu.ssm_step`` (the leaf handed whole and aliased
+    to it) **and** the experts' grouped matmul under ``mtpu.expert_scan`` (bf16
+    experts of 4096 x 768, F in 2 blocks of 384, the ``[10, 36, 4096, 768]``
+    stacks handed whole: no layer's or expert's slice is a copy); the
+    attention layer holds the second alone. The state and the pages are
+    updated in place, and with the arguments the temporaries fit the chip."""
+    import re
+
+    compiled = granite_small["block"]()
+    mem = compiled.memory_analysis()
+    held = granite_small["state_bytes"] + granite_small["page_bytes"]
+    assert 9.2e9 < granite_small["params_bytes"] < 9.4e9
+    assert 2.44e9 < granite_small["state_bytes"] < 2.46e9 and granite_small["page_bytes"] == 402653184
+    assert mem.alias_size_in_bytes >= held  # updated in place, not copied out
+    assert mem.temp_size_in_bytes < 1 * GIB
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes) < 13.5 * GIB
+    text = compiled.as_text()
+    assert "f32[9,64,128,64,128]" in text  # the state leaf, handed whole to the kernel
+    assert not re.search(r"f32\[9,64,128,64,128\]\S* copy\(", text)
+    assert "f32[64,128,64,128]" not in text  # no XLA pass over a layer's state
+    assert "bf16[10,36,4096,768]" in text and "bf16[10,36,768,4096]" in text
+    assert not re.search(r"bf16\[(10,)?36,(4096,768|768,4096)\]\S* copy\(", text)
+    assert "bf16[36,4096,768]" not in text and "bf16[4096,768]" not in text  # no slice of the stacks
+    scopes = _kernel_scopes(text)
+    step = [s for s in scopes if "mtpu.ssm_step" in s]
+    scan = [s for s in scopes if "mtpu.expert_scan" in s]
+    # two runs of Mamba layers, each a scan whose body holds both; the attention layer's experts
+    assert len(step) == 2 and len(scan) == 3 and len(scopes) == 5
+    for scope in ("mtpu.router", "mtpu.expert_dispatch", "mtpu.dense_mlp", "mtpu.ssm_proj",
+                  "mtpu.attention"):
+        assert scope in text
